@@ -19,6 +19,7 @@ import optax
 import horovod_tpu.jax as hvd
 from horovod_tpu import spmd
 from horovod_tpu.models import ResNet50, ResNet101
+from horovod_tpu.utils.compile_cache import enable_compile_cache
 
 
 def main():
@@ -35,6 +36,7 @@ def main():
                         "in-graph where XLA picks the wire type)")
     args = p.parse_args()
 
+    enable_compile_cache()
     hvd.init()
     devices = jax.devices()
     n_dev = len(devices)
@@ -76,7 +78,7 @@ def main():
         for _ in range(n):
             params, batch_stats, opt_state, loss = step(
                 params, batch_stats, opt_state, images, labels)
-        float(loss)  # hard sync (block_until_ready is unreliable here)
+        jax.block_until_ready(loss)
 
     run_batches(args.num_warmup_batches)
     img_secs = []

@@ -12,10 +12,10 @@ version tax; everyone else imports semantics.
 
 Policy:
 
-* wrappers are **version-gated on ``jax.__version__``** (parsed once
-  per call through :func:`jax_version` so tests can mock a future
-  release), with a feature probe as the safety net where the gate's
-  edge is known to have shipped off-cycle;
+* a wrapper that must differ between supported releases is gated on
+  :func:`jax_version` (parsed per call so tests can mock a release).
+  None is today: the tree runs on one installation (jax 0.9), so
+  ``shard_map`` and ``axis_size`` call the current spelling directly;
 * the supported floor is pinned in :data:`SUPPORTED_JAX_FLOOR` (also
   pinned in pyproject + README); the analyzer's API table flags any
   symbol that does not exist across the whole supported span;
@@ -31,12 +31,9 @@ from typing import Dict, Optional, Sequence
 
 # The oldest JAX this tree supports (pinned in pyproject.toml and
 # README; tools/hvdlint/jax_compat.py imports it for its API table).
+# shard_map/axis_size below need jax >= 0.5; raising this literal, the
+# analyzer's twin and the pins together is ROADMAP D9.
 SUPPORTED_JAX_FLOOR = (0, 4, 37)
-
-# jax >= this hoists shard_map to the top level (``jax.shard_map``,
-# replication checker spelled ``check_vma``); older releases keep it
-# in jax.experimental.shard_map with ``check_rep``.
-_TOP_LEVEL_SHARD_MAP = (0, 5, 0)
 
 
 def _parse_version(v: str) -> tuple:
@@ -167,37 +164,19 @@ def with_sharding_constraint(x, mesh, spec):
 # ---------------------------------------------------------------------------
 
 def shard_map(body, mesh, in_specs, out_specs, check: bool = False):
-    """Version-portable shard_map. ``check=False`` (the project
-    default) disables the static replication checker — collectives
-    guarantee their own output sharding, which the checker cannot see.
-
-    jax >= 0.5 hoists shard_map to the top level with ``check_vma``;
-    the 0.4.x line keeps it in jax.experimental.shard_map with
-    ``check_rep``. Gated on :func:`jax_version` with a feature probe
-    as the net (0.4.35 briefly aliased the top-level name behind a
-    deprecation gate that *raises* — the probe must tolerate that).
-    """
+    """``jax.shard_map``. ``check=False`` (the project default)
+    disables the static replication checker (``check_vma``) —
+    collectives guarantee their own output sharding, which the
+    checker cannot see."""
     import jax
-    if jax_version() >= _TOP_LEVEL_SHARD_MAP:
-        fn = getattr(jax, "shard_map", None)
-        if fn is not None:
-            return fn(body, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_vma=check)
-    from jax.experimental.shard_map import shard_map as fn
-    return fn(body, mesh=mesh, in_specs=in_specs,
-              out_specs=out_specs, check_rep=check)
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)
 
 
 def axis_size(axis) -> int:
-    """Static size of a named mesh axis, inside shard_map/pmap.
-    ``jax.lax.axis_size`` only exists above the supported floor; the
-    0.4.x spelling is the classic ``psum(1, axis)``, which jax
-    constant-folds to the axis size at trace time."""
+    """Static size of a named mesh axis, inside shard_map/pmap."""
     import jax
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis)
-    return jax.lax.psum(1, axis)
+    return jax.lax.axis_size(axis)
 
 
 def psum_scatter(x, axis, scatter_dimension: int = 0, tiled: bool = True):

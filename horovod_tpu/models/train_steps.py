@@ -1,0 +1,156 @@
+"""The two synthetic training programs, built once.
+
+``bench.py`` measures them and ``chip_smoke.py`` proves they start on
+the chip; both import the constructions here so they run the same
+program: ``horovod_tpu.jax.DistributedOptimizer`` around optax
+SGD-momentum inside a ``shard_map``'d step over the mesh's ``data``
+axis (a size-1 mesh included, so the axis is always in scope for the
+gradient pmean and the cross-replica batch norm), parameters placed
+replicated and broadcast through the runtime at start the way a user's
+script does, and donated buffers so XLA updates the weights in place.
+"""
+
+from __future__ import annotations
+
+from typing import Callable, Optional
+
+import jax
+import jax.numpy as jnp
+import optax
+
+import horovod_tpu.jax as hvd
+from horovod_tpu import spmd
+from horovod_tpu.compat import jaxshim
+from horovod_tpu.models.resnet import ResNet50
+from horovod_tpu.models.transformer import (
+    TransformerConfig, TransformerLM, lm_loss_from_hidden,
+)
+
+AXIS = "data"
+RESNET_CLASSES = 1000
+
+
+def distributed_sgd():
+    """The optimizer of both programs: optax SGD-momentum wrapped so
+    ``update`` first pmeans the gradients over the ``data`` axis."""
+    return hvd.DistributedOptimizer(
+        optax.sgd(0.01, momentum=0.9), axis=AXIS)
+
+
+def bench_lm(seq: int = 2048, num_layers: int = 12,
+             attention_fn: Optional[Callable] = None) -> TransformerLM:
+    """The transformer cell's model: d=2048 as 16 heads of 128,
+    V=32000, bf16 (735.1M parameters at the published 12 layers).
+    Only depth and sequence length may be cut."""
+    return TransformerLM(TransformerConfig(
+        vocab_size=32000, num_layers=num_layers, num_heads=16,
+        head_dim=128, max_seq_len=seq, dtype=jnp.bfloat16,
+        attention_fn=attention_fn))
+
+
+def bench_resnet() -> ResNet50:
+    """The ResNet-50 cell's model: bf16, batch norm statistics synced
+    over the ``data`` axis."""
+    return ResNet50(num_classes=RESNET_CLASSES, dtype=jnp.bfloat16,
+                    axis_name=AXIS)
+
+
+def lm_loss_fn(model: TransformerLM):
+    """``(params, tokens) -> loss``: chunked next-token cross-entropy
+    that never materializes the [B, S, V] logits."""
+    def loss_fn(p, t):
+        hidden = model.apply({"params": p}, t, return_hidden=True)
+        return lm_loss_from_hidden(hidden, p["lm_head"]["kernel"], t)
+    return loss_fn
+
+
+def lm_train_step(model: TransformerLM, tx, mesh):
+    """``jit(shard_map(step))`` with ``(params, opt_state)`` donated:
+    ``(params, opt_state, tokens) -> (params, opt_state, loss)``. The
+    loss is the mean over the mesh, not one shard's."""
+    loss_fn = lm_loss_fn(model)
+
+    def step(p, os_, t):
+        loss, grads = jax.value_and_grad(loss_fn)(p, t)
+        updates, new_os = tx.update(grads, os_, p)
+        return (optax.apply_updates(p, updates), new_os,
+                jax.lax.pmean(loss, AXIS))
+
+    rep = jaxshim.partition_spec()
+    step = jaxshim.shard_map(
+        step, mesh=mesh,
+        in_specs=(rep, rep, jaxshim.partition_spec(AXIS)),
+        out_specs=(rep, rep, rep))
+    return jax.jit(step, donate_argnums=(0, 1))
+
+
+def resnet_train_step(model: ResNet50, tx, mesh):
+    """``jit(shard_map(step))`` with ``(params, batch_stats,
+    opt_state)`` donated: ``(params, batch_stats, opt_state, images,
+    labels) -> (params, batch_stats, opt_state, loss)``."""
+    def loss_fn(p, bs, x, y):
+        logits, updates = model.apply(
+            {"params": p, "batch_stats": bs}, x, train=True,
+            mutable=["batch_stats"])
+        one_hot = jax.nn.one_hot(y, RESNET_CLASSES)
+        loss = -jnp.mean(jnp.sum(
+            jax.nn.log_softmax(logits) * one_hot, axis=-1))
+        return loss, updates["batch_stats"]
+
+    def step(p, bs, os_, x, y):
+        (loss, new_bs), grads = jax.value_and_grad(
+            loss_fn, has_aux=True)(p, bs, x, y)
+        updates, new_os = tx.update(grads, os_, p)
+        return (optax.apply_updates(p, updates), new_bs, new_os,
+                jax.lax.pmean(loss, AXIS))
+
+    rep = jaxshim.partition_spec()
+    batch = jaxshim.partition_spec(AXIS)
+    step = jaxshim.shard_map(
+        step, mesh=mesh, in_specs=(rep, rep, rep, batch, batch),
+        out_specs=(rep, rep, rep, rep))
+    return jax.jit(step, donate_argnums=(0, 1, 2))
+
+
+def synthetic_tokens(seed: int, batch: int, seq: int, vocab: int, mesh):
+    """A fixed random token batch, dim 0 split over the mesh."""
+    tokens = jax.random.randint(
+        jax.random.key(seed), (batch, seq), 0, vocab, jnp.int32)
+    return jax.device_put(tokens, spmd.batch_sharding(mesh))
+
+
+def synthetic_images(seed: int, batch: int, mesh, image_size: int = 224):
+    """ImageNet-shaped random images and labels, split over the mesh."""
+    k_img, k_lab = jax.random.split(jax.random.key(seed))
+    images = jax.random.normal(
+        k_img, (batch, image_size, image_size, 3), jnp.bfloat16)
+    labels = jax.random.randint(
+        k_lab, (batch,), 0, RESNET_CLASSES, jnp.int32)
+    sharding = spmd.batch_sharding(mesh)
+    return (jax.device_put(images, sharding),
+            jax.device_put(labels, sharding))
+
+
+def _init_replicated(init_fn, mesh, seed, batch):
+    return jax.jit(init_fn,
+                   out_shardings=spmd.replicated_sharding(mesh))(
+        jax.random.key(seed), batch)
+
+
+def lm_train_state(model: TransformerLM, tx, mesh, tokens, seed: int = 0):
+    """``(params, opt_state)`` replicated over the mesh, the parameters
+    broadcast through the runtime as a user's start-up does."""
+    variables = _init_replicated(model.init, mesh, seed, tokens)
+    params = variables["params"]
+    opt_state = tx.init(params)
+    return hvd.broadcast_parameters(params, root_rank=0), opt_state
+
+
+def resnet_train_state(model: ResNet50, tx, mesh, images, seed: int = 0):
+    """``(params, batch_stats, opt_state)`` replicated over the mesh."""
+    variables = _init_replicated(
+        lambda r, x: model.init(r, x, train=True), mesh, seed, images)
+    params = variables["params"]
+    opt_state = tx.init(params)
+    return (hvd.broadcast_parameters(params, root_rank=0),
+            variables["batch_stats"], opt_state)

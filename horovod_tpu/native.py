@@ -27,7 +27,8 @@ _tried = False
 _NATIVE_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "native")
 _SO_PATH = os.path.join(_NATIVE_DIR, "libhvdtpu.so")
-_SRC_PATH = os.path.join(_NATIVE_DIR, "hvdtpu.cc")
+_STAMP_PATH = _SO_PATH + ".srcsha256"
+_SOURCES = ("hvdtpu.cc", "hvdtpu.h", "hvdtpu.lds", "Makefile")
 
 # Idle-slice callback type for hvd_steady_coord (the coordinator's
 # PING fan-out re-enters Python once per idle poll slice). Module
@@ -58,16 +59,30 @@ def disabled_via_env() -> bool:
             in ("0", "false"))
 
 
+def _source_digest() -> str:
+    """sha256 over everything the Makefile compiles the library from."""
+    import hashlib
+    h = hashlib.sha256()
+    for name in _SOURCES:
+        with open(os.path.join(_NATIVE_DIR, name), "rb") as f:
+            h.update(f.read())
+    return h.hexdigest()
+
+
 def _so_fresh() -> bool:
-    """The built library exists and is no older than its source."""
-    if not os.path.exists(_SO_PATH):
+    """The built library exists and was built from the sources on disk.
+    Decided by the source digest the build stamped beside it, never by
+    mtimes: a copied or freshly checked-out tree carries mtimes that
+    say nothing about which source a found library came from."""
+    try:
+        with open(_STAMP_PATH) as f:
+            stamp = f.read().strip()
+        return os.path.exists(_SO_PATH) and stamp == _source_digest()
+    except OSError:
         return False
-    return not (os.path.exists(_SRC_PATH)
-                and os.path.getmtime(_SRC_PATH)
-                > os.path.getmtime(_SO_PATH))
 
 
-def _build() -> bool:
+def _build(force: bool = False) -> bool:
     if not os.path.isdir(_NATIVE_DIR):
         return False
     # Multiple local ranks may race the first build. Serialize with an
@@ -81,17 +96,29 @@ def _build() -> bool:
         import fcntl
         with open(lock_path, "w") as lock:
             fcntl.flock(lock, fcntl.LOCK_EX)
-            if _so_fresh():
+            if force:
+                # A failed forced build must not leave the old library
+                # looking fresh.
+                if os.path.exists(_STAMP_PATH):
+                    os.remove(_STAMP_PATH)
+            elif _so_fresh():
                 return True
+            digest = _source_digest()
             tmp_target = f"libhvdtpu.build{os.getpid()}.so"
             subprocess.run(
                 ["make", "-C", _NATIVE_DIR, "-s",
                  f"TARGET={tmp_target}"],
                 check=True, capture_output=True, timeout=120)
             os.replace(os.path.join(_NATIVE_DIR, tmp_target), _SO_PATH)
+            with open(_STAMP_PATH, "w") as f:
+                f.write(digest + "\n")
             return True
     except (subprocess.SubprocessError, FileNotFoundError, OSError) as e:
-        hlog.debug(f"native build failed: {e}")
+        # Loud only where a compiler exists: without one the Python
+        # paths are the supported configuration.
+        log = hlog.warning if compiler_available() else hlog.debug
+        stderr = getattr(e, "stderr", None) or b""
+        log(f"native build failed: {e}\n{stderr.decode(errors='replace')}")
         return False
 
 
@@ -124,16 +151,10 @@ def _configure(lib: ctypes.CDLL) -> None:
     lib.hvd_sum_into.restype = ctypes.c_int
     lib.hvd_sum_into.argtypes = [
         ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64, ctypes.c_int]
-    try:
-        # Stale-.so tolerance (see get()): a pre-compression library
-        # lacks the cast symbol; cast_into then reports unavailable
-        # and callers use the numpy fallback.
-        lib.hvd_cast.restype = ctypes.c_int
-        lib.hvd_cast.argtypes = [
-            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64,
-            ctypes.c_int, ctypes.c_int]
-    except AttributeError:
-        pass
+    lib.hvd_cast.restype = ctypes.c_int
+    lib.hvd_cast.argtypes = [
+        ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64,
+        ctypes.c_int, ctypes.c_int]
     lib.hvd_hmac_sha256.restype = None
     lib.hvd_hmac_sha256.argtypes = [
         u8p, ctypes.c_int, ctypes.c_uint8, u8p, ctypes.c_int64, u8p]
@@ -163,25 +184,19 @@ def _configure(lib: ctypes.CDLL) -> None:
         u8p, ctypes.c_int,
         ctypes.c_int, ctypes.c_int,
         u8pp, i64p, u8p]
-    try:
-        # Stale-.so tolerance (see get()): a pre-overlap library lacks
-        # the chunked entry; SteadyPlan.chunked then stays False and
-        # the classic one-shot worker carries the cycle.
-        lib.hvd_steady_worker_chunked.restype = ctypes.c_int
-        lib.hvd_steady_worker_chunked.argtypes = [
-            ctypes.c_int, ctypes.c_uint8, ctypes.c_uint8,
-            u8p, ctypes.c_int64,
-            u8pp, i64p,
-            vpp, vpp,
-            ctypes.POINTER(ctypes.c_int), ctypes.c_int64,
-            vpp,
-            i64p, ctypes.POINTER(ctypes.c_int), ctypes.c_int,
-            u8p, ctypes.c_int,
-            u8p, ctypes.c_int,
-            ctypes.c_int, ctypes.c_int,
-            u8pp, i64p, u8p]
-    except AttributeError:
-        pass
+    lib.hvd_steady_worker_chunked.restype = ctypes.c_int
+    lib.hvd_steady_worker_chunked.argtypes = [
+        ctypes.c_int, ctypes.c_uint8, ctypes.c_uint8,
+        u8p, ctypes.c_int64,
+        u8pp, i64p,
+        vpp, vpp,
+        ctypes.POINTER(ctypes.c_int), ctypes.c_int64,
+        vpp,
+        i64p, ctypes.POINTER(ctypes.c_int), ctypes.c_int,
+        u8p, ctypes.c_int,
+        u8p, ctypes.c_int,
+        ctypes.c_int, ctypes.c_int,
+        u8pp, i64p, u8p]
     lib.hvd_steady_coord.restype = ctypes.c_int
     lib.hvd_steady_coord.argtypes = [
         ctypes.POINTER(ctypes.c_int), ctypes.c_int,
@@ -196,48 +211,41 @@ def _configure(lib: ctypes.CDLL) -> None:
         ON_IDLE_FUNC,
         u8p, ctypes.POINTER(ctypes.c_double),
         ctypes.POINTER(ctypes.c_int), u8pp, i64p, u8p]
-    try:
-        # Stale-.so tolerance (see get()): a pre-reactor library lacks
-        # the batched/zerocopy/relay/codec entries; the wrappers below
-        # and the controller fast paths then report unavailable and the
-        # callers run the sequential/classic/numpy code, wire-identical.
-        lib.hvd_gather_frames_batched.restype = ctypes.c_int
-        lib.hvd_gather_frames_batched.argtypes = [
-            ctypes.POINTER(ctypes.c_int), ctypes.c_int,
-            u8p, ctypes.c_int,
-            ctypes.c_uint8, vpp,
-            i64p, i64p,
-            u8p, ctypes.c_int,
-            ctypes.c_int, ctypes.c_int,
-            ON_IDLE_FUNC,
-            u8p, ctypes.POINTER(ctypes.c_double),
-            ctypes.POINTER(ctypes.c_int32), ctypes.POINTER(ctypes.c_int),
-            ctypes.POINTER(ctypes.c_int), u8pp, i64p, u8p]
-        lib.hvd_sendv_zc.restype = ctypes.c_int
-        lib.hvd_sendv_zc.argtypes = [
-            ctypes.c_int, ctypes.c_uint8, vpp, i64p, ctypes.c_int,
-            u8p, ctypes.c_int,
-            ctypes.c_int,
-            ctypes.POINTER(ctypes.c_int), ctypes.POINTER(ctypes.c_int)]
-        lib.hvd_relay_frame.restype = ctypes.c_int
-        lib.hvd_relay_frame.argtypes = [
-            ctypes.c_int, ctypes.POINTER(ctypes.c_int), ctypes.c_int,
-            ctypes.c_uint8, ctypes.c_void_p, ctypes.c_int64,
-            u8p, ctypes.c_int,
-            u8p, ctypes.c_int,
-            ctypes.c_int64, ctypes.c_int, ctypes.c_int,
-            i64p, u8p, u8pp]
-        lib.hvd_build_flags.restype = ctypes.c_int
-        lib.hvd_build_flags.argtypes = []
-        lib.hvd_quant8.restype = ctypes.c_int
-        lib.hvd_quant8.argtypes = [
-            ctypes.c_void_p, ctypes.c_int64, ctypes.c_int,
-            ctypes.c_void_p, ctypes.c_void_p, u8p]
-        lib.hvd_dequant8.restype = ctypes.c_int
-        lib.hvd_dequant8.argtypes = [
-            u8p, ctypes.c_int64, ctypes.c_int, ctypes.c_void_p]
-    except AttributeError:
-        pass
+    lib.hvd_gather_frames_batched.restype = ctypes.c_int
+    lib.hvd_gather_frames_batched.argtypes = [
+        ctypes.POINTER(ctypes.c_int), ctypes.c_int,
+        u8p, ctypes.c_int,
+        ctypes.c_uint8, vpp,
+        i64p, i64p,
+        u8p, ctypes.c_int,
+        ctypes.c_int, ctypes.c_int,
+        ON_IDLE_FUNC,
+        u8p, ctypes.POINTER(ctypes.c_double),
+        ctypes.POINTER(ctypes.c_int32), ctypes.POINTER(ctypes.c_int),
+        ctypes.POINTER(ctypes.c_int), u8pp, i64p, u8p]
+    lib.hvd_sendv_zc.restype = ctypes.c_int
+    lib.hvd_sendv_zc.argtypes = [
+        ctypes.c_int, ctypes.c_uint8, vpp, i64p, ctypes.c_int,
+        u8p, ctypes.c_int,
+        ctypes.c_int,
+        ctypes.POINTER(ctypes.c_int), ctypes.POINTER(ctypes.c_int)]
+    lib.hvd_relay_frame.restype = ctypes.c_int
+    lib.hvd_relay_frame.argtypes = [
+        ctypes.c_int, ctypes.POINTER(ctypes.c_int), ctypes.c_int,
+        ctypes.c_uint8, ctypes.c_void_p, ctypes.c_int64,
+        u8p, ctypes.c_int,
+        u8p, ctypes.c_int,
+        ctypes.c_int64, ctypes.c_int, ctypes.c_int,
+        i64p, u8p, u8pp]
+    lib.hvd_build_flags.restype = ctypes.c_int
+    lib.hvd_build_flags.argtypes = []
+    lib.hvd_quant8.restype = ctypes.c_int
+    lib.hvd_quant8.argtypes = [
+        ctypes.c_void_p, ctypes.c_int64, ctypes.c_int,
+        ctypes.c_void_p, ctypes.c_void_p, u8p]
+    lib.hvd_dequant8.restype = ctypes.c_int
+    lib.hvd_dequant8.argtypes = [
+        u8p, ctypes.c_int64, ctypes.c_int, ctypes.c_void_p]
 
 
 def get() -> Optional[ctypes.CDLL]:
@@ -252,14 +260,10 @@ def get() -> Optional[ctypes.CDLL]:
         if disabled_via_env():
             return None
         if not _so_fresh() and not _build():
-            if not os.path.exists(_SO_PATH):
-                hlog.debug("native core unavailable; using Python paths")
-                return None
-            # rebuild of a stale .so failed: keep using the old one —
-            # dtype-ABI extensions degrade gracefully (sum_into returns
-            # False for codes the old library rejects)
-            hlog.warning("native core rebuild failed; using stale "
-                         "library")
+            # A library that was not built from the sources on disk is
+            # never loaded: its ABI is unknown.
+            hlog.debug("native core unavailable; using Python paths")
+            return None
         try:
             lib = ctypes.CDLL(_SO_PATH)
             _configure(lib)
@@ -353,6 +357,18 @@ def compiler_available() -> bool:
     return any(shutil.which(c) for c in ("g++", "c++", "clang++"))
 
 
+def rebuild() -> None:
+    """Build the library from the sources on disk whatever is already
+    there, and forget any library loaded so far. For entry points that
+    must not trust a library they find (chip_smoke.py); call before
+    the runtime starts."""
+    global _lib, _tried
+    with _lock:
+        _lib, _tried = None, False
+        if not disabled_via_env():
+            _build(force=True)
+
+
 def build_status():
     """(loaded, reason) for CI plumbing: attempt the normal get() path
     and explain a None result. Used by tests/conftest.py to build the
@@ -373,10 +389,9 @@ def cast_into(src, dst) -> bool:
     """dst[:] = src with a dtype cast via the native kernel (the
     wire-compression leg: f32<->bf16/f16). Returns False when the
     native path cannot serve this pair (caller falls back to numpy
-    casting). An older .so without the symbol degrades the same way —
-    the stale-library contract of get()."""
+    casting)."""
     lib = get()
-    if lib is None or not hasattr(lib, "hvd_cast"):
+    if lib is None:
         return False
     sc = _DTYPE_CODES.get(str(src.dtype))
     dc = _DTYPE_CODES.get(str(dst.dtype))
@@ -422,7 +437,7 @@ def quant8(src, out, residual=None, residual_out=None) -> bool:
     the post-quantization error. Returns False when the native path
     cannot serve this call (caller falls back to numpy)."""
     lib = get()
-    if lib is None or not hasattr(lib, "hvd_quant8"):
+    if lib is None:
         return False
     code = _QUANT_CODES.get(str(src.dtype))
     if code is None or not src.flags["C_CONTIGUOUS"] \
@@ -456,7 +471,7 @@ def dequant8(raw, out) -> bool:
     astype/multiply round-trip collapsed into one pass, bit-identical.
     Returns False when the native path cannot serve this call."""
     lib = get()
-    if lib is None or not hasattr(lib, "hvd_dequant8"):
+    if lib is None:
         return False
     code = _QUANT_CODES.get(str(out.dtype))
     if code is None or not raw.flags["C_CONTIGUOUS"] \
@@ -472,9 +487,8 @@ def dequant8(raw, out) -> bool:
 def build_flags() -> int:
     """Capability bitmask of the loaded core (hvd_build_flags): bit 0
     io_uring compiled in, bit 1 the running kernel accepts it, bit 2
-    MSG_ZEROCOPY sends compiled in. 0 when the native core (or a stale
-    pre-reactor .so) does not export the symbol."""
+    MSG_ZEROCOPY sends compiled in. 0 without the native core."""
     lib = get()
-    if lib is None or not hasattr(lib, "hvd_build_flags"):
+    if lib is None:
         return 0
     return int(lib.hvd_build_flags())

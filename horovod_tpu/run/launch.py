@@ -21,6 +21,7 @@ import time
 from typing import Dict, List, Optional, Tuple
 
 from horovod_tpu.common import config as hconfig
+from horovod_tpu.run.chips import ChipShortage, chip_env
 from horovod_tpu.run.services import DriverService, local_addresses
 
 
@@ -221,6 +222,7 @@ def run_local(np_: int, command: List[str],
         penv = dict(os.environ)
         if env:
             penv.update(env)
+        penv.update(chip_env(rank, np_, penv))
         penv["HOROVOD_RANK"] = str(rank)
         penv["HOROVOD_SIZE"] = str(np_)
         penv["HOROVOD_CONTROLLER_ADDR"] = "127.0.0.1"
@@ -332,6 +334,7 @@ def run_local_elastic(np_: int, command: List[str],
         penv = dict(os.environ)
         if env:
             penv.update(env)
+        penv.update(chip_env(slot, max_np, penv))
         penv["HOROVOD_ELASTIC"] = "1"
         penv["HOROVOD_ELASTIC_MIN_WORLD"] = str(min_np)
         penv["HOROVOD_TPU_ELASTIC_PORT"] = str(elastic_ports[slot])
@@ -659,15 +662,19 @@ def main(argv: Optional[List[str]] = None) -> None:
             total = sum(s for _, s in parse_hosts(args.hosts))
             if total != args.num_proc:
                 parser.error(f"-np {args.num_proc} != total slots {total}")
-        if args.elastic:
-            sys.exit(run_local_elastic(
-                args.num_proc, command, env=metrics_env,
-                start_timeout=start_timeout,
-                min_np=args.min_np or 1,
-                max_np=args.max_np,
-                restarts=args.restarts))
-        sys.exit(run_local(args.num_proc, command, env=metrics_env,
-                           start_timeout=start_timeout))
+        try:
+            if args.elastic:
+                sys.exit(run_local_elastic(
+                    args.num_proc, command, env=metrics_env,
+                    start_timeout=start_timeout,
+                    min_np=args.min_np or 1,
+                    max_np=args.max_np,
+                    restarts=args.restarts))
+            sys.exit(run_local(args.num_proc, command, env=metrics_env,
+                               start_timeout=start_timeout))
+        except ChipShortage as e:
+            print(f"hvdtpurun: {e}", file=sys.stderr)
+            sys.exit(1)
 
     if args.elastic:
         parser.error("--elastic currently drives the local launch "

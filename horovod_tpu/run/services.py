@@ -28,6 +28,7 @@ from horovod_tpu.common import config as hconfig
 from horovod_tpu.common import lockdep
 from horovod_tpu.common import logging as hlog
 from horovod_tpu.common import network
+from horovod_tpu.run.chips import chip_env
 
 TAG_MSG = 7
 
@@ -279,9 +280,11 @@ class TaskServer:
         assignment = msg["assignment"]
         controller = msg["controller"]
         procs = []
-        for rank in assignment["ranks"]:
+        ranks = assignment["ranks"]
+        for local_rank, rank in enumerate(ranks):
             env = dict(os.environ)
             env.update(msg.get("env", {}))
+            env.update(chip_env(local_rank, len(ranks), env))
             env["HOROVOD_RANK"] = str(rank)
             env["HOROVOD_SIZE"] = str(assignment["size"])
             env["HOROVOD_CONTROLLER_ADDR"] = controller["addr"]

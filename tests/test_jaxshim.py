@@ -1,11 +1,9 @@
 """compat/jaxshim — the one sanctioned JAX version boundary.
 
-The wrappers re-read ``jax.__version__`` per call (never cached at
-import) precisely so these tests can mock a FUTURE release and prove
-the gate flips to the new spelling before that release exists: the
-whole point of the shim is that the next jax migration is a
-one-module diff, and that claim is only testable against versions we
-don't have installed.
+``jax_version`` re-reads ``jax.__version__`` per call (never cached at
+import) so a wrapper that has to differ between releases can be
+tested against a mocked one. None does today: ``shard_map`` and
+``axis_size`` call the installed release's spelling directly.
 """
 
 import numpy as np
@@ -39,12 +37,11 @@ def test_jax_version_reads_live_not_cached(monkeypatch):
     assert jaxshim.jax_version() == (0, 4, 37)
 
 
-# -- the shard_map version gate --------------------------------------------
+# -- shard_map ---------------------------------------------------------------
 
-def test_shard_map_future_jax_takes_top_level_check_vma(monkeypatch):
-    """On a mocked future release the gate must call the top-level
-    ``jax.shard_map`` with the ``check_vma`` spelling — without that
-    release being installed."""
+def test_shard_map_takes_top_level_check_vma(monkeypatch):
+    """The shim calls the top-level ``jax.shard_map`` with the
+    ``check_vma`` spelling and the checker off."""
     seen = {}
 
     def fake_shard_map(body, mesh=None, in_specs=None, out_specs=None,
@@ -53,9 +50,7 @@ def test_shard_map_future_jax_takes_top_level_check_vma(monkeypatch):
                     out_specs=out_specs, body=body)
         return "future-mapped"
 
-    monkeypatch.setattr(jax, "__version__", "0.9.0")
-    monkeypatch.setattr(jax, "shard_map", fake_shard_map,
-                        raising=False)
+    monkeypatch.setattr(jax, "shard_map", fake_shard_map)
     out = jaxshim.shard_map(lambda x: x, mesh="M", in_specs="I",
                             out_specs="O")
     assert out == "future-mapped"
@@ -64,46 +59,9 @@ def test_shard_map_future_jax_takes_top_level_check_vma(monkeypatch):
     assert seen["check_vma"] is False and "check_rep" not in seen
 
 
-def test_shard_map_floor_jax_takes_experimental_check_rep(monkeypatch):
-    """At the supported floor the gate must stay on
-    ``jax.experimental.shard_map`` with ``check_rep``."""
-    from jax.experimental import shard_map as esm
-    seen = {}
-
-    def fake(body, mesh=None, in_specs=None, out_specs=None, **kw):
-        seen.update(kw)
-        return "floor-mapped"
-
-    monkeypatch.setattr(jax, "__version__", "0.4.37")
-    monkeypatch.setattr(esm, "shard_map", fake)
-    assert jaxshim.shard_map(lambda x: x, mesh="M", in_specs="I",
-                             out_specs="O") == "floor-mapped"
-    assert seen["check_rep"] is False and "check_vma" not in seen
-
-
-def test_shard_map_future_without_top_level_falls_back(monkeypatch):
-    """The feature probe is the net under the version gate: a release
-    that *claims* >= 0.5 but ships no top-level shard_map (the 0.4.35
-    deprecation-alias incident) must still resolve the experimental
-    spelling instead of raising."""
-    from jax.experimental import shard_map as esm
-    seen = {}
-
-    def fake(body, mesh=None, in_specs=None, out_specs=None, **kw):
-        seen.update(kw)
-        return "probed-fallback"
-
-    monkeypatch.setattr(jax, "__version__", "0.9.0")
-    monkeypatch.delattr(jax, "shard_map", raising=False)
-    monkeypatch.setattr(esm, "shard_map", fake)
-    assert jaxshim.shard_map(lambda x: x, mesh="M", in_specs="I",
-                             out_specs="O") == "probed-fallback"
-    assert seen["check_rep"] is False
-
-
 def test_shard_map_executes_on_running_jax():
-    """Whatever spelling the gate picked for the INSTALLED jax must
-    actually trace: one psum over a real mesh (conftest forces an
+    """The spelling the shim calls must actually trace on the
+    INSTALLED jax: one psum over a real mesh (conftest forces an
     8-device host platform)."""
     mesh = jaxshim.make_mesh()
     n = mesh.devices.size
@@ -119,24 +77,11 @@ def test_shard_map_executes_on_running_jax():
         np.asarray(y), np.full(n, np.arange(n).sum(), np.float32))
 
 
-# -- axis_size gate ---------------------------------------------------------
+# -- axis_size --------------------------------------------------------------
 
-def test_axis_size_prefers_native_spelling(monkeypatch):
-    monkeypatch.setattr(jax.lax, "axis_size", lambda a: 7,
-                        raising=False)
+def test_axis_size_calls_lax_axis_size(monkeypatch):
+    monkeypatch.setattr(jax.lax, "axis_size", lambda a: 7)
     assert jaxshim.axis_size("model") == 7
-
-
-def test_axis_size_floor_falls_back_to_psum(monkeypatch):
-    """Below 0.5 there is no jax.lax.axis_size: the shim must lower
-    to the psum(1, axis) constant-fold instead of AttributeError."""
-    seen = {}
-    monkeypatch.delattr(jax.lax, "axis_size", raising=False)
-    monkeypatch.setattr(
-        jax.lax, "psum",
-        lambda v, a: seen.setdefault("call", (v, a)) and 3 or 3)
-    assert jaxshim.axis_size("model") == 3
-    assert seen["call"] == (1, "model")
 
 
 # -- mesh construction ------------------------------------------------------
