@@ -1,0 +1,10 @@
+"""The compiler's memory_analysis of the window's largest executable: arguments + outputs - aliased + temporaries + code."""
+from chipbench import readers
+
+LAYER = "Device"
+UNIT = "GB"
+MOVES = "images_per_s_chip"
+
+
+def read(ctx):
+    return readers.hbm_need_gb(ctx)
