@@ -540,7 +540,7 @@ def worker_cache(rank: int, size: int) -> None:
         # simultaneous A/B pair's step k on each side shares the
         # same wall-clock throttle state, so index-paired ratios
         # cancel the common-mode noise that swamps sub-percent
-        # effects (--trace-overhead)
+        # effects
         "step_times_us": [round(t * 1e6, 1) for t in times],
         "cycles_per_step": round((c1 - c0) / CACHE_BENCH_STEPS, 2),
         "cache_enabled": bool(s1.get("enabled")),
@@ -561,119 +561,6 @@ def worker_cache(rank: int, size: int) -> None:
         report["native_steady_cycles"] = (
             s1.get("native_steady_cycles", 0)
             - s0.get("native_steady_cycles", 0))
-    if rank == 0:
-        print("RESULT " + json.dumps(report), flush=True)
-    hvd.shutdown()
-
-
-TRACE_TOGGLE_BLOCKS = 16   # ABBA-ordered on/off block pairs
-TRACE_TOGGLE_BLOCK_STEPS = 24
-
-
-def worker_trace_toggle(rank: int, size: int) -> None:
-    """Within-process A/B for the trace-overhead section: the same
-    steady bucket as worker_cache, but alternating short armed/dark
-    blocks INSIDE one world by re-pointing the runtime's recorder/
-    collector hooks between blocks. Adjacent blocks share the host's
-    throttle state at the ~100ms scale and everything else — the
-    processes, the negotiated world, the cache state — is literally
-    identical, so the paired block ratios resolve the sub-percent
-    costs that process-level A/B noise swamps on this box.
-    ``HVD_TRACE_TOGGLE`` picks what toggles: ``flight`` (the
-    default-on ring writes alone) or ``trace`` (flight + span
-    collection + TAG_TRACE shipping + rank 0's arrival stamps — the
-    whole plane)."""
-    import numpy as np
-    import horovod_tpu as hvd
-    from horovod_tpu.common import basics as _b
-    from horovod_tpu.common import trace as htrace
-
-    hvd.init()
-    which = os.environ.get("HVD_TRACE_TOGGLE", "flight")
-    pairs = int(os.environ.get("HVD_TOGGLE_BLOCKS",
-                               TRACE_TOGGLE_BLOCKS))
-    block_steps = int(os.environ.get("HVD_TOGGLE_STEPS",
-                                     TRACE_TOGGLE_BLOCK_STEPS))
-    n = (4 << 10) // 8
-    xs = [np.full(n, float(rank + 1) * (i + 1), np.float64)
-          for i in range(CACHE_BENCH_TENSORS)]
-    ssum = sum(range(1, size + 1))
-
-    def step():
-        hs = hvd.grouped_allreduce_async(xs, average=False, name="tt")
-        for h in hs:
-            hvd.synchronize(h)
-
-    for _ in range(8):
-        step()
-        time.sleep(CACHE_BENCH_GAP_S)
-    hvd.barrier(name="tt.bar")
-    rt = _b.runtime()
-    ctl = rt.controller
-    armed = (rt._flight, rt._trace, ctl._on_arrivals)
-
-    def _arm(on: bool) -> None:
-        # plain attribute stores: atomic under the GIL, read fresh by
-        # the background loop each round (runtime.py keeps the
-        # toggled paths NameError-safe by construction)
-        rt._flight = armed[0] if on else htrace.NOOP_RECORDER
-        if which == "trace":
-            rt._trace = armed[1] if on else htrace.NOOP_TRACE
-            rt._trace_on = on
-            ctl._on_arrivals = armed[2] if on else None
-
-    on_times, off_times = [], []
-    on_cycles = off_cycles = 0
-    for p in range(pairs):
-        # ABBA ordering: alternate which mode runs first within a
-        # pair, so a drift that consistently favors the second block
-        # of a pair cancels across pairs instead of biasing the
-        # median
-        order = (True, False) if p % 2 == 0 else (False, True)
-        for on in order:
-            _arm(on)
-            k0 = rt._cycle_count
-            t0 = time.perf_counter()
-            for _ in range(block_steps):
-                step()
-            dt = time.perf_counter() - t0
-            if on:
-                on_times.append(dt)
-                on_cycles += rt._cycle_count - k0
-            else:
-                off_times.append(dt)
-                off_cycles += rt._cycle_count - k0
-            time.sleep(CACHE_BENCH_GAP_S)
-    _arm(True)
-    out = hvd.grouped_allreduce(xs, average=False, name="tt.chk")
-    for i in range(CACHE_BENCH_TENSORS):
-        assert abs(float(np.asarray(out[i])[0])
-                   - ssum * (i + 1)) < 1e-6
-    pair_pcts = sorted(
-        (a / b - 1.0) * 100 for a, b in zip(on_times, off_times))
-    _, med_on, _ = _quantiles(on_times)
-    _, med_off, _ = _quantiles(off_times)
-    div = block_steps * CACHE_BENCH_TENSORS
-    # absolute enabled-path cost per negotiation round, the
-    # world-size-independent quantity the orchestrator scales into
-    # the target bucket's geometry (block MEDIANS absorb the burst
-    # blocks that poison per-pair ratios)
-    rounds_per_block = ((on_cycles + off_cycles)
-                        / max(1, len(on_times) + len(off_times)))
-    delta_us_per_round = ((med_on - med_off) * 1e6
-                          / max(1.0, rounds_per_block))
-    report = {
-        "toggled": which,
-        "blocks_per_mode": pairs,
-        "steps_per_block": block_steps,
-        "on_us_per_op": round(med_on * 1e6 / div, 2),
-        "off_us_per_op": round(med_off * 1e6 / div, 2),
-        "rounds_per_block": round(rounds_per_block, 1),
-        "delta_us_per_round": round(delta_us_per_round, 3),
-        "block_pair_overhead_pct": [round(p, 2) for p in pair_pcts],
-        "overhead_pct": round(
-            (med_on / med_off - 1.0) * 100, 2),
-    }
     if rank == 0:
         print("RESULT " + json.dumps(report), flush=True)
     hvd.shutdown()
@@ -1190,135 +1077,6 @@ def _metrics_bench_section(np_: int) -> dict:
             "pair_overhead_pct": [round((r - 1) * 100, 2)
                                   for r in ratios],
             "enabled_overhead_pct": round((med_ratio - 1) * 100, 2)}
-
-
-def _trace_bench_section(np_: int) -> dict:
-    """World-trace-plane overhead on the PR 3 steady bucket
-    (`--trace-overhead`, docs/tracing.md). Two quantities, each
-    measured two ways:
-
-    * FLIGHT: the default-on flight recorder alone (one ring write
-      per negotiation round). Acceptance: <= 1% — the price every
-      production job pays.
-    * TRACE: the whole plane armed — flight + span collection +
-      TAG_TRACE shipping + rank 0's arrival stamps and merged-file
-      writer. Acceptance: <= 5%. Its pair leg runs with metrics on,
-      so it also re-proves the zero-copy steady contract
-      (data_copies == 0): span batching never touches payload bytes.
-
-    Protocols: the simultaneous-pair A/B (same as --metrics-only,
-    recorded for cross-section comparability) — and, as the
-    HEADLINE the pass bools gate on, the within-process TOGGLE
-    (worker_trace_toggle): a ws=2 world alternates ~2s armed/dark
-    blocks by re-pointing the runtime's hooks, so both modes share
-    one process set and adjacent blocks share throttle state. The
-    toggle resolves the ABSOLUTE per-round cost (a quantity process-
-    level A/B cannot see under this host's noise floor — the same
-    caveat the zero-copy section documents for its pair protocol);
-    that cost is world-size independent, so the headline scales it
-    into the np_ bucket's measured rounds-per-step and step latency
-    from the pair baseline."""
-    import threading
-    base_env = {"HOROVOD_TPU_SHM": "0",
-                "HOROVOD_TPU_RING_THRESHOLD": "-1"}
-    off_env = dict(base_env, HOROVOD_TPU_FLIGHT="0")
-    flight_env = dict(base_env)  # flight recorder default-on
-    trace_env = dict(base_env, HOROVOD_TPU_METRICS="1",
-                     HOROVOD_TPU_METRICS_INTERVAL="1",
-                     HOROVOD_TPU_TRACE=os.path.join(
-                         tempfile.mkdtemp(prefix="hvdtrace_bench"),
-                         "world_trace.json"),
-                     HOROVOD_TPU_TRACE_INTERVAL="0.5")
-
-    def _pairs(on_env):
-        # The --metrics-only protocol, recorded for comparability;
-        # the pass bools gate on the toggle worlds below instead
-        # (two worlds timesharing this box's core cannot resolve
-        # sub-percent effects — observed pair spread is +/- several
-        # percent). Alongside the whole-run ratios, each pair also
-        # records the median of index-paired step ratios.
-        offs, ons, run_ratios, paired = [], [], [], []
-        for rep in range(3):
-            pair = {}
-
-            def _go(key, env):
-                pair[key] = _run_world("cache", np_, timeout=600.0,
-                                       extra_env=env)
-
-            ta = threading.Thread(target=_go, args=("off", off_env))
-            tb = threading.Thread(target=_go, args=("on", on_env))
-            ta.start()
-            tb.start()
-            ta.join()
-            tb.join()
-            offs.append(pair["off"])
-            ons.append(pair["on"])
-            run_ratios.append(pair["on"]["us_per_op"]
-                              / pair["off"]["us_per_op"])
-            rs = sorted(a / b for a, b in
-                        zip(pair["on"]["step_times_us"],
-                            pair["off"]["step_times_us"]))
-            paired.append(rs[len(rs) // 2])
-        offs.sort(key=lambda d: d["us_per_op"])
-        ons.sort(key=lambda d: d["us_per_op"])
-        run_ratios.sort()
-        paired.sort()
-        med_off = dict(offs[len(offs) // 2])
-        med_on = dict(ons[len(ons) // 2])
-        med_off.pop("step_times_us", None)  # keep RESULTS readable
-        med_on.pop("step_times_us", None)
-        return (med_off, med_on,
-                [round((r - 1) * 100, 2) for r in run_ratios],
-                round((paired[len(paired) // 2] - 1) * 100, 2))
-
-    f_off, f_on, f_pcts, f_paired = _pairs(flight_env)
-    t_off, t_on, t_pcts, t_paired = _pairs(trace_env)
-    # The precision instrument: within-process armed/dark toggling in
-    # a ws=2 world, whose low scheduling noise (2 processes, ~2s
-    # blocks) resolves the absolute per-round cost; that cost —
-    # world-size independent, it is the same ring write / span append
-    # everywhere — is then scaled into the np_ steady bucket's
-    # measured geometry (rounds per step, step latency) from the
-    # pair baseline above.
-    tgl_env = {"HVD_TOGGLE_BLOCKS": "8", "HVD_TOGGLE_STEPS": "800"}
-    tgl_flight = _run_world(
-        "trace_toggle", 2, timeout=600.0,
-        extra_env=dict(base_env, HVD_TRACE_TOGGLE="flight",
-                       **tgl_env))
-    tgl_trace = _run_world(
-        "trace_toggle", 2, timeout=600.0,
-        extra_env=dict(base_env, HVD_TRACE_TOGGLE="trace",
-                       HOROVOD_TPU_TRACE=os.path.join(
-                           tempfile.mkdtemp(prefix="hvdtrace_tgl"),
-                           "world_trace.json"),
-                       HOROVOD_TPU_TRACE_INTERVAL="0.25",
-                       **tgl_env))
-
-    def _scaled_pct(tgl, baseline):
-        return round(max(0.0, tgl["delta_us_per_round"])
-                     * baseline["cycles_per_step"]
-                     / baseline["us_per_step"] * 100, 3)
-
-    f_pct = _scaled_pct(tgl_flight, f_off)
-    t_pct = _scaled_pct(tgl_trace, t_off)
-    return {"world_size": np_,
-            "flight_overhead_pct": f_pct,
-            "flight_within_1pct": f_pct <= 1.0,
-            "trace_overhead_pct": t_pct,
-            "trace_within_5pct": t_pct <= 5.0,
-            "flight_toggle": tgl_flight,
-            "trace_toggle": tgl_trace,
-            "baseline": f_off,
-            "flight_on": f_on,
-            "flight_pair_overhead_pct": f_pcts,
-            "flight_paired_step_pct": f_paired,
-            "trace_baseline": t_off,
-            "trace_on": t_on,
-            "trace_pair_overhead_pct": t_pcts,
-            "trace_paired_step_pct": t_paired,
-            "trace_data_copies": t_on.get("data_copies"),
-            "zero_copies_with_trace":
-                t_on.get("data_copies") == 0}
 
 
 AUTOTUNE_VALUE_TENSORS = 24
@@ -2653,7 +2411,7 @@ def main() -> None:
                              "overhead", "autotune_value", "cache",
                              "elastic", "compression",
                              "compression_autotune", "overlap",
-                             "trace_toggle", "multitenant",
+                             "multitenant",
                              "kernel_gather", "kernel_relay",
                              "selfop_sync", "ici"])
     ap.add_argument("--rank", type=int)
@@ -2666,13 +2424,6 @@ def main() -> None:
     ap.add_argument("--metrics-only", action="store_true",
                     help="run just the metrics-plane overhead A/B and "
                          "merge it into the existing RESULTS_cpu.json")
-    ap.add_argument("--trace-overhead", action="store_true",
-                    help="run just the world-trace-plane overhead A/B "
-                         "(default-on flight recorder, then full "
-                         "tracing armed, each vs a dark baseline; "
-                         "simultaneous-pair protocol, same as "
-                         "--metrics-only) and merge it into the "
-                         "existing RESULTS_cpu.json")
     ap.add_argument("--steady-only", action="store_true",
                     help="run just the zero-copy steady-bucket A/B "
                          "(HOROVOD_TPU_ZERO_COPY on/off) and merge it "
@@ -2741,7 +2492,6 @@ def main() -> None:
          "compression": worker_compression,
          "compression_autotune": worker_compression_autotune,
          "overlap": worker_overlap,
-         "trace_toggle": worker_trace_toggle,
          "multitenant": worker_multitenant,
          "kernel_gather": worker_kernel_gather,
          "kernel_relay": worker_kernel_relay,
@@ -2943,30 +2693,6 @@ def main() -> None:
             json.dump(merged, fh, indent=2)
             fh.write("\n")
         print(f"merged zero_copy_steady into {results_path}")
-        return
-
-    if args.trace_overhead:
-        print(f"== world-trace-plane overhead A/B (np={np_}, steady "
-              f"bucket) ==", flush=True)
-        to = _trace_bench_section(np_)
-        print(f"  flight recorder (default-on) overhead "
-              f"{to['flight_overhead_pct']}% "
-              f"(<=1 pass={to['flight_within_1pct']})   full tracing "
-              f"armed {to['trace_overhead_pct']}% "
-              f"(<=5 pass={to['trace_within_5pct']})   data copies "
-              f"with trace={to['trace_data_copies']} "
-              f"(zero pass={to['zero_copies_with_trace']})",
-              flush=True)
-        try:
-            with open(results_path) as fh:
-                merged = json.load(fh)
-        except (OSError, ValueError):
-            merged = {}
-        merged["trace_overhead"] = to
-        with open(results_path, "w") as fh:
-            json.dump(merged, fh, indent=2)
-            fh.write("\n")
-        print(f"merged trace_overhead into {results_path}")
         return
 
     if args.metrics_only:

@@ -27,6 +27,7 @@ from typing import Optional
 from horovod_tpu.common import lockdep
 from horovod_tpu.common import logging as hlog
 from horovod_tpu.common import network
+from horovod_tpu.common import trace as htrace
 from horovod_tpu.common.config import Config
 from horovod_tpu.common.controller import (
     Controller, LocalController, TcpCoordinator, TcpWorker,
@@ -104,37 +105,46 @@ def _build_runtime(cfg: Config, coordinator_listener=None,
         from horovod_tpu.common import tenancy as _tenancy
         tenant_desc = _tenancy.descriptor_of(cfg)
 
-    if size == 1:
-        controller: Controller = LocalController()
-    elif rank == 0:
-        listener = coordinator_listener
-        if listener is None and cfg.controller_fd >= 0:
-            import socket as _socket
-            listener = _socket.socket(fileno=cfg.controller_fd)
-        coord = TcpCoordinator(size, port=cfg.controller_port,
-                               secret=secret,
-                               start_timeout=cfg.start_timeout,
-                               listener=listener,
-                               hierarchical=cfg.hier_controller,
-                               heartbeat_interval=cfg.heartbeat_interval_s,
-                               heartbeat_timeout=cfg.heartbeat_timeout_s,
-                               elastic_port=elastic_port,
-                               world_id=cfg.world_id,
-                               tenant_desc=tenant_desc)
-        coord.accept_workers()
-        controller = coord
-    else:
-        if not cfg.controller_addr or not cfg.controller_port:
-            raise ValueError(
-                "HOROVOD_CONTROLLER_ADDR/PORT must be set for "
-                "multi-process init (use the hvdtpurun launcher).")
-        controller = TcpWorker(rank, size, cfg.controller_addr,
-                               cfg.controller_port, secret=secret,
-                               start_timeout=cfg.start_timeout,
-                               heartbeat_interval=cfg.heartbeat_interval_s,
-                               heartbeat_timeout=cfg.heartbeat_timeout_s,
-                               elastic_port=elastic_port,
-                               world_id=cfg.world_id)
+    if size > 1 or cfg.metrics_enabled:
+        # The native core is built or loaded by whoever asks first: the
+        # TCP control plane, or the registry's build identity. Here,
+        # where start-up can see what it costs.
+        with htrace.span("hvd.init.native"):
+            from horovod_tpu import native
+            native.get()
+
+    with htrace.span("hvd.init.rendezvous", n=size):
+        if size == 1:
+            controller: Controller = LocalController()
+        elif rank == 0:
+            listener = coordinator_listener
+            if listener is None and cfg.controller_fd >= 0:
+                import socket as _socket
+                listener = _socket.socket(fileno=cfg.controller_fd)
+            coord = TcpCoordinator(size, port=cfg.controller_port,
+                                   secret=secret,
+                                   start_timeout=cfg.start_timeout,
+                                   listener=listener,
+                                   hierarchical=cfg.hier_controller,
+                                   heartbeat_interval=cfg.heartbeat_interval_s,
+                                   heartbeat_timeout=cfg.heartbeat_timeout_s,
+                                   elastic_port=elastic_port,
+                                   world_id=cfg.world_id,
+                                   tenant_desc=tenant_desc)
+            coord.accept_workers()
+            controller = coord
+        else:
+            if not cfg.controller_addr or not cfg.controller_port:
+                raise ValueError(
+                    "HOROVOD_CONTROLLER_ADDR/PORT must be set for "
+                    "multi-process init (use the hvdtpurun launcher).")
+            controller = TcpWorker(rank, size, cfg.controller_addr,
+                                   cfg.controller_port, secret=secret,
+                                   start_timeout=cfg.start_timeout,
+                                   heartbeat_interval=cfg.heartbeat_interval_s,
+                                   heartbeat_timeout=cfg.heartbeat_timeout_s,
+                                   elastic_port=elastic_port,
+                                   world_id=cfg.world_id)
     # Rank-local reactor opt-out (HOROVOD_TPU_REACTOR=0): the batched
     # recv discipline and the chunked-relay legs fall back to the
     # sequential/store-and-forward paths on THIS rank only.
@@ -152,25 +162,28 @@ def _build_runtime(cfg: Config, coordinator_listener=None,
             elastic_ctx.membership.generation, controller.rank,
             controller.size, table)
 
-    from horovod_tpu.ops.shm_ops import ShmBackend
-    socket_backend = SocketBackend(controller, secret=secret,
-                                   config=cfg)
-    backends = [
-        XlaMeshBackend(controller, config=cfg),
-        ShmBackend(controller, fallback=socket_backend, config=cfg,
-                   secret=secret),
-        socket_backend,
-        LocalBackend(lambda: controller.size),
-    ]
-    op_manager = OperationManager(backends)
+    with htrace.span("hvd.init.runtime"):
+        from horovod_tpu.ops.shm_ops import ShmBackend
+        socket_backend = SocketBackend(controller, secret=secret,
+                                       config=cfg)
+        backends = [
+            XlaMeshBackend(controller, config=cfg),
+            ShmBackend(controller, fallback=socket_backend, config=cfg,
+                       secret=secret),
+            socket_backend,
+            LocalBackend(lambda: controller.size),
+        ]
+        op_manager = OperationManager(backends)
 
-    parameter_manager = None
-    if cfg.autotune:
-        from horovod_tpu.common.parameter_manager import ParameterManager
-        parameter_manager = ParameterManager(cfg, controller)
+        parameter_manager = None
+        if cfg.autotune:
+            from horovod_tpu.common.parameter_manager import (
+                ParameterManager,
+            )
+            parameter_manager = ParameterManager(cfg, controller)
 
-    rt = Runtime(cfg, controller, op_manager, parameter_manager)
-    rt.start()
+        rt = Runtime(cfg, controller, op_manager, parameter_manager)
+        rt.start()
     return rt
 
 
@@ -194,99 +207,111 @@ def init(comm=None, config: Optional[Config] = None,
     init. Launcher-spawned rank 0 can instead inherit the reservation
     as a file descriptor via ``HOROVOD_CONTROLLER_FD``.
     """
-    global _runtime
     with _lock:
         if _runtime is not None and _runtime.alive:
             return  # already initialized (reference: InitializeHorovodOnce
                     # test-and-set, operations.cc:1342-1360)
         cfg = config or Config.from_env()
-        hlog.set_level(cfg.log_level)
-        # Publish the wire-compression latch (common/wire_dtype.py):
-        # the framework-level Compression helpers become pass-throughs
-        # while the negotiated data plane compresses, so gradients are
-        # never cast twice.
-        from horovod_tpu.common import wire_dtype as _wd
-        _wd.set_active(_wd.wire_code_of(cfg.compression))
-        if isinstance(comm, list):
-            ranks = [int(r) for r in comm]
-            env_size = cfg.size
-            g_rank = cfg.rank if cfg.rank >= 0 else 0
-            full_world = _is_full_world(ranks, env_size)
-            # An inherited coordinator fd (launcher-reserved) serves
-            # the FULL world's published endpoint; it is only valid
-            # when this process leads that full world. Close it
-            # otherwise or it lingers as a dead listener that eats the
-            # port and black-holes connects.
-            if cfg.controller_fd >= 0 and not (full_world
-                                               and g_rank == 0):
-                import os as _os
-                try:
-                    _os.close(cfg.controller_fd)
-                except OSError:
-                    pass
-                cfg.controller_fd = -1
-            if g_rank in ranks:
-                cfg.rank = ranks.index(g_rank)
-                cfg.size = len(ranks)
-                if not full_world and cfg.controller_port:
-                    # The env endpoint belongs to the full world:
-                    # derive a per-membership port (tenancy.py) so a
-                    # sub-coordinator never collides with the full
-                    # world's listener OR another sub-world's — the
-                    # old first-rank-only derivation collided for two
-                    # subsets sharing a first rank, and a subset
-                    # anchored at global rank 0 squatted the fleet
-                    # port itself. Every member derives identically
-                    # from the full list; the world id below turns
-                    # any residual collision into a named handshake
-                    # error. On multi-host launches where the first
-                    # listed rank is not on the env-addr host, set
-                    # HOROVOD_CONTROLLER_ADDR to that rank's host
-                    # before calling init.
-                    from horovod_tpu.common import tenancy as _tenancy
-                    cfg.controller_port = _tenancy.derive_subworld_port(
-                        cfg.controller_port, "", ranks)
-                    cfg.world_id = _tenancy.derive_world_id("", ranks)
-            else:
-                cfg.rank, cfg.size = 0, 1
-        elif comm is not None:
-            rank, size = comm
-            cfg.rank, cfg.size = int(rank), int(size)
-        secret = cfg.secret_key.encode() if cfg.secret_key else b""
+        # The program's spans are armed from the configuration, so that
+        # start-up itself is the first of them.
+        htrace.bind_span_registry(None)
+        htrace.arm_spans(cfg.metrics_enabled or bool(cfg.trace_path))
+        with htrace.span("hvd.init") as sp:
+            rt = _init_world(cfg, comm, coordinator_listener)
+            sp.n = rt.controller.size
 
-        # Elastic worlds (HOROVOD_ELASTIC=1, common/elastic.py): bind
-        # this process's re-rendezvous listener once; a respawned
-        # joiner (HOROVOD_ELASTIC_JOIN=1) instead dials the advertised
-        # coordinator endpoint and blocks until the next rendezvous
-        # barrier admits it with a fresh dense rank.
-        elastic_ctx = None
-        if cfg.elastic_enabled and not isinstance(comm, list):
-            from horovod_tpu.common import elastic as _elastic
-            if cfg.elastic_join:
-                assignment = _elastic.join_world(cfg, secret)
-                cfg.rank = assignment.rank
-                cfg.size = assignment.size
-                cfg.controller_addr = assignment.controller_addr
-                cfg.controller_port = assignment.controller_port
-                cfg.controller_fd = -1
-            if cfg.size > 1 or cfg.size <= 0:
-                elastic_ctx = _elastic.ensure_context(cfg, secret)
 
-        rt = _build_runtime(cfg,
-                            coordinator_listener=coordinator_listener,
-                            elastic_ctx=elastic_ctx)
-        _runtime = rt
-        from horovod_tpu import ops
-        ops.reset_name_counters("")
-        # Service mode (docs/multitenancy.md): rank 0 of a --service
-        # fleet opens the tenant gate so jobs can attach/detach and
-        # pull parameter snapshots without the fleet re-rendezvousing.
-        if cfg.service_enabled and not cfg.world_id \
-                and rt.controller.rank == 0:
-            from horovod_tpu.common import tenancy as _tenancy
-            _tenancy.start_service_gate(cfg, secret)
-        hlog.debug(f"horovod_tpu initialized: rank {rt.controller.rank}"
-                   f" of {rt.controller.size}", rank=rt.controller.rank)
+def _init_world(cfg: Config, comm, coordinator_listener) -> Runtime:
+    """init()'s work on a resolved Config, under ``_lock``."""
+    global _runtime
+    hlog.set_level(cfg.log_level)
+    # Publish the wire-compression latch (common/wire_dtype.py):
+    # the framework-level Compression helpers become pass-throughs
+    # while the negotiated data plane compresses, so gradients are
+    # never cast twice.
+    from horovod_tpu.common import wire_dtype as _wd
+    _wd.set_active(_wd.wire_code_of(cfg.compression))
+    if isinstance(comm, list):
+        ranks = [int(r) for r in comm]
+        env_size = cfg.size
+        g_rank = cfg.rank if cfg.rank >= 0 else 0
+        full_world = _is_full_world(ranks, env_size)
+        # An inherited coordinator fd (launcher-reserved) serves
+        # the FULL world's published endpoint; it is only valid
+        # when this process leads that full world. Close it
+        # otherwise or it lingers as a dead listener that eats the
+        # port and black-holes connects.
+        if cfg.controller_fd >= 0 and not (full_world
+                                           and g_rank == 0):
+            import os as _os
+            try:
+                _os.close(cfg.controller_fd)
+            except OSError:
+                pass
+            cfg.controller_fd = -1
+        if g_rank in ranks:
+            cfg.rank = ranks.index(g_rank)
+            cfg.size = len(ranks)
+            if not full_world and cfg.controller_port:
+                # The env endpoint belongs to the full world:
+                # derive a per-membership port (tenancy.py) so a
+                # sub-coordinator never collides with the full
+                # world's listener OR another sub-world's — the
+                # old first-rank-only derivation collided for two
+                # subsets sharing a first rank, and a subset
+                # anchored at global rank 0 squatted the fleet
+                # port itself. Every member derives identically
+                # from the full list; the world id below turns
+                # any residual collision into a named handshake
+                # error. On multi-host launches where the first
+                # listed rank is not on the env-addr host, set
+                # HOROVOD_CONTROLLER_ADDR to that rank's host
+                # before calling init.
+                from horovod_tpu.common import tenancy as _tenancy
+                cfg.controller_port = _tenancy.derive_subworld_port(
+                    cfg.controller_port, "", ranks)
+                cfg.world_id = _tenancy.derive_world_id("", ranks)
+        else:
+            cfg.rank, cfg.size = 0, 1
+    elif comm is not None:
+        rank, size = comm
+        cfg.rank, cfg.size = int(rank), int(size)
+    secret = cfg.secret_key.encode() if cfg.secret_key else b""
+
+    # Elastic worlds (HOROVOD_ELASTIC=1, common/elastic.py): bind
+    # this process's re-rendezvous listener once; a respawned
+    # joiner (HOROVOD_ELASTIC_JOIN=1) instead dials the advertised
+    # coordinator endpoint and blocks until the next rendezvous
+    # barrier admits it with a fresh dense rank.
+    elastic_ctx = None
+    if cfg.elastic_enabled and not isinstance(comm, list):
+        from horovod_tpu.common import elastic as _elastic
+        if cfg.elastic_join:
+            assignment = _elastic.join_world(cfg, secret)
+            cfg.rank = assignment.rank
+            cfg.size = assignment.size
+            cfg.controller_addr = assignment.controller_addr
+            cfg.controller_port = assignment.controller_port
+            cfg.controller_fd = -1
+        if cfg.size > 1 or cfg.size <= 0:
+            elastic_ctx = _elastic.ensure_context(cfg, secret)
+
+    rt = _build_runtime(cfg,
+                        coordinator_listener=coordinator_listener,
+                        elastic_ctx=elastic_ctx)
+    _runtime = rt
+    from horovod_tpu import ops
+    ops.reset_name_counters("")
+    # Service mode (docs/multitenancy.md): rank 0 of a --service
+    # fleet opens the tenant gate so jobs can attach/detach and
+    # pull parameter snapshots without the fleet re-rendezvousing.
+    if cfg.service_enabled and not cfg.world_id \
+            and rt.controller.rank == 0:
+        from horovod_tpu.common import tenancy as _tenancy
+        _tenancy.start_service_gate(cfg, secret)
+    hlog.debug(f"horovod_tpu initialized: rank {rt.controller.rank}"
+               f" of {rt.controller.size}", rank=rt.controller.rank)
+    return rt
 
 
 def shutdown() -> None:

@@ -1454,9 +1454,7 @@ class TcpCoordinator(Controller):
         # every lag is measured against. The native fanout stamps at
         # true frame completion (in C); the Python fallback stamps as
         # its sequential recv loop returns, which is best-effort for
-        # frames that were already buffered. The hook is captured ONCE
-        # — the trace-overhead toggle bench re-points it from another
-        # thread mid-gather, and check-then-recheck would call None.
+        # frames that were already buffered.
         on_arrivals = self._on_arrivals
         track = (expect_tag == TAG_REQUESTS
                  and on_arrivals is not None)

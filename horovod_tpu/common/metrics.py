@@ -339,25 +339,6 @@ def merge_into(dst: dict, src: dict) -> dict:
     return dst
 
 
-# -- scaling-efficiency feed -------------------------------------------------
-# Written by whoever measured a scaling run in THIS process — the
-# MULTICHIP harness (__graft_entry__.run_multichip) after its
-# per-world-size sweep, or an operator's own calibration pass. Any
-# armed runtime registry mirrors the values lazily as the
-# hvd_scaling_efficiency{world_size="N"} gauge family on its next
-# snapshot (runtime._collect_runtime_metrics).
-
-_scaling_eff: "Dict[int, float]" = {}
-
-
-def note_scaling_efficiency(world_size: int, efficiency: float) -> None:
-    _scaling_eff[int(world_size)] = float(efficiency)
-
-
-def scaling_efficiencies() -> "Dict[int, float]":
-    return dict(_scaling_eff)
-
-
 # -- Prometheus text rendering ----------------------------------------------
 
 def _split_labels(full_name: str) -> Tuple[str, str]:

@@ -418,8 +418,8 @@ class Runtime:
         # -- metrics plane (HOROVOD_TPU_METRICS, common/metrics.py) ----
         # Disabled (the default) hands every call site the shared
         # no-op metric — same zero-overhead contract as _NoOpTimeline;
-        # _metrics_on additionally gates the extra clock reads so the
-        # disabled hot path does not even pay a time.monotonic().
+        # durations are observed by the program's spans (htrace.span),
+        # which read no clock while neither plane is on.
         self.metrics = hmetrics.create_registry(config.metrics_enabled,
                                                 tenant=self._tenant)
         self._metrics_on = bool(config.metrics_enabled)
@@ -527,7 +527,6 @@ class Runtime:
         self._selfop_last_tick = 0.0
         selfop.install_signal_handler(self._wake.set)
         self._selfop_decision_metrics: Dict[str, object] = {}
-        self._scaling_eff_metrics: Dict[int, object] = {}
         self._m_sync_s = reg.histogram(
             "hvd_rejoin_sync_seconds",
             "wall time of each fast rejoin state sync "
@@ -612,6 +611,16 @@ class Runtime:
         self._trace = htrace.create_collector(bool(config.trace_path),
                                               tenant=self._tenant)
         self._trace_on = self._trace.enabled
+        # The program's spans (htrace.span) are armed with either
+        # plane; hvd_span_seconds lives in the default world's registry.
+        if self._metrics_on or self._trace_on:
+            htrace.arm_spans(True)
+        if not self._world_id:
+            htrace.bind_span_registry(reg)
+        # World cycle of the newest batch this rank began to execute:
+        # what a span that closes once its handles are done (a handle
+        # wait, hvd.allreduce_gradients) takes as its cycle.
+        self.exec_cycle = 0
         self._world_cycle = 0
         self._trace_last_pub = 0.0
         self._trace_spans_sent = 0
@@ -712,7 +721,8 @@ class Runtime:
                       wire_dtype=self._propose_wire(request_type,
                                                     dtype))
         entry.request_type = request_type
-        if not self.tensor_table.add(entry, req):
+        if not self.tensor_table.add(entry, req,
+                                     htrace.span_clock_ns()):
             return Status.InvalidArgument(
                 DUPLICATE_NAME_ERROR_FMT
                 % (request_type.name.lower(), entry.tensor_name))
@@ -760,7 +770,7 @@ class Runtime:
                                                         dtype))
             entry.request_type = request_type
             pairs.append((entry, req))
-        dup = self.tensor_table.add_all(pairs)
+        dup = self.tensor_table.add_all(pairs, htrace.span_clock_ns())
         if dup is not None:
             return Status.InvalidArgument(
                 DUPLICATE_NAME_ERROR_FMT
@@ -862,6 +872,8 @@ class Runtime:
     # -- the loop --------------------------------------------------------
     def _background_loop(self) -> None:
         threadcheck.register_role("hvd-background")
+        htrace.bind_thread_collector(self._trace if self._trace_on
+                                     else None)
         try:
             while self._run_loop_once():
                 pass
@@ -1189,24 +1201,28 @@ class Runtime:
             return requests
         deadline = time.monotonic() + self._bounded_hold_s(
             2, self._BURST_HOLD_S)
-        while True:
-            # Event-driven, not polled: clear BEFORE draining so an
-            # enqueue that lands between the drain and the wait still
-            # sets the event (no missed wake, no busy spin — an
-            # earlier 0.5 ms polling variant of this hold cost more
-            # GIL contention than the fragmentation it prevented).
-            self._wake.clear()
-            more = self.tensor_table.pop_messages()
-            if more:
-                requests.extend(more)
-                seen.update(r.tensor_name for r in more)
-                if not fragment():
-                    return requests
-                continue
-            remaining = deadline - time.monotonic()
-            if remaining <= 0 or self._shutdown_requested.is_set():
-                return requests
-            self._wake.wait(remaining)
+        with htrace.span("hvd.hold", tag="burst") as hold:
+            while True:
+                # Event-driven, not polled: clear BEFORE draining so an
+                # enqueue that lands between the drain and the wait
+                # still sets the event (no missed wake, no busy spin —
+                # an earlier 0.5 ms polling variant of this hold cost
+                # more GIL contention than the fragmentation it
+                # prevented).
+                self._wake.clear()
+                more = self.tensor_table.pop_messages()
+                if more:
+                    requests.extend(more)
+                    seen.update(r.tensor_name for r in more)
+                    if not fragment():
+                        break
+                    continue
+                remaining = deadline - time.monotonic()
+                if remaining <= 0 or self._shutdown_requested.is_set():
+                    break
+                self._wake.wait(remaining)
+        self._m_burst_hold_s.inc((hold.end_ns - hold.start_ns) * 1e-9)
+        return requests
 
     def _build_spec_frame(self, hit_mask: int):
         """Build a fused speculative cycle frame: the pure-hit bitmask
@@ -1716,6 +1732,7 @@ class Runtime:
         wc = self._world_cycle
         self.timeline.set_world_cycle(wc)
         self._flight.record(htrace.EV_CYCLE, wc)
+        htrace.note_cycle(wc)
         return wc
 
     def _maybe_publish_trace(self) -> None:
@@ -1831,159 +1848,22 @@ class Runtime:
             self._drain_overlap(block=self._overlap.stalled)
 
         requests = self.tensor_table.pop_messages()
-        if requests and self._cache is not None:
-            if self._metrics_on:
-                tb = time.monotonic()
-                requests = self._absorb_burst(requests)
-                self._m_burst_hold_s.inc(time.monotonic() - tb)
-            else:
-                requests = self._absorb_burst(requests)
-            requests = self._split_buckets(requests)
-        shutting_down = self._shutdown_requested.is_set()
-
-        if self._tenant_lane is not None and requests \
-                and not shutting_down:
-            # QoS-weighted tenant scheduling (common/tenancy.py): a
-            # cycle with local work waits for this tenant's turn in
-            # the process-local weighted interleave, and an over-quota
-            # tenant is DEFERRED — never skipped, so no frame is ever
-            # lost. The wait is bounded by the same hold rule as every
-            # other hold in this loop (far under the heartbeat
-            # deadline), so a deferred tenant's peers can never
-            # mistake pacing for death.
-            self._tenant_lane.acquire(self._bounded_hold_s(8, 2.0))
-
-        if (self._overlap is not None and not requests
-                and not shutting_down
-                and (self._overlap.outstanding or self._steady)):
-            # Overlap regime with nothing local to negotiate: hold for
-            # work instead of initiating an empty classic round. A
-            # wake from a runner completion is NOT work — without this
-            # hold, completion wakes leak empty frames into the world
-            # rounds, misalign them across ranks, and every
-            # speculative bid that lands in such a round dies as a
-            # dead grant. Bounded like the steady idle hold (far under
-            # the heartbeat deadline) so stall detection, full-path
-            # peers and shutdown all keep their liveness: at expiry
-            # the empty round proceeds after all.
-            now = time.monotonic()
-            if self._overlap_hold_deadline is None:
-                self._overlap_hold_deadline = now + \
-                    self._bounded_hold_s(8, self._STEADY_IDLE_S)
-            if now < self._overlap_hold_deadline:
-                self._wake.wait(self._overlap_hold_deadline - now)
-                self._wake.clear()
-                self._drain_overlap(block=False)
-                return True
-            self._overlap_hold_deadline = None
-        elif requests:
-            self._overlap_hold_deadline = None
-
-        payload, bit_requests = self._build_request_frame(
-            requests, shutting_down)
-
-        # 0.0 (not unbound) when dark: _trace_on may be flipped from
-        # another thread mid-cycle (the trace-overhead toggle bench),
-        # and the span emit below must then skip, never NameError.
-        tn = (time.monotonic()
-              if self._metrics_on or self._trace_on else 0.0)
-        submitted = False
-        meta = None
-        if not isinstance(payload, hsteady.SteadyPlan) \
-                and self._overlap is not None \
-                and self._overlap.outstanding:
-            # Classic frame while cycles are in flight: the wire must
-            # quiesce first (cycles are strictly ordered), and the
-            # drained verdicts may have moved cache state or requeued
-            # cancelled buckets — rebuild the frame afterwards.
-            self._drain_overlap(block=True)
-            requests.extend(self.tensor_table.pop_messages())
-            payload, bit_requests = self._build_request_frame(
-                requests, shutting_down)
-        if isinstance(payload, hsteady.SteadyPlan):
-            if self._overlap is not None:
-                submitted = self._submit_overlap_cycle(payload,
-                                                       bit_requests)
-                if not submitted:
-                    # Runner stalled or stopped under us: quiesce, then
-                    # run this cycle synchronously — the wire is ours
-                    # again once the drain returns. The drain applies
-                    # OTHER cycles' verdicts, whose apply path clears
-                    # the speculative in-flight state — save THIS
-                    # unsent cycle's across it.
-                    spec_save = (self._spec_steady,
-                                 self._spec_inflight)
-                    self._drain_overlap(block=True)
-                    self._spec_steady, self._spec_inflight = spec_save
-            if not submitted:
-                # Zero-copy steady step: negotiation + data plane in
-                # ONE native call (deviations rejoin the classic path
-                # inside). An abort raised from inside the C loop must
-                # leave no in-flight speculative state behind: elastic
-                # recovery re-enters a fresh cycle loop, and stale
-                # inflight entries would satisfy the next spec verdict
-                # with dead arrays.
-                try:
-                    meta = self._native_steady_cycle(payload)
-                except BaseException:
-                    self._spec_inflight = None
-                    self._spec_steady = None
-                    raise
+        if requests:
+            # A pass that popped work is one hvd.cycle span, whose
+            # wall time is hvd_cycle_seconds; the batch's wait in the
+            # queue ends where the span starts.
+            queued_ns = self.tensor_table.popped_queued_ns
+            with htrace.span("hvd.cycle", n=len(requests),
+                             also=self._m_cycle_s) as cyc:
+                out = self._negotiate_and_perform(requests)
+            if queued_ns and cyc.on:
+                htrace.interval("hvd.queue_wait", queued_ns,
+                                cyc.start_ns, cyc.cycle, len(requests))
         else:
-            gathered = self.controller.gather_requests(payload)
-            if self.controller.is_coordinator:
-                reply, meta = self._coordinate_cycle(gathered)
-                self.controller.broadcast_responses(reply)
-            else:
-                data = self.controller.broadcast_responses(None)
-                meta = wire.parse_cycle_response(self._unstamp(data))
-        if meta is not None:
-            # A world round completed synchronously in this iteration
-            # (a submitted overlap cycle completes at drain instead).
-            wc = self._note_round()
-            if self._trace_on and tn:
-                self._trace.slice(
-                    "STEADY" if isinstance(payload, hsteady.SteadyPlan)
-                    else "ROUND", tn, time.monotonic() - tn, wc)
-        if self._metrics_on:
-            self._m_negotiation_s.observe(time.monotonic() - tn)
-
-        if submitted:
-            # The cycle completes out of band; its verdict applies at
-            # a later drain, in submission order. Handles resolve
-            # then — synchronize() only ever blocks on the tail
-            # bucket. Treat the submit as activity and loop
-            # immediately: the next bucket may already be queued.
-            self._idle_cycles = 0
-            if self._tenant_lane is not None:
-                self._tenant_lane.note_cycle(self._cycle_bytes)
-                if self.parameter_manager is None:
-                    self._cycle_bytes = 0
-                if self.tensor_table.queue_pending():
-                    self._tenant_lane.want = True  # backlog persists
-            if self.parameter_manager is not None:
-                self.parameter_manager.on_cycle(self._cycle_bytes)
-                self._cycle_bytes = 0
-            if self._metrics_on:
-                self._m_cycle_s.observe(time.monotonic() - t0)
-                self._maybe_publish_metrics()
-            if self._trace_on:
-                self._maybe_publish_trace()
+            out = self._negotiate_and_perform(requests)
+        if out is None:
             return True
-
-        if isinstance(meta, CacheCycleResponse):
-            resp_list = self._apply_cached_cycle(meta, bit_requests)
-        else:
-            if self._cache is not None:
-                raise ConnectionError(
-                    "coordinator negotiated without the response cache "
-                    "while this rank has it enabled — HOROVOD_CACHE_"
-                    "ENABLED/HOROVOD_CACHE_CAPACITY must be identical "
-                    "on every rank")
-            resp_list = meta
-
-        self._perform_operations(resp_list)
-
+        resp_list, requests = out
         if resp_list.shutdown:
             return False
 
@@ -2017,7 +1897,6 @@ class Runtime:
             self._idle_cycles += 1
         elapsed = time.monotonic() - t0
         if self._metrics_on:
-            self._m_cycle_s.observe(elapsed)
             self._maybe_publish_metrics()
         if self._trace_on:
             self._maybe_publish_trace()
@@ -2083,14 +1962,177 @@ class Runtime:
         if sleep_s > 0:
             # Wake early on shutdown OR new local work (enqueue sets
             # _wake) so backoff never adds submit latency.
-            if self._metrics_on and idle_hold:
-                tw = time.monotonic()
-                self._wake.wait(sleep_s)
-                self._m_idle_hold_s.inc(time.monotonic() - tw)
+            if idle_hold:
+                with htrace.span("hvd.hold", tag="idle") as hold:
+                    self._wake.wait(sleep_s)
+                self._m_idle_hold_s.inc(
+                    (hold.end_ns - hold.start_ns) * 1e-9)
             else:
                 self._wake.wait(sleep_s)
         self._wake.clear()
         return True
+
+    def _negotiate_and_perform(self, requests: List[Request]):
+        """The work of one pass of the loop on the ``requests`` it
+        popped (none on an idle pass): hold for the rest of a burst,
+        negotiate one world round, execute what it granted. Returns
+        ``(responses, requests)`` for the loop to pace by, or None
+        where the pass ends at once (an overlapped cycle was submitted,
+        or the overlap regime holds for work)."""
+        if requests and self._cache is not None:
+            requests = self._absorb_burst(requests)
+            requests = self._split_buckets(requests)
+        shutting_down = self._shutdown_requested.is_set()
+
+        if self._tenant_lane is not None and requests \
+                and not shutting_down:
+            # QoS-weighted tenant scheduling (common/tenancy.py): a
+            # cycle with local work waits for this tenant's turn in
+            # the process-local weighted interleave, and an over-quota
+            # tenant is DEFERRED — never skipped, so no frame is ever
+            # lost. The wait is bounded by the same hold rule as every
+            # other hold in this loop (far under the heartbeat
+            # deadline), so a deferred tenant's peers can never
+            # mistake pacing for death.
+            self._tenant_lane.acquire(self._bounded_hold_s(8, 2.0))
+
+        if (self._overlap is not None and not requests
+                and not shutting_down
+                and (self._overlap.outstanding or self._steady)):
+            # Overlap regime with nothing local to negotiate: hold for
+            # work instead of initiating an empty classic round. A
+            # wake from a runner completion is NOT work — without this
+            # hold, completion wakes leak empty frames into the world
+            # rounds, misalign them across ranks, and every
+            # speculative bid that lands in such a round dies as a
+            # dead grant. Bounded like the steady idle hold (far under
+            # the heartbeat deadline) so stall detection, full-path
+            # peers and shutdown all keep their liveness: at expiry
+            # the empty round proceeds after all.
+            now = time.monotonic()
+            if self._overlap_hold_deadline is None:
+                self._overlap_hold_deadline = now + \
+                    self._bounded_hold_s(8, self._STEADY_IDLE_S)
+            if now < self._overlap_hold_deadline:
+                self._wake.wait(self._overlap_hold_deadline - now)
+                self._wake.clear()
+                self._drain_overlap(block=False)
+                return None
+            self._overlap_hold_deadline = None
+        elif requests:
+            self._overlap_hold_deadline = None
+
+        payload, bit_requests = self._build_request_frame(
+            requests, shutting_down)
+
+        # The window hvd_negotiation_seconds times: request gather to
+        # response broadcast, whatever path the round takes.
+        with htrace.span("hvd.negotiate",
+                         also=self._m_negotiation_s) as neg:
+            submitted = False
+            meta = None
+            if not isinstance(payload, hsteady.SteadyPlan) \
+                    and self._overlap is not None \
+                    and self._overlap.outstanding:
+                # Classic frame while cycles are in flight: the wire must
+                # quiesce first (cycles are strictly ordered), and the
+                # drained verdicts may have moved cache state or requeued
+                # cancelled buckets — rebuild the frame afterwards.
+                self._drain_overlap(block=True)
+                requests.extend(self.tensor_table.pop_messages())
+                payload, bit_requests = self._build_request_frame(
+                    requests, shutting_down)
+            if isinstance(payload, hsteady.SteadyPlan):
+                if self._overlap is not None:
+                    submitted = self._submit_overlap_cycle(payload,
+                                                           bit_requests)
+                    if not submitted:
+                        # Runner stalled or stopped under us: quiesce, then
+                        # run this cycle synchronously — the wire is ours
+                        # again once the drain returns. The drain applies
+                        # OTHER cycles' verdicts, whose apply path clears
+                        # the speculative in-flight state — save THIS
+                        # unsent cycle's across it.
+                        spec_save = (self._spec_steady,
+                                     self._spec_inflight)
+                        self._drain_overlap(block=True)
+                        self._spec_steady, self._spec_inflight = spec_save
+                if not submitted:
+                    # Zero-copy steady step: negotiation + data plane in
+                    # ONE native call (deviations rejoin the classic path
+                    # inside). An abort raised from inside the C loop must
+                    # leave no in-flight speculative state behind: elastic
+                    # recovery re-enters a fresh cycle loop, and stale
+                    # inflight entries would satisfy the next spec verdict
+                    # with dead arrays.
+                    try:
+                        meta = self._native_steady_cycle(payload)
+                    except BaseException:
+                        self._spec_inflight = None
+                        self._spec_steady = None
+                        raise
+            else:
+                gathered = self.controller.gather_requests(payload)
+                if self.controller.is_coordinator:
+                    reply, meta = self._coordinate_cycle(gathered)
+                    self.controller.broadcast_responses(reply)
+                else:
+                    data = self.controller.broadcast_responses(None)
+                    meta = wire.parse_cycle_response(self._unstamp(data))
+            if meta is not None:
+                # A world round completed synchronously in this iteration
+                # (a submitted overlap cycle completes at drain instead).
+                self._note_round()
+                if neg.on:
+                    neg.tag = self._round_kind(payload, meta)
+
+        if submitted:
+            # The cycle completes out of band; its verdict applies at
+            # a later drain, in submission order. Handles resolve
+            # then — synchronize() only ever blocks on the tail
+            # bucket. Treat the submit as activity and loop
+            # immediately: the next bucket may already be queued.
+            self._idle_cycles = 0
+            if self._tenant_lane is not None:
+                self._tenant_lane.note_cycle(self._cycle_bytes)
+                if self.parameter_manager is None:
+                    self._cycle_bytes = 0
+                if self.tensor_table.queue_pending():
+                    self._tenant_lane.want = True  # backlog persists
+            if self.parameter_manager is not None:
+                self.parameter_manager.on_cycle(self._cycle_bytes)
+                self._cycle_bytes = 0
+            if self._metrics_on:
+                self._maybe_publish_metrics()
+            if self._trace_on:
+                self._maybe_publish_trace()
+            return None
+
+        if isinstance(meta, CacheCycleResponse):
+            resp_list = self._apply_cached_cycle(meta, bit_requests)
+        else:
+            if self._cache is not None:
+                raise ConnectionError(
+                    "coordinator negotiated without the response cache "
+                    "while this rank has it enabled — HOROVOD_CACHE_"
+                    "ENABLED/HOROVOD_CACHE_CAPACITY must be identical "
+                    "on every rank")
+            resp_list = meta
+
+        self._perform_operations(resp_list)
+        return resp_list, requests
+
+    @staticmethod
+    def _round_kind(payload, meta) -> str:
+        """hvd.negotiate's tag: how the round was negotiated."""
+        if isinstance(payload, hsteady.SteadyPlan):
+            return "steady"
+        if isinstance(meta, CacheCycleResponse):
+            if meta.spec_payload is not None:
+                return "spec"
+            if not meta.response_list.responses:
+                return "cached"
+        return "round"
 
     def _coordinate_cycle(self, gathered: List[bytes]):
         """Parse every rank's cycle frame and produce this cycle's
@@ -2560,19 +2602,6 @@ class Runtime:
                 self._selfop_decision_metrics[kind] = m
             m.set_total(n)
         self._m_ckpt_age.set(selfop.checkpoint_age_s())
-        # Scaling efficiencies mirror lazily per world size, same
-        # doctrine: the series appears once something measured one
-        # (the MULTICHIP harness, or an operator calibration pass).
-        for n, eff in hmetrics.scaling_efficiencies().items():
-            g = self._scaling_eff_metrics.get(n)
-            if g is None:
-                g = self.metrics.gauge(
-                    f'hvd_scaling_efficiency{{world_size="{n}"}}',
-                    "measured throughput fraction of ideal linear "
-                    "scaling at this world size (fed by "
-                    "__graft_entry__.run_multichip)")
-                self._scaling_eff_metrics[n] = g
-            g.set(eff)
         self._m_cycles.set_total(self._cycle_count)
         self._m_cached_cycles.set_total(self._cached_cycles)
         self._m_spec_cycles.set_total(self._spec_cycles)
@@ -2978,10 +3007,10 @@ class Runtime:
                     e.callback = _cb
             else:
                 self.timeline.activity_start_all(names, ACT_COLLECTIVE)
-            # 0.0 (not unbound) when dark — _trace_on may flip from
-            # another thread mid-execute (the trace-overhead toggle
-            # bench); the emit below must then skip, never NameError.
-            tx = time.monotonic() if self._trace_on else 0.0
+            # The batch's hvd.execute span is opened in the manager,
+            # which knows the backend (issue-side wall time: an async
+            # backend completes on a finalizer thread).
+            self.exec_cycle = self._world_cycle
             try:
                 status = self.op_manager.execute(entries, response)
             except WorldAbortedError as e:
@@ -3009,13 +3038,6 @@ class Runtime:
             except Exception as e:
                 status = Status.UnknownError(
                     f"collective execution failed: {e!r}")
-            if self._trace_on and tx:
-                # Issue-side wall time of the batch (async backends
-                # complete on finalizer threads — their tail rides
-                # the next ROUND span, like the timeline's B span).
-                self._trace.slice(f"{op_name} x{len(entries)}", tx,
-                                  time.monotonic() - tx,
-                                  self._world_cycle)
             if closer is None and self.timeline.enabled:
                 self.timeline.activity_end_all(names)
                 for e in entries:
@@ -3023,9 +3045,10 @@ class Runtime:
             self._cycle_bytes += sum(
                 getattr(e.tensor, "nbytes", 0) for e in entries)
             if not status.in_progress():
-                for e in entries:
-                    if e.callback:
-                        e.callback(status)
+                with htrace.span("hvd.complete", n=len(entries)):
+                    for e in entries:
+                        if e.callback:
+                            e.callback(status)
 # -- thread-affinity sanitizer (HOROVOD_TPU_THREADCHECK) ------------------
 # Checked-field ids mirror the static thread-ownership analyzer's.
 # _tenant_lane has no fixed owner: it legitimately migrates (main

@@ -50,8 +50,15 @@ class TensorTable:
         self._lock = lockdep.lock("tensor_table.TensorTable._lock")
         self._table: Dict[str, TensorTableEntry] = {}
         self._message_queue: List[Request] = []
+        # When the queue's oldest request was added, as the adder
+        # stamped it (``now_ns``; 0 = not stamped: tracing is off), and
+        # the same for the batch pop_messages last drained: the two
+        # ends of the program's hvd.queue_wait interval.
+        self._queued_ns = 0
+        self.popped_queued_ns = 0
 
-    def add(self, entry: TensorTableEntry, request: Request) -> bool:
+    def add(self, entry: TensorTableEntry, request: Request,
+            now_ns: int = 0) -> bool:
         """Insert entry + request atomically. Returns False on duplicate
         name (reference: operations.cc:1459-1462 DUPLICATE_NAME_ERROR)."""
         with self._lock:
@@ -59,9 +66,11 @@ class TensorTable:
                 return False
             self._table[entry.tensor_name] = entry
             self._message_queue.append(request)
+            if not self._queued_ns:
+                self._queued_ns = now_ns
             return True
 
-    def add_all(self, pairs) -> Optional[str]:
+    def add_all(self, pairs, now_ns: int = 0) -> Optional[str]:
         """Insert several (entry, request) pairs under ONE lock hold —
         all-or-nothing, and atomic w.r.t. pop_messages, so a concurrent
         cycle tick can never split the batch across two RequestLists
@@ -74,6 +83,8 @@ class TensorTable:
             for entry, request in pairs:
                 self._table[entry.tensor_name] = entry
                 self._message_queue.append(request)
+            if not self._queued_ns:
+                self._queued_ns = now_ns
             return None
 
     def pop_messages(self) -> List[Request]:
@@ -82,6 +93,7 @@ class TensorTable:
         with self._lock:
             msgs = self._message_queue
             self._message_queue = []
+            self.popped_queued_ns, self._queued_ns = self._queued_ns, 0
             return msgs
 
     def requeue(self, requests: List[Request]) -> None:

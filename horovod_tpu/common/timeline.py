@@ -48,8 +48,8 @@ class _NoOpTimeline:
     def negotiate_cached(self, fused=False): pass
     def wire_plan(self, detail): pass
     def start(self, name, op_name): pass
-    def activity_start_all(self, names, activity): pass
-    def activity_end_all(self, names): pass
+    def activity_start_all(self, names, activity, ts_ns=0): pass
+    def activity_end_all(self, names, ts_ns=0): pass
     def end(self, name): pass
     def async_start(self, name, event_name, batch_id): pass
     def async_end(self, name, event_name, batch_id): pass
@@ -83,7 +83,9 @@ class Timeline(_NoOpTimeline):
         self._next_pid = 1
         self._wc = 0  # world cycle number (set_world_cycle)
         self._lock = lockdep.lock("timeline.Timeline._lock")
-        self._start_ts = time.monotonic()
+        # The program's spans' clock (common/trace.py), so that an
+        # activity that coincides with a span takes the span's reading.
+        self._start_ns = time.time_ns()
         self._writer = threading.Thread(target=self._write_loop,
                                         name="hvd-timeline-writer",
                                         daemon=True)
@@ -130,8 +132,8 @@ class Timeline(_NoOpTimeline):
                 f.flush()
             f.write("\n]\n")
 
-    def _ts(self) -> int:
-        return int((time.monotonic() - self._start_ts) * 1e6)
+    def _ts(self, ts_ns: int = 0) -> int:
+        return ((ts_ns or time.time_ns()) - self._start_ns) // 1000
 
     def _pid(self, name: str) -> int:
         with self._lock:
@@ -151,8 +153,9 @@ class Timeline(_NoOpTimeline):
     # stamping them too would only bloat the file.
     _WC_PHASES = frozenset(("B", "X", "i", "b"))
 
-    def _emit(self, ph: str, name: str, event_name: str, **kw):
-        rec = {"ph": ph, "pid": self._pid(name), "ts": self._ts()}
+    def _emit(self, ph: str, name: str, event_name: str, ts_ns: int = 0,
+              **kw):
+        rec = {"ph": ph, "pid": self._pid(name), "ts": self._ts(ts_ns)}
         if event_name:
             rec["name"] = event_name
         rec.update(kw)
@@ -199,13 +202,16 @@ class Timeline(_NoOpTimeline):
     def start(self, name: str, op_name: str) -> None:
         self._emit("B", name, op_name)
 
-    def activity_start_all(self, names, activity: str) -> None:
+    def activity_start_all(self, names, activity: str,
+                           ts_ns: int = 0) -> None:
+        """``ts_ns``: the reading of the span that opens with the
+        activity, where there is one; 0 reads the clock."""
         for name in names:
-            self._emit("B", name, activity)
+            self._emit("B", name, activity, ts_ns)
 
-    def activity_end_all(self, names) -> None:
+    def activity_end_all(self, names, ts_ns: int = 0) -> None:
         for name in names:
-            self._emit("E", name, "")
+            self._emit("E", name, "", ts_ns)
 
     def end(self, name: str) -> None:
         self._emit("E", name, "")

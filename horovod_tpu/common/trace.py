@@ -38,6 +38,16 @@ Four coupled pieces answer them:
   stall shutdown and SIGUSR2 — a production abort ships the last N
   seconds of world history with no profiling armed.
 
+* :func:`span` / :func:`interval` — the program's ONE span call
+  (``hvd.init`` ... ``hvd.complete``, the vocabulary in
+  :data:`SPAN_COUNTS`): a ``jax.profiler.TraceAnnotation`` so an
+  operator's profile shows the program's spans over the device's ops,
+  one ``hvd_span_seconds{span=...}`` histogram family, a
+  process-lifetime ring on the profiler's clock
+  (:func:`recent_spans`), and the world trace's slices — armed with
+  the registry or the world trace, the shared :data:`NOOP_SPAN`
+  otherwise (docs/tracing.md).
+
 The recorder and the clock table are process-lifetime singletons (the
 lockdep pattern): they must survive elastic re-inits so a postmortem
 spans world generations, and modules without a Runtime in hand
@@ -53,7 +63,8 @@ import signal
 import threading
 import time
 from collections import OrderedDict, deque
-from typing import Dict, List, Optional, Tuple
+from itertools import count
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 from horovod_tpu.common import config as hconfig
 from horovod_tpu.common import lockdep
@@ -71,7 +82,9 @@ __all__ = [
     "NOOP_TRACE", "FlightRecorder", "NOOP_RECORDER", "flight",
     "clock", "StragglerTracker", "WorldTraceWriter",
     "install_sigusr2", "serialize_trace_frame", "parse_trace_frame",
-    "combine_trace_frames",
+    "combine_trace_frames", "span", "interval", "NOOP_SPAN",
+    "SPAN_COUNTS", "SpanRecord", "recent_spans", "spans_dropped",
+    "arm_spans",
 ]
 
 
@@ -287,6 +300,305 @@ def create_collector(enabled: bool, tenant: str = ""):
 
 
 # ---------------------------------------------------------------------------
+# Program spans (per process, on the profiler's clock)
+# ---------------------------------------------------------------------------
+
+# The vocabulary: span name -> what its ``n`` counts ("" = nothing).
+# Stable names — docs/tracing.md lists them with the layer metric each
+# is for, and the chip benchmark's readers key on them.
+SPAN_COUNTS = {
+    "hvd.init": "ranks",
+    "hvd.init.native": "",
+    "hvd.init.rendezvous": "ranks",
+    "hvd.init.runtime": "",
+    "hvd.broadcast_parameters": "leaves",
+    "hvd.allreduce_gradients": "leaves",
+    "hvd.enqueue": "tensors",
+    "hvd.synchronize": "",
+    "hvd.queue_wait": "tensors",
+    "hvd.cycle": "requests",
+    "hvd.hold": "",
+    "hvd.negotiate": "",
+    "hvd.execute": "tensors",
+    "hvd.pack": "",
+    "hvd.unpack": "",
+    "hvd.complete": "handles",
+}
+
+SPAN_METRIC = "hvd_span_seconds"
+# Decades from 1 us: a handle wait that finds its handle done is a
+# microsecond, a start-up broadcast seconds.
+SPAN_BUCKETS = (1e-6, 1e-5, 1e-4, 1e-3, 1e-2, 0.1, 1.0, 10.0)
+SPAN_RING_CAPACITY = 1 << 16
+
+
+class SpanRecord(NamedTuple):
+    """One closed span. ``start_ns``/``end_ns`` are ``time.time_ns()``:
+    the clock the JAX profiler stamps host events with (an
+    ``.xplane.pb`` holds them minus its ``profile_start_time``).
+    ``thread`` is the thread's name ("" for an interval that is no
+    thread's), ``parent`` the ``id`` of the innermost span open on the
+    same thread (0 = none), ``cycle`` the world cycle number the
+    spans of one exchange share (0 = not known when the span
+    closed)."""
+    name: str
+    start_ns: int
+    end_ns: int
+    thread: str
+    parent: int
+    cycle: int
+    counts: dict
+    id: int
+
+
+class _NoOpSpan:
+    """Tracing off: the one object every site gets. Entering it reads
+    no clock; sites may set ``cycle``/``n``/``nbytes``/``tag`` on it
+    unguarded."""
+
+    __slots__ = ()
+    on = False
+    start_ns = end_ns = 0
+
+    def __enter__(self): return self
+    def __exit__(self, *exc): return False
+    def __setattr__(self, name, value): pass
+
+
+NOOP_SPAN = _NoOpSpan()
+
+
+class _NoAnnotation:
+    def __init__(self, name): pass
+    def __enter__(self): return self
+    def __exit__(self, *exc): return False
+
+
+class SpanRing:
+    """Bounded ring of closed spans (the FlightRecorder pattern): past
+    capacity the oldest are overwritten and counted as dropped."""
+
+    def __init__(self, capacity: int = SPAN_RING_CAPACITY):
+        self._lock = lockdep.lock("trace.SpanRing._lock")
+        self._ring: List[Optional[SpanRecord]] = [None] * max(1, capacity)
+        self._next = 0
+
+    def push(self, rec: SpanRecord) -> None:
+        with self._lock:
+            self._ring[self._next % len(self._ring)] = rec
+            self._next += 1
+
+    def snapshot(self) -> List[SpanRecord]:
+        """The spans kept, oldest first."""
+        with self._lock:
+            n, nxt = len(self._ring), self._next
+            return [self._ring[i % n] for i in range(max(0, nxt - n), nxt)]
+
+    @property
+    def dropped(self) -> int:
+        """How many spans were overwritten."""
+        return max(0, self._next - len(self._ring))
+
+
+class _ThreadSpans(threading.local):
+    """A thread's open spans, and the world-trace collector of the
+    runtime whose background loop the thread is (None elsewhere: the
+    world trace is the loop's track, its cycle numbers in order)."""
+
+    def __init__(self):
+        self.stack: List["_Span"] = []
+        self.cycle = 0          # note_cycle's: the round being worked for
+        self.collector = None
+        self.name = threading.current_thread().name
+
+
+_TLS = _ThreadSpans()
+_SPAN_IDS = count(1)
+_SPANS_ON = False
+_SPAN_RING: Optional[SpanRing] = None
+_SPAN_REGISTRY = None          # the default world's registry, once bound
+_SPAN_HISTS: Dict[str, object] = {}
+# (name, seconds) of spans closed before a registry was bound: start-up's
+# own, observed once the world it started has one.
+_SPAN_UNOBSERVED: List[Tuple[str, float]] = []
+_ANNOTATION = None             # jax.profiler.TraceAnnotation, once armed
+_WALL_TO_MONO_S = 0.0          # TraceCollector's clock is time.monotonic()
+
+
+def arm_spans(on: bool) -> None:
+    """Arm (or, from ``hvd.init()`` alone, disarm) the span call for
+    this process. ``hvd.init()`` decides from the configuration it
+    reads, before its own first span; a runtime whose registry or
+    world trace is on arms too (tenant worlds)."""
+    global _SPANS_ON, _SPAN_RING, _ANNOTATION, _WALL_TO_MONO_S
+    if on and _SPAN_RING is None:
+        try:
+            from jax.profiler import TraceAnnotation as _ANNOTATION
+        except ImportError:
+            # jax is an optional extra: without it there is no profiler
+            # to show the spans in; the ring and the registry stand.
+            _ANNOTATION = _NoAnnotation
+        _WALL_TO_MONO_S = time.monotonic() - time.time()
+        _SPAN_RING = SpanRing()
+    _SPANS_ON = bool(on)
+
+
+def bind_span_registry(registry) -> None:
+    """The registry ``hvd_span_seconds`` is observed in: the default
+    world's, bound when its runtime is built (``hvd.init()`` unbinds
+    the world's before: what start-up closes until then is observed
+    at the binding)."""
+    global _SPAN_REGISTRY
+    _SPAN_REGISTRY = registry
+    _SPAN_HISTS.clear()
+    if registry is not None:
+        for name, seconds in _SPAN_UNOBSERVED:
+            _span_histogram(name).observe(seconds)
+    del _SPAN_UNOBSERVED[:]
+
+
+def _span_histogram(name: str):
+    hist = _SPAN_HISTS.get(name)
+    if hist is None:
+        hist = _SPAN_HISTS[name] = _SPAN_REGISTRY.histogram(
+            f'{SPAN_METRIC}{{span="{name}"}}',
+            "wall time of the program's spans, by span "
+            "(docs/tracing.md)", SPAN_BUCKETS)
+    return hist
+
+
+def bind_thread_collector(collector) -> None:
+    """Called by a runtime's background loop on its own thread: spans
+    closed there feed ``collector`` (the world trace)."""
+    _TLS.collector = collector
+
+
+class _Span:
+    """An open span; see :func:`span`."""
+
+    __slots__ = ("name", "cycle", "n", "nbytes", "tag", "also",
+                 "start_ns", "end_ns", "id", "_parent", "_annotation")
+    on = True
+
+    def __init__(self, name, cycle, n, nbytes, tag, also):
+        self.name, self.cycle, self.n = name, cycle, n
+        self.nbytes, self.tag, self.also = nbytes, tag, also
+        self.start_ns = self.end_ns = self._parent = 0
+        self.id = next(_SPAN_IDS)
+
+    def __enter__(self):
+        tls = _TLS
+        stack = tls.stack
+        if stack:
+            self._parent = stack[-1].id
+            self.cycle = self.cycle or stack[-1].cycle
+        else:
+            self.cycle = self.cycle or tls.cycle
+        stack.append(self)
+        self._annotation = _ANNOTATION(self.name)
+        self._annotation.__enter__()
+        self.start_ns = time.time_ns()
+        return self
+
+    def __exit__(self, *exc):
+        self.end_ns = time.time_ns()
+        self._annotation.__exit__(*exc)
+        tls = _TLS
+        tls.stack.pop()
+        _close(self, tls.name, tls.collector)
+        return False
+
+
+def _close(sp: _Span, thread: str, collector) -> None:
+    """Keep the closed span: ring, histograms, world trace."""
+    counts = {}
+    key = SPAN_COUNTS[sp.name]
+    if key and sp.n:
+        counts[key] = sp.n
+    if sp.nbytes:
+        counts["bytes"] = sp.nbytes
+    if sp.tag:
+        counts["tag"] = sp.tag
+    _SPAN_RING.push(SpanRecord(sp.name, sp.start_ns, sp.end_ns, thread,
+                               sp._parent, sp.cycle, counts, sp.id))
+    seconds = (sp.end_ns - sp.start_ns) * 1e-9
+    if _SPAN_REGISTRY is not None:
+        _span_histogram(sp.name).observe(seconds)
+    elif len(_SPAN_UNOBSERVED) < 64:
+        _SPAN_UNOBSERVED.append((sp.name, seconds))
+    if sp.also is not None:
+        sp.also.observe(seconds)
+    if collector is not None:
+        collector.slice(f"{sp.name} {sp.tag}" if sp.tag else sp.name,
+                        sp.start_ns * 1e-9 + _WALL_TO_MONO_S, seconds,
+                        sp.cycle)
+
+
+def span(name: str, cycle: int = 0, n: int = 0, nbytes: int = 0,
+         tag: str = "", also=None):
+    """The program's span call, a context manager. Armed, one call
+    opens a ``jax.profiler.TraceAnnotation(name)``, and on close keeps
+    a :class:`SpanRecord` in the process's ring, observes the duration
+    in ``hvd_span_seconds{span=name}`` (and in ``also``, a histogram
+    that timed the same window under an older name), and feeds the
+    world trace where the thread is a runtime's background loop. Not
+    armed it returns :data:`NOOP_SPAN`. ``n`` counts what
+    :data:`SPAN_COUNTS` says for ``name``; ``cycle``, ``n``,
+    ``nbytes`` and ``tag`` may be set on the span until it closes; a
+    span opened without a cycle takes its parent's, or the round its
+    thread last noted (:func:`note_cycle`)."""
+    if not _SPANS_ON:
+        return NOOP_SPAN
+    return _Span(name, cycle, n, nbytes, tag, also)
+
+
+def interval(name: str, start_ns: int, end_ns: int, cycle: int = 0,
+             n: int = 0, nbytes: int = 0, tag: str = "") -> None:
+    """A span that is no thread's (the wait of a batch in the queue):
+    both ends on :func:`span`'s clock, no parent, no annotation."""
+    if _SPANS_ON:
+        sp = _Span(name, cycle, n, nbytes, tag, None)
+        sp.start_ns, sp.end_ns = start_ns, end_ns
+        _close(sp, "", _TLS.collector)
+
+
+def note_cycle(cycle: int) -> None:
+    """World round ``cycle`` completed on this thread (a runtime's
+    background loop): the spans it has open belong to that round, and
+    so do those it opens until the next."""
+    if _SPANS_ON:
+        tls = _TLS
+        tls.cycle = cycle
+        for open_span in tls.stack:
+            open_span.cycle = cycle
+
+
+def current_cycle() -> int:
+    """The cycle a span opened on this thread now would get (0 = none):
+    what work handed to another thread carries over."""
+    tls = _TLS
+    return tls.stack[-1].cycle if tls.stack else tls.cycle
+
+
+def span_clock_ns() -> int:
+    """A reading of the spans' clock for :func:`interval`, or 0 where
+    spans are off (so that a site stamps nothing then)."""
+    return time.time_ns() if _SPANS_ON else 0
+
+
+def recent_spans() -> List[SpanRecord]:
+    """The ring's spans in the order they closed. Process-lifetime: it
+    outlives ``hvd.shutdown()`` and elastic re-inits."""
+    return _SPAN_RING.snapshot() if _SPAN_RING is not None else []
+
+
+def spans_dropped() -> int:
+    """Spans the ring has overwritten; a reader that needs every span
+    of a run gives no value when this is not 0."""
+    return _SPAN_RING.dropped if _SPAN_RING is not None else 0
+
+
+# ---------------------------------------------------------------------------
 # Flight recorder (per rank, on by default)
 # ---------------------------------------------------------------------------
 
@@ -448,6 +760,15 @@ def _reset_for_tests() -> None:
         _FLIGHT = None
     with _CLOCK_LOCK:
         _CLOCK = None
+
+
+def _reset_spans_for_tests(capacity: int = SPAN_RING_CAPACITY) -> None:
+    """Disarm and start an empty ring of ``capacity``."""
+    global _SPAN_RING
+    arm_spans(True)
+    _SPAN_RING = SpanRing(capacity)
+    arm_spans(False)
+    bind_span_registry(None)
 
 
 _SIGUSR2_INSTALLED = False

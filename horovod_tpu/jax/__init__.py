@@ -40,6 +40,8 @@ from horovod_tpu.ops import (  # noqa: F401
     reducescatter, reducescatter_async, barrier, poll, synchronize,
     Average, Sum,
 )
+from horovod_tpu.common import basics as _basics
+from horovod_tpu.common import trace as _htrace
 from horovod_tpu.common.compression import Compression  # noqa: F401
 from horovod_tpu import spmd as _spmd
 from horovod_tpu.spmd import (  # noqa: F401
@@ -63,6 +65,12 @@ def DistributedOptimizer(tx, op: int = _spmd.Average,
         return tx.init(params)
 
     def update_fn(grads, state, params=None, **extra):
+        import jax
+        with jax.named_scope("exchange"):
+            grads = exchange(grads)
+        return tx.update(grads, state, params, **extra)
+
+    def exchange(grads):
         if gradient_predivide_factor != 1.0 and op == _spmd.Average:
             # Reference semantics (horovod allreduce prescale/postscale):
             # prescale by 1/f before the sum, postscale by f/size after —
@@ -83,11 +91,9 @@ def DistributedOptimizer(tx, op: int = _spmd.Average,
                     return compression.decompress(averaged(c), ctx)
             else:
                 one = averaged
-            grads = jax.tree_util.tree_map(one, grads)
-        else:
-            grads = _spmd.allreduce_gradients(grads, op=op, axis=axis,
-                                              compression=compression)
-        return tx.update(grads, state, params, **extra)
+            return jax.tree_util.tree_map(one, grads)
+        return _spmd.allreduce_gradients(grads, op=op, axis=axis,
+                                         compression=compression)
 
     return optax.GradientTransformationExtraArgs(init_fn, update_fn)
 
@@ -108,19 +114,39 @@ def allreduce_gradients(grads, op: int = Average,
     only ever blocks on the last bucket."""
     import jax
 
-    leaves, treedef = jax.tree_util.tree_flatten(grads)
-    if compression is Compression.none:
-        handles = grouped_allreduce_async(leaves, name="grad", op=op)
-        outs = [synchronize(h) for h in handles]
-        return jax.tree_util.tree_unflatten(treedef, outs)
-    handles = []
-    for i, g in enumerate(leaves):
-        comp, ctx = compression.compress(g)
-        handles.append((allreduce_async(comp, name=f"grad.{i}", op=op),
-                        ctx))
-    outs = [compression.decompress(synchronize(h), ctx)
-            for h, ctx in handles]
-    return jax.tree_util.tree_unflatten(treedef, outs)
+    with _htrace.span("hvd.allreduce_gradients") as sp:
+        leaves, treedef = jax.tree_util.tree_flatten(grads)
+        _count_leaves(sp, leaves)
+        if compression is Compression.none:
+            handles = grouped_allreduce_async(leaves, name="grad", op=op)
+            outs = [synchronize(h) for h in handles]
+        else:
+            handles = []
+            for i, g in enumerate(leaves):
+                comp, ctx = compression.compress(g)
+                handles.append(
+                    (allreduce_async(comp, name=f"grad.{i}", op=op), ctx))
+            outs = [compression.decompress(synchronize(h), ctx)
+                    for h, ctx in handles]
+        return _unflatten_done(sp, treedef, outs)
+
+
+def _count_leaves(sp, leaves) -> None:
+    """A whole tree's span counts its leaves and their bytes."""
+    if sp.on:
+        sp.n = len(leaves)
+        sp.nbytes = sum(getattr(leaf, "nbytes", 0) for leaf in leaves)
+
+
+def _unflatten_done(sp, treedef, outs):
+    """The tree back, every handle done: its span takes the world
+    cycle of the newest batch this rank began to execute."""
+    import jax
+
+    tree = jax.tree_util.tree_unflatten(treedef, outs)
+    if sp.on:
+        sp.cycle = _basics.active_runtime().exec_cycle
+    return tree
 
 
 def broadcast_parameters(params, root_rank: int = 0):
@@ -130,11 +156,14 @@ def broadcast_parameters(params, root_rank: int = 0):
     horovod_tpu.spmd.broadcast_variables."""
     import jax
 
-    leaves, treedef = jax.tree_util.tree_flatten(params)
-    handles = [broadcast_async(p, root_rank=root_rank, name=f"bcast.p.{i}")
-               for i, p in enumerate(leaves)]
-    outs = [synchronize(h) for h in handles]
-    return jax.tree_util.tree_unflatten(treedef, outs)
+    with _htrace.span("hvd.broadcast_parameters") as sp:
+        leaves, treedef = jax.tree_util.tree_flatten(params)
+        _count_leaves(sp, leaves)
+        handles = [broadcast_async(p, root_rank=root_rank,
+                                   name=f"bcast.p.{i}")
+                   for i, p in enumerate(leaves)]
+        outs = [synchronize(h) for h in handles]
+        return _unflatten_done(sp, treedef, outs)
 
 
 def broadcast_optimizer_state(opt_state, root_rank: int = 0):

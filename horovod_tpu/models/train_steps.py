@@ -64,6 +64,15 @@ def lm_loss_fn(model: TransformerLM):
     return loss_fn
 
 
+def _apply(tx, grads, opt_state, params):
+    """The optimizer's part of a step, under its own scope in a profile
+    (the gradients' pmean inside ``tx.update`` is ``exchange`` there:
+    ``horovod_tpu.jax.DistributedOptimizer`` names it)."""
+    with jax.named_scope("optimizer"):
+        updates, opt_state = tx.update(grads, opt_state, params)
+        return optax.apply_updates(params, updates), opt_state
+
+
 def lm_train_step(model: TransformerLM, tx, mesh):
     """``jit(shard_map(step))`` with ``(params, opt_state)`` donated:
     ``(params, opt_state, tokens) -> (params, opt_state, loss)``. The
@@ -71,10 +80,12 @@ def lm_train_step(model: TransformerLM, tx, mesh):
     loss_fn = lm_loss_fn(model)
 
     def step(p, os_, t):
-        loss, grads = jax.value_and_grad(loss_fn)(p, t)
-        updates, new_os = tx.update(grads, os_, p)
-        return (optax.apply_updates(p, updates), new_os,
-                jax.lax.pmean(loss, AXIS))
+        with jax.named_scope("loss"):
+            loss, grads = jax.value_and_grad(loss_fn)(p, t)
+        new_p, new_os = _apply(tx, grads, os_, p)
+        with jax.named_scope("exchange"):
+            loss = jax.lax.pmean(loss, AXIS)
+        return new_p, new_os, loss
 
     rep = jaxshim.partition_spec()
     step = jaxshim.shard_map(
@@ -98,11 +109,13 @@ def resnet_train_step(model: ResNet50, tx, mesh):
         return loss, updates["batch_stats"]
 
     def step(p, bs, os_, x, y):
-        (loss, new_bs), grads = jax.value_and_grad(
-            loss_fn, has_aux=True)(p, bs, x, y)
-        updates, new_os = tx.update(grads, os_, p)
-        return (optax.apply_updates(p, updates), new_bs, new_os,
-                jax.lax.pmean(loss, AXIS))
+        with jax.named_scope("loss"):
+            (loss, new_bs), grads = jax.value_and_grad(
+                loss_fn, has_aux=True)(p, bs, x, y)
+        new_p, new_os = _apply(tx, grads, os_, p)
+        with jax.named_scope("exchange"):
+            loss = jax.lax.pmean(loss, AXIS)
+        return new_p, new_bs, new_os, loss
 
     rep = jaxshim.partition_spec()
     batch = jaxshim.partition_spec(AXIS)

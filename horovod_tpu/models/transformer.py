@@ -328,6 +328,7 @@ def lm_loss_from_hidden(hidden, head_kernel, tokens, chunk: int = 1024):
         h, t, m = xs
         return carry + chunk_ll(h, t, m), None
 
-    total, _ = jax.lax.scan(body, jnp.float32(0.0),
-                            (hid, targets, mask))
+    with jax.named_scope("lm_head_loss"):
+        total, _ = jax.lax.scan(body, jnp.float32(0.0),
+                                (hid, targets, mask))
     return -total / (b * s)

@@ -21,6 +21,7 @@ import numpy as np
 
 from horovod_tpu.common import basics
 from horovod_tpu.common import lockdep
+from horovod_tpu.common import trace as htrace
 from horovod_tpu.common.message import (
     RequestType, numpy_dtype_to_datatype,
 )
@@ -89,22 +90,23 @@ def _enqueue(kind: RequestType, tensor, name: Optional[str],
              root_rank: int = -1, prescale: float = 1.0,
              postscale: float = 1.0) -> int:
     rt = basics.active_runtime()
-    payload, ctx, device, np_dtype, shape, ready_fn = _inspect(tensor)
-    dtype = numpy_dtype_to_datatype(np_dtype)
-    name = name or _auto_name(kind.name.lower())
-    handle = rt.handle_manager.allocate()
+    with htrace.span("hvd.enqueue", n=1):
+        payload, ctx, device, np_dtype, shape, ready_fn = _inspect(tensor)
+        dtype = numpy_dtype_to_datatype(np_dtype)
+        name = name or _auto_name(kind.name.lower())
+        handle = rt.handle_manager.allocate()
 
-    entry = TensorTableEntry(tensor_name=name, tensor=payload,
-                             root_rank=root_rank, device=device,
-                             ready_fn=ready_fn, context=ctx)
+        entry = TensorTableEntry(tensor_name=name, tensor=payload,
+                                 root_rank=root_rank, device=device,
+                                 ready_fn=ready_fn, context=ctx)
 
-    def callback(status: Status) -> None:
-        rt.handle_manager.mark_done(handle, status, entry.output)
+        def callback(status: Status) -> None:
+            rt.handle_manager.mark_done(handle, status, entry.output)
 
-    entry.callback = callback
-    status = rt.enqueue(kind, entry, dtype, shape, prescale, postscale)
-    if not status.ok():
-        rt.handle_manager.mark_done(handle, status, None)
+        entry.callback = callback
+        status = rt.enqueue(kind, entry, dtype, shape, prescale, postscale)
+        if not status.ok():
+            rt.handle_manager.mark_done(handle, status, None)
     return handle
 
 
@@ -121,7 +123,9 @@ def synchronize(handle: int) -> Any:
     HorovodInternalError subclass) carrying the originating rank."""
     rt = basics.active_runtime()
     try:
-        status = rt.handle_manager.wait(handle)
+        with htrace.span("hvd.synchronize") as sp:
+            status = rt.handle_manager.wait(handle)
+            sp.cycle = rt.exec_cycle
     except ValueError:
         # Handle ids are unique across world generations, so a stale
         # id is provably from BEFORE an elastic resize (its collective
@@ -205,6 +209,13 @@ def grouped_allreduce_async(tensors, average: Optional[bool] = None,
     tensor (unsupported dtype, unscalable integer average) fails the
     whole call without leaking half a group in flight — peers never
     block on members this rank never submitted."""
+    with htrace.span("hvd.enqueue", n=len(tensors)):
+        return _grouped_allreduce_async(tensors, average, name, op,
+                                        prescale_factor, postscale_factor)
+
+
+def _grouped_allreduce_async(tensors, average, name, op, prescale_factor,
+                             postscale_factor) -> list:
     if name is None:
         name = _auto_name("grouped_allreduce")
     resolved_op = op if op is not None else (

@@ -20,11 +20,14 @@ from __future__ import annotations
 from contextlib import contextmanager
 from typing import List
 
+from horovod_tpu.common import trace as htrace
 from horovod_tpu.common.message import Response
 from horovod_tpu.common.metrics import NOOP_METRIC
 from horovod_tpu.common.status import Status
 from horovod_tpu.common.tensor_table import TensorTableEntry
-from horovod_tpu.common.timeline import NOOP_TIMELINE
+from horovod_tpu.common.timeline import (
+    ACT_MEMCPY_IN_FUSION_BUFFER, NOOP_TIMELINE,
+)
 
 
 class CollectiveBackend:
@@ -57,18 +60,25 @@ class CollectiveBackend:
             "payload bytes moved through this data plane")
 
     @contextmanager
-    def activity(self, names, act, enabled: bool = True):
-        """Timeline sub-activity span; the finally guarantees the span
-        closes even when the wrapped transport/pack raises, so an error
+    def activity(self, names, act, enabled: bool = True, nbytes: int = 0):
+        """A fusion pack or unpack: the program's ``hvd.pack`` /
+        ``hvd.unpack`` span (yielded: a pack sets its ``nbytes`` once
+        it has the buffer) and the timeline's sub-activity, which takes
+        the span's clock readings. The finally guarantees both close
+        even when the wrapped transport/pack raises, so an error
         mid-batch cannot misnest every later event in the trace."""
         if not enabled:
-            yield
+            yield htrace.NOOP_SPAN
             return
-        self.timeline.activity_start_all(names, act)
+        sp = htrace.span("hvd.pack" if act == ACT_MEMCPY_IN_FUSION_BUFFER
+                         else "hvd.unpack", nbytes=nbytes)
+        sp.__enter__()
+        self.timeline.activity_start_all(names, act, sp.start_ns)
         try:
-            yield
+            yield sp
         finally:
-            self.timeline.activity_end_all(names)
+            sp.__exit__(None, None, None)
+            self.timeline.activity_end_all(names, sp.end_ns)
 
     def enabled(self, entries: List[TensorTableEntry],
                 response: Response) -> bool:

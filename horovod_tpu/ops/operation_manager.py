@@ -10,9 +10,9 @@ local (size-1).
 
 from __future__ import annotations
 
-import time
 from typing import List
 
+from horovod_tpu.common import trace as htrace
 from horovod_tpu.common.message import Response, ResponseType
 from horovod_tpu.common.status import Status
 from horovod_tpu.common.tensor_table import TensorTableEntry
@@ -24,14 +24,16 @@ class OperationManager:
         self._backends = backends
         self._metrics_on = False
         self._fusion_threshold_fn = None
+        self._m_wall = {}
 
     def attach_metrics(self, registry, fusion_threshold_fn=None) -> None:
         """Install the per-op-type instrumentation the runtime's
         registry provides (the disabled registry hands back no-op
         metrics, keeping every call free): op counts, payload bytes
         per collective kind, collective wall-time histograms (issue
-        time for async backends — completion rides the finalizer), and
-        the fusion-buffer fill ratio against the world threshold.
+        time for async backends — completion rides the finalizer;
+        observed by the ``hvd.execute`` span, not beside it), and the
+        fusion-buffer fill ratio against the world threshold.
         Backends get their own per-plane counters via
         CollectiveBackend.attach_metrics."""
         from horovod_tpu.common.metrics import RATIO_BUCKETS
@@ -118,11 +120,24 @@ class OperationManager:
 
     def execute(self, entries: List[TensorTableEntry],
                 response: Response) -> Status:
+        """Run the batch on the first enabled backend, inside the
+        program's ``hvd.execute`` span."""
         backend = self._pick(entries, response)
         rt = response.response_type
-        if not self._metrics_on:
+        # The span is the backend's call alone, the window
+        # hvd_collective_seconds has always timed: counting comes first.
+        sp = htrace.span("hvd.execute", n=len(entries),
+                         also=self._m_wall.get(rt))
+        if self._metrics_on or sp.on:
+            nbytes = sum(getattr(e.tensor, "nbytes", 0) for e in entries)
+            sp.nbytes = nbytes
+            sp.tag = f"{rt.name.lower()}/{backend.name}"
+            if self._metrics_on:
+                self._count(backend, rt, len(entries), nbytes)
+        with sp:
             return self._dispatch(backend, rt, entries, response)
-        nbytes = sum(getattr(e.tensor, "nbytes", 0) for e in entries)
+
+    def _count(self, backend, rt, n_entries: int, nbytes: int) -> None:
         op_counter = self._m_ops.get(rt)
         if op_counter is not None:
             op_counter.inc()
@@ -131,17 +146,10 @@ class OperationManager:
             byte_counter.inc(nbytes)
         backend.m_ops.inc()
         backend.m_bytes.inc(nbytes)
-        if len(entries) > 1 and self._fusion_threshold_fn is not None:
+        if n_entries > 1 and self._fusion_threshold_fn is not None:
             threshold = self._fusion_threshold_fn()
             if threshold > 0:
                 self._m_fill.observe(nbytes / threshold)
-        t0 = time.perf_counter()
-        try:
-            return self._dispatch(backend, rt, entries, response)
-        finally:
-            wall = self._m_wall.get(rt)
-            if wall is not None:
-                wall.observe(time.perf_counter() - t0)
 
     @staticmethod
     def _dispatch(backend, rt, entries, response) -> Status:

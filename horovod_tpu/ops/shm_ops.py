@@ -321,11 +321,13 @@ class ShmBackend(CollectiveBackend):
         dtype = arrays[0].dtype
         names = [e.tensor_name for e in entries]
         multi = len(entries) > 1  # single-tensor pack is a view
-        with self.activity(names, ACT_MEMCPY_IN_FUSION_BUFFER, multi):
+        with self.activity(names, ACT_MEMCPY_IN_FUSION_BUFFER,
+                           multi) as sp:
             # Arena-safe: every shm result is copied out of the
             # segment before entries see it, so outputs never alias
             # the pack buffer.
             fused, _ = _pack_fused(arrays, response, self._arena)
+            sp.nbytes = fused.nbytes
         if fused.size == 0:
             # Nothing to move; every rank short-circuits identically
             # (sizes are negotiated), so no control rounds are owed.
@@ -357,7 +359,8 @@ class ShmBackend(CollectiveBackend):
                 ctl.gather_data(b"")
                 ctl.broadcast_data(None)
                 result = self._view(out_off, dtype, fused.size).copy()
-        with self.activity(names, ACT_MEMCPY_OUT_FUSION_BUFFER, multi):
+        with self.activity(names, ACT_MEMCPY_OUT_FUSION_BUFFER, multi,
+                           result.nbytes):
             _unpack_fused(entries, arrays, result, response)
         return Status.OK()
 
@@ -571,8 +574,10 @@ class ShmBackend(CollectiveBackend):
         out_off = ctl.size * stride
         total_elems = sum(rank_counts)
         multi = len(entries) > 1
-        with self.activity(names, ACT_MEMCPY_IN_FUSION_BUFFER, multi):
+        with self.activity(names, ACT_MEMCPY_IN_FUSION_BUFFER,
+                           multi) as sp:
             packed = _pack_flat(arrays, self._arena)
+            sp.nbytes = packed.nbytes
         dtype = packed.dtype
         if ctl.is_coordinator:
             ctl.gather_data(b"")
@@ -593,7 +598,8 @@ class ShmBackend(CollectiveBackend):
             ctl.gather_data(b"")
             ctl.broadcast_data(None)
             result = self._view(out_off, dtype, total_elems).copy()
-        with self.activity(names, ACT_MEMCPY_OUT_FUSION_BUFFER, multi):
+        with self.activity(names, ACT_MEMCPY_OUT_FUSION_BUFFER, multi,
+                           result.nbytes):
             _unpack_allgather(entries, arrays, result, comp,
                               rank_counts)
         return Status.OK()

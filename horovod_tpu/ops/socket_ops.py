@@ -367,9 +367,11 @@ class SocketBackend(CollectiveBackend):
         # arena-aliased output is then silently overwritten by the
         # next op's pack.
         use_arena = self._zero_copy and ring is None
-        with self.activity(names, ACT_MEMCPY_IN_FUSION_BUFFER, multi):
+        with self.activity(names, ACT_MEMCPY_IN_FUSION_BUFFER,
+                           multi) as sp:
             fused, fresh = _pack_fused(
                 arrays, response, self._arena if use_arena else None)
+            sp.nbytes = fused.nbytes
         (self._m_ring_ops if ring is not None
          else self._m_star_ops).inc()
         wire = response.wire_dtype
@@ -377,7 +379,7 @@ class SocketBackend(CollectiveBackend):
             result = self._compressed_allreduce(fused, wire, ring,
                                                 names)
             with self.activity(names, ACT_MEMCPY_OUT_FUSION_BUFFER,
-                               multi):
+                               multi, result.nbytes):
                 _unpack_fused(entries, arrays, result, response)
             return Status.OK()
         if ring is not None:
@@ -420,7 +422,8 @@ class SocketBackend(CollectiveBackend):
             else:
                 result = _np_from_bytes(ctl.broadcast_data(None), dtype)
 
-        with self.activity(names, ACT_MEMCPY_OUT_FUSION_BUFFER, multi):
+        with self.activity(names, ACT_MEMCPY_OUT_FUSION_BUFFER, multi,
+                           result.nbytes):
             _unpack_fused(entries, arrays, result, response)
         return Status.OK()
 
@@ -517,10 +520,12 @@ class SocketBackend(CollectiveBackend):
         multi = len(entries) > 1  # single-tensor pack is a view
         comp, rank_counts = _allgather_layout(entries, arrays, response,
                                               ctl.size)
-        with self.activity(names, ACT_MEMCPY_IN_FUSION_BUFFER, multi):
+        with self.activity(names, ACT_MEMCPY_IN_FUSION_BUFFER,
+                           multi) as sp:
             packed = _pack_flat(
                 arrays, self._arena if (self._zero_copy and multi)
                 else None)
+            sp.nbytes = packed.nbytes
         wire = response.wire_dtype
         if wire != _wd.WIRE_NONE:
             result = self._compressed_allgather(packed, wire,
@@ -554,7 +559,8 @@ class SocketBackend(CollectiveBackend):
             else:
                 result = _np_from_bytes(ctl.broadcast_data(None),
                                         packed.dtype)
-        with self.activity(names, ACT_MEMCPY_OUT_FUSION_BUFFER, multi):
+        with self.activity(names, ACT_MEMCPY_OUT_FUSION_BUFFER, multi,
+                           result.nbytes):
             _unpack_allgather(entries, arrays, result, comp,
                               rank_counts)
         return Status.OK()

@@ -32,6 +32,7 @@ import numpy as np
 
 from horovod_tpu.common import lockdep
 from horovod_tpu.common import logging as hlog
+from horovod_tpu.common import trace as htrace
 from horovod_tpu.common.invariants import world_coherent
 from horovod_tpu.common.message import Response
 from horovod_tpu.common.status import Status
@@ -313,18 +314,20 @@ class XlaMeshBackend(CollectiveBackend):
         if fin is None:
             return Status.OK()
         outs = [e.output for e in entries]
+        cycle = htrace.current_cycle()   # of the batch's hvd.execute
 
         def finalize():
             st = self._observe(outs)
-            for e in entries:
-                if e.callback:
-                    try:
-                        e.callback(st)
-                    except Exception as ex:
-                        # One adapter callback must not starve the rest
-                        # of the batch of their completions.
-                        hlog.error(f"completion callback for "
-                                   f"{e.tensor_name} raised: {ex!r}")
+            with htrace.span("hvd.complete", cycle, len(entries)):
+                for e in entries:
+                    if e.callback:
+                        try:
+                            e.callback(st)
+                        except Exception as ex:
+                            # One adapter callback must not starve the
+                            # rest of the batch of their completions.
+                            hlog.error(f"completion callback for "
+                                       f"{e.tensor_name} raised: {ex!r}")
 
         if not fin.submit(finalize):
             # Draining: observe readiness inline; the loop fires the

@@ -163,6 +163,7 @@ def _flash_bhsd(q, k, v, offsets, causal: bool, block_q: int,
             jax.ShapeDtypeStruct((bh, 1, seq_q), jnp.float32),
         ),
         interpret=interpret,
+        name="flash_fwd",
         cost_estimate=pl.CostEstimate(
             flops=4 * bh * seq_q * seq_k * d // (2 if causal else 1),
             bytes_accessed=(2 * q.size + k.size + v.size)
@@ -306,6 +307,7 @@ def _flash_bwd_bhsd(q, k, v, do, lse, delta, offsets, causal: bool,
         ),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
+        name="flash_bwd_dq",
         cost_estimate=pl.CostEstimate(
             flops=6 * bh * seq_q * seq_k * d // (2 if causal else 1),
             bytes_accessed=(2 * q.size + k.size + v.size)
@@ -335,6 +337,7 @@ def _flash_bwd_bhsd(q, k, v, do, lse, delta, offsets, causal: bool,
         out_shape=(jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)),
         interpret=interpret,
+        name="flash_bwd_dkv",
         cost_estimate=pl.CostEstimate(
             flops=10 * bh * seq_q * seq_k * d // (2 if causal else 1),
             bytes_accessed=(q.size + 2 * (k.size + v.size))

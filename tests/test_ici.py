@@ -210,29 +210,6 @@ class TestAdoptPacked:
         assert out[0].flags["C_CONTIGUOUS"]
 
 
-class TestScalingEfficiencyFeed:
-    def test_note_and_read_back(self):
-        from horovod_tpu.common import metrics as hmetrics
-        hmetrics.note_scaling_efficiency(16, 0.42)
-        assert hmetrics.scaling_efficiencies()[16] == 0.42
-
-    def test_runtime_exports_gauge_family(self, monkeypatch):
-        """An armed runtime registry mirrors the MULTICHIP harness's
-        verdicts as hvd_scaling_efficiency{world_size="N"} gauges on
-        its next snapshot."""
-        monkeypatch.setenv("HOROVOD_TPU_METRICS", "1")
-        from horovod_tpu.common import metrics as hmetrics
-        import horovod_tpu as hvd
-        hmetrics.note_scaling_efficiency(4, 0.5)
-        hvd.init()
-        try:
-            snap = hvd.metrics()["local"]
-            rec = snap['hvd_scaling_efficiency{world_size="4"}']
-            assert rec["v"] == 0.5
-        finally:
-            hvd.shutdown()
-
-
 # -- in-process IciPlane over the conftest-forced 8-device mesh -------------
 
 def _plane(max_devices=0):
