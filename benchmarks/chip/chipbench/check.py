@@ -125,14 +125,17 @@ class StagedGradient:
 
 
 def reference_steps(stages: dict, make_state, shards: Sequence[tuple],
-                    lr: float, momentum: float, steps: int) -> dict:
+                    lr: float, momentum: float, steps: int,
+                    mean_loss: bool = False) -> dict:
     """Follow ``steps`` steps of SGD with momentum in float32 under
     ``highest`` matmul precision, from the state ``make_state()``
     gives (called again at the end for the change: the steps update in
     place). ``shards`` are the batches of the ranks in turn: the
     gradient is the mean of the shards' gradients, the loss the first
     shard's (each rank reports its own), and the running statistics
-    the first shard's too."""
+    the first shard's too. With ``mean_loss`` the shards are one
+    in-jit batch's rows, a chip's share each, and the loss is their
+    mean, as the step over the mesh reports it."""
     grad_fn = StagedGradient(stages)
 
     @functools.partial(jax.jit, donate_argnums=(0, 1))
@@ -152,16 +155,19 @@ def reference_steps(stages: dict, make_state, shards: Sequence[tuple],
     losses, grad_norms = [], None
     with jax.default_matmul_precision("highest"):
         for step in range(steps):
-            total, new_aux = None, None
+            total, new_aux, shard_losses = None, None, []
             for i, shard in enumerate(shards):
                 loss, aux_i, grads = grad_fn(params, aux, shard)
+                shard_losses.append(loss)
                 if i == 0:
-                    losses.append(float(loss))
                     new_aux = aux_i
                 total = grads if total is None else add(total, grads)
                 del grads
             params, trace = apply(params, trace, total)
             del total
+            losses.append(
+                sum(float(l) for l in shard_losses) / len(shards)
+                if mean_loss else float(shard_losses[0]))
             aux = new_aux
             if step == 0:
                 grad_norms = leaf_norms(trace)

@@ -328,25 +328,43 @@ class Program:
                 "update_norms": update_norms, "state": state}
 
     def reference(self) -> dict:
-        """The plain reference's first steps, over every rank's batch."""
+        """The plain reference's first steps, over every rank's batch.
+        An in-jit step over several chips reports the mean loss of all
+        its rows and applies the mean gradient: the reference takes
+        the rows a chip's share at a time (the whole batch in float32
+        does not fit one chip beside the parameters, and the mean over
+        rows is linear), which holds for a model whose rows do not meet
+        before the loss."""
         shards = [self.make_batch(r) for r in range(self.size)]
+        whole = self.mode == "injit" and self.chips > 1
+        if whole:
+            if self.shapes["aux"]:
+                raise ValueError(
+                    "statistics over all the rows of a mesh (batch norm "
+                    "over the data axis) cannot be followed a chip's "
+                    "share at a time: the reference has no such path yet")
+            n = self.sz["per_chip_batch"]
+            shards = [tuple(a[i * n:(i + 1) * n] for a in shards[0])
+                      for i in range(self.chips)]
         return check.reference_steps(
             self.family.reference_stages(self.sz), self.make_model_state,
-            shards, self.lr, self.momentum, CHECK_STEPS)
+            shards, self.lr, self.momentum, CHECK_STEPS, mean_loss=whole)
 
 
 # -- one rank's run ---------------------------------------------------------
 
-def device_line(chips: int, rehearse: bool) -> dict:
+def device_line(traffic: dict, rehearse: bool) -> dict:
+    """The devices of this process, which must hold the mix's chips: a
+    rank of a launched world holds one, a single process all of them."""
+    chips = 1 if traffic["ranks"] > 1 else traffic["chips"]
     devices = jax.local_devices()
-    if not rehearse:
-        if devices[0].platform != "tpu":
-            sys.exit(f"chipbench: found no TPU (platform "
-                     f"{devices[0].platform!r}); the benchmark measures "
-                     f"the chip and does not fall back")
-        if len(devices) < chips:
-            sys.exit(f"chipbench: the cell needs {chips} chip(s) in this "
-                     f"process, found {len(devices)}")
+    if not rehearse and devices[0].platform != "tpu":
+        sys.exit(f"chipbench: found no TPU (platform "
+                 f"{devices[0].platform!r}); the benchmark measures "
+                 f"the chip and does not fall back")
+    if len(devices) < chips:
+        sys.exit(f"chipbench: the cell needs {chips} chip(s) in this "
+                 f"process, found {len(devices)}")
     return {"platform": devices[0].platform, "kind": devices[0].device_kind,
             "count": len(devices)}
 
@@ -433,7 +451,7 @@ def run_rank(args, manifest: dict) -> dict:
                  f"world has {size}")
     clock.mark("world_start")
 
-    device = device_line(1 if ranks > 1 else traffic["chips"], args.rehearse)
+    device = device_line(traffic, args.rehearse)
     jax.block_until_ready(jnp.zeros(8) + 1)
     if size > 1:
         hvd.barrier()       # until every rank has its chip

@@ -18,7 +18,12 @@ def percentile(values, q: float) -> float:
 
 
 def mfu(ctx):
-    """Model FLOPs the rate implies over the chip's published peak."""
+    """Model FLOPs the rate implies over the chip's published peak. A
+    rehearsal has no chip and so no peak, and its tiny sizes may be
+    none the family keeps a FLOP count for: nothing to read. On the
+    chip a size without a count stops the run, in the family's words."""
+    if ctx["peak"] is None:
+        return None
     flops = ctx["family"].flops_per_sample(ctx["sz"])
     return 100.0 * ctx["rate"] * flops / ctx["peak"].bf16_flops
 

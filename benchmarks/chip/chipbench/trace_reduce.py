@@ -35,18 +35,39 @@ def find_xplane(trace_dir: str) -> str:
 
 
 TARGET = re.compile(r'custom_call_target="([^"]+)"')
+COLLECTIVES = ("all-reduce", "reduce-scatter", "all-gather",
+               "collective-permute")
+# the opcode stands before its operands' bracket; an operand named
+# ``%all-reduce.5`` is followed by a comma or the closing bracket
+COLLECTIVE_OP = re.compile(
+    r"(?<![%\w.\-])((?:" + "|".join(COLLECTIVES) + r")(?:-start|-done)?)\(")
 
 
 def short_name(text: str) -> str:
     """``fusion.2031`` from the HLO instruction the profiler names a
     device event by (``%fusion.2031 = f32[...] fusion(...)``); a custom
     call keeps its target, which is how a Pallas kernel is told from
-    the compiler's own calls: ``custom-call.7[tpu_custom_call]``."""
+    the compiler's own calls: ``custom-call.7[tpu_custom_call]``. A
+    collective whose name does not say what it is keeps its opcode:
+    JAX's ``psum`` of one array comes out as ``psum.797[all-reduce]``,
+    a combined one as ``all-reduce.58``."""
     if not text.startswith("%"):
         return text
     name = text[1:].split(" ", 1)[0]
     target = TARGET.search(text)
-    return f"{name}[{target.group(1)}]" if target else name
+    if target:
+        return f"{name}[{target.group(1)}]"
+    op = COLLECTIVE_OP.search(text)
+    if op and not name.startswith(op.group(1)):
+        return f"{name}[{op.group(1)}]"
+    return name
+
+
+def is_collective(name: str) -> bool:
+    """An event of :func:`short_name`'s that is a collective op, by its
+    own name or by the opcode kept behind it."""
+    return name.startswith(COLLECTIVES) or (
+        name.endswith("]") and name.rsplit("[", 1)[-1].startswith(COLLECTIVES))
 
 
 def load_xplane(path: str, keep_host=lambda name: name.startswith(

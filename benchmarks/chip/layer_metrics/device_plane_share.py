@@ -3,7 +3,7 @@ from chipbench import readers
 
 LAYER = "Data plane"
 UNIT = "%"
-MOVES = "images_per_s_chip.eager"
+MOVES = "images_per_s_chip"
 
 
 def read(ctx):

@@ -3,7 +3,7 @@ from chipbench import readers
 
 LAYER = "Eager adapter and cycle"
 UNIT = "ms"
-MOVES = "images_per_s_chip.eager"
+MOVES = "images_per_s_chip"
 
 
 def read(ctx):

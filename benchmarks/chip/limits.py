@@ -46,7 +46,7 @@ def main() -> None:
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         manifest = json.load(f)
     spec = harness.resolve_cell(manifest, args.workload, args.rehearse)
-    harness.device_line(1, args.rehearse)
+    harness.device_line(spec["traffic"], args.rehearse)
     hvd.init()
     rank, size = hvd.rank(), hvd.size()
     seeds = [int(s) for s in args.seeds.split(",") if s]

@@ -20,6 +20,7 @@ T0 = time.time()
 
 import argparse  # noqa: E402
 import json  # noqa: E402
+import math  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
 import signal  # noqa: E402
@@ -73,7 +74,26 @@ def last_line(args, manifest, results) -> dict:
         device["busy_s"] = sum(r["busy_s"] for r in results) / len(results)
         device["window_s"] = first["traced_window_s"]
         line["breakdown"] = first["breakdown"]
+    line["checks"] = compared(results)
     return line
+
+
+def compared(results) -> dict:
+    """Each number the check compared beside its limit, ``{name:
+    [value, limit]}``: rank 0's under their own names, another rank's
+    with ``.r<rank>`` behind them. Also the run's last lines on
+    standard error."""
+    out = {}
+    for r in results:
+        for name, c in r["checks"].items():
+            value = c["value"] if math.isfinite(c["value"]) \
+                else repr(c["value"])
+            out[name + (f".r{r['rank']}" if r["rank"] else "")] = [
+                value, c["limit"]]
+            print(f"chipbench: check: rank {r['rank']} {name} {value} "
+                  f"limit {c['limit']} {'ok' if c['ok'] else 'NOT OK'}",
+                  file=sys.stderr, flush=True)
+    return out
 
 
 def run_world(args, ranks: int) -> list:
@@ -141,6 +161,12 @@ def main(argv=None) -> None:
     print(f"chipbench: runtime_env {traffic.get('runtime_env')}", flush=True)
     os.environ.update(traffic.get("runtime_env", {}))
     ranks = traffic["ranks"]
+    force = f"--xla_force_host_platform_device_count={traffic['chips']}"
+    if (args.rehearse and ranks == 1 and traffic["chips"] > 1
+            and force.split("=")[0] not in os.environ.get("XLA_FLAGS", "")):
+        # one process over several chips rehearses on as many CPU devices
+        os.environ["XLA_FLAGS"] = (
+            os.environ.get("XLA_FLAGS", "") + " " + force).strip()
     if ranks > 1:
         results = run_world(args, ranks)
     else:

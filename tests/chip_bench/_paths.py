@@ -17,15 +17,51 @@ def manifest() -> dict:
         return json.load(f)
 
 
-def manifest_with_kept() -> dict:
-    """BENCHMARK.json with the entries that ``kept/eager-cells.json``
-    holds for a later PR added to it: the eager cell of one rank."""
-    m = manifest()
-    with open(os.path.join(BENCH, "kept", "eager-cells.json")) as f:
-        kept = json.load(f)
-    for key in ("workloads", "end_to_end", "per_layer"):
-        m[key] = m[key] + kept[key]
+def kept(name: str) -> dict:
+    with open(os.path.join(BENCH, "kept", name)) as f:
+        return json.load(f)
+
+
+def apply_entries(m: dict, entries: dict) -> dict:
+    """``m`` with a kept file's ``entries_for_BENCHMARK.json`` applied
+    as that key says: each cell appended, and its name appended to the
+    ``workloads`` list of each metric named. A cell or a name that is
+    there already stays as it is, once."""
+    have = {w["name"] for w in m["workloads"]}
+    new = [w for w in entries["workloads"] if w["name"] not in have]
+    m["workloads"] = m["workloads"] + new
+    metrics = {x["name"]: x for x in m["end_to_end"] + m["per_layer"]}
+    for name in entries["append_cell_to"]:
+        lists = metrics[name]["workloads"]
+        lists += [w["name"] for w in entries["workloads"]
+                  if w["name"] not in lists]
     return m
+
+
+def merge_kept(m: dict, kept: dict) -> dict:
+    """``m`` with a kept file's entries merged in by name: a cell or a
+    metric is added only where ``m`` has none of that name, and a metric
+    both have gets the union of their ``workloads``. So a kept entry
+    that has since moved into BENCHMARK.json neither doubles nor has to
+    leave ``kept/``."""
+    if "entries_for_BENCHMARK.json" in kept:
+        apply_entries(m, kept["entries_for_BENCHMARK.json"])
+    for key in ("workloads", "end_to_end", "per_layer"):
+        have = {x["name"]: x for x in m[key]}
+        for entry in kept[key]:
+            mine = have.get(entry["name"])
+            if mine is None:
+                m[key] = m[key] + [entry]
+            elif "workloads" in mine and "workloads" in entry:
+                mine["workloads"] += [c for c in entry["workloads"]
+                                      if c not in mine["workloads"]]
+    return m
+
+
+def manifest_with_kept() -> dict:
+    """BENCHMARK.json with what ``kept/eager-cells.json`` holds for a
+    later PR merged into it: the eager cell of one rank."""
+    return merge_kept(manifest(), kept("eager-cells.json"))
 
 
 def checkout_with(m: dict, root) -> None:

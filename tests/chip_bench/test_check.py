@@ -30,6 +30,7 @@ def world():
 def gaps(spec, seed, param_dtype=None, control_leaves=""):
     program = harness.Program(spec, seed, 1, param_dtype=param_dtype,
                               control_leaves=control_leaves)
+    assert program.chips == spec["cell"]["chips"]
     state = program.make_state()
     batch = program.make_batch(0, program.batch_sharding)
     program.compile(state, batch)
@@ -40,6 +41,7 @@ def gaps(spec, seed, param_dtype=None, control_leaves=""):
 
 @pytest.mark.parametrize("cell, leaves", [
     ("lm-injit-1chip", ("", "lm_head")),    # all parameters; the head alone
+    ("lm-injit-4chip", ("lm_head",)),   # four devices, rows in four groups
     ("resnet50-eager-1rank", ("",)), ("resnet50-injit-1chip", ("",))])
 def test_program_passes_and_bf16_parameters_fail(world, cell, leaves):
     import jax.numpy as jnp
@@ -111,3 +113,26 @@ def test_an_exchange_left_out_is_not_correct(monkeypatch):
     result = run_in_process("resnet50-eager-1rank", monkeypatch)
     assert result["correct"] is False
     assert not result["checks"]["grad_norm_gap"]["ok"]
+
+
+def test_a_chips_rows_left_out_of_a_step_over_four_are_not_correct(
+        monkeypatch):
+    """The step over four devices as it is, fed the first chip's rows
+    in the last chip's place: a part of the batch never reaches the
+    gradients' mean, and the first gradient's norm gives it away."""
+    import jax
+    import jax.numpy as jnp
+    real = harness.Program.step
+
+    def short(self, state, batch, stop=0.0):
+        n = self.sz["per_chip_batch"]
+        batch = tuple(jax.device_put(
+            jnp.concatenate([a[:-n], a[:n]]), a.sharding) for a in batch)
+        return real(self, state, batch, stop)
+
+    sound = run_in_process("lm-injit-4chip", monkeypatch)
+    assert sound["correct"] and sound["device"]["count"] >= 4
+    result = run_in_process("lm-injit-4chip", monkeypatch, step=short)
+    assert result["correct"] is False
+    assert not result["checks"]["grad_norm_gap"]["ok"]
+    assert result["checks"]["replay_loss_gap"]["ok"]

@@ -12,7 +12,7 @@ from . import _paths
 NEW_METRIC = '''"""Negotiation cycles the runtime ran a step."""
 LAYER = "Eager adapter and cycle"
 UNIT = "count"
-MOVES = "images_per_s_chip.eager"
+MOVES = "images_per_s_chip"
 
 
 def read(ctx):
@@ -63,7 +63,7 @@ def test_new_files_and_entries_alone_make_a_new_cell(tmp_path):
     m["per_layer"].append({
         "name": "cycles_per_step", "unit": "count", "better": "lower",
         "source": "program_counter", "layer": "Eager adapter and cycle",
-        "moves": "images_per_s_chip.eager", "workloads": [cell]})
+        "moves": "images_per_s_chip", "workloads": [cell]})
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(m))
 
     env = dict(os.environ, JAX_PLATFORMS="cpu")

@@ -66,3 +66,23 @@ def test_every_peak_names_its_source(kind):
     peak = peaks.PEAKS[kind]
     assert peak.bf16_flops > 0 and peak.hbm_bytes > 0
     assert "Google Cloud TPU documentation" in peak.source
+
+
+def test_mfu_has_nothing_to_read_at_a_rehearsal_and_raises_on_the_chip():
+    """A rehearsal has no peak and sizes no FLOP count is kept for:
+    ``None``, for every family. With a chip's peak a ResNet that is not
+    ResNet-50 at 224 stops the run, and ResNet-50 reads its share."""
+    from chipbench import harness, readers
+    resnet = harness.load_module("families", "resnet")
+    lm = harness.load_module("families", "transformer_lm")
+    tiny = {"stages": [1, 1], "filters": 8, "image": 32}
+    full = {"stages": [3, 4, 6, 3], "filters": 64, "image": 224}
+    ctx = {"peak": None, "rate": 2596.9, "family": resnet, "sz": tiny}
+    assert readers.mfu(ctx) is None
+    assert readers.mfu(dict(ctx, sz=full)) is None
+    assert readers.mfu({"peak": None, "rate": 1.0, "family": lm,
+                        "sz": {}}) is None
+    ctx["peak"] = peaks.chip_peak("TPU v5 lite")
+    with pytest.raises(ValueError, match="ResNet-50's at 224x224"):
+        readers.mfu(ctx)
+    assert readers.mfu(dict(ctx, sz=full)) == pytest.approx(32.34, abs=0.01)

@@ -1,7 +1,9 @@
 """BENCHMARK.json is well formed by the contract's own rules, and every
 name in it leads to a file of its own. The entries kept for a later PR
 (``kept/eager-cells.json``) are held to the same rules, entry by entry,
-so that they can be added as they are."""
+so that they can be added as they are. Each rule is a function of a
+manifest, so that a test can hold a manifest of its own making to all
+of them (:func:`hold_to_every_rule`)."""
 
 import ast
 import json
@@ -18,60 +20,65 @@ NAME = re.compile(r"[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}\Z")
 UNIT = re.compile(r"[A-Za-z0-9_/%.\-]{1,16}\Z")
 PATH = re.compile(r"[A-Za-z0-9_.\-/]{1,200}\Z")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
-E2E = {m["name"]: m for m in KEPT["end_to_end"]}
-LAYERS = {m["name"]: m for m in KEPT["per_layer"]}
-CELLS = {w["name"]: w for w in KEPT["workloads"]}
-CONFIGS = {c["name"]: c for c in M["configs"]}
 # A size whose change would make another model of it: never in `reduced`.
 WIDTHS = re.compile(r"(hidden|intermediate|latent|state|projection)_size"
                     r"|_dim\Z|_rank\Z|head_size|head_dim|expansion"
                     r"|experts_per_tok")
 
 
-def cells_of(metric: dict):
-    return metric.get("workloads") or list(CELLS)
+def by_name(m: dict, key: str) -> dict:
+    return {x["name"]: x for x in m[key]}
 
 
-def test_top_level_keys_are_exactly_the_contracts():
-    assert set(M) == {"command", "paths", "run_seconds", "configs",
+def cells_of(m: dict, metric: dict):
+    return metric.get("workloads") or [w["name"] for w in m["workloads"]]
+
+
+# -- the rules, each of one manifest ----------------------------------------
+
+def rule_top_level(m):
+    assert set(m) == {"command", "paths", "run_seconds", "configs",
                       "workloads", "end_to_end", "per_layer"}
-    assert isinstance(M["run_seconds"], int) and 1 <= M["run_seconds"] <= 51
-    assert len(json.dumps(M)) < 64 * 1024
+    assert isinstance(m["run_seconds"], int) and 1 <= m["run_seconds"] <= 51
+    assert len(json.dumps(m)) < 64 * 1024
 
 
-def test_a_full_check_of_24_cells_fits_the_drivers_day():
+def rule_a_full_check_of_24_cells_fits_the_drivers_day(m):
     runs = 2 + 14 * 24
-    assert runs * (M["run_seconds"] + 60) + 24 * 2 * 90 + 1200 <= 43200
+    assert runs * (m["run_seconds"] + 60) + 24 * 2 * 90 + 1200 <= 43200
 
 
-def test_the_manifest_stands_without_the_kept_entries():
-    cells = [w["name"] for w in M["workloads"]]
-    metrics = M["end_to_end"] + M["per_layer"]
-    for m in metrics:
-        assert all(c in cells for c in m.get("workloads", [])), m["name"]
-        if "moves" in m:
-            assert m["moves"] in [e["name"] for e in M["end_to_end"]]
+def rule_stands_alone(m):
+    cells = [w["name"] for w in m["workloads"]]
+    for x in m["end_to_end"] + m["per_layer"]:
+        assert all(c in cells for c in x.get("workloads", [])), x["name"]
+        if "moves" in x:
+            assert x["moves"] in by_name(m, "end_to_end")
     for cell in cells:
-        reported = [m["name"] for m in M["end_to_end"]
-                    if cell in m.get("workloads", cells)]
+        reported = [x["name"] for x in m["end_to_end"]
+                    if cell in x.get("workloads", cells)]
         assert "setup_s" in reported and len(reported) >= 2
-        assert any(cell in m.get("workloads", cells)
-                   for m in M["per_layer"])
-    assert {c["name"] for c in M["configs"]} == {
-        w["config"] for w in M["workloads"]}
-    assert len(M["end_to_end"]) <= 5        # four and setup_s (ISSUE 23)
+        assert any(cell in x.get("workloads", cells)
+                   for x in m["per_layer"])
+    assert {c["name"] for c in m["configs"]} == {
+        w["config"] for w in m["workloads"]}
+    assert len(m["end_to_end"]) <= 5        # four and setup_s (ISSUE 23)
 
 
-def test_command_names_no_file_outside_paths():
-    assert 1 <= len(M["command"]) <= 32
-    for word in M["command"][1:]:
+def rule_setup_s_is_reported_by_every_cell(m):
+    assert "workloads" not in by_name(m, "end_to_end")["setup_s"]
+    assert 1 <= len(m["end_to_end"]) <= 16
+
+
+def rule_command(m):
+    assert 1 <= len(m["command"]) <= 32
+    for word in m["command"][1:]:
         assert not word.startswith("/") and ".." not in word.split("/")
         if os.path.exists(os.path.join(_paths.ROOT, word)):
-            assert any(word.startswith(p + "/") for p in M["paths"])
+            assert any(word.startswith(p + "/") for p in m["paths"])
 
 
-@pytest.mark.parametrize("path", M["paths"])
-def test_paths_are_directories_of_the_benchmarks_own(path):
+def rule_path(m, path):
     assert PATH.match(path)
     assert os.path.isdir(os.path.join(_paths.ROOT, path))
     for base, _, files in os.walk(os.path.join(_paths.ROOT, path)):
@@ -82,16 +89,16 @@ def test_paths_are_directories_of_the_benchmarks_own(path):
             assert PATH.match(rel), rel
 
 
-@pytest.mark.parametrize("name", sorted(CONFIGS))
-def test_config_entry_and_file(name):
-    c = CONFIGS[name]
+def rule_config(m, name):
+    configs = by_name(m, "configs")
+    c = configs[name]
     assert set(c) == {"name", "source", "file", "reduced", "why"}
     assert NAME.match(name) and len(c["reduced"]) <= 16
     for text in (c["source"], c["why"]):
         assert 1 <= len(text) <= 200 and "\n" not in text and "\t" not in text
-    assert any(c["file"].startswith(p + "/") for p in M["paths"])
-    assert sum(1 for o in CONFIGS.values() if o["file"] == c["file"]) == 1
-    assert any(w["config"] == name for w in CELLS.values())
+    assert any(c["file"].startswith(p + "/") for p in m["paths"])
+    assert sum(1 for o in configs.values() if o["file"] == c["file"]) == 1
+    assert any(w["config"] == name for w in m["workloads"])
     with open(os.path.join(_paths.ROOT, c["file"])) as f:
         data = json.load(f)
     assert sorted(data["reduced"]) == sorted(c["reduced"])
@@ -104,49 +111,51 @@ def test_config_entry_and_file(name):
     assert "rehearse" in data and "assumed" in data
 
 
-@pytest.mark.parametrize("name", sorted(CELLS))
-def test_cell_entry_and_traffic_file(name):
-    w = CELLS[name]
+def rule_cell(m, name):
+    cells = by_name(m, "workloads")
+    w = cells[name]
     assert set(w) == {"name", "config", "traffic", "chips", "why"}
     assert NAME.match(name) and NAME.match(w["traffic"])
-    assert w["config"] in CONFIGS and w["chips"] in (1, 4)
+    assert w["config"] in by_name(m, "configs") and w["chips"] in (1, 4)
     assert 1 <= len(w["why"]) <= 200 and "\n" not in w["why"]
-    assert sum(1 for o in CELLS.values() if (o["config"], o["traffic"])
+    assert sum(1 for o in cells.values() if (o["config"], o["traffic"])
                == (w["config"], w["traffic"])) == 1
     with open(os.path.join(_paths.BENCH, "traffic",
                            w["traffic"] + ".json")) as f:
         traffic = json.load(f)
     assert traffic["chips"] == w["chips"]
     assert traffic["mode"] in ("injit", "eager") and traffic["ranks"] >= 1
-    reported = [m for m in E2E.values() if name in cells_of(m)]
-    assert "setup_s" in {m["name"] for m in reported}
+    reported = [x for x in m["end_to_end"] if name in cells_of(m, x)]
+    assert "setup_s" in {x["name"] for x in reported}
     assert len(reported) >= 2, "setup_s and one more end-to-end metric"
-    assert any(name in cells_of(m) for m in LAYERS.values())
+    assert any(name in cells_of(m, x) for x in m["per_layer"])
 
 
-def test_at_most_a_quarter_of_the_cells_take_four_chips():
-    four = [w for w in M["workloads"] if w["chips"] == 4]
-    assert len(four) <= max(1, len(M["workloads"]) // 4)
-    assert 1 <= len(M["workloads"]) <= 24 and 1 <= len(CONFIGS) <= 24
+def rule_at_most_a_quarter_of_the_cells_take_four_chips(m):
+    four = [w for w in m["workloads"] if w["chips"] == 4]
+    assert len(four) <= max(1, len(m["workloads"]) // 4)
+    assert 1 <= len(m["workloads"]) <= 24 and 1 <= len(m["configs"]) <= 24
 
 
-@pytest.mark.parametrize("name", sorted(E2E))
-def test_end_to_end_metric(name):
-    m = E2E[name]
-    assert set(m) - {"workloads"} == {"name", "unit", "better", "bound",
+def rule_names_are_unique(m):
+    assert 1 <= len(m["per_layer"]) <= 128
+    for group in (m["configs"], m["workloads"],
+                  m["end_to_end"] + m["per_layer"]):
+        names = [g["name"] for g in group]
+        assert len(names) == len(set(names))
+
+
+def rule_end_to_end(m, name):
+    x = by_name(m, "end_to_end")[name]
+    assert set(x) - {"workloads"} == {"name", "unit", "better", "bound",
                                       "source"}
-    assert NAME.match(name) and UNIT.match(m["unit"])
-    assert m["better"] in ("lower", "higher")
-    assert m["source"] in ("host_clock", "device_trace")
-    assert 0.01 <= m["bound"] <= 0.1
-    assert all(c in CELLS for c in cells_of(m))
+    assert NAME.match(name) and UNIT.match(x["unit"])
+    assert x["better"] in ("lower", "higher")
+    assert x["source"] in ("host_clock", "device_trace")
+    assert 0.01 <= x["bound"] <= 0.1
+    assert all(c in by_name(m, "workloads") for c in cells_of(m, x))
     assert os.path.exists(os.path.join(_paths.BENCH, "end_to_end",
                                        name + ".py"))
-
-
-def test_setup_s_is_reported_by_every_cell():
-    assert "workloads" not in E2E["setup_s"]
-    assert 1 <= len(E2E) <= 16
 
 
 def reader_constants(name):
@@ -158,30 +167,76 @@ def reader_constants(name):
             if isinstance(t, ast.Name)}
 
 
-@pytest.mark.parametrize("name", sorted(LAYERS))
-def test_per_layer_metric_and_its_reader_file(name):
-    m = LAYERS[name]
-    assert set(m) - {"workloads"} == {"name", "unit", "better", "source",
+def rule_per_layer(m, name):
+    x = by_name(m, "per_layer")[name]
+    assert set(x) - {"workloads"} == {"name", "unit", "better", "source",
                                       "layer", "moves"}
-    assert NAME.match(name) and UNIT.match(m["unit"])
-    assert m["better"] in ("lower", "higher") and m["source"] in SOURCES
-    assert 1 <= len(m["layer"]) <= 200 and "\n" not in m["layer"]
+    assert NAME.match(name) and UNIT.match(x["unit"])
+    assert x["better"] in ("lower", "higher") and x["source"] in SOURCES
+    assert 1 <= len(x["layer"]) <= 200 and "\n" not in x["layer"]
     consts = reader_constants(name)
     assert (consts["LAYER"], consts["UNIT"], consts["MOVES"]) == (
-        m["layer"], m["unit"], m["moves"])
-    moved = E2E[m["moves"]]
-    for cell in cells_of(m):
-        assert cell in cells_of(moved), (
-            f"{name} moves {m['moves']}, which {cell} does not report")
-    if "workloads" not in m:
+        x["layer"], x["unit"], x["moves"])
+    moved = by_name(m, "end_to_end")[x["moves"]]
+    for cell in cells_of(m, x):
+        assert cell in cells_of(m, moved), (
+            f"{name} moves {x['moves']}, which {cell} does not report")
+    if "workloads" not in x:
         assert "workloads" not in moved
     if name.endswith("_roofline") or "mfu" in name:
-        assert m["unit"] == "%"
+        assert x["unit"] == "%"
 
 
-def test_names_are_unique():
-    for group in (M["configs"], M["workloads"],
-                  M["end_to_end"] + M["per_layer"]):
-        names = [g["name"] for g in group]
-        assert len(names) == len(set(names))
-    assert 1 <= len(LAYERS) <= 128
+WHOLE = (rule_top_level, rule_a_full_check_of_24_cells_fits_the_drivers_day,
+         rule_stands_alone, rule_setup_s_is_reported_by_every_cell,
+         rule_command, rule_at_most_a_quarter_of_the_cells_take_four_chips,
+         rule_names_are_unique)
+
+
+def hold_to_every_rule(m):
+    """Every rule above, of a manifest a test has made."""
+    for rule in WHOLE:
+        rule(m)
+    for key, rule in (("configs", rule_config), ("workloads", rule_cell),
+                      ("end_to_end", rule_end_to_end),
+                      ("per_layer", rule_per_layer)):
+        for name in by_name(m, key):
+            rule(m, name)
+    for path in m["paths"]:
+        rule_path(m, path)
+
+
+# -- the manifest as it is, and with the kept entries merged in -------------
+
+@pytest.mark.parametrize("rule", WHOLE, ids=lambda r: r.__name__)
+def test_the_manifest_as_a_whole(rule):
+    rule(M)
+
+
+def test_the_merged_manifest_has_each_name_once():
+    rule_names_are_unique(KEPT)
+
+
+@pytest.mark.parametrize("path", M["paths"])
+def test_paths_are_directories_of_the_benchmarks_own(path):
+    rule_path(M, path)
+
+
+@pytest.mark.parametrize("name", sorted(by_name(M, "configs")))
+def test_config_entry_and_file(name):
+    rule_config(KEPT, name)
+
+
+@pytest.mark.parametrize("name", sorted(by_name(KEPT, "workloads")))
+def test_cell_entry_and_traffic_file(name):
+    rule_cell(KEPT, name)
+
+
+@pytest.mark.parametrize("name", sorted(by_name(KEPT, "end_to_end")))
+def test_end_to_end_metric(name):
+    rule_end_to_end(KEPT, name)
+
+
+@pytest.mark.parametrize("name", sorted(by_name(KEPT, "per_layer")))
+def test_per_layer_metric_and_its_reader_file(name):
+    rule_per_layer(KEPT, name)

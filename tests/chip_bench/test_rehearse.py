@@ -26,13 +26,20 @@ def last_json(stdout: str) -> dict:
     return json.loads(stdout.strip().splitlines()[-1])
 
 
-# One process a cell: the eager cell's rehearsal is the traced one, so
-# that the per-layer readers run through the command as well. That cell
-# is kept for a later PR (PERF.md, Open questions), so it runs from a
-# root whose manifest has its entries added.
+# One process a cell: the eager cell's rehearsal and the four-chip
+# cell's are the traced ones, so that the per-layer readers run through
+# the command as well. The eager cell is kept for a later PR (PERF.md,
+# Open questions), so it runs from a root whose manifest has its entries
+# merged in: by name, so that the PR which moves it into BENCHMARK.json
+# finds it here once. The cell of four chips is one process over four
+# forced CPU devices (``run.py`` asks for them where the environment
+# has not).
+TRACED = ("resnet50-eager-1rank", "lm-injit-4chip")
+
+
 @pytest.mark.parametrize("cell", [w["name"] for w in KEPT["workloads"]])
 def test_cell_rehearses_through_the_command(cell, tmp_path):
-    traced = cell == "resnet50-eager-1rank"
+    traced = cell in TRACED
     root = _paths.ROOT
     if cell not in [w["name"] for w in M["workloads"]]:
         root = tmp_path
@@ -107,3 +114,45 @@ def test_unknown_workload_is_refused():
     out = run(["--workload", "no-such-cell", "--seed", "1", "--seconds",
                "1", "--trace", "0", "--rehearse"])
     assert out.returncode != 0 and '"correct"' not in out.stdout
+
+
+def test_the_result_line_ends_with_each_number_beside_its_limit(capsys):
+    """``checks`` comes last in the line, ``[value, limit]`` by name,
+    another rank's names with its rank behind them; the same are the
+    last lines on standard error; a reading that is no number stays
+    valid JSON."""
+    import argparse
+    import importlib.util
+    import math
+    spec = importlib.util.spec_from_file_location(
+        "chipbench_run", os.path.join(_paths.BENCH, "run.py"))
+    run_py = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(run_py)
+
+    def rank(r, checks):
+        return {"rank": r, "correct": all(c["ok"] for c in checks.values()),
+                "digest": [1.0], "attempted": 3, "failed": 0,
+                "metrics": {"setup_s": {"value": 1.5, "unit": "s"}},
+                "device": {"platform": "tpu", "kind": "TPU v5 lite",
+                           "count": 1},
+                "memory_peak_bytes": 7, "checks": checks}
+
+    results = [
+        rank(0, {"loss_gap": {"value": 2e-5, "limit": 1e-4, "ok": True},
+                 "window_loss_falls": {"value": math.inf, "limit": 0.0,
+                                       "ok": False}}),
+        rank(1, {"replay_loss_gap": {"value": 0.0, "limit": 0.0,
+                                     "ok": True}})]
+    line = run_py.last_line(argparse.Namespace(rehearse=False, trace=0),
+                            M, results)
+    assert list(line)[-1] == "checks" and line["correct"] is False
+    assert line["checks"] == {"loss_gap": [2e-5, 1e-4],
+                              "window_loss_falls": ["inf", 0.0],
+                              "replay_loss_gap.r1": [0.0, 0.0]}
+    assert line["device"]["count"] == 2
+    json.dumps(line, allow_nan=False)
+    err = capsys.readouterr().err.strip().splitlines()
+    assert err == [
+        "chipbench: check: rank 0 loss_gap 2e-05 limit 0.0001 ok",
+        "chipbench: check: rank 0 window_loss_falls inf limit 0.0 NOT OK",
+        "chipbench: check: rank 1 replay_loss_gap 0.0 limit 0.0 ok"]

@@ -4,8 +4,6 @@ the others to be, the eager cell rehearses traced from a manifest copy
 with both kept files added and the new readers give values, and
 ``program_spans.idle_under`` splits a hand-made trace as it should."""
 
-import json
-import os
 import re
 
 import pytest
@@ -15,8 +13,7 @@ from .test_manifest import NAME, SOURCES, UNIT, reader_constants
 from .test_rehearse import check_rehearsal, run
 
 CELL = "resnet50-eager-1rank"
-with open(os.path.join(_paths.BENCH, "kept", "eager-spans.json")) as f:
-    SPANS_KEPT = json.load(f)
+SPANS_KEPT = _paths.kept("eager-spans.json")
 NEW = {m["name"]: m for m in SPANS_KEPT["per_layer"]}
 # the readers that find something to read on the CPU, where nothing is
 # profiled and LocalBackend packs nothing
@@ -26,10 +23,7 @@ REHEARSED = ["enqueue_ms_per_step", "queue_wait_ms_per_step",
 
 
 def manifest_with_both() -> dict:
-    m = _paths.manifest_with_kept()
-    for key in ("workloads", "end_to_end", "per_layer"):
-        m[key] = m[key] + SPANS_KEPT[key]
-    return m
+    return _paths.merge_kept(_paths.manifest_with_kept(), SPANS_KEPT)
 
 
 @pytest.mark.parametrize("name", sorted(NEW))
@@ -52,7 +46,8 @@ def test_kept_span_metric_and_its_reader_file(name):
 
 
 def test_state_broadcast_s_is_listed_for_every_cell():
-    entry = _paths.manifest()["per_layer"][-1]
+    (entry,) = [m for m in _paths.manifest()["per_layer"]
+                if m["name"] == "state_broadcast_s"]
     assert entry == {"name": "state_broadcast_s", "unit": "s",
                      "better": "lower", "source": "program_span",
                      "layer": "Launcher and start-up", "moves": "setup_s"}

@@ -121,3 +121,67 @@ def test_recorded_chip_trace_reduces_to_its_recorded_numbers():
     events = r["events"]["/device:TPU:0"]
     fusions = tr.time_of(events, lambda n: "fusion" in n)
     assert 0.5 * r["busy_s"] < fusions / 1e9 <= r["busy_s"]
+
+
+# -- collective_time_share ---------------------------------------------------
+
+def collective_share(reduced):
+    from chipbench import harness
+    reader = harness.load_module("layer_metrics", "collective_time_share")
+    return reader.read({"trace": reduced})
+
+
+def test_collective_time_share_is_a_devices_mean_self_time():
+    """Two devices over a window of 100 us. The first: an all-reduce of
+    12 us named for JAX's psum inside a ``while`` (taken out of it), an ``all-reduce-start``
+    of 1 us and its ``-done`` of 6 us, a reduce-scatter of 4 us that
+    holds a fusion of 1 us (taken out), an all-gather cut to 3 us by the
+    window's end; 12 + 1 + 6 + 3 + 3 = 25 us. The second has none: 0.
+    The mean is 12.5 us of 100."""
+    t = trace(
+        [["while.1", 0, 40 * US], ["fusion.1", 2 * US, 10 * US],
+         ["psum.3[all-reduce]", 20 * US, 12 * US],
+         ["all-reduce-start.1", 45 * US, 1 * US],
+         ["fusion.2", 46 * US, 10 * US],
+         ["all-reduce-done.1", 56 * US, 6 * US],
+         ["reduce-scatter.2", 70 * US, 4 * US], ["fusion.3", 71 * US, 1 * US],
+         ["not-all-reduce.1", 80 * US, 5 * US],
+         ["all-gather.7", 97 * US, 9 * US]],
+        [["bench.window", 0, 100 * US]])
+    t["planes"].append({"name": "/device:TPU:1", "lines": [
+        {"name": "XLA Ops", "events": [["fusion.9", 0, 22 * US]]}]})
+    r = tr.reduce_trace(t)
+    assert r["devices"] == 2
+    assert collective_share(r) == pytest.approx(12.5)
+
+
+def test_collective_time_share_of_one_chip_is_zero_and_of_no_trace_none():
+    with open(RECORDED) as f:
+        r = tr.reduce_trace(json.load(f)["trace"])
+    share = collective_share(r)
+    assert share == 0.0 and share is not None
+    assert collective_share(None) is None
+
+
+def test_a_collective_keeps_its_opcode_where_its_name_does_not_say_it():
+    psum = ('%psum.797 = f32[2048,50304]{1,0:T(8,128)} all-reduce('
+            '%get-tuple-element.517), channel_id=1, replica_groups={{0,1,2,3}}')
+    combined = ('%all-reduce.58 = (f32[2048,8192]{1,0}, /*index=1*/f32[16]{0}) '
+                'all-reduce(%bitcast_convert_fusion.16, %custom-call.247)')
+    user = ('%fusion.7 = f32[8]{0} fusion(%all-reduce.58, %psum.797), '
+            'kind=kLoop, calls=%fused_computation.3')
+    done = '%ar-done.2 = f32[8]{0} all-reduce-done(%all-reduce-start.2)'
+    assert tr.short_name(psum) == "psum.797[all-reduce]"
+    assert tr.short_name(combined) == "all-reduce.58"
+    assert tr.short_name(user) == "fusion.7"
+    assert tr.short_name(done) == "ar-done.2[all-reduce-done]"
+    assert tr.short_name("%custom-call.7 = f32[8]{0} custom-call(%x), "
+                         'custom_call_target="tpu_custom_call"') \
+        == "custom-call.7[tpu_custom_call]"
+    for name in ("psum.797[all-reduce]", "all-reduce.58", "all-gather.1",
+                 "ar-done.2[all-reduce-done]", "reduce-scatter-start.4",
+                 "collective-permute-done.9"):
+        assert tr.is_collective(name), name
+    for name in ("fusion.7", "custom-call.7[tpu_custom_call]",
+                 "not-all-reduce.1", "while.1", "x[7]"):
+        assert not tr.is_collective(name), name
