@@ -331,6 +331,13 @@ class Runtime:
         # forever. A transient dead round (grant 0) does not count,
         # and a completed fused cycle resets the mask's slate.
         self._spec_denied: Dict[int, int] = {}
+        # (hit mask, fusion threshold) of steady sets whose bid THIS
+        # rank's backend declined (fused_cycle_reducible said no): the
+        # answer is a function of the replay plan's sizes and of the
+        # backend that carries them, both fixed for the key within a
+        # cache epoch, so later cycles decline without walking the
+        # plan again. Cleared with _spec_denied when the epoch moves.
+        self._spec_declined: set = set()
         # [(fused Response, entries, arrays)] per payload segment of
         # the spec frame in flight this cycle (build->apply, bg thread
         # only); None when the current cycle is not speculative.
@@ -406,6 +413,9 @@ class Runtime:
         self._native_steady_cycles = 0
         self._spec_cycles = 0  # cycles completed via the fused round
         self._spec_bids = 0    # speculative frames sent (observability)
+        # Steady cycles whose bid this rank's backend declined from
+        # the batch's size, before any payload was copied.
+        self._spec_declines = 0
         # Hits the last cycle bid but the world did not grant, now
         # requeued: their peers were already granted and will not be
         # re-enqueued, so they must never trigger a burst hold.
@@ -437,6 +447,10 @@ class Runtime:
             "hvd_fused_spec_cycles_total",
             "single-round fused speculative cycles completed")
         self._m_spec_bids = reg.counter("hvd_spec_bids_total")
+        self._m_spec_declines = reg.counter(
+            "hvd_spec_declines_total",
+            "steady cycles whose speculative bid this rank's backend "
+            "declined from the batch's size, before any copy")
         self._m_spec_denials = reg.counter("hvd_spec_denials_total")
         self._m_native_steady = reg.counter(
             "hvd_native_steady_cycles_total",
@@ -1150,7 +1164,7 @@ class Runtime:
                     and hit_mask in self._steady \
                     and self._spec_denied.get(hit_mask, 0) \
                     < self._SPEC_DENY_LIMIT:
-                payload = self._build_spec_frame(hit_mask)
+                payload = self._build_spec_frame(hit_mask, bit_requests)
                 if payload is not None:
                     return payload, bit_requests
             # Pure-hit (or empty) frame: bit-identical every
@@ -1224,7 +1238,29 @@ class Runtime:
         self._m_burst_hold_s.inc((hold.end_ns - hold.start_ns) * 1e-9)
         return requests
 
-    def _build_spec_frame(self, hit_mask: int):
+    @staticmethod
+    def _batch_nbytes(entries, bit_requests) -> int:
+        """Uncompressed input bytes of a batch from what its entries
+        already say: the payload's ``nbytes`` (metadata on numpy and
+        jax arrays alike), or shape x dtype of this cycle's request
+        where a payload carries none. Never converts, fetches or waits
+        for a buffer — the count the backend is asked with must cost
+        nothing."""
+        total = 0
+        by_name = None
+        for e in entries:
+            nbytes = getattr(e.tensor, "nbytes", None)
+            if nbytes is None:
+                if by_name is None:
+                    by_name = {r.tensor_name: r for _, r in bit_requests}
+                req = by_name[e.tensor_name]
+                nbytes = datatype_size(req.tensor_type)
+                for d in req.tensor_shape:
+                    nbytes *= int(d)
+            total += nbytes
+        return total
+
+    def _build_spec_frame(self, hit_mask: int, bit_requests):
         """Build a fused speculative cycle frame: the pure-hit bitmask
         PLUS this rank's pre-packed fused allreduce buffers in
         replay-plan order, or None when the batch is not speculation-
@@ -1232,6 +1268,13 @@ class Runtime:
         plane of its own — shm/ring/XLA — would carry it, or an entry
         vanished). Entries are only PEEKED: the world may still deny
         the grant, in which case the classic path pops them later.
+
+        The WHOLE plan is held to every eligibility check — the
+        backend's fused_cycle_reducible among them, asked with the
+        batch's size from metadata (_batch_nbytes) — before the first
+        payload is converted: a decline costs no device -> host copy
+        and no wait for the computation that produces the tensors,
+        and the answer is kept per steady set (_spec_declined).
 
         With the zero-copy plane engaged, the return value is a
         SteadyPlan (packed into the persistent fusion arena; the
@@ -1247,11 +1290,12 @@ class Runtime:
             # this cycle — a native/spec grant would bypass it and
             # keep replaying verdicts of the superseded plan.
             return None
+        key = (hit_mask, self._world_fusion_threshold)
+        if key in self._spec_declined:
+            self._spec_declines += 1
+            return None
         plan = self._replay_plan(hit_mask, self._world_fusion_threshold)
-        seg_arrays = []
-        seg_wires = []
-        prescales = []
-        inflight = []
+        admitted = []
         for resp in plan:
             if resp.response_type != ResponseType.ALLREDUCE:
                 return None
@@ -1271,14 +1315,24 @@ class Runtime:
             entries = self.tensor_table.peek_entries(resp.tensor_names)
             if entries is None:
                 return None
-            arrays = [_to_numpy(e.tensor) for e in entries]
             try:
                 backend = self.op_manager.pick(entries, resp)
             except RuntimeError:
                 return None
             if not backend.fused_cycle_reducible(
-                    sum(a.nbytes for a in arrays)):
+                    self._batch_nbytes(entries, bit_requests)):
+                if len(self._spec_declined) >= 64:
+                    self._spec_declined.clear()
+                self._spec_declined.add(key)
+                self._spec_declines += 1
                 return None
+            admitted.append((resp, entries))
+        seg_arrays = []
+        seg_wires = []
+        prescales = []
+        inflight = []
+        for resp, entries in admitted:
+            arrays = [_to_numpy(e.tensor) for e in entries]
             seg_arrays.append(arrays)
             seg_wires.append(resp.wire_dtype)
             prescales.append(resp.prescale_factor)
@@ -2317,6 +2371,7 @@ class Runtime:
                 # slot<->name bindings moved; every mask is stale
                 self._steady.clear()
                 self._spec_denied.clear()
+                self._spec_declined.clear()
                 self._steady_epoch = cache.epoch
             if self._spec_inflight is not None and not missed:
                 # We bid speculatively; the world granted everything
@@ -2606,6 +2661,7 @@ class Runtime:
         self._m_cached_cycles.set_total(self._cached_cycles)
         self._m_spec_cycles.set_total(self._spec_cycles)
         self._m_spec_bids.set_total(self._spec_bids)
+        self._m_spec_declines.set_total(self._spec_declines)
         self._m_spec_denials.set_total(self._spec_denials_total)
         self._m_native_steady.set_total(self._native_steady_cycles)
         self._m_overlap_cycles.set_total(self._overlap_cycles)
@@ -2740,6 +2796,7 @@ class Runtime:
                 "cached_cycles": self._cached_cycles,
                 "spec_cycles": self._spec_cycles,
                 "spec_bids": self._spec_bids,
+                "spec_declines": self._spec_declines,
                 "native_steady_cycles": self._native_steady_cycles,
                 "ici_cycles": self._ici_cycles,
                 "ici_compiles": (self._ici_plane.compiles

@@ -90,7 +90,11 @@ class CollectiveBackend:
         precondition for the speculative fused cycle (runtime.py) to
         piggyback the payload on the negotiation round. Planes with
         their own transport (shm, ring, XLA mesh) say False so
-        speculation never steals a batch from a faster data plane."""
+        speculation never steals a batch from a faster data plane.
+        ``nbytes`` is the batch's uncompressed input size, reckoned
+        from the entries' metadata: the runtime asks before it
+        converts or fetches any tensor, so the answer may depend on
+        nothing else about the payloads."""
         return False
 
     def execute_allreduce(self, entries, response) -> Status:

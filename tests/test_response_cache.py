@@ -356,3 +356,187 @@ class TestConfigKnobs:
     def test_capacity_must_be_positive(self):
         with pytest.raises(ValueError):
             ResponseCache(0)
+
+
+class _Payload:
+    """A tensor table payload that records every conversion to numpy
+    (``__array__``), or raises on one: what the speculative frame
+    builder may read without a conversion is metadata alone."""
+
+    def __init__(self, name, arr, log, nbytes=True, convertible=True):
+        self._name, self._arr, self._log = name, arr, log
+        self._convertible = convertible
+        self.shape, self.dtype = arr.shape, arr.dtype
+        if nbytes:
+            self.nbytes = arr.nbytes
+
+    def __array__(self, dtype=None, copy=None):
+        if not self._convertible:
+            raise AssertionError(
+                f"payload {self._name} was converted before the "
+                f"backend was asked")
+        self._log.append(self._name)
+        return self._arr
+
+
+class _AskedBackend:
+    """Answers fused_cycle_reducible from a rule and keeps every
+    byte count it was asked with."""
+
+    def __init__(self, rule):
+        self._rule = rule
+        self.asked = []
+
+    def fused_cycle_reducible(self, nbytes):
+        self.asked.append(nbytes)
+        return self._rule(nbytes)
+
+
+class TestSpecFrameAsksBeforeItCopies:
+    """_build_spec_frame holds the whole replay plan to the backend's
+    fused_cycle_reducible, asked with the batch's uncompressed bytes
+    from metadata, before any payload is converted."""
+
+    SHAPES = [(3,), (2, 4), (5,)]      # 12, 32 and 20 bytes of f32
+
+    def _shell(self, threshold, backend, nbytes, convertible):
+        """A transport-free Runtime holding one steady set of cached
+        allreduces in its table: just what _build_spec_frame reads."""
+        import types
+
+        import numpy as np
+
+        from horovod_tpu.common.runtime import Runtime
+        from horovod_tpu.common.tensor_table import (
+            TensorTable, TensorTableEntry,
+        )
+        rt = Runtime.__new__(Runtime)
+        rt._cache = ResponseCache(8)
+        rt.parameter_manager = None
+        rt.controller = types.SimpleNamespace(is_coordinator=False)
+        rt._replay_epoch = -1
+        rt._replay_plans = {}
+        rt._world_fusion_threshold = threshold
+        rt._world_id = 0
+        # the serialized frame, not a SteadyPlan
+        rt._steady_native_ok = False
+        rt._ici_plane = None
+        rt._spec_inflight = None
+        rt._spec_bids = 0
+        rt._spec_declines = 0
+        rt._spec_declined = set()
+        rt.tensor_table = TensorTable()
+        rt.op_manager = types.SimpleNamespace(
+            pick=lambda entries, resp: backend)
+        log, arrays, bit_requests = [], [], []
+        for i, shape in enumerate(self.SHAPES):
+            name = f"g.{i}"
+            arr = (np.arange(int(np.prod(shape)), dtype=np.float32)
+                   .reshape(shape) + 10.0 * i)
+            req = _req(name, shape=shape)
+            _put(rt._cache, name, req, _resp(name, numel=arr.size))
+            rt.tensor_table.add(
+                TensorTableEntry(name, _Payload(name, arr, log, nbytes,
+                                                convertible)), req)
+            arrays.append(arr)
+            bit_requests.append((i, req))
+        return rt, log, arrays, bit_requests
+
+    @pytest.mark.parametrize("threshold,rule,nbytes,segments", [
+        pytest.param(1 << 20, lambda n: False, True, None,
+                     id="declines"),
+        pytest.param(1 << 20, lambda n: False, False, None,
+                     id="declines-by-request-shape"),
+        pytest.param(48, lambda n: n > 20, True, None,
+                     id="second-segment-declines"),
+        pytest.param(1 << 20, lambda n: True, True, [[0, 1, 2]],
+                     id="accepts"),
+        pytest.param(1 << 20, lambda n: True, False, [[0, 1, 2]],
+                     id="accepts-by-request-shape"),
+        pytest.param(48, lambda n: True, True, [[0, 1], [2]],
+                     id="accepts-two-segments"),
+    ])
+    def test_plan_is_asked_whole_then_copied(self, threshold, rule,
+                                             nbytes, segments):
+        import numpy as np
+
+        backend = _AskedBackend(rule)
+        declines = segments is None
+        rt, log, arrays, bit_requests = self._shell(
+            threshold, backend, nbytes, convertible=not declines)
+        mask = 0b111
+        if declines:
+            for cycle in (1, 2, 3):
+                assert rt._build_spec_frame(mask, bit_requests) is None
+                assert rt._spec_declines == cycle
+            assert log == [] and rt._spec_bids == 0
+            assert rt._spec_inflight is None
+            # The plan is asked in order and only as far as the first
+            # "no" (sizes from nbytes or from the request alike), and
+            # the answer is kept for the steady set: once, not thrice.
+            want = [64] if threshold > 48 else [12 + 32, 20]
+            assert backend.asked == want
+            # Another fusion threshold is another plan: asked again.
+            rt._world_fusion_threshold = threshold - 1
+            assert rt._build_spec_frame(mask, bit_requests) is None
+            assert backend.asked == want * 2 and log == []
+            assert rt._spec_declines == 4
+            return
+        frame = rt._build_spec_frame(mask, bit_requests)
+        assert rt._spec_bids == 1 and rt._spec_declines == 0
+        # Asked with sum(nbytes) of what was then converted, every
+        # segment before the first conversion, each payload once.
+        assert backend.asked == [
+            sum(arrays[i].nbytes for i in seg) for seg in segments]
+        assert log == [f"g.{i}" for seg in segments for i in seg]
+        # The frame the parent's order (convert, then ask) builds.
+        want = wire.serialize_cycle_request(CacheCycleRequest(
+            epoch=rt._cache.epoch, nslots=rt._cache.nslots,
+            hit_mask=mask, spec_payload=[
+                (DataType.FLOAT32,
+                 np.concatenate([arrays[i].reshape(-1) for i in seg]))
+                for seg in segments]))
+        assert frame == want
+        assert [[e.tensor_name for e in entries]
+                for _, entries, _ in rt._spec_inflight] == [
+            [f"g.{i}" for i in seg] for seg in segments]
+
+    def test_world_of_one_declines_every_steady_cycle(self, hvd_world,
+                                                      monkeypatch):
+        """LocalBackend has no star to ride: once the set is steady,
+        every cycle declines from the sizes, converts nothing and
+        bids nothing; the sums stay exact."""
+        import numpy as np
+
+        from horovod_tpu.common import basics
+        from horovod_tpu.ops import socket_ops
+
+        converted = []
+        real = socket_ops._to_numpy
+
+        def counting(tensor):
+            converted.append(1)
+            return real(tensor)
+
+        monkeypatch.setattr(socket_ops, "_to_numpy", counting)
+        hvd = hvd_world
+        xs = [np.full(16 + i, float(i + 1), np.float32)
+              for i in range(6)]
+
+        def step():
+            hs = hvd.grouped_allreduce_async(xs, average=False,
+                                             name="sd")
+            for x, h in zip(xs, hs):
+                np.testing.assert_array_equal(hvd.synchronize(h), x)
+
+        stats = basics.runtime().negotiation_cache_stats
+        for _ in range(6):      # learn the cache, then the steady set
+            step()
+        before = stats()
+        assert before["spec_declines"] > 0, before
+        for _ in range(5):
+            step()
+        after = stats()
+        assert after["spec_declines"] - before["spec_declines"] == 5, (
+            before, after)
+        assert after["spec_bids"] == 0 and converted == [], after
