@@ -1,0 +1,398 @@
+"""DeepSeek-V3-style sparse decoder, as GLM-4.7-Flash (``glm4_moe_lite``)
+lays it out: latent attention, a dropless expert layer that holds a
+share of the experts, a shared expert, a multi-token-prediction module.
+
+Pre-norm residual blocks, RMSNorm, no biases::
+
+    x <- x + MLA(norm(x));  x <- x + FFN(norm(x))
+
+FFN is a dense SwiGLU in the first ``first_k_dense`` layers and the
+expert layer after them.
+
+* **MLA** (:class:`LatentAttention`): queries and keys come through
+  low-rank latents with an RMSNorm of their own; a rotary part that all
+  heads share on the key side. In training the keys and values are
+  expanded per head and go through ``flash_attention``; nothing is
+  absorbed.
+* **Expert layer** (:class:`ExpertLayer`): a float32 sigmoid router over
+  *all* ``n_routed_experts``, top k of ``score + bias``, weights
+  normalised over the k and scaled. The layer is told which experts it
+  holds (``experts_held`` from ``expert_offset``: one chip's share under
+  expert parallelism), routes over all of them and computes its own
+  experts' part; what the absent experts would add is left out. **No
+  assignment to a held expert is dropped, whatever the imbalance**:
+  assignments are sorted by expert and the three products run over the
+  held groups at their real sizes (``jax.lax.ragged_dot``, which the TPU
+  compiler lowers to a grouped-matmul kernel of its own). The row buffer
+  is static, so the layer picks the smallest of ``ROW_TIERS`` (shares of
+  tokens x k) that holds this step's assignments; the last tier is 1.0.
+* **Multi-token prediction** (:class:`GlmMoeLM`, depth 1)::
+
+      h'_i = W_eh [norm_e(Emb(t_{i+1})), norm_h(h_i)]
+
+  with ``h_i`` the last layer's output before the final norm, one
+  expert block of its own, a final norm of its own and the shared head.
+
+The model returns the two hidden states and the expert layers' load
+counts; ``train_steps.glm_moe_loss_fn`` turns them into
+``CE(main) + lambda * CE(h'_i -> t_{i+2})``.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+from typing import Any
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from horovod_tpu.models.transformer import apply_rope, best_attention
+
+
+@dataclasses.dataclass(frozen=True)
+class GlmMoeConfig:
+    vocab_size: int = 154880
+    num_layers: int = 47             # dense + expert layers, without MTP
+    first_k_dense: int = 1
+    hidden_size: int = 2048
+    num_heads: int = 20
+    q_lora_rank: int = 768
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 192
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 256
+    intermediate_size: int = 10240
+    moe_intermediate_size: int = 1536
+    n_routed_experts: int = 64       # the router's width
+    num_experts_per_tok: int = 4
+    routed_scaling_factor: float = 1.8
+    # The share of the experts this chip holds: ids
+    # [expert_offset, expert_offset + experts_held).
+    experts_held: int = 64
+    expert_offset: int = 0
+    mtp_layers: int = 1              # 0 or 1
+    mtp_loss_weight: float = 0.3     # lambda of the multi-token loss
+    rms_norm_eps: float = 1e-5
+    rope_theta: float = 1e6
+    dtype: Any = jnp.bfloat16
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+
+# Columns of an expert layer's counts, behind the held experts' own.
+ABSENT, DROPPED = -2, -1
+# Static sizes of the expert layer's row buffer, as shares of tokens x k,
+# ascending. A layer that holds an eighth of the experts sees an eighth
+# of the assignments by expectation and 1.9 times that in its fullest
+# expert (PERF.md, PR 27): a quarter holds the sound case, and the last
+# tier holds every assignment, so nothing is ever dropped.
+ROW_TIERS = (0.25, 1.0)
+
+
+def _norm(cfg: GlmMoeConfig, name: str):
+    return nn.RMSNorm(epsilon=cfg.rms_norm_eps, dtype=cfg.dtype,
+                      param_dtype=jnp.float32, name=name)
+
+
+def _dense(cfg: GlmMoeConfig, features, name: str, axis=-1):
+    return nn.DenseGeneral(features, axis=axis, use_bias=False,
+                           dtype=cfg.dtype, name=name)
+
+
+class LatentAttention(nn.Module):
+    cfg: GlmMoeConfig
+
+    @nn.compact
+    def __call__(self, x, positions):
+        cfg = self.cfg
+        h, nope, v_dim = cfg.num_heads, cfg.qk_nope_head_dim, cfg.v_head_dim
+        with jax.named_scope("mla"):
+            c_q = _norm(cfg, "q_norm")(_dense(cfg, cfg.q_lora_rank, "q_a")(x))
+            q = _dense(cfg, (h, cfg.qk_head_dim), "q_b")(c_q)
+            kv = _dense(cfg, cfg.kv_lora_rank + cfg.qk_rope_head_dim,
+                        "kv_a")(x)
+            c_kv = _norm(cfg, "kv_norm")(kv[..., :cfg.kv_lora_rank])
+            k_rope = kv[..., None, cfg.kv_lora_rank:]          # [B,S,1,R]
+            kv = _dense(cfg, (h, nope + v_dim), "kv_b")(c_kv)
+            q_rope = apply_rope(q[..., nope:], positions, cfg.rope_theta)
+            k_rope = apply_rope(k_rope, positions, cfg.rope_theta)
+            q = jnp.concatenate([q[..., :nope], q_rope], -1)
+            k = jnp.concatenate(
+                [kv[..., :nope],
+                 jnp.broadcast_to(k_rope, k_rope.shape[:2]
+                                  + (h, cfg.qk_rope_head_dim))], -1)
+            out = best_attention(q, k, kv[..., nope:], True)
+            return _dense(cfg, cfg.hidden_size, "o", axis=(-2, -1))(out)
+
+
+class SwiGLU(nn.Module):
+    cfg: GlmMoeConfig
+    width: int
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.cfg
+        gate = _dense(cfg, self.width, "gate")(x)
+        up = _dense(cfg, self.width, "up")(x)
+        return _dense(cfg, cfg.hidden_size, "down")(nn.silu(gate) * up)
+
+
+# -- rows to their experts and back -----------------------------------------
+# ``order`` lists the assignments (token * k + choice) sorted by expert,
+# held ones first; ``inv`` is its inverse. A tier takes the first
+# ``cap`` of them. Both directions are gathers, and each is the other's
+# transpose.
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def rows_to_experts(x, order, inv, held, k):
+    """``x[order // k]``: [cap, D] rows in expert order from [N, D]."""
+    return x[order // k]
+
+
+def _rows_to_experts_fwd(x, order, inv, held, k):
+    return x[order // k], (order, inv, held)
+
+
+def _rows_to_experts_bwd(k, res, g):
+    order, inv, held = res
+    return (rows_from_experts(g, order, inv, held, k), None, None, None)
+
+
+rows_to_experts.defvjp(_rows_to_experts_fwd, _rows_to_experts_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def rows_from_experts(rows, order, inv, held, k):
+    """[N, D] from [cap, D] rows in expert order: each token's held
+    choices summed in float32; a row that belongs to no held expert
+    (whatever the buffer holds there) is left out."""
+    cap = rows.shape[0]
+    picked = rows[jnp.minimum(inv, cap - 1)]                 # [N*k, D]
+    picked = jnp.where(held.reshape(-1, 1), picked, 0)
+    return jnp.sum(picked.reshape(-1, k, rows.shape[-1]),
+                   axis=1, dtype=jnp.float32).astype(rows.dtype)
+
+
+def _rows_from_experts_fwd(rows, order, inv, held, k):
+    return rows_from_experts(rows, order, inv, held, k), (order, inv, held)
+
+
+def _rows_from_experts_bwd(k, res, g):
+    order, inv, held = res
+    live = held.reshape(-1)[order]
+    rows = jnp.where(live[:, None], rows_to_experts(g, order, inv, held, k),
+                     0)
+    return (rows, None, None, None)
+
+
+rows_from_experts.defvjp(_rows_from_experts_fwd, _rows_from_experts_bwd)
+
+
+class Router(nn.Module):
+    """The router's parameters: the float32 kernel over all the experts
+    and the correction bias that only the choice reads (the balancing
+    rule that moves it is the trainer's, not the layer's)."""
+
+    cfg: GlmMoeConfig
+
+    @nn.compact
+    def __call__(self):
+        cfg = self.cfg
+        kernel = self.param("kernel", nn.initializers.lecun_normal(),
+                            (cfg.hidden_size, cfg.n_routed_experts),
+                            jnp.float32)
+        bias = self.param("bias", nn.initializers.zeros,
+                          (cfg.n_routed_experts,), jnp.float32)
+        return kernel, bias
+
+
+class HeldExperts(nn.Module):
+    """The held experts' three kernels, [experts_held, in, out]."""
+
+    cfg: GlmMoeConfig
+
+    @nn.compact
+    def __call__(self):
+        cfg = self.cfg
+        d, width = cfg.hidden_size, cfg.moe_intermediate_size
+        init = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1,
+                                            batch_axis=(0,))
+        return tuple(
+            self.param(name, init, (cfg.experts_held, *dims),
+                       jnp.float32).astype(cfg.dtype)
+            for name, dims in (("gate", (d, width)), ("up", (d, width)),
+                               ("down", (width, d))))
+
+
+class ExpertLayer(nn.Module):
+    """``(y, counts)``: the held experts' part of the routed result plus
+    the shared expert; ``counts`` int32 [experts_held + 2]: assignments
+    to each held expert, to absent experts, and dropped (always 0)."""
+
+    cfg: GlmMoeConfig
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.cfg
+        e, k, held_n = (cfg.n_routed_experts, cfg.num_experts_per_tok,
+                        cfg.experts_held)
+        if not 0 <= cfg.expert_offset <= e - held_n:
+            raise ValueError(
+                f"experts [{cfg.expert_offset}, {cfg.expert_offset + held_n}"
+                f") are not among the router's {e}")
+        d, width = cfg.hidden_size, cfg.moe_intermediate_size
+        xf = x.reshape(-1, d)
+        n = xf.shape[0]
+
+        with jax.named_scope("moe.route"):
+            # float32 at full precision: a rounded score moves the
+            # choice (the chip's default runs an f32 matmul in bf16).
+            w_r, bias = Router(cfg, name="router")()
+            scores = jax.nn.sigmoid(jnp.dot(
+                xf.astype(jnp.float32), w_r,
+                precision=jax.lax.Precision.HIGHEST))
+            _, chosen = jax.lax.top_k(
+                scores + jax.lax.stop_gradient(bias), k)       # [N, k]
+            # kept only where a caller asks for ``intermediates``
+            self.sow("intermediates", "chosen", chosen)
+            picked = jnp.take_along_axis(scores, chosen, axis=-1)
+            gates = cfg.routed_scaling_factor * picked \
+                / jnp.sum(picked, axis=-1, keepdims=True)
+
+        with jax.named_scope("moe.dispatch"):
+            local = chosen - cfg.expert_offset
+            held = (local >= 0) & (local < held_n)             # [N, k]
+            group = jnp.where(held, local, held_n).reshape(-1)
+            order = jnp.argsort(group, stable=True).astype(jnp.int32)
+            inv = jnp.zeros_like(order).at[order].set(
+                jnp.arange(n * k, dtype=jnp.int32))
+            sizes = jnp.zeros((held_n + 1,), jnp.int32).at[group].add(1)
+            held_total = jnp.sum(sizes[:held_n])
+            gate_rows = jnp.where(held, gates, 0.0).reshape(-1)
+
+        w_gate, w_up, w_down = HeldExperts(cfg, name="experts")()
+
+        def routed(cap):
+            """The held experts' part through a row buffer of ``cap``."""
+            def run(_):
+                first = order[:cap]
+                with jax.named_scope("moe.dispatch"):
+                    rows = rows_to_experts(xf, first, inv, held, k)
+                with jax.named_scope("moe.experts"):
+                    gs = sizes[:held_n]
+                    hidden = nn.silu(jax.lax.ragged_dot(rows, w_gate, gs)) \
+                        * jax.lax.ragged_dot(rows, w_up, gs)
+                    out = jax.lax.ragged_dot(hidden, w_down, gs)
+                with jax.named_scope("moe.combine"):
+                    # rows behind the last group are whatever the
+                    # buffer held: never let them meet a gradient
+                    live = jnp.arange(cap)[:, None] < held_total
+                    out = jnp.where(live, out, 0) \
+                        * gate_rows[first][:, None].astype(out.dtype)
+                    return rows_from_experts(out, first, inv, held, k)
+            return run
+
+        caps = [max(1, int(round(t * n * k))) for t in ROW_TIERS]
+        tier = jnp.sum(held_total > jnp.asarray(caps[:-1], jnp.int32))
+        y = jax.lax.switch(tier, [routed(c) for c in caps], None)
+
+        with jax.named_scope("moe.shared"):
+            y = y + SwiGLU(cfg, width, name="shared")(xf)
+        # held assignments whose row lies behind the buffer that ran: the
+        # last tier holds every row, so none
+        dropped = jnp.sum(held.reshape(-1)
+                          & (inv >= jnp.asarray(caps, jnp.int32)[tier]))
+        counts = jnp.concatenate([sizes, dropped[None]])
+        return y.reshape(x.shape), counts
+
+
+class Block(nn.Module):
+    cfg: GlmMoeConfig
+    use_moe: bool
+
+    @nn.compact
+    def __call__(self, x, positions):
+        cfg = self.cfg
+        x = x + LatentAttention(cfg, name="attn")(
+            _norm(cfg, "ln1")(x), positions)
+        h = _norm(cfg, "ln2")(x)
+        if self.use_moe:
+            y, counts = ExpertLayer(cfg, name="moe")(h)
+        else:
+            y = SwiGLU(cfg, cfg.intermediate_size, name="mlp")(h)
+            counts = jnp.zeros((cfg.experts_held + 2,), jnp.int32)
+        return x + y, counts
+
+
+def _keep_kernel_outputs(prim, *_, **__) -> bool:
+    """Remat policy: a recomputed block keeps what its Pallas kernels
+    wrote (flash attention's output and row statistics), so the
+    backward pass does not run the forward kernel again."""
+    return prim.name == "pallas_call"
+
+
+# Every block is recomputed in the backward pass: at the widths this
+# model is published at, six blocks' activations do not fit a chip
+# beside the state (the step without it fails to compile at 16.9 GB).
+RematBlock = nn.remat(Block, policy=_keep_kernel_outputs)
+
+
+class GlmMoeLM(nn.Module):
+    cfg: GlmMoeConfig
+
+    @nn.compact
+    def __call__(self, tokens, positions=None, return_logits=False):
+        """tokens [B, S] -> ``(hidden, mtp_hidden, counts)``: the
+        pre-head states [B, S, D] of the main model (after its final
+        norm) and of the multi-token module (``None`` without one; its
+        position i predicts token i + 2, and its last position has no
+        token to read and no target), and the expert layers' counts
+        [layers, experts_held + 2], dense layers as zeros, the
+        multi-token module's layer last. ``return_logits=True`` gives
+        the main model's float32 logits [B, S, vocab] instead (training
+        goes through ``lm_loss_from_hidden``, which never builds them)."""
+        cfg = self.cfg
+        if positions is None:
+            positions = jnp.broadcast_to(
+                jnp.arange(tokens.shape[1], dtype=jnp.int32)[None],
+                tokens.shape)
+        embed = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
+                         name="embed")
+        x = embed(tokens)
+        counts = []
+        for i in range(cfg.num_layers):
+            x, c = RematBlock(cfg, use_moe=i >= cfg.first_k_dense,
+                              name=f"block_{i}")(x, positions)
+            counts.append(c)
+        hidden = _norm(cfg, "norm_f")(x)
+        head = nn.Dense(cfg.vocab_size, use_bias=False, dtype=jnp.float32,
+                        name="lm_head")
+        if return_logits or self.is_initializing():
+            logits = head(hidden.astype(jnp.float32))
+            if return_logits:
+                return logits
+        mtp_hidden = None
+        if cfg.mtp_layers:
+            with jax.named_scope("mtp"):
+                mtp_hidden, c = MultiTokenModule(cfg, name="mtp")(
+                    x, embed(jnp.roll(tokens, -1, axis=1)), positions)
+            counts.append(c)
+        return hidden, mtp_hidden, jnp.stack(counts)
+
+
+class MultiTokenModule(nn.Module):
+    cfg: GlmMoeConfig
+
+    @nn.compact
+    def __call__(self, h, next_embedded, positions):
+        cfg = self.cfg
+        joined = jnp.concatenate(
+            [_norm(cfg, "norm_e")(next_embedded), _norm(cfg, "norm_h")(h)],
+            -1)
+        x = _dense(cfg, cfg.hidden_size, "proj")(joined)
+        x, counts = RematBlock(cfg, use_moe=True, name="block")(x, positions)
+        return _norm(cfg, "norm_f")(x), counts

@@ -12,15 +12,19 @@ script does, and donated buffers so XLA updates the weights in place.
 
 from __future__ import annotations
 
+import collections
 from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import optax
 
 import horovod_tpu.jax as hvd
 from horovod_tpu import spmd
+from horovod_tpu.common.basics import active_runtime
 from horovod_tpu.compat import jaxshim
+from horovod_tpu.models.glm_moe import ABSENT, DROPPED, GlmMoeLM
 from horovod_tpu.models.resnet import ResNet50
 from horovod_tpu.models.transformer import (
     TransformerConfig, TransformerLM, lm_loss_from_hidden,
@@ -93,6 +97,113 @@ def lm_train_step(model: TransformerLM, tx, mesh):
         in_specs=(rep, rep, jaxshim.partition_spec(AXIS)),
         out_specs=(rep, rep, rep))
     return jax.jit(step, donate_argnums=(0, 1))
+
+
+def glm_moe_loss_fn(model: GlmMoeLM):
+    """``(params, tokens) -> (loss, counts)``: the main next-token
+    cross-entropy plus ``mtp_loss_weight`` times the multi-token one
+    (position i of the module predicts token i + 2), both through the
+    chunked head; ``counts`` are the expert layers' loads
+    ([layers, experts_held + 2], ``glm_moe.ExpertLayer``)."""
+    weight = model.cfg.mtp_loss_weight
+
+    def loss_fn(p, t):
+        hidden, mtp_hidden, counts = model.apply({"params": p}, t)
+        head = p["lm_head"]["kernel"]
+        loss = lm_loss_from_hidden(hidden, head, t)
+        if mtp_hidden is not None:
+            with jax.named_scope("mtp"):
+                # the module's last position read no token and has no
+                # target: the shifted ids are one shorter
+                loss = loss + weight * lm_loss_from_hidden(
+                    mtp_hidden[:, :-1], head, t[:, 1:])
+        return loss, counts
+    return loss_fn
+
+
+def glm_moe_train_step(model: GlmMoeLM, tx, mesh):
+    """``jit(shard_map(step))`` with ``(params, opt_state)`` donated:
+    ``(params, opt_state, tokens) -> (params, opt_state, loss,
+    counts)``. The loss is the mean over the mesh, the expert layers'
+    counts their sum; feed the counts to :class:`MoeLoadFeed`, which
+    never waits for a step."""
+    loss_fn = glm_moe_loss_fn(model)
+
+    def step(p, os_, t):
+        with jax.named_scope("loss"):
+            (loss, counts), grads = jax.value_and_grad(
+                loss_fn, has_aux=True)(p, t)
+        new_p, new_os = _apply(tx, grads, os_, p)
+        with jax.named_scope("exchange"):
+            loss = jax.lax.pmean(loss, AXIS)
+            counts = jax.lax.psum(counts, AXIS)
+        return new_p, new_os, loss, counts
+
+    rep = jaxshim.partition_spec()
+    step = jaxshim.shard_map(
+        step, mesh=mesh,
+        in_specs=(rep, rep, jaxshim.partition_spec(AXIS)),
+        out_specs=(rep, rep, rep, rep))
+    return jax.jit(step, donate_argnums=(0, 1))
+
+
+class MoeLoadFeed:
+    """The host's end of the expert layers' counters: takes each step's
+    counts as the device array the step returned and adds them to the
+    metrics registry once they are there, **without waiting for a
+    step**: an array still in flight stays queued until a later
+    ``push`` or the registry's next snapshot finds it ready. With the
+    metrics plane off (the default) a push keeps nothing.
+
+    ``hvd_moe_assignments_total{held="1"|"0"}``: token-to-expert
+    assignments to the experts this chip holds, and to the absent ones;
+    ``hvd_moe_dropped_total``: assignments to a held expert that were
+    not computed (the layer drops none); ``hvd_moe_steps_total``: steps
+    counted so far; ``hvd_moe_expert_load_max_over_mean``: of the last
+    step counted, the fullest held expert's load over the mean load,
+    the worst expert layer's."""
+
+    def __init__(self):
+        reg = active_runtime().metrics
+        self.enabled = bool(getattr(reg, "enabled", False))
+        self._pending: collections.deque = collections.deque()
+        if not self.enabled:
+            return
+        self._held = reg.counter(
+            'hvd_moe_assignments_total{held="1"}',
+            "token-to-expert assignments to experts this chip holds")
+        self._absent = reg.counter(
+            'hvd_moe_assignments_total{held="0"}',
+            "token-to-expert assignments to experts held elsewhere")
+        self._dropped = reg.counter(
+            "hvd_moe_dropped_total",
+            "assignments to a held expert that were not computed")
+        self._steps = reg.counter("hvd_moe_steps_total",
+                                  "steps whose expert loads were counted")
+        self._skew = reg.gauge(
+            "hvd_moe_expert_load_max_over_mean",
+            "fullest held expert's load over the mean, the worst expert "
+            "layer of the last step counted", agg="max")
+        reg.add_collector(self.drain)
+
+    def push(self, counts) -> None:
+        if self.enabled:
+            self._pending.append(counts)
+            self.drain()
+
+    def drain(self) -> None:
+        """Count every queued step whose array is ready; never blocks."""
+        while self._pending and self._pending[0].is_ready():
+            counts = np.asarray(self._pending.popleft())
+            loads = counts[:, :ABSENT]
+            loads = loads[loads.sum(axis=1) > 0]     # expert layers only
+            self._held.inc(int(loads.sum()))
+            self._absent.inc(int(counts[:, ABSENT].sum()))
+            self._dropped.inc(int(counts[:, DROPPED].sum()))
+            self._steps.inc(1)
+            if loads.size:
+                self._skew.set(float(
+                    (loads.max(axis=1) / loads.mean(axis=1)).max()))
 
 
 def resnet_train_step(model: ResNet50, tx, mesh):

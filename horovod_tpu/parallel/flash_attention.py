@@ -378,24 +378,28 @@ def _shapes_ok(seq_q, seq_k, block_q, block_k):
 # lengths (ring shards, tests) degrade gracefully instead of falling
 # back to dense.
 #
-# The 512x1024 default was only ever validated for D <= 128
-# (ADVICE r05): the kernels' resident VMEM grows linearly with D —
-# per program roughly (block_q + 2*block_k) * D tile elements plus
-# the (block_q, D) f32 accumulator (the backward adds do/dq tiles of
-# the same shape) — so at D=256 the 512x1024 tile pair already sits
-# near ~3 MB of f32 working set and at D=512 it would blow the
-# ~16 MB/core VMEM budget outright once double-buffered pipelining
-# and the p-block scratch are counted. _ladders_for halves the
-# ladder per doubling past 128 so the working set stays roughly
-# D-invariant; tiles never drop below the 128-lane MXU width.
+# The kernels' resident VMEM grows linearly with D: per program
+# roughly (block_q + 2*block_k) * D tile elements plus the
+# (block_q, D) f32 accumulator (the backward adds do/dq tiles of the
+# same shape). _ladders_for halves the ladder per doubling past
+# _HEAD_DIM_BASE so the working set stays roughly D-invariant; tiles
+# never drop below the 128-lane MXU width.
+#
+# Measured at D=256 on v5e silicon (PR 27: B4 H20 S4096, one layer's
+# forward + dq + dk/dv, ms): 512x1024 8.08 + 20.46 = 28.54; 512x512
+# 28.98; 1024x512 27.36; 256x1024 32.82; the halved pair 256x512,
+# which ADVICE r05 reasoned and nobody had run, 11.46 + 24.78 = 36.24
+# (27% slower); 256x256 46.29; 128x512 53.79. 1024x1024 and 2048x512
+# do not fit VMEM. So the default pair holds through D=256 and the
+# halving starts past it (D=512 and beyond are still unmeasured).
 _BLOCK_Q_LADDER = (512, 256, 128)
 _BLOCK_K_LADDER = (1024, 512, 256, 128)
-_HEAD_DIM_BASE = 128  # the largest D the default ladder was measured at
+_HEAD_DIM_BASE = 256  # the largest D the default ladder was measured at
 
 
 def _ladders_for(head_dim: int):
     """(q_ladder, k_ladder) scaled to ``head_dim``: the measured
-    512x1024 defaults up to D=128, then each doubling of D halves the
+    512x1024 defaults up to D=256, then each doubling of D halves the
     leading tiles (floor 128) so per-program VMEM stays level."""
     q_top, k_top = _BLOCK_Q_LADDER[0], _BLOCK_K_LADDER[0]
     d = max(1, int(head_dim))

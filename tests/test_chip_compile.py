@@ -58,6 +58,9 @@ _CASES = [
     ("bench-d128-128x128", 64, 2048, 128, 128, 128),
     ("d64-512x1024", 64, 2048, 64, *_top(64)),
     ("d256-ladder", 32, 2048, 256, *_top(256)),
+    # the latent attention of glm47flash-injit-1chip: B4 x 20 heads
+    ("glm-d256-s4096", 80, 4096, 256, *_top(256)),
+    ("d512-ladder", 16, 2048, 512, *_top(512)),
     ("ring-shard-s512", 64, 512, 128, 512, 512),
 ]
 
@@ -94,7 +97,8 @@ def test_flash_backward_compiles_for_v5e(chip, bh, seq, d, block_q,
     assert _kernel_calls(compiled) == 2  # dq, and dk/dv
 
 
-def test_d256_ladder_is_halved():
-    """The D=256 case above compiles the ladder ADVICE r05 asked for,
-    not the D<=128 default."""
-    assert _top(128) == (512, 1024) and _top(256) == (256, 512)
+def test_d256_keeps_the_default_pair_and_d512_is_halved():
+    """The D=256 cases above compile the pair the chip measured fastest
+    of the ladder there (PR 27), the D=512 case the halved one."""
+    assert _top(128) == _top(256) == (512, 1024)
+    assert _top(512) == (256, 512)
