@@ -58,7 +58,10 @@ def DistributedOptimizer(tx, op: int = _spmd.Average,
     (reference: horovod/tensorflow/__init__.py:151-249). Use inside a
     shard_map/pjit-traced step with ``axis`` in scope; under a plain
     jit (GSPMD) you don't need it at all — replicated params + sharded
-    batch already imply the gradient all-reduce."""
+    batch already imply the gradient all-reduce. Give the step's
+    ``jax.jit`` ``compiler_options=spmd.overlap_compiler_options(mesh,
+    axis)`` so that on a TPU mesh of several chips the all-reduces run
+    under the backward (docs/parallelism.md)."""
     import optax
 
     def init_fn(params):

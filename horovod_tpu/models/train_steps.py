@@ -77,6 +77,17 @@ def _apply(tx, grads, opt_state, params):
         return optax.apply_updates(params, updates), opt_state
 
 
+def _jit_step(step, mesh, donate_argnums):
+    """``jax.jit`` of a step whose gradients are reduced over the
+    mesh's ``data`` axis: where that axis spans chips the compiler is
+    asked to run the all-reduces under the backward
+    (``spmd.overlap_compiler_options``); over a mesh of one the step
+    is jitted as ever."""
+    return jax.jit(
+        step, donate_argnums=donate_argnums,
+        compiler_options=spmd.overlap_compiler_options(mesh, AXIS))
+
+
 def lm_train_step(model: TransformerLM, tx, mesh):
     """``jit(shard_map(step))`` with ``(params, opt_state)`` donated:
     ``(params, opt_state, tokens) -> (params, opt_state, loss)``. The
@@ -96,7 +107,7 @@ def lm_train_step(model: TransformerLM, tx, mesh):
         step, mesh=mesh,
         in_specs=(rep, rep, jaxshim.partition_spec(AXIS)),
         out_specs=(rep, rep, rep))
-    return jax.jit(step, donate_argnums=(0, 1))
+    return _jit_step(step, mesh, donate_argnums=(0, 1))
 
 
 def glm_moe_loss_fn(model: GlmMoeLM):
@@ -144,7 +155,7 @@ def glm_moe_train_step(model: GlmMoeLM, tx, mesh):
         step, mesh=mesh,
         in_specs=(rep, rep, jaxshim.partition_spec(AXIS)),
         out_specs=(rep, rep, rep, rep))
-    return jax.jit(step, donate_argnums=(0, 1))
+    return _jit_step(step, mesh, donate_argnums=(0, 1))
 
 
 class MoeLoadFeed:
@@ -233,7 +244,7 @@ def resnet_train_step(model: ResNet50, tx, mesh):
     step = jaxshim.shard_map(
         step, mesh=mesh, in_specs=(rep, rep, rep, batch, batch),
         out_specs=(rep, rep, rep, rep))
-    return jax.jit(step, donate_argnums=(0, 1, 2))
+    return _jit_step(step, mesh, donate_argnums=(0, 1, 2))
 
 
 def synthetic_tokens(seed: int, batch: int, seq: int, vocab: int, mesh):

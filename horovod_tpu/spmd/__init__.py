@@ -225,6 +225,9 @@ def shard_batch(mesh, batch, axis: AxisName = "data"):
 from horovod_tpu.spmd.zero import (  # noqa: E402
     zero_optimizer, zero_state_specs, sharded_clip_by_global_norm,
 )
+from horovod_tpu.spmd.overlap import (  # noqa: E402
+    overlap_compiler_options, collective_schedule,
+)
 
 __all__ = [
     "Average", "Sum", "Min", "Max",
@@ -233,4 +236,5 @@ __all__ = [
     "allreduce_gradients", "broadcast_variables",
     "batch_sharding", "replicated_sharding", "shard_batch",
     "zero_optimizer", "zero_state_specs", "sharded_clip_by_global_norm",
+    "overlap_compiler_options", "collective_schedule",
 ]

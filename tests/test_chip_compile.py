@@ -1,10 +1,13 @@
-"""The flash kernels compile for the chip the benchmark runs on.
+"""The flash kernels compile for the chip the benchmark runs on, and
+the data-parallel step's all-reduces are scheduled under its backward.
 
 The TPU's compiler is installed wherever the tests run and compiles
 for a chip that is described, not attached (``v5e:2x2``, device kind
 ``TPU v5 lite``). Interpret mode on the CPU cannot see what it refuses:
-a slice off the tiling, too much VMEM. Each case is one or two seconds;
-the whole-step compiles (20 to 40 s) stay out of tier-1.
+a slice off the tiling, too much VMEM. Each kernel case is one or two
+seconds; the whole-step compiles at the cells' widths (20 to 170 s)
+stay out of tier-1, and narrow steps over a mesh of the four described
+chips (5 to 15 s each) stand in for them.
 
 Such compiles write persistent-cache entries that cannot be read back
 without a chip, so the cache is off around this file.
@@ -27,11 +30,11 @@ pytestmark = pytest.mark.fast
 
 
 @pytest.fixture(scope="module")
-def chip():
-    """One described v5e chip, as a sharding for abstract arguments."""
+def topo():
+    """The four described chips of a v5e host, the compile cache off
+    while they are in use."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
-    from jax.sharding import SingleDeviceSharding
     try:
         topo = topologies.get_topology_desc(
             platform="tpu", topology_name="v5e:2x2")
@@ -41,9 +44,24 @@ def chip():
     was_on = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was_on)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def chip(topo):
+    """One described v5e chip, as a sharding for abstract arguments."""
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def mesh4(topo):
+    """The mesh of ``lm-injit-4chip``: ``data``=4 over the host's chips."""
+    import numpy as np
+    from jax.sharding import Mesh
+    return Mesh(np.array(topo.devices), ("data",))
 
 
 def _top(head_dim):
@@ -102,3 +120,135 @@ def test_d256_keeps_the_default_pair_and_d512_is_halved():
     of the ladder there (PR 27), the D=512 case the halved one."""
     assert _top(128) == _top(256) == (512, 1024)
     assert _top(512) == (256, 512)
+
+
+# -- the data-parallel step over four chips (spmd/overlap.py) -------------
+
+def _abstract(tree, sharding):
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        tree)
+
+
+def _flash(q, k, v, causal=True):
+    # ``best_attention`` picks by the default backend, the CPU's here
+    from horovod_tpu.parallel.flash_attention import flash_attention
+    return flash_attention(q, k, v, causal=causal, interpret=False)
+
+
+def _lm_step_compiled(mesh4):
+    """``lm_train_step`` of a two-layer ``TransformerLM`` cut to half
+    the cell's width (d 1024: its MLP, head and embedding leaves are
+    16.8 MB each, its attention leaves 4.2 MB), compiled for the mesh."""
+    from horovod_tpu import spmd
+    from horovod_tpu.models import train_steps
+    from horovod_tpu.models.transformer import (
+        TransformerConfig, TransformerLM)
+    model = TransformerLM(TransformerConfig(
+        vocab_size=4096, num_layers=2, num_heads=8, head_dim=128,
+        max_seq_len=256, dtype=jnp.bfloat16, attention_fn=_flash))
+    tx = train_steps.distributed_sgd()
+    params = jax.eval_shape(
+        lambda k: model.init(k, jnp.zeros((1, 256), jnp.int32)),
+        jax.random.key(0))["params"]
+    rep = spmd.replicated_sharding(mesh4)
+    tokens = jax.ShapeDtypeStruct((4, 256), jnp.int32,
+                                  sharding=spmd.batch_sharding(mesh4))
+    step = train_steps.lm_train_step(model, tx, mesh4)
+    compiled = step.lower(_abstract(params, rep),
+                          _abstract(jax.eval_shape(tx.init, params), rep),
+                          tokens).compile()
+    n_bytes = sum(4 * p.size for p in jax.tree_util.tree_leaves(params))
+    return compiled, n_bytes
+
+
+def test_lm_step_reduces_its_gradients_under_the_backward(mesh4):
+    """With the options the step asks for itself, most of the
+    gradients' bytes go in asynchronous pairs that have compute ops
+    scheduled between start and done, and the flash kernels are still
+    in the executable."""
+    from horovod_tpu import spmd
+    assert spmd.overlap_compiler_options(mesh4)
+    compiled, n_bytes = _lm_step_compiled(mesh4)
+    got = spmd.collective_schedule(compiled)
+    hidden = got["async"]["overlapped"]
+    assert got["sync"]["bytes"] + got["async"]["bytes"] == n_bytes + 4
+    assert hidden["count"] >= 4
+    assert hidden["bytes"] > got["sync"]["bytes"]
+    assert hidden["bytes"] == got["async"]["bytes"]
+    assert _kernel_calls(compiled) == 2 * 3     # fwd, dq, dk/dv a layer
+
+
+def test_lm_step_without_the_options_reduces_synchronously(
+        mesh4, monkeypatch):
+    """The same step without the options: every all-reduce is
+    synchronous. If a libtpu changes that default, this fails and the
+    options can go."""
+    from horovod_tpu import spmd
+    monkeypatch.setattr(spmd, "overlap_compiler_options",
+                        lambda mesh, axis="data": None)
+    compiled, n_bytes = _lm_step_compiled(mesh4)
+    got = spmd.collective_schedule(compiled)
+    assert got["async"]["count"] == 0
+    assert got["sync"]["bytes"] == n_bytes + 4
+
+
+def _resnet_step(mesh4):
+    """``resnet_train_step`` at ResNet-18's depth (the step is the
+    cell's; the 50-layer model compiles in 22 s), 32 x 32 images."""
+    from horovod_tpu import spmd
+    from horovod_tpu.models import train_steps
+    from horovod_tpu.models.resnet import ResNet18
+    model = ResNet18(num_classes=train_steps.RESNET_CLASSES,
+                     dtype=jnp.bfloat16, axis_name=train_steps.AXIS)
+    tx = train_steps.distributed_sgd()
+    variables = jax.eval_shape(
+        lambda k: model.init(k, jnp.zeros((1, 32, 32, 3), jnp.bfloat16),
+                             train=True), jax.random.key(0))
+    rep = spmd.replicated_sharding(mesh4)
+    rows = spmd.batch_sharding(mesh4)
+    args = (_abstract(variables["params"], rep),
+            _abstract(variables["batch_stats"], rep),
+            _abstract(jax.eval_shape(tx.init, variables["params"]), rep),
+            jax.ShapeDtypeStruct((4, 32, 32, 3), jnp.bfloat16,
+                                 sharding=rows),
+            jax.ShapeDtypeStruct((4,), jnp.int32, sharding=rows))
+    return train_steps.resnet_train_step(model, tx, mesh4), args
+
+
+def _glm_moe_step(mesh4):
+    """``glm_moe_train_step`` of a small sparse decoder: latent
+    attention (dense here), two expert layers' worth of routing, the
+    multi-token module."""
+    from horovod_tpu import spmd
+    from horovod_tpu.models import glm_moe, train_steps
+    model = glm_moe.GlmMoeLM(glm_moe.GlmMoeConfig(
+        vocab_size=512, num_layers=2, hidden_size=128, num_heads=2,
+        q_lora_rank=48, kv_lora_rank=32, qk_nope_head_dim=96,
+        qk_rope_head_dim=32, v_head_dim=128, intermediate_size=256,
+        moe_intermediate_size=128, n_routed_experts=8,
+        num_experts_per_tok=2, experts_held=4))
+    tx = train_steps.distributed_sgd()
+    params = jax.eval_shape(
+        lambda k: model.init(k, jnp.zeros((1, 64), jnp.int32)),
+        jax.random.key(0))["params"]
+    rep = spmd.replicated_sharding(mesh4)
+    args = (_abstract(params, rep),
+            _abstract(jax.eval_shape(tx.init, params), rep),
+            jax.ShapeDtypeStruct((4, 64), jnp.int32,
+                                 sharding=spmd.batch_sharding(mesh4)))
+    return train_steps.glm_moe_train_step(model, tx, mesh4), args
+
+
+@pytest.mark.parametrize("build", [_resnet_step, _glm_moe_step],
+                         ids=["resnet", "glm_moe"])
+def test_the_other_steps_compile_with_the_options(mesh4, build):
+    """No cell runs these two steps over four chips; they take the
+    same options there, and the compiler accepts them: the gradients
+    are reduced, every one, in one kind or the other."""
+    from horovod_tpu import spmd
+    step, args = build(mesh4)
+    compiled = step.lower(*args).compile()
+    got = spmd.collective_schedule(compiled)
+    n_bytes = sum(4 * p.size for p in jax.tree_util.tree_leaves(args[0]))
+    assert got["sync"]["bytes"] + got["async"]["bytes"] >= n_bytes
