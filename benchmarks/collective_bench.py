@@ -1354,159 +1354,6 @@ def _compression_bench_section(np_: int) -> dict:
     }
 
 
-def worker_ici(rank: int, size: int) -> None:
-    """ICI-plane A/B leg (ISSUE 18): a steady single-tensor allreduce
-    loop at HVD_BENCH_BYTES with the fused-psum mesh pack toggled by
-    the section driver through the production knob (HOROVOD_TPU_ICI
-    over a forced multi-device host mesh). Besides the median steady
-    latency the report carries the engagement proof the acceptance
-    gates on: ici_cycles advancing while ici_compiles stays flat
-    (every steady cycle rode the PRE-compiled executable) and a zero
-    hvd_data_copies_total delta on the Python side of the mesh leg."""
-    import numpy as np
-    import horovod_tpu as hvd
-    from horovod_tpu.common import basics as _b
-
-    nbytes = int(os.environ.get("HVD_BENCH_BYTES", str(1 << 20)))
-    steps = int(os.environ.get("HVD_BENCH_STEPS",
-                               str(COMP_BENCH_STEPS)))
-    hvd.init()
-    n = max(1, nbytes // 4)
-    x = np.full(n, float(rank + 1), np.float32)
-    ssum = float(sum(range(1, size + 1)))
-
-    out = None
-    for _ in range(5):
-        out = hvd.allreduce(x, average=False, name="ig")
-        time.sleep(COMP_BENCH_GAP_S)
-    assert abs(float(np.asarray(out)[0]) - ssum) < 1e-3
-    hvd.barrier(name="ig.bar")
-    rt = _b.runtime()
-    s0 = rt.negotiation_cache_stats()
-    c0 = hvd.metrics()["local"].get("hvd_data_copies_total",
-                                    {"v": 0.0})["v"]
-    times = []
-    for _ in range(steps):
-        t0 = time.perf_counter()
-        hvd.allreduce(x, average=False, name="ig")
-        times.append(time.perf_counter() - t0)
-        time.sleep(COMP_BENCH_GAP_S)
-    s1 = rt.negotiation_cache_stats()
-    c1 = hvd.metrics()["local"].get("hvd_data_copies_total",
-                                    {"v": 0.0})["v"]
-    out = hvd.allreduce(x, average=False, name="ig")
-    assert abs(float(np.asarray(out)[0]) - ssum) < 1e-3
-    _, med, _ = _quantiles(times)
-    report = {
-        "bytes": nbytes,
-        "steps": steps,
-        "us_per_op": round(med * 1e6, 1),
-        "ici": os.environ.get("HOROVOD_TPU_ICI", "0"),
-        "compression": os.environ.get("HOROVOD_COMPRESSION", "none"),
-        "ici_cycles": s1["ici_cycles"] - s0["ici_cycles"],
-        "ici_compiles_delta": s1["ici_compiles"] - s0["ici_compiles"],
-        "data_copies_delta": c1 - c0,
-    }
-    if rank == 0:
-        print("RESULT " + json.dumps(report), flush=True)
-    hvd.shutdown()
-
-
-def _ici_bench_section(np_: int) -> dict:
-    """The ISSUE 18 acceptance A/B at world_size=np_, each rank
-    holding a forced 8-device host mesh: HOROVOD_TPU_ICI on vs off on
-    the socket-star steady loop, ISOLATED ALTERNATING legs (adjacent
-    runs see similar throttle states) plus one SIMULTANEOUS pair
-    (both worlds see the identical machine at every instant). The
-    engagement proof — steady cycles riding the pre-compiled
-    fused-psum executable with a flat compile count and zero Python-
-    side data copies — is recorded from the ON worlds; the latency
-    ratio is recorded without a pass threshold (on a CPU loopback
-    mesh the device round trip competes with a plain numpy cast; on
-    real ICI the pack/cast/reduce runs where the gradients already
-    live)."""
-    import threading
-
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "xla_force_host_platform_device_count" not in flags:
-        flags = (flags
-                 + " --xla_force_host_platform_device_count=8").strip()
-    base = {"HOROVOD_TPU_SHM": "0", "HOROVOD_TPU_RING_THRESHOLD": "-1",
-            "HOROVOD_TPU_METRICS": "1", "XLA_FLAGS": flags}
-    on_env = dict(base, HOROVOD_TPU_ICI="1")
-    big = 1 << 20
-
-    iso = {"off": [], "on": []}
-    iso_ratios = []
-    engaged = []
-    for _ in range(3):
-        a = _run_world("ici", np_, timeout=600.0,
-                       extra_env=dict(base, HVD_BENCH_BYTES=str(big)))
-        b = _run_world("ici", np_, timeout=600.0,
-                       extra_env=dict(on_env,
-                                      HVD_BENCH_BYTES=str(big)))
-        iso["off"].append(a["us_per_op"])
-        iso["on"].append(b["us_per_op"])
-        iso_ratios.append(a["us_per_op"] / b["us_per_op"])
-        engaged.append(b)
-        print(f"  isolated off {a['us_per_op']} us/op vs on "
-              f"{b['us_per_op']} us/op  (ici_cycles="
-              f"{b['ici_cycles']}, compiles_delta="
-              f"{b['ici_compiles_delta']}, copies_delta="
-              f"{b['data_copies_delta']})", flush=True)
-    iso_ratios.sort()
-
-    pair = {}
-
-    def _go(key, env):
-        pair[key] = _run_world(
-            "ici", np_, timeout=600.0,
-            extra_env=dict(env, HVD_BENCH_BYTES=str(big)))
-
-    ta = threading.Thread(target=_go, args=("off", base))
-    tb = threading.Thread(target=_go, args=("on", on_env))
-    ta.start()
-    tb.start()
-    ta.join()
-    tb.join()
-
-    # the bf16 mesh leg: prescale+cast fused into the same executable
-    comp = _run_world(
-        "ici", np_, timeout=600.0,
-        extra_env=dict(on_env, HOROVOD_COMPRESSION="bf16",
-                       HVD_BENCH_BYTES=str(big)))
-
-    cycles_ok = all(e["ici_cycles"] >= e["steps"] for e in engaged)
-    compiles_ok = all(e["ici_compiles_delta"] == 0 for e in engaged)
-    copies_ok = all(e["data_copies_delta"] == 0
-                    for e in engaged + [comp])
-    return {
-        "world_size": np_,
-        "devices_per_rank": 8,
-        "cores": os.cpu_count(),
-        "bytes": big,
-        "isolated_us_per_op": iso,
-        "isolated_ratios_off_over_on":
-            [round(r, 2) for r in iso_ratios],
-        "isolated_ratio_off_over_on":
-            round(iso_ratios[len(iso_ratios) // 2], 2),
-        "pair_off_us_per_op": pair["off"]["us_per_op"],
-        "pair_on_us_per_op": pair["on"]["us_per_op"],
-        "pair_ratio_off_over_on": round(
-            pair["off"]["us_per_op"] / pair["on"]["us_per_op"], 2),
-        "bf16_on_us_per_op": comp["us_per_op"],
-        "steady_cycles_on_plane_pass": cycles_ok,
-        "compile_count_flat_pass": compiles_ok,
-        "data_copies_zero_pass": copies_ok,
-        "note": (
-            "CPU loopback mesh: the A/B isolates plumbing overhead, "
-            "not ICI bandwidth — the device round trip competes with "
-            "a host memcpy here, while on a real slice the fused "
-            "executable replaces the host pack AND the cross-rank "
-            "reduce"),
-    }
-
-
 def worker_autotune_value(rank: int, size: int) -> None:
     """Autotune VALUE demo (not just mechanics): a fusion-sensitive
     workload — many small allreduces per step — measured under (a)
@@ -2413,7 +2260,7 @@ def main() -> None:
                              "compression_autotune", "overlap",
                              "multitenant",
                              "kernel_gather", "kernel_relay",
-                             "selfop_sync", "ici"])
+                             "selfop_sync"])
     ap.add_argument("--rank", type=int)
     ap.add_argument("--size", type=int)
     ap.add_argument("--skip-variants", action="store_true",
@@ -2463,14 +2310,6 @@ def main() -> None:
                          "the same 64 MiB model-shaped state, socket "
                          "plane; zero-copy delta recorded) and merge "
                          "it into RESULTS_cpu.json")
-    ap.add_argument("--ici", action="store_true",
-                    help="run just the ICI-plane A/B (fused-psum "
-                         "steady pack over a forced 8-device host "
-                         "mesh, HOROVOD_TPU_ICI on/off; isolated-"
-                         "alternating + simultaneous-pair protocols; "
-                         "engagement proof: pre-compiled executable "
-                         "reuse + zero data copies) and merge it "
-                         "into RESULTS_cpu.json")
     ap.add_argument("--compression", action="store_true",
                     help="run just the wire-compression/two-level "
                          "grid ((algorithm x dtype x bucket) medians "
@@ -2496,7 +2335,6 @@ def main() -> None:
          "kernel_gather": worker_kernel_gather,
          "kernel_relay": worker_kernel_relay,
          "selfop_sync": worker_selfop_sync,
-         "ici": worker_ici,
          "overhead": worker_overhead}[args.worker](
              args.rank, args.size)
         return
@@ -2626,29 +2464,6 @@ def main() -> None:
             json.dump(merged, fh, indent=2)
             fh.write("\n")
         print(f"merged compression into {results_path}")
-        return
-
-    if args.ici:
-        np_ici = min(np_, 2)  # each rank carries its own 8-dev mesh
-        print(f"== ICI fused-psum plane A/B (np={np_ici}, 8 forced "
-              f"devices per rank) ==", flush=True)
-        ic = _ici_bench_section(np_ici)
-        print(f"  isolated off/on ratio "
-              f"{ic['isolated_ratio_off_over_on']}x   pair "
-              f"{ic['pair_ratio_off_over_on']}x   steady-on-plane "
-              f"pass={ic['steady_cycles_on_plane_pass']}   compiles "
-              f"flat pass={ic['compile_count_flat_pass']}   copies "
-              f"zero pass={ic['data_copies_zero_pass']}", flush=True)
-        try:
-            with open(results_path) as fh:
-                merged = json.load(fh)
-        except (OSError, ValueError):
-            merged = {}
-        merged["ici"] = ic
-        with open(results_path, "w") as fh:
-            json.dump(merged, fh, indent=2)
-            fh.write("\n")
-        print(f"merged ici into {results_path}")
         return
 
     if args.overlap:

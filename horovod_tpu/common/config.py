@@ -195,21 +195,6 @@ class Config:
     two_level: bool = False
     two_level_threshold_bytes: int = 0
 
-    # ICI-native data plane (HOROVOD_TPU_ICI=1): fused allreduce
-    # batches stamped ALG_ICI pack/prescale/cast on-device through ONE
-    # pre-compiled fused-psum XLA executable over the local device mesh
-    # (ops/xla_ops.py IciPlane), then ride the existing compressed
-    # socket/ring plane for the cross-slice (DCN) leg. Requires >= 2
-    # local devices (ici_devices caps how many the plane meshes over; 0
-    # = all visible). The capability is world-AND-agreed at init so
-    # heterogeneous worlds degrade to the socket plane consistently.
-    # With HOROVOD_AUTOTUNE=1, ALG_ICI instead joins the per-bucket
-    # discrete grid; without it, HOROVOD_TPU_ICI_THRESHOLD gates the
-    # static stamp by fused-batch size.
-    ici_enabled: bool = False
-    ici_devices: int = 0
-    ici_threshold_bytes: int = 0
-
     # Idle backoff for the background loop (TPU-native extension): after
     # a grace period of empty cycles the negotiation sleep ramps toward
     # this cap instead of waking every cycle_time_ms forever; enqueue
@@ -439,11 +424,6 @@ class Config:
         c.two_level = _env_bool("HOROVOD_TWO_LEVEL", c.two_level)
         c.two_level_threshold_bytes = _env_int(
             "HOROVOD_TWO_LEVEL_THRESHOLD", c.two_level_threshold_bytes)
-        c.ici_enabled = _env_bool("HOROVOD_TPU_ICI", c.ici_enabled)
-        c.ici_devices = _env_int("HOROVOD_TPU_ICI_DEVICES",
-                                 c.ici_devices)
-        c.ici_threshold_bytes = _env_int(
-            "HOROVOD_TPU_ICI_THRESHOLD", c.ici_threshold_bytes)
         c.idle_backoff_ms = _env_float(
             "HOROVOD_TPU_IDLE_BACKOFF", c.idle_backoff_ms)
         c.hierarchical_allreduce = _env_bool(

@@ -2541,7 +2541,7 @@ class TcpWorker(Controller):
                 f"control channel to {self._ch.peer} closed before "
                 f"the steady cycle")
         kind, val = _steady.run_worker_cycle(
-            lib, plan, fd, self._ch.secret, bufs,
+            lib, plan, fd, self._ch.secret,
             bytes((TAG_PING, TAG_METRICS, TAG_TRACE)), TAG_REQUESTS,
             TAG_RESPONSES, self._ch._hb)
         if self._metrics_on:

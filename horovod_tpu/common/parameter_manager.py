@@ -217,16 +217,14 @@ class ParameterManager:
     # -- wire plan (algorithm x dtype per size bucket) -------------------
     def configure_wire(self, proposed_wire: int, multi_host: bool,
                        world_size: int, shm_enabled: bool = True,
-                       ring_allowed: bool = True,
-                       ici_allowed: bool = False) -> None:
+                       ring_allowed: bool = True) -> None:
         """Arm the discrete grid phase (coordinator only). Algorithm
         candidates follow topology AND configuration feasibility
         (ring needs >= 3 ranks and must not be explicitly disabled;
-        two-level needs a multi-host world with the shm plane on;
-        ICI needs the world-agreed mesh plane — HOROVOD_TPU_ICI with
-        every rank holding >= 2 local devices — because a stamped
-        combo whose plane cannot engage would just measure default
-        routing twice under a misleading name); wire candidates are
+        two-level needs a multi-host world with the shm plane on,
+        because a stamped combo whose plane cannot engage would just
+        measure default routing twice under a misleading name); wire
+        candidates are
         every dtype AT OR BELOW this world's proposal — the tuner
         explores by CAPPING the negotiated verdict, so it can never
         compress harder than the operator asked (numerics-safe)."""
@@ -237,8 +235,6 @@ class ParameterManager:
             algs.append(_wd.ALG_RING)
         if multi_host and shm_enabled:
             algs.append(_wd.ALG_TWOLEVEL)
-        if ici_allowed:
-            algs.append(_wd.ALG_ICI)
         wires = [w for w in (_wd.WIRE_NONE, _wd.WIRE_BF16,
                              _wd.WIRE_FP16, _wd.WIRE_INT8)
                  if w <= proposed_wire]

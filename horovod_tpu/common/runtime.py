@@ -260,31 +260,6 @@ class Runtime:
         t = getattr(controller, "topology", None)
         self._multi_host = (t is not None
                             and t.local_size < t.size)
-        # -- ICI-native data plane (HOROVOD_TPU_ICI, ops/xla_ops.py) ---
-        # The fused-psum steady cycle: ALG_ICI-stamped buckets pack/
-        # prescale/cast through ONE pre-compiled XLA executable over
-        # the local device mesh, and the resulting wire buffer rides
-        # the existing compressed socket/ring plane cross-slice. The
-        # capability is world-AND-agreed HERE — a fixed init position
-        # every rank reaches exactly once, right after the controller
-        # handshake — so a single mesh-less rank degrades the verdict
-        # to the socket plane everywhere, together. (HOROVOD_TPU_ICI
-        # itself must be set world-wide, like HOROVOD_TWO_LEVEL.)
-        self._ici_plane = None
-        self._ici_cycles = 0
-        if config.ici_enabled and controller.size > 1:
-            from horovod_tpu.ops.xla_ops import IciPlane
-            plane = IciPlane(config.ici_devices)
-            local_ok = plane.probe()
-            if controller.agree(local_ok):
-                self._ici_plane = plane
-            elif controller.rank == 0:
-                hlog.warning(
-                    "HOROVOD_TPU_ICI=1 degraded to the socket plane: "
-                    "at least one rank has no local multi-device mesh "
-                    "(needs >= 2 devices; set XLA_FLAGS="
-                    "--xla_force_host_platform_device_count=N for a "
-                    "CPU-mesh run)")
         # Algorithm/dtype policy consulted when stamping fused
         # responses (coordinator only): the autotuner when armed
         # (ParameterManager.plan — per-size-bucket tuned table), the
@@ -294,8 +269,7 @@ class Runtime:
             parameter_manager.configure_wire(
                 self._wire_propose, self._multi_host, controller.size,
                 shm_enabled=config.shm_enabled,
-                ring_allowed=config.ring_threshold_bytes >= 0,
-                ici_allowed=self._ici_plane is not None)
+                ring_allowed=config.ring_threshold_bytes >= 0)
             # Overlap bucket count joins the discrete grid (measured
             # between the wire sweep and the BO phase) only when the
             # overlap tier can actually engage on this rank.
@@ -304,9 +278,7 @@ class Runtime:
         else:
             self._wire_policy = _wd.StaticWirePolicy(
                 config.two_level, config.two_level_threshold_bytes,
-                self._multi_host, shm_enabled=config.shm_enabled,
-                ici_allowed=self._ici_plane is not None,
-                ici_threshold_bytes=config.ici_threshold_bytes)
+                self._multi_host, shm_enabled=config.shm_enabled)
             if config.two_level and controller.rank == 0 \
                     and not (self._multi_host and config.shm_enabled):
                 hlog.warning(
@@ -564,8 +536,6 @@ class Runtime:
         controller.attach_metrics(reg)
         op_manager.attach_metrics(
             reg, lambda: self._world_fusion_threshold)
-        if self._ici_plane is not None:
-            self._ici_plane.attach_metrics(reg)
         # Rank-0 world aggregation + read surfaces: control-tree
         # METRICS frames fold here, exposed over Prometheus HTTP
         # (HOROVOD_TPU_METRICS_PORT), a JSONL snapshot log
@@ -1263,25 +1233,28 @@ class Runtime:
     def _build_spec_frame(self, hit_mask: int, bit_requests):
         """Build a fused speculative cycle frame: the pure-hit bitmask
         PLUS this rank's pre-packed fused allreduce buffers in
-        replay-plan order, or None when the batch is not speculation-
-        eligible (non-allreduce entries in the steady set, a data
-        plane of its own — shm/ring/XLA — would carry it, or an entry
-        vanished). Entries are only PEEKED: the world may still deny
-        the grant, in which case the classic path pops them later.
+        replay-plan order, or None when the steady set may not be bid
+        (_spec_admitted). Entries are only PEEKED: the world may still
+        deny the grant, in which case the classic path pops them
+        later."""
+        admitted = self._spec_admitted(hit_mask, bit_requests)
+        if admitted is None:
+            return None
+        return self._pack_spec_frame(hit_mask, admitted)
 
-        The WHOLE plan is held to every eligibility check — the
-        backend's fused_cycle_reducible among them, asked with the
-        batch's size from metadata (_batch_nbytes) — before the first
-        payload is converted: a decline costs no device -> host copy
-        and no wait for the computation that produces the tensors,
-        and the answer is kept per steady set (_spec_declined).
+    def _spec_admitted(self, hit_mask: int, bit_requests):
+        """May this steady set be bid? The replay plan's
+        ``[(response, entries)]`` when every batch is speculation-
+        eligible, else None (non-allreduce entries in the steady set,
+        a data plane of its own — shm/ring/XLA — would carry it, or an
+        entry vanished).
 
-        With the zero-copy plane engaged, the return value is a
-        SteadyPlan (packed into the persistent fusion arena; the
-        cycle then runs as one native call) instead of serialized
-        bytes — _run_loop_once dispatches on the type."""
-        from horovod_tpu.ops.socket_ops import _pack_fused, _to_numpy
-        cache = self._cache
+        The WHOLE plan is held to every check — the backend's
+        fused_cycle_reducible among them, asked with the batch's size
+        from metadata (_batch_nbytes) — and no payload is converted:
+        a decline costs no device -> host copy and no wait for the
+        computation that produces the tensors, and the backend's
+        answer is kept per steady set (_spec_declined)."""
         pm = self.parameter_manager
         if pm is not None and self.controller.is_coordinator \
                 and pm.plan_revision != self._wire_plan_rev:
@@ -1299,13 +1272,9 @@ class Runtime:
         for resp in plan:
             if resp.response_type != ResponseType.ALLREDUCE:
                 return None
-            if resp.algorithm not in (_wd.ALG_DEFAULT, _wd.ALG_STAR,
-                                      _wd.ALG_ICI):
+            if resp.algorithm not in (_wd.ALG_DEFAULT, _wd.ALG_STAR):
                 # Ring/two-level batches own their data plane; the
-                # speculative round must not steal them. ALG_ICI is
-                # admitted on purpose: its intra-slice leg packs on
-                # the mesh BEFORE this very cycle, and its cross-slice
-                # leg IS the speculative star.
+                # speculative round must not steal them.
                 return None
             if resp.wire_dtype == _wd.WIRE_INT8:
                 # int8 payloads carry per-rank scales the inline
@@ -1327,38 +1296,33 @@ class Runtime:
                 self._spec_declines += 1
                 return None
             admitted.append((resp, entries))
-        seg_arrays = []
-        seg_wires = []
-        prescales = []
-        inflight = []
-        for resp, entries in admitted:
-            arrays = [_to_numpy(e.tensor) for e in entries]
-            seg_arrays.append(arrays)
-            seg_wires.append(resp.wire_dtype)
-            prescales.append(resp.prescale_factor)
-            inflight.append((resp, entries, arrays))
+        return admitted
+
+    def _pack_spec_frame(self, hit_mask: int, admitted):
+        """Pack an admitted steady set (_spec_admitted) and count the
+        bid. With the zero-copy plane engaged the return value is a
+        SteadyPlan (packed into the persistent fusion arena; the cycle
+        then runs as one native call), else the serialized frame —
+        _negotiate_and_perform dispatches on the type."""
+        from horovod_tpu.ops.socket_ops import (
+            _pack_fused, _to_numpy, compress_send_payload,
+            record_compression,
+        )
+        inflight = [(resp, entries, [_to_numpy(e.tensor) for e in entries])
+                    for resp, entries in admitted]
         if self._steady_native:
+            seg_arrays = [arrays for _, _, arrays in inflight]
+            seg_wires = [resp.wire_dtype for resp, _ in admitted]
             splan = self._steady_plan_for(hit_mask, seg_arrays,
                                           seg_wires)
             if splan is not None:
-                bufs = None
-                if self._ici_plane is not None and any(
-                        resp.algorithm == _wd.ALG_ICI
-                        for resp, _, _ in inflight):
-                    bufs = self._ici_pack(splan, hit_mask, seg_arrays,
-                                          seg_wires, prescales,
-                                          inflight)
-                if bufs is None:
-                    # Coordinator accumulators double as the broadcast
-                    # result its outputs will alias — fresh, never
-                    # arena.
-                    bufs = splan.pack(
-                        seg_arrays, prescales,
-                        use_arena=not self.controller.is_coordinator)
+                # Coordinator accumulators double as the broadcast
+                # result its outputs will alias — fresh, never arena.
+                bufs = splan.pack(
+                    seg_arrays,
+                    [resp.prescale_factor for resp, _ in admitted],
+                    use_arena=not self.controller.is_coordinator)
                 if any(seg_wires):
-                    from horovod_tpu.ops.socket_ops import (
-                        record_compression,
-                    )
                     record_compression(
                         sum(sum(a.nbytes for a in arrays)
                             for arrays in seg_arrays),
@@ -1368,93 +1332,22 @@ class Runtime:
                 self._spec_bids += 1
                 return splan
         segments = []
-        ici_segs = 0
-        for j, (resp, _, arrays) in enumerate(inflight):
-            w = resp.wire_dtype
-            buf = None
-            if self._ici_plane is not None \
-                    and resp.algorithm == _wd.ALG_ICI:
-                buf = self._ici_pack_segment(
-                    cache.epoch, hit_mask, j, arrays,
-                    resp.prescale_factor, w)
-            if buf is not None:
-                ici_segs += 1
-                if w:
-                    from horovod_tpu.ops.socket_ops import (
-                        record_compression,
-                    )
-                    record_compression(
-                        sum(a.nbytes for a in arrays), buf.nbytes)
-                    segments.append((_wd.wire_datatype(w), buf))
-                else:
-                    segments.append(
-                        (numpy_dtype_to_datatype(buf.dtype), buf))
-                continue
+        for resp, _, arrays in inflight:
             fused, _ = _pack_fused(arrays, resp)  # applies prescale
+            w = resp.wire_dtype
             if w:
-                from horovod_tpu.ops.socket_ops import (
-                    compress_send_payload,
-                )
-                wirearr = compress_send_payload(fused, w)
-                segments.append((_wd.wire_datatype(w), wirearr))
+                segments.append((_wd.wire_datatype(w),
+                                 compress_send_payload(fused, w)))
             else:
                 segments.append((numpy_dtype_to_datatype(fused.dtype),
                                  fused))
-        if ici_segs:
-            self._ici_cycles += 1
         self._spec_inflight = inflight
         self._spec_bids += 1
+        cache = self._cache
         return self._stamp(wire.serialize_cycle_request(
             CacheCycleRequest(
                 epoch=cache.epoch, nslots=cache.nslots,
                 hit_mask=hit_mask, spec_payload=segments)))
-
-    def _ici_pack_segment(self, epoch: int, hit_mask: int, j: int,
-                          arrays, prescale: float, wire_code: int):
-        """One spec-frame segment through the ICI plane's pre-compiled
-        fused-psum executable (concat + prescale + wire cast on the
-        device mesh); None when the plane cannot carry it — the caller
-        falls back to the host pack for bit-identical bytes."""
-        import numpy as np
-
-        plane = self._ici_plane
-        plane.note_cache_epoch(epoch)
-        flats = [a.reshape(-1) if a.flags["C_CONTIGUOUS"]
-                 else np.ascontiguousarray(a).reshape(-1)
-                 for a in arrays]
-        flat = flats[0] if len(flats) == 1 else np.concatenate(flats)
-        try:
-            return plane.fused_pack((epoch, hit_mask, j), flat,
-                                    prescale, wire_code)
-        except Exception as e:
-            # A mid-flight device failure must degrade, not abort: the
-            # host pack produces byte-identical wire payloads.
-            hlog.warning(f"ICI fused pack failed; falling back to the "
-                         f"host pack for this cycle: {e!r}")
-            return None
-
-    def _ici_pack(self, splan, hit_mask: int, seg_arrays, seg_wires,
-                  prescales, inflight):
-        """Pack a whole native steady frame on the ICI plane: every
-        segment must both be stamped ALG_ICI and survive the mesh leg,
-        and the plan must adopt the buffers byte-compatibly; any
-        deviation returns None and SteadyPlan.pack carries the cycle
-        on the host, bit-identically."""
-        epoch = self._cache.epoch
-        bufs = []
-        for j, (arrays, pre) in enumerate(zip(seg_arrays, prescales)):
-            resp = inflight[j][0]
-            if resp.algorithm != _wd.ALG_ICI:
-                return None  # mixed-verdict frame: keep packs uniform
-            buf = self._ici_pack_segment(epoch, hit_mask, j, arrays,
-                                         pre, seg_wires[j])
-            if buf is None:
-                return None
-            bufs.append(buf)
-        adopted = splan.adopt_packed(bufs)
-        if adopted is not None:
-            self._ici_cycles += 1
-        return adopted
 
     def _steady_plan_for(self, hit_mask: int, seg_arrays, seg_wires):
         """Memoized SteadyPlan for (mask, threshold) at the current
@@ -2326,13 +2219,6 @@ class Runtime:
                 f"cannot continue safely")
         if meta.spec_payload is not None:
             return self._complete_spec_cycle(meta, bit_requests)
-        # Epoch-coupled compiled state in the backends (the XLA mesh
-        # executable cache) evicts at this broadcast-driven position —
-        # one int compare per cycle; a bump lands one cycle after
-        # _populate_cache moves the epoch, which is fine because the
-        # executables are KEYED correctly (verdict + shapes) and the
-        # eviction is hygiene.
-        self.op_manager.note_cache_epoch(cache.epoch)
         inner = meta.response_list
         if meta.invalid_mask:
             cache.evict_slots(meta.invalid_mask)
@@ -2748,12 +2634,6 @@ class Runtime:
             alg, w = self._last_wire_verdict
             line = (f"wire plan {_wd.ALG_NAMES.get(alg, alg)}"
                     f"/{_wd.WIRE_NAMES.get(w, w)}")
-            if self._ici_plane is not None:
-                # Whether the mesh leg is actually carrying cycles —
-                # an ici verdict with 0 mesh cycles means every pack
-                # fell back to the host path (see troubleshooting.md).
-                line += (f" (ici mesh {self._ici_plane.ndev} devices, "
-                         f"{self._ici_cycles} cycles)")
             parts.append(line)
         if self._elastic is not None:
             parts.append(self._elastic.world_line())
@@ -2798,9 +2678,6 @@ class Runtime:
                 "spec_bids": self._spec_bids,
                 "spec_declines": self._spec_declines,
                 "native_steady_cycles": self._native_steady_cycles,
-                "ici_cycles": self._ici_cycles,
-                "ici_compiles": (self._ici_plane.compiles
-                                 if self._ici_plane is not None else 0),
                 "overlap_cycles": self._overlap_cycles,
                 "overlap_inflight": (self._overlap.outstanding
                                      if self._overlap is not None

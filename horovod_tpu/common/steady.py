@@ -212,32 +212,6 @@ class SteadyPlan:
             bufs.append(dst)
         return bufs
 
-    def adopt_packed(self, bufs: List[np.ndarray]):
-        """Adopt per-segment send buffers packed OUTSIDE this plan —
-        the ICI plane's fused-psum executable emits the bucket already
-        concatenated, prescaled and cast to the wire dtype (ops/
-        xla_ops.py IciPlane.fused_pack). Validates that each buffer is
-        byte-compatible with the segment the wire header declares and
-        returns the list ready for the steady cycle; None on any
-        mismatch so the caller re-packs on the host path instead of
-        shipping a malformed frame. Foreign buffers deliberately do
-        NOT alias the arena views: run_worker_cycle rebuilds its send
-        pointers for them and skips the deferred chunked cast (the
-        payload is already in wire form)."""
-        if len(bufs) != self.nseg:
-            return None
-        out = []
-        for j, b in enumerate(bufs):
-            if b is None or not isinstance(b, np.ndarray):
-                return None
-            if b.dtype != self.seg_np_dtypes[j] \
-                    or b.nbytes != self.seg_nbytes[j]:
-                return None
-            if not b.flags["C_CONTIGUOUS"]:
-                b = np.ascontiguousarray(b)
-            out.append(b)
-        return out
-
     def materialize_wire(self) -> None:
         """Deferred-cast fallback: fill the wire views from staging —
         exactly the bytes the chunked native send would have produced
@@ -308,10 +282,11 @@ def _hb_ms(hb) -> Tuple[int, int]:
 
 
 def run_worker_cycle(lib, plan: SteadyPlan, fd: int, secret: bytes,
-                     bufs: List[np.ndarray], skip_tags: bytes,
-                     req_tag: int, resp_tag: int, hb):
-    """One native steady cycle, worker side. Returns
-    (DONE, result_segments) | (FRAME, tag, payload) | (ERR, rc)."""
+                     skip_tags: bytes, req_tag: int, resp_tag: int, hb):
+    """One native steady cycle, worker side, sending what
+    ``plan.pack(use_arena=True)`` left in the plan's arena views.
+    Returns (DONE, result_segments) | (FRAME, tag, payload) |
+    (ERR, rc)."""
     c = _c_common(plan)
     b = plan.cache.get("worker")
     if b is None:
@@ -324,13 +299,6 @@ def run_worker_cycle(lib, plan: SteadyPlan, fd: int, secret: bytes,
                 *[v.ctypes.data for v in plan.send_views]),
         }
         plan.cache["worker"] = b
-    if bufs is not plan.send_views and \
-            any(x is not y for x, y in zip(bufs, plan.send_views)):
-        # Defensive: a caller that packed elsewhere still works.
-        send_ptrs = (ctypes.c_void_p * plan.nseg)(
-            *[v.ctypes.data for v in bufs])
-    else:
-        send_ptrs = b["send_ptrs"]
     result = np.empty(sum(plan.seg_nbytes), np.uint8)
     recv_ptrs = (ctypes.c_void_p * plan.nseg)()
     off = 0
@@ -341,7 +309,7 @@ def run_worker_cycle(lib, plan: SteadyPlan, fd: int, secret: bytes,
     dev_buf = _u8p()
     dev_len = ctypes.c_int64()
     dev_tag = ctypes.c_uint8()
-    if plan.chunked and send_ptrs is b["send_ptrs"]:
+    if plan.chunked:
         # Chunked pipelined send: staging holds the full-precision
         # bytes; the C loop casts wire chunks interleaved with the
         # send (one fused cast+HMAC pass when frame auth is armed).
@@ -359,7 +327,7 @@ def run_worker_cycle(lib, plan: SteadyPlan, fd: int, secret: bytes,
             plan.cache["chunked"] = ch
         rc = lib.hvd_steady_worker_chunked(
             fd, req_tag, resp_tag, c["prefix"], len(plan.prefix),
-            c["hdr_ptrs"], c["hdr_lens"], send_ptrs,
+            c["hdr_ptrs"], c["hdr_lens"], b["send_ptrs"],
             ch["stage_ptrs"], ch["stage_codes"],
             plan.chunk_bytes, recv_ptrs,
             c["seg_lens"], c["seg_codes"], plan.nseg,
@@ -368,13 +336,9 @@ def run_worker_cycle(lib, plan: SteadyPlan, fd: int, secret: bytes,
             ctypes.byref(dev_buf), ctypes.byref(dev_len),
             ctypes.byref(dev_tag))
     else:
-        if plan.chunked:
-            # Defensive repack outside the arena: the deferred cast
-            # never ran — materialize the wire views it would target.
-            plan.materialize_wire()
         rc = lib.hvd_steady_worker(
             fd, req_tag, resp_tag, c["prefix"], len(plan.prefix),
-            c["hdr_ptrs"], c["hdr_lens"], send_ptrs, recv_ptrs,
+            c["hdr_ptrs"], c["hdr_lens"], b["send_ptrs"], recv_ptrs,
             c["seg_lens"], plan.nseg, b["secret"], len(secret),
             b["skip"], b["nskip"], timeout_ms, interval_ms,
             ctypes.byref(dev_buf), ctypes.byref(dev_len),

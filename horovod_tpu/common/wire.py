@@ -44,6 +44,7 @@ from horovod_tpu.common.message import (
     CacheCycleRequest, CacheCycleResponse, DataType, Request, RequestList,
     RequestType, Response, ResponseList, ResponseType,
 )
+from horovod_tpu.common.wire_dtype import ALG_NAMES
 
 _U8 = struct.Struct("<B")
 _U32 = struct.Struct("<I")
@@ -239,6 +240,11 @@ def _read_response(r: _Reader) -> Response:
     resp_type = _RESP_TYPE_OF[r.u8()]
     wire_dtype = r.u8()
     algorithm = r.u8()
+    if algorithm not in ALG_NAMES:
+        # A code this build does not define must not be routed as if
+        # it were ALG_DEFAULT while its sender routes it otherwise.
+        raise ConnectionError(
+            f"unknown algorithm code {algorithm} in a response")
     err = r.string()
     prescale = r.f64()
     postscale = r.f64()

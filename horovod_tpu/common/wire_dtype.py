@@ -32,10 +32,7 @@ analyzer enforces pairwise distinctness per family):
   heuristics (byte-identical to pre-compression behavior), ``STAR``/
   ``RING`` force the flat socket paths, ``TWOLEVEL`` selects the
   hierarchical intra-host-reduce / cross-host-ring / intra-host-
-  broadcast plane (ops/shm_ops.py), ``ICI`` runs the pre-compiled
-  fused-psum XLA executable over the local device mesh for the
-  intra-slice leg (ops/xla_ops.py IciPlane) and the compressed
-  socket/ring plane for the cross-slice (DCN) leg.
+  broadcast plane (ops/shm_ops.py).
 """
 
 from __future__ import annotations
@@ -57,14 +54,12 @@ ALG_DEFAULT = 0
 ALG_STAR = 1
 ALG_RING = 2
 ALG_TWOLEVEL = 3
-ALG_ICI = 4
 
 WIRE_NAMES = {WIRE_NONE: "none", WIRE_BF16: "bf16",
               WIRE_FP16: "fp16", WIRE_INT8: "int8"}
 _NAME_WIRES = {v: k for k, v in WIRE_NAMES.items()}
 ALG_NAMES = {ALG_DEFAULT: "default", ALG_STAR: "star",
-             ALG_RING: "ring", ALG_TWOLEVEL: "twolevel",
-             ALG_ICI: "ici"}
+             ALG_RING: "ring", ALG_TWOLEVEL: "twolevel"}
 
 # Request dtypes a wire cast can shrink. fp16/bf16 tensors are already
 # half-width and int tensors have no meaningful reduced-precision sum.
@@ -427,25 +422,16 @@ class StaticWirePolicy:
     negotiated wire dtype (the request proposals already carry the
     operator's choice). Two-level additionally requires the shm plane
     (its intra-host legs live there) — a stamp whose plane cannot
-    engage would silently no-op as default routing. ICI (the world-
-    agreed mesh plane, HOROVOD_TPU_ICI) outranks two-level: the fused
-    batch packs/casts/reduces on-device and only the pre-compressed
-    wire buffer touches the cross-slice socket plane. The autotuned
+    engage would silently no-op as default routing. The autotuned
     twin is ParameterManager.plan (common/parameter_manager.py)."""
 
     def __init__(self, two_level: bool, threshold_bytes: int,
-                 multi_host: bool, shm_enabled: bool = True,
-                 ici_allowed: bool = False,
-                 ici_threshold_bytes: int = 0):
+                 multi_host: bool, shm_enabled: bool = True):
         self._two_level = bool(two_level) and multi_host and shm_enabled
         self._threshold = max(0, int(threshold_bytes))
-        self._ici = bool(ici_allowed)
-        self._ici_threshold = max(0, int(ici_threshold_bytes))
 
     def plan(self, nbytes: int):
         """-> (ALG_* code, wire cap or None)."""
-        if self._ici and nbytes >= self._ici_threshold:
-            return ALG_ICI, None
         if self._two_level and nbytes >= self._threshold:
             return ALG_TWOLEVEL, None
         return ALG_DEFAULT, None

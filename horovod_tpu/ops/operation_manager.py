@@ -80,17 +80,6 @@ class OperationManager:
         for b in self._backends:
             b.timeline = timeline
 
-    def note_cache_epoch(self, epoch: int) -> None:
-        """Fan a ResponseCache epoch bump out to every backend that
-        holds epoch-coupled compiled state (the XLA mesh backend's
-        executable cache); called by the runtime at the broadcast-
-        driven position where the epoch moves, so evictions happen at
-        the same stream point on every rank."""
-        for b in self._backends:
-            note = getattr(b, "note_cache_epoch", None)
-            if note is not None:
-                note(epoch)
-
     def close(self) -> None:
         """Release backend resources (ring channels, shm mappings) at
         shutdown."""

@@ -143,14 +143,10 @@ class ShmBackend(CollectiveBackend):
             return False
         if response is not None \
                 and response.response_type == ResponseType.ALLREDUCE \
-                and response.algorithm in (_wd.ALG_STAR, _wd.ALG_RING,
-                                           _wd.ALG_ICI):
+                and response.algorithm in (_wd.ALG_STAR, _wd.ALG_RING):
             # A stamped FLAT algorithm belongs to the socket plane —
             # declining here is what makes the coordinator's verdict
             # (and the autotuner's exploration) actually select it.
-            # ALG_ICI counts as flat too: its intra-slice leg runs on
-            # the device mesh before the cycle and its cross-slice leg
-            # is the (compressed) socket star/ring.
             return False
         if t.local_size == t.size:
             return True  # same-host world: every collective

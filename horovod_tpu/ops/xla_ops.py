@@ -33,13 +33,13 @@ import numpy as np
 from horovod_tpu.common import lockdep
 from horovod_tpu.common import logging as hlog
 from horovod_tpu.common import trace as htrace
-from horovod_tpu.common.invariants import world_coherent
 from horovod_tpu.common.message import Response
 from horovod_tpu.common.status import Status
 from horovod_tpu.ops.backend import CollectiveBackend
 
 _AXIS = "hvd_proc"
-_ICI_AXIS = "ici"
+# Compiled executables XlaMeshBackend holds at most.
+_CACHE_MAX = 256
 
 
 def _shard_map(body, mesh, in_specs, out_specs):
@@ -97,7 +97,6 @@ class XlaMeshBackend(CollectiveBackend):
         self._mesh2d = None   # (cross, local) factored mesh, see below
         self._my_device = None
         self._cache: Dict[Tuple, object] = {}
-        self._cache_epoch = -1
         self._available = None
         self._m_compiles = None  # set by attach_metrics
         self._m_cache_size = None
@@ -242,23 +241,17 @@ class XlaMeshBackend(CollectiveBackend):
             fn = self._cache.get(key)
             if fn is None:
                 fn = builder()
+                # Every attribute a program bakes in is in its key
+                # (shapes, scales, sizes, axes, _verdict_sig), so an
+                # entry never goes stale; the bound only caps what
+                # shape churn can hold. Oldest first.
+                if len(self._cache) >= _CACHE_MAX:
+                    del self._cache[next(iter(self._cache))]
                 self._cache[key] = fn
                 if self._m_compiles is not None:
                     self._m_compiles.inc()
                     self._m_cache_size.set(len(self._cache))
         return fn
-
-    def note_cache_epoch(self, epoch: int) -> None:
-        """ResponseCache epoch bump: every compiled executable was
-        built for verdicts of the previous epoch's responses — evict
-        them like every other world-replicated plan (the runtime calls
-        this at the same broadcast-driven position on all ranks)."""
-        with self._lock:
-            if epoch != self._cache_epoch:
-                self._cache_epoch = epoch
-                self._cache.clear()
-                if self._m_cache_size is not None:
-                    self._m_cache_size.set(0)
 
     @staticmethod
     def _verdict_sig(response):
@@ -667,262 +660,3 @@ class XlaMeshBackend(CollectiveBackend):
         self._run_shard_op("barrier", jnp.zeros((1,), jnp.float32),
                            P(), body).block_until_ready()
         return Status.OK()
-
-
-class IciPlane:
-    """Pre-compiled fused-psum steady cycle over the local device mesh
-    (HOROVOD_TPU_ICI): the intra-slice leg of the ALG_ICI verdict.
-
-    The PR 3 fused speculative cycle packs each steady bucket on the
-    HOST — numpy concat, prescale multiply, wire-dtype cast — every
-    step. This plane lowers that whole pack to ONE jitted fused-psum
-    XLA executable per (cache epoch, steady mask, wire dtype, segment
-    signature): each local device prescales and casts its shard of the
-    bucket, writes it at its own offset into a zero-filled wire buffer,
-    and a psum over the ``ici`` axis assembles the contiguous wire
-    payload (zeros elsewhere make the sum an exact identity). On a
-    real pod slice the SAME program's psum is what performs the
-    gradient reduce — :meth:`fused_reduce_partials` runs it over
-    per-device partial contributions; on the forced-host-platform CI
-    mesh (``XLA_FLAGS=--xla_force_host_platform_device_count=N``) the
-    shards are lanes of the pack pipeline and the psum is pure
-    assembly, so results stay bit-exact with the socket plane's numpy
-    pack. Either way the host sees ONE wire buffer, already in the
-    negotiated wire dtype, that rides the existing compressed
-    socket/ring plane for the cross-slice (DCN) leg — negotiation
-    never leaves the coordinator's one-round-trip cached path.
-
-    Executables are the analog of common/steady.py's SteadyPlan: built
-    once per signature, replayed every steady cycle, evicted on the
-    ResponseCache epoch bump (world-replicated plan state — the epoch
-    only moves on broadcast verdicts, which hvdlint's world-coherence
-    analyzer enforces on :meth:`note_cache_epoch`)."""
-
-    # Wire dtypes the fused executable can cast to on-device. int8 is
-    # excluded for the same reason the speculative cycle excludes it:
-    # its per-rank scale header cannot ride the inline coordinator
-    # reduce.
-    _WIRES = (0, 1, 2)  # WIRE_NONE, WIRE_BF16, WIRE_FP16
-
-    def __init__(self, max_devices: int = 0):
-        self._max_devices = max(0, int(max_devices))
-        self._lock = lockdep.lock("xla_ops.IciPlane._lock")
-        self._mesh = None
-        self._ndev = 0
-        self._cache: Dict[Tuple, object] = {}
-        # Epoch-coupled plan state, moved only by the broadcast cache
-        # epoch (note_cache_epoch).
-        self._epoch = -1  # hvdlint: world-replicated
-        self.compiles = 0
-        self.cycles = 0
-        self._m_compiles = None
-        self._m_cycles = None
-        self._m_bytes = None
-
-    # -- capability ------------------------------------------------------
-    def probe(self) -> bool:
-        """This rank's view only — the runtime feeds it through
-        controller.agree() so a world with one mesh-less rank degrades
-        to the socket plane everywhere, together."""
-        try:
-            import jax
-
-            from horovod_tpu.compat import jaxshim
-            devs = sorted(jax.local_devices(), key=lambda d: d.id)
-            if self._max_devices:
-                devs = devs[:self._max_devices]
-            if len(devs) < 2:
-                hlog.debug(
-                    f"ICI plane unavailable: {len(devs)} local "
-                    "device(s); need >= 2 (set XLA_FLAGS="
-                    "--xla_force_host_platform_device_count=N for a "
-                    "CPU-mesh CI run)")
-                return False
-            self._mesh = jaxshim.make_raw_mesh(np.array(devs),
-                                               (_ICI_AXIS,))
-            self._ndev = len(devs)
-            return True
-        except Exception as e:
-            hlog.warning(f"ICI plane unavailable, the steady cycle "
-                         f"takes the socket plane: {e!r}")
-            return False
-
-    @property
-    def ndev(self) -> int:
-        return self._ndev
-
-    def attach_metrics(self, registry) -> None:
-        self._m_compiles = registry.counter(
-            "hvd_ici_compiles_total",
-            "fused-psum executables built for the ICI plane (flat "
-            "after warmup when the steady cycle is riding the cache)")
-        self._m_cycles = registry.counter(
-            "hvd_ici_cycles_total",
-            "steady fused segments packed/reduced on the ICI mesh")
-        # The mesh leg's share of the per-backend byte totals (same
-        # family as hvd_backend_bytes_total{backend="xla_mesh"/...}).
-        self._m_bytes = registry.counter(
-            'hvd_backend_bytes_total{backend="ici_mesh"}',
-            "payload bytes moved through the ICI mesh leg")
-
-    @world_coherent
-    def note_cache_epoch(self, epoch: int) -> None:
-        """Evict compiled plans of a superseded ResponseCache epoch.
-        Called at the same broadcast-driven stream position on every
-        rank (the epoch is a pure function of the coordinator's
-        verdicts), so the plan state never diverges."""
-        with self._lock:
-            if epoch != self._epoch:
-                self._epoch = epoch
-                self._cache.clear()
-
-    # -- compiled fused-psum cycle ---------------------------------------
-    def _compiled(self, key, builder):
-        with self._lock:
-            fn = self._cache.get(key)
-            if fn is None:
-                fn = builder()
-                self._cache[key] = fn
-                self.compiles += 1
-                if self._m_compiles is not None:
-                    self._m_compiles.inc()
-        return fn
-
-    @staticmethod
-    def _np_wire(wire):
-        from horovod_tpu.common import wire_dtype as _wd
-        return _wd.wire_np_dtype(wire)
-
-    def fused_pack(self, sig, flat, prescale: float, wire: int):
-        """Pack one steady segment through the pre-compiled fused-psum
-        executable: ``flat`` (1-D host array, the segment's concat) is
-        scattered one shard per device, each shard is prescaled and
-        cast to the wire dtype ON DEVICE, and the psum assembles the
-        contiguous wire buffer. Returns a writable host array in the
-        wire dtype, byte-compatible with SteadyPlan.pack's output for
-        the same segment, or None when this segment cannot ride the
-        mesh (no mesh, unsupported dtype/wire) — the caller falls back
-        to the host pack.
-
-        ``sig`` is (cache_epoch, steady_mask, segment_index): with the
-        shapes/dtypes below it forms the one-executable-per-signature
-        key the steady cycle replays."""
-        if self._mesh is None or wire not in self._WIRES:
-            return None
-        if flat.dtype not in (np.float32, np.float64):
-            return None
-        import jax
-        import jax.numpy as jnp
-        from jax.sharding import PartitionSpec as P
-
-        from horovod_tpu.compat import jaxshim
-
-        if flat.dtype == np.float64 and not jax.config.jax_enable_x64:
-            # device_put would silently canonicalize f64 down to f32
-            # and the adopted buffer could never be byte-compatible
-            # with the plan — decline BEFORE paying the transfer.
-            return None
-        n = int(flat.size)
-        if n == 0:
-            return None
-        ndev = self._ndev
-        shard = -(-n // ndev)  # ceil
-        n_pad = shard * ndev
-        out_dtype = self._np_wire(wire) if wire else flat.dtype
-        key = (sig, n, str(flat.dtype), wire, float(prescale))
-
-        def build():
-            def body(x):
-                if prescale != 1.0:
-                    x = x * jnp.asarray(prescale, x.dtype)
-                if wire:
-                    x = x.astype(out_dtype)
-                idx = jax.lax.axis_index(_ICI_AXIS)
-                buf = jnp.zeros((n_pad,), x.dtype)
-                buf = jax.lax.dynamic_update_slice(
-                    buf, x, (idx * shard,))
-                # Every position holds exactly one device's shard and
-                # zeros from the others — x + 0 is exact, so the psum
-                # is pure assembly here and becomes the gradient
-                # reduce when the axis spans a real pod slice.
-                return jax.lax.psum(buf, _ICI_AXIS)
-
-            m = _shard_map(body, self._mesh, P(_ICI_AXIS), P())
-            return jax.jit(m)
-
-        fn = self._compiled(key, build)
-        if n_pad != n:
-            padded = np.zeros((n_pad,), flat.dtype)
-            padded[:n] = flat
-        else:
-            padded = flat
-        garr = jax.device_put(
-            padded, jaxshim.named_sharding(self._mesh, P(_ICI_AXIS)))
-        out = fn(garr)
-        host = np.asarray(jax.device_get(out.addressable_data(0)))
-        res = host[:n]
-        if not res.flags.writeable:
-            # The coordinator reduces peers INTO its own buffer; the
-            # device fetch may hand back a read-only view.
-            res = res.copy()
-        self.cycles += 1
-        if self._m_cycles is not None:
-            self._m_cycles.inc()
-            self._m_bytes.inc(res.nbytes)
-        return res
-
-    def fused_reduce_partials(self, sig, partials, prescale: float,
-                              wire: int):
-        """Pod-mode variant: ``partials`` is [ndev, n] — one partial
-        gradient contribution per local device — and the psum REDUCES
-        across the axis instead of assembling shards. Each device
-        prescales and casts its row to the wire dtype first, so the
-        sum happens in wire precision exactly like the coordinator's
-        cross-slice reduce (common/wire_dtype.py reduce_peer_payloads).
-        Returns the reduced wire-dtype host row, or None when the
-        plane cannot carry it."""
-        if self._mesh is None or wire not in self._WIRES:
-            return None
-        partials = np.ascontiguousarray(partials)
-        if partials.ndim != 2 or partials.shape[0] != self._ndev:
-            return None
-        if partials.dtype not in (np.float32, np.float64):
-            return None
-        import jax
-        import jax.numpy as jnp
-        from jax.sharding import PartitionSpec as P
-
-        from horovod_tpu.compat import jaxshim
-
-        if partials.dtype == np.float64 \
-                and not jax.config.jax_enable_x64:
-            return None  # canonicalization would change the bytes
-        n = int(partials.shape[1])
-        out_dtype = self._np_wire(wire) if wire else partials.dtype
-        key = (sig, "partials", n, str(partials.dtype), wire,
-               float(prescale))
-
-        def build():
-            def body(x):
-                x = x.reshape((n,))
-                if prescale != 1.0:
-                    x = x * jnp.asarray(prescale, x.dtype)
-                if wire:
-                    x = x.astype(out_dtype)
-                return jax.lax.psum(x, _ICI_AXIS)
-
-            m = _shard_map(body, self._mesh, P(_ICI_AXIS), P())
-            return jax.jit(m)
-
-        fn = self._compiled(key, build)
-        garr = jax.device_put(
-            partials, jaxshim.named_sharding(self._mesh, P(_ICI_AXIS)))
-        out = fn(garr)
-        host = np.asarray(jax.device_get(out.addressable_data(0)))
-        if not host.flags.writeable:
-            host = host.copy()
-        self.cycles += 1
-        if self._m_cycles is not None:
-            self._m_cycles.inc()
-            self._m_bytes.inc(host.nbytes)
-        return host

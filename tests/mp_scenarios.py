@@ -4354,114 +4354,6 @@ def scenario_int8_codec_parity(hvd, rank, size):
         np.save(out_path, np.concatenate([o.reshape(-1) for o in outs]))
 
 
-def scenario_ici_steady(hvd, rank, size):
-    """ICI-native fused-psum steady cycle end to end (the wrapper arms
-    HOROVOD_TPU_ICI=1 over a forced multi-device host mesh — conftest
-    already exports ``--xla_force_host_platform_device_count=8`` to
-    every spawned world): the steady grouped-allreduce loop must (a)
-    return correct sums, (b) ride the PRE-COMPILED fused-psum
-    executable — ici_cycles advancing every steady step while
-    ici_compiles stays FLAT across 25 replays (100% reuse, over the
-    >=95% acceptance bar), (c) keep hvd_data_copies_total delta 0 on
-    the Python side of the mesh leg, and (d) prove the coordinator
-    stamped ALG_ICI (ici_cycles only tick on an ALG_ICI verdict, so
-    their advance IS the stamp).  With HVD_ICI_EXPECT=0 the same body
-    asserts the world-consistent DEGRADE instead (heterogeneous
-    worlds and the all-socket replay: zero ici cycles anywhere); the
-    wrapper byte-compares both worlds' saved outputs for the
-    bit-exactness leg."""
-    from horovod_tpu.common import basics as _b
-
-    expect_ici = os.environ.get("HVD_ICI_EXPECT", "1") == "1"
-    rng = np.random.RandomState(7100 + rank)
-    xs = [rng.randn(512 + 128 * i).astype(np.float32) for i in range(4)]
-    # every rank reconstructs the world sum from the seeds, so
-    # correctness is pinned locally even over random payloads
-    want = [np.zeros_like(x) for x in xs]
-    for r in range(size):
-        rr = np.random.RandomState(7100 + r)
-        for i in range(4):
-            want[i] = want[i] + rr.randn(512 + 128 * i).astype(
-                np.float32)
-
-    def step():
-        hs = hvd.grouped_allreduce_async(xs, average=False, name="ici")
-        return [np.asarray(hvd.synchronize(h)) for h in hs]
-
-    for _ in range(5):
-        res = step()
-    hvd.barrier(name="ici.bar")
-    rt = _b.runtime()
-    s0 = rt.negotiation_cache_stats()
-    c0 = _metric_value(hvd, "hvd_data_copies_total")
-    for _ in range(25):
-        res = step()
-    s1 = rt.negotiation_cache_stats()
-    c1 = _metric_value(hvd, "hvd_data_copies_total")
-    # bf16 wire: contributions round to 8 mantissa bits before the sum
-    tol = (0.02 * max(float(np.abs(w).max()) for w in want)
-           if os.environ.get("HOROVOD_COMPRESSION") else 1e-5)
-    for r, w in zip(res, want):
-        np.testing.assert_allclose(r, w, atol=tol)
-    assert s1["spec_cycles"] > s0["spec_cycles"], (rank, s0, s1)
-    if expect_ici:
-        assert s1["ici_cycles"] - s0["ici_cycles"] >= 20, (rank, s0, s1)
-        # steady cycles ride the cached executable: compile count flat
-        assert s1["ici_compiles"] == s0["ici_compiles"], (rank, s0, s1)
-        assert _metric_value(hvd, "hvd_ici_cycles_total") > 0, rank
-        assert _metric_value(
-            hvd, 'hvd_backend_bytes_total{backend="ici_mesh"}') > 0, \
-            rank
-        assert c1 - c0 == 0, (rank, c0, c1)
-    else:
-        # degrade must be WORLD-consistent: no rank ever packs on ICI
-        assert s1["ici_cycles"] == 0, (rank, s1)
-        assert _metric_value(hvd, "hvd_ici_cycles_total") == 0, rank
-    out_path = os.environ.get("HVD_ICI_OUT")
-    if rank == 0 and out_path:
-        np.save(out_path, np.concatenate([o.reshape(-1) for o in res]))
-    _assert_cache_coherent(hvd, rank, size, "ici.fp")
-
-
-def scenario_abort_sigkill_ici_steady(hvd, rank, size):
-    """SIGKILL a rank squarely mid-ICI-fused-psum steady state (fault
-    spec fires at an op index reached deep in ALG_ICI steady cycling):
-    the mesh leg must not mask the PR 2 fail-fast invariant — every
-    survivor raises WorldAbortedError naming the dead rank within the
-    heartbeat deadline, and its stats prove the kill really landed in
-    ICI steady state."""
-    import time
-    from horovod_tpu.common.status import WorldAbortedError
-
-    victim = 1
-    deadline = float(os.environ["HOROVOD_HEARTBEAT_TIMEOUT"]) + 12.0
-    # f32: the mesh leg declines f64 without jax_enable_x64, and this
-    # scenario must die with the plane ENGAGED
-    x = np.full(1024, float(rank + 1), np.float32)
-    t0 = time.monotonic()
-    aborted = None
-    while True:
-        try:
-            hvd.allreduce(x, average=False, name="ik.steady")
-        except WorldAbortedError as e:
-            aborted = e
-            break
-        assert time.monotonic() - t0 < deadline, (
-            f"rank {rank}: collectives kept succeeding {deadline}s "
-            f"after the fault")
-    assert aborted.origin_rank == victim, (rank, str(aborted))
-    assert f"rank {victim}" in str(aborted), str(aborted)
-    stats = _cache_runtime_stats(hvd)
-    # the kill landed with the ICI plane engaged and cycling
-    assert stats["ici_cycles"] >= 5, stats
-    try:
-        hvd.allreduce(x, average=False, name="ik.post")
-        raise AssertionError("enqueue after world abort must fail")
-    except WorldAbortedError as e:
-        assert e.origin_rank == victim, str(e)
-    hvd.shutdown()
-
-
 def main():
     scenario, rank, size, port = (sys.argv[1], int(sys.argv[2]),
                                   int(sys.argv[3]), int(sys.argv[4]))

@@ -401,33 +401,30 @@ BAD_ALG_CODES = """
     ALG_DEFAULT = 0
     ALG_STAR = 1
     ALG_TWOLEVEL = 3
-    ALG_ICI = 3
+    ALG_NEXT = 3
     ALG_HUGE = 300
 """
 
 
-def test_wire_protocol_alg_ici_joins_family_distinctness(tmp_path):
-    """ALG_ICI (the ISSUE 18 mesh-plane verdict) rides the same
-    negotiated u8 algorithm byte as star/ring/two-level — a collision
-    would make the coordinator's ICI stamp decode as another
-    topology on every peer."""
+def test_wire_protocol_new_alg_joins_family_distinctness(tmp_path):
+    """A new ALG_* verdict rides the same negotiated u8 algorithm
+    byte as star/ring/two-level — a collision would make the
+    coordinator's stamp decode as another topology on every peer."""
     fs = _lint_snippet(tmp_path, BAD_ALG_CODES, "wire-protocol",
                        name="wire_dtype.py")
     msgs = "\n".join(f.message for f in fs)
-    assert "ALG_TWOLEVEL and ALG_ICI share byte value" in msgs
+    assert "ALG_TWOLEVEL and ALG_NEXT share byte value" in msgs
     assert "ALG_HUGE = 300 does not fit the u8" in msgs
 
 
 def test_wire_protocol_real_alg_codes_distinct():
     """Anchor the real tree: every shipped ALG_* verdict code in
-    wire_dtype.py — ALG_ICI included — is pairwise distinct and
-    u8-ranged."""
+    wire_dtype.py is pairwise distinct and u8-ranged."""
     from horovod_tpu.common import wire_dtype as wd
     codes = {n: getattr(wd, n) for n in dir(wd)
              if n.startswith("ALG_") and not n.endswith("NAMES")
              and isinstance(getattr(wd, n), int)}
-    assert len(codes) >= 5, codes          # default/star/ring/2lvl/ici
-    assert "ALG_ICI" in codes, codes
+    assert len(codes) >= 4, codes          # default/star/ring/2lvl
     assert len(set(codes.values())) == len(codes), codes
     assert all(0 <= v <= 255 for v in codes.values()), codes
 
@@ -819,24 +816,6 @@ def test_world_coherence_fires_on_local_overlap_mutation(tmp_path):
     msgs = "\n".join(f.message for f in fs)
     assert "world-replicated" in msgs \
         and "requeue_priority" in msgs, fs
-
-
-def test_world_coherence_real_ici_plan_state_is_anchored():
-    """The REAL IciPlane.note_cache_epoch must carry the
-    @world_coherent anchor — its epoch-coupled compiled-plan state is
-    world-replicated (one fused-psum executable set per broadcast
-    cache epoch); stripping the anchor fails the tree, proving a
-    rank-local epoch move (which would desynchronize eviction and
-    replay stale executables on one rank) cannot land unnoticed."""
-    from tools.hvdlint import world_coherence
-    p = Project([os.path.join(REPO, "horovod_tpu")])
-    qn = "horovod_tpu.ops.xla_ops.IciPlane.note_cache_epoch"
-    assert qn in p.index.functions, sorted(
-        k for k in p.index.functions if "IciPlane" in k)[:20]
-    p.index.functions[qn].decorators = set()
-    fs = world_coherence.run(p)
-    assert any("_epoch" in f.message
-               and "world-replicated" in f.message for f in fs), fs
 
 
 def test_world_coherence_real_overlap_inflight_is_anchored():

@@ -257,3 +257,89 @@ class TestRaggedPsumDecision:
         # totals decide (uniform bulk outweighs the skewed entry)
         sizes = [64, 1, 1, 1] + [256, 256, 256, 256]
         assert not ragged_psum_wins(sizes, [1, 64], 4)
+
+
+class TestMeshExecutableCache:
+    """XlaMeshBackend keeps one compiled program per key, and the key
+    holds everything a program bakes in (shapes, scales, the
+    negotiated verdict): an executable never goes stale, so nothing
+    but the size bound ever drops one."""
+
+    def _backend(self):
+        """The mesh backend over a one-device mesh of this process: a
+        world of one runs the same jit(shard_map(psum)) path."""
+        import jax
+
+        from horovod_tpu.common.metrics import MetricsRegistry
+        from horovod_tpu.compat import jaxshim
+        from horovod_tpu.ops import xla_ops
+
+        class _Ctl:
+            rank = 0
+            size = 1
+        b = xla_ops.XlaMeshBackend(_Ctl())
+        dev = jax.devices()[0]
+        b._mesh = jaxshim.make_raw_mesh(np.array([dev]), (xla_ops._AXIS,))
+        b._my_device = dev
+        b.attach_metrics(MetricsRegistry())
+        return b
+
+    @staticmethod
+    def _allreduce(b, n, algorithm=0, wire_dtype=0):
+        import types
+
+        import jax.numpy as jnp
+
+        from horovod_tpu.common.message import Response, ResponseType
+        x = jnp.arange(n, dtype=jnp.float32)
+        e = types.SimpleNamespace(tensor=x, output=None, callback=None,
+                                  tensor_name=f"t{n}")
+        resp = Response(response_type=ResponseType.ALLREDUCE,
+                        tensor_names=[e.tensor_name],
+                        algorithm=algorithm, wire_dtype=wire_dtype)
+        assert b.execute_allreduce([e], resp).ok()
+        np.testing.assert_array_equal(np.asarray(e.output), np.asarray(x))
+
+    def test_verdict_in_signature(self):
+        from horovod_tpu.common import wire_dtype as wd
+        from horovod_tpu.common.message import Response
+        from horovod_tpu.ops.xla_ops import XlaMeshBackend
+        sig = XlaMeshBackend._verdict_sig
+        r1 = Response(wire_dtype=wd.WIRE_BF16, algorithm=wd.ALG_RING)
+        r2 = Response(wire_dtype=wd.WIRE_NONE, algorithm=wd.ALG_RING)
+        r3 = Response(wire_dtype=wd.WIRE_BF16, algorithm=wd.ALG_TWOLEVEL)
+        assert sig(r1) != sig(r2) and sig(r1) != sig(r3)
+        assert sig(None) == ()
+
+    def test_another_tensors_response_keeps_the_first_executable(self):
+        """A newly negotiated response (another tensor, another cache
+        epoch) must not cost the first tensor its program."""
+        b = self._backend()
+        self._allreduce(b, 4)
+        (first,) = b._cache
+        self._allreduce(b, 6)
+        assert first in b._cache and len(b._cache) == 2
+        self._allreduce(b, 4)
+        assert b._m_compiles.value == 2 == b._m_cache_size.value
+
+    def test_same_shape_under_two_verdicts_keeps_two_executables(self):
+        from horovod_tpu.common import wire_dtype as wd
+        b = self._backend()
+        for _ in range(2):
+            self._allreduce(b, 4)
+            self._allreduce(b, 4, wd.ALG_TWOLEVEL, wd.WIRE_BF16)
+        assert len(b._cache) == 2 == b._m_compiles.value
+        assert {k[-1] for k in b._cache} == {
+            (wd.WIRE_NONE, wd.ALG_DEFAULT),
+            (wd.WIRE_BF16, wd.ALG_TWOLEVEL)}
+
+    def test_cache_is_bounded_oldest_first(self):
+        from horovod_tpu.ops.xla_ops import _CACHE_MAX
+        b = self._backend()
+        for i in range(_CACHE_MAX + 3):
+            assert b._compiled(("k", i), lambda i=i: i) == i
+        assert len(b._cache) == _CACHE_MAX == b._m_cache_size.value
+        assert list(b._cache)[0] == ("k", 3)
+        assert b._compiled(("k", 3), lambda: "rebuilt") == 3
+        assert b._compiled(("k", 0), lambda: "rebuilt") == "rebuilt"
+        assert b._m_compiles.value == _CACHE_MAX + 4

@@ -342,7 +342,7 @@ def test_chunked_worker_wire_parity(secret):
     assert plan.chunked
     comp = np.linspace(-2, 2, n, dtype=np.float32)
     raw = np.linspace(5, 6, n, dtype=np.float32)
-    bufs = plan.pack([[comp], [raw]], [1.0, 1.0])
+    plan.pack([[comp], [raw]], [1.0, 1.0])
 
     # classic bytes: clone plan without chunking, same data
     ref = SteadyPlan(7, 64, 0b11, segments, FusionArena())
@@ -375,7 +375,7 @@ def test_chunked_worker_wire_parity(secret):
     t = threading.Thread(target=peer, daemon=True)
     t.start()
     kind, val = hsteady.run_worker_cycle(
-        lib, plan, a.fileno(), secret, bufs, b"", 2, 3, (5.0, 0.1))
+        lib, plan, a.fileno(), secret, b"", 2, 3, (5.0, 0.1))
     t.join(5.0)
     a.close()
     b.close()

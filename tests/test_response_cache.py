@@ -15,6 +15,7 @@ from horovod_tpu.common.coordinator import ResponseCache, fuse_responses
 from horovod_tpu.common.message import (
     CacheCycleRequest, CacheCycleResponse, DataType, Request, RequestList,
     RequestType, Response, ResponseList, ResponseType,
+    numpy_dtype_to_datatype,
 )
 
 
@@ -399,9 +400,14 @@ class TestSpecFrameAsksBeforeItCopies:
 
     SHAPES = [(3,), (2, 4), (5,)]      # 12, 32 and 20 bytes of f32
 
-    def _shell(self, threshold, backend, nbytes, convertible):
+    def _shell(self, threshold, backend, nbytes, convertible,
+               dtype="float32", last_resp=None, absent=(),
+               **resp_attrs):
         """A transport-free Runtime holding one steady set of cached
-        allreduces in its table: just what _build_spec_frame reads."""
+        allreduces in its table: just what _build_spec_frame reads.
+        ``resp_attrs`` are set on every cached response and
+        ``last_resp`` on the last one alone; names in ``absent`` are
+        cached but not in the table."""
         import types
 
         import numpy as np
@@ -420,7 +426,6 @@ class TestSpecFrameAsksBeforeItCopies:
         rt._world_id = 0
         # the serialized frame, not a SteadyPlan
         rt._steady_native_ok = False
-        rt._ici_plane = None
         rt._spec_inflight = None
         rt._spec_bids = 0
         rt._spec_declines = 0
@@ -431,13 +436,21 @@ class TestSpecFrameAsksBeforeItCopies:
         log, arrays, bit_requests = [], [], []
         for i, shape in enumerate(self.SHAPES):
             name = f"g.{i}"
-            arr = (np.arange(int(np.prod(shape)), dtype=np.float32)
-                   .reshape(shape) + 10.0 * i)
-            req = _req(name, shape=shape)
-            _put(rt._cache, name, req, _resp(name, numel=arr.size))
-            rt.tensor_table.add(
-                TensorTableEntry(name, _Payload(name, arr, log, nbytes,
-                                                convertible)), req)
+            arr = ((np.arange(int(np.prod(shape)), dtype=np.float32)
+                    .reshape(shape) + 10.0 * i) / 3).astype(dtype)
+            req = _req(name, shape=shape,
+                       dtype=numpy_dtype_to_datatype(arr.dtype))
+            resp = _resp(name, numel=arr.size)
+            for attr, value in resp_attrs.items():
+                setattr(resp, attr, value)
+            if i == len(self.SHAPES) - 1:
+                for attr, value in (last_resp or {}).items():
+                    setattr(resp, attr, value)
+            _put(rt._cache, name, req, resp)
+            if name not in absent:
+                rt.tensor_table.add(
+                    TensorTableEntry(name, _Payload(
+                        name, arr, log, nbytes, convertible)), req)
             arrays.append(arr)
             bit_requests.append((i, req))
         return rt, log, arrays, bit_requests
@@ -500,6 +513,123 @@ class TestSpecFrameAsksBeforeItCopies:
         assert [[e.tensor_name for e in entries]
                 for _, entries, _ in rt._spec_inflight] == [
             [f"g.{i}" for i in seg] for seg in segments]
+
+    @pytest.mark.parametrize("reason", [
+        "not-an-allreduce", "ring", "two-level", "int8-wire",
+        "vanished-entry", "no-backend", "tuner-moved-the-plan"])
+    def test_every_other_decline_converts_nothing(self, reason):
+        """Each reason _spec_admitted has for "no" besides the
+        backend's own, met at the LAST batch of the plan or before
+        the plan is read: None, no payload converted, nothing bid,
+        and (none of them being the backend's answer) nothing counted
+        or kept in the memo."""
+        import types
+
+        from horovod_tpu.common import wire_dtype as wd
+
+        backend = _AskedBackend(lambda n: True)
+        last_resp = {
+            "not-an-allreduce":
+                {"response_type": ResponseType.BROADCAST},
+            "ring": {"algorithm": wd.ALG_RING},
+            "two-level": {"algorithm": wd.ALG_TWOLEVEL},
+            "int8-wire": {"wire_dtype": wd.WIRE_INT8},
+        }.get(reason)
+        rt, log, arrays, bit_requests = self._shell(
+            1 << 20, backend, True, convertible=False,
+            last_resp=last_resp,
+            absent=("g.2",) if reason == "vanished-entry" else ())
+        first = arrays[0].nbytes + arrays[1].nbytes
+        if reason == "no-backend":
+            def pick(entries, resp):
+                raise RuntimeError("no backend")
+            rt.op_manager = types.SimpleNamespace(pick=pick)
+            asked = []
+        elif reason == "tuner-moved-the-plan":
+            rt.parameter_manager = types.SimpleNamespace(plan_revision=2)
+            rt._wire_plan_rev = 1
+            rt.controller = types.SimpleNamespace(is_coordinator=True)
+            asked = []
+        elif reason == "vanished-entry":
+            asked = []          # one batch of three, one name gone
+        else:
+            asked = [first]     # the batch before the odd one passed
+        mask = 0b111
+        assert rt._spec_admitted(mask, bit_requests) is None
+        assert rt._build_spec_frame(mask, bit_requests) is None
+        assert backend.asked == asked * 2 and log == []
+        assert rt._spec_bids == 0 and rt._spec_inflight is None
+        assert rt._spec_declines == 0 and rt._spec_declined == set()
+
+    @pytest.mark.parametrize("native", [False, True],
+                             ids=["serialized", "steady-plan"])
+    @pytest.mark.parametrize("dtype,wire_name", [
+        ("float32", "none"), ("float32", "bf16"), ("float32", "fp16"),
+        ("float64", "none"), ("float64", "bf16"),
+        ("bfloat16", "none"), ("float16", "none")])
+    def test_both_renderings_hold_the_host_packs_bytes(
+            self, dtype, wire_name, native):
+        """_pack_spec_frame's two renderings (the serialized frame's
+        segments; a SteadyPlan's packed buffers, arena and fresh)
+        carry, per batch, the bytes _pack_fused + compress_send_payload
+        make of the same entries: prescale applied in the tensors' own
+        dtype, then one cast to the negotiated wire dtype."""
+        import ml_dtypes  # noqa: F401  (registers bfloat16 by name)
+        import numpy as np
+
+        from horovod_tpu.common import steady, wire_dtype as wd
+        from horovod_tpu.common.arena import FusionArena
+        from horovod_tpu.ops.socket_ops import (
+            _pack_fused, compress_send_payload,
+        )
+
+        w = wd.wire_code_of(wire_name)
+        backend = _AskedBackend(lambda n: True)
+        itemsize = np.dtype(dtype).itemsize
+        rt, log, arrays, bit_requests = self._shell(
+            11 * itemsize, backend, True, convertible=True, dtype=dtype,
+            wire_dtype=w, prescale_factor=0.5)
+        mask = 0b111
+        admitted = rt._spec_admitted(mask, bit_requests)
+        assert [r.tensor_names for r, _ in admitted] == [
+            ["g.0", "g.1"], ["g.2"]] and log == []
+        want = []
+        for seg, (resp, _) in zip([[0, 1], [2]], admitted):
+            fused, _ = _pack_fused([arrays[i] for i in seg], resp)
+            assert fused.dtype == arrays[0].dtype
+            if w:
+                fused = compress_send_payload(fused, w)
+                assert fused.dtype == wd.wire_np_dtype(w)
+            want.append(fused.tobytes())
+        if not native:
+            frame = rt._pack_spec_frame(mask, admitted)
+            got = wire.parse_cycle_request(frame).spec_payload
+            assert [dt for dt, _ in got] == [
+                wd.wire_datatype(w) if w
+                else numpy_dtype_to_datatype(arrays[0].dtype)] * 2
+            assert [a.tobytes() for _, a in got] == want
+            assert rt._spec_bids == 1 and len(log) == 3
+            return
+        rt._steady_native_ok = rt._spec_ok = True
+        rt._steady_plans, rt._steady_plan_epoch = {}, -1
+        rt._overlap, rt._overlap_chunk = None, 0
+        rt._send_arena = FusionArena()
+        for bids, coordinator in enumerate((False, True), 1):
+            rt.controller.is_coordinator = coordinator
+            splan = rt._pack_spec_frame(mask, admitted)
+            assert isinstance(splan, steady.SteadyPlan)
+            plan, bufs = rt._spec_steady
+            assert plan is splan and rt._spec_bids == bids
+            assert [b.tobytes() for b in bufs] == want
+            # workers send from the arena, the coordinator reduces
+            # into buffers of its own
+            assert all((b is v) != coordinator
+                       for b, v in zip(bufs, splan.send_views))
+            assert splan.frame_bytes(bufs) == wire.serialize_cycle_request(
+                CacheCycleRequest(
+                    epoch=rt._cache.epoch, nslots=rt._cache.nslots,
+                    hit_mask=mask, spec_payload=[
+                        (dt, b) for dt, b in zip(splan.seg_dtypes, bufs)]))
 
     def test_world_of_one_declines_every_steady_cycle(self, hvd_world,
                                                       monkeypatch):

@@ -53,6 +53,31 @@ def test_response_roundtrip():
     assert out == rl
 
 
+@pytest.mark.parametrize("code", [4, 255])
+def test_unknown_algorithm_code_is_refused(code):
+    """A response stamped with an algorithm code this build does not
+    define (4 was the retired mesh plane's) is a protocol error, in a
+    plain and in a cached-cycle frame alike: parsed, it would be
+    routed as ALG_DEFAULT here and as something else by its sender."""
+    from horovod_tpu.common import wire_dtype as wd
+    from horovod_tpu.common.message import CacheCycleResponse
+    assert code not in wd.ALG_NAMES
+    resp = Response(response_type=ResponseType.ALLREDUCE,
+                    tensor_names=["a"], devices=[-1], tensor_sizes=[4],
+                    algorithm=code)
+    rl = ResponseList([resp])
+    with pytest.raises(ConnectionError, match=f"algorithm code {code}"):
+        wire.parse_response_list(wire.serialize_response_list(rl))
+    cached = CacheCycleResponse(epoch=1, nslots=2, grant_mask=0,
+                                invalid_mask=0, response_list=rl)
+    with pytest.raises(ConnectionError, match=f"algorithm code {code}"):
+        wire.parse_cycle_response(wire.serialize_cycle_response(cached))
+    for known in wd.ALG_NAMES:
+        resp.algorithm = known
+        assert wire.parse_response_list(
+            wire.serialize_response_list(rl)) == rl
+
+
 def test_error_response_roundtrip():
     resp = Response(response_type=ResponseType.ERROR,
                     tensor_names=["bad"],
