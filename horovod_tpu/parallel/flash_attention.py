@@ -16,11 +16,22 @@ Kernel shape (the canonical TPU flash structure):
   (1, BLOCK_Q, 1) blocks — both trailing block dims equal the array
   dims, which satisfies the mosaic tiling rule without replicating
   stats across 128 lanes;
-- causal block-skip: kv tiles entirely in the future are predicated
-  off with `pl.when`, saving ~half the FLOPs of causal attention;
+- causal skip, by tile and inside it: a kv tile entirely in the future
+  is predicated off with `pl.when`; a tile wholly at or under the
+  diagonal runs one unmasked body; a tile the diagonal crosses runs a
+  body specialised to where it crosses (`_over_tile`), which computes
+  only the [sub_q, sub_k] sub-tiles the mask leaves and masks only the
+  ones the diagonal crosses (`_diagonal_blocks`). The DMA tile and the
+  grid stay as large as the ladder measured them; at 512x1024 tiles the
+  kernels compute 56% of the square at S=2048 and 53% at S=4096 where
+  the whole-tile predicate computed 75% and 62.5% (the causal need is
+  50%; `causal_subtile_counts` gives the number for any call);
 - `offsets` is a runtime int32[2] (scalar-prefetch, SMEM): the global
   positions of q[0] and k[0]. Ring attention passes traced offsets for
-  its rotated kv blocks — no retrace per ring step.
+  its rotated kv blocks — no retrace per ring step: the case is chosen
+  at run time among static bodies. A crossing that does not lie on the
+  tiles' own grid (offsets that are no multiple of the tiles) takes
+  the whole-tile masked body.
 
 Backward is a pair of pallas kernels (the FlashAttention-2 split):
 - dq kernel, grid (BH, q blocks, kv blocks): recomputes each p-block
@@ -28,6 +39,8 @@ Backward is a pair of pallas kernels (the FlashAttention-2 split):
   dq += ds @ k in fp32 scratch;
 - dk/dv kernel, grid (BH, kv blocks, q blocks): same recompute per
   tile, accumulates dv += pᵀ @ do and dk += dsᵀ @ q.
+Both take the blocks of a tile from the same `_over_tile` as the
+forward.
 delta = rowsum(do · o) is precomputed once outside (one fused XLA
 pass, [BH, S, 1]); lse = m + log l comes from the forward's stats, so
 no O(S²) buffer exists anywhere in the backward.
@@ -47,6 +60,7 @@ constraints.
 from __future__ import annotations
 
 import functools
+import math
 import warnings
 from typing import Optional, Tuple
 
@@ -56,9 +70,127 @@ import jax.numpy as jnp
 _NEG_INF = -1e30
 
 
+def _diagonal_positions(block_q: int, block_k: int):
+    """The positions ``d = q_start - k_start`` of a tile the diagonal
+    crosses that have a body of their own: those on the grid the tiles
+    themselves make (self-attention and a ring's shards, whose offsets
+    are multiples of both tiles, put every tile there): two at 512x1024
+    tiles, one at square ones. At ``d`` the tile's first row sees its
+    first ``d + 1`` columns."""
+    step = math.gcd(block_q, block_k)
+    at = tuple(range(-(block_q // step - 1) * step, block_k - 1, step))
+    # Each is one more body in all three kernels: tiles far from each
+    # other's multiple (explicit odd blocks) keep the whole-tile mask.
+    return at if len(at) <= 4 else ()
+
+
+def _diagonal_blocks(d: int, block_q: int, block_k: int, sub_q: int,
+                     sub_k: int):
+    """What is left of a tile at position ``d`` once each
+    [sub_q, sub_k] sub-tile wholly above the diagonal is dropped: one
+    block ``(row_lo, row_hi, free, vis)`` per chunk of ``sub_q`` rows
+    that sees anything, over the tile's columns ``[0, vis)``; the
+    sub-tiles in ``[0, free)`` lie wholly at or under the diagonal (no
+    mask), those in ``[free, vis)`` are crossed by it (masked)."""
+    blocks = []
+    for r0 in range(0, block_q, sub_q):
+        seen = d + r0 + sub_q       # columns the chunk's last row sees
+        vis = min(block_k, max(0, -(-seen // sub_k) * sub_k))
+        free = min(vis, max(0, (d + r0 + 1) // sub_k * sub_k))
+        if vis:
+            blocks.append((r0, r0 + sub_q, free, vis))
+    return blocks
+
+
+def _tile_blocks(d: int, block_q: int, block_k: int, sub_q: int,
+                 sub_k: int):
+    """The blocks of scores the causal kernels compute in a tile at
+    position ``d``, by the rule ``_over_tile`` applies to the runtime
+    offsets: nothing above the diagonal, the whole tile unmasked under
+    it, ``_diagonal_blocks`` where it crosses on the grid, and the whole
+    tile masked (every sub-tile pays) where it crosses off it."""
+    if d <= -block_q:
+        return []
+    if d >= block_k - 1:
+        return [(0, block_q, block_k, block_k)]
+    if d in _diagonal_positions(block_q, block_k):
+        return _diagonal_blocks(d, block_q, block_k, sub_q, sub_k)
+    return [(0, block_q, 0, block_k)]
+
+
+def _over_tile(offs_ref, qi, j, run, *, block_q: int, block_k: int,
+               sub_q: int, sub_k: int, causal: bool):
+    """``run(blocks, d)`` on the blocks of scores the (q tile ``qi``, kv
+    tile ``j``) pair owes, chosen from what the kernel observes: the
+    static ``causal`` and tile shapes and the runtime offsets in SMEM
+    (``_tile_blocks`` is the same rule on plain integers). Each case is
+    a straight-line body of static slices, so one compilation serves
+    every offset; ``d`` is the position the masks are built from."""
+    from jax.experimental import pallas as pl
+
+    unmasked = [(0, block_q, block_k, block_k)]
+    if not causal:
+        run(unmasked, None)
+        return
+    d = (offs_ref[0] + qi * block_q) - (offs_ref[1] + j * block_k)
+    pl.when(d >= block_k - 1)(lambda: run(unmasked, None))
+    off_grid = jnp.logical_and(d > -block_q, d < block_k - 1)
+    for at in _diagonal_positions(block_q, block_k):
+        pl.when(d == at)(lambda at=at: run(
+            _diagonal_blocks(at, block_q, block_k, sub_q, sub_k), at))
+        off_grid = jnp.logical_and(off_grid, d != at)
+    pl.when(off_grid)(lambda: run([(0, block_q, 0, block_k)], d))
+
+
+def _block_slices(block):
+    """(rows of the q tile, columns of the kv tile) a block spans."""
+    r0, r1, _, vis = block
+    return slice(r0, r1), slice(0, vis)
+
+
+def _tail_mask(d, block):
+    """The allowed scores among a block's masked columns
+    ``[free, vis)``, or None where it has none."""
+    r0, r1, free, vis = block
+    if free == vis:
+        return None
+    # the block's first row lies d + r0 - free after its first masked column
+    return d + r0 - free \
+        + jax.lax.broadcasted_iota(jnp.int32, (r1 - r0, 1), 0) \
+        >= jax.lax.broadcasted_iota(jnp.int32, (1, vis - free), 1)
+
+
+def _where_tail(mask, x, fill):
+    """``x`` with ``fill`` where ``mask`` (over its last columns)
+    forbids; the columns before them are not touched."""
+    if mask is None:
+        return x
+    free = x.shape[1] - mask.shape[1]
+    tail = jnp.where(mask, x[:, free:], fill)
+    return jnp.concatenate([x[:, :free], tail], axis=1) if free else tail
+
+
+def _attend(q, k, v, carry, scale: float, mask):
+    """One online-softmax update of ``(m, l, acc)`` by a [rows, cols]
+    block of scores; ``mask`` (``_tail_mask``) covers its last columns,
+    None where every score is allowed."""
+    m_prev, l_prev, acc = carry
+    q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+    s = _where_tail(mask, jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale, _NEG_INF)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = _where_tail(mask, jnp.exp(s - m_new), 0.0)
+    corr = jnp.exp(m_prev - m_new)
+    l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
+    acc = acc * corr + jax.lax.dot_general(
+        p, v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    return m_new, l_new, acc
+
+
 def _kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
-            m_scr, l_scr, acc_scr, *, block_q: int, block_k: int,
-            num_k: int, causal: bool, scale: float):
+            m_scr, l_scr, acc_scr, *, num_k: int, scale: float, **tile):
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
@@ -70,41 +202,15 @@ def _kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    q_start = offs_ref[0] + qi * block_q
-    k_start = offs_ref[1] + j * block_k
-    # Causal block-skip: the whole kv tile is in the future of the
-    # whole q tile -> nothing to do.
-    visible = jnp.logical_or(
-        jnp.logical_not(causal),
-        k_start <= q_start + block_q - 1)
+    def run(blocks, d):
+        for block in blocks:
+            rows, cols = _block_slices(block)
+            m_scr[rows], l_scr[rows], acc_scr[rows] = _attend(
+                q_ref[0, rows, :], k_ref[0, cols, :], v_ref[0, cols, :],
+                (m_scr[rows], l_scr[rows], acc_scr[rows]), scale,
+                _tail_mask(d, block))
 
-    @pl.when(visible)
-    def _():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [BQ, BK]
-        if causal:
-            q_pos = q_start + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, 1), 0)
-            k_pos = k_start + jax.lax.broadcasted_iota(
-                jnp.int32, (1, block_k), 1)
-            allowed = q_pos >= k_pos
-            s = jnp.where(allowed, s, _NEG_INF)
-        m_prev = m_scr[:]
-        block_max = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, block_max)
-        p = jnp.exp(s - m_new)
-        if causal:
-            p = jnp.where(allowed, p, 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        m_scr[:] = m_new
-        l_scr[:] = l_scr[:] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    _over_tile(offs_ref, qi, j, run, **tile)
 
     @pl.when(j == num_k - 1)
     def _():
@@ -117,12 +223,25 @@ def _kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
         m_ref[0] = jnp.transpose(m_scr[:])
         l_ref[0] = jnp.transpose(l)
 
+
+def _executed_share(seq_q, seq_k, block_q, block_k, sub, causal):
+    """Share of the [Sq, Sk] square the kernels execute, for their
+    ``cost_estimate``: the sub-tiles computed at zero offsets (the
+    offsets are runtime values; a ring's steps average the same)."""
+    if not causal:
+        return 1.0
+    n = causal_subtile_counts(seq_q, seq_k, block_q, block_k, sub)
+    return n["computed"] / (n["computed"] + n["skipped"])
+
+
 @functools.partial(
-    jax.jit, static_argnames=("causal", "block_q", "block_k", "interpret"))
+    jax.jit, static_argnames=("causal", "block_q", "block_k", "interpret",
+                              "sub"))
 def _flash_bhsd(q, k, v, offsets, causal: bool, block_q: int,
-                block_k: int, interpret: bool):
+                block_k: int, interpret: bool, sub=None):
     """q: [BH, Sq, D]; k, v: [BH, Sk, D]; offsets: int32[2].
-    Returns (o [BH,Sq,D], m [BH,1,Sq], l [BH,1,Sq])."""
+    Returns (o [BH,Sq,D], m [BH,1,Sq], l [BH,1,Sq]). ``sub`` is the
+    causal sub-tile (rows, columns); None takes the ladder's."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -130,10 +249,12 @@ def _flash_bhsd(q, k, v, offsets, causal: bool, block_q: int,
     seq_k = k.shape[1]
     scale = 1.0 / (d ** 0.5)
     num_k = seq_k // block_k
+    sub_q, sub_k = sub = sub or _subtile_for(d, block_q, block_k)
+    share = _executed_share(seq_q, seq_k, block_q, block_k, sub, causal)
 
     kernel = functools.partial(
-        _kernel, block_q=block_q, block_k=block_k, num_k=num_k,
-        causal=causal, scale=scale)
+        _kernel, block_q=block_q, block_k=block_k, sub_q=sub_q,
+        sub_k=sub_k, num_k=num_k, causal=causal, scale=scale)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -165,47 +286,42 @@ def _flash_bhsd(q, k, v, offsets, causal: bool, block_q: int,
         interpret=interpret,
         name="flash_fwd",
         cost_estimate=pl.CostEstimate(
-            flops=4 * bh * seq_q * seq_k * d // (2 if causal else 1),
+            flops=int(4 * bh * seq_q * seq_k * d * share),
             bytes_accessed=(2 * q.size + k.size + v.size)
             * q.dtype.itemsize,
-            transcendentals=bh * seq_q * seq_k,
+            transcendentals=int(bh * seq_q * seq_k * share),
         ),
     )(offsets, q, k, v)
 
 
 def _recompute_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    q_start, k_start, block_q: int, block_k: int,
-                    causal: bool, scale: float):
-    """Shared backward-tile recompute: p = exp(s - lse) and
-    ds = p · (dp − delta) · scale for one [BQ, BK] tile. The dq and
-    dk/dv kernels differ only in what they contract these with."""
-    q = q_ref[0].astype(jnp.float32)
-    k = k_ref[0].astype(jnp.float32)
-    v = v_ref[0].astype(jnp.float32)
-    do = do_ref[0].astype(jnp.float32)
-    lse = jnp.transpose(lse_ref[0])                   # [1,BQ] -> [BQ,1]
-    delta = jnp.transpose(delta_ref[0])               # [1,BQ] -> [BQ,1]
-    s = jax.lax.dot_general(
+                    block, d, scale: float):
+    """Shared backward recompute for one block of scores (rows of the
+    q tile against the first columns of the kv tile, the last of them
+    masked): p = exp(s - lse) and ds = p · (dp − delta) · scale. The dq
+    and dk/dv kernels differ only in what they contract these with."""
+    rows, cols = _block_slices(block)
+    q = q_ref[0, rows, :].astype(jnp.float32)
+    k = k_ref[0, cols, :].astype(jnp.float32)
+    v = v_ref[0, cols, :].astype(jnp.float32)
+    do = do_ref[0, rows, :].astype(jnp.float32)
+    lse = jnp.transpose(lse_ref[0, :, rows])          # [1,R] -> [R,1]
+    delta = jnp.transpose(delta_ref[0, :, rows])      # [1,R] -> [R,1]
+    s = _where_tail(_tail_mask(d, block), jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale   # [BQ, BK]
-    if causal:
-        q_pos = q_start + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, 1), 0)
-        k_pos = k_start + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_k), 1)
-        s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
+        preferred_element_type=jnp.float32) * scale, _NEG_INF)  # [R, C]
     # Dead rows (l == 0) store lse = +inf -> p underflows to 0.
     p = jnp.exp(s - lse)
     dp = jax.lax.dot_general(
         do, v, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)           # [BQ, BK]
+        preferred_element_type=jnp.float32)           # [R, C]
     ds = p * (dp - delta) * scale
     return q, k, do, p, ds
 
 
 def _bwd_dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                   delta_ref, dq_ref, dq_scr, *, block_q: int,
-                   block_k: int, num_k: int, causal: bool, scale: float):
+                   delta_ref, dq_ref, dq_scr, *, num_k: int, scale: float,
+                   **tile):
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
@@ -215,20 +331,16 @@ def _bwd_dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     def _():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    q_start = offs_ref[0] + qi * block_q
-    k_start = offs_ref[1] + j * block_k
-    visible = jnp.logical_or(
-        jnp.logical_not(causal),
-        k_start <= q_start + block_q - 1)
+    def run(blocks, d):
+        for block in blocks:
+            _, k, _, _, ds = _recompute_p_ds(
+                q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, block, d,
+                scale)
+            dq_scr[_block_slices(block)[0], :] += jax.lax.dot_general(
+                ds, k, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
 
-    @pl.when(visible)
-    def _():
-        _, k, _, _, ds = _recompute_p_ds(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-            q_start, k_start, block_q, block_k, causal, scale)
-        dq_scr[:] += jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    _over_tile(offs_ref, qi, j, run, **tile)
 
     @pl.when(j == num_k - 1)
     def _():
@@ -237,8 +349,7 @@ def _bwd_dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
 def _bwd_dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                     delta_ref, dk_ref, dv_ref, dk_scr, dv_scr, *,
-                    block_q: int, block_k: int, num_q: int,
-                    causal: bool, scale: float):
+                    num_q: int, scale: float, **tile):
     from jax.experimental import pallas as pl
 
     j = pl.program_id(1)      # kv block (outer)
@@ -249,23 +360,20 @@ def _bwd_dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    q_start = offs_ref[0] + qi * block_q
-    k_start = offs_ref[1] + j * block_k
-    visible = jnp.logical_or(
-        jnp.logical_not(causal),
-        k_start <= q_start + block_q - 1)
+    def run(blocks, d):
+        for block in blocks:
+            q, _, do, p, ds = _recompute_p_ds(
+                q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, block, d,
+                scale)
+            cols = _block_slices(block)[1]
+            dv_scr[cols, :] += jax.lax.dot_general(
+                p, do, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)           # [C, D]
+            dk_scr[cols, :] += jax.lax.dot_general(
+                ds, q, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)           # [C, D]
 
-    @pl.when(visible)
-    def _():
-        q, _, do, p, ds = _recompute_p_ds(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-            q_start, k_start, block_q, block_k, causal, scale)
-        dv_scr[:] += jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)           # [BK, D]
-        dk_scr[:] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)           # [BK, D]
+    _over_tile(offs_ref, qi, j, run, **tile)
 
     @pl.when(qi == num_q - 1)
     def _():
@@ -274,19 +382,25 @@ def _bwd_dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
 
 @functools.partial(
-    jax.jit, static_argnames=("causal", "block_q", "block_k", "interpret"))
+    jax.jit, static_argnames=("causal", "block_q", "block_k", "interpret",
+                              "sub"))
 def _flash_bwd_bhsd(q, k, v, do, lse, delta, offsets, causal: bool,
-                    block_q: int, block_k: int, interpret: bool):
+                    block_q: int, block_k: int, interpret: bool,
+                    sub=None):
     """Backward kernels. q, do: [BH,Sq,D]; k, v: [BH,Sk,D];
-    lse, delta: [BH,1,Sq] fp32. Returns (dq, dk, dv) in input dtypes."""
+    lse, delta: [BH,1,Sq] fp32. Returns (dq, dk, dv) in input dtypes.
+    ``sub`` as for ``_flash_bhsd``."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bh, seq_q, d = q.shape
     seq_k = k.shape[1]
-    scale = 1.0 / (d ** 0.5)
     num_q = seq_q // block_q
     num_k = seq_k // block_k
+    sub_q, sub_k = sub = sub or _subtile_for(d, block_q, block_k)
+    share = _executed_share(seq_q, seq_k, block_q, block_k, sub, causal)
+    tile = dict(block_q=block_q, block_k=block_k, sub_q=sub_q,
+                sub_k=sub_k, causal=causal, scale=1.0 / (d ** 0.5))
 
     q_spec = pl.BlockSpec((1, block_q, d), lambda b, i, j, offs: (b, i, 0))
     k_spec = pl.BlockSpec((1, block_k, d), lambda b, i, j, offs: (b, j, 0))
@@ -294,9 +408,7 @@ def _flash_bwd_bhsd(q, k, v, do, lse, delta, offsets, causal: bool,
                              lambda b, i, j, offs: (b, 0, i))
 
     dq = pl.pallas_call(
-        functools.partial(
-            _bwd_dq_kernel, block_q=block_q, block_k=block_k,
-            num_k=num_k, causal=causal, scale=scale),
+        functools.partial(_bwd_dq_kernel, num_k=num_k, **tile),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(bh, num_q, num_k),
@@ -309,10 +421,10 @@ def _flash_bwd_bhsd(q, k, v, do, lse, delta, offsets, causal: bool,
         interpret=interpret,
         name="flash_bwd_dq",
         cost_estimate=pl.CostEstimate(
-            flops=6 * bh * seq_q * seq_k * d // (2 if causal else 1),
+            flops=int(6 * bh * seq_q * seq_k * d * share),
             bytes_accessed=(2 * q.size + k.size + v.size)
             * q.dtype.itemsize,
-            transcendentals=bh * seq_q * seq_k,
+            transcendentals=int(bh * seq_q * seq_k * share),
         ),
     )(offsets, q, k, v, do, lse, delta)
 
@@ -322,9 +434,7 @@ def _flash_bwd_bhsd(q, k, v, do, lse, delta, offsets, causal: bool,
     stat_spec2 = pl.BlockSpec((1, 1, block_q),
                               lambda b, j, i, offs: (b, 0, i))
     dk, dv = pl.pallas_call(
-        functools.partial(
-            _bwd_dkv_kernel, block_q=block_q, block_k=block_k,
-            num_q=num_q, causal=causal, scale=scale),
+        functools.partial(_bwd_dkv_kernel, num_q=num_q, **tile),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(bh, num_k, num_q),
@@ -339,13 +449,41 @@ def _flash_bwd_bhsd(q, k, v, do, lse, delta, offsets, causal: bool,
         interpret=interpret,
         name="flash_bwd_dkv",
         cost_estimate=pl.CostEstimate(
-            flops=10 * bh * seq_q * seq_k * d // (2 if causal else 1),
+            flops=int(10 * bh * seq_q * seq_k * d * share),
             bytes_accessed=(q.size + 2 * (k.size + v.size))
             * q.dtype.itemsize,
-            transcendentals=bh * seq_q * seq_k,
+            transcendentals=int(bh * seq_q * seq_k * share),
         ),
     )(offsets, q, k, v, do, lse, delta)
     return dq, dk, dv
+
+
+def causal_subtile_counts(seq_q: int, seq_k: int, block_q: int,
+                          block_k: int, sub, q_offset: int = 0,
+                          k_offset: int = 0) -> dict:
+    """How often the causal structure engages in one head of a call:
+    of the (seq_q / sub_q) x (seq_k / sub_k) sub-tiles of the square,
+    how many the kernels compute (``computed``), how many of those pay
+    for the mask (``masked``) and how many are not touched
+    (``skipped``). ``sub=(block_q, block_k)`` is the whole-tile
+    predicate the kernels had before PR 30. Plain integers through
+    ``_tile_blocks``, the rule the kernels apply to their runtime
+    offsets."""
+    sub_q, sub_k = sub
+    if block_q % sub_q or block_k % sub_k or seq_q % block_q \
+            or seq_k % block_k:
+        raise ValueError(
+            f"sub-tile {sub} must divide the tile ({block_q}, {block_k}) "
+            f"and the tile the sequences ({seq_q}, {seq_k})")
+    computed = masked = 0
+    for q_start in range(q_offset, q_offset + seq_q, block_q):
+        for k_start in range(k_offset, k_offset + seq_k, block_k):
+            for r0, r1, free, vis in _tile_blocks(
+                    q_start - k_start, block_q, block_k, sub_q, sub_k):
+                computed += (r1 - r0) // sub_q * (vis // sub_k)
+                masked += (r1 - r0) // sub_q * ((vis - free) // sub_k)
+    return {"computed": computed, "masked": masked,
+            "skipped": (seq_q // sub_q) * (seq_k // sub_k) - computed}
 
 
 def _dense_reference(q, k, v, causal: bool, q_offset, k_offset):
@@ -412,6 +550,70 @@ def _ladders_for(head_dim: int):
     return q_ladder, k_ladder
 
 
+# Causal sub-tile (q rows, kv columns) by head size, as (largest head
+# size, sub-tile) rungs: the DMA tile and the grid stay as large as the
+# ladders above measured them, and inside a tile the diagonal crosses
+# the kernels compute by sub-tile (_diagonal_blocks). Measured on v5e
+# silicon (PR 30; 512x1024 tiles, forward + dq + dk/dv of one layer,
+# ms; "whole" is the whole-tile predicate the kernels had):
+#   sub-tile   BH64 S2048 D128         BH80 S4096 D256
+#   whole      1.162+1.219+1.480=3.861  8.172+9.640+10.808=28.620
+#   512x512    1.054+1.082+1.251=3.387  7.122+8.978+ 9.925=26.025
+#   256x512    1.088+1.075+1.292=3.455  7.260+8.879+ 9.942=26.080
+#   256x256    1.080+1.038+1.239=3.357  7.134+8.626+ 9.517=25.276
+#   128x128    1.142+1.035+1.348=3.526  7.546+8.545+ 9.498=25.589
+#    64x256    1.302+1.148+2.215=4.665  7.943+8.760+11.085=27.788
+# 256x256 computes 56.25% of the square at S=2048 and 53.1% at S=4096
+# (whole: 75% and 62.5%). A row chunk is one body over all the columns
+# it sees, masked in its last sub-tile only: the same sub-tiles as a
+# loop over them with runtime trip counts ran 64% and 31% SLOWER than
+# whole (the forward's statistics and accumulator carried through the
+# loop), and one softmax update per sub-tile only 3% and 10% faster
+# (the per-row work of an update, reductions across lanes above all,
+# is paid again by every block of a row). One rung: both head sizes
+# want the same; past 256 nothing is measured and the last rung stands.
+_SUBTILE_LADDER = ((256, (256, 256)),)
+
+
+def _subtile_for(head_dim: int, block_q: int, block_k: int):
+    """The causal sub-tile for a (block_q, block_k) tile at
+    ``head_dim``: the rung of the smallest head size that holds it (the
+    last one past that), cut to the tile; a tile it does not divide is
+    its own sub-tile."""
+    sub_q, sub_k = next(
+        (sub for d, sub in _SUBTILE_LADDER if head_dim <= d),
+        _SUBTILE_LADDER[-1][1])
+    sub_q, sub_k = min(sub_q, block_q), min(sub_k, block_k)
+    return (sub_q if block_q % sub_q == 0 else block_q,
+            sub_k if block_k % sub_k == 0 else block_k)
+
+
+def _note_subtiles(seq_q, seq_k, head_dim, block_q, block_k, q_offset,
+                   k_offset) -> None:
+    """Write ``causal_subtile_counts`` of the call being traced into the
+    metrics registry (``hvd_flash_subtiles{kind=...}``, docs/metrics.md),
+    where a world with its metrics plane on is there to read it. Traced
+    offsets (the ring's) have no count at trace time and write nothing."""
+    from horovod_tpu.common import basics
+    if not basics.initialized():
+        return
+    reg = basics.active_runtime().metrics
+    if not reg.enabled:
+        return
+    try:
+        q_offset, k_offset = int(q_offset), int(k_offset)
+    except TypeError:       # a tracer: jax.errors.ConcretizationTypeError
+        return
+    counts = causal_subtile_counts(
+        seq_q, seq_k, block_q, block_k,
+        _subtile_for(head_dim, block_q, block_k), q_offset, k_offset)
+    for kind, n in counts.items():
+        reg.gauge(
+            f'hvd_flash_subtiles{{kind="{kind}"}}',
+            "sub-tiles a head of the causal flash call traced last: "
+            "computed, of those masked, and skipped", agg="max").set(n)
+
+
 def _auto_block(seq: int, ladder, explicit) -> int:
     if explicit is not None:
         return min(explicit, seq)
@@ -462,6 +664,9 @@ def flash_attention_stats(q, k, v, causal: bool = True,
             f"blocks ({block_q}, {block_k})")
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    if causal:
+        _note_subtiles(seq_q, seq_k, q.shape[-1], block_q, block_k,
+                       q_offset, k_offset)
     offsets = jnp.stack([jnp.asarray(q_offset, jnp.int32),
                          jnp.asarray(k_offset, jnp.int32)])
     return _run(q, k, v, offsets, causal, block_q, block_k, interpret)
@@ -577,6 +782,9 @@ def flash_attention(q, k, v, causal: bool = True,
         return _dense_reference(q, k, v, causal, q_offset, k_offset)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    if causal:
+        _note_subtiles(seq_q, seq_k, q.shape[-1], bq, bk, q_offset,
+                       k_offset)
     offsets = jnp.stack([jnp.asarray(q_offset, jnp.int32),
                          jnp.asarray(k_offset, jnp.int32)])
     return _flash(q, k, v, offsets, bool(causal), bq, bk,

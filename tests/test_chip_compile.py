@@ -23,7 +23,7 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
 from horovod_tpu.parallel.flash_attention import (  # noqa: E402
-    _flash_bhsd, _flash_bwd_bhsd, _ladders_for,
+    _flash_bhsd, _flash_bwd_bhsd, _ladders_for, _subtile_for,
 )
 
 pytestmark = pytest.mark.fast
@@ -113,6 +113,43 @@ def test_flash_backward_compiles_for_v5e(chip, bh, seq, d, block_q,
         qkv, qkv, qkv, qkv, stat, stat, offsets, causal=True,
         block_q=block_q, block_k=block_k, interpret=False).compile()
     assert _kernel_calls(compiled) == 2  # dq, and dk/dv
+
+
+# The cells' own flash calls: (cell, BH, S, D) at the tile and the
+# causal sub-tile `flash_attention` picks for them.
+_CELL_SHAPES = [
+    ("lm-injit", 64, 2048, 128),            # B4 x 16 heads of 128
+    ("glm47flash-injit", 80, 4096, 256),    # B4 x 20 heads of 256
+]
+
+
+@pytest.mark.parametrize("causal", [True, False],
+                         ids=["causal", "noncausal"])
+@pytest.mark.parametrize("bh,seq,d", [c[1:] for c in _CELL_SHAPES],
+                         ids=[c[0] for c in _CELL_SHAPES])
+def test_cells_kernels_compile_with_their_subtiles(chip, bh, seq, d,
+                                                   causal):
+    """Forward, dq and dk/dv at the two cells' shapes, the sub-tile
+    loops on runtime offsets included: a VMEM or Mosaic refusal of the
+    chosen sub-tile shows here, before the chip is asked."""
+    block_q, block_k = _top(d)
+    sub_q, sub_k = _subtile_for(d, block_q, block_k)
+    assert block_q % sub_q == 0 and block_k % sub_k == 0
+    assert (sub_q, sub_k) != (block_q, block_k)
+    qkv = jax.ShapeDtypeStruct((bh, seq, d), jnp.bfloat16, sharding=chip)
+    stat = jax.ShapeDtypeStruct((bh, 1, seq), jnp.float32, sharding=chip)
+    offsets = jax.ShapeDtypeStruct((2,), jnp.int32, sharding=chip)
+    fwd = _flash_bhsd.lower(
+        qkv, qkv, qkv, offsets, causal=causal, block_q=block_q,
+        block_k=block_k, interpret=False).compile()
+    bwd = _flash_bwd_bhsd.lower(
+        qkv, qkv, qkv, qkv, stat, stat, offsets, causal=causal,
+        block_q=block_q, block_k=block_k, interpret=False).compile()
+    assert (_kernel_calls(fwd), _kernel_calls(bwd)) == (1, 2)
+    for text, names in ((fwd.as_text(), ["flash_fwd"]),
+                        (bwd.as_text(), ["flash_bwd_dq", "flash_bwd_dkv"])):
+        for name in names:      # the benchmark's readers match by name
+            assert name in text
 
 
 def test_d256_keeps_the_default_pair_and_d512_is_halved():

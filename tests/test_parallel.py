@@ -330,6 +330,88 @@ def test_flash_attention_stats_values():
     np.testing.assert_allclose(np.asarray(l), l_ref, rtol=1e-5)
 
 
+# (id, q_offset, k_offset): a 512-row q shard against a 512-row kv shard,
+# square tiles as the ring passes them, the ladder's sub-tile inside
+_SHARD_CASES = [
+    ("diagonal", 512, 512),
+    ("wholly-past", 1024, 0),       # every sub-tile mask-free
+    ("wholly-future", 0, 1024),     # no sub-tile computed
+    ("one-row-sees-one-column", 0, 511),
+    ("off-the-subtile-grid", 300, 77),
+]
+
+
+@pytest.mark.parametrize("causal", [True, False],
+                         ids=["causal", "noncausal"])
+def test_flash_shards_traced_offsets_one_compile(causal):
+    """The ring's use of the kernels: `flash_attention_stats` and
+    `flash_attention_bwd` on traced offsets, every shard position
+    through one compilation, forward and gradients against the dense
+    reference. Without the mask the offsets change nothing."""
+    from horovod_tpu.parallel import flash_attention as fa
+    rng = np.random.RandomState(21)
+    b, s, h, d = 1, 512, 2, 16
+    q, k, v, g = (jnp.asarray(rng.randn(b, s, h, d), jnp.float32)
+                  for _ in range(4))
+    assert fa._subtile_for(d, 512, 512) != (512, 512)
+
+    @jax.jit
+    def shard(q_off, k_off):
+        o, m, l = fa.flash_attention_stats(
+            q, k, v, causal=causal, q_offset=q_off, k_offset=k_off,
+            block_q=512, block_k=512, interpret=True)
+        return (o,) + fa.flash_attention_bwd(
+            q, k, v, o, m, l, g, causal=causal, q_offset=q_off,
+            k_offset=k_off, block_q=512, block_k=512, interpret=True)
+
+    for name, q_off, k_off in _SHARD_CASES:
+        ref, vjp = jax.vjp(
+            lambda q, k, v: fa._dense_reference(q, k, v, causal, q_off,
+                                                k_off), q, k, v)
+        got = shard(jnp.int32(q_off), jnp.int32(k_off))
+        for a, b_ in zip(got, (ref,) + vjp(g)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                       atol=5e-5, err_msg=name)
+    assert shard._cache_size() == 1
+
+
+def test_flash_subtile_gauge_reaches_the_registry(monkeypatch):
+    """A world with its metrics plane on reads how often the causal
+    structure engaged in the flash call traced last; traced offsets
+    (the ring's) and a plane that is off write nothing."""
+    import horovod_tpu as hvd
+    from horovod_tpu.parallel import flash_attention as fa
+    rng = np.random.RandomState(22)
+    q, k, v = (jnp.asarray(rng.randn(1, 256, 1, 16), jnp.float32)
+               for _ in range(3))
+
+    def call(q_off=0):
+        return fa.flash_attention(q, k, v, causal=True, q_offset=q_off,
+                                  block_q=128, block_k=256, interpret=True)
+
+    def gauges():
+        return {name: rec["v"]
+                for name, rec in hvd.metrics()["local"].items()
+                if name.startswith("hvd_flash_subtiles")}
+
+    hvd.shutdown()
+    call()                                  # no world: nothing to write to
+    monkeypatch.setenv("HOROVOD_TPU_METRICS", "1")
+    hvd.init()
+    try:
+        assert gauges() == {}
+        jax.jit(call)(jnp.int32(0))         # traced offset: no count
+        assert gauges() == {}
+        call()
+        want = fa.causal_subtile_counts(
+            256, 256, 128, 256, fa._subtile_for(16, 128, 256))
+        assert gauges() == {
+            f'hvd_flash_subtiles{{kind="{kind}"}}': float(n)
+            for kind, n in want.items()}
+    finally:
+        hvd.shutdown()
+
+
 # ---------------------------------------------------------------------------
 # Expert parallelism (MoE)
 # ---------------------------------------------------------------------------
