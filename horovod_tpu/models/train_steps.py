@@ -25,6 +25,7 @@ from horovod_tpu import spmd
 from horovod_tpu.common.basics import active_runtime
 from horovod_tpu.compat import jaxshim
 from horovod_tpu.models.glm_moe import ABSENT, DROPPED, GlmMoeLM
+from horovod_tpu.models.phi4flash import Phi4FlashLM
 from horovod_tpu.models.resnet import ResNet50
 from horovod_tpu.models.transformer import (
     TransformerConfig, TransformerLM, lm_loss_from_hidden,
@@ -88,12 +89,8 @@ def _jit_step(step, mesh, donate_argnums):
         compiler_options=spmd.overlap_compiler_options(mesh, AXIS))
 
 
-def lm_train_step(model: TransformerLM, tx, mesh):
-    """``jit(shard_map(step))`` with ``(params, opt_state)`` donated:
-    ``(params, opt_state, tokens) -> (params, opt_state, loss)``. The
-    loss is the mean over the mesh, not one shard's."""
-    loss_fn = lm_loss_fn(model)
-
+def _loss_train_step(loss_fn, tx, mesh):
+    """The step of a model whose loss is ``loss_fn(params, tokens)``."""
     def step(p, os_, t):
         with jax.named_scope("loss"):
             loss, grads = jax.value_and_grad(loss_fn)(p, t)
@@ -108,6 +105,43 @@ def lm_train_step(model: TransformerLM, tx, mesh):
         in_specs=(rep, rep, jaxshim.partition_spec(AXIS)),
         out_specs=(rep, rep, rep))
     return _jit_step(step, mesh, donate_argnums=(0, 1))
+
+
+def lm_train_step(model: TransformerLM, tx, mesh):
+    """``jit(shard_map(step))`` with ``(params, opt_state)`` donated:
+    ``(params, opt_state, tokens) -> (params, opt_state, loss)``. The
+    loss is the mean over the mesh, not one shard's."""
+    return _loss_train_step(lm_loss_fn(model), tx, mesh)
+
+
+# The chunked head's backward rewrites the whole gradient of the table
+# (512 MB at 50,016 rows of 2,560) once a chunk. Measured on v5e silicon
+# at the cell's shape (PR 31; one row of 16,384; ms a step, GB of
+# temporaries): 1024 988.6, 5.15; 2048 979.3, 5.37; 4096 970.8, 5.53;
+# 8192 980.4, 7.20.
+PHI4FLASH_HEAD_CHUNK = 4096
+
+
+def phi4flash_loss_fn(model: Phi4FlashLM):
+    """``(params, tokens) -> loss``: next-token cross-entropy through
+    the chunked head **on the embedding's own rows** (the head is the
+    embedding's transpose, so the loss's gradient and the lookup's add
+    into one leaf). Chunks of ``PHI4FLASH_HEAD_CHUNK`` positions."""
+    def loss_fn(p, t):
+        hidden = model.apply({"params": p}, t)
+        return lm_loss_from_hidden(hidden, p["embed"]["embedding"].T, t,
+                                   chunk=PHI4FLASH_HEAD_CHUNK)
+    return loss_fn
+
+
+def phi4flash_train_step(model: Phi4FlashLM, tx, mesh):
+    """The decoder-hybrid-decoder's step, ``lm_train_step``'s shape:
+    ``(params, opt_state, tokens) -> (params, opt_state, loss)``, state
+    donated. Every block is recomputed in the backward pass with its
+    kernels' outputs kept (``phi4flash.RematBlock``); the scan's memory
+    and the kept key-value pair pass from block to block beside the
+    residual, and their readers' gradients add on the way back."""
+    return _loss_train_step(phi4flash_loss_fn(model), tx, mesh)
 
 
 def glm_moe_loss_fn(model: GlmMoeLM):

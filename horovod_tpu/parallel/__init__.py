@@ -9,6 +9,8 @@ support at all. These are first-class here:
                         parallelism)
 - ``ring_attention``  — sequence/context parallelism for long sequences
 - ``pipeline``        — GPipe-style pipeline parallelism over a mesh axis
+- ``ssm_scan``        — the selective scan of a state-space layer, as
+                        Pallas kernels (forward and backward)
 - ``trainer``         — composes dp x tp x sp x ep into one jitted step
 """
 
@@ -27,6 +29,7 @@ from horovod_tpu.parallel.pipeline import (
 from horovod_tpu.parallel.trainer import (
     Trainer, TrainerConfig, make_chunked_lm_loss,
 )
+from horovod_tpu.parallel.ssm_scan import selective_scan
 
 
 def __getattr__(name):
@@ -44,4 +47,5 @@ __all__ = [
     "ulysses_attention", "make_ulysses_attention",
     "pipeline_stages", "make_pipeline_apply", "PipelinedLM",
     "Trainer", "TrainerConfig", "make_chunked_lm_loss",
+    "selective_scan",
 ]

@@ -26,6 +26,17 @@ Kernel shape (the canonical TPU flash structure):
   kernels compute 56% of the square at S=2048 and 53% at S=4096 where
   the whole-tile predicate computed 75% and 62.5% (the causal need is
   50%; `causal_subtile_counts` gives the number for any call);
+- a ``window`` is a second diagonal, ``window`` keys behind the first:
+  tiles wholly behind it are skipped like those above the causal one,
+  and not even walked: a windowed call's grid has only the steps the
+  band can touch (``_streamed_tiles``: three a q tile at 1024x1024
+  tiles and a window of 512, where the causal grid has sixteen at
+  S=16384), each step's kv tile computed from the runtime offsets;
+- k and v may have fewer heads than q (index maps send a group of
+  query heads to one key-value head; the dk/dv kernel's inner grid
+  axis runs over the group's q tiles, so dk and dv are summed in its
+  scratch and nothing is repeated in HBM), and v a head size of its
+  own;
 - `offsets` is a runtime int32[2] (scalar-prefetch, SMEM): the global
   positions of q[0] and k[0]. Ring attention passes traced offsets for
   its rotated kv blocks — no retrace per ring step: the case is chosen
@@ -102,30 +113,70 @@ def _diagonal_blocks(d: int, block_q: int, block_k: int, sub_q: int,
     return blocks
 
 
+def _window_positions(block_q: int, block_k: int, window: int):
+    """As ``_diagonal_positions`` for a call with a window: the
+    positions on the tiles' own grid at which either diagonal (the
+    causal one, or the window's ``window`` keys behind it) crosses the
+    tile. Three at 512x1024 tiles and a window of 512."""
+    step = math.gcd(block_q, block_k)
+    at = tuple(d for d in range(-(block_q // step - 1) * step,
+                                window + block_k - 1, step)
+               if not block_k - 1 <= d <= window - block_q)
+    return at if len(at) <= 6 else ()
+
+
+def _window_blocks(d: int, block_q: int, block_k: int, sub_q: int,
+                   sub_k: int, window: int):
+    """``_diagonal_blocks`` between two diagonals: per chunk of
+    ``sub_q`` rows one block ``(row_lo, row_hi, free, vis, lo)`` over
+    the tile's columns ``[lo, vis)``, the sub-tiles any of its rows can
+    see (a row sees the ``window`` keys up to its own). A chunk that
+    sees all of them carries no mask (``free == vis``); any other is
+    masked over all its columns (``free == lo``): a window's layer is a
+    thin band, and its two masks are not worth two more bodies."""
+    blocks = []
+    for r0 in range(0, block_q, sub_q):
+        oldest = d + r0 - window + 1        # of the chunk's first row
+        newest = d + r0 + sub_q - 1         # of its last row
+        lo = min(block_k, max(0, oldest // sub_k * sub_k))
+        vis = min(block_k, max(0, -(-(newest + 1) // sub_k) * sub_k))
+        if vis <= lo:
+            continue
+        whole = d + r0 >= vis - 1 and newest - lo < window
+        blocks.append((r0, r0 + sub_q, vis if whole else lo, vis, lo))
+    return blocks
+
+
 def _tile_blocks(d: int, block_q: int, block_k: int, sub_q: int,
-                 sub_k: int):
+                 sub_k: int, window=None):
     """The blocks of scores the causal kernels compute in a tile at
     position ``d``, by the rule ``_over_tile`` applies to the runtime
-    offsets: nothing above the diagonal, the whole tile unmasked under
-    it, ``_diagonal_blocks`` where it crosses on the grid, and the whole
+    offsets: nothing above the diagonal (nor, with a ``window``, behind
+    it), the whole tile unmasked between them, ``_diagonal_blocks`` (or
+    ``_window_blocks``) where one crosses on the grid, and the whole
     tile masked (every sub-tile pays) where it crosses off it."""
-    if d <= -block_q:
+    if d <= -block_q or (window is not None
+                         and d >= window + block_k - 1):
         return []
-    if d >= block_k - 1:
+    if d >= block_k - 1 and (window is None or d <= window - block_q):
         return [(0, block_q, block_k, block_k)]
-    if d in _diagonal_positions(block_q, block_k):
+    if window is not None:
+        if d in _window_positions(block_q, block_k, window):
+            return _window_blocks(d, block_q, block_k, sub_q, sub_k, window)
+    elif d in _diagonal_positions(block_q, block_k):
         return _diagonal_blocks(d, block_q, block_k, sub_q, sub_k)
     return [(0, block_q, 0, block_k)]
 
 
 def _over_tile(offs_ref, qi, j, run, *, block_q: int, block_k: int,
-               sub_q: int, sub_k: int, causal: bool):
+               sub_q: int, sub_k: int, causal: bool, window=None):
     """``run(blocks, d)`` on the blocks of scores the (q tile ``qi``, kv
     tile ``j``) pair owes, chosen from what the kernel observes: the
-    static ``causal`` and tile shapes and the runtime offsets in SMEM
-    (``_tile_blocks`` is the same rule on plain integers). Each case is
-    a straight-line body of static slices, so one compilation serves
-    every offset; ``d`` is the position the masks are built from."""
+    static ``causal``, ``window`` and tile shapes and the runtime
+    offsets in SMEM (``_tile_blocks`` is the same rule on plain
+    integers). Each case is a straight-line body of static slices, so
+    one compilation serves every offset; ``d`` is the position the
+    masks are built from."""
     from jax.experimental import pallas as pl
 
     unmasked = [(0, block_q, block_k, block_k)]
@@ -133,31 +184,52 @@ def _over_tile(offs_ref, qi, j, run, *, block_q: int, block_k: int,
         run(unmasked, None)
         return
     d = (offs_ref[0] + qi * block_q) - (offs_ref[1] + j * block_k)
-    pl.when(d >= block_k - 1)(lambda: run(unmasked, None))
-    off_grid = jnp.logical_and(d > -block_q, d < block_k - 1)
-    for at in _diagonal_positions(block_q, block_k):
-        pl.when(d == at)(lambda at=at: run(
-            _diagonal_blocks(at, block_q, block_k, sub_q, sub_k), at))
+    if window is None:
+        pl.when(d >= block_k - 1)(lambda: run(unmasked, None))
+        off_grid = jnp.logical_and(d > -block_q, d < block_k - 1)
+        bodies = [(at, _diagonal_blocks(at, block_q, block_k, sub_q, sub_k))
+                  for at in _diagonal_positions(block_q, block_k)]
+    else:
+        if block_k - 1 <= window - block_q:     # a tile fits between them
+            pl.when(jnp.logical_and(d >= block_k - 1,
+                                    d <= window - block_q))(
+                lambda: run(unmasked, None))
+        off_grid = jnp.logical_and(
+            jnp.logical_and(d > -block_q, d < window + block_k - 1),
+            jnp.logical_or(d < block_k - 1, d > window - block_q))
+        bodies = [(at, _window_blocks(at, block_q, block_k, sub_q, sub_k,
+                                      window))
+                  for at in _window_positions(block_q, block_k, window)]
+    for at, blocks in bodies:
+        pl.when(d == at)(lambda at=at, blocks=blocks: run(blocks, at))
         off_grid = jnp.logical_and(off_grid, d != at)
     pl.when(off_grid)(lambda: run([(0, block_q, 0, block_k)], d))
 
 
+def _block_lo(block) -> int:
+    """A block's first column: its fifth member, 0 where it has none."""
+    return block[4] if len(block) > 4 else 0
+
+
 def _block_slices(block):
     """(rows of the q tile, columns of the kv tile) a block spans."""
-    r0, r1, _, vis = block
-    return slice(r0, r1), slice(0, vis)
+    r0, r1, _, vis = block[:4]
+    return slice(r0, r1), slice(_block_lo(block), vis)
 
 
-def _tail_mask(d, block):
+def _tail_mask(d, block, window=None):
     """The allowed scores among a block's masked columns
     ``[free, vis)``, or None where it has none."""
-    r0, r1, free, vis = block
+    r0, r1, free, vis = block[:4]
     if free == vis:
         return None
     # the block's first row lies d + r0 - free after its first masked column
-    return d + r0 - free \
-        + jax.lax.broadcasted_iota(jnp.int32, (r1 - r0, 1), 0) \
-        >= jax.lax.broadcasted_iota(jnp.int32, (1, vis - free), 1)
+    ahead = d + r0 - free \
+        + jax.lax.broadcasted_iota(jnp.int32, (r1 - r0, 1), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (1, vis - free), 1)
+    if window is None:
+        return ahead >= cols
+    return jnp.logical_and(ahead >= cols, ahead - cols < window)
 
 
 def _where_tail(mask, x, fill):
@@ -189,14 +261,60 @@ def _attend(q, k, v, carry, scale: float, mask):
     return m_new, l_new, acc
 
 
+def _floor_div(x, n: int):
+    """``x // n`` rounded down for a traced int32 of either sign."""
+    return jax.lax.div(jnp.where(x >= 0, x, x - (n - 1)), jnp.int32(n))
+
+
+def _streamed_tiles(window, block_o: int, block_s: int, num_s: int,
+                    behind: bool):
+    """How a kernel walks the tiles it streams (kv tiles past a q tile
+    in the forward and dq kernels, q tiles past a kv tile in dk/dv):
+    ``(steps, tile, fetch)``. Without a window the grid has one step a
+    tile and the step is the tile, as ever. With one, only the
+    ``steps`` consecutive tiles the band can touch are walked:
+    ``tile(o, step, offs)`` is the tile of a step (``o`` the outer
+    tile, ``offs`` the runtime offsets) and ``fetch`` the block index
+    its DMA asks for, held at the last tile that owes anything so that
+    a step behind it moves nothing. ``behind``: the streamed tiles are
+    keys, which lie behind their queries; else queries, ahead of their
+    keys."""
+    if window is None:
+        return num_s, (lambda o, step, offs: step), \
+            (lambda o, step, offs: step)
+    steps = min(num_s, (block_o + window + block_s - 3) // block_s + 1)
+
+    def span(o, offs):
+        if behind:      # o a q tile: keys [start - window + 1, end]
+            start = offs[0] + o * block_o - offs[1]
+            lo, hi = start - (window - 1), start + block_o - 1
+        else:           # o a kv tile: queries [start, end + window - 1]
+            start = offs[1] + o * block_o - offs[0]
+            lo, hi = start, start + block_o - 1 + window - 1
+        first = jnp.clip(_floor_div(lo, block_s), 0, num_s - steps)
+        last = jnp.clip(_floor_div(hi, block_s), first, num_s - 1)
+        return first, last
+
+    def tile(o, step, offs):
+        return span(o, offs)[0] + step
+
+    def fetch(o, step, offs):
+        first, last = span(o, offs)
+        return jnp.minimum(first + step, last)
+
+    return steps, tile, fetch
+
+
 def _kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
-            m_scr, l_scr, acc_scr, *, num_k: int, scale: float, **tile):
+            m_scr, l_scr, acc_scr, *, steps: int, kv_tile, scale: float,
+            **tile):
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
-    j = pl.program_id(2)
+    step = pl.program_id(2)
+    j = kv_tile(qi, step, offs_ref)
 
-    @pl.when(j == 0)
+    @pl.when(step == 0)
     def _():
         m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
@@ -208,11 +326,11 @@ def _kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
             m_scr[rows], l_scr[rows], acc_scr[rows] = _attend(
                 q_ref[0, rows, :], k_ref[0, cols, :], v_ref[0, cols, :],
                 (m_scr[rows], l_scr[rows], acc_scr[rows]), scale,
-                _tail_mask(d, block))
+                _tail_mask(d, block, tile.get("window")))
 
     _over_tile(offs_ref, qi, j, run, **tile)
 
-    @pl.when(j == num_k - 1)
+    @pl.when(step == steps - 1)
     def _():
         l = l_scr[:]
         denom = jnp.where(l == 0.0, 1.0, l)
@@ -224,70 +342,93 @@ def _kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
         l_ref[0] = jnp.transpose(l)
 
 
-def _executed_share(seq_q, seq_k, block_q, block_k, sub, causal):
+def _executed_share(seq_q, seq_k, block_q, block_k, sub, causal,
+                    window=None):
     """Share of the [Sq, Sk] square the kernels execute, for their
     ``cost_estimate``: the sub-tiles computed at zero offsets (the
     offsets are runtime values; a ring's steps average the same)."""
     if not causal:
         return 1.0
-    n = causal_subtile_counts(seq_q, seq_k, block_q, block_k, sub)
+    n = causal_subtile_counts(seq_q, seq_k, block_q, block_k, sub,
+                              window=window)
     return n["computed"] / (n["computed"] + n["skipped"])
+
+
+def _kv_row(group: int):
+    """The row of k and v (``[B * kv heads, S, D]``) that row ``b`` of q
+    (``[B * q heads, S, D]``) reads: ``group`` consecutive query heads
+    share one key-value head, and nothing is repeated in HBM."""
+    return (lambda b: b) if group == 1 else (lambda b: b // group)
 
 
 @functools.partial(
     jax.jit, static_argnames=("causal", "block_q", "block_k", "interpret",
-                              "sub"))
+                              "sub", "window", "out_dtype"))
 def _flash_bhsd(q, k, v, offsets, causal: bool, block_q: int,
-                block_k: int, interpret: bool, sub=None):
-    """q: [BH, Sq, D]; k, v: [BH, Sk, D]; offsets: int32[2].
-    Returns (o [BH,Sq,D], m [BH,1,Sq], l [BH,1,Sq]). ``sub`` is the
-    causal sub-tile (rows, columns); None takes the ladder's."""
+                block_k: int, interpret: bool, sub=None, window=None,
+                out_dtype=None):
+    """q: [BH, Sq, D]; k: [BHkv, Sk, D]; v: [BHkv, Sk, Dv], BH a
+    multiple of BHkv; offsets: int32[2]. Returns (o [BH,Sq,Dv],
+    m [BH,1,Sq], l [BH,1,Sq]). ``sub`` is the causal sub-tile (rows,
+    columns); None takes the ladder's. ``window``: a row sees its own
+    key and the ``window - 1`` before it. ``out_dtype``: of ``o``
+    (None: q's), written from the float32 accumulator."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bh, seq_q, d = q.shape
-    seq_k = k.shape[1]
+    seq_k, dv = k.shape[1], v.shape[2]
+    out_dtype = q.dtype if out_dtype is None else out_dtype
     scale = 1.0 / (d ** 0.5)
     num_k = seq_k // block_k
     sub_q, sub_k = sub = sub or _subtile_for(d, block_q, block_k)
-    share = _executed_share(seq_q, seq_k, block_q, block_k, sub, causal)
+    share = _executed_share(seq_q, seq_k, block_q, block_k, sub, causal,
+                            window)
+    kv_row = _kv_row(bh // k.shape[0])
+    steps, kv_tile, kv_fetch = _streamed_tiles(
+        window, block_q, block_k, num_k, behind=True)
 
+    tile = dict(block_q=block_q, block_k=block_k, sub_q=sub_q,
+                sub_k=sub_k, causal=causal)
+    if window is not None:
+        tile["window"] = window
     kernel = functools.partial(
-        _kernel, block_q=block_q, block_k=block_k, sub_q=sub_q,
-        sub_k=sub_k, num_k=num_k, causal=causal, scale=scale)
+        _kernel, steps=steps, kv_tile=kv_tile, scale=scale, **tile)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(bh, seq_q // block_q, num_k),
+        grid=(bh, seq_q // block_q, steps),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j, offs: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j, offs: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j, offs: (b, j, 0)),
+            pl.BlockSpec((1, block_k, d), lambda b, i, j, offs:
+                         (kv_row(b), kv_fetch(i, j, offs), 0)),
+            pl.BlockSpec((1, block_k, dv), lambda b, i, j, offs:
+                         (kv_row(b), kv_fetch(i, j, offs), 0)),
         ],
         out_specs=(
-            pl.BlockSpec((1, block_q, d), lambda b, i, j, offs: (b, i, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda b, i, j, offs: (b, i, 0)),
             pl.BlockSpec((1, 1, block_q), lambda b, i, j, offs: (b, 0, i)),
             pl.BlockSpec((1, 1, block_q), lambda b, i, j, offs: (b, 0, i)),
         ),
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
         ],
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=(
-            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct((bh, seq_q, dv), out_dtype),
             jax.ShapeDtypeStruct((bh, 1, seq_q), jnp.float32),
             jax.ShapeDtypeStruct((bh, 1, seq_q), jnp.float32),
         ),
         interpret=interpret,
         name="flash_fwd",
         cost_estimate=pl.CostEstimate(
-            flops=int(4 * bh * seq_q * seq_k * d * share),
-            bytes_accessed=(2 * q.size + k.size + v.size)
+            flops=int(2 * bh * seq_q * seq_k * (d + dv) * share),
+            bytes_accessed=(q.size + bh * seq_q * dv + k.size + v.size)
             * q.dtype.itemsize,
             transcendentals=int(bh * seq_q * seq_k * share),
         ),
@@ -295,10 +436,10 @@ def _flash_bhsd(q, k, v, offsets, causal: bool, block_q: int,
 
 
 def _recompute_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    block, d, scale: float):
+                    block, d, scale: float, window=None):
     """Shared backward recompute for one block of scores (rows of the
-    q tile against the first columns of the kv tile, the last of them
-    masked): p = exp(s - lse) and ds = p · (dp − delta) · scale. The dq
+    q tile against columns of the kv tile, the last of them masked):
+    p = exp(s - lse) and ds = p · (dp − delta) · scale. The dq
     and dk/dv kernels differ only in what they contract these with."""
     rows, cols = _block_slices(block)
     q = q_ref[0, rows, :].astype(jnp.float32)
@@ -307,7 +448,7 @@ def _recompute_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     do = do_ref[0, rows, :].astype(jnp.float32)
     lse = jnp.transpose(lse_ref[0, :, rows])          # [1,R] -> [R,1]
     delta = jnp.transpose(delta_ref[0, :, rows])      # [1,R] -> [R,1]
-    s = _where_tail(_tail_mask(d, block), jax.lax.dot_general(
+    s = _where_tail(_tail_mask(d, block, window), jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale, _NEG_INF)  # [R, C]
     # Dead rows (l == 0) store lse = +inf -> p underflows to 0.
@@ -320,14 +461,15 @@ def _recompute_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _bwd_dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                   delta_ref, dq_ref, dq_scr, *, num_k: int, scale: float,
-                   **tile):
+                   delta_ref, dq_ref, dq_scr, *, steps: int, kv_tile,
+                   scale: float, **tile):
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
-    j = pl.program_id(2)
+    step = pl.program_id(2)
+    j = kv_tile(qi, step, offs_ref)
 
-    @pl.when(j == 0)
+    @pl.when(step == 0)
     def _():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
@@ -335,27 +477,28 @@ def _bwd_dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         for block in blocks:
             _, k, _, _, ds = _recompute_p_ds(
                 q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, block, d,
-                scale)
+                scale, tile.get("window"))
             dq_scr[_block_slices(block)[0], :] += jax.lax.dot_general(
                 ds, k, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
 
     _over_tile(offs_ref, qi, j, run, **tile)
 
-    @pl.when(j == num_k - 1)
+    @pl.when(step == steps - 1)
     def _():
         dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                     delta_ref, dk_ref, dv_ref, dk_scr, dv_scr, *,
-                    num_q: int, scale: float, **tile):
+                    steps: int, q_tile, scale: float, **tile):
     from jax.experimental import pallas as pl
 
     j = pl.program_id(1)      # kv block (outer)
-    qi = pl.program_id(2)     # q block (inner, streams)
+    step = pl.program_id(2)   # q blocks stream (inner), a group's heads
+    qi = q_tile(j, step, offs_ref)  # one after another
 
-    @pl.when(qi == 0)
+    @pl.when(step == 0)
     def _():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
@@ -364,18 +507,18 @@ def _bwd_dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         for block in blocks:
             q, _, do, p, ds = _recompute_p_ds(
                 q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, block, d,
-                scale)
+                scale, tile.get("window"))
             cols = _block_slices(block)[1]
             dv_scr[cols, :] += jax.lax.dot_general(
                 p, do, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)           # [C, D]
+                preferred_element_type=jnp.float32)           # [C, Dv]
             dk_scr[cols, :] += jax.lax.dot_general(
                 ds, q, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)           # [C, D]
 
     _over_tile(offs_ref, qi, j, run, **tile)
 
-    @pl.when(qi == num_q - 1)
+    @pl.when(step == steps - 1)
     def _():
         dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
@@ -383,92 +526,127 @@ def _bwd_dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
 @functools.partial(
     jax.jit, static_argnames=("causal", "block_q", "block_k", "interpret",
-                              "sub"))
+                              "sub", "window"))
 def _flash_bwd_bhsd(q, k, v, do, lse, delta, offsets, causal: bool,
                     block_q: int, block_k: int, interpret: bool,
-                    sub=None):
-    """Backward kernels. q, do: [BH,Sq,D]; k, v: [BH,Sk,D];
-    lse, delta: [BH,1,Sq] fp32. Returns (dq, dk, dv) in input dtypes.
-    ``sub`` as for ``_flash_bhsd``."""
+                    sub=None, window=None):
+    """Backward kernels. q: [BH,Sq,D]; do: [BH,Sq,Dv]; k: [BHkv,Sk,D];
+    v: [BHkv,Sk,Dv]; lse, delta: [BH,1,Sq] fp32. Returns (dq, dk, dv)
+    in input dtypes; dk and dv are summed over the query heads that
+    share a key-value head inside the dk/dv kernel's grid. ``sub`` and
+    ``window`` as for ``_flash_bhsd``."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bh, seq_q, d = q.shape
-    seq_k = k.shape[1]
+    seq_k, dv = k.shape[1], v.shape[2]
+    group = bh // k.shape[0]
     num_q = seq_q // block_q
     num_k = seq_k // block_k
     sub_q, sub_k = sub = sub or _subtile_for(d, block_q, block_k)
-    share = _executed_share(seq_q, seq_k, block_q, block_k, sub, causal)
+    share = _executed_share(seq_q, seq_k, block_q, block_k, sub, causal,
+                            window)
     tile = dict(block_q=block_q, block_k=block_k, sub_q=sub_q,
                 sub_k=sub_k, causal=causal, scale=1.0 / (d ** 0.5))
+    if window is not None:
+        tile["window"] = window
+    kv_row = _kv_row(group)
+    steps, kv_tile, kv_fetch = _streamed_tiles(
+        window, block_q, block_k, num_k, behind=True)
 
-    q_spec = pl.BlockSpec((1, block_q, d), lambda b, i, j, offs: (b, i, 0))
-    k_spec = pl.BlockSpec((1, block_k, d), lambda b, i, j, offs: (b, j, 0))
+    def q_spec(width):
+        return pl.BlockSpec((1, block_q, width),
+                            lambda b, i, j, offs: (b, i, 0))
+
+    def k_spec(width):
+        return pl.BlockSpec((1, block_k, width), lambda b, i, j, offs:
+                            (kv_row(b), kv_fetch(i, j, offs), 0))
+
     stat_spec = pl.BlockSpec((1, 1, block_q),
                              lambda b, i, j, offs: (b, 0, i))
 
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, num_k=num_k, **tile),
+        functools.partial(_bwd_dq_kernel, steps=steps, kv_tile=kv_tile,
+                          **tile),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(bh, num_q, num_k),
-            in_specs=[q_spec, k_spec, k_spec, q_spec, stat_spec,
-                      stat_spec],
-            out_specs=q_spec,
+            grid=(bh, num_q, steps),
+            in_specs=[q_spec(d), k_spec(d), k_spec(dv), q_spec(dv),
+                      stat_spec, stat_spec],
+            out_specs=q_spec(d),
             scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
         name="flash_bwd_dq",
         cost_estimate=pl.CostEstimate(
-            flops=int(6 * bh * seq_q * seq_k * d * share),
+            flops=int(2 * bh * seq_q * seq_k * (2 * d + dv) * share),
             bytes_accessed=(2 * q.size + k.size + v.size)
             * q.dtype.itemsize,
             transcendentals=int(bh * seq_q * seq_k * share),
         ),
     )(offsets, q, k, v, do, lse, delta)
 
-    # dk/dv: swap grid so the kv block is outer and q streams.
-    q_spec2 = pl.BlockSpec((1, block_q, d), lambda b, j, i, offs: (b, i, 0))
-    k_spec2 = pl.BlockSpec((1, block_k, d), lambda b, j, i, offs: (b, j, 0))
-    stat_spec2 = pl.BlockSpec((1, 1, block_q),
-                              lambda b, j, i, offs: (b, 0, i))
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, num_q=num_q, **tile),
+    # dk/dv: swap grid so the kv block is outer and q streams: the q
+    # tiles of each of the group's query heads in turn, into one
+    # accumulator a key-value head.
+    q_steps, q_tile, q_fetch = _streamed_tiles(
+        window, block_k, block_q, num_q, behind=False)
+    if group == 1:
+        q_row, q_of = (lambda b, step: b), (lambda step: step)
+    else:
+        q_row = lambda b, step: b * group + step // q_steps
+        q_of = lambda step: step % q_steps
+
+    def q_spec2(width):
+        return pl.BlockSpec((1, block_q, width), lambda b, j, i, offs:
+                            (q_row(b, i), q_fetch(j, q_of(i), offs), 0))
+
+    def k_spec2(width):
+        return pl.BlockSpec((1, block_k, width),
+                            lambda b, j, i, offs: (b, j, 0))
+
+    stat_spec2 = pl.BlockSpec((1, 1, block_q), lambda b, j, i, offs:
+                              (q_row(b, i), 0, q_fetch(j, q_of(i), offs)))
+    dk, dv_ = pl.pallas_call(
+        functools.partial(
+            _bwd_dkv_kernel, steps=group * q_steps,
+            q_tile=lambda j, step, offs: q_tile(j, q_of(step), offs),
+            **tile),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(bh, num_k, num_q),
-            in_specs=[q_spec2, k_spec2, k_spec2, q_spec2, stat_spec2,
-                      stat_spec2],
-            out_specs=(k_spec2, k_spec2),
+            grid=(k.shape[0], num_k, group * q_steps),
+            in_specs=[q_spec2(d), k_spec2(d), k_spec2(dv), q_spec2(dv),
+                      stat_spec2, stat_spec2],
+            out_specs=(k_spec2(d), k_spec2(dv)),
             scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                            pltpu.VMEM((block_k, d), jnp.float32)],
+                            pltpu.VMEM((block_k, dv), jnp.float32)],
         ),
         out_shape=(jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)),
         interpret=interpret,
         name="flash_bwd_dkv",
         cost_estimate=pl.CostEstimate(
-            flops=int(10 * bh * seq_q * seq_k * d * share),
+            flops=int(2 * bh * seq_q * seq_k * (3 * d + 2 * dv) * share),
             bytes_accessed=(q.size + 2 * (k.size + v.size))
             * q.dtype.itemsize,
             transcendentals=int(bh * seq_q * seq_k * share),
         ),
     )(offsets, q, k, v, do, lse, delta)
-    return dq, dk, dv
+    return dq, dk, dv_
 
 
 def causal_subtile_counts(seq_q: int, seq_k: int, block_q: int,
                           block_k: int, sub, q_offset: int = 0,
-                          k_offset: int = 0) -> dict:
+                          k_offset: int = 0, window=None) -> dict:
     """How often the causal structure engages in one head of a call:
     of the (seq_q / sub_q) x (seq_k / sub_k) sub-tiles of the square,
     how many the kernels compute (``computed``), how many of those pay
     for the mask (``masked``) and how many are not touched
     (``skipped``). ``sub=(block_q, block_k)`` is the whole-tile
-    predicate the kernels had before PR 30. Plain integers through
-    ``_tile_blocks``, the rule the kernels apply to their runtime
-    offsets."""
+    predicate the kernels had before PR 30; ``window`` counts a
+    windowed call. Plain integers through ``_tile_blocks``, the rule
+    the kernels apply to their runtime offsets."""
     sub_q, sub_k = sub
     if block_q % sub_q or block_k % sub_k or seq_q % block_q \
             or seq_k % block_k:
@@ -478,18 +656,26 @@ def causal_subtile_counts(seq_q: int, seq_k: int, block_q: int,
     computed = masked = 0
     for q_start in range(q_offset, q_offset + seq_q, block_q):
         for k_start in range(k_offset, k_offset + seq_k, block_k):
-            for r0, r1, free, vis in _tile_blocks(
-                    q_start - k_start, block_q, block_k, sub_q, sub_k):
-                computed += (r1 - r0) // sub_q * (vis // sub_k)
+            for block in _tile_blocks(q_start - k_start, block_q, block_k,
+                                      sub_q, sub_k, window):
+                r0, r1, free, vis = block[:4]
+                computed += (r1 - r0) // sub_q \
+                    * ((vis - _block_lo(block)) // sub_k)
                 masked += (r1 - r0) // sub_q * ((vis - free) // sub_k)
     return {"computed": computed, "masked": masked,
             "skipped": (seq_q // sub_q) * (seq_k // sub_k) - computed}
 
 
-def _dense_reference(q, k, v, causal: bool, q_offset, k_offset):
+def _dense_reference(q, k, v, causal: bool, q_offset, k_offset,
+                     window=None, out_dtype=None):
     """Mathematically identical dense formulation (fp32 softmax) — the
-    shape fallback and the test oracle for the kernels."""
+    shape fallback and the test oracle for the kernels. Fewer key-value
+    heads than query heads are repeated here, which the kernels never
+    do."""
     d = q.shape[-1]
+    group = q.shape[2] // k.shape[2]
+    if group > 1:
+        k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                         preferred_element_type=jnp.float32)
     logits = logits / jnp.sqrt(jnp.float32(d))
@@ -497,11 +683,14 @@ def _dense_reference(q, k, v, causal: bool, q_offset, k_offset):
         q_pos = q_offset + jnp.arange(q.shape[1])
         k_pos = k_offset + jnp.arange(k.shape[1])
         allowed = q_pos[:, None] >= k_pos[None, :]
+        if window is not None:
+            allowed &= q_pos[:, None] - k_pos[None, :] < window
         logits = jnp.where(allowed[None, None], logits, _NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1)
     if causal:
         probs = jnp.where(allowed[None, None], probs, 0.0)
-    return jnp.einsum("bhqk,bkhd->bqhd", probs.astype(q.dtype), v)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs.astype(q.dtype), v,
+                      preferred_element_type=out_dtype)
 
 
 def _shapes_ok(seq_q, seq_k, block_q, block_k):
@@ -530,15 +719,41 @@ def _shapes_ok(seq_q, seq_k, block_q, block_k):
 # (27% slower); 256x256 46.29; 128x512 53.79. 1024x1024 and 2048x512
 # do not fit VMEM. So the default pair holds through D=256 and the
 # halving starts past it (D=512 and beyond are still unmeasured).
+#
+# Measured at D=64 on v5e silicon (PR 31: B1, 20 query heads over 10
+# key-value heads, S16384, key head 64, value head 128; one call's
+# forward and dq + dk/dv, ms; 256x256 sub-tiles):
+#   tile        causal                  window 512
+#   512x1024    14.87 + 36.13 = 50.99   2.48 + 4.72 =  7.20
+#   1024x1024   12.28 + 31.85 = 44.13   2.32 + 4.58 =  6.90
+#   512x2048    14.76 + 32.86 = 47.62
+#   1024x512    22.08 + 35.92 = 58.00   2.94 + 5.07 =  8.01
+#   512x512     23.62 + 41.69 = 65.30   3.09 + 5.65 =  8.73
+#   256x1024    20.98 + 43.84 = 64.82
+#   256x512                             3.83 + 7.16 = 10.98
+#   512x256                             4.47 + 8.06 = 12.53
+#   256x256                             5.18 + 9.54 = 14.72
+# 1024x2048 does not fit VMEM (the dq kernel). At 1024x1024 the
+# sub-tile hardly matters (causal: 256x256 44.13, 512x512 44.13,
+# 128x128 44.53; windowed: 256x256 6.90, 128x128 7.10, 512x512 7.21).
+# So a head of 64 or less takes a q tile of 1024 where the sequence has
+# one (_HEAD_DIM_SMALL): half the bytes a row, so twice the rows in the
+# same VMEM. The causal call then runs at 51% of the MXU's peak on the
+# scores it needs. A windowed call wants the same tile, not a shorter
+# one that hugs its band: it is paced by its grid steps (2.4 us each,
+# three a q tile), not by the 1.4 ms of arithmetic the band needs, and
+# longer tiles are fewer steps.
 _BLOCK_Q_LADDER = (512, 256, 128)
 _BLOCK_K_LADDER = (1024, 512, 256, 128)
 _HEAD_DIM_BASE = 256  # the largest D the default ladder was measured at
+_HEAD_DIM_SMALL = 64  # up to here a q tile of 1024 fits, and is faster
 
 
 def _ladders_for(head_dim: int):
     """(q_ladder, k_ladder) scaled to ``head_dim``: the measured
-    512x1024 defaults up to D=256, then each doubling of D halves the
-    leading tiles (floor 128) so per-program VMEM stays level."""
+    512x1024 defaults up to D=256 (a q tile of 1024 on top up to D=64),
+    then each doubling of D halves the leading tiles (floor 128) so
+    per-program VMEM stays level."""
     q_top, k_top = _BLOCK_Q_LADDER[0], _BLOCK_K_LADDER[0]
     d = max(1, int(head_dim))
     while d > _HEAD_DIM_BASE and (q_top > 128 or k_top > 128):
@@ -547,6 +762,8 @@ def _ladders_for(head_dim: int):
         d //= 2
     q_ladder = tuple(b for b in _BLOCK_Q_LADDER if b <= q_top)
     k_ladder = tuple(b for b in _BLOCK_K_LADDER if b <= k_top)
+    if head_dim <= _HEAD_DIM_SMALL:
+        q_ladder = (2 * q_ladder[0],) + q_ladder
     return q_ladder, k_ladder
 
 
@@ -571,7 +788,8 @@ def _ladders_for(head_dim: int):
 # loop), and one softmax update per sub-tile only 3% and 10% faster
 # (the per-row work of an update, reductions across lanes above all,
 # is paid again by every block of a row). One rung: both head sizes
-# want the same; past 256 nothing is measured and the last rung stands.
+# want the same, and so does 64 (PR 31: the table beside the tile
+# ladders); past 256 nothing is measured and the last rung stands.
 _SUBTILE_LADDER = ((256, (256, 256)),)
 
 
@@ -589,11 +807,12 @@ def _subtile_for(head_dim: int, block_q: int, block_k: int):
 
 
 def _note_subtiles(seq_q, seq_k, head_dim, block_q, block_k, q_offset,
-                   k_offset) -> None:
+                   k_offset, window=None) -> None:
     """Write ``causal_subtile_counts`` of the call being traced into the
-    metrics registry (``hvd_flash_subtiles{kind=...}``, docs/metrics.md),
-    where a world with its metrics plane on is there to read it. Traced
-    offsets (the ring's) have no count at trace time and write nothing."""
+    metrics registry (``hvd_flash_subtiles{kind=...}``, and with a
+    window ``{kind=...,window="<n>"}``: docs/metrics.md), where a world
+    with its metrics plane on is there to read it. Traced offsets (the
+    ring's) have no count at trace time and write nothing."""
     from horovod_tpu.common import basics
     if not basics.initialized():
         return
@@ -606,10 +825,12 @@ def _note_subtiles(seq_q, seq_k, head_dim, block_q, block_k, q_offset,
         return
     counts = causal_subtile_counts(
         seq_q, seq_k, block_q, block_k,
-        _subtile_for(head_dim, block_q, block_k), q_offset, k_offset)
+        _subtile_for(head_dim, block_q, block_k), q_offset, k_offset,
+        window)
+    label = "" if window is None else f',window="{window}"'
     for kind, n in counts.items():
         reg.gauge(
-            f'hvd_flash_subtiles{{kind="{kind}"}}',
+            f'hvd_flash_subtiles{{kind="{kind}"{label}}}',
             "sub-tiles a head of the causal flash call traced last: "
             "computed, of those masked, and skipped", agg="max").set(n)
 
@@ -633,31 +854,56 @@ def _from_bhsd(x, b, h):
     return x.reshape(b, h, s, d).transpose(0, 2, 1, 3)
 
 
-def _run(q, k, v, offsets, causal, block_q, block_k, interpret):
+def _run(q, k, v, offsets, causal, block_q, block_k, interpret,
+         window=None, out_dtype=None):
     b, seq_q, h, d = q.shape
     o, m, l = _flash_bhsd(_to_bhsd(q), _to_bhsd(k), _to_bhsd(v), offsets,
-                          causal, block_q, block_k, bool(interpret))
+                          causal, block_q, block_k, bool(interpret),
+                          window=window, out_dtype=out_dtype)
     o = _from_bhsd(o, b, h)
     m = m[:, 0].reshape(b, h, seq_q)
     l = l[:, 0].reshape(b, h, seq_q)
     return o, m, l
 
 
+def _check_call(q, k, v, causal, window):
+    """The shapes a call may have: [B, S, H, D] with k and v over a
+    divisor of q's heads, v's head size its own; a window only under
+    the causal mask."""
+    if k.shape[:3] != v.shape[:3] or k.shape[-1] != q.shape[-1] \
+            or q.shape[2] % k.shape[2]:
+        raise ValueError(
+            f"q{q.shape} k{k.shape} v{v.shape}: k and v share batch, "
+            f"length and heads, k has q's head size, and q's heads are a "
+            f"multiple of theirs")
+    if window is not None and (not causal or window < 1):
+        raise ValueError(f"window {window!r} needs causal=True and at "
+                         f"least the row's own key")
+
+
+def _blocks_for(q, k, block_q, block_k):
+    """The (q, kv) tile of a call: explicit, or the largest rung of the
+    head size's ladder that divides the sequence."""
+    q_ladder, k_ladder = _ladders_for(q.shape[-1])
+    return (_auto_block(q.shape[1], q_ladder, block_q),
+            _auto_block(k.shape[1], k_ladder, block_k))
+
+
 def flash_attention_stats(q, k, v, causal: bool = True,
                           q_offset=0, k_offset=0,
                           block_q: Optional[int] = None,
                           block_k: Optional[int] = None,
-                          interpret: Optional[bool] = None
+                          interpret: Optional[bool] = None,
+                          window: Optional[int] = None
                           ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Forward-only flash attention that also returns the softmax
-    statistics: (o [B,Sq,H,D], m [B,H,Sq] running max, l [B,H,Sq]
+    statistics: (o [B,Sq,H,Dv], m [B,H,Sq] running max, l [B,H,Sq]
     normalizer). Ring attention merges these across rotated kv shards.
     Offsets may be traced values (one compilation serves every ring
     step)."""
+    _check_call(q, k, v, causal, window)
     seq_q, seq_k = q.shape[1], k.shape[1]
-    q_ladder, k_ladder = _ladders_for(q.shape[-1])
-    block_q = _auto_block(seq_q, q_ladder, block_q)
-    block_k = _auto_block(seq_k, k_ladder, block_k)
+    block_q, block_k = _blocks_for(q, k, block_q, block_k)
     if not _shapes_ok(seq_q, seq_k, block_q, block_k):
         raise ValueError(
             f"sequence lengths ({seq_q}, {seq_k}) must be divisible by "
@@ -666,10 +912,11 @@ def flash_attention_stats(q, k, v, causal: bool = True,
         interpret = jax.default_backend() != "tpu"
     if causal:
         _note_subtiles(seq_q, seq_k, q.shape[-1], block_q, block_k,
-                       q_offset, k_offset)
+                       q_offset, k_offset, window)
     offsets = jnp.stack([jnp.asarray(q_offset, jnp.int32),
                          jnp.asarray(k_offset, jnp.int32)])
-    return _run(q, k, v, offsets, causal, block_q, block_k, interpret)
+    return _run(q, k, v, offsets, causal, block_q, block_k, interpret,
+                window)
 
 
 def _lse_from_stats(m, l):
@@ -687,19 +934,21 @@ def flash_attention_bwd(q, k, v, o, m, l, do, causal: bool = True,
                         q_offset=0, k_offset=0,
                         block_q: Optional[int] = None,
                         block_k: Optional[int] = None,
-                        interpret: Optional[bool] = None):
+                        interpret: Optional[bool] = None,
+                        window: Optional[int] = None):
     """Raw flash backward against externally-merged softmax stats.
 
-    q, k, v, o, do: [B,S,H,D]; m, l: [B,H,Sq] (as returned — or ring-
-    merged — from flash_attention_stats). Returns (dq, dk, dv) in the
-    input dtypes. Ring attention calls this once per rotated kv shard
-    with the *global* lse, which makes per-shard contributions sum to
-    the exact full-sequence gradient."""
+    q: [B,Sq,H,D]; k: [B,Sk,Hkv,D]; v: [B,Sk,Hkv,Dv]; o, do:
+    [B,Sq,H,Dv]; m, l: [B,H,Sq] (as returned — or ring-merged — from
+    flash_attention_stats). Returns (dq, dk, dv) in the input dtypes,
+    dk and dv summed over the query heads that share their head. Ring
+    attention calls this once per rotated kv shard with the *global*
+    lse, which makes per-shard contributions sum to the exact
+    full-sequence gradient."""
+    _check_call(q, k, v, causal, window)
     b, seq_q, h, d = q.shape
     seq_k = k.shape[1]
-    q_ladder, k_ladder = _ladders_for(d)
-    block_q = _auto_block(seq_q, q_ladder, block_q)
-    block_k = _auto_block(seq_k, k_ladder, block_k)
+    block_q, block_k = _blocks_for(q, k, block_q, block_k)
     if not _shapes_ok(seq_q, seq_k, block_q, block_k):
         raise ValueError(
             f"sequence lengths ({seq_q}, {seq_k}) must be divisible by "
@@ -714,28 +963,34 @@ def flash_attention_bwd(q, k, v, o, m, l, do, causal: bool = True,
                     axis=-1)[:, None, :]   # [BH,1,S], see _lse_from_stats
     dq, dk, dv = _flash_bwd_bhsd(qb, kb, vb, dob, lse, delta, offsets,
                                  bool(causal), block_q, block_k,
-                                 bool(interpret))
-    return (_from_bhsd(dq, b, h), _from_bhsd(dk, b, h),
-            _from_bhsd(dv, b, h))
+                                 bool(interpret), window=window)
+    return (_from_bhsd(dq, b, h), _from_bhsd(dk, b, k.shape[2]),
+            _from_bhsd(dv, b, k.shape[2]))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def _flash(q, k, v, offsets, causal, block_q, block_k, interpret):
-    return _run(q, k, v, offsets, causal, block_q, block_k, interpret)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+def _flash(q, k, v, offsets, causal, block_q, block_k, interpret,
+           window=None, out_dtype=None):
+    return _run(q, k, v, offsets, causal, block_q, block_k, interpret,
+                window, out_dtype)[0]
 
 
-def _flash_fwd(q, k, v, offsets, causal, block_q, block_k, interpret):
-    o, m, l = _run(q, k, v, offsets, causal, block_q, block_k, interpret)
+def _flash_fwd(q, k, v, offsets, causal, block_q, block_k, interpret,
+               window, out_dtype):
+    o, m, l = _run(q, k, v, offsets, causal, block_q, block_k, interpret,
+                   window, out_dtype)
     return o, (q, k, v, o, m, l, offsets)
 
 
-def _flash_bwd(causal, block_q, block_k, interpret, residuals, g):
+def _flash_bwd(causal, block_q, block_k, interpret, window, out_dtype,
+               residuals, g):
     import numpy as np
     q, k, v, o, m, l, offsets = residuals
     dq, dk, dv = flash_attention_bwd(
         q, k, v, o, m, l, g, causal=causal,
         q_offset=offsets[0], k_offset=offsets[1],
-        block_q=block_q, block_k=block_k, interpret=interpret)
+        block_q=block_q, block_k=block_k, interpret=interpret,
+        window=window)
     d_offsets = np.zeros(offsets.shape, jax.dtypes.float0)
     return dq, dk, dv, d_offsets
 
@@ -747,9 +1002,29 @@ def flash_attention(q, k, v, causal: bool = True,
                     q_offset=0, k_offset=0,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
-                    interpret: Optional[bool] = None):
-    """Blockwise-softmax attention. q, k, v: [B, S, H, D] (the module
-    layout of models/transformer.py); returns [B, Sq, H, D] in q.dtype.
+                    interpret: Optional[bool] = None,
+                    window: Optional[int] = None, out_dtype=None):
+    """Blockwise-softmax attention. q: [B, Sq, H, D] (the module layout
+    of models/transformer.py); k: [B, Sk, Hkv, D]; v: [B, Sk, Hkv, Dv];
+    returns [B, Sq, H, Dv] in q.dtype, or in ``out_dtype``: the kernel
+    writes from a float32 accumulator, and a caller that subtracts two
+    nearly equal maps (differential attention) wants it unrounded.
+
+    **Fewer key-value heads**: ``Hkv`` divides ``H``, and query head
+    ``h`` reads key-value head ``h // (H / Hkv)`` (consecutive query
+    heads share one). Nothing is repeated in HBM: the kernels' index
+    maps send a group's query heads to the same k and v tiles, and the
+    dk/dv kernel sums over the group in its grid.
+
+    **A value head of its own size**: ``Dv`` need not be ``D``; the
+    scores scale by ``D ** -0.5`` and the output has ``Dv``.
+
+    **``window``** (with ``causal``): a row sees its own key and the
+    ``window - 1`` before it. Tiles wholly behind the window are
+    skipped in all three kernels as those above the diagonal are, and
+    not fetched: each kernel's grid walks only the tiles the band can
+    touch (``_streamed_tiles``). ``None`` is the causal mask alone,
+    which lowers to what it did before the window existed.
 
     ``q_offset``/``k_offset`` (python ints or traced scalars) are the
     global positions of element 0, shifting the causal mask — ring
@@ -762,10 +1037,9 @@ def flash_attention(q, k, v, causal: bool = True,
     in ``jax.default_matmul_precision("float32")`` for ~2e-6 agreement
     at several times the MXU cost; the context reaches inside the
     pallas kernel (verified on v5e silicon)."""
+    _check_call(q, k, v, causal, window)
     seq_q, seq_k = q.shape[1], k.shape[1]
-    q_ladder, k_ladder = _ladders_for(q.shape[-1])
-    bq = _auto_block(seq_q, q_ladder, block_q)
-    bk = _auto_block(seq_k, k_ladder, block_k)
+    bq, bk = _blocks_for(q, k, block_q, block_k)
     if not _shapes_ok(seq_q, seq_k, bq, bk):
         if not causal:
             raise ValueError("non-causal path requires block-divisible "
@@ -779,13 +1053,15 @@ def flash_attention(q, k, v, causal: bool = True,
                 f"{bq}x{bk}); running the dense fallback, which "
                 f"materializes the [B,H,Sq,Sk] f32 logits in HBM",
                 RuntimeWarning, stacklevel=2)
-        return _dense_reference(q, k, v, causal, q_offset, k_offset)
+        return _dense_reference(q, k, v, causal, q_offset, k_offset,
+                                window, out_dtype)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     if causal:
         _note_subtiles(seq_q, seq_k, q.shape[-1], bq, bk, q_offset,
-                       k_offset)
+                       k_offset, window)
     offsets = jnp.stack([jnp.asarray(q_offset, jnp.int32),
                          jnp.asarray(k_offset, jnp.int32)])
     return _flash(q, k, v, offsets, bool(causal), bq, bk,
-                  bool(interpret))
+                  bool(interpret), window,
+                  None if out_dtype is None else jnp.dtype(out_dtype))

@@ -152,6 +152,63 @@ def test_cells_kernels_compile_with_their_subtiles(chip, bh, seq, d,
             assert name in text
 
 
+# phi4flash-injit-1chip (PR 31): one row of 16,384, two flash calls a
+# layer of 20 query heads over 10 key-value heads, key head 64, value
+# head 128, inside the window of 512 and over the whole prefix.
+@pytest.mark.parametrize("window", [512, None], ids=["window", "full"])
+def test_phi4flash_kernels_compile_for_v5e(chip, window):
+    """Grouped heads, a value head of its own size, head size 64, the
+    windowed grid and a float32 output (and so a float32 ``do``)
+    through the TPU's compiler at the cell's shape and the tiles
+    ``flash_attention`` picks there."""
+    from horovod_tpu.parallel.flash_attention import _blocks_for
+    seq, d, dv = 16384, 64, 128
+    arr = lambda *shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dt, sharding=chip)
+    q, k, v, do = arr(20, seq, d), arr(10, seq, d), arr(10, seq, dv), \
+        arr(20, seq, dv, dt=jnp.float32)
+    stat = arr(20, 1, seq, dt=jnp.float32)
+    offsets = arr(2, dt=jnp.int32)
+    block_q, block_k = _blocks_for(
+        arr(1, seq, 20, d), arr(1, seq, 10, d), None, None)
+    assert (block_q, block_k) == (1024, 1024)
+    args = dict(causal=True, block_q=block_q, block_k=block_k,
+                interpret=False, window=window)
+    fwd = _flash_bhsd.lower(q, k, v, offsets, out_dtype=jnp.float32,
+                            **args).compile()
+    bwd = _flash_bwd_bhsd.lower(q, k, v, do, stat, stat, offsets,
+                                **args).compile()
+    assert (_kernel_calls(fwd), _kernel_calls(bwd)) == (1, 2)
+    for text, names in ((fwd.as_text(), ["flash_fwd"]),
+                        (bwd.as_text(), ["flash_bwd_dq", "flash_bwd_dkv"])):
+        for name in names:
+            assert name in text
+
+
+def test_the_selective_scan_compiles_for_v5e(chip):
+    """``ssm_scan_fwd`` and ``ssm_scan_bwd`` at the cell's shape (one
+    row of 16,384, 5,120 channels, 16 states) and the ladder's chunk:
+    scalars from SMEM, registers of 1,024 channels, the backward's
+    chunk of states in VMEM."""
+    from horovod_tpu.parallel import ssm_scan as ss
+    bt, seq, channels, states = 1, 16384, 5120, 16
+    arr = lambda *shape, dt=jnp.float32: jax.ShapeDtypeStruct(
+        shape, dt, sharding=chip)
+    args = (arr(bt, seq, channels, dt=jnp.bfloat16), arr(bt, seq, channels),
+            arr(channels, states), arr(bt, seq, states),
+            arr(bt, seq, states), arr(channels))
+
+    def loss(*x):
+        return jnp.sum(ss.selective_scan(*x, interpret=False)
+                       .astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=tuple(range(6)))) \
+        .lower(*args).compile()
+    assert _kernel_calls(compiled) == 2
+    for name in ("ssm_scan_fwd", "ssm_scan_bwd"):
+        assert name in compiled.as_text()
+
+
 def test_d256_keeps_the_default_pair_and_d512_is_halved():
     """The D=256 cases above compile the pair the chip measured fastest
     of the ladder there (PR 27), the D=512 case the halved one."""
