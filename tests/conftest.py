@@ -5,7 +5,27 @@ anywhere, so SPMD/mesh tests exercise real multi-device sharding without
 TPU hardware (the driver separately dry-runs the multi-chip path; see
 __graft_entry__.py)."""
 
+import faulthandler
 import os
+import signal
+import sys
+import tempfile
+from collections import defaultdict
+
+# No source is compiled twice in a run either. The image's site-packages
+# carry no bytecode and its environment says PYTHONDONTWRITEBYTECODE=1:
+# left so, each of the run's hundreds of interpreters compiles what it
+# imports from source (1.4 of the 2.0 s of ``import jax``, 4.5 of the
+# 10 s of ``import tensorflow``, half of ``import horovod_tpu``). One
+# bytecode cache for this process and everything spawned from it, beside
+# ``.jax_cache`` and ignored like it (with a prefix set Python writes no
+# ``__pycache__`` anywhere else).
+sys.pycache_prefix = os.environ.setdefault(
+    "PYTHONPYCACHEPREFIX",
+    os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                 ".pycache"))
+os.environ.pop("PYTHONDONTWRITEBYTECODE", None)
+sys.dont_write_bytecode = False
 
 os.environ["JAX_PLATFORMS"] = "cpu"
 _flags = os.environ.get("XLA_FLAGS", "")
@@ -13,7 +33,17 @@ if "xla_force_host_platform_device_count" not in _flags:
     os.environ["XLA_FLAGS"] = (
         _flags + " --xla_force_host_platform_device_count=8").strip()
 
-import tempfile  # noqa: E402
+# What the suite holds the program to on the CPU is its arithmetic
+# against references on toy shapes, in code that runs for milliseconds:
+# not worth LLVM's optimiser. Spawned ranks and examples inherit the
+# variable. The compiles for a described TPU keep the compiler whole
+# (the ``whole_compiler`` fixture below).
+os.environ.setdefault("JAX_DISABLE_MOST_OPTIMIZATIONS", "1")
+# No program is compiled twice in a run: the persistent cache below keeps
+# every compilation, not only those over jax's default floor of 1 s (most
+# of this suite's are under it, the more so without the optimiser), so a
+# later test or a spawned rank that builds the same program loads it.
+os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
 
 # The flight recorder (common/trace.py) is ON by default and dumps
 # into HOROVOD_TPU_FLIGHT_DIR (default: CWD) on every world abort.
@@ -31,8 +61,7 @@ os.environ.setdefault("HOROVOD_TPU_FLIGHT_DIR",
 # os.environ) — and across runs: the mp tier pays the same model jits
 # hundreds of times in short-lived interpreters. The directory is the
 # operator's JAX_COMPILATION_CACHE_DIR or the fixed <checkout>/.jax_cache
-# (a temporary name would never hit twice); compiles under jax's
-# default 1 s floor are not cached.
+# (a temporary name would never hit twice).
 from horovod_tpu.utils.compile_cache import enable_compile_cache  # noqa: E402
 
 enable_compile_cache()
@@ -93,6 +122,10 @@ def pytest_configure(config):
         "runs, big example smokes) excluded from the budgeted tier-1 "
         "sweep (-m 'not slow'); the full matrix (plain `pytest "
         "tests/`) still runs them")
+    config.addinivalue_line(
+        "markers", "time_limit(seconds): this test's own limit in place "
+        f"of the default {TEST_LIMIT_S:g} s, for a test whose spawned "
+        "worlds carry a longer timeout= of their own")
 
 
 def pytest_collection_modifyitems(config, items):
@@ -110,3 +143,85 @@ def hvd_world():
     hvd.init()
     yield hvd
     hvd.shutdown()
+
+
+@pytest.fixture(scope="module")
+def whole_compiler():
+    """The optimiser back on while a module compiles for a described
+    TPU: what such a test asserts on (the schedule, what fits VMEM) is
+    the compiler's own work."""
+    import jax
+    was = jax.config.read("jax_disable_most_optimizations")
+    jax.config.update("jax_disable_most_optimizations", False)
+    yield
+    jax.config.update("jax_disable_most_optimizations", was)
+
+
+# -- every test has a limit of its own --------------------------------------
+
+TEST_LIMIT_S = 180.0
+
+
+def _all_stacks() -> str:
+    with tempfile.TemporaryFile("w+") as fh:
+        faulthandler.dump_traceback(file=fh, all_threads=True)
+        fh.seek(0)
+        return fh.read()
+
+
+@pytest.fixture(autouse=True)
+def _time_limit(request):
+    """Fail (not hang) a test that outlasts its limit, with every
+    thread's stack, and let the run go on. ``time_limit(seconds)``
+    marks the few whose spawned worlds carry a longer ``timeout=``."""
+    marker = request.node.get_closest_marker("time_limit")
+    limit = float(marker.args[0]) if marker else TEST_LIMIT_S
+
+    def overrun(signum, frame):
+        pytest.fail(f"{request.node.nodeid} exceeded its time limit of "
+                    f"{limit:g} s\n{_all_stacks()}", pytrace=False)
+
+    was = signal.signal(signal.SIGALRM, overrun)
+    signal.setitimer(signal.ITIMER_REAL, limit)
+    try:
+        yield
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, was)
+
+
+# -- a cut run says so ------------------------------------------------------
+
+@pytest.fixture(scope="session", autouse=True)
+def _sigterm_as_found():
+    """``hvd.init()`` puts the program's preemption handler on SIGTERM
+    for the process's life: it waits out HOROVOD_PREEMPT_GRACE (30 s) and
+    exits 0, so under it `timeout` running out reads as a kill ten
+    seconds later (137, not 124). Spawned ranks install it as ever; this
+    process tells ``selfop`` it already has, and so keeps SIGTERM's
+    disposition as it found it, inside a test that holds a world too."""
+    from horovod_tpu.common import selfop
+    selfop._handler_installed = True
+    yield
+    selfop._handler_installed = False
+
+
+# -- where the time went ----------------------------------------------------
+
+def pytest_terminal_summary(terminalreporter):
+    by_file, by_test = defaultdict(float), defaultdict(float)
+    for reports in terminalreporter.stats.values():
+        for rep in reports:
+            if getattr(rep, "when", None) in ("setup", "call", "teardown"):
+                by_file[rep.nodeid.split("::", 1)[0]] += rep.duration
+                by_test[rep.nodeid] += rep.duration
+
+    def largest_first(seconds):
+        return sorted(seconds.items(), key=lambda kv: -kv[1])
+
+    terminalreporter.section("seconds by file (set-up + call + teardown)")
+    for name, s in largest_first(by_file):
+        terminalreporter.write_line(f"{s:9.2f} s  {name}")
+    terminalreporter.section("the twenty longest tests")
+    for name, s in largest_first(by_test)[:20]:
+        terminalreporter.write_line(f"{s:9.2f} s  {name}")

@@ -2037,7 +2037,7 @@ def scenario_abort_heartbeat_hang(hvd, rank, size):
     t0 = time.monotonic()
     _await_world_abort(hvd, rank, victim, hb_timeout + 15.0, "hb.hang")
     # the point of the heartbeat: detection is BOUNDED by the knob,
-    # not by the 8 s wedge ending or TCP keepalive (hours)
+    # not by the wedge ending (6 s) or TCP keepalive (hours)
     assert time.monotonic() - t0 < hb_timeout + 15.0
 
 

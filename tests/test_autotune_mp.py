@@ -7,6 +7,8 @@ integration leg the reference exercises by running under mpirun."""
 
 import os
 
+import pytest
+
 from tests.test_multiprocess import run_scenario
 
 _MAX_SAMPLES = 3
@@ -37,6 +39,7 @@ def test_autotune_two_process_sync_and_log(tmp_path):
         assert float(score) >= 0.0
 
 
+@pytest.mark.time_limit(270)
 def test_autotune_sync_through_hier_controller(tmp_path):
     """Tuned values must reach MIGRATED LEAVES too: with 4 ranks on 2
     fake hosts the ResponseList trailer rides the local root's relay,

@@ -10,7 +10,9 @@ stay out of tier-1, and narrow steps over a mesh of the four described
 chips (5 to 15 s each) stand in for them.
 
 Such compiles write persistent-cache entries that cannot be read back
-without a chip, so the cache is off around this file.
+without a chip, so the cache is off around this file. The suite's CPU
+compiles skip the optimiser (``tests/conftest.py``); what these tests
+assert on is the TPU compiler's own work, so this file keeps it whole.
 """
 
 import os
@@ -26,7 +28,11 @@ from horovod_tpu.parallel.flash_attention import (  # noqa: E402
     _flash_bhsd, _flash_bwd_bhsd, _ladders_for, _subtile_for,
 )
 
-pytestmark = pytest.mark.fast
+pytestmark = [pytest.mark.fast, pytest.mark.usefixtures("whole_compiler")]
+
+
+def test_the_compiler_is_whole_here():
+    assert not jax.config.read("jax_disable_most_optimizations")
 
 
 @pytest.fixture(scope="module")

@@ -466,6 +466,7 @@ def _train_world(tmp_path, tag: str, compression: str) -> dict:
 
 
 @pytest.mark.slow
+@pytest.mark.time_limit(750)  # three worlds of _train_world's 240 s
 def test_convergence_parity_none_bf16_int8(tmp_path):
     """The ISSUE 9 convergence-parity leg: the toy TransformerLM from
     models/ trained data-parallel at ws=4 under none / bf16 /

@@ -16,6 +16,9 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 EX = os.path.join(REPO, "examples")
 
+# _run's own timeout= is the limit that speaks first
+pytestmark = pytest.mark.time_limit(450)
+
 
 def _run(script, *args, n_devices=1, timeout=420, extra_env=None):
     env = dict(os.environ)
@@ -95,12 +98,13 @@ def test_zero_fsdp():
     assert "ZeRO-1" in out and "FSDP" in out
 
 
+@pytest.mark.time_limit(870)  # the example twice
 def test_torch_imagenet_resnet50(tmp_path):
     """ImageNet-scale torch example (fp16 allreduce + gradient
     accumulation + warmup + checkpoint/resume), smoke-sized."""
     ckpt = str(tmp_path / "checkpoint-{epoch}.pth.tar")
     out = _run("torch_imagenet_resnet50.py", "--epochs", "1",
-               "--steps-per-epoch", "2", "--batch-size", "2",
+               "--steps-per-epoch", "1", "--batch-size", "2",
                "--batches-per-allreduce", "2", "--image-size", "32",
                "--num-classes", "10", "--width", "8",
                "--fp16-allreduce", "--checkpoint-format", ckpt)
@@ -108,13 +112,14 @@ def test_torch_imagenet_resnet50(tmp_path):
     assert os.path.exists(ckpt.format(epoch=1))
     # resume path: epoch 1 checkpoint found -> trains epoch 2 only
     out = _run("torch_imagenet_resnet50.py", "--epochs", "2",
-               "--steps-per-epoch", "2", "--batch-size", "2",
+               "--steps-per-epoch", "1", "--batch-size", "2",
                "--image-size", "32", "--num-classes", "10",
                "--width", "8", "--checkpoint-format", ckpt)
     assert "epoch 2/2" in out and "epoch 1/2" not in out
 
 
 @pytest.mark.slow
+@pytest.mark.time_limit(630)
 def test_keras_imagenet_resnet50(tmp_path):
     """ImageNet-scale keras example: warmup + staged-decay callbacks,
     metric averaging, fusion-threshold sweep knob."""
@@ -134,6 +139,7 @@ def test_keras_mnist_advanced():
 
 
 @pytest.mark.slow
+@pytest.mark.time_limit(630)
 def test_keras_spark_training():
     """End-to-end Spark workflow in fake-pyspark demo mode: driver
     dataset -> spark.run training -> driver-side scoring."""

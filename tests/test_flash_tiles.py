@@ -13,6 +13,8 @@ from horovod_tpu.parallel.flash_attention import (
     _BLOCK_K_LADDER, _BLOCK_Q_LADDER, _auto_block, _ladders_for,
 )
 
+from .compiled import out_and_vjp
+
 
 def _blocks_for(seq_q, seq_k, head_dim):
     ql, kl = _ladders_for(head_dim)
@@ -319,6 +321,24 @@ _KERNEL_CASES = [
 ]
 
 
+def _jitted_kernels(qb, kb, vb, dob, block_q, block_k, sub):
+    """The causal forward and the three gradients of one tile choice as
+    one program of the offsets, interpret mode: ``(o, dq, dk, dv)``."""
+    import jax
+    from horovod_tpu.parallel import flash_attention as fa
+
+    @jax.jit
+    def kernels(offs):
+        o, m, l = fa._flash_bhsd(qb, kb, vb, offs, True, block_q, block_k,
+                                 True, sub)
+        lse = fa._lse_from_stats(m[:, 0][None], l[:, 0][None])
+        delta = jax.numpy.sum(dob * o, axis=-1)[:, None, :]
+        return (o,) + fa._flash_bwd_bhsd(qb, kb, vb, dob, lse, delta, offs,
+                                         True, block_q, block_k, True, sub)
+
+    return kernels
+
+
 @pytest.mark.parametrize("seq_q,seq_k,block_q,block_k,sub,offsets",
                          [c[1:] for c in _KERNEL_CASES],
                          ids=[c[0] for c in _KERNEL_CASES])
@@ -333,22 +353,19 @@ def test_subtiled_kernels_match_dense(seq_q, seq_k, block_q, block_k, sub,
     q, k, v, do = _kernel_case(seq_q, seq_k, d, seed=seq_q + block_q)
     qb, kb, vb, dob = (fa._to_bhsd(x) for x in (q, k, v, do))
 
+    kernels = _jitted_kernels(qb, kb, vb, dob, block_q, block_k, sub)
+
     @jax.jit
-    def kernels(offs):
-        o, m, l = fa._flash_bhsd(qb, kb, vb, offs, True, block_q, block_k,
-                                 True, sub)
-        lse = fa._lse_from_stats(m[:, 0][None], l[:, 0][None])
-        delta = jnp.sum(dob * o, axis=-1)[:, None, :]
-        return (o,) + fa._flash_bwd_bhsd(qb, kb, vb, dob, lse, delta, offs,
-                                         True, block_q, block_k, True, sub)
+    def reference(offs):
+        ref, vjp = jax.vjp(
+            lambda q, k, v: fa._dense_reference(q, k, v, True, offs[0],
+                                                offs[1]), q, k, v)
+        return (ref,) + vjp(do)
 
     for q_off, k_off in offsets:
-        ref, vjp = jax.vjp(
-            lambda q, k, v: fa._dense_reference(q, k, v, True, q_off,
-                                                k_off), q, k, v)
-        got = kernels(jnp.asarray([q_off, k_off], jnp.int32))
-        for name, a, b in zip(("o", "dq", "dk", "dv"), got,
-                              (ref,) + vjp(do)):
+        offs = jnp.asarray([q_off, k_off], jnp.int32)
+        for name, a, b in zip(("o", "dq", "dk", "dv"), kernels(offs),
+                              reference(offs)):
             np.testing.assert_allclose(
                 np.asarray(fa._from_bhsd(a, 1, 1)), np.asarray(b),
                 atol=2e-5, err_msg=f"{name} at offsets {(q_off, k_off)}")
@@ -366,20 +383,15 @@ def test_skipped_subtiles_are_never_touched():
     q, k, v, do = _kernel_case(256, 512, 16, seed=5)
     poison = jnp.arange(512)[None, :, None, None] >= 256
     k_bad, v_bad = (jnp.where(poison, jnp.nan, x) for x in (k, v))
-    ref, vjp = jax.vjp(
+    ref, (rq, rk, rv) = out_and_vjp(
         lambda q, k, v: fa._dense_reference(q, k, v, True, 0, 0),
-        q, k[:, :256], v[:, :256])
+        do, q, k[:, :256], v[:, :256])
     qb, kb, vb, dob = (fa._to_bhsd(x) for x in (q, k_bad, v_bad, do))
-    offs = jnp.zeros((2,), jnp.int32)
-    o, m, l = fa._flash_bhsd(qb, kb, vb, offs, True, 256, 512, True,
-                             (128, 128))
+
+    o, dq, dk, dv = _jitted_kernels(qb, kb, vb, dob, 256, 512, (128, 128))(
+        jnp.zeros((2,), jnp.int32))
     np.testing.assert_allclose(np.asarray(fa._from_bhsd(o, 1, 1)),
                                np.asarray(ref), atol=2e-5)
-    lse = fa._lse_from_stats(m[:, 0][None], l[:, 0][None])
-    delta = jnp.sum(dob * o, axis=-1)[:, None, :]
-    dq, dk, dv = fa._flash_bwd_bhsd(qb, kb, vb, dob, lse, delta, offs,
-                                    True, 256, 512, True, (128, 128))
-    rq, rk, rv = vjp(do)
     np.testing.assert_allclose(np.asarray(fa._from_bhsd(dq, 1, 1)),
                                np.asarray(rq), atol=2e-5)
     for got, want in ((dk, rk), (dv, rv)):

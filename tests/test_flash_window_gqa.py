@@ -15,6 +15,7 @@ jnp = jax.numpy
 
 from horovod_tpu.parallel import flash_attention as fa  # noqa: E402
 
+from .compiled import out_and_vjp  # noqa: E402
 from .test_flash_tiles import _counted  # noqa: E402
 
 pytestmark = pytest.mark.fast
@@ -64,11 +65,11 @@ def test_kernels_match_dense(b, sq, sk, h, hkv, d, dv, window, bq, bk,
         return fa._dense_reference(q, k, v, causal, q_off, k_off, window)
 
     with jax.default_matmul_precision("highest"):
-        out, vjp = jax.vjp(flash, q, k, v)
-        want, vjp_want = jax.vjp(dense, q, k, v)
+        out, grads = out_and_vjp(flash, g, q, k, v)
+        want, grads_want = out_and_vjp(dense, g, q, k, v)
         assert out.shape == (b, sq, h, dv)
         np.testing.assert_allclose(out, want, atol=2e-5)
-        for got, ref, name in zip(vjp(g), vjp_want(g), "qkv"):
+        for got, ref, name in zip(grads, grads_want, "qkv"):
             assert got.shape == ref.shape
             np.testing.assert_allclose(got, ref, atol=5e-5,
                                        err_msg=f"d{name}")
@@ -233,16 +234,16 @@ def test_the_output_leaves_the_accumulator_unrounded_when_asked():
                                   block_k=32, interpret=True,
                                   out_dtype=out_dtype)
 
-    exact, vjp = jax.vjp(lambda *x: flash(*x, jnp.float32), q, k, v)
+    g = g.astype(jnp.float32)
+    exact, grads = out_and_vjp(lambda *x: flash(*x, jnp.float32), g, q, k, v)
     rounded = flash(q, k, v, None)
     assert exact.dtype == jnp.float32 and rounded.dtype == jnp.bfloat16
     np.testing.assert_array_equal(exact.astype(jnp.bfloat16), rounded)
     assert float(jnp.abs(exact - rounded.astype(jnp.float32)).max()) > 0
-    want, vjp_want = jax.vjp(
+    want, grads_want = out_and_vjp(
         lambda *x: fa._dense_reference(*x, True, 0, 0, 24, jnp.float32),
-        *(x.astype(jnp.float32) for x in (q, k, v)))
+        g, *(x.astype(jnp.float32) for x in (q, k, v)))
     np.testing.assert_allclose(exact, want, atol=2e-2)
-    for got, ref in zip(vjp(g.astype(jnp.float32)),
-                        vjp_want(g.astype(jnp.float32))):
+    for got, ref in zip(grads, grads_want):
         assert got.dtype == jnp.bfloat16
         np.testing.assert_allclose(got.astype(jnp.float32), ref, atol=0.15)

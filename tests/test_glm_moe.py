@@ -69,16 +69,17 @@ def part(name, model, params, x):
     cfg = model.cfg
     moe = params["block_1"]
     if name == "mla":
-        return (glm_moe.LatentAttention(cfg).apply(
-            {"params": moe["attn"]}, x, positions(x)),
-            REF["mla"](moe["attn"], x))
+        return (jax.jit(lambda p, x: glm_moe.LatentAttention(cfg).apply(
+            {"params": p}, x, positions(x)))(moe["attn"], x),
+            jax.jit(REF["mla"])(moe["attn"], x))
     if name == "expert_layer":
-        return (glm_moe.ExpertLayer(cfg).apply({"params": moe["moe"]}, x)[0],
-                REF["expert_layer"](moe["moe"], x))
+        return (jax.jit(lambda p, x: glm_moe.ExpertLayer(cfg).apply(
+            {"params": p}, x)[0])(moe["moe"], x),
+            jax.jit(REF["expert_layer"])(moe["moe"], x))
     dense = name == "dense_block"
     p = params["block_0"] if dense else moe
-    return (glm_moe.Block(cfg, use_moe=not dense).apply(
-        {"params": p}, x, positions(x))[0], REF["block"](p, x))
+    return (jax.jit(lambda p, x: glm_moe.Block(cfg, use_moe=not dense).apply(
+        {"params": p}, x, positions(x))[0])(p, x), jax.jit(REF["block"])(p, x))
 
 
 @pytest.mark.parametrize("name", ["mla", "expert_layer", "dense_block",
@@ -139,14 +140,14 @@ def test_the_shares_add_up_to_the_uncut_layer(model, x):
     shapes, fans = FAMILY.param_shapes(whole)
     p = weights.make_tree(shapes, fans, seed=13, stream=0)[
         "params"]["block_1"]["moe"]
-    want = FAMILY.reference_fns(whole)["expert_layer"](p, x)
-    shared = FAMILY._swiglu(p["shared"], x)
+    want = jax.jit(FAMILY.reference_fns(whole)["expert_layer"])(p, x)
+    shared = jax.jit(FAMILY._swiglu)(p["shared"], x)
     total = shared
     for offset in range(0, e, held):
         mine = dict(p, experts={
             k: v[offset:offset + held] for k, v in p["experts"].items()})
-        y, counts = glm_moe.ExpertLayer(
-            float32(model.cfg, expert_offset=offset)).apply(
+        y, counts = jax.jit(glm_moe.ExpertLayer(
+            float32(model.cfg, expert_offset=offset)).apply)(
                 {"params": mine}, x)
         total = total + (y - shared)
         assert counts[glm_moe.DROPPED] == 0
@@ -168,19 +169,20 @@ def test_routing_drops_nothing_whatever_the_load(
     bias[list(chosen)] = 10.0
     p["router"] = dict(p["router"], bias=jnp.asarray(bias))
     layer = glm_moe.ExpertLayer(model.cfg)
-    y, counts = layer.apply({"params": p}, x)
+    reference = jax.jit(REF["expert_layer"])
+    y, counts = jax.jit(layer.apply)({"params": p}, x)
     n = x.shape[0] * x.shape[1]
     assert counts[glm_moe.DROPPED] == 0
     assert counts[:SZ["experts_held"]].sum() == held_each * n
     assert counts[glm_moe.ABSENT] == (SZ["top_k"] - held_each) * n
-    np.testing.assert_allclose(y, REF["expert_layer"](p, x), **TOL)
+    np.testing.assert_allclose(y, reference(p, x), **TOL)
     if not held_each:
         np.testing.assert_allclose(
-            y, FAMILY._swiglu(p["shared"], x), **TOL)
-    grads = jax.grad(lambda q: jnp.sum(jnp.square(
-        layer.apply({"params": q}, x)[0])))(p)
-    want = jax.grad(lambda q: jnp.sum(jnp.square(
-        REF["expert_layer"](q, x))))(p)
+            y, jax.jit(FAMILY._swiglu)(p["shared"], x), **TOL)
+    grads = jax.jit(jax.grad(lambda q: jnp.sum(jnp.square(
+        layer.apply({"params": q}, x)[0]))))(p)
+    want = jax.jit(jax.grad(lambda q: jnp.sum(jnp.square(
+        REF["expert_layer"](q, x)))))(p)
     for got, ref in zip(jax.tree_util.tree_leaves(grads),
                         jax.tree_util.tree_leaves(want)):
         assert np.isfinite(got).all()

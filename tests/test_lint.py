@@ -18,6 +18,7 @@ Two tiers in one module, both fast/in-process (pytest.mark.lint):
   condition-variable transparency, metrics mirror.
 """
 
+import contextlib
 import glob
 import json
 import os
@@ -29,7 +30,7 @@ import threading
 
 import pytest
 
-from tools.hvdlint import lint_paths
+from tools.hvdlint import Finding, core, lint_paths
 from tools.hvdlint.core import Project
 
 pytestmark = pytest.mark.lint
@@ -50,21 +51,72 @@ def _lint_snippet(tmp_path, code: str, analyzer: str, name="mod.py",
     return lint_paths([str(pkg)], [analyzer])
 
 
+# -- the tree is read once --------------------------------------------------
+
+@pytest.fixture(scope="module", autouse=True)
+def parse_once():
+    """Every ``Project`` of this module parses a file's text once, so a
+    mutation test parses again only the file it mutated. The index and
+    the analyzers are cross-module and still run over the whole tree."""
+    parsed = {}
+    real = core.SourceFile
+
+    def source_file(path, modname, text):
+        key = (path, modname, text)
+        if key not in parsed:
+            parsed[key] = real(path, modname, text)
+        return parsed[key]
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(core, "SourceFile", source_file)
+        yield
+
+
+@pytest.fixture(scope="module")
+def tree_report():
+    """Every analyzer over the real package, once, through the CLI."""
+    return subprocess.run(
+        [sys.executable, "-m", "tools.hvdlint", "horovod_tpu", "--json"],
+        cwd=REPO, capture_output=True, text=True, timeout=120)
+
+
+def _tree_findings(tree_report, analyzer=None):
+    return [Finding(**d) for d in json.loads(tree_report.stdout)["findings"]
+            if analyzer in (None, d["analyzer"])]
+
+
+@pytest.fixture(scope="module")
+def tree_project():
+    return Project([os.path.join(REPO, "horovod_tpu")])
+
+
+@contextlib.contextmanager
+def _undecorated(project, *qualnames):
+    """``project`` with the decorators of ``qualnames`` stripped."""
+    infos = [project.index.functions[qn] for qn in qualnames]
+    kept = [info.decorators for info in infos]
+    for info in infos:
+        info.decorators = set()
+    try:
+        yield
+    finally:
+        for info, decorators in zip(infos, kept):
+            info.decorators = decorators
+
+
 # -- the project gate -------------------------------------------------------
 
-def test_tree_is_clean():
+def test_tree_is_clean(tree_report):
     """Every analyzer over the real package: zero findings. A finding
     here means either a real new bug (fix it) or an intentional
     pattern (suppress WITH a justification, or extend the analyzer's
     allowlist — both reviewed changes)."""
-    findings = lint_paths([os.path.join(REPO, "horovod_tpu")])
+    findings = _tree_findings(tree_report)
     assert findings == [], "\n".join(f.render() for f in findings)
 
 
-def test_cli_json_exit_codes(tmp_path):
-    out = subprocess.run(
-        [sys.executable, "-m", "tools.hvdlint", "horovod_tpu", "--json"],
-        cwd=REPO, capture_output=True, text=True, timeout=120)
+def test_cli_json_exit_codes(tmp_path, tree_report):
+    out = tree_report
     assert out.returncode == 0, out.stdout + out.stderr
     payload = json.loads(out.stdout)
     assert payload["count"] == 0 and payload["findings"] == []
@@ -80,8 +132,7 @@ def test_cli_json_exit_codes(tmp_path):
     assert payload["findings"][0]["analyzer"] == "knobs"
 
     out = subprocess.run(
-        [sys.executable, "-m", "tools.hvdlint", "-a", "no-such",
-         "horovod_tpu"],
+        [sys.executable, "-m", "tools.hvdlint", "-a", "no-such", str(bad)],
         cwd=REPO, capture_output=True, text=True, timeout=120)
     assert out.returncode == 2
 
@@ -592,7 +643,7 @@ def test_native_codec_tag_distinctness(tmp_path):
     assert "does not fit the u8 tag byte" in msgs
 
 
-def test_native_codec_real_tree_mirror():
+def test_native_codec_real_tree_mirror(tree_report):
     """The REAL loader must mirror the REAL header exactly — this is
     the check that catches a future C signature change whose author
     forgot the ctypes side."""
@@ -609,8 +660,7 @@ def test_native_codec_real_tree_mirror():
                "hvd_relay_frame", "hvd_quant8", "hvd_dequant8",
                "hvd_build_flags"):
         assert fn in decls, fn
-    fs = lint_paths([os.path.join(REPO, "horovod_tpu")],
-                    ["native-codec"])
+    fs = _tree_findings(tree_report, "native-codec")
     assert fs == [], "\n".join(f.render() for f in fs)
 
 
@@ -727,16 +777,15 @@ def test_world_coherence_annotated_is_clean(tmp_path):
                          "world-coherence") == []
 
 
-def test_world_coherence_decorator_is_load_bearing():
+def test_world_coherence_decorator_is_load_bearing(tree_project):
     """Stripping @world_coherent from the runtime's verdict applier
     must fail the real tree — the annotation is what the analyzer
     anchors trust to, not a comment."""
     from tools.hvdlint import world_coherence
-    p = Project([os.path.join(REPO, "horovod_tpu")])
-    info = p.index.functions[
-        "horovod_tpu.common.runtime.Runtime._apply_cached_cycle"]
-    info.decorators = set()
-    fs = world_coherence.run(p)
+    p = tree_project
+    with _undecorated(
+            p, "horovod_tpu.common.runtime.Runtime._apply_cached_cycle"):
+        fs = world_coherence.run(p)
     assert any("world-replicated" in f.message for f in fs), fs
 
 
@@ -772,24 +821,22 @@ def test_world_coherence_fires_on_local_elastic_mutation(tmp_path):
     assert "world-replicated" in msgs and "Membership.install" in msgs, fs
 
 
-def test_world_coherence_real_elastic_membership_is_anchored():
+def test_world_coherence_real_elastic_membership_is_anchored(tree_project):
     """The REAL elastic Membership.install must carry the
     @world_coherent anchor — stripping it fails the tree, proving the
     rank table / generation / blacklist can only move behind
     broadcast-identical inputs."""
     from tools.hvdlint import world_coherence
-    p = Project([os.path.join(REPO, "horovod_tpu")])
+    p = tree_project
     qn = "horovod_tpu.common.elastic.Membership.install"
     assert qn in p.index.functions, sorted(
         k for k in p.index.functions if "elastic" in k)[:20]
-    info = p.index.functions[qn]
-    info.decorators = set()
     # apply_membership is covered only through its own decorator;
     # strip that too so coverage cannot flow around the mutator.
-    p.index.functions[
-        "horovod_tpu.common.elastic.ElasticContext.apply_membership"
-    ].decorators = set()
-    fs = world_coherence.run(p)
+    with _undecorated(
+            p, qn,
+            "horovod_tpu.common.elastic.ElasticContext.apply_membership"):
+        fs = world_coherence.run(p)
     assert any("Membership" in f.message
                and "world-replicated" in f.message for f in fs), fs
 
@@ -818,22 +865,21 @@ def test_world_coherence_fires_on_local_overlap_mutation(tmp_path):
         and "requeue_priority" in msgs, fs
 
 
-def test_world_coherence_real_overlap_inflight_is_anchored():
+def test_world_coherence_real_overlap_inflight_is_anchored(tree_project):
     """The REAL overlap submit path must carry the @world_coherent
     anchor — stripping it (and the drain-side mutators coverage could
     flow through) fails the tree, proving the in-flight cycle
     sequence only ever moves in the world-identical program order."""
     from tools.hvdlint import world_coherence
-    p = Project([os.path.join(REPO, "horovod_tpu")])
+    p = tree_project
     qn = "horovod_tpu.common.runtime.Runtime._submit_overlap_cycle"
     assert qn in p.index.functions, sorted(
         k for k in p.index.functions if "overlap" in k)[:20]
-    for fn in ("_submit_overlap_cycle", "_apply_overlap_verdict",
-               "_unwind_cancelled_cycle", "_drop_inflight_mask"):
-        p.index.functions[
+    with _undecorated(p, *(
             f"horovod_tpu.common.runtime.Runtime.{fn}"
-        ].decorators = set()
-    fs = world_coherence.run(p)
+            for fn in ("_submit_overlap_cycle", "_apply_overlap_verdict",
+                       "_unwind_cancelled_cycle", "_drop_inflight_mask"))):
+        fs = world_coherence.run(p)
     assert any("_inflight_masks" in f.message
                and "world-replicated" in f.message for f in fs), fs
 
@@ -868,22 +914,20 @@ def test_world_coherence_fires_on_local_tenant_descriptor(tmp_path):
     assert "world-replicated" in msgs and "Tenant.apply" in msgs, fs
 
 
-def test_world_coherence_real_tenant_descriptor_is_anchored():
+def test_world_coherence_real_tenant_descriptor_is_anchored(tree_project):
     """The REAL tenant descriptor install must carry the
     @world_coherent anchor — stripping it (and the module-level
     installer coverage could flow through) fails the tree, proving
     tenant scheduling state only ever moves on the coordinator's
     handshake broadcast."""
     from tools.hvdlint import world_coherence
-    p = Project([os.path.join(REPO, "horovod_tpu")])
+    p = tree_project
     qn = "horovod_tpu.common.tenancy.Tenant._apply_descriptor"
     assert qn in p.index.functions, sorted(
         k for k in p.index.functions if "tenancy" in k)[:20]
-    p.index.functions[qn].decorators = set()
-    p.index.functions[
-        "horovod_tpu.common.tenancy._install_descriptor"
-    ].decorators = set()
-    fs = world_coherence.run(p)
+    with _undecorated(
+            p, qn, "horovod_tpu.common.tenancy._install_descriptor"):
+        fs = world_coherence.run(p)
     assert any("_desc" in f.message
                and "world-replicated" in f.message for f in fs), fs
 
@@ -921,18 +965,18 @@ def test_world_coherence_fires_on_local_selfop_verdict(tmp_path):
         and "SupervisionVerdict.install" in msgs, fs
 
 
-def test_world_coherence_real_selfop_verdict_is_anchored():
+def test_world_coherence_real_selfop_verdict_is_anchored(tree_project):
     """The REAL SupervisionVerdict.install must carry the
     @world_coherent anchor — stripping it fails the tree, proving the
     demotion/pacing descriptor only ever moves on inputs every member
     received in the same resize verdict."""
     from tools.hvdlint import world_coherence
-    p = Project([os.path.join(REPO, "horovod_tpu")])
+    p = tree_project
     qn = "horovod_tpu.common.selfop.SupervisionVerdict.install"
     assert qn in p.index.functions, sorted(
         k for k in p.index.functions if "selfop" in k)[:20]
-    p.index.functions[qn].decorators = set()
-    fs = world_coherence.run(p)
+    with _undecorated(p, qn):
+        fs = world_coherence.run(p)
     assert any("SupervisionVerdict" in f.message
                and "world-replicated" in f.message for f in fs), fs
 

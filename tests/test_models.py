@@ -1,5 +1,7 @@
 """Model zoo shape/grad sanity (fp32 on CPU devices)."""
 
+from functools import partial
+
 import numpy as np
 import pytest
 
@@ -15,19 +17,21 @@ from horovod_tpu.models.transformer import causal_attention, lm_loss
 def test_mnist_convnet_forward():
     model = MnistConvNet()
     x = jnp.zeros((2, 28, 28, 1))
-    params = model.init(jax.random.key(0), x)
-    out = model.apply(params, x)
+    params = jax.jit(model.init)(jax.random.key(0), x)
+    out = jax.jit(model.apply)(params, x)
     assert out.shape == (2, 10)
 
 
 def test_resnet18_forward_train_eval():
     model = ResNet18(num_classes=10, dtype=jnp.float32)
     x = jnp.zeros((2, 32, 32, 3))
-    variables = model.init(jax.random.key(0), x, train=False)
-    out = model.apply(variables, x, train=False)
+    # each under one jit: op by op the two are hundreds of compilations
+    variables = jax.jit(partial(model.init, train=False))(
+        jax.random.key(0), x)
+    out = jax.jit(partial(model.apply, train=False))(variables, x)
     assert out.shape == (2, 10)
-    out, updates = model.apply(variables, x, train=True,
-                               mutable=["batch_stats"])
+    out, updates = jax.jit(partial(
+        model.apply, train=True, mutable=["batch_stats"]))(variables, x)
     assert out.shape == (2, 10)
     assert "batch_stats" in updates
 
@@ -37,15 +41,15 @@ def test_transformer_forward_and_loss_grad():
                             head_dim=8, max_seq_len=16, dtype=jnp.float32)
     model = TransformerLM(cfg)
     tokens = jnp.zeros((2, 16), jnp.int32)
-    params = model.init(jax.random.key(0), tokens)
-    logits = model.apply(params, tokens)
+    params = jax.jit(model.init)(jax.random.key(0), tokens)
+    logits = jax.jit(model.apply)(params, tokens)
     assert logits.shape == (2, 16, 128)
     assert logits.dtype == jnp.float32
 
     def loss(p):
         return lm_loss(model.apply(p, tokens), tokens)
 
-    g = jax.grad(loss)(params)
+    g = jax.jit(jax.grad(loss))(params)
     flat = jax.tree_util.tree_leaves(g)
     assert all(np.isfinite(np.asarray(t)).all() for t in flat)
 

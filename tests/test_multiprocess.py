@@ -137,6 +137,7 @@ def test_wide_world_smoke():
     run_scenario("allreduce_fused", 12, timeout=180.0)
 
 
+@pytest.mark.time_limit(630)
 def test_wide_world_hier_smoke():
     """16 ranks as 4 fake hosts x 4: the deepest hierarchy the suite
     runs — 3 local leaves + 3 aggregate root channels at the
@@ -236,6 +237,7 @@ def test_hier_controller_uneven_hosts():
             "HOROVOD_HOSTNAME": f"fakehost{min(rank // 2, 1)}"})
 
 
+@pytest.mark.time_limit(270)
 def test_hier_controller_three_hosts():
     """6 ranks on 3 fake hosts (2 each): multiple aggregate channels
     at the coordinator simultaneously."""
@@ -343,6 +345,7 @@ def test_torch_gather_bcast_grad():
     run_scenario("torch_gather_bcast_grad", 3, timeout=180.0)
 
 
+@pytest.mark.time_limit(270)
 def test_tfkeras_facade():
     run_scenario("tfkeras_facade", 2, timeout=240.0)
 
@@ -392,6 +395,7 @@ def test_grouped_allreduce_atomic():
     run_scenario("grouped_atomic", 2, timeout=180.0)
 
 
+@pytest.mark.time_limit(330)
 @pytest.mark.parametrize("plane,ranks", [
     ("shm", 3), ("socket", 3), ("shm", 6)])
 def test_coordinator_fuzz(plane, ranks):
@@ -488,6 +492,7 @@ def test_response_cache_heterogeneous_speculation_knob():
             {"HOROVOD_CACHE_SPECULATIVE": "0"} if rank == 1 else {}))
 
 
+@pytest.mark.time_limit(330)
 def test_kitchen_sink_all_subsystems(tmp_path):
     """Cross-subsystem integration: autotune (+log), timeline (+cycle
     marks), hierarchical shm over a fake 2-host topology, and the stall
@@ -548,7 +553,7 @@ def test_secret_mismatch_fails_init_loudly():
     base = _base_env({"HOROVOD_CONTROLLER_ADDR": "127.0.0.1",
                       "HOROVOD_CONTROLLER_PORT": str(port),
                       "HOROVOD_SIZE": "2",
-                      "HOROVOD_START_TIMEOUT": "6"})
+                      "HOROVOD_START_TIMEOUT": "3"})
     code = "import horovod_tpu as hvd; hvd.init()"
     procs = []
     for rank in range(2):
@@ -743,7 +748,7 @@ def test_abort_sever_mid_native_steady():
 
 
 def test_abort_heartbeat_detects_silent_hang():
-    """Wedge rank 1's background loop for 10 s WITHOUT killing it (no
+    """Wedge rank 1's background loop for 6 s WITHOUT killing it (no
     FIN/RST ever reaches the peers — the case TCP error detection
     cannot see): survivors must abort within the 3 s heartbeat
     deadline plus slack, naming rank 1, proving detection is bounded
@@ -752,7 +757,7 @@ def test_abort_heartbeat_detects_silent_hang():
         "abort_heartbeat_hang", 3, timeout=60.0,
         extra_env={**_HB_ENV,
                    "HOROVOD_FAULT_SPEC":
-                       "rank=1:hang:cycle=20:seconds=10"})
+                       "rank=1:hang:cycle=20:seconds=6"})
 
 
 def test_abort_severed_control_link():
@@ -779,6 +784,7 @@ def test_abort_sigkill_ring_data_plane():
         expect_rc={1: _SIGKILL_RC})
 
 
+@pytest.mark.time_limit(270)
 def test_ring_data_plane_with_hier_controller():
     """Large payloads on the TCP ring while the CONTROL plane is
     hierarchical: ring rendezvous (listener ports via relayed
@@ -1113,6 +1119,7 @@ def test_rank_subset_init():
     run_scenario("subset_world", 3, timeout=120.0)
 
 
+@pytest.mark.time_limit(270)
 def test_subset_world_hierarchical():
     """A rank-subset sub-world spanning two multi-rank fake hosts
     activates the hierarchical control plane inside the subset."""
@@ -1159,6 +1166,7 @@ def test_tenants_sigkill_isolated_to_one_tenant():
                  expect_rc={1: _SIGKILL_RC})
 
 
+@pytest.mark.time_limit(270)
 def test_tenants_service_attach_snapshot_detach():
     """hvdtpurun --service semantics end to end: a 2-rank warm fleet
     serves a 2-replica job that attaches, pulls a parameter snapshot
@@ -1187,6 +1195,7 @@ def test_xla_mesh_backend():
     run_scenario("xla_backend", 2, timeout=180.0)
 
 
+@pytest.mark.time_limit(270)
 def test_xla_mesh_backend_tree_broadcast():
     """HOROVOD_XLA_BCAST=tree: the binary-tree ppermute broadcast
     rendering delivers every root's values (3 ranks exercises the
@@ -1195,6 +1204,7 @@ def test_xla_mesh_backend_tree_broadcast():
                  extra_env={"HOROVOD_XLA_BCAST": "tree"})
 
 
+@pytest.mark.time_limit(270)
 def test_xla_async_overlap_end_to_end(tmp_path):
     """Negotiation/execution overlap proven END-TO-END: a deliberately
     slow big XLA collective stays in flight while later cycles
@@ -1207,6 +1217,7 @@ def test_xla_async_overlap_end_to_end(tmp_path):
             if rank == 0 else {}))
 
 
+@pytest.mark.time_limit(330)
 def test_xla_ragged_allgather_skew_guard():
     """1 big / 4 tiny ranks: the fused allgather switches to the
     masked-psum (allgatherv-shaped) rendering; uniform shapes keep the
@@ -1219,6 +1230,7 @@ def test_xla_hierarchical_allreduce():
                  extra_env={"HOROVOD_HIERARCHICAL_ALLREDUCE": "1"})
 
 
+@pytest.mark.time_limit(270)
 def test_xla_hierarchical_allreduce_multihost():
     """Forced 2-host topology (4 ranks): hierarchical allreduce must
     compile and run the factored (cross, local) psum with values
@@ -1230,6 +1242,7 @@ def test_xla_hierarchical_allreduce_multihost():
             "HOROVOD_HOSTNAME": f"fakehost{rank // 2}"})
 
 
+@pytest.mark.time_limit(270)
 def test_xla_hierarchical_allgather():
     """Forced 2-host topology (2 ranks per fake host): the
     HOROVOD_HIERARCHICAL_ALLGATHER knob must route allgather through
@@ -1241,6 +1254,7 @@ def test_xla_hierarchical_allgather():
             "HOROVOD_HOSTNAME": f"fakehost{rank // 2}"})
 
 
+@pytest.mark.time_limit(330)
 def test_coordinator_fuzz_through_hier_controller():
     """The 240-job mixed-collective fuzz with every rank's requests
     riding aggregated frames (3 ranks, 2 fake hosts): randomized

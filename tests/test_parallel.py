@@ -1,6 +1,8 @@
 """Parallelism extensions: ring attention exactness, TP sharding rules,
 and the composed dp x tp (x sp) Trainer on the 8-device CPU mesh."""
 
+from functools import partial
+
 import numpy as np
 import pytest
 
@@ -60,7 +62,7 @@ def test_tp_rules_match_expected_paths():
                             head_dim=4, dtype=jnp.float32)
     model = TransformerLM(cfg)
     tokens = jnp.zeros((1, 8), jnp.int32)
-    params = model.init(jax.random.key(0), tokens)
+    params = jax.jit(model.init)(jax.random.key(0), tokens)
     mesh = spmd.create_mesh({"data": 4, "model": 2})
     shardings = infer_sharding(params, transformer_tp_rules("model"), mesh)
     flat = {"/".join(str(getattr(k, "key", k)) for k in path): s
@@ -212,11 +214,11 @@ def test_ring_attention_flash_path_matches_dense():
     np.testing.assert_allclose(np.asarray(f(q, k, v)),
                                np.asarray(causal_attention(q, k, v)),
                                atol=2e-5)
-    g1 = jax.grad(lambda q, k, v: (f(q, k, v) ** 2).sum(),
-                  argnums=(0, 1, 2))(q, k, v)
-    g2 = jax.grad(
+    g1 = jax.jit(jax.grad(lambda q, k, v: (f(q, k, v) ** 2).sum(),
+                          argnums=(0, 1, 2)))(q, k, v)
+    g2 = jax.jit(jax.grad(
         lambda q, k, v: (causal_attention(q, k, v) ** 2).sum(),
-        argnums=(0, 1, 2))(q, k, v)
+        argnums=(0, 1, 2)))(q, k, v)
     for a, b_ in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
                                    atol=1e-4)
@@ -240,8 +242,8 @@ def test_flash_attention_grad_matches_dense():
     def loss_dense(q, k, v):
         return (causal_attention(q, k, v) ** 2).sum()
 
-    g1 = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-    g2 = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
+    g1 = jax.jit(jax.grad(loss_flash, argnums=(0, 1, 2)))(q, k, v)
+    g2 = jax.jit(jax.grad(loss_dense, argnums=(0, 1, 2)))(q, k, v)
     for a, b_ in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
                                    atol=1e-4)
@@ -260,32 +262,33 @@ def test_flash_attention_grad_noncausal_and_offsets():
         probs = jax.nn.softmax(logits, axis=-1)
         return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
+    def grads(attention):
+        """Of the summed square of ``attention(q, k, v)``, one program."""
+        return jax.jit(jax.grad(lambda *a: (attention(*a) ** 2).sum(),
+                                argnums=(0, 1, 2)))(q, k, v)
+
     # non-causal
-    g1 = jax.grad(lambda *a: (flash_attention(
-        *a, causal=False, block_q=32, block_k=32,
-        interpret=True) ** 2).sum(), argnums=(0, 1, 2))(q, k, v)
-    g2 = jax.grad(lambda *a: (dense_nc(*a) ** 2).sum(),
-                  argnums=(0, 1, 2))(q, k, v)
+    g1 = grads(lambda *a: flash_attention(
+        *a, causal=False, block_q=32, block_k=32, interpret=True))
+    g2 = grads(dense_nc)
     for a, b_ in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
                                    atol=1e-4)
 
     # causal with a fully-past kv block (ring step shape): same as
     # non-causal dense
-    g1 = jax.grad(lambda *a: (flash_attention(
+    g1 = grads(lambda *a: flash_attention(
         *a, causal=True, q_offset=64, k_offset=0, block_q=32,
-        block_k=32, interpret=True) ** 2).sum(),
-        argnums=(0, 1, 2))(q, k, v)
+        block_k=32, interpret=True))
     for a, b_ in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
                                    atol=1e-4)
 
     # fully-future kv block: zero output -> zero grads, no NaN from
     # dead rows (l == 0)
-    g1 = jax.grad(lambda *a: (flash_attention(
+    g1 = grads(lambda *a: flash_attention(
         *a, causal=True, q_offset=0, k_offset=64, block_q=32,
-        block_k=32, interpret=True) ** 2).sum(),
-        argnums=(0, 1, 2))(q, k, v)
+        block_k=32, interpret=True))
     for a in g1:
         assert np.all(np.isfinite(np.asarray(a)))
         np.testing.assert_allclose(np.asarray(a), 0.0, atol=1e-6)
@@ -427,8 +430,8 @@ def test_moe_matches_per_token_reference():
     layer = MoEMLP(cfg)
     x = jax.random.normal(jax.random.key(0), (2, 8, cfg.embed_dim),
                           jnp.float32)
-    variables = layer.init(jax.random.key(1), x)
-    y = layer.apply(variables, x)
+    variables = jax.jit(layer.init)(jax.random.key(1), x)
+    y = jax.jit(layer.apply)(variables, x)
 
     p = variables["params"]
     wr = np.asarray(p["router"]["kernel"], np.float64)
@@ -461,8 +464,8 @@ def test_moe_capacity_drops_overflow_tokens():
     layer = MoEMLP(cfg)
     x = jnp.tile(jax.random.normal(jax.random.key(0),
                                    (1, 1, cfg.embed_dim)), (1, 4, 1))
-    variables = layer.init(jax.random.key(1), x)
-    y = np.asarray(layer.apply(variables, x))[0]
+    variables = jax.jit(layer.init)(jax.random.key(1), x)
+    y = np.asarray(jax.jit(layer.apply)(variables, x))[0]
     # identical tokens -> same expert; capacity 1 keeps only token 0
     assert np.any(y[0] != 0.0)
     np.testing.assert_allclose(y[1:], 0.0)
@@ -510,9 +513,9 @@ def test_moe_aux_loss_sowed():
                             num_experts=2, moe_every=2)
     model = TransformerLM(cfg)
     tokens = jnp.zeros((2, 8), jnp.int32)
-    variables = model.init(jax.random.key(0), tokens)
-    _, inter = model.apply(variables, tokens,
-                           mutable=["intermediates"])
+    variables = jax.jit(model.init)(jax.random.key(0), tokens)
+    _, inter = jax.jit(partial(
+        model.apply, mutable=["intermediates"]))(variables, tokens)
     aux = moe_aux_loss(inter["intermediates"])
     # perfectly balanced routing gives aux == 1.0; anything routed
     # gives a finite positive value >= 1 for top-1 switch gating
@@ -614,8 +617,8 @@ def test_pipeline_gradients_match_sequential():
     def seq_loss(p):
         return jnp.mean(_pp_sequential(p, x) ** 2)
 
-    gp = jax.grad(pipe_loss)(stacked)
-    gs = jax.grad(seq_loss)(stacked)
+    gp = jax.jit(jax.grad(pipe_loss))(stacked)
+    gs = jax.jit(jax.grad(seq_loss))(stacked)
     np.testing.assert_allclose(np.asarray(gp["w"]), np.asarray(gs["w"]),
                                atol=1e-5)
     np.testing.assert_allclose(np.asarray(gp["b"]), np.asarray(gs["b"]),
@@ -635,8 +638,9 @@ def test_pipeline_transformer_blocks():
                     jnp.float32)
     positions = jnp.broadcast_to(jnp.arange(16, dtype=jnp.int32)[None],
                                  (4, 16))
-    p0 = block.init(jax.random.key(0), x, positions)["params"]
-    p1 = block.init(jax.random.key(1), x, positions)["params"]
+    init = jax.jit(block.init)
+    p0 = init(jax.random.key(0), x, positions)["params"]
+    p1 = init(jax.random.key(1), x, positions)["params"]
     stacked = jax.tree_util.tree_map(
         lambda a, b: jnp.stack([a, b]), p0, p1)
 
@@ -648,7 +652,7 @@ def test_pipeline_transformer_blocks():
 
     run = make_pipeline_apply(mesh, block_fn, num_microbatches=2)
     out = run(stacked, x)
-    ref = block_fn(p1, block_fn(p0, x))
+    ref = jax.jit(lambda h: block_fn(p1, block_fn(p0, h)))(x)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5)
 
@@ -689,8 +693,8 @@ def test_moe_top2_matches_per_token_reference():
     layer = MoEMLP(cfg)
     x = jax.random.normal(jax.random.key(2), (2, 8, cfg.embed_dim),
                           jnp.float32)
-    variables = layer.init(jax.random.key(3), x)
-    y = layer.apply(variables, x)
+    variables = jax.jit(layer.init)(jax.random.key(3), x)
+    y = jax.jit(layer.apply)(variables, x)
 
     p = variables["params"]
     wr = np.asarray(p["router"]["kernel"], np.float64)
@@ -732,7 +736,7 @@ def test_pipelined_lm_matches_sequential_logits():
 
     plm = PipelinedLM(cfg, mesh, num_microbatches=2)
     params = plm.from_transformer_params(variables)
-    logits = plm.apply(params, tokens)
+    logits = jax.jit(plm.apply)(params, tokens)
     np.testing.assert_allclose(np.asarray(logits),
                                np.asarray(ref_logits), atol=2e-4)
 
@@ -752,13 +756,13 @@ def test_pipelined_lm_trains_with_dp():
         np.tile(np.arange(16, dtype=np.int32)[None], (8, 1)))
 
     plm = PipelinedLM(cfg, mesh, num_microbatches=2, data_axis="data")
-    params = plm.init(jax.random.key(0), tokens)
+    params = jax.jit(plm.init)(jax.random.key(0), tokens)
 
     @jax.jit
     def loss_fn(p):
         return lm_loss(plm.apply(p, tokens), tokens)
 
-    grad = jax.grad(loss_fn)
+    grad = jax.jit(jax.grad(loss_fn))
     losses = [float(loss_fn(params))]
     for _ in range(6):
         params = jax.tree_util.tree_map(
@@ -800,10 +804,10 @@ def test_ulysses_matches_reference():
     v = jnp.asarray(rng.randn(b, s, h, d), jnp.float32)
     attn = make_ulysses_attention(mesh, data_axis="data",
                                   seq_axis="seq")
-    out = attn(q, k, v, True)
-    np.testing.assert_allclose(np.asarray(out),
-                               np.asarray(causal_attention(q, k, v)),
-                               atol=2e-5)
+    out = jax.jit(partial(attn, causal=True))(q, k, v)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(jax.jit(causal_attention)(q, k, v)),
+        atol=2e-5)
 
 
 def test_ulysses_trainer_matches_dense_loss():
@@ -853,14 +857,16 @@ def test_seq_parallel_attention_respects_causal_flag():
     q = jnp.asarray(rng.randn(b, s, h, d), jnp.float32)
     k = jnp.asarray(rng.randn(b, s, h, d), jnp.float32)
     v = jnp.asarray(rng.randn(b, s, h, d), jnp.float32)
-    ref = causal_attention(q, k, v, causal=False)
+    ref = jax.jit(partial(causal_attention, causal=False))(q, k, v)
     uly = make_ulysses_attention(mesh, data_axis="data", seq_axis="seq")
-    np.testing.assert_allclose(np.asarray(uly(q, k, v, False)),
-                               np.asarray(ref), atol=2e-5)
+    np.testing.assert_allclose(
+        np.asarray(jax.jit(partial(uly, causal=False))(q, k, v)),
+        np.asarray(ref), atol=2e-5)
     ring = make_ring_attention(mesh, data_axis="data", seq_axis="seq",
                                model_axis=None)
-    np.testing.assert_allclose(np.asarray(ring(q, k, v, False)),
-                               np.asarray(ref), atol=2e-5)
+    np.testing.assert_allclose(
+        np.asarray(jax.jit(partial(ring, causal=False))(q, k, v)),
+        np.asarray(ref), atol=2e-5)
 
 
 # ---------------------------------------------------------------------------
@@ -974,8 +980,8 @@ def test_chunked_lm_loss_matches_default():
     def l_chunked(p):
         return chunked(model.apply, p, {"tokens": tokens})
 
-    v0, g0 = jax.value_and_grad(l_default)(params)
-    v1, g1 = jax.value_and_grad(l_chunked)(params)
+    v0, g0 = jax.jit(jax.value_and_grad(l_default))(params)
+    v1, g1 = jax.jit(jax.value_and_grad(l_chunked))(params)
     np.testing.assert_allclose(float(v0), float(v1), rtol=1e-5)
     jax.tree_util.tree_map(
         lambda a, b: np.testing.assert_allclose(

@@ -92,6 +92,7 @@ def test_each_kept_layer_is_the_kind_its_published_index_says():
     assert abs(phi4flash.lambda_init(0) - 0.2) < 1e-12
 
 
+@jax.jit
 def carried(params, x):
     """The reference's memory and key-value pair for ``x`` entering
     layers 16 and 17."""
@@ -107,8 +108,9 @@ def test_a_block_of_the_program_is_the_references(index, model, params, x):
            16: (x, None, None, None), 17: (x, memory, None, None),
            18: (x17, memory, k, v), 19: (x17, memory, k, v)}[index]
     p = params[f"layer_{index}"]
-    got = phi4flash.Block(model.cfg, index).apply({"params": p}, *ins)
-    want = REF["block"](p, index, *ins)
+    got = jax.jit(phi4flash.Block(model.cfg, index).apply)(
+        {"params": p}, *ins)
+    want = jax.jit(REF["block"], static_argnums=1)(p, index, *ins)
     for g, w in zip(got, want):
         assert (g is None) == (w is None)
         if w is not None:
@@ -125,8 +127,8 @@ def test_the_window_is_on_the_layers_that_have_one_and_on_no_other(
     """Layer 1 must not see key 0 from position 8 on; layer 17 must."""
     for index, sees in ((1, False), (17, True)):
         p = params[f"layer_{index}"]
-        run = lambda x: phi4flash.Block(model.cfg, index).apply(
-            {"params": p}, x, None, None, None)[0]
+        run = jax.jit(lambda x: phi4flash.Block(model.cfg, index).apply(
+            {"params": p}, x, None, None, None)[0])
         moved = x.at[:, 0].set(-x[:, 1])
         far = np.abs(np.asarray(run(moved) - run(x)))[:, SZ["window"]:]
         assert (far.max() > 1e-4) == sees, (index, far.max())
@@ -147,9 +149,10 @@ def test_the_programs_attention_runs_through_the_flash_kernels(model,
     cfg = dataclasses.replace(model.cfg, attention_fn=through_kernels)
     for index, window in ((1, SZ["window"]), (17, None)):
         p = params[f"layer_{index}"]
-        got = phi4flash.Block(cfg, index).apply(
+        got = jax.jit(phi4flash.Block(cfg, index).apply)(
             {"params": p}, x, None, None, None)[0]
-        want = REF["block"](p, index, x, None, None, None)[0]
+        want = jax.jit(REF["block"], static_argnums=1)(
+            p, index, x, None, None, None)[0]
         np.testing.assert_allclose(got, want, **TOL)
         assert calls[-2:] == [((2, 32, 2, 8), (2, 32, 1, 8), (2, 32, 1, 16),
                                window)] * 2

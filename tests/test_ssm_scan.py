@@ -11,6 +11,8 @@ jnp = jax.numpy
 
 from horovod_tpu.parallel import ssm_scan as ss  # noqa: E402
 
+from .compiled import out_and_vjp  # noqa: E402
+
 pytestmark = pytest.mark.fast
 
 
@@ -40,11 +42,11 @@ def test_kernels_match_the_literal_recurrence(bt, seq, channels, states,
     args = _case(3, bt, seq, channels, states)
     g = jnp.asarray(np.random.RandomState(4).randn(bt, seq, channels),
                     jnp.float32)
-    y, vjp = jax.vjp(lambda *x: ss.selective_scan(
-        *x, chunk=chunk, interpret=True), *args)
-    want, vjp_want = jax.vjp(ss.selective_scan_reference, *args)
+    y, grads = out_and_vjp(lambda *x: ss.selective_scan(
+        *x, chunk=chunk, interpret=True), g, *args)
+    want, grads_want = out_and_vjp(ss.selective_scan_reference, g, *args)
     np.testing.assert_allclose(y, want, rtol=2e-5, atol=2e-5)
-    for got, ref, name in zip(vjp(g), vjp_want(g),
+    for got, ref, name in zip(grads, grads_want,
                               ("u", "delta", "A", "B", "C", "D")):
         assert got.shape == ref.shape and got.dtype == ref.dtype
         scale = float(jnp.abs(ref).max()) + 1.0
