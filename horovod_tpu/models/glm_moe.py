@@ -14,18 +14,22 @@ expert layer after them.
   heads share on the key side. In training the keys and values are
   expanded per head and go through ``flash_attention``; nothing is
   absorbed.
-* **Expert layer** (:class:`ExpertLayer`): a float32 sigmoid router over
-  *all* ``n_routed_experts``, top k of ``score + bias``, weights
-  normalised over the k and scaled. The layer is told which experts it
-  holds (``experts_held`` from ``expert_offset``: one chip's share under
-  expert parallelism), routes over all of them and computes its own
-  experts' part; what the absent experts would add is left out. **No
-  assignment to a held expert is dropped, whatever the imbalance**:
-  assignments are sorted by expert and the three products run over the
-  held groups at their real sizes (``jax.lax.ragged_dot``, which the TPU
-  compiler lowers to a grouped-matmul kernel of its own). The row buffer
-  is static, so the layer picks the smallest of ``ROW_TIERS`` (shares of
-  tokens x k) that holds this step's assignments; the last tier is 1.0.
+* **Expert layer** (:class:`ExpertLayer`, the one expert layer of
+  every sparse model here; the configuration it is given says what
+  differs between them): a float32 router over *all*
+  ``n_routed_experts``, here sigmoid scores and the top k of ``score +
+  bias``, weights normalised over the k and scaled. The layer is told
+  which experts it holds (``experts_held`` from ``expert_offset``: one
+  chip's share under expert parallelism), routes over all of them and
+  computes its own experts' part; what the absent experts would add is
+  left out. **No assignment to a held expert is dropped, whatever the
+  imbalance**: assignments are sorted by expert and the three products
+  run over the held groups at their real sizes (``jax.lax.ragged_dot``,
+  which the TPU compiler lowers to a grouped-matmul kernel of its own).
+  The row buffer is static, so the layer picks the smaller of
+  ``row_tiers`` (shares of tokens x k) that holds this step's
+  assignments: twice the share of the experts held, or every
+  assignment, a buffer of the first size at a time.
 * **Multi-token prediction** (:class:`GlmMoeLM`, depth 1)::
 
       h'_i = W_eh [norm_e(Emb(t_{i+1})), norm_h(h_i)]
@@ -68,6 +72,11 @@ class GlmMoeConfig:
     n_routed_experts: int = 64       # the router's width
     num_experts_per_tok: int = 4
     routed_scaling_factor: float = 1.8
+    # What else ``ExpertLayer`` asks of its configuration: the family's
+    # scores are sigmoids beside a correction bias, and its shared
+    # expert has no gate.
+    scoring: str = "sigmoid"
+    shared_expert_gate: bool = False
     # The share of the experts this chip holds: ids
     # [expert_offset, expert_offset + experts_held).
     experts_held: int = 64
@@ -82,23 +91,38 @@ class GlmMoeConfig:
     def qk_head_dim(self) -> int:
         return self.qk_nope_head_dim + self.qk_rope_head_dim
 
+    @property
+    def shared_intermediate_size(self) -> int:
+        return self.moe_intermediate_size    # n_shared_experts 1
+
 
 # Columns of an expert layer's counts, behind the held experts' own.
 ABSENT, DROPPED = -2, -1
-# Static sizes of the expert layer's row buffer, as shares of tokens x k,
-# ascending. A layer that holds an eighth of the experts sees an eighth
-# of the assignments by expectation and 1.9 times that in its fullest
-# expert (PERF.md, PR 27): a quarter holds the sound case, and the last
-# tier holds every assignment, so nothing is ever dropped.
-ROW_TIERS = (0.25, 1.0)
+# The first tier of the expert layer's row buffer over the share of
+# the experts held. A layer that holds an eighth of the experts sees an
+# eighth of the assignments by expectation and 1.9 times that in its
+# fullest expert (PERF.md, PR 27); over all the held experts together
+# twice the expectation holds the sound case.
+ROW_TIER_HEADROOM = 2.0
 
 
-def _norm(cfg: GlmMoeConfig, name: str):
+def row_tiers(experts_held: int, n_routed_experts: int) -> tuple:
+    """Static sizes of the expert layer's row buffer, as shares of
+    tokens x k, ascending: ``ROW_TIER_HEADROOM`` times the share of the
+    experts held (a quarter where an eighth is held, an eighth for a
+    sixteenth), then 1.0, every assignment, so that nothing is ever
+    dropped. A layer that holds half the experts or more has the one
+    tier."""
+    first = ROW_TIER_HEADROOM * experts_held / n_routed_experts
+    return (first, 1.0) if first < 1.0 else (1.0,)
+
+
+def _norm(cfg, name: str):
     return nn.RMSNorm(epsilon=cfg.rms_norm_eps, dtype=cfg.dtype,
                       param_dtype=jnp.float32, name=name)
 
 
-def _dense(cfg: GlmMoeConfig, features, name: str, axis=-1):
+def _dense(cfg, features, name: str, axis=-1):
     return nn.DenseGeneral(features, axis=axis, use_bias=False,
                            dtype=cfg.dtype, name=name)
 
@@ -130,7 +154,7 @@ class LatentAttention(nn.Module):
 
 
 class SwiGLU(nn.Module):
-    cfg: GlmMoeConfig
+    cfg: Any
     width: int
 
     @nn.compact
@@ -165,12 +189,30 @@ def _rows_to_experts_bwd(k, res, g):
 rows_to_experts.defvjp(_rows_to_experts_fwd, _rows_to_experts_bwd)
 
 
+# Rows of the buffer against assignments, above which the way back to
+# token order adds the buffer's rows into their tokens instead of
+# picking a row for every assignment. Measured on v5e silicon (PR 33;
+# 16,384 tokens of 2,048, bfloat16, ms): ten choices a token and a
+# buffer of 20,480 rows, picking 163,840 rows 5.52, adding 20,480 rows
+# 2.98; four choices and a buffer of 16,384, picking 65,536 rows 2.05,
+# adding 16,384 rows 2.61: an added row costs what 4.5 to 5 picked
+# ones do.
+PICKED_ROWS_AN_ADDED_ROW = 5
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
 def rows_from_experts(rows, order, inv, held, k):
     """[N, D] from [cap, D] rows in expert order: each token's held
     choices summed in float32; a row that belongs to no held expert
-    (whatever the buffer holds there) is left out."""
-    cap = rows.shape[0]
+    (whatever the buffer holds there) is left out. Where the buffer is
+    short against the assignments (``PICKED_ROWS_AN_ADDED_ROW``) its
+    rows are added into their tokens; else every assignment picks its
+    row."""
+    cap, n = rows.shape[0], held.shape[0]
+    if n * k > PICKED_ROWS_AN_ADDED_ROW * cap:
+        token = jnp.where(held.reshape(-1)[order], order // k, n)
+        return jnp.zeros((n, rows.shape[-1]), jnp.float32).at[token].add(
+            rows.astype(jnp.float32), mode="drop").astype(rows.dtype)
     picked = rows[jnp.minimum(inv, cap - 1)]                 # [N*k, D]
     picked = jnp.where(held.reshape(-1, 1), picked, 0)
     return jnp.sum(picked.reshape(-1, k, rows.shape[-1]),
@@ -194,10 +236,11 @@ rows_from_experts.defvjp(_rows_from_experts_fwd, _rows_from_experts_bwd)
 
 class Router(nn.Module):
     """The router's parameters: the float32 kernel over all the experts
-    and the correction bias that only the choice reads (the balancing
-    rule that moves it is the trainer's, not the layer's)."""
+    and, where the scores are sigmoids, the correction bias that only
+    the choice reads (the balancing rule that moves it is the
+    trainer's, not the layer's); ``None`` under a softmax."""
 
-    cfg: GlmMoeConfig
+    cfg: Any
 
     @nn.compact
     def __call__(self):
@@ -205,6 +248,8 @@ class Router(nn.Module):
         kernel = self.param("kernel", nn.initializers.lecun_normal(),
                             (cfg.hidden_size, cfg.n_routed_experts),
                             jnp.float32)
+        if cfg.scoring == "softmax":
+            return kernel, None
         bias = self.param("bias", nn.initializers.zeros,
                           (cfg.n_routed_experts,), jnp.float32)
         return kernel, bias
@@ -213,7 +258,7 @@ class Router(nn.Module):
 class HeldExperts(nn.Module):
     """The held experts' three kernels, [experts_held, in, out]."""
 
-    cfg: GlmMoeConfig
+    cfg: Any
 
     @nn.compact
     def __call__(self):
@@ -231,9 +276,25 @@ class HeldExperts(nn.Module):
 class ExpertLayer(nn.Module):
     """``(y, counts)``: the held experts' part of the routed result plus
     the shared expert; ``counts`` int32 [experts_held + 2]: assignments
-    to each held expert, to absent experts, and dropped (always 0)."""
+    to each held expert, to absent experts, and dropped (always 0).
 
-    cfg: GlmMoeConfig
+    One body for every sparse model; the configuration says what
+    differs. It is read for ``hidden_size``, ``moe_intermediate_size``,
+    ``shared_intermediate_size``, ``n_routed_experts`` (the router's
+    width), ``num_experts_per_tok``, ``experts_held``,
+    ``expert_offset``, ``dtype`` and
+
+    * ``scoring``: ``"sigmoid"`` (scores are sigmoids, the choice reads
+      ``score + bias``, a correction bias the layer owns) or
+      ``"softmax"`` (scores are a softmax over all the experts, no
+      bias);
+    * ``routed_scaling_factor``: what the normalised weights of a
+      token's k choices are multiplied by;
+    * ``shared_expert_gate``: whether the shared expert's output is
+      multiplied by ``sigmoid(x w_s)``, ``w_s`` the layer's own
+      ``d -> 1``."""
+
+    cfg: Any
 
     @nn.compact
     def __call__(self, x):
@@ -244,7 +305,9 @@ class ExpertLayer(nn.Module):
             raise ValueError(
                 f"experts [{cfg.expert_offset}, {cfg.expert_offset + held_n}"
                 f") are not among the router's {e}")
-        d, width = cfg.hidden_size, cfg.moe_intermediate_size
+        if cfg.scoring not in ("sigmoid", "softmax"):
+            raise ValueError(f"scoring {cfg.scoring!r}: sigmoid or softmax")
+        d = cfg.hidden_size
         xf = x.reshape(-1, d)
         n = xf.shape[0]
 
@@ -252,11 +315,15 @@ class ExpertLayer(nn.Module):
             # float32 at full precision: a rounded score moves the
             # choice (the chip's default runs an f32 matmul in bf16).
             w_r, bias = Router(cfg, name="router")()
-            scores = jax.nn.sigmoid(jnp.dot(
-                xf.astype(jnp.float32), w_r,
-                precision=jax.lax.Precision.HIGHEST))
-            _, chosen = jax.lax.top_k(
-                scores + jax.lax.stop_gradient(bias), k)       # [N, k]
+            logits = jnp.dot(xf.astype(jnp.float32), w_r,
+                             precision=jax.lax.Precision.HIGHEST)
+            if cfg.scoring == "softmax":
+                scores = jax.nn.softmax(logits, axis=-1)
+                _, chosen = jax.lax.top_k(scores, k)           # [N, k]
+            else:
+                scores = jax.nn.sigmoid(logits)
+                _, chosen = jax.lax.top_k(
+                    scores + jax.lax.stop_gradient(bias), k)
             # kept only where a caller asks for ``intermediates``
             self.sow("intermediates", "chosen", chosen)
             picked = jnp.take_along_axis(scores, chosen, axis=-1)
@@ -276,32 +343,81 @@ class ExpertLayer(nn.Module):
 
         w_gate, w_up, w_down = HeldExperts(cfg, name="experts")()
 
+        def span(first, inv, held, gs, live):
+            """The held experts' part of the assignments ``first`` (a
+            run of ``order``): ``inv`` and ``held`` say where in the
+            run an assignment's row lies and whether it is there, ``gs``
+            how many of its rows each expert has, ``live`` which rows
+            belong to a held expert at all."""
+            with jax.named_scope("moe.dispatch"):
+                rows = rows_to_experts(xf, first, inv, held, k)
+            with jax.named_scope("moe.experts"):
+                hidden = nn.silu(jax.lax.ragged_dot(rows, w_gate, gs)) \
+                    * jax.lax.ragged_dot(rows, w_up, gs)
+                out = jax.lax.ragged_dot(hidden, w_down, gs)
+            with jax.named_scope("moe.combine"):
+                # rows behind the last group are whatever the
+                # buffer held: never let them meet a gradient
+                out = jnp.where(live, out, 0) \
+                    * gate_rows[first][:, None].astype(out.dtype)
+                return rows_from_experts(out, first, inv, held, k)
+
         def routed(cap):
             """The held experts' part through a row buffer of ``cap``."""
             def run(_):
-                first = order[:cap]
-                with jax.named_scope("moe.dispatch"):
-                    rows = rows_to_experts(xf, first, inv, held, k)
-                with jax.named_scope("moe.experts"):
-                    gs = sizes[:held_n]
-                    hidden = nn.silu(jax.lax.ragged_dot(rows, w_gate, gs)) \
-                        * jax.lax.ragged_dot(rows, w_up, gs)
-                    out = jax.lax.ragged_dot(hidden, w_down, gs)
-                with jax.named_scope("moe.combine"):
-                    # rows behind the last group are whatever the
-                    # buffer held: never let them meet a gradient
-                    live = jnp.arange(cap)[:, None] < held_total
-                    out = jnp.where(live, out, 0) \
-                        * gate_rows[first][:, None].astype(out.dtype)
-                    return rows_from_experts(out, first, inv, held, k)
+                return span(order[:cap], inv, held, sizes[:held_n],
+                            jnp.arange(cap)[:, None] < held_total)
             return run
 
-        caps = [max(1, int(round(t * n * k))) for t in ROW_TIERS]
-        tier = jnp.sum(held_total > jnp.asarray(caps[:-1], jnp.int32))
-        y = jax.lax.switch(tier, [routed(c) for c in caps], None)
+        def walked(cap):
+            """Every assignment, ``cap`` rows of the sorted order at a
+            time: the tier that drops nothing never holds more than the
+            first tier's buffer. A slab is recomputed in the backward
+            pass; one behind the last held row multiplies groups of no
+            rows (a conditional around it would have the scan stack
+            every slab's operands for the backward pass)."""
+            slabs = -(-n * k // cap)
+            padded = jnp.pad(order, (0, slabs * cap - n * k))
+            ends = jnp.cumsum(sizes[:held_n])
+            starts = ends - sizes[:held_n]
+            place = inv.reshape(held.shape)
+
+            @jax.checkpoint
+            def slab(y, lo):
+                first = jax.lax.dynamic_slice(padded, (lo,), (cap,))
+                inside = held & (place >= lo) & (place < lo + cap)
+                gs = jnp.clip(ends, lo, lo + cap) \
+                    - jnp.clip(starts, lo, lo + cap)
+                live = (lo + jnp.arange(cap))[:, None] < held_total
+                return y + span(first, jnp.clip(inv - lo, 0, cap - 1),
+                                inside, gs, live).astype(jnp.float32), None
+
+            def run(_):
+                y, _ = jax.lax.scan(
+                    slab, jnp.zeros((n, d), jnp.float32),
+                    jnp.arange(slabs, dtype=jnp.int32) * cap)
+                return y.astype(cfg.dtype)
+            return run
+
+        caps = [max(1, int(round(t * n * k)))
+                for t in row_tiers(held_n, e)]
+        if len(caps) == 1:
+            tier = 0
+            y = routed(caps[0])(None)
+        else:
+            tier = jnp.sum(held_total > jnp.asarray(caps[:-1], jnp.int32))
+            y = jax.lax.switch(
+                tier, [routed(c) for c in caps[:-1]] + [walked(caps[-2])],
+                None)
 
         with jax.named_scope("moe.shared"):
-            y = y + SwiGLU(cfg, width, name="shared")(xf)
+            shared = SwiGLU(cfg, cfg.shared_intermediate_size,
+                            name="shared")(xf)
+            if cfg.shared_expert_gate:
+                shared = shared * jax.nn.sigmoid(_dense(
+                    cfg, 1, "shared_gate")(xf).astype(jnp.float32)) \
+                    .astype(shared.dtype)
+            y = y + shared
         # held assignments whose row lies behind the buffer that ran: the
         # last tier holds every row, so none
         dropped = jnp.sum(held.reshape(-1)

@@ -140,20 +140,25 @@ def _dense(cfg: Phi4FlashConfig, features: int, name: str, bias=False,
 
 class CausalDepthwiseConv(nn.Module):
     """``out_t = sum_j w[j] x_{t - (taps - 1) + j} + b`` a channel, in
-    float32: position ``t`` reads itself and the ``taps - 1`` before."""
+    float32: position ``t`` reads itself and the ``taps - 1`` before.
+    ``use_bias`` False leaves ``b`` out (the Gated DeltaNet's
+    convolution has none)."""
 
     taps: int
+    use_bias: bool = True
 
     @nn.compact
     def __call__(self, x):
         seq, channels = x.shape[1], x.shape[2]
         kernel = self.param("kernel", nn.initializers.lecun_normal(),
                             (self.taps, channels), jnp.float32)
-        bias = self.param("bias", nn.initializers.zeros, (channels,),
-                          jnp.float32)
         padded = jnp.pad(x, ((0, 0), (self.taps - 1, 0), (0, 0)))
-        return sum(padded[:, j:j + seq].astype(jnp.float32) * kernel[j]
-                   for j in range(self.taps)) + bias
+        out = sum(padded[:, j:j + seq].astype(jnp.float32) * kernel[j]
+                  for j in range(self.taps))
+        if not self.use_bias:
+            return out
+        return out + self.param("bias", nn.initializers.zeros, (channels,),
+                                jnp.float32)
 
 
 class Mamba(nn.Module):

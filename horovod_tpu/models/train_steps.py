@@ -26,6 +26,7 @@ from horovod_tpu.common.basics import active_runtime
 from horovod_tpu.compat import jaxshim
 from horovod_tpu.models.glm_moe import ABSENT, DROPPED, GlmMoeLM
 from horovod_tpu.models.phi4flash import Phi4FlashLM
+from horovod_tpu.models.qwen3next import Qwen3NextLM
 from horovod_tpu.models.resnet import ResNet50
 from horovod_tpu.models.transformer import (
     TransformerConfig, TransformerLM, lm_loss_from_hidden,
@@ -166,14 +167,9 @@ def glm_moe_loss_fn(model: GlmMoeLM):
     return loss_fn
 
 
-def glm_moe_train_step(model: GlmMoeLM, tx, mesh):
-    """``jit(shard_map(step))`` with ``(params, opt_state)`` donated:
-    ``(params, opt_state, tokens) -> (params, opt_state, loss,
-    counts)``. The loss is the mean over the mesh, the expert layers'
-    counts their sum; feed the counts to :class:`MoeLoadFeed`, which
-    never waits for a step."""
-    loss_fn = glm_moe_loss_fn(model)
-
+def _counted_train_step(loss_fn, tx, mesh):
+    """The step of a sparse model whose loss is ``loss_fn(params,
+    tokens) -> (loss, counts)``."""
     def step(p, os_, t):
         with jax.named_scope("loss"):
             (loss, counts), grads = jax.value_and_grad(
@@ -190,6 +186,34 @@ def glm_moe_train_step(model: GlmMoeLM, tx, mesh):
         in_specs=(rep, rep, jaxshim.partition_spec(AXIS)),
         out_specs=(rep, rep, rep, rep))
     return _jit_step(step, mesh, donate_argnums=(0, 1))
+
+
+def glm_moe_train_step(model: GlmMoeLM, tx, mesh):
+    """``jit(shard_map(step))`` with ``(params, opt_state)`` donated:
+    ``(params, opt_state, tokens) -> (params, opt_state, loss,
+    counts)``. The loss is the mean over the mesh, the expert layers'
+    counts their sum; feed the counts to :class:`MoeLoadFeed`, which
+    never waits for a step."""
+    return _counted_train_step(glm_moe_loss_fn(model), tx, mesh)
+
+
+def qwen3next_loss_fn(model: Qwen3NextLM):
+    """``(params, tokens) -> (loss, counts)``: next-token cross-entropy
+    through the chunked head on the untied ``lm_head``; ``counts`` are
+    the expert layers' loads ([layers, experts_held + 2])."""
+    def loss_fn(p, t):
+        hidden, counts = model.apply({"params": p}, t)
+        return lm_loss_from_hidden(hidden, p["lm_head"]["kernel"], t), counts
+    return loss_fn
+
+
+def qwen3next_train_step(model: Qwen3NextLM, tx, mesh):
+    """The hybrid linear-attention sparse decoder's step,
+    ``glm_moe_train_step``'s shape: ``(params, opt_state, tokens) ->
+    (params, opt_state, loss, counts)``, state donated, every block
+    recomputed with its kernels' outputs kept
+    (``qwen3next.RematBlock``), the counts for :class:`MoeLoadFeed`."""
+    return _counted_train_step(qwen3next_loss_fn(model), tx, mesh)
 
 
 class MoeLoadFeed:

@@ -11,6 +11,8 @@ support at all. These are first-class here:
 - ``pipeline``        — GPipe-style pipeline parallelism over a mesh axis
 - ``ssm_scan``        — the selective scan of a state-space layer, as
                         Pallas kernels (forward and backward)
+- ``gated_delta``     — the gated delta rule of a Gated DeltaNet layer
+                        (a matrix state a head), as Pallas kernels
 - ``trainer``         — composes dp x tp x sp x ep into one jitted step
 """
 
@@ -30,6 +32,7 @@ from horovod_tpu.parallel.trainer import (
     Trainer, TrainerConfig, make_chunked_lm_loss,
 )
 from horovod_tpu.parallel.ssm_scan import selective_scan
+from horovod_tpu.parallel.gated_delta import gated_delta_rule
 
 
 def __getattr__(name):
@@ -47,5 +50,5 @@ __all__ = [
     "ulysses_attention", "make_ulysses_attention",
     "pipeline_stages", "make_pipeline_apply", "PipelinedLM",
     "Trainer", "TrainerConfig", "make_chunked_lm_loss",
-    "selective_scan",
+    "selective_scan", "gated_delta_rule",
 ]

@@ -743,6 +743,21 @@ def _shapes_ok(seq_q, seq_k, block_q, block_k):
 # one that hugs its band: it is paced by its grid steps (2.4 us each,
 # three a q tile), not by the 1.4 ms of arithmetic the band needs, and
 # longer tiles are fewer steps.
+#
+# Measured at D=256 over grouped heads on v5e silicon (PR 33: B1, 16
+# query heads over 2 key-value heads, S16384, the gated attention of
+# models/qwen3next.py; one call's forward, and forward + backward less
+# that forward, by the host's clock, ms; 256x256 sub-tiles):
+#   512x1024   16.24 + 48.38 =  64.62
+#   1024x512   16.07 + 50.84 =  66.91
+#   256x1024   18.77 + 50.82 =  69.60
+#   512x512    17.96 + 54.40 =  72.37
+#   256x512    23.65 + 60.03 =  83.68
+#   512x2048   16.12 + 135.87 = 151.99
+# 1024x1024 does not fit VMEM (the dk/dv kernel, which holds a group's
+# eight query heads' worth of accumulators). The default pair holds for
+# grouped heads at 256 as it does for equal ones: no rung changed, and
+# in the cell's step the three kernels run at 74% of their roofline.
 _BLOCK_Q_LADDER = (512, 256, 128)
 _BLOCK_K_LADDER = (1024, 512, 256, 128)
 _HEAD_DIM_BASE = 256  # the largest D the default ladder was measured at

@@ -1,0 +1,524 @@
+"""Pallas gated delta rule: the recurrence of a Gated DeltaNet layer
+(arXiv:2412.06464) in the delta rule's chunked form
+(arXiv:2406.06484), as TPU kernels, forward and backward.
+
+A value head carries a float32 state ``S`` [Dk, Dv], zero at the start
+of a sequence (``g <= 0`` the log of the decay, ``beta`` in (0, 1))::
+
+    S   <- exp(g_t) S
+    u_t  = beta_t (v_t - S^T k_t)
+    S   <- S + k_t u_t^T
+    o_t  = S^T q_t
+
+The state is a matrix and its update a rank-one *correction*, so there
+is no elementwise form: ``gated_delta_rule_reference`` is the literal
+``lax.scan`` (one tiny step a position), the tests' oracle and no path
+to ship. The kernels compute a chunk of ``C`` positions at a time. With
+``G_i`` the running sum of ``g`` inside the chunk and ``S`` the state
+that enters it::
+
+    A_ij = -beta_i (k_i . k_j) exp(G_i - G_j)   for j < i
+    T    = (I - A)^-1
+    W    = T (beta exp(G) K)        U = T (beta V)
+    V'   = U - W S
+    O    = (Q exp(G)) S + tril(Q K^T exp(G_i - G_j)) V'
+    S'   = exp(G_C) S + (K exp(G_C - G))^T V'
+
+Kernel shape:
+
+- grid ``(batch, key head, chunk)``, the chunk innermost and in order;
+  the state of the key head's value heads lives in float32 VMEM scratch
+  between the chunks of a sequence. **One step serves every value head
+  that reads the key head** (two at the model's shape): ``K K^T`` and
+  ``Q K^T`` are computed once, and ``dq`` and ``dk`` leave the backward
+  kernel already summed over them. q, k, v and o stay ``[B, S, H x D]``
+  in HBM: a block is a chunk's rows of one head's columns, so nothing is
+  transposed or repeated around the kernels. ``G`` and ``beta`` come as
+  ``[B, Hv, chunks, C]``, a head's whole table resident, a chunk one
+  row of it.
+- **the triangular inverse is all MXU**: ``A`` is strictly lower
+  triangular, so ``A^C = 0`` and ``(I - A)^-1 = (I + A)(I + A^2)(I +
+  A^4)...`` exactly, ``log2(C) - 1`` squarings and as many products, in
+  float32 at full precision (``_unit_lower_inverse``).
+- ``gdn_fwd`` also writes the state that **entered** each chunk
+  (``[B, Hv, chunks, Dk, Dv]`` float32: 64 KB a head and chunk, 537 MB
+  a layer at the model's shape and a chunk of 64, 268 MB at 128; a
+  recomputed block keeps it, as it keeps every kernel's output).
+  ``gdn_bwd`` walks the chunks from the last to the first with ``dS``
+  in scratch: it recomputes a chunk's ``T``, ``W``, ``U`` and ``V'``
+  from the entering state and propagates through every product above,
+  ``T`` included (``dA = T^T dT T^T``).
+- MXU operands take q's type (bfloat16 in the model: exact for q, k and
+  v, a rounding for ``T``, ``S`` and the gated operands), accumulation
+  is float32; the state, ``G``, ``beta``, every exponential and the
+  inverse are float32 whatever the operands' type.
+
+``gated_delta_rule`` is differentiable through the two kernels
+(``custom_vjp``) in q, k, v, g and beta. Off the TPU the kernels run in
+interpreter mode, as the flash and scan kernels do.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+
+# Chunk length by sequence length, as (longest sequence, chunk) rungs;
+# the last rung stands past it. Measured on v5e silicon (PR 33; the
+# model's shape: B1, 16 key heads serving 32 value heads, S16384,
+# 128/128, bfloat16 q, k, v, one layer; the forward through
+# ``gated_delta_rule`` and forward + backward through its gradient, by
+# the host's clock, ms; the inverse by ``_unit_lower_inverse``):
+#   chunk   fwd      fwd+bwd   entering states   products / recurrence
+#    32     18.837   45.319    1,074 MB          1.3
+#    64     13.571   31.525      537 MB          2.1
+#   128     11.316   25.179      268 MB          5.4
+#   256     33.473   72.180      134 MB          19.1
+# (128 and 64 again in a second call: 11.301 and 25.165, 13.556 and
+# 31.514.) A longer chunk executes more (``chunk_flops`` over the
+# recurrence's 7 Dk Dv a position: the last column) and is faster all
+# the same up to 128: there every product fills the MXU's 128 x 128
+# and there are half the grid steps and entering states of 64; at 256
+# the inverse's fourteen products of 256^3 a head and chunk are the
+# kernel. In the cell's step (traced) the kernels read 9.77 ms forward
+# and 12.97 backward a layer at 128, 12.2 and 17.1 at 64.
+# The inverse, same shape and clock (PR 33, a later call; the doublings
+# twice, first and last): forward substitution by row blocks as ``(I -
+# A) = (I - D)(I - M)``, ``D`` the diagonal blocks (inverted by
+# doublings, every block in one product of full width), ``M = (I -
+# D)^-1 (A - D)``, then ``X_i = (I - D)^-1_i + M_i X_<i`` a block of
+# rows at a time, against ``_unit_lower_inverse``'s doublings:
+#   chunk 128      full + thin products   fwd      fwd+bwd
+#   doublings      12                     11.319   25.214  (11.326, 25.186)
+#   blocks of 16    7 + 7                 11.930   26.308
+#   blocks of 32    9 + 3                 11.271   24.978
+#   blocks of 64   11 + 1                 11.534   25.516
+#   chunk 64: doublings 13.552 and 31.491 (13.553, 31.540); blocks of
+#   16 14.685 and 33.324; of 32 14.237 and 32.777
+# Blocks of 16 save five products of 128^3 and lose more than that to
+# seven thin ones in a chain; blocks of 32 are 0.9% ahead at 128 and
+# 4% behind at 64. The doublings stay: five lines, no block size to
+# choose, within a hundredth of the best form at the ladder's chunk.
+# The forms agree to one bfloat16 step in o and the gradients (``T``
+# is rounded to the operands' type for ``W`` and ``U``).
+_CHUNK_LADDER = ((None, 128),)
+
+
+def _chunk_for(seq: int) -> int:
+    """The ladder's chunk for a sequence of ``seq``, halved while the
+    half still holds the whole sequence (and 8 positions)."""
+    chunk = next(c for longest, c in _CHUNK_LADDER
+                 if longest is None or seq <= longest)
+    while chunk // 2 >= max(seq, 8):
+        chunk //= 2
+    return chunk
+
+
+def _note_chunks(seq: int, chunk: int) -> None:
+    """``hvd_gdn_chunks{kind=...}`` of the call being traced
+    (docs/metrics.md), where a world with its metrics plane on is there
+    to read it."""
+    from horovod_tpu.common import basics
+    if not basics.initialized():
+        return
+    reg = basics.active_runtime().metrics
+    if not reg.enabled:
+        return
+    for kind, n in (("chunks", -(-seq // chunk)), ("chunk_length", chunk)):
+        reg.gauge(
+            f'hvd_gdn_chunks{{kind="{kind}"}}',
+            "the gated delta rule traced last: chunks a sequence and "
+            "positions a chunk", agg="max").set(n)
+
+
+# -- inside a chunk ---------------------------------------------------------
+
+_F32 = jnp.float32
+_NT = (((1,), (1,)), ((), ()))      # x y^T
+_NN = (((1,), (0,)), ((), ()))      # x y
+_TN = (((0,), (0,)), ((), ()))      # x^T y
+
+
+def _mm(x, y, dims, dtype):
+    """A product on the MXU: operands in ``dtype``, float32 out."""
+    return jax.lax.dot_general(x.astype(dtype), y.astype(dtype), dims,
+                               preferred_element_type=_F32)
+
+
+def _mm_f32(x, y, dims=_NN):
+    return jax.lax.dot_general(x, y, dims, preferred_element_type=_F32,
+                               precision=jax.lax.Precision.HIGHEST)
+
+
+def _unit_lower_inverse(a):
+    """``(I - a)^-1`` of a strictly lower triangular ``a`` [C, C]:
+    ``(I + a)(I + a^2)(I + a^4)...``, exact because ``a^C = 0``."""
+    size = a.shape[0]
+    rows = jax.lax.broadcasted_iota(jnp.int32, a.shape, 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, a.shape, 1)
+    t = jnp.where(rows == cols, 1.0, 0.0).astype(_F32) + a
+    power, n = a, 2
+    while n < size:
+        power = _mm_f32(power, power)           # a^n
+        t = t + _mm_f32(power, t)               # (I + a^n) t
+        n *= 2
+    return t
+
+
+def _masks(chunk: int):
+    rows = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    return rows == cols, rows > cols, rows >= cols
+
+
+def _col(row, eye):
+    """[C, 1] of a [1, C] row."""
+    return jnp.sum(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
+
+
+def _row(col, eye):
+    """[1, C] of a [C, 1] column."""
+    return jnp.sum(jnp.where(eye, col, 0.0), axis=0, keepdims=True)
+
+
+def _last(row):
+    """[1, 1]: the last entry of a [1, C] row."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, row.shape, 1)
+    return jnp.sum(jnp.where(lane == row.shape[1] - 1, row, 0.0), axis=1,
+                   keepdims=True)
+
+
+def _chunk(q, k, v, g_row, b_row, state, kk, qk, mm):
+    """A value head's chunk from the state that entered it: everything
+    the forward writes and the backward propagates through. q, k
+    [C, Dk]; v [C, Dv]; g_row, b_row [1, C] float32 (``G`` and
+    ``beta``); state [Dk, Dv] float32; kk, qk [C, C] the key head's raw
+    products."""
+    eye, lower, lower_eq = _masks(q.shape[0])
+    g_col, b_col = _col(g_row, eye), _col(b_row, eye)
+    decay = jnp.where(lower_eq, jnp.exp(jnp.minimum(g_col - g_row, 0.0)),
+                      0.0)                              # exp(G_i - G_j)
+    a = jnp.where(lower, -b_col * kk * decay, 0.0)
+    t = _unit_lower_inverse(a)
+    e_col = jnp.exp(g_col)
+    kb = (b_col * e_col) * k.astype(_F32)
+    vb = b_col * v.astype(_F32)
+    w = _mm(t, kb, _NN, mm)
+    u = _mm(t, vb, _NN, mm)
+    v_new = u - _mm(w, state, _NN, mm)
+    p = qk * decay
+    g_last = _last(g_row)
+    e_last = jnp.exp(g_last - g_col)                    # exp(G_C - G_i)
+    return dict(eye=eye, lower=lower, b_col=b_col, decay=decay, t=t,
+                e_col=e_col, kb=kb, vb=vb, w=w, v_new=v_new, p=p,
+                g_last=g_last, e_last=e_last)
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, sent_ref, s_scr,
+                *, rep: int, dv: int):
+    from jax.experimental import pallas as pl
+    c = pl.program_id(2)
+
+    @pl.when(c == 0)
+    def _():
+        s_scr[...] = jnp.zeros_like(s_scr)
+
+    q, k = q_ref[0], k_ref[0]
+    mm = q.dtype
+    kk = _mm(k, k, _NT, mm)
+    qk = _mm(q, k, _NT, mm)
+    for r in range(rep):
+        v = v_ref[0, :, r * dv:(r + 1) * dv]
+        state = s_scr[r]
+        sent_ref[0, r, 0] = state
+        x = _chunk(q, k, v, g_ref[0, r, pl.ds(c, 1), :],
+                   b_ref[0, r, pl.ds(c, 1), :], state, kk, qk, mm)
+        out = _mm(q.astype(_F32) * x["e_col"], state, _NN, mm) \
+            + _mm(x["p"], x["v_new"], _NN, mm)
+        o_ref[0, :, r * dv:(r + 1) * dv] = out.astype(o_ref.dtype)
+        s_scr[r] = jnp.exp(x["g_last"]) * state + _mm(
+            k.astype(_F32) * x["e_last"], x["v_new"], _TN, mm)
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, sent_ref, do_ref,
+                dq_ref, dk_ref, dv_ref, dg_ref, db_ref, ds_scr,
+                *, rep: int, dv: int, n_chunks: int):
+    from jax.experimental import pallas as pl
+    step = pl.program_id(2)
+    c = n_chunks - 1 - step
+
+    @pl.when(step == 0)                   # the sequence's last chunk
+    def _():
+        ds_scr[...] = jnp.zeros_like(ds_scr)
+
+    q, k = q_ref[0], k_ref[0]
+    mm = q.dtype
+    qf, kf = q.astype(_F32), k.astype(_F32)
+    kk = _mm(k, k, _NT, mm)
+    qk = _mm(q, k, _NT, mm)
+    dq = jnp.zeros(q.shape, _F32)
+    dk = jnp.zeros(k.shape, _F32)
+    rowsum = lambda x: jnp.sum(x, axis=1, keepdims=True)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, q.shape[0]), 1)
+    for r in range(rep):
+        v = v_ref[0, :, r * dv:(r + 1) * dv]
+        vf = v.astype(_F32)
+        state = sent_ref[0, r, 0]
+        d_out = do_ref[0, :, r * dv:(r + 1) * dv]
+        d_state = ds_scr[r]
+        x = _chunk(q, k, v, g_ref[0, r, pl.ds(c, 1), :],
+                   b_ref[0, r, pl.ds(c, 1), :], state, kk, qk, mm)
+        eye, t, decay = x["eye"], x["t"], x["decay"]
+        b_col, e_col, e_last = x["b_col"], x["e_col"], x["e_last"]
+        kd = kf * e_last
+        # O = (Q e) S + P V';  S' = e_C S + Kd^T V'
+        d_vnew = _mm(x["p"], d_out, _TN, mm) + _mm(kd, d_state, _NN, mm)
+        d_p = _mm(d_out, x["v_new"], _NT, mm)
+        d_qe = _mm(d_out, state, _NT, mm)
+        d_kd = _mm(x["v_new"], d_state, _NT, mm)
+        decay_last = jnp.exp(x["g_last"])
+        # V' = U - W S
+        ds_scr[r] = _mm(qf * e_col, d_out, _TN, mm) \
+            + decay_last * d_state - _mm(x["w"], d_vnew, _TN, mm)
+        d_w = -_mm(d_vnew, state, _NT, mm)
+        # W = T kb, U = T vb
+        d_t = _mm(d_w, x["kb"], _NT, mm) + _mm(d_vnew, x["vb"], _NT, mm)
+        d_kb = _mm(t, d_w, _TN, mm)
+        d_vb = _mm(t, d_vnew, _TN, mm)
+        # T = (I - A)^-1: dA = T^T dT T^T, below the diagonal
+        d_a = jnp.where(x["lower"],
+                        _mm_f32(_mm_f32(t, d_t, _TN), t, _NT), 0.0)
+        # A = -beta kk decay;  P = qk decay
+        d_qk = d_p * decay
+        d_kk = -b_col * d_a * decay
+        d_decay = (d_p * qk - b_col * d_a * kk) * decay
+        tail = rowsum(d_kd * kf) * e_last       # d / d(G_C - G_i)
+        d_g_col = rowsum(d_qe * qf) * e_col - tail \
+            + rowsum(d_kb * kf) * b_col * e_col + rowsum(d_decay)
+        d_g_last = jnp.sum(tail, axis=0, keepdims=True) \
+            + decay_last * jnp.sum(rowsum(state * d_state), axis=0,
+                                   keepdims=True)
+        d_b_col = rowsum(d_vb * vf) + rowsum(d_kb * kf) * e_col \
+            - rowsum(d_a * kk * decay)
+        d_g_row = _row(d_g_col, eye) \
+            - jnp.sum(d_decay, axis=0, keepdims=True) \
+            + jnp.where(lane == q.shape[0] - 1, d_g_last, 0.0)
+        dg_ref[0, r, pl.ds(c, 1), :] = d_g_row
+        db_ref[0, r, pl.ds(c, 1), :] = _row(d_b_col, eye)
+        dv_ref[0, :, r * dv:(r + 1) * dv] = (b_col * d_vb).astype(
+            dv_ref.dtype)
+        dq = dq + d_qe * e_col + _mm(d_qk, k, _NN, mm)
+        dk = dk + d_kd * e_last + _mm(d_qk, q, _TN, mm) \
+            + (b_col * e_col) * d_kb \
+            + _mm(d_kk, k, _NN, mm) + _mm(d_kk, k, _TN, mm)
+    dq_ref[0] = dq.astype(dq_ref.dtype)
+    dk_ref[0] = dk.astype(dk_ref.dtype)
+
+
+# -- the calls ---------------------------------------------------------------
+
+def _compiler_params():
+    from jax.experimental.pallas import tpu as pltpu
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=64 << 20)
+
+
+def _specs(chunk, dk, dv, rep, n_chunks, reverse: bool):
+    """Block specs over the grid (batch, key head, chunk); with
+    ``reverse`` the chunks are walked from the last to the first."""
+    from jax.experimental import pallas as pl
+    at = (lambda c: n_chunks - 1 - c) if reverse else (lambda c: c)
+    return dict(
+        qk=pl.BlockSpec((1, chunk, dk), lambda b, h, c: (b, at(c), h)),
+        v=pl.BlockSpec((1, chunk, rep * dv), lambda b, h, c: (b, at(c), h)),
+        gates=pl.BlockSpec((1, rep, n_chunks, chunk),
+                           lambda b, h, c: (b, h, 0, 0)),
+        sent=pl.BlockSpec((1, rep, 1, dk, dv),
+                          lambda b, h, c: (b, h, at(c), 0, 0)))
+
+
+def chunk_flops(chunk: int, dk: int, dv: int, rep: int) -> int:
+    """Multiply-adds x 2 of the forward kernel's products for one key
+    head's chunk (``rep`` value heads)."""
+    doublings = max(0, chunk.bit_length() - 2)
+    shared = 2 * 2 * chunk * chunk * dk
+    inverse = 2 * doublings * 2 * chunk ** 3
+    head = inverse + 2 * chunk * chunk * (dk + dv) \
+        + 2 * 2 * chunk * dk * dv + 2 * chunk * chunk * dv \
+        + 2 * chunk * dk * dv
+    return shared + rep * head
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "heads", "interpret"))
+def _gdn_fwd(q, k, v, g, beta, chunk: int, heads, interpret: bool):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    hk, hv = heads
+    rep = hv // hk
+    bt, padded = q.shape[:2]
+    dk, dv = q.shape[2] // hk, v.shape[2] // hv
+    n_chunks = padded // chunk
+    s = _specs(chunk, dk, dv, rep, n_chunks, reverse=False)
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, rep=rep, dv=dv),
+        grid=(bt, hk, n_chunks),
+        in_specs=[s["qk"], s["qk"], s["v"], s["gates"], s["gates"]],
+        out_specs=(s["v"], s["sent"]),
+        out_shape=(
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
+            jax.ShapeDtypeStruct((bt, hv, n_chunks, dk, dv), _F32)),
+        scratch_shapes=[pltpu.VMEM((rep, dk, dv), _F32)],
+        compiler_params=_compiler_params(),
+        interpret=interpret,
+        name="gdn_fwd",
+        cost_estimate=pl.CostEstimate(
+            flops=bt * hk * n_chunks * chunk_flops(chunk, dk, dv, rep),
+            transcendentals=bt * hv * n_chunks * chunk * (chunk + 2),
+            bytes_accessed=(q.size + k.size) * q.dtype.itemsize
+            + 2 * v.size * v.dtype.itemsize + 4 * (g.size + beta.size)
+            + 4 * bt * hv * n_chunks * dk * dv),
+    )(q, k, v, g, beta)
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "heads", "interpret"))
+def _gdn_bwd(q, k, v, g, beta, sent, d_out, chunk: int, heads,
+             interpret: bool):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    hk, hv = heads
+    rep = hv // hk
+    bt, padded = q.shape[:2]
+    dk, dv = q.shape[2] // hk, v.shape[2] // hv
+    n_chunks = padded // chunk
+    s = _specs(chunk, dk, dv, rep, n_chunks, reverse=True)
+    return pl.pallas_call(
+        functools.partial(_bwd_kernel, rep=rep, dv=dv, n_chunks=n_chunks),
+        grid=(bt, hk, n_chunks),
+        in_specs=[s["qk"], s["qk"], s["v"], s["gates"], s["gates"],
+                  s["sent"], s["v"]],
+        out_specs=(s["qk"], s["qk"], s["v"], s["gates"], s["gates"]),
+        out_shape=(
+            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct(k.shape, k.dtype),
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
+            jax.ShapeDtypeStruct(g.shape, _F32),
+            jax.ShapeDtypeStruct(beta.shape, _F32)),
+        scratch_shapes=[pltpu.VMEM((rep, dk, dv), _F32)],
+        compiler_params=_compiler_params(),
+        interpret=interpret,
+        name="gdn_bwd",
+        cost_estimate=pl.CostEstimate(
+            flops=3 * bt * hk * n_chunks * chunk_flops(chunk, dk, dv, rep),
+            transcendentals=bt * hv * n_chunks * chunk * (chunk + 2),
+            bytes_accessed=2 * (q.size + k.size) * q.dtype.itemsize
+            + 3 * v.size * v.dtype.itemsize + 8 * (g.size + beta.size)
+            + 4 * sent.size),
+    )(q, k, v, g, beta, sent, d_out)
+
+
+def _laid_out(q, k, v, g, beta, chunk):
+    """The kernels' operands from the module's: time padded to whole
+    chunks (a padded step has beta 0 and g 0: the state passes through
+    it unchanged), heads folded into the columns, ``G`` (the running
+    sum of ``g`` inside each chunk) and ``beta`` as [B, Hv, chunks, C]
+    float32."""
+    bt, seq, hk, dk = q.shape
+    hv = v.shape[2]
+    n_chunks = -(-seq // chunk)
+    pad = n_chunks * chunk - seq
+
+    def timed(x):
+        x = x.reshape(bt, seq, -1)
+        return jnp.pad(x, ((0, 0), (0, pad), (0, 0))) if pad else x
+
+    def gate(x):
+        x = timed(x.astype(_F32)).transpose(0, 2, 1)
+        return x.reshape(bt, hv, n_chunks, chunk)
+
+    return (timed(q), timed(k), timed(v),
+            jnp.cumsum(gate(g), axis=-1), gate(beta))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _rule(q, k, v, g, beta, chunk, interpret):
+    return _rule_fwd(q, k, v, g, beta, chunk, interpret)[0]
+
+
+def _rule_fwd(q, k, v, g, beta, chunk, interpret):
+    seq = q.shape[1]
+    heads = (q.shape[2], v.shape[2])
+    out, sent = _gdn_fwd(*_laid_out(q, k, v, g, beta, chunk), chunk=chunk,
+                         heads=heads, interpret=interpret)
+    return out[:, :seq].reshape(v.shape), (q, k, v, g, beta, sent)
+
+
+def _rule_bwd(chunk, interpret, res, d_out):
+    q, k, v, g, beta, sent = res
+    bt, seq, hv = v.shape[:3]
+    ops = _laid_out(q, k, v, g, beta, chunk)
+    padded = ops[0].shape[1]
+    d_out = jnp.pad(d_out.astype(v.dtype).reshape(bt, seq, -1),
+                    ((0, 0), (0, padded - seq), (0, 0)))
+    dq, dk, dv, d_gsum, d_beta = _gdn_bwd(
+        *ops, sent, d_out, chunk=chunk, heads=(q.shape[2], hv),
+        interpret=interpret)
+    # G is the running sum of g inside a chunk: g_j reaches every G_i
+    # with i >= j
+    d_g = jnp.flip(jnp.cumsum(jnp.flip(d_gsum, -1), axis=-1), -1)
+    gate = lambda x, like: x.reshape(bt, hv, padded).transpose(0, 2, 1)[
+        :, :seq].astype(like.dtype)
+    return (dq[:, :seq].reshape(q.shape), dk[:, :seq].reshape(k.shape),
+            dv[:, :seq].reshape(v.shape), gate(d_g, g), gate(d_beta, beta))
+
+
+_rule.defvjp(_rule_fwd, _rule_bwd)
+
+
+def gated_delta_rule(q, k, v, g, beta, chunk: Optional[int] = None,
+                     interpret: Optional[bool] = None):
+    """``o`` [B, S, Hv, Dv] in ``v.dtype`` of the recurrence in the
+    module docstring. q, k: [B, S, Hk, Dk] (already normalised and
+    scaled: the rule takes them as they come); v: [B, S, Hv, Dv] with
+    ``Hv`` a multiple of ``Hk`` (value head ``h`` reads key head ``h //
+    (Hv / Hk)``); g, beta: [B, S, Hv], ``g <= 0``. ``chunk`` None takes
+    the ladder's (``_CHUNK_LADDER``), a power of two; a length that is
+    no multiple of it is padded with steps that leave the state as it
+    is. Differentiable in all five operands."""
+    if k.shape != q.shape or v.shape[:2] != q.shape[:2] \
+            or v.shape[2] % q.shape[2] or g.shape != v.shape[:3] \
+            or beta.shape != g.shape:
+        raise ValueError(
+            f"q{q.shape} k{k.shape} v{v.shape} g{g.shape} beta{beta.shape}"
+            f": want [B,S,Hk,Dk] twice, [B,S,Hv,Dv], [B,S,Hv] twice")
+    chunk = _chunk_for(q.shape[1]) if chunk is None else int(chunk)
+    if chunk & (chunk - 1):
+        raise ValueError(f"chunk {chunk} is no power of two")
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    _note_chunks(q.shape[1], chunk)
+    return _rule(q, k, v, g, beta, chunk, bool(interpret))
+
+
+def gated_delta_rule_reference(q, k, v, g, beta):
+    """The literal recurrence, one ``lax.scan`` step a position, in
+    float32: the kernels' oracle."""
+    rep = v.shape[2] // q.shape[2]
+    q, k = (jnp.repeat(x.astype(_F32), rep, axis=2) for x in (q, k))
+    v, g, beta = (x.astype(_F32) for x in (v, g, beta))
+
+    def step(state, xs):
+        qt, kt, vt, gt, bt = xs         # [B,H,Dk] x2, [B,H,Dv], [B,H] x2
+        state = jnp.exp(gt)[..., None, None] * state
+        u = bt[..., None] * (vt - jnp.einsum("bhkv,bhk->bhv", state, kt))
+        state = state + kt[..., :, None] * u[..., None, :]
+        return state, jnp.einsum("bhkv,bhk->bhv", state, qt)
+
+    s0 = jnp.zeros((q.shape[0], v.shape[2], q.shape[3], v.shape[3]), _F32)
+    timed = lambda x: jnp.moveaxis(x, 1, 0)
+    _, out = jax.lax.scan(step, s0, tuple(
+        timed(x) for x in (q, k, v, g, beta)))
+    return jnp.moveaxis(out, 0, 1)

@@ -215,6 +215,30 @@ def test_the_selective_scan_compiles_for_v5e(chip):
         assert name in compiled.as_text()
 
 
+def test_the_gated_delta_rule_compiles_for_v5e(chip):
+    """``gdn_fwd`` and ``gdn_bwd`` at the cell's shape (one row of
+    16,384, 16 key heads serving 32 value heads of 128) and the ladder's
+    chunk: two value heads a grid step, the triangular inverse's
+    float32 products, a head's whole table of gates resident."""
+    from horovod_tpu.parallel import gated_delta as gd
+    bt, seq, key_heads, value_heads, d = 1, 16384, 16, 32, 128
+    arr = lambda *shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dt, sharding=chip)
+    gate = arr(bt, seq, value_heads, dt=jnp.float32)
+    args = (arr(bt, seq, key_heads, d), arr(bt, seq, key_heads, d),
+            arr(bt, seq, value_heads, d), gate, gate)
+
+    def loss(*x):
+        return jnp.sum(gd.gated_delta_rule(*x, interpret=False)
+                       .astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=tuple(range(5)))) \
+        .lower(*args).compile()
+    assert _kernel_calls(compiled) == 2
+    for name in ("gdn_fwd", "gdn_bwd"):
+        assert name in compiled.as_text()
+
+
 def test_d256_keeps_the_default_pair_and_d512_is_halved():
     """The D=256 cases above compile the pair the chip measured fastest
     of the ladder there (PR 27), the D=512 case the halved one."""

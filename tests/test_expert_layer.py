@@ -1,0 +1,211 @@
+"""The one expert layer of both sparse models
+(``horovod_tpu.models.glm_moe.ExpertLayer``) under both scorings
+against a dense masked sum written here, its row buffer's tiers, and
+the tier that walks the rows a slab at a time against the one-buffer
+result with every assignment forced onto held experts."""
+
+import dataclasses
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from horovod_tpu.models import glm_moe, qwen3next
+
+pytestmark = [pytest.mark.fast, pytest.mark.time_limit(120)]
+
+D, WIDTH, EXPERTS, HELD, OFFSET, K = 32, 16, 16, 4, 8, 4
+TOL = dict(rtol=3e-5, atol=3e-6)
+
+
+def config(scoring, **over):
+    """A configuration of either model at one small size."""
+    if scoring == "sigmoid":
+        return dataclasses.replace(glm_moe.GlmMoeConfig(
+            hidden_size=D, moe_intermediate_size=WIDTH,
+            n_routed_experts=EXPERTS, num_experts_per_tok=K,
+            experts_held=HELD, expert_offset=OFFSET, dtype=jnp.float32),
+            **over)
+    return dataclasses.replace(qwen3next.Qwen3NextConfig(
+        hidden_size=D, moe_intermediate_size=WIDTH,
+        shared_intermediate_size=24, n_routed_experts=EXPERTS,
+        num_experts_per_tok=K, experts_held=HELD, expert_offset=OFFSET,
+        dtype=jnp.float32), **over)
+
+
+def dense_masked_sum(cfg, p, x):
+    """Every held expert over every token, weighted by the router's
+    weight for it (zero where the token did not choose it), plus the
+    shared expert, gated where the configuration says so."""
+    xf = x.reshape(-1, D)
+    logits = xf @ p["router"]["kernel"]
+    if cfg.scoring == "softmax":
+        scores = jax.nn.softmax(logits, -1)
+        choice = scores
+    else:
+        scores = jax.nn.sigmoid(logits)
+        choice = scores + p["router"]["bias"]
+    _, chosen = jax.lax.top_k(choice, K)
+    picked = scores * jnp.sum(
+        jax.nn.one_hot(chosen, cfg.n_routed_experts), axis=1)
+    weights = cfg.routed_scaling_factor * picked \
+        / jnp.sum(picked, -1, keepdims=True)
+    swiglu = lambda g, u, d_: (nn.silu(xf @ g) * (xf @ u)) @ d_
+    shared = p["shared"]
+    y = swiglu(shared["gate"]["kernel"], shared["up"]["kernel"],
+               shared["down"]["kernel"])
+    if cfg.shared_expert_gate:
+        y = y * jax.nn.sigmoid(xf @ p["shared_gate"]["kernel"])
+    for j in range(cfg.experts_held):
+        y = y + weights[:, cfg.expert_offset + j, None] * swiglu(
+            p["experts"]["gate"][j], p["experts"]["up"][j],
+            p["experts"]["down"][j])
+    return y.reshape(x.shape)
+
+
+def layer_and_params(cfg):
+    layer = glm_moe.ExpertLayer(cfg)
+    x = jax.random.normal(jax.random.key(1), (2, 24, D))
+    return layer, layer.init(jax.random.key(2), x)["params"], x
+
+
+@pytest.mark.parametrize("scoring", ["sigmoid", "softmax"])
+def test_the_layer_is_the_dense_masked_sum_under_either_scoring(scoring):
+    cfg = config(scoring)
+    layer, p, x = layer_and_params(cfg)
+    assert ("bias" in p["router"]) == (scoring == "sigmoid")
+    assert ("shared_gate" in p) == (scoring == "softmax")
+    assert p["shared"]["up"]["kernel"].shape[1] \
+        == (WIDTH if scoring == "sigmoid" else 24)
+    if scoring == "sigmoid":
+        p["router"]["bias"] = jax.random.normal(jax.random.key(3),
+                                                (EXPERTS,)) * 0.3
+    weight = jax.random.normal(jax.random.key(4), x.shape)
+
+    def both(fn):
+        return jax.jit(jax.value_and_grad(
+            lambda p, x: jnp.sum(fn(p, x) * weight), argnums=(0, 1),
+            has_aux=False))(p, x)
+
+    got, got_grads = both(lambda p, x: layer.apply({"params": p}, x)[0])
+    want, want_grads = both(lambda p, x: dense_masked_sum(cfg, p, x))
+    np.testing.assert_allclose(got, want, atol=1e-4)   # a sum that cancels
+    for g, w in zip(jax.tree_util.tree_leaves(got_grads),
+                    jax.tree_util.tree_leaves(want_grads)):
+        np.testing.assert_allclose(g, w, rtol=1e-4,
+                                   atol=1e-5 * float(jnp.abs(w).max() + 1))
+    y, counts = jax.jit(layer.apply)({"params": p}, x)
+    np.testing.assert_allclose(y, dense_masked_sum(cfg, p, x), **TOL)
+    assert int(counts.sum()) == 48 * K and int(counts[glm_moe.DROPPED]) == 0
+
+
+@pytest.mark.parametrize("forced", [False, True],
+                         ids=["the_sound_tier", "the_walked_tier"])
+def test_a_short_buffer_adds_its_rows_into_their_tokens(forced):
+    """Two of 32 experts held: the buffer is an eighth of the 192
+    assignments, under a fifth, so the way back to token order is an
+    add of the buffer's rows and not a pick for every assignment; with
+    every token forced onto the held pair the walked tier does the same
+    a slab at a time."""
+    cfg = config("softmax", n_routed_experts=32, experts_held=2,
+                 num_experts_per_tok=K)
+    layer, p, x = layer_and_params(cfg)
+    cap = round(glm_moe.row_tiers(2, 32)[0] * 48 * K)
+    assert 48 * K > glm_moe.PICKED_ROWS_AN_ADDED_ROW * cap
+    if forced:
+        x = jnp.abs(x) + 0.1
+        kernel = np.asarray(p["router"]["kernel"]) * 0.1
+        kernel[:, OFFSET:OFFSET + 2] += 1.0
+        p["router"] = {"kernel": jnp.asarray(kernel)}
+    weight = jax.random.normal(jax.random.key(4), x.shape)
+
+    def both(fn):
+        return jax.jit(jax.grad(
+            lambda p, x: jnp.sum(fn(p, x) * weight), argnums=(0, 1)))(p, x)
+
+    got = both(lambda p, x: layer.apply({"params": p}, x)[0])
+    want = both(lambda p, x: dense_masked_sum(cfg, p, x))
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(g, w, rtol=1e-4,
+                                   atol=1e-5 * float(jnp.abs(w).max() + 1))
+    y, counts = jax.jit(layer.apply)({"params": p}, x)
+    np.testing.assert_allclose(y, dense_masked_sum(cfg, p, x), **TOL)
+    assert int(counts[glm_moe.DROPPED]) == 0
+    assert int(counts[:2].sum()) == (48 * 2 if forced else counts[:2].sum())
+    assert (int(counts[:2].sum()) > cap) == forced
+
+
+def test_a_scoring_the_layer_does_not_know_is_refused():
+    cfg = dataclasses.replace(config("softmax"), scoring="tanh")
+    with pytest.raises(ValueError, match="sigmoid or softmax"):
+        layer_and_params(cfg)
+
+
+@pytest.mark.parametrize("held, experts, want", [
+    (8, 64, (0.25, 1.0)),        # glm47flash-injit-1chip: the quarter it had
+    (32, 512, (0.125, 1.0)),     # qwen3next-injit-1chip: 20,480 rows
+    (4, 16, (0.5, 1.0)),
+    (32, 64, (1.0,)), (64, 64, (1.0,))])
+def test_the_tiers_follow_the_share_held(held, experts, want):
+    assert glm_moe.row_tiers(held, experts) == want
+
+
+@pytest.mark.parametrize("scoring", ["sigmoid", "softmax"])
+def test_the_walked_tier_is_the_one_buffer(scoring, monkeypatch):
+    """Every token's four choices forced onto the four held experts: 192
+    assignments, all held, against a first tier of 96 rows. The walked
+    tier takes them in two slabs; one buffer of 192 rows gives the
+    same, and the dense sum says both are right."""
+    cfg = config(scoring)
+    layer, p, x = layer_and_params(cfg)
+    kernel = np.asarray(p["router"]["kernel"]) * 0.1
+    if scoring == "sigmoid":
+        bias = np.zeros(EXPERTS, np.float32)
+        bias[OFFSET:OFFSET + HELD] = 10.0
+        p["router"] = {"kernel": jnp.asarray(kernel),
+                       "bias": jnp.asarray(bias)}
+    else:
+        # positive inputs and a large column: the held experts' logits
+        # lead on every token
+        x = jnp.abs(x) + 0.1
+        kernel[:, OFFSET:OFFSET + HELD] += 1.0
+        p["router"] = {"kernel": jnp.asarray(kernel)}
+    weight = jax.random.normal(jax.random.key(6), x.shape)
+
+    def run():
+        def loss(p, x):
+            y, counts = layer.apply({"params": p}, x)
+            return jnp.sum(y * weight), (y, counts)
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1),
+                                          has_aux=True))(p, x)
+
+    (_, (walked, counts)), walked_grads = run()
+    monkeypatch.setattr(glm_moe, "row_tiers", lambda held, experts: (1.0,))
+    (_, (whole, counts_one)), whole_grads = run()
+    assert int(counts[:HELD].sum()) == 48 * K == int(counts_one[:HELD].sum())
+    assert int(counts[glm_moe.ABSENT]) == 0 == int(counts[glm_moe.DROPPED])
+    np.testing.assert_allclose(walked, whole, **TOL)
+    np.testing.assert_allclose(walked, dense_masked_sum(cfg, p, x), **TOL)
+    for g, w in zip(jax.tree_util.tree_leaves(walked_grads),
+                    jax.tree_util.tree_leaves(whole_grads)):
+        np.testing.assert_allclose(g, w, rtol=1e-4,
+                                   atol=1e-5 * float(jnp.abs(w).max() + 1))
+
+
+def test_a_ragged_last_slab_loses_nothing(monkeypatch):
+    """Tiers of 0.3: 192 assignments in slabs of 58, the last one 18
+    rows long and padded."""
+    cfg = config("sigmoid")
+    monkeypatch.setattr(glm_moe, "row_tiers",
+                        lambda held, experts: (0.3, 1.0))
+    layer, p, x = layer_and_params(cfg)
+    bias = np.zeros(EXPERTS, np.float32)
+    bias[[OFFSET, OFFSET + 1, OFFSET + 3, 0]] = 10.0
+    p["router"] = dict(p["router"], bias=jnp.asarray(bias))
+    y, counts = jax.jit(layer.apply)({"params": p}, x)
+    assert int(counts[:HELD].sum()) == 48 * 3 > 58
+    assert int(counts[glm_moe.DROPPED]) == 0
+    np.testing.assert_allclose(y, dense_masked_sum(cfg, p, x), **TOL)

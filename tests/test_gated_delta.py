@@ -1,0 +1,153 @@
+"""The gated delta rule's kernels
+(``horovod_tpu/parallel/gated_delta.py``) in interpreter mode against
+the literal recurrence: forward and every gradient (q, k, v, g, beta),
+at lengths that are and are not a multiple of the chunk, with a key
+head serving one value head and two; and the chunked equations the
+kernels compute, in plain ``jax.numpy``, against the same
+recurrence."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from horovod_tpu.parallel import gated_delta as gd
+
+from .compiled import out_and_vjp
+
+pytestmark = [pytest.mark.fast, pytest.mark.time_limit(120)]
+
+
+def operands(seed, batch, seq, key_heads, value_heads, dk, dv,
+             dtype=jnp.float32):
+    """q, k as the layer hands them over (L2-normalised, q scaled), v,
+    a decay's logarithm around -0.1 and beta in (0, 1)."""
+    ks = jax.random.split(jax.random.key(seed), 5)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+    q = unit(jax.random.normal(ks[0], (batch, seq, key_heads, dk))) \
+        * dk ** -0.5
+    k = unit(jax.random.normal(ks[1], (batch, seq, key_heads, dk)))
+    v = jax.random.normal(ks[2], (batch, seq, value_heads, dv))
+    g = -0.1 * jnp.exp(jax.random.normal(ks[3], (batch, seq, value_heads)))
+    beta = jax.nn.sigmoid(
+        jax.random.normal(ks[4], (batch, seq, value_heads)))
+    return q.astype(dtype), k.astype(dtype), v.astype(dtype), g, beta
+
+
+def chunked(q, k, v, g, beta, chunk):
+    """The chunked form of the module's docstring, a value head and a
+    chunk at a time, in plain ``jax.numpy`` (float32, the inverse by
+    ``jnp.linalg``)."""
+    rep = v.shape[2] // q.shape[2]
+    q, k = (jnp.repeat(x, rep, axis=2) for x in (q, k))
+    seq = q.shape[1]
+    low = jnp.tril(jnp.ones((chunk, chunk), bool), -1)
+    low_eq = jnp.tril(jnp.ones((chunk, chunk), bool))
+
+    def head(q, k, v, g, beta):                     # [S, D] ..., [S]
+        state = jnp.zeros((q.shape[1], v.shape[1]))
+        outs = []
+        for s in range(0, seq, chunk):
+            qc, kc, vc = q[s:s + chunk], k[s:s + chunk], v[s:s + chunk]
+            bc = beta[s:s + chunk, None]
+            run = jnp.cumsum(g[s:s + chunk])
+            decay = jnp.exp(run[:, None] - run[None, :])
+            a = jnp.where(low, -bc * (kc @ kc.T) * decay, 0.0)
+            t = jnp.linalg.inv(jnp.eye(chunk) - a)
+            w = t @ (bc * jnp.exp(run)[:, None] * kc)
+            u = t @ (bc * vc)
+            v_new = u - w @ state
+            outs.append((qc * jnp.exp(run)[:, None]) @ state
+                        + jnp.where(low_eq, (qc @ kc.T) * decay, 0.0) @ v_new)
+            state = jnp.exp(run[-1]) * state \
+                + (kc * jnp.exp(run[-1] - run)[:, None]).T @ v_new
+        return jnp.concatenate(outs)
+
+    heads = jax.vmap(head, in_axes=(1, 1, 1, 1, 1), out_axes=1)
+    return jax.vmap(heads)(q, k, v, g, beta)
+
+
+def test_the_chunked_equations_are_the_recurrence():
+    args = operands(1, 2, 32, 2, 4, 8, 6)
+    with jax.default_matmul_precision("highest"):
+        np.testing.assert_allclose(
+            jax.jit(lambda *a: chunked(*a, chunk=8))(*args),
+            jax.jit(gd.gated_delta_rule_reference)(*args),
+            rtol=2e-5, atol=2e-6)
+
+
+# (case, sequence, chunk, key heads, value heads, Dk, Dv)
+CASES = [("whole_chunks", 32, 16, 2, 4, 16, 8),
+         ("a_ragged_tail", 40, 16, 2, 4, 16, 8),
+         ("one_value_head_a_key_head", 24, 8, 2, 2, 8, 16),
+         ("shorter_than_a_chunk", 11, 16, 1, 2, 8, 8)]
+
+
+@pytest.mark.parametrize("case", CASES, ids=[c[0] for c in CASES])
+def test_the_kernels_are_the_recurrence_forward_and_backward(case):
+    _, seq, chunk, hk, hv, dk, dv = case
+    args = operands(2, 2, seq, hk, hv, dk, dv)
+    weight = jax.random.normal(jax.random.key(9), (2, seq, hv, dv))
+    got, got_grads = out_and_vjp(
+        lambda *a: gd.gated_delta_rule(*a, chunk=chunk, interpret=True),
+        weight, *args)
+    want, want_grads = out_and_vjp(gd.gated_delta_rule_reference, weight,
+                                   *args)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+    for name, g, w in zip(("q", "k", "v", "g", "beta"), got_grads,
+                          want_grads):
+        np.testing.assert_allclose(
+            g, w, rtol=1e-4, atol=1e-5 * float(jnp.abs(w).max()),
+            err_msg=f"d{name}")
+
+
+def test_bfloat16_operands_keep_the_state_and_the_gates_in_float32():
+    """The model's call: q, k, v in bfloat16. The result is within a
+    bfloat16's rounding of the float32 recurrence on the same rounded
+    operands, and comes back in v's type."""
+    args = operands(3, 1, 48, 2, 4, 16, 16, jnp.bfloat16)
+    got = jax.jit(lambda *a: gd.gated_delta_rule(
+        *a, chunk=16, interpret=True))(*args)
+    want = jax.jit(gd.gated_delta_rule_reference)(*args)
+    assert got.dtype == jnp.bfloat16
+    np.testing.assert_allclose(got.astype(jnp.float32), want, rtol=0.05,
+                               atol=0.02 * float(jnp.abs(want).max()))
+
+
+def test_the_unit_lower_inverse_is_exact_for_a_nilpotent_matrix():
+    a = jnp.tril(jax.random.normal(jax.random.key(4), (32, 32)) * 0.3, -1)
+    np.testing.assert_allclose(
+        jax.jit(gd._unit_lower_inverse)(a),
+        np.linalg.inv(np.eye(32) - np.asarray(a, np.float64)),
+        rtol=2e-5, atol=2e-5)
+
+
+def test_the_ladder_gives_a_power_of_two_that_holds_a_short_sequence():
+    top = gd._CHUNK_LADDER[-1][1]
+    assert gd._chunk_for(16384) == top
+    assert gd._chunk_for(top + 1) == top
+    assert gd._chunk_for(20) == min(top, 32)
+    assert gd._chunk_for(3) == 8
+
+
+def test_a_call_the_rule_cannot_serve_is_refused():
+    q, k, v, g, beta = operands(5, 1, 16, 2, 4, 8, 8)
+    with pytest.raises(ValueError, match="power of two"):
+        gd.gated_delta_rule(q, k, v, g, beta, chunk=12)
+    with pytest.raises(ValueError, match="want"):
+        gd.gated_delta_rule(q, k, v[:, :, :3], g[..., :3], beta[..., :3])
+    with pytest.raises(ValueError, match="want"):
+        gd.gated_delta_rule(q, k, v, g[..., :2], beta)
+
+
+def test_the_chunked_forms_products_exceed_the_recurrences_count():
+    """``chunk_flops`` by hand at the model's shape (chunk 64, heads of
+    128, two value heads a key head)."""
+    c, d = 64, 128
+    shared = 2 * (2 * c * c * d)                      # K K^T, Q K^T
+    inverse = 10 * 2 * c ** 3                         # 5 squarings, 5 products
+    head = inverse + 2 * (2 * c * c * d) + 2 * (2 * c * d * d) \
+        + 2 * c * c * d + 2 * c * d * d
+    assert gd.chunk_flops(64, 128, 128, 2) == shared + 2 * head
+    per_position_and_head = gd.chunk_flops(64, 128, 128, 2) / (2 * c)
+    assert 2.1 < per_position_and_head / (7 * d * d) < 2.2
