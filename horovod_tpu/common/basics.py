@@ -389,6 +389,22 @@ def metrics() -> dict:
     return active_runtime().metrics_view()
 
 
+def note_traced(name: str, help: str, kinds: dict, labels: str = "") -> None:
+    """``name{kind="<k>"<labels>}`` = n (gauge, max) for each of
+    ``kinds``: what code that runs when a step is traced (a kernel's
+    wrapper, the head loss's forward rule) says of the call being traced
+    (docs/metrics.md), where a world with its metrics plane on is there
+    to read it."""
+    if not initialized():
+        return
+    reg = active_runtime().metrics
+    if not reg.enabled:
+        return
+    for kind, n in kinds.items():
+        reg.gauge(f'{name}{{kind="{kind}"{labels}}}', help,
+                  agg="max").set(n)
+
+
 def coordinator_threads_supported() -> bool:
     """Enqueues may come from any thread (the table is mutex-guarded),
     so multi-threaded use is always supported — unlike the reference,

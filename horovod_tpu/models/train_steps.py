@@ -115,11 +115,12 @@ def lm_train_step(model: TransformerLM, tx, mesh):
     return _loss_train_step(lm_loss_fn(model), tx, mesh)
 
 
-# The chunked head's backward rewrites the whole gradient of the table
+# The chunked head adds into the whole float32 gradient of the table
 # (512 MB at 50,016 rows of 2,560) once a chunk. Measured on v5e silicon
-# at the cell's shape (PR 31; one row of 16,384; ms a step, GB of
+# at the cell's shape (PR 31, **on the two-pass form**, whose backward
+# made each chunk's logits again; one row of 16,384; ms a step, GB of
 # temporaries): 1024 988.6, 5.15; 2048 979.3, 5.37; 4096 970.8, 5.53;
-# 8192 980.4, 7.20.
+# 8192 980.4, 7.20. Not read again on the one-pass form (PR 34).
 PHI4FLASH_HEAD_CHUNK = 4096
 
 

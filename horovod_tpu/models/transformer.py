@@ -19,6 +19,7 @@ TPU-first choices:
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable, Optional
 
 import flax.linen as nn
@@ -287,21 +288,10 @@ def lm_loss(logits, tokens):
     return -jnp.mean(ll)
 
 
-def lm_loss_from_hidden(hidden, head_kernel, tokens, chunk: int = 1024):
-    """Chunked next-token cross-entropy from pre-head hidden states.
-
-    Identical math to ``lm_loss(model(tokens), tokens)`` but the
-    [B, S, vocab] fp32 logits are never materialized: the head matmul
-    + log-softmax run per sequence chunk inside a rematerialized scan,
-    so peak logits memory is B × chunk × vocab in both forward and
-    backward (the backward recomputes each chunk's logits). At vocab
-    32k, seq 4096, batch 8 this turns 2 × 3.9 GB of fp32 logits
-    buffers into 2 × ~1 GB at chunk=1024 (scaling linearly in chunk).
-
-    hidden: [B, S, D] as returned by ``model(tokens,
-    return_hidden=True)``; head_kernel: the lm_head kernel
-    ``params["lm_head"]["kernel"]`` [D, vocab] fp32.
-    """
+def _chunked(hidden, tokens, chunk: int):
+    """The head loss's layout: hidden states, targets and the padding
+    mask as ``[chunks, B, chunk, ...]``, with the batch, the predicted
+    positions and the chunk length the scan really takes."""
     targets = tokens[:, 1:]
     hid = hidden[:, :-1]
     b, s, d = hid.shape
@@ -316,19 +306,102 @@ def lm_loss_from_hidden(hidden, head_kernel, tokens, chunk: int = 1024):
     hid = hid.reshape(b, n, chunk, d).transpose(1, 0, 2, 3)
     targets = targets.reshape(b, n, chunk).transpose(1, 0, 2)
     mask = mask.reshape(b, n, chunk).transpose(1, 0, 2)
+    return (hid, targets, mask), b, s, chunk
 
-    @jax.checkpoint
-    def chunk_ll(h, t, m):
-        logits = h.astype(jnp.float32) @ head_kernel
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        ll = jnp.take_along_axis(logp, t[..., None], axis=-1)[..., 0]
-        return jnp.sum(ll * m)
 
-    def body(carry, xs):
-        h, t, m = xs
-        return carry + chunk_ll(h, t, m), None
+def _chunk_ll(h, head_kernel, t):
+    """A chunk's targets' log-likelihoods and all its log-probabilities
+    (float32; ``log_softmax``'s arithmetic). The targets are picked from
+    the logits themselves, so that nothing but the logits has to exist
+    as an array: the log-probabilities fuse into whatever reads them."""
+    logits = h.astype(jnp.float32) @ head_kernel
+    top = jnp.max(logits, axis=-1, keepdims=True)
+    lse = jnp.log(jnp.sum(jnp.exp(logits - top), axis=-1, keepdims=True))
+    ll = jnp.take_along_axis(logits, t[..., None], axis=-1) - top - lse
+    return ll[..., 0], logits - top - lse
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def lm_loss_from_hidden(hidden, head_kernel, tokens, chunk: int = 1024):
+    """Chunked next-token cross-entropy from pre-head hidden states.
+
+    Identical math to ``lm_loss(model(tokens), tokens)`` but the
+    [B, S, vocab] fp32 logits are never materialized: the head matmul
+    + log-softmax run per sequence chunk inside a scan, so peak logits
+    memory is B × chunk × vocab. At vocab 32k, seq 4096, batch 8 this
+    turns 2 × 3.9 GB of fp32 logits buffers into 2 × ~1 GB at
+    chunk=1024 (scaling linearly in chunk).
+
+    **A differentiated call makes its gradient in the pass that makes
+    the loss** (``jax.custom_vjp``): everything the cross-entropy's
+    gradient needs is known once a chunk's logits are, so the same scan
+    forms ``dlogits = (onehot - softmax) * mask * -1/(B*S)``, emits the
+    chunk's ``dlogits @ W^T`` in the hidden's type and adds ``h^T @
+    dlogits`` into a float32 [D, vocab] carry: three vocabulary-sized
+    products a chunk, one softmax, and the backward pass only scales the
+    two kept gradients by the loss's cotangent. (Autodiff of the
+    rematerialized scan recomputed each chunk's logits and softmax in
+    the backward: four products.) An undifferentiated call forms no
+    gradient. The gradient of the table is always formed, so a caller
+    that froze the table would pay for a product it drops; reverse mode
+    once is what there is (no ``jvp``, no gradient of the gradient).
+
+    hidden: [B, S, D] as returned by ``model(tokens,
+    return_hidden=True)``; head_kernel: the lm_head kernel
+    ``params["lm_head"]["kernel"]`` [D, vocab] fp32, or a tied
+    embedding's transpose.
+    """
+    xs, b, s, _ = _chunked(hidden, tokens, chunk)
+
+    def body(total, x):
+        h, t, m = x
+        return total + jnp.sum(_chunk_ll(h, head_kernel, t)[0] * m), None
 
     with jax.named_scope("lm_head_loss"):
-        total, _ = jax.lax.scan(body, jnp.float32(0.0),
-                                (hid, targets, mask))
+        total, _ = jax.lax.scan(body, jnp.float32(0.0), xs)
     return -total / (b * s)
+
+
+def _lm_loss_fwd(hidden, head_kernel, tokens, chunk):
+    xs, b, s, chunk = _chunked(hidden, tokens, chunk)
+    vocab = head_kernel.shape[-1]
+
+    def body(carry, x):
+        total, d_kernel = carry
+        h, t, m = x
+        ll, logp = _chunk_ll(h, head_kernel, t)
+        d_logits = ((jax.nn.one_hot(t, vocab, dtype=jnp.float32)
+                     - jnp.exp(logp)) * (m * (-1.0 / (b * s)))[..., None])
+        # the two products autodiff makes of ``h.astype(f32) @ W``, at
+        # its operand types and default precision
+        d_h = jnp.einsum("bcv,dv->bcd", d_logits, head_kernel)
+        d_kernel = d_kernel + jnp.einsum(
+            "bcd,bcv->dv", h.astype(jnp.float32), d_logits)
+        return (total + jnp.sum(ll * m), d_kernel), d_h.astype(h.dtype)
+
+    # the body's three products: the logits, ``d_h`` and ``d_kernel``
+    # (docs/metrics.md)
+    from horovod_tpu.common import basics
+    basics.note_traced(
+        "hvd_head_loss_chunks",
+        "the differentiated head loss traced last: chunks a sequence, "
+        "positions a chunk and vocabulary-sized products a chunk",
+        {"chunks": xs[0].shape[0], "chunk_length": chunk, "products": 3})
+    with jax.named_scope("lm_head_loss"):
+        (total, d_kernel), d_hid = jax.lax.scan(
+            body, (jnp.float32(0.0),
+                   jnp.zeros(head_kernel.shape, jnp.float32)), xs)
+    # back through ``_chunked``: positions in order, the padding and the
+    # last position (which predicts nothing) without a gradient
+    d_hid = d_hid.transpose(1, 0, 2, 3).reshape(b, -1, hidden.shape[-1])
+    d_hidden = jnp.pad(d_hid[:, :s], ((0, 0), (0, 1), (0, 0)))
+    return -total / (b * s), (d_hidden, d_kernel.astype(head_kernel.dtype))
+
+
+def _lm_loss_bwd(chunk, kept, g):
+    d_hidden, d_kernel = kept
+    return ((d_hidden * g).astype(d_hidden.dtype),
+            (d_kernel * g).astype(d_kernel.dtype), None)
+
+
+lm_loss_from_hidden.defvjp(_lm_loss_fwd, _lm_loss_bwd)

@@ -829,25 +829,19 @@ def _note_subtiles(seq_q, seq_k, head_dim, block_q, block_k, q_offset,
     with its metrics plane on is there to read it. Traced offsets (the
     ring's) have no count at trace time and write nothing."""
     from horovod_tpu.common import basics
-    if not basics.initialized():
-        return
-    reg = basics.active_runtime().metrics
-    if not reg.enabled:
-        return
     try:
         q_offset, k_offset = int(q_offset), int(k_offset)
     except TypeError:       # a tracer: jax.errors.ConcretizationTypeError
         return
-    counts = causal_subtile_counts(
-        seq_q, seq_k, block_q, block_k,
-        _subtile_for(head_dim, block_q, block_k), q_offset, k_offset,
-        window)
-    label = "" if window is None else f',window="{window}"'
-    for kind, n in counts.items():
-        reg.gauge(
-            f'hvd_flash_subtiles{{kind="{kind}"{label}}}',
-            "sub-tiles a head of the causal flash call traced last: "
-            "computed, of those masked, and skipped", agg="max").set(n)
+    basics.note_traced(
+        "hvd_flash_subtiles",
+        "sub-tiles a head of the causal flash call traced last: "
+        "computed, of those masked, and skipped",
+        causal_subtile_counts(
+            seq_q, seq_k, block_q, block_k,
+            _subtile_for(head_dim, block_q, block_k), q_offset, k_offset,
+            window),
+        "" if window is None else f',window="{window}"')
 
 
 def _auto_block(seq: int, ladder, explicit) -> int:

@@ -119,19 +119,13 @@ def _chunk_for(seq: int) -> int:
 
 def _note_chunks(seq: int, chunk: int) -> None:
     """``hvd_gdn_chunks{kind=...}`` of the call being traced
-    (docs/metrics.md), where a world with its metrics plane on is there
-    to read it."""
+    (docs/metrics.md)."""
     from horovod_tpu.common import basics
-    if not basics.initialized():
-        return
-    reg = basics.active_runtime().metrics
-    if not reg.enabled:
-        return
-    for kind, n in (("chunks", -(-seq // chunk)), ("chunk_length", chunk)):
-        reg.gauge(
-            f'hvd_gdn_chunks{{kind="{kind}"}}',
-            "the gated delta rule traced last: chunks a sequence and "
-            "positions a chunk", agg="max").set(n)
+    basics.note_traced(
+        "hvd_gdn_chunks",
+        "the gated delta rule traced last: chunks a sequence and "
+        "positions a chunk",
+        {"chunks": -(-seq // chunk), "chunk_length": chunk})
 
 
 # -- inside a chunk ---------------------------------------------------------

@@ -94,19 +94,13 @@ def _channel_tile(channels: int):
 
 def _note_chunks(seq: int, chunk: int) -> None:
     """``hvd_ssm_scan_chunks{kind=...}`` of the call being traced
-    (docs/metrics.md), where a world with its metrics plane on is there
-    to read it."""
+    (docs/metrics.md)."""
     from horovod_tpu.common import basics
-    if not basics.initialized():
-        return
-    reg = basics.active_runtime().metrics
-    if not reg.enabled:
-        return
-    for kind, n in (("chunks", -(-seq // chunk)), ("chunk_length", chunk)):
-        reg.gauge(
-            f'hvd_ssm_scan_chunks{{kind="{kind}"}}',
-            "the selective scan traced last: chunks a sequence and "
-            "positions a chunk", agg="max").set(n)
+    basics.note_traced(
+        "hvd_ssm_scan_chunks",
+        "the selective scan traced last: chunks a sequence and "
+        "positions a chunk",
+        {"chunks": -(-seq // chunk), "chunk_length": chunk})
 
 
 def _next_states(bc_ref, t, dl, du, a_all, hs):
