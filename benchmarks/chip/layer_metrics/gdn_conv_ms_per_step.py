@@ -1,0 +1,12 @@
+"""Device time a step under ``gdn.conv``: the causal depthwise
+convolution of four taps over 8,192 channels and its SiLU, forward and
+backward."""
+from chipbench import scope_readers
+
+LAYER = "User's jitted step"
+UNIT = "ms"
+MOVES = "tokens_per_s_chip"
+
+
+def read(ctx):
+    return scope_readers.scope_ms_per_step(ctx, ("gdn.conv",))

@@ -84,7 +84,7 @@ __all__ = [
     "install_sigusr2", "serialize_trace_frame", "parse_trace_frame",
     "combine_trace_frames", "span", "interval", "NOOP_SPAN",
     "SPAN_COUNTS", "SpanRecord", "recent_spans", "spans_dropped",
-    "arm_spans",
+    "arm_spans", "spans_armed", "DEVICE_SCOPES",
 ]
 
 
@@ -325,6 +325,52 @@ SPAN_COUNTS = {
     "hvd.complete": "handles",
 }
 
+# The device side's vocabulary: scope name -> what it holds. A scope is
+# a ``jax.named_scope`` the program opens or a flax module's name that
+# stands for a part no ``named_scope`` names; either is a component of
+# the ``op_name`` every instruction of a compiled step carries, and
+# ``spmd.device_scopes`` gives an instruction the innermost one. Stable
+# names: docs/tracing.md lists them with the layer metric that reads
+# each, and the chip benchmark's readers key on them.
+DEVICE_SCOPES = {
+    "loss": "forward and backward of a step; what no scope inside it "
+            "names (residual adds, a block's own norms)",
+    "optimizer": "the update outside the backward's fusions",
+    "exchange": "the gradients' pmean and the loss's; a collective that "
+                "carries no scope",
+    "lm_head_loss": "the chunked head loss: three vocabulary-sized "
+                    "products a chunk",
+    "embed": "the token lookup and its scatter-add into the table's "
+             "gradient (flax name)",
+    "attn": "TransformerLM's attention; GlmMoeLM's outside `mla` "
+            "(flax name)",
+    "mlp": "a dense feed-forward (flax name; a scope in Phi4FlashLM)",
+    "mla": "latent attention: projections, norms, rotary, the flash "
+           "call, the output projection",
+    "moe": "the expert layer outside its named parts (flax name): the "
+           "conditional over its row buffer's tiers, the grouped "
+           "products' custom calls (the compiler leaves them no path)",
+    "moe.route": "the float32 router, top k, gate weights",
+    "moe.dispatch": "the sort by expert and the gather into expert order",
+    "moe.experts": "the three grouped products and the SwiGLU between",
+    "moe.combine": "gate weights and the gather back to token order",
+    "moe.shared": "the shared expert and its gate",
+    "mtp": "the multi-token module and its pass through the head",
+    "gdn.proj": "the Gated DeltaNet's input and output projections",
+    "gdn.conv": "its causal depthwise convolution and SiLU",
+    "gdn.rule": "L2 norms, gates, running sums and the rule's two kernels",
+    "gdn.gate": "the gated RMSNorm of the rule's output",
+    "gated_attn": "gated grouped-head attention around its flash call",
+    "ssm.proj": "Mamba's four projections",
+    "ssm.conv": "its causal depthwise convolution and SiLU",
+    "ssm.scan": "the relayouts and the selective scan's two kernels",
+    "ssm.gate": "y * silu(z)",
+    "gmu": "a gated memory unit",
+    "diff_attn": "full differential attention around its flash calls",
+    "diff_attn.window": "the same inside the sliding window",
+    "diff_attn.cross": "the same onto the kept keys and values",
+}
+
 SPAN_METRIC = "hvd_span_seconds"
 # Decades from 1 us: a handle wait that finds its handle done is a
 # microsecond, a start-up broadcast seconds.
@@ -441,6 +487,12 @@ def arm_spans(on: bool) -> None:
         _WALL_TO_MONO_S = time.monotonic() - time.time()
         _SPAN_RING = SpanRing()
     _SPANS_ON = bool(on)
+
+
+def spans_armed() -> bool:
+    """Whether the span call is armed: what else the program keeps only
+    under tracing (a step's executable, ``spmd.note_compiled``) asks."""
+    return _SPANS_ON
 
 
 def bind_span_registry(registry) -> None:

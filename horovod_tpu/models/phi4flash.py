@@ -286,11 +286,12 @@ class Block(nn.Module):
             if kind == "full":
                 k, v = k_own, v_own
         x = x + mixed
-        gate_up = _dense(cfg, 2 * cfg.intermediate_size, "fc1")(
-            _ln(cfg, "ln2")(x))
-        width = cfg.intermediate_size
-        x = x + _dense(cfg, cfg.hidden_size, "fc2")(
-            gate_up[..., width:] * nn.silu(gate_up[..., :width]))
+        h = _ln(cfg, "ln2")(x)
+        with jax.named_scope("mlp"):
+            gate_up = _dense(cfg, 2 * cfg.intermediate_size, "fc1")(h)
+            width = cfg.intermediate_size
+            x = x + _dense(cfg, cfg.hidden_size, "fc2")(
+                gate_up[..., width:] * nn.silu(gate_up[..., :width]))
         return x, memory, k, v
 
 

@@ -22,6 +22,7 @@ import optax
 
 import horovod_tpu.jax as hvd
 from horovod_tpu import spmd
+from horovod_tpu.common import trace
 from horovod_tpu.common.basics import active_runtime
 from horovod_tpu.compat import jaxshim
 from horovod_tpu.models.glm_moe import ABSENT, DROPPED, GlmMoeLM
@@ -79,15 +80,55 @@ def _apply(tx, grads, opt_state, params):
         return optax.apply_updates(params, updates), opt_state
 
 
+class _NotedLowered:
+    """A lowered step whose ``compile()`` hands JAX's own ``Compiled``
+    on and notes it with ``spmd`` (``spmd.noted_device_scopes``)."""
+
+    def __init__(self, lowered):
+        self._lowered = lowered
+
+    def compile(self, *args, **kwargs):
+        compiled = self._lowered.compile(*args, **kwargs)
+        spmd.note_compiled(compiled)
+        return compiled
+
+    def __getattr__(self, name):
+        return getattr(self._lowered, name)
+
+
+class _NotedStep:
+    """The jitted step under an armed trace: called and lowered as the
+    ``jax.jit`` object it holds."""
+
+    def __init__(self, jitted):
+        self._jitted = jitted
+
+    def __call__(self, *args, **kwargs):
+        return self._jitted(*args, **kwargs)
+
+    def lower(self, *args, **kwargs):
+        return _NotedLowered(self._jitted.lower(*args, **kwargs))
+
+    def __getattr__(self, name):
+        return getattr(self._jitted, name)
+
+
 def _jit_step(step, mesh, donate_argnums):
     """``jax.jit`` of a step whose gradients are reduced over the
     mesh's ``data`` axis: where that axis spans chips the compiler is
     asked to run the all-reduces under the backward
     (``spmd.overlap_compiler_options``); over a mesh of one the step
-    is jitted as ever."""
-    return jax.jit(
+    is jitted as ever. Where the program's spans are armed when the
+    step is built (``HOROVOD_TPU_METRICS``, a world trace) the
+    ``jax.jit`` object comes back inside a thin step whose
+    ``lower(...).compile()`` notes the executable, so that
+    ``spmd.noted_device_scopes()`` can say which scope each of its
+    device ops belongs to; not armed, it is the ``jax.jit`` object
+    itself and nothing is kept."""
+    jitted = jax.jit(
         step, donate_argnums=donate_argnums,
         compiler_options=spmd.overlap_compiler_options(mesh, AXIS))
+    return _NotedStep(jitted) if trace.spans_armed() else jitted
 
 
 def _loss_train_step(loss_fn, tx, mesh):

@@ -228,6 +228,9 @@ from horovod_tpu.spmd.zero import (  # noqa: E402
 from horovod_tpu.spmd.overlap import (  # noqa: E402
     overlap_compiler_options, collective_schedule,
 )
+from horovod_tpu.spmd.scopes import (  # noqa: E402
+    device_scopes, scope_of, note_compiled, noted_device_scopes,
+)
 
 __all__ = [
     "Average", "Sum", "Min", "Max",
@@ -237,4 +240,5 @@ __all__ = [
     "batch_sharding", "replicated_sharding", "shard_batch",
     "zero_optimizer", "zero_state_specs", "sharded_clip_by_global_norm",
     "overlap_compiler_options", "collective_schedule",
+    "device_scopes", "scope_of", "note_compiled", "noted_device_scopes",
 ]

@@ -286,14 +286,21 @@ def _lm_step_compiled(mesh4):
     return compiled, n_bytes
 
 
-def test_lm_step_reduces_its_gradients_under_the_backward(mesh4):
+@pytest.fixture(scope="module")
+def lm_step4(mesh4):
+    """The step with the options it asks for itself, compiled once for
+    every test that reads its executable (no program twice in a run)."""
+    return _lm_step_compiled(mesh4)
+
+
+def test_lm_step_reduces_its_gradients_under_the_backward(mesh4, lm_step4):
     """With the options the step asks for itself, most of the
     gradients' bytes go in asynchronous pairs that have compute ops
     scheduled between start and done, and the flash kernels are still
     in the executable."""
     from horovod_tpu import spmd
     assert spmd.overlap_compiler_options(mesh4)
-    compiled, n_bytes = _lm_step_compiled(mesh4)
+    compiled, n_bytes = lm_step4
     got = spmd.collective_schedule(compiled)
     hidden = got["async"]["overlapped"]
     assert got["sync"]["bytes"] + got["async"]["bytes"] == n_bytes + 4
@@ -301,6 +308,51 @@ def test_lm_step_reduces_its_gradients_under_the_backward(mesh4):
     assert hidden["bytes"] > got["sync"]["bytes"]
     assert hidden["bytes"] == got["async"]["bytes"]
     assert _kernel_calls(compiled) == 2 * 3     # fwd, dq, dk/dv a layer
+
+
+def test_lm_steps_device_ops_have_their_scopes(lm_step4):
+    """``spmd.device_scopes`` on the same executable: every flash call
+    is under ``attn``, what the head loss's path names under
+    ``lm_head_loss`` (the products of its one chunk here), the
+    asynchronous pairs and the synchronous all-reduces under
+    ``exchange``, and of the instructions that do the device's work
+    (fusions, kernels, loops) under a twentieth carry no scope."""
+    import re
+    from horovod_tpu import spmd
+    from horovod_tpu.spmd import overlap
+    compiled, _ = lm_step4
+    text = compiled.as_text()
+    table = spmd.device_scopes(compiled)
+    assert table == spmd.device_scopes(text)
+    comps = overlap._computations(text)
+    lines = {m.group(1): (m.group(3), line)
+             for comp, body in comps.items()
+             if comp != "ENTRY" for line in body
+             for m in [overlap._INSTR.match(line)] if m}
+
+    def multiplies(name):       # a fusion around one of the MXU's products
+        called = re.search(r"calls=%?([\w.\-]+)", lines[name][1])
+        return called and any(" convolution(" in line
+                              for line in comps[called.group(1)])
+    kernels = [n for n in table if n.startswith("flash_")]
+    assert len(kernels) == 2 * 3 and {table[n] for n in kernels} == {"attn"}
+    assert sum(n in table.backward for n in kernels) == 2 * 2
+    head = [n for n in table if "jvp(lm_head_loss)" in lines[n][1]]
+    assert {table[n] for n in head} == {"lm_head_loss"}
+    # the logits, `dlogits @ W^T` and `h^T @ dlogits`
+    assert len([n for n in head if multiplies(n)]) == 3
+    assert not [n for n in head if n in table.backward]     # one pass
+    pairs = spmd.collective_schedule(compiled)["pairs"]
+    assert len(pairs) >= 4
+    for pair in pairs:
+        assert table[pair["name"]] == "exchange"
+        assert table[pair["name"].replace("-start", "-done")] == "exchange"
+    reduces = [n for n in table if lines[n][0] == "all-reduce"]
+    assert reduces and {table[n] for n in reduces} == {"exchange"}
+    work = [n for n in table if overlap._is_compute(*lines[n])]
+    assert len(work) > 100
+    assert sum(table[n] == "" for n in work) < len(work) / 20
+    assert {"loss", "optimizer", "mlp", "embed"} <= set(table.values())
 
 
 def test_lm_step_without_the_options_reduces_synchronously(
