@@ -192,6 +192,16 @@ class TenantScheduler:
     # differential between their clocks IS the weighting mechanism.
     _IDLE_RESET_S = 0.25
 
+    # An out-weighted lane yields to its contenders for at most this
+    # long in one acquire. The decisions are rank-local, and under load
+    # two ranks' clocks drift apart: rank A then holds tenant X for Y
+    # while rank B holds Y for X, each granted cycle sits in a gather
+    # that waits for the peer's held lane, and only a hold running out
+    # ends it. A contender's own cycle takes milliseconds, so this
+    # costs the weights nothing; a quota's deferral keeps the whole of
+    # ``max_hold_s``.
+    _INTERLEAVE_HOLD_S = 0.1
+
     def __init__(self):
         self._cv = lockdep.condition("tenancy.TenantScheduler._lock")
         self._lanes: List[_Lane] = []
@@ -290,7 +300,8 @@ class TenantScheduler:
                             o.want and o.vtime < lane.vtime - 1e-12
                             and self._solvent_at(o, now)
                             for o in self._lanes if o is not lane)
-                        if not contender:
+                        if not contender \
+                                or now - t0 >= self._INTERLEAVE_HOLD_S:
                             break
                         # Out-weighted: wait for a competitor's grant
                         # to move the clock (notify below), re-check

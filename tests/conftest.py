@@ -6,10 +6,12 @@ TPU hardware (the driver separately dry-runs the multi-chip path; see
 __graft_entry__.py)."""
 
 import faulthandler
+import gc
 import os
 import signal
 import sys
 import tempfile
+import time
 from collections import defaultdict
 
 # No source is compiled twice in a run either. The image's site-packages
@@ -26,6 +28,15 @@ sys.pycache_prefix = os.environ.setdefault(
                  ".pycache"))
 os.environ.pop("PYTHONDONTWRITEBYTECODE", None)
 sys.dont_write_bytecode = False
+
+# This process lives for a quarter of an hour and keeps what it makes
+# (every test's report, jax's tracing caches, the compiled programs:
+# 2.7 million objects by the end). Python's cyclic collector, at its
+# default of a young collection every 700 allocations, ran 21,538 times
+# in a run of the suite and took 38 s of it walking them. Tracing
+# allocates by the hundred thousand, so the collector comes when that
+# many are new.
+gc.set_threshold(100_000, 20, 20)
 
 os.environ["JAX_PLATFORMS"] = "cpu"
 _flags = os.environ.get("XLA_FLAGS", "")
@@ -160,6 +171,12 @@ def whole_compiler():
 # -- every test has a limit of its own --------------------------------------
 
 TEST_LIMIT_S = 180.0
+# The clock the whole suite has: the `timeout` of the command the driver
+# runs after every PR (`commands` in /root/TESTS_LAST_RUN.json). A run
+# cut there counts only as far as it got, so a run says how much of it
+# its tests used (ROADMAP.md T1 has the budget).
+SUITE_LIMIT_S = 1470.0
+_RUN_STARTED = time.monotonic()     # collection is on the clock too
 
 
 def _all_stacks() -> str:
@@ -206,6 +223,35 @@ def _sigterm_as_found():
     selfop._handler_installed = False
 
 
+# -- the frameworks' bytecode is written beside the first tests --------------
+
+@pytest.fixture(scope="session", autouse=True)
+def _frameworks_bytecode():
+    """On a fresh tree the first interpreter to import TensorFlow, Keras
+    or torch compiles some thousands of modules from source and writes
+    them to the run's bytecode cache (10 to 25 s of one core, in the
+    middle of ``test_adapters.py`` or twice at once in a world's two
+    ranks). The whole run has minutes of other tests before it needs
+    them, so an interpreter of its own imports them at the start, beside
+    those tests; with the cache already written nothing is started."""
+    import importlib.util
+    import subprocess
+    cold = [name for name in ("tensorflow", "keras", "torch")
+            if (spec := importlib.util.find_spec(name)) and spec.origin
+            and not os.path.exists(importlib.util.cache_from_source(
+                spec.origin))]
+    if not cold:
+        yield
+        return
+    proc = subprocess.Popen(
+        [sys.executable, "-c", "import " + ", ".join(cold)],
+        env={**os.environ, "KERAS_BACKEND": "tensorflow"},
+        stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL)
+    yield
+    proc.kill()
+    proc.wait()
+
+
 # -- where the time went ----------------------------------------------------
 
 def pytest_terminal_summary(terminalreporter):
@@ -219,6 +265,11 @@ def pytest_terminal_summary(terminalreporter):
     def largest_first(seconds):
         return sorted(seconds.items(), key=lambda kv: -kv[1])
 
+    wall, tests = time.monotonic() - _RUN_STARTED, sum(by_file.values())
+    terminalreporter.section("the suite's clock")
+    terminalreporter.write_line(
+        f"{wall:.1f} s of wall, {tests:.1f} s in tests: "
+        f"{tests / SUITE_LIMIT_S:.1%} of the {SUITE_LIMIT_S:g} s limit")
     terminalreporter.section("seconds by file (set-up + call + teardown)")
     for name, s in largest_first(by_file):
         terminalreporter.write_line(f"{s:9.2f} s  {name}")

@@ -4354,9 +4354,21 @@ def scenario_int8_codec_parity(hvd, rank, size):
         np.save(out_path, np.concatenate([o.reshape(-1) for o in outs]))
 
 
+# What a rank prints when it has run one of several scenarios to its
+# end (``tests/test_multiprocess.py`` ``run_scenarios`` reads it).
+SCENARIO_DONE = "scenario done:"
+
+
 def main():
-    scenario, rank, size, port = (sys.argv[1], int(sys.argv[2]),
-                                  int(sys.argv[3]), int(sys.argv[4]))
+    """``python -m tests.mp_scenarios NAME[,NAME...] RANK SIZE
+    PORT[,PORT...]``: the scenarios in turn in this interpreter, each
+    between its own ``hvd.init()`` and ``hvd.shutdown()`` on its own
+    port, a line on stdout for each that ran to its end. The first
+    that raises ends the process: the ones after it never run, and
+    print nothing."""
+    scenarios, ports = sys.argv[1].split(","), sys.argv[4].split(",")
+    rank, size = int(sys.argv[2]), int(sys.argv[3])
+    assert len(scenarios) == len(ports), (scenarios, ports)
     # Hard in-process deadline (set by run_scenario slightly under its
     # subprocess timeout): a deadlocked rank dumps every thread's stack
     # and exits nonzero, so a regression that reintroduces a hang fails
@@ -4369,16 +4381,18 @@ def main():
     os.environ["HOROVOD_RANK"] = str(rank)
     os.environ["HOROVOD_SIZE"] = str(size)
     os.environ["HOROVOD_CONTROLLER_ADDR"] = "127.0.0.1"
-    os.environ["HOROVOD_CONTROLLER_PORT"] = str(port)
     os.environ.setdefault("HOROVOD_CYCLE_TIME", "1")
     import horovod_tpu as hvd
-    fn = globals()[f"scenario_{scenario}"]
-    if not getattr(fn, "no_auto_init", False):
-        hvd.init()
-    try:
-        fn(hvd, rank, size)
-    finally:
-        hvd.shutdown()
+    for scenario, port in zip(scenarios, ports):
+        os.environ["HOROVOD_CONTROLLER_PORT"] = port
+        fn = globals()[f"scenario_{scenario}"]
+        if not getattr(fn, "no_auto_init", False):
+            hvd.init()
+        try:
+            fn(hvd, rank, size)
+        finally:
+            hvd.shutdown()
+        print(SCENARIO_DONE, scenario, flush=True)
 
 
 if __name__ == "__main__":

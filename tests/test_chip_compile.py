@@ -15,6 +15,7 @@ compiles skip the optimiser (``tests/conftest.py``); what these tests
 assert on is the TPU compiler's own work, so this file keeps it whole.
 """
 
+import functools
 import os
 
 import pytest
@@ -94,30 +95,44 @@ def _kernel_calls(compiled) -> int:
         'custom_call_target="tpu_custom_call"')
 
 
+@pytest.fixture(scope="module")
+def flash_compiled(chip):
+    """``flash_compiled(which, BH, S, D, block_q, block_k, causal)``:
+    the forward (``"fwd"``) or the backward (``"bwd"``) compiled for
+    the chip, once for every case that names the same program (the two
+    cells' causal shapes are cases of the ladder's tests too)."""
+    @functools.cache
+    def compiled(which, bh, seq, d, block_q, block_k, causal):
+        qkv = jax.ShapeDtypeStruct((bh, seq, d), jnp.bfloat16,
+                                   sharding=chip)
+        stat = jax.ShapeDtypeStruct((bh, 1, seq), jnp.float32,
+                                    sharding=chip)
+        offsets = jax.ShapeDtypeStruct((2,), jnp.int32, sharding=chip)
+        tiles = dict(causal=causal, block_q=block_q, block_k=block_k,
+                     interpret=False)
+        if which == "fwd":
+            return _flash_bhsd.lower(
+                qkv, qkv, qkv, offsets, **tiles).compile()
+        return _flash_bwd_bhsd.lower(
+            qkv, qkv, qkv, qkv, stat, stat, offsets, **tiles).compile()
+    return compiled
+
+
 @pytest.mark.parametrize("bh,seq,d,block_q,block_k",
                          [c[1:] for c in _CASES],
                          ids=[c[0] for c in _CASES])
-def test_flash_forward_compiles_for_v5e(chip, bh, seq, d, block_q,
-                                        block_k):
-    qkv = jax.ShapeDtypeStruct((bh, seq, d), jnp.bfloat16, sharding=chip)
-    offsets = jax.ShapeDtypeStruct((2,), jnp.int32, sharding=chip)
-    compiled = _flash_bhsd.lower(
-        qkv, qkv, qkv, offsets, causal=True, block_q=block_q,
-        block_k=block_k, interpret=False).compile()
+def test_flash_forward_compiles_for_v5e(flash_compiled, bh, seq, d,
+                                        block_q, block_k):
+    compiled = flash_compiled("fwd", bh, seq, d, block_q, block_k, True)
     assert _kernel_calls(compiled) == 1
 
 
 @pytest.mark.parametrize("bh,seq,d,block_q,block_k",
                          [c[1:] for c in _CASES],
                          ids=[c[0] for c in _CASES])
-def test_flash_backward_compiles_for_v5e(chip, bh, seq, d, block_q,
-                                         block_k):
-    qkv = jax.ShapeDtypeStruct((bh, seq, d), jnp.bfloat16, sharding=chip)
-    stat = jax.ShapeDtypeStruct((bh, 1, seq), jnp.float32, sharding=chip)
-    offsets = jax.ShapeDtypeStruct((2,), jnp.int32, sharding=chip)
-    compiled = _flash_bwd_bhsd.lower(
-        qkv, qkv, qkv, qkv, stat, stat, offsets, causal=True,
-        block_q=block_q, block_k=block_k, interpret=False).compile()
+def test_flash_backward_compiles_for_v5e(flash_compiled, bh, seq, d,
+                                         block_q, block_k):
+    compiled = flash_compiled("bwd", bh, seq, d, block_q, block_k, True)
     assert _kernel_calls(compiled) == 2  # dq, and dk/dv
 
 
@@ -133,8 +148,8 @@ _CELL_SHAPES = [
                          ids=["causal", "noncausal"])
 @pytest.mark.parametrize("bh,seq,d", [c[1:] for c in _CELL_SHAPES],
                          ids=[c[0] for c in _CELL_SHAPES])
-def test_cells_kernels_compile_with_their_subtiles(chip, bh, seq, d,
-                                                   causal):
+def test_cells_kernels_compile_with_their_subtiles(flash_compiled, bh,
+                                                   seq, d, causal):
     """Forward, dq and dk/dv at the two cells' shapes, the sub-tile
     loops on runtime offsets included: a VMEM or Mosaic refusal of the
     chosen sub-tile shows here, before the chip is asked."""
@@ -142,15 +157,8 @@ def test_cells_kernels_compile_with_their_subtiles(chip, bh, seq, d,
     sub_q, sub_k = _subtile_for(d, block_q, block_k)
     assert block_q % sub_q == 0 and block_k % sub_k == 0
     assert (sub_q, sub_k) != (block_q, block_k)
-    qkv = jax.ShapeDtypeStruct((bh, seq, d), jnp.bfloat16, sharding=chip)
-    stat = jax.ShapeDtypeStruct((bh, 1, seq), jnp.float32, sharding=chip)
-    offsets = jax.ShapeDtypeStruct((2,), jnp.int32, sharding=chip)
-    fwd = _flash_bhsd.lower(
-        qkv, qkv, qkv, offsets, causal=causal, block_q=block_q,
-        block_k=block_k, interpret=False).compile()
-    bwd = _flash_bwd_bhsd.lower(
-        qkv, qkv, qkv, qkv, stat, stat, offsets, causal=causal,
-        block_q=block_q, block_k=block_k, interpret=False).compile()
+    fwd, bwd = (flash_compiled(which, bh, seq, d, block_q, block_k, causal)
+                for which in ("fwd", "bwd"))
     assert (_kernel_calls(fwd), _kernel_calls(bwd)) == (1, 2)
     for text, names in ((fwd.as_text(), ["flash_fwd"]),
                         (bwd.as_text(), ["flash_bwd_dq", "flash_bwd_dkv"])):
@@ -260,7 +268,7 @@ def _flash(q, k, v, causal=True):
     return flash_attention(q, k, v, causal=causal, interpret=False)
 
 
-def _lm_step_compiled(mesh4):
+def _lm_step_compiled(mesh4, num_layers=2):
     """``lm_train_step`` of a two-layer ``TransformerLM`` cut to half
     the cell's width (d 1024: its MLP, head and embedding leaves are
     16.8 MB each, its attention leaves 4.2 MB), compiled for the mesh."""
@@ -269,7 +277,7 @@ def _lm_step_compiled(mesh4):
     from horovod_tpu.models.transformer import (
         TransformerConfig, TransformerLM)
     model = TransformerLM(TransformerConfig(
-        vocab_size=4096, num_layers=2, num_heads=8, head_dim=128,
+        vocab_size=4096, num_layers=num_layers, num_heads=8, head_dim=128,
         max_seq_len=256, dtype=jnp.bfloat16, attention_fn=_flash))
     tx = train_steps.distributed_sgd()
     params = jax.eval_shape(
@@ -359,24 +367,27 @@ def test_lm_step_without_the_options_reduces_synchronously(
         mesh4, monkeypatch):
     """The same step without the options: every all-reduce is
     synchronous. If a libtpu changes that default, this fails and the
-    options can go."""
+    options can go. (One layer shows it: the schedule alone is read.)"""
     from horovod_tpu import spmd
     monkeypatch.setattr(spmd, "overlap_compiler_options",
                         lambda mesh, axis="data": None)
-    compiled, n_bytes = _lm_step_compiled(mesh4)
+    compiled, n_bytes = _lm_step_compiled(mesh4, num_layers=1)
     got = spmd.collective_schedule(compiled)
     assert got["async"]["count"] == 0
     assert got["sync"]["bytes"] == n_bytes + 4
 
 
 def _resnet_step(mesh4):
-    """``resnet_train_step`` at ResNet-18's depth (the step is the
-    cell's; the 50-layer model compiles in 22 s), 32 x 32 images."""
+    """``resnet_train_step`` of ResNet-18's four stages at one block
+    each (the step is the cell's; what is read is that every gradient
+    is reduced, which a block a stage shows; the 50-layer model
+    compiles in 22 s, ResNet-18 in 9), 32 x 32 images."""
     from horovod_tpu import spmd
     from horovod_tpu.models import train_steps
-    from horovod_tpu.models.resnet import ResNet18
-    model = ResNet18(num_classes=train_steps.RESNET_CLASSES,
-                     dtype=jnp.bfloat16, axis_name=train_steps.AXIS)
+    from horovod_tpu.models.resnet import BasicBlock, ResNet
+    model = ResNet(stage_sizes=[1, 1, 1, 1], block_cls=BasicBlock,
+                   num_classes=train_steps.RESNET_CLASSES,
+                   dtype=jnp.bfloat16, axis_name=train_steps.AXIS)
     tx = train_steps.distributed_sgd()
     variables = jax.eval_shape(
         lambda k: model.init(k, jnp.zeros((1, 32, 32, 3), jnp.bfloat16),

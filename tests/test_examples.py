@@ -7,11 +7,14 @@ Each example runs in its own subprocess: examples own their world
 (hvd.init/shutdown) and some need a virtual multi-device CPU platform,
 which must be configured before jax imports."""
 
+import json
 import os
 import subprocess
 import sys
 
 import pytest
+
+from tests.test_multiprocess import thread_pool_env
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 EX = os.path.join(REPO, "examples")
@@ -21,7 +24,39 @@ pytestmark = pytest.mark.time_limit(450)
 
 
 def _run(script, *args, n_devices=1, timeout=420, extra_env=None):
-    env = dict(os.environ)
+    return _python([os.path.join(EX, script), *args], script, n_devices,
+                   timeout, extra_env)
+
+
+# The example's ``__main__`` once for each list of arguments, a line
+# between two runs' output.
+_NEXT_RUN = "-- the example again --"
+_IN_TURN = f"""
+import json, runpy, sys
+script, runs = sys.argv[1], json.loads(sys.argv[2])
+for n, argv in enumerate(runs):
+    if n:
+        print({_NEXT_RUN!r}, flush=True)
+    sys.argv = [script, *argv]
+    runpy.run_path(script, run_name="__main__")
+"""
+
+
+def _run_in_turn(script, *runs, **kwargs):
+    """The example once for each of ``runs`` (a list of arguments each),
+    in turn in ONE interpreter, which imports the example's framework
+    once; each run's stdout. Each run is the example's whole
+    ``__main__``, from its ``hvd.init()`` to its ``hvd.shutdown()``."""
+    path = os.path.join(EX, script)
+    out = _python(["-c", _IN_TURN, path, json.dumps(runs)], script,
+                  **kwargs)
+    outs = out.split(_NEXT_RUN + "\n")
+    assert len(outs) == len(runs), out
+    return outs
+
+
+def _python(argv, script, n_devices=1, timeout=420, extra_env=None):
+    env = {**os.environ, **thread_pool_env(1)}
     env["JAX_PLATFORMS"] = "cpu"
     env["HOROVOD_CYCLE_TIME"] = "1"
     if extra_env:
@@ -35,7 +70,7 @@ def _run(script, *args, n_devices=1, timeout=420, extra_env=None):
     ).strip()
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
     proc = subprocess.run(
-        [sys.executable, os.path.join(EX, script), *args],
+        [sys.executable, *argv],
         capture_output=True, text=True, timeout=timeout, env=env,
         cwd=REPO)
     assert proc.returncode == 0, (
@@ -98,24 +133,26 @@ def test_zero_fsdp():
     assert "ZeRO-1" in out and "FSDP" in out
 
 
-@pytest.mark.time_limit(870)  # the example twice
 def test_torch_imagenet_resnet50(tmp_path):
     """ImageNet-scale torch example (fp16 allreduce + gradient
-    accumulation + warmup + checkpoint/resume), smoke-sized."""
+    accumulation + warmup + checkpoint/resume), smoke-sized. The
+    resume needs a run that starts beside a checkpoint, so the example
+    runs twice; one interpreter runs both (torch and jax imported
+    once)."""
     ckpt = str(tmp_path / "checkpoint-{epoch}.pth.tar")
-    out = _run("torch_imagenet_resnet50.py", "--epochs", "1",
-               "--steps-per-epoch", "1", "--batch-size", "2",
-               "--batches-per-allreduce", "2", "--image-size", "32",
-               "--num-classes", "10", "--width", "8",
-               "--fp16-allreduce", "--checkpoint-format", ckpt)
+    out, resumed = _run_in_turn(
+        "torch_imagenet_resnet50.py",
+        ["--epochs", "1", "--steps-per-epoch", "1", "--batch-size", "2",
+         "--batches-per-allreduce", "2", "--image-size", "32",
+         "--num-classes", "10", "--width", "8", "--fp16-allreduce",
+         "--checkpoint-format", ckpt],
+        # resume path: epoch 1 checkpoint found -> trains epoch 2 only
+        ["--epochs", "2", "--steps-per-epoch", "1", "--batch-size", "2",
+         "--image-size", "32", "--num-classes", "10", "--width", "8",
+         "--checkpoint-format", ckpt])
     assert "loss" in out.lower()
     assert os.path.exists(ckpt.format(epoch=1))
-    # resume path: epoch 1 checkpoint found -> trains epoch 2 only
-    out = _run("torch_imagenet_resnet50.py", "--epochs", "2",
-               "--steps-per-epoch", "1", "--batch-size", "2",
-               "--image-size", "32", "--num-classes", "10",
-               "--width", "8", "--checkpoint-format", ckpt)
-    assert "epoch 2/2" in out and "epoch 1/2" not in out
+    assert "epoch 2/2" in resumed and "epoch 1/2" not in resumed
 
 
 @pytest.mark.slow

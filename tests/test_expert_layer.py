@@ -68,7 +68,12 @@ def dense_masked_sum(cfg, p, x):
 def layer_and_params(cfg):
     layer = glm_moe.ExpertLayer(cfg)
     x = jax.random.normal(jax.random.key(1), (2, 24, D))
-    return layer, layer.init(jax.random.key(2), x)["params"], x
+    return layer, jax.jit(layer.init)(jax.random.key(2), x)["params"], x
+
+
+def dense(cfg, p, x):
+    """``dense_masked_sum`` as one program."""
+    return jax.jit(lambda p, x: dense_masked_sum(cfg, p, x))(p, x)
 
 
 @pytest.mark.parametrize("scoring", ["sigmoid", "softmax"])
@@ -97,7 +102,7 @@ def test_the_layer_is_the_dense_masked_sum_under_either_scoring(scoring):
         np.testing.assert_allclose(g, w, rtol=1e-4,
                                    atol=1e-5 * float(jnp.abs(w).max() + 1))
     y, counts = jax.jit(layer.apply)({"params": p}, x)
-    np.testing.assert_allclose(y, dense_masked_sum(cfg, p, x), **TOL)
+    np.testing.assert_allclose(y, dense(cfg, p, x), **TOL)
     assert int(counts.sum()) == 48 * K and int(counts[glm_moe.DROPPED]) == 0
 
 
@@ -132,7 +137,7 @@ def test_a_short_buffer_adds_its_rows_into_their_tokens(forced):
         np.testing.assert_allclose(g, w, rtol=1e-4,
                                    atol=1e-5 * float(jnp.abs(w).max() + 1))
     y, counts = jax.jit(layer.apply)({"params": p}, x)
-    np.testing.assert_allclose(y, dense_masked_sum(cfg, p, x), **TOL)
+    np.testing.assert_allclose(y, dense(cfg, p, x), **TOL)
     assert int(counts[glm_moe.DROPPED]) == 0
     assert int(counts[:2].sum()) == (48 * 2 if forced else counts[:2].sum())
     assert (int(counts[:2].sum()) > cap) == forced
@@ -188,7 +193,7 @@ def test_the_walked_tier_is_the_one_buffer(scoring, monkeypatch):
     assert int(counts[:HELD].sum()) == 48 * K == int(counts_one[:HELD].sum())
     assert int(counts[glm_moe.ABSENT]) == 0 == int(counts[glm_moe.DROPPED])
     np.testing.assert_allclose(walked, whole, **TOL)
-    np.testing.assert_allclose(walked, dense_masked_sum(cfg, p, x), **TOL)
+    np.testing.assert_allclose(walked, dense(cfg, p, x), **TOL)
     for g, w in zip(jax.tree_util.tree_leaves(walked_grads),
                     jax.tree_util.tree_leaves(whole_grads)):
         np.testing.assert_allclose(g, w, rtol=1e-4,
@@ -208,4 +213,4 @@ def test_a_ragged_last_slab_loses_nothing(monkeypatch):
     y, counts = jax.jit(layer.apply)({"params": p}, x)
     assert int(counts[:HELD].sum()) == 48 * 3 > 58
     assert int(counts[glm_moe.DROPPED]) == 0
-    np.testing.assert_allclose(y, dense_masked_sum(cfg, p, x), **TOL)
+    np.testing.assert_allclose(y, dense(cfg, p, x), **TOL)

@@ -13,6 +13,7 @@ import numpy as np
 import pytest
 
 from .chip_bench import _paths  # noqa: F401  (makes chipbench importable)
+from .compiled import weights_under
 from chipbench import check, harness, weights
 
 from horovod_tpu.models import glm_moe, train_steps
@@ -138,8 +139,7 @@ def test_the_shares_add_up_to_the_uncut_layer(model, x):
     e, held = SZ["experts"], SZ["experts_held"]
     whole = dict(SZ, experts_held=e, expert_offset=0)
     shapes, fans = FAMILY.param_shapes(whole)
-    p = weights.make_tree(shapes, fans, seed=13, stream=0)[
-        "params"]["block_1"]["moe"]
+    p = weights_under(shapes, fans, 13, 0, "params/block_1/moe")
     want = jax.jit(FAMILY.reference_fns(whole)["expert_layer"])(p, x)
     shared = jax.jit(FAMILY._swiglu)(p["shared"], x)
     total = shared

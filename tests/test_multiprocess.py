@@ -9,6 +9,8 @@ import socket
 import subprocess
 import sys
 import tempfile
+import threading
+import time
 
 import pytest
 
@@ -21,6 +23,22 @@ def _free_port() -> int:
     port = s.getsockname()[1]
     s.close()
     return port
+
+
+def thread_pool_env(processes: int) -> dict:
+    """torch's OpenMP pool for each of ``processes`` interpreters that
+    run at once: its share of the cores this run may use, and two
+    threads at most. Left alone the pool is a thread a core in EVERY
+    rank, whose workers spin at each barrier; on a host whose cores are
+    taken a spin waits out somebody else's time slice
+    (``torch_synthetic_benchmark`` beside eight busy loops: 26 to 34 s
+    at eight threads, 4 s at two), and at the suite's toy shapes the
+    pool is the slower alone too (``torch_mnist`` 5.9 s at eight, 4.1 s
+    at two). TensorFlow's pools neither spin nor gain: they stay as
+    they are. The caller's own setting wins."""
+    share = str(max(1, min(2, len(os.sched_getaffinity(0)) // processes)))
+    return {name: os.environ.get(name, share)
+            for name in ("OMP_NUM_THREADS", "MKL_NUM_THREADS")}
 
 
 def _base_env(extra_env=None):
@@ -49,30 +67,37 @@ def _base_env(extra_env=None):
     return base
 
 
-def run_scenario(scenario: str, size: int, timeout: float = 90.0,
-                 extra_env=None, per_rank_env=None, expect_rc=None):
-    """``expect_rc`` maps rank -> expected returncode for ranks that
-    are SUPPOSED to die (fault-injection victims: a SIGKILL'd rank
-    exits -9, not 0). Every other rank must exit 0.
-
-    Each rank also gets a hard in-process deadline a bit under
-    ``timeout`` (HOROVOD_TEST_DEADLINE -> faulthandler alarm in
-    mp_scenarios.main): a deadlocked rank self-reports with thread
+def _spawn_world(scenarios, size, timeout, extra_env=None,
+                 per_rank_env=None):
+    """One interpreter a rank, each running ``scenarios`` in turn (a
+    port for each). Each rank also gets a hard in-process deadline a
+    bit under ``timeout`` (HOROVOD_TEST_DEADLINE -> faulthandler alarm
+    in mp_scenarios.main): a deadlocked rank self-reports with thread
     stacks instead of relying on this parent's kill."""
-    port = _free_port()
-    procs = []
-    base = _base_env(extra_env)
+    ports = ",".join(str(_free_port()) for _ in scenarios)
+    base = _base_env({**thread_pool_env(size), **(extra_env or {})})
     base.setdefault("HOROVOD_TEST_DEADLINE",
                     str(max(5.0, timeout - 5.0)))
+    procs = []
     for rank in range(size):
         env = dict(base)
         if per_rank_env:
             env.update(per_rank_env(rank))
         procs.append(subprocess.Popen(
-            [sys.executable, "-m", "tests.mp_scenarios", scenario,
-             str(rank), str(size), str(port)],
+            [sys.executable, "-m", "tests.mp_scenarios",
+             ",".join(scenarios), str(rank), str(size), ports],
             cwd=REPO, env=env,
             stdout=subprocess.PIPE, stderr=subprocess.STDOUT))
+    return procs
+
+
+def run_scenario(scenario: str, size: int, timeout: float = 90.0,
+                 extra_env=None, per_rank_env=None, expect_rc=None):
+    """``expect_rc`` maps rank -> expected returncode for ranks that
+    are SUPPOSED to die (fault-injection victims: a SIGKILL'd rank
+    exits -9, not 0). Every other rank must exit 0."""
+    procs = _spawn_world([scenario], size, timeout, extra_env,
+                         per_rank_env)
     failures = []
     for rank, p in enumerate(procs):
         try:
@@ -85,8 +110,72 @@ def run_scenario(scenario: str, size: int, timeout: float = 90.0,
         want = 0 if expect_rc is None else expect_rc.get(rank, 0)
         if p.returncode != want:
             failures.append((rank, p.returncode, out.decode()))
-    assert not failures, "\n".join(
-        f"--- rank {r} exited {rc} ---\n{o}" for r, rc, o in failures)
+    assert not failures, _ranks_said(failures)
+
+
+def _ranks_said(ranks):
+    return "\n".join(
+        f"--- rank {r} exited {rc} ---\n{o}" for r, rc, o in ranks)
+
+
+class ScenarioResults:
+    """What one world made of several scenarios: ``check(name)`` is the
+    assertion ``run_scenario(name, ...)`` would have made."""
+
+    def __init__(self, scenarios, outputs):
+        from tests.mp_scenarios import SCENARIO_DONE
+        self._outputs = outputs     # rank -> (returncode, text)
+        said = [text.splitlines() for _, text in outputs]
+        # in the world's order: the first is the one that ended the
+        # world, those after it never ran
+        self._unfinished = [
+            s for s in scenarios
+            if not all(f"{SCENARIO_DONE} {s}" in lines for lines in said)]
+
+    def check(self, scenario):
+        if scenario not in self._unfinished:
+            return
+        first = self._unfinished[0]
+        why = (f"scenario {scenario} failed" if scenario == first else
+               f"scenario {scenario} did not run: {first}, earlier in "
+               f"the same world, ended it")
+        raise AssertionError(why + "\n" + _ranks_said(
+            (r, rc, o) for r, (rc, o) in enumerate(self._outputs)))
+
+
+def run_scenarios(scenarios, size: int, timeout: float = 170.0):
+    """``scenarios`` in turn in ONE world's interpreters, so that what
+    they import (a framework, seconds of it) is imported once a rank;
+    each scenario still runs between its own ``hvd.init()`` and
+    ``hvd.shutdown()``, and ``timeout`` (under a test's own limit) is
+    for all of them together. Returns the ``ScenarioResults`` the tests
+    read; nothing is asserted here, so each test fails or passes by its
+    own scenario."""
+    procs = _spawn_world(scenarios, size, timeout)
+    outputs = [None] * size
+
+    def drain(rank, p):
+        out, _ = p.communicate()
+        outputs[rank] = (p.returncode, out.decode())
+
+    threads = [threading.Thread(target=drain, args=(r, p))
+               for r, p in enumerate(procs)]
+    for t in threads:
+        t.start()
+    deadline = time.monotonic() + timeout
+    while any(p.poll() is None for p in procs) \
+            and time.monotonic() < deadline:
+        if any(p.returncode for p in procs):
+            # a rank that failed leaves its peers waiting for it at the
+            # next rendezvous: what they had to say they soon have said
+            deadline = min(deadline, time.monotonic() + 10.0)
+        time.sleep(0.05)
+    for p in procs:
+        if p.poll() is None:
+            p.kill()
+    for t in threads:
+        t.join()
+    return ScenarioResults(list(scenarios), outputs)
 
 
 @pytest.mark.parametrize("size", [2, 4])
@@ -284,52 +373,76 @@ def test_stall_shutdown():
                    "HOROVOD_STALL_SHUTDOWN_TIME_SECONDS": "2"})
 
 
-def test_torch_distributed_optimizer():
-    run_scenario("torch_optimizer", 2, timeout=120.0)
+# One world a framework, not one a test: the two-rank scenarios of a
+# framework run in turn in the same two interpreters, which import it
+# once (torch 2 s a rank, TensorFlow 5.5 s with bytecode and 10 s
+# without, Keras on top of it). The first test to ask starts the world;
+# each test reads its own scenario's result. Scenarios that need an
+# environment or a size of their own keep a world of their own.
+
+@pytest.fixture(scope="module")
+def torch_world():
+    return run_scenarios(
+        ["torch_optimizer", "torch_allreduce_grad", "torch_adam_state",
+         "torch_opt_state_asymmetric"], 2)
+
+
+@pytest.fixture(scope="module")
+def tensorflow_world():
+    # tf_broadcast_hook turns eager execution off for its process, and
+    # so goes last
+    return run_scenarios(
+        ["keras_optimizer", "tf_tape", "tf_allreduce_grad",
+         "tf_sparse_as_dense", "tfkeras_facade", "tf_broadcast_hook"],
+        2)
+
+
+def test_torch_distributed_optimizer(torch_world):
+    torch_world.check("torch_optimizer")
 
 
 def test_jax_adapter_host_path():
     run_scenario("jax_adapter", 2)
 
 
-def test_torch_allreduce_grad():
+def test_torch_allreduce_grad(torch_world):
     """Backward through hvd.allreduce matches the reference's autograd
     semantics."""
-    run_scenario("torch_allreduce_grad", 2, timeout=120.0)
+    torch_world.check("torch_allreduce_grad")
 
 
-def test_torch_adam_state_broadcast():
-    run_scenario("torch_adam_state", 2, timeout=120.0)
+def test_torch_adam_state_broadcast(torch_world):
+    torch_world.check("torch_adam_state")
 
 
-def test_torch_opt_state_asymmetric_broadcast():
+def test_torch_opt_state_asymmetric_broadcast(torch_world):
     """Checkpoint-restore shape: only rank 0 has optimizer state; the
     broadcast must materialize worker state instead of hanging."""
-    run_scenario("torch_opt_state_asymmetric", 2, timeout=120.0)
+    torch_world.check("torch_opt_state_asymmetric")
 
 
-def test_keras_distributed_optimizer():
-    run_scenario("keras_optimizer", 2, timeout=180.0)
+def test_keras_distributed_optimizer(tensorflow_world):
+    tensorflow_world.check("keras_optimizer")
 
 
-def test_tf_distributed_gradient_tape():
-    run_scenario("tf_tape", 2, timeout=180.0)
+def test_tf_distributed_gradient_tape(tensorflow_world):
+    tensorflow_world.check("tf_tape")
 
 
-def test_tf_allreduce_grad():
-    run_scenario("tf_allreduce_grad", 2, timeout=180.0)
+def test_tf_allreduce_grad(tensorflow_world):
+    tensorflow_world.check("tf_allreduce_grad")
 
 
-def test_tf_sparse_as_dense():
+def test_tf_sparse_as_dense(tensorflow_world):
     """sparse_as_dense=True matches the IndexedSlices gather path
     bit-for-bit on an embedding gradient."""
-    run_scenario("tf_sparse_as_dense", 2, timeout=180.0)
+    tensorflow_world.check("tf_sparse_as_dense")
 
 
-def test_tf_broadcast_hook():
+def test_tf_broadcast_hook(tensorflow_world):
     """BroadcastGlobalVariablesHook drives a real TF1
     MonitoredTrainingSession broadcast."""
-    run_scenario("tf_broadcast_hook", 2, timeout=180.0)
+    tensorflow_world.check("tf_broadcast_hook")
 
 
 @pytest.mark.slow
@@ -345,9 +458,8 @@ def test_torch_gather_bcast_grad():
     run_scenario("torch_gather_bcast_grad", 3, timeout=180.0)
 
 
-@pytest.mark.time_limit(270)
-def test_tfkeras_facade():
-    run_scenario("tfkeras_facade", 2, timeout=240.0)
+def test_tfkeras_facade(tensorflow_world):
+    tensorflow_world.check("tfkeras_facade")
 
 
 def test_scalar_broadcast():

@@ -116,25 +116,34 @@ def test_trainer_dp_tp_sp_with_ring_attention():
     assert float(loss1) < float(loss0)
 
 
-def test_sp_matches_dense_attention_loss():
-    """Loss with ring attention == loss with dense attention."""
+def _first_loss_over_seq(attention_fn=None):
+    """The first step's loss of the tiny model over ``data`` 2 x ``seq``
+    4, with ``attention_fn(mesh)`` for its attention (dense without)."""
     import optax
     mesh = spmd.create_mesh({"data": 2, "seq": 4})
-    attn = make_ring_attention(mesh, data_axis="data", seq_axis="seq",
-                               model_axis=None)
+    attn = attention_fn and attention_fn(mesh)
     tokens = np.tile(np.arange(16, dtype=np.int32)[None], (4, 1))
     batch = {"tokens": tokens}
+    trainer = Trainer(TransformerLM(_tiny_cfg(attention_fn=attn)), mesh,
+                      optax.sgd(1e-2),
+                      TrainerConfig(model_axis=None, seq_axis="seq"))
+    _, loss = trainer.train_step(trainer.init(jax.random.key(7), batch),
+                                 batch)
+    return float(loss)
 
-    dense = Trainer(TransformerLM(_tiny_cfg()), mesh, optax.sgd(1e-2),
-                    TrainerConfig(model_axis=None, seq_axis="seq"))
-    ringy = Trainer(TransformerLM(_tiny_cfg(attention_fn=attn)), mesh,
-                    optax.sgd(1e-2),
-                    TrainerConfig(model_axis=None, seq_axis="seq"))
-    s0 = dense.init(jax.random.key(7), batch)
-    s1 = ringy.init(jax.random.key(7), batch)
-    _, l0 = dense.train_step(s0, batch)
-    _, l1 = ringy.train_step(s1, batch)
-    np.testing.assert_allclose(float(l0), float(l1), rtol=1e-4)
+
+@pytest.fixture(scope="module")
+def dense_loss_over_seq():
+    """The dense trainer both sequence-parallel attentions are held to,
+    built and compiled once."""
+    return _first_loss_over_seq()
+
+
+def test_sp_matches_dense_attention_loss(dense_loss_over_seq):
+    """Loss with ring attention == loss with dense attention."""
+    ringy = _first_loss_over_seq(lambda mesh: make_ring_attention(
+        mesh, data_axis="data", seq_axis="seq", model_axis=None))
+    np.testing.assert_allclose(dense_loss_over_seq, ringy, rtol=1e-4)
 
 
 # ---------------------------------------------------------------------------
@@ -581,6 +590,7 @@ def _pp_setup(n_stages, d=8):
     return stacked, x
 
 
+@jax.jit
 def _pp_sequential(stacked, x):
     for s in range(stacked["w"].shape[0]):
         x = _pp_block({"w": stacked["w"][s], "b": stacked["b"][s]}, x)
@@ -672,8 +682,9 @@ def test_pipeline_composes_with_data_parallelism():
                                np.asarray(_pp_sequential(stacked, x)),
                                atol=1e-5)
 
-    gp = jax.grad(lambda p: jnp.mean(run(p, x) ** 2))(stacked)
-    gs = jax.grad(lambda p: jnp.mean(_pp_sequential(p, x) ** 2))(stacked)
+    gp = jax.jit(jax.grad(lambda p: jnp.mean(run(p, x) ** 2)))(stacked)
+    gs = jax.jit(jax.grad(
+        lambda p: jnp.mean(_pp_sequential(p, x) ** 2)))(stacked)
     np.testing.assert_allclose(np.asarray(gp["w"]), np.asarray(gs["w"]),
                                atol=1e-5)
     np.testing.assert_allclose(np.asarray(gp["b"]), np.asarray(gs["b"]),
@@ -759,15 +770,17 @@ def test_pipelined_lm_trains_with_dp():
     params = jax.jit(plm.init)(jax.random.key(0), tokens)
 
     @jax.jit
-    def loss_fn(p):
-        return lm_loss(plm.apply(p, tokens), tokens)
+    def step(p):
+        """The loss at ``p`` and the parameters a step on: the
+        pipelined tower forward and backward, one program."""
+        loss, g = jax.value_and_grad(
+            lambda p: lm_loss(plm.apply(p, tokens), tokens))(p)
+        return loss, jax.tree_util.tree_map(lambda a, g: a - 0.5 * g, p, g)
 
-    grad = jax.jit(jax.grad(loss_fn))
-    losses = [float(loss_fn(params))]
-    for _ in range(6):
-        params = jax.tree_util.tree_map(
-            lambda a, g: a - 0.5 * g, params, grad(params))
-        losses.append(float(loss_fn(params)))
+    losses = []
+    for _ in range(7):      # the loss before each of six steps, and after
+        loss, params = step(params)
+        losses.append(float(loss))
     assert losses[-1] < losses[0], losses
 
 
@@ -810,27 +823,13 @@ def test_ulysses_matches_reference():
         atol=2e-5)
 
 
-def test_ulysses_trainer_matches_dense_loss():
+def test_ulysses_trainer_matches_dense_loss(dense_loss_over_seq):
     """Training loss with Ulysses attention == dense attention loss
     (mirror of the ring-attention equivalence test)."""
-    import optax
     from horovod_tpu.parallel import make_ulysses_attention
-    mesh = spmd.create_mesh({"data": 2, "seq": 4})
-    attn = make_ulysses_attention(mesh, data_axis="data",
-                                  seq_axis="seq")
-    tokens = np.tile(np.arange(16, dtype=np.int32)[None], (4, 1))
-    batch = {"tokens": tokens}
-
-    dense = Trainer(TransformerLM(_tiny_cfg()), mesh, optax.sgd(1e-2),
-                    TrainerConfig(model_axis=None, seq_axis="seq"))
-    ulys = Trainer(TransformerLM(_tiny_cfg(attention_fn=attn)), mesh,
-                   optax.sgd(1e-2),
-                   TrainerConfig(model_axis=None, seq_axis="seq"))
-    s0 = dense.init(jax.random.key(7), batch)
-    s1 = ulys.init(jax.random.key(7), batch)
-    _, l0 = dense.train_step(s0, batch)
-    _, l1 = ulys.train_step(s1, batch)
-    np.testing.assert_allclose(float(l0), float(l1), rtol=1e-4)
+    ulys = _first_loss_over_seq(lambda mesh: make_ulysses_attention(
+        mesh, data_axis="data", seq_axis="seq"))
+    np.testing.assert_allclose(dense_loss_over_seq, ulys, rtol=1e-4)
 
 
 def test_ulysses_rejects_indivisible_heads():

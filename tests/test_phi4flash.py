@@ -18,6 +18,7 @@ import optax
 import pytest
 
 from .chip_bench import _paths  # noqa: F401  (makes chipbench importable)
+from .compiled import momentum_step
 from chipbench import check, harness, weights
 
 from horovod_tpu.models import phi4flash, train_steps
@@ -56,6 +57,12 @@ def params():
 def reference():
     """The reference's chain, its stages compiled once for the file."""
     return check.StagedGradient(FAMILY.reference_stages(SZ))
+
+
+@pytest.fixture(scope="module")
+def loss_and_grads(model):
+    """The program's loss and gradients, compiled once for the file."""
+    return jax.jit(jax.value_and_grad(train_steps.phi4flash_loss_fn(model)))
 
 
 @pytest.fixture(scope="module")
@@ -158,15 +165,17 @@ def test_the_programs_attention_runs_through_the_flash_kernels(model,
                                window)] * 2
 
 
-def test_the_whole_loss_and_its_gradients_are_the_references(model, params,
-                                                             reference):
+def test_the_whole_loss_and_its_gradients_are_the_references(
+        loss_and_grads, params, reference):
     """Through the reference's chain of five: the loss's use of the
     embedding's rows reaches the embedding's own gradient, and the
     memory and the key-value pair collect from every reader."""
     t = tokens()
-    loss, grads = jax.jit(jax.value_and_grad(
-        train_steps.phi4flash_loss_fn(model)))(params, t)
-    want_loss, _, want = reference(params, {}, (t,))
+    loss, grads = loss_and_grads(params, t)
+    # as `check.py` and the three steps below call it: one set of
+    # stages for the file (on the CPU the precision changes no product)
+    with jax.default_matmul_precision("highest"):
+        want_loss, _, want = reference(params, {}, (t,))
     np.testing.assert_allclose(loss, want_loss, rtol=1e-6)
     got, want = flat(grads), flat(want)
     assert set(got) == set(want)
@@ -176,30 +185,31 @@ def test_the_whole_loss_and_its_gradients_are_the_references(model, params,
             atol=3e-6 * float(np.abs(want[path]).max() + 1), err_msg=path)
 
 
-def test_three_steps_follow_the_references(model, params, reference):
+def test_three_steps_follow_the_references(loss_and_grads, params,
+                                           reference):
     """SGD with momentum, three steps on one batch: the program's
     losses and its parameters' change against the reference's."""
     t = tokens()
     tx = optax.sgd(0.01, momentum=0.9)
-    loss_fn = train_steps.phi4flash_loss_fn(model)
 
     @jax.jit
-    def step(p, o):
-        loss, g = jax.value_and_grad(loss_fn)(p, t)
+    def apply(p, o, g):
         updates, o = tx.update(g, o, p)
-        return optax.apply_updates(p, updates), o, loss
+        return optax.apply_updates(p, updates), o
+
+    def step(p, o):
+        loss, g = loss_and_grads(p, t)
+        return (*apply(p, o, g), loss)
 
     p, o, losses = params, tx.init(params), []
-    want_p, trace, want_losses = params, None, []
+    want_p, want_losses = params, []
+    trace = jax.tree_util.tree_map(jnp.zeros_like, params)
     for _ in range(3):
         p, o, loss = step(p, o)
         losses.append(float(loss))
         with jax.default_matmul_precision("highest"):
             want_loss, _, g = reference(want_p, {}, (t,))
-        trace = g if trace is None else jax.tree_util.tree_map(
-            lambda m, g_: g_ + 0.9 * m, trace, g)
-        want_p = jax.tree_util.tree_map(lambda w, m: w - 0.01 * m,
-                                        want_p, trace)
+        want_p, trace = momentum_step(want_p, trace, g)
         want_losses.append(float(want_loss))
     np.testing.assert_allclose(losses, want_losses, rtol=2e-6)
     start = {"params": params, "aux": {}}
@@ -208,8 +218,8 @@ def test_three_steps_follow_the_references(model, params, reference):
         check.diff_norms({"params": want_p, "aux": {}}, start), rtol=2e-3)
 
 
-def test_the_tied_embeddings_gradient_is_the_sum_of_both_uses(model,
-                                                              params):
+def test_the_tied_embeddings_gradient_is_the_sum_of_both_uses(
+        model, params, loss_and_grads):
     """Untie by hand: the lookup reads one copy of the table and the
     head another; the tied gradient is the two copies' sum."""
     t = tokens()
@@ -222,8 +232,7 @@ def test_the_tied_embeddings_gradient_is_the_sum_of_both_uses(model,
 
     table = params["embed"]["embedding"]
     g_lookup, g_head = jax.jit(jax.grad(untied, argnums=(0, 1)))(table, table)
-    tied = jax.jit(jax.grad(train_steps.phi4flash_loss_fn(model)))(
-        params, t)["embed"]["embedding"]
+    tied = loss_and_grads(params, t)[1]["embed"]["embedding"]
     assert float(jnp.abs(g_lookup).max()) > 0 < float(jnp.abs(g_head).max())
     np.testing.assert_allclose(tied, g_lookup + g_head, rtol=1e-4, atol=3e-6)
 

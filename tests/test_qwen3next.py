@@ -16,6 +16,7 @@ import optax
 import pytest
 
 from .chip_bench import _paths  # noqa: F401  (makes chipbench importable)
+from .compiled import momentum_step, weights_under
 from chipbench import check, harness, weights
 
 from horovod_tpu.models import glm_moe, qwen3next, train_steps
@@ -163,8 +164,7 @@ def test_gated_attention_runs_through_the_flash_kernels_at_a_head_of_256(
                   assumed=dict(CONFIG["assumed"], sequence_length=32))
     sz = FAMILY.sizes(config, 1)
     shapes, fans = FAMILY.param_shapes(sz)
-    p = weights.make_tree(shapes, fans, seed=7, stream=0)[
-        "params"]["layer_3"]["mixer"]
+    p = weights_under(shapes, fans, 7, 0, "params/layer_3/mixer")
     calls = []
 
     def through_kernels(q, k, v):
@@ -188,7 +188,10 @@ def test_the_whole_loss_and_its_gradients_are_the_references(
         loss_and_grads, params, reference):
     t = tokens()
     (loss, counts), grads = loss_and_grads(params, t)
-    want_loss, _, want = reference(params, {}, (t,))
+    # as `check.py` and the three steps below call it: one set of
+    # stages for the file (on the CPU the precision changes no product)
+    with jax.default_matmul_precision("highest"):
+        want_loss, _, want = reference(params, {}, (t,))
     np.testing.assert_allclose(loss, want_loss, rtol=1e-6)
     assert counts.shape == (4, SZ["experts_held"] + 2)
     assert (np.asarray(counts).sum(axis=1) == t.size * SZ["top_k"]).all()
@@ -217,16 +220,14 @@ def test_three_steps_follow_the_references(loss_and_grads, params,
         return (*apply(p, o, g), loss)
 
     p, o, losses = params, tx.init(params), []
-    want_p, trace, want_losses = params, None, []
+    want_p, want_losses = params, []
+    trace = jax.tree_util.tree_map(jnp.zeros_like, params)
     for _ in range(3):
         p, o, loss = step(p, o)
         losses.append(float(loss))
         with jax.default_matmul_precision("highest"):
             want_loss, _, g = reference(want_p, {}, (t,))
-        trace = g if trace is None else jax.tree_util.tree_map(
-            lambda m, g_: g_ + 0.9 * m, trace, g)
-        want_p = jax.tree_util.tree_map(lambda w, m: w - 0.01 * m,
-                                        want_p, trace)
+        want_p, trace = momentum_step(want_p, trace, g)
         want_losses.append(float(want_loss))
     np.testing.assert_allclose(losses, want_losses, rtol=2e-6)
     start = {"params": params, "aux": {}}
@@ -242,8 +243,7 @@ def test_the_sixteen_shares_add_up_to_the_uncut_layer(model, x):
     e, held = SZ["experts"], SZ["experts_held"]
     whole = dict(SZ, experts_held=e, expert_offset=0)
     shapes, fans = FAMILY.param_shapes(whole)
-    p = weights.make_tree(shapes, fans, seed=13, stream=0)[
-        "params"]["layer_0"]["moe"]
+    p = weights_under(shapes, fans, 13, 0, "params/layer_0/moe")
     want = jax.jit(FAMILY.reference_fns(whole)["expert_layer"])(p, x)
     shared = jax.jit(lambda p, x: jax.nn.sigmoid(
         x @ p["shared_gate"]["kernel"]) * FAMILY._swiglu(p["shared"], x))(

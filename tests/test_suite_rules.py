@@ -1,7 +1,8 @@
 """The suite's own rules (``tests/conftest.py``): CPU compiles skip the
 optimiser, one bytecode cache serves every interpreter of a run, every
 test has a time limit, the pytest process keeps SIGTERM's disposition,
-and a run prints where its time went."""
+and a run prints how much of its clock it used and where the time
+went."""
 
 import os
 import re
@@ -93,6 +94,26 @@ def test_the_run_prints_where_its_time_went(inner_run):
     # the driver's count of passes still reads the progress lines only
     dots = "".join(line for line in lines if _DOTS.match(line))
     assert dots.count(".") == 2 and dots.count("F") == 1, dots
+
+
+def test_the_run_says_how_much_of_its_clock_it_used(inner_run):
+    """One line before the tables: the wall, the tests' own seconds and
+    their share of the limit the driver's command runs under."""
+    from tests.conftest import SUITE_LIMIT_S
+    out, seconds = inner_run
+    lines = out.stdout.splitlines()
+    clock = lines.index(next(
+        line for line in lines if "the suite's clock" in line))
+    assert clock < lines.index(next(
+        line for line in lines if "seconds by file" in line))
+    said = re.fullmatch(
+        r"(\d+\.\d) s of wall, (\d+\.\d) s in tests: "
+        r"(\d+\.\d)% of the 1470 s limit", lines[clock + 1])
+    assert said and SUITE_LIMIT_S == 1470, lines[clock + 1]
+    wall, tests, share = map(float, said.groups())
+    # the test that oversleeps is cut at its second
+    assert 1.0 <= tests <= wall <= seconds, (tests, wall, seconds)
+    assert abs(share - 100 * tests / SUITE_LIMIT_S) < 0.1, (share, tests)
 
 
 def test_a_world_leaves_sigterm_as_it_found_it():
