@@ -361,6 +361,10 @@ DEVICE_SCOPES = {
     "gdn.rule": "L2 norms, gates, running sums and the rule's two kernels",
     "gdn.gate": "the gated RMSNorm of the rule's output",
     "gated_attn": "gated grouped-head attention around its flash call",
+    "shortconv.proj": "the gated short convolution's two projections",
+    "shortconv.conv": "its two gates and the three causal depthwise taps",
+    "normed_attn": "grouped-head attention with normalised q and k "
+                   "around its flash call",
     "ssm.proj": "Mamba's four projections",
     "ssm.conv": "its causal depthwise convolution and SiLU",
     "ssm.scan": "the relayouts and the selective scan's two kernels",

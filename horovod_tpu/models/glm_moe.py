@@ -73,10 +73,11 @@ class GlmMoeConfig:
     num_experts_per_tok: int = 4
     routed_scaling_factor: float = 1.8
     # What else ``ExpertLayer`` asks of its configuration: the family's
-    # scores are sigmoids beside a correction bias, and its shared
-    # expert has no gate.
+    # scores are sigmoids beside a correction bias, its shared expert
+    # has no gate, and the chosen weights are divided by their bare sum.
     scoring: str = "sigmoid"
     shared_expert_gate: bool = False
+    topk_weight_eps: float = 0.0
     # The share of the experts this chip holds: ids
     # [expert_offset, expert_offset + experts_held).
     experts_held: int = 64
@@ -275,14 +276,18 @@ class HeldExperts(nn.Module):
 
 class ExpertLayer(nn.Module):
     """``(y, counts)``: the held experts' part of the routed result plus
-    the shared expert; ``counts`` int32 [experts_held + 2]: assignments
-    to each held expert, to absent experts, and dropped (always 0).
+    the shared expert, where the model has one; ``counts`` int32
+    [experts_held + 2]: assignments to each held expert, to absent
+    experts, and dropped (always 0).
 
     One body for every sparse model; the configuration says what
     differs. It is read for ``hidden_size``, ``moe_intermediate_size``,
-    ``shared_intermediate_size``, ``n_routed_experts`` (the router's
-    width), ``num_experts_per_tok``, ``experts_held``,
-    ``expert_offset``, ``dtype`` and
+    ``n_routed_experts`` (the router's width), ``num_experts_per_tok``,
+    ``experts_held``, ``expert_offset``, ``dtype`` and
+
+    * ``shared_intermediate_size``: the shared expert's width; 0 is a
+      model without one (no ``shared`` parameters, no ``moe.shared``
+      scope);
 
     * ``scoring``: ``"sigmoid"`` (scores are sigmoids, the choice reads
       ``score + bias``, a correction bias the layer owns) or
@@ -290,6 +295,8 @@ class ExpertLayer(nn.Module):
       bias);
     * ``routed_scaling_factor``: what the normalised weights of a
       token's k choices are multiplied by;
+    * ``topk_weight_eps``: what is added to the sum of a token's k
+      chosen scores before they are divided by it (0: the bare sum);
     * ``shared_expert_gate``: whether the shared expert's output is
       multiplied by ``sigmoid(x w_s)``, ``w_s`` the layer's own
       ``d -> 1``."""
@@ -327,8 +334,13 @@ class ExpertLayer(nn.Module):
             # kept only where a caller asks for ``intermediates``
             self.sow("intermediates", "chosen", chosen)
             picked = jnp.take_along_axis(scores, chosen, axis=-1)
-            gates = cfg.routed_scaling_factor * picked \
-                / jnp.sum(picked, axis=-1, keepdims=True)
+            # (the scaled scores first and no add of a zero: the lowered
+            # step of a model without the constant is what it was)
+            gates = cfg.routed_scaling_factor * picked
+            total = jnp.sum(picked, axis=-1, keepdims=True)
+            if cfg.topk_weight_eps:
+                total = total + cfg.topk_weight_eps
+            gates = gates / total
 
         with jax.named_scope("moe.dispatch"):
             local = chosen - cfg.expert_offset
@@ -410,14 +422,15 @@ class ExpertLayer(nn.Module):
                 tier, [routed(c) for c in caps[:-1]] + [walked(caps[-2])],
                 None)
 
-        with jax.named_scope("moe.shared"):
-            shared = SwiGLU(cfg, cfg.shared_intermediate_size,
-                            name="shared")(xf)
-            if cfg.shared_expert_gate:
-                shared = shared * jax.nn.sigmoid(_dense(
-                    cfg, 1, "shared_gate")(xf).astype(jnp.float32)) \
-                    .astype(shared.dtype)
-            y = y + shared
+        if cfg.shared_intermediate_size:
+            with jax.named_scope("moe.shared"):
+                shared = SwiGLU(cfg, cfg.shared_intermediate_size,
+                                name="shared")(xf)
+                if cfg.shared_expert_gate:
+                    shared = shared * jax.nn.sigmoid(_dense(
+                        cfg, 1, "shared_gate")(xf).astype(jnp.float32)) \
+                        .astype(shared.dtype)
+                y = y + shared
         # held assignments whose row lies behind the buffer that ran: the
         # last tier holds every row, so none
         dropped = jnp.sum(held.reshape(-1)

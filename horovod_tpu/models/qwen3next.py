@@ -90,6 +90,7 @@ class Qwen3NextConfig:
     scoring: str = "softmax"
     routed_scaling_factor: float = 1.0
     shared_expert_gate: bool = True
+    topk_weight_eps: float = 0.0
     # The share of the experts this chip holds: ids
     # [expert_offset, expert_offset + experts_held).
     experts_held: int = 512

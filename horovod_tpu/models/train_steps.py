@@ -26,6 +26,7 @@ from horovod_tpu.common import trace
 from horovod_tpu.common.basics import active_runtime
 from horovod_tpu.compat import jaxshim
 from horovod_tpu.models.glm_moe import ABSENT, DROPPED, GlmMoeLM
+from horovod_tpu.models.lfm2 import Lfm2MoeLM
 from horovod_tpu.models.phi4flash import Phi4FlashLM
 from horovod_tpu.models.qwen3next import Qwen3NextLM
 from horovod_tpu.models.resnet import ResNet50
@@ -256,6 +257,40 @@ def qwen3next_train_step(model: Qwen3NextLM, tx, mesh):
     recomputed with its kernels' outputs kept
     (``qwen3next.RematBlock``), the counts for :class:`MoeLoadFeed`."""
     return _counted_train_step(qwen3next_loss_fn(model), tx, mesh)
+
+
+# The chunked head over the embedding's own 8,192 rows of 2,048, 4 rows
+# of 8,192 positions a step. Measured on v5e silicon (PR 39: value and
+# both gradients of `lm_loss_from_hidden` alone at that shape, ms, then
+# the compiler's temporaries in GB): chunk 512 21.78 and 0.135, 1,024
+# 23.81 and 0.269, 2,048 26.02 and 0.437, 4,096 25.58 and 0.706, 8,192
+# (one chunk a row) 21.64 and 1.208. The shortest is as fast as the
+# longest and leaves the step, which needs 14.3 of the chip's 16.9 GB,
+# a gigabyte more.
+LFM2_HEAD_CHUNK = 512
+
+
+def lfm2_loss_fn(model: Lfm2MoeLM):
+    """``(params, tokens) -> (loss, counts)``: next-token cross-entropy
+    through the chunked head **on the embedding's own rows** (as
+    ``phi4flash_loss_fn``: the loss's gradient and the lookup's add into
+    one leaf), chunks of ``LFM2_HEAD_CHUNK`` positions; ``counts`` are
+    the layers' loads ([layers, experts_held + 2], a dense layer's as
+    zeros)."""
+    def loss_fn(p, t):
+        hidden, counts = model.apply({"params": p}, t)
+        return lm_loss_from_hidden(hidden, p["embed"]["embedding"].T, t,
+                                   chunk=LFM2_HEAD_CHUNK), counts
+    return loss_fn
+
+
+def lfm2_train_step(model: Lfm2MoeLM, tx, mesh):
+    """The convolution-attention sparse decoder's step,
+    ``glm_moe_train_step``'s shape: ``(params, opt_state, tokens) ->
+    (params, opt_state, loss, counts)``, state donated, every block
+    recomputed with its kernels' outputs kept (``lfm2.RematBlock``),
+    the counts for :class:`MoeLoadFeed`."""
+    return _counted_train_step(lfm2_loss_fn(model), tx, mesh)
 
 
 class MoeLoadFeed:

@@ -758,6 +758,21 @@ def _shapes_ok(seq_q, seq_k, block_q, block_k):
 # eight query heads' worth of accumulators). The default pair holds for
 # grouped heads at 256 as it does for equal ones: no rung changed, and
 # in the cell's step the three kernels run at 74% of their roofline.
+#
+# Measured at D=64 over grouped heads on v5e silicon (PR 39: B4, 32
+# query heads over 8 key-value heads, S8192, the attention of
+# models/lfm2.py; one call's forward, and forward + backward less that
+# forward, by the host's clock, ms; 256x256 sub-tiles):
+#   1024x1024  21.10 + 53.14 =  74.24   (the ladder's own pick: 74.23)
+#   512x2048   23.37 + 52.27 =  75.64
+#   512x1024   24.19 + 58.60 =  82.79
+#   2048x512   30.55 + 58.15 =  88.70
+#   1024x512   36.93 + 61.86 =  98.79
+#   256x1024   31.56 + 68.93 = 100.50
+#   512x512    38.76 + 69.64 = 108.40
+# 2048x1024 (the forward) and 1024x2048 (the dq kernel) do not fit
+# VMEM. The q tile of 1024 that PR 31 gave a head of 64 holds at four
+# query heads a key-value head and half the sequence: no rung changed.
 _BLOCK_Q_LADDER = (512, 256, 128)
 _BLOCK_K_LADDER = (1024, 512, 256, 128)
 _HEAD_DIM_BASE = 256  # the largest D the default ladder was measured at

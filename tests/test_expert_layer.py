@@ -1,10 +1,15 @@
-"""The one expert layer of both sparse models
-(``horovod_tpu.models.glm_moe.ExpertLayer``) under both scorings
-against a dense masked sum written here, its row buffer's tiers, and
-the tier that walks the rows a slab at a time against the one-buffer
-result with every assignment forced onto held experts."""
+"""The one expert layer of every sparse model
+(``horovod_tpu.models.glm_moe.ExpertLayer``) under both scorings, with
+and without a shared expert, against a dense masked sum written here,
+the constant in its normaliser, its row buffer's tiers, the tier that
+walks the rows a slab at a time against the one-buffer result with
+every assignment forced onto held experts, the eight shares of a layer
+adding up to the uncut layer, and the two older models' parameter trees
+as they were."""
 
 import dataclasses
+import hashlib
+import json
 
 import flax.linen as nn
 import jax
@@ -12,7 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from horovod_tpu.models import glm_moe, qwen3next
+from horovod_tpu.models import glm_moe, lfm2, qwen3next
 
 pytestmark = [pytest.mark.fast, pytest.mark.time_limit(120)]
 
@@ -21,7 +26,15 @@ TOL = dict(rtol=3e-5, atol=3e-6)
 
 
 def config(scoring, **over):
-    """A configuration of either model at one small size."""
+    """A configuration of one of the three models at one small size:
+    ``no_shared`` is the one without a shared expert (sigmoid scores,
+    1e-6 in the normaliser)."""
+    if scoring == "no_shared":
+        return dataclasses.replace(lfm2.Lfm2MoeConfig(
+            hidden_size=D, moe_intermediate_size=WIDTH,
+            n_routed_experts=EXPERTS, num_experts_per_tok=K,
+            experts_held=HELD, expert_offset=OFFSET, dtype=jnp.float32),
+            **over)
     if scoring == "sigmoid":
         return dataclasses.replace(glm_moe.GlmMoeConfig(
             hidden_size=D, moe_intermediate_size=WIDTH,
@@ -37,8 +50,10 @@ def config(scoring, **over):
 
 def dense_masked_sum(cfg, p, x):
     """Every held expert over every token, weighted by the router's
-    weight for it (zero where the token did not choose it), plus the
-    shared expert, gated where the configuration says so."""
+    weight for it (zero where the token did not choose it: the chosen
+    scores over their sum plus the configuration's constant), plus the
+    shared expert where there is one, gated where the configuration
+    says so."""
     xf = x.reshape(-1, D)
     logits = xf @ p["router"]["kernel"]
     if cfg.scoring == "softmax":
@@ -51,11 +66,13 @@ def dense_masked_sum(cfg, p, x):
     picked = scores * jnp.sum(
         jax.nn.one_hot(chosen, cfg.n_routed_experts), axis=1)
     weights = cfg.routed_scaling_factor * picked \
-        / jnp.sum(picked, -1, keepdims=True)
+        / (jnp.sum(picked, -1, keepdims=True) + cfg.topk_weight_eps)
     swiglu = lambda g, u, d_: (nn.silu(xf @ g) * (xf @ u)) @ d_
-    shared = p["shared"]
-    y = swiglu(shared["gate"]["kernel"], shared["up"]["kernel"],
-               shared["down"]["kernel"])
+    y = jnp.zeros_like(xf)
+    if cfg.shared_intermediate_size:
+        shared = p["shared"]
+        y = swiglu(shared["gate"]["kernel"], shared["up"]["kernel"],
+                   shared["down"]["kernel"])
     if cfg.shared_expert_gate:
         y = y * jax.nn.sigmoid(xf @ p["shared_gate"]["kernel"])
     for j in range(cfg.experts_held):
@@ -76,15 +93,21 @@ def dense(cfg, p, x):
     return jax.jit(lambda p, x: dense_masked_sum(cfg, p, x))(p, x)
 
 
-@pytest.mark.parametrize("scoring", ["sigmoid", "softmax"])
+@pytest.mark.parametrize("scoring", ["sigmoid", "softmax", "no_shared"])
 def test_the_layer_is_the_dense_masked_sum_under_either_scoring(scoring):
+    """``no_shared``: a configuration whose ``shared_intermediate_size``
+    is 0 has no ``shared`` leaves, and its layer is the routed sum
+    alone."""
     cfg = config(scoring)
     layer, p, x = layer_and_params(cfg)
-    assert ("bias" in p["router"]) == (scoring == "sigmoid")
+    assert ("bias" in p["router"]) == (scoring != "softmax")
     assert ("shared_gate" in p) == (scoring == "softmax")
-    assert p["shared"]["up"]["kernel"].shape[1] \
-        == (WIDTH if scoring == "sigmoid" else 24)
-    if scoring == "sigmoid":
+    if scoring == "no_shared":
+        assert set(p) == {"router", "experts"}
+    else:
+        assert p["shared"]["up"]["kernel"].shape[1] \
+            == (WIDTH if scoring == "sigmoid" else 24)
+    if scoring != "softmax":
         p["router"]["bias"] = jax.random.normal(jax.random.key(3),
                                                 (EXPERTS,)) * 0.3
     weight = jax.random.normal(jax.random.key(4), x.shape)
@@ -214,3 +237,101 @@ def test_a_ragged_last_slab_loses_nothing(monkeypatch):
     assert int(counts[:HELD].sum()) == 48 * 3 > 58
     assert int(counts[glm_moe.DROPPED]) == 0
     np.testing.assert_allclose(y, dense(cfg, p, x), **TOL)
+
+
+@pytest.mark.parametrize("scoring", ["sigmoid", "softmax", "no_shared"])
+def test_the_constant_in_the_normaliser_is_read(scoring):
+    """``topk_weight_eps``: a constant large enough to show (the
+    published 1e-6 moves a weight by 4e-7 of itself) gives the dense
+    sum with that constant, and another result than the bare sum."""
+    bare = config(scoring, topk_weight_eps=0.0)
+    cfg = config(scoring, topk_weight_eps=0.5)
+    layer, p, x = layer_and_params(cfg)
+    y, _ = jax.jit(layer.apply)({"params": p}, x)
+    np.testing.assert_allclose(y, dense(cfg, p, x), **TOL)
+    without, _ = jax.jit(glm_moe.ExpertLayer(bare).apply)({"params": p}, x)
+    np.testing.assert_allclose(without, dense(bare, p, x), **TOL)
+    assert float(jnp.abs(y - without).max()) > 1e-3
+    assert config("no_shared").topk_weight_eps == 1e-6
+    assert glm_moe.GlmMoeConfig().topk_weight_eps == 0.0 \
+        == qwen3next.Qwen3NextConfig().topk_weight_eps
+
+
+def test_the_eight_shares_add_up_to_the_uncut_layer():
+    """The share tied to the model: 64 experts as eight chips' eight
+    each (offsets 0, 8, ..., 56). With no shared expert there is
+    nothing to count once: the chips' outputs sum to the layer that
+    holds all 64, and every assignment is held by exactly one chip."""
+    experts, held = 64, 8
+    whole = config("no_shared", n_routed_experts=experts,
+                   experts_held=experts, expert_offset=0)
+    layer, p, x = layer_and_params(whole)
+    p["router"]["bias"] = jax.random.normal(jax.random.key(3),
+                                            (experts,)) * 0.3
+    want = dense(whole, p, x)
+
+    @jax.jit
+    def shares(p, x):
+        """Every chip's ``(y, counts)``, one program for the eight."""
+        out = []
+        for offset in range(0, experts, held):
+            mine = dict(p, experts={k: v[offset:offset + held]
+                                    for k, v in p["experts"].items()})
+            cfg = dataclasses.replace(whole, experts_held=held,
+                                      expert_offset=offset)
+            out.append(glm_moe.ExpertLayer(cfg).apply({"params": mine}, x))
+        return out
+
+    total, seen = 0.0, 0
+    for y, counts in shares(p, x):
+        assert counts.shape == (held + 2,) and counts[glm_moe.DROPPED] == 0
+        assert int(counts.sum()) == 48 * K
+        total = total + y
+        seen += int(counts[:held].sum())
+    assert seen == 48 * K
+    np.testing.assert_allclose(total, want, **TOL)
+    np.testing.assert_allclose(
+        jax.jit(layer.apply)({"params": p}, x)[0], want, **TOL)
+
+
+LAYER_TREES = {
+    "sigmoid": {"router/kernel": (D, EXPERTS), "router/bias": (EXPERTS,),
+                "experts/gate": (HELD, D, WIDTH),
+                "experts/up": (HELD, D, WIDTH),
+                "experts/down": (HELD, WIDTH, D),
+                "shared/gate/kernel": (D, WIDTH),
+                "shared/up/kernel": (D, WIDTH),
+                "shared/down/kernel": (WIDTH, D)},
+    "softmax": {"router/kernel": (D, EXPERTS),
+                "experts/gate": (HELD, D, WIDTH),
+                "experts/up": (HELD, D, WIDTH),
+                "experts/down": (HELD, WIDTH, D),
+                "shared/gate/kernel": (D, 24), "shared/up/kernel": (D, 24),
+                "shared/down/kernel": (24, D),
+                "shared_gate/kernel": (D, 1)}}
+# sha256 of the whole models' parameter shapes at their cells'
+# rehearsal sizes, read on the parent commit (PR 38's tree)
+MODEL_TREES = {"sigmoid": ("glm47flash-injit-1chip", "dde9e3c7cb5af6bd"),
+               "softmax": ("qwen3next-injit-1chip", "7f7d11904d27bce3")}
+
+
+@pytest.mark.parametrize("scoring", ["sigmoid", "softmax"])
+def test_the_older_models_parameter_trees_are_what_they_were(scoring):
+    """The layer's leaves under ``GlmMoeConfig`` and ``Qwen3NextConfig``
+    by name and shape, and the whole models' trees at the rehearsal
+    sizes by the hash the parent commit gives."""
+    from .chip_bench import _paths
+    from chipbench import harness, weights
+    _, p, _ = layer_and_params(config(scoring))
+    got = {"/".join(str(k.key) for k in path): leaf.shape for path, leaf
+           in jax.tree_util.tree_flatten_with_path(p)[0]}
+    assert got == LAYER_TREES[scoring]
+    cell, want = MODEL_TREES[scoring]
+    spec = harness.resolve_cell(_paths.manifest(), cell, rehearse=True)
+    family = harness.load_module("families", spec["config"]["family"])
+    sz = family.sizes(spec["config"],
+                      spec["config"]["assumed"]["per_chip_batch"])
+    tree = json.dumps(weights.flat_shapes(jax.tree_util.tree_map(
+        lambda a: tuple(a.shape),
+        family.program_shapes(family.build_model(sz), sz))), sort_keys=True)
+    assert hashlib.sha256(tree.encode()).hexdigest()[:16] == want
