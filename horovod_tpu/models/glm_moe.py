@@ -46,7 +46,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any
+from typing import Any, NamedTuple
 
 import flax.linen as nn
 import jax
@@ -167,69 +167,172 @@ class SwiGLU(nn.Module):
 
 
 # -- rows to their experts and back -----------------------------------------
-# ``order`` lists the assignments (token * k + choice) sorted by expert,
-# held ones first; ``inv`` is its inverse. A tier takes the first
-# ``cap`` of them. Both directions are gathers, and each is the other's
-# transpose.
+# A row buffer holds ``cap`` rows in expert order: the held experts' runs
+# one behind the other, token order inside a run, and behind them rows
+# that belong to no assignment. ``Span`` is the buffer's index plan; the
+# way there and the way back handle the buffer's rows and the tokens,
+# never every assignment, and each is the other's transpose.
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def rows_to_experts(x, order, inv, held, k):
-    """``x[order // k]``: [cap, D] rows in expert order from [N, D]."""
-    return x[order // k]
-
-
-def _rows_to_experts_fwd(x, order, inv, held, k):
-    return x[order // k], (order, inv, held)
+# Places a product of ``_run_sums``: the matrix unit's width.
+RUN_BLOCK = 128
 
 
-def _rows_to_experts_bwd(k, res, g):
-    order, inv, held = res
-    return (rows_from_experts(g, order, inv, held, k), None, None, None)
+class Span(NamedTuple):
+    order: jax.Array    # [cap] the assignment (token * k + choice) of a row
+    live: jax.Array     # [cap] whether the row belongs to an assignment
+    # the buffer's places: its rows in token order, filled up to whole
+    # blocks of ``RUN_BLOCK`` with at least one place that sums nothing
+    row: jax.Array      # [places] the live rows, their assignments ascending
+    token: jax.Array    # [places] their tokens; N behind them, then -1
+    first: jax.Array    # [N] the place where a token's run starts, or cap
+
+
+def expert_order(group, held_n):
+    """``(order, sizes)`` of the assignments' groups (a held expert's
+    index, or ``held_n`` for an absent one): the assignments sorted by
+    group, token order inside one, and how many each group has (what
+    the plan cost was two scatters of tokens x k scalars, not the sort:
+    the table below)."""
+    order = jnp.argsort(group, stable=True).astype(jnp.int32)
+    sizes = jnp.sum(group[:, None] == jnp.arange(held_n + 1), axis=0,
+                    dtype=jnp.int32)
+    return order, sizes
+
+
+# ``span_of`` and ``_run_sums`` are jitted so that a step traces each
+# once and not once a layer, tier and pass: traced every time they
+# added 4.3 s to ``lfm2moe-injit-1chip``'s 34 s of warm set-up (PR 40).
+@functools.partial(jax.jit, static_argnums=(2, 3))
+def span_of(order, live, n, k) -> Span:
+    """The plan of the buffer whose rows hold the assignments ``order``
+    where ``live``: one sort of the buffer's rows back into token order
+    and one scatter of the runs' starts; a token without a run starts
+    at ``cap``, the first of the places that sum nothing."""
+    cap = order.shape[0]
+    fill = RUN_BLOCK - cap % RUN_BLOCK
+    place = jnp.arange(cap, dtype=jnp.int32)
+    assignment, row = jax.lax.sort(
+        (jnp.where(live, order, n * k), place), num_keys=1)
+    token = assignment // k
+    starts = token != jnp.concatenate([jnp.full((1,), -1, token.dtype),
+                                       token[:-1]])
+    first = jnp.full((n,), cap, jnp.int32).at[
+        jnp.where(starts, token, n)].set(place, mode="drop",
+                                         unique_indices=True)
+    return Span(order, live, jnp.pad(row, (0, fill)),
+                jnp.pad(token, (0, fill), constant_values=-1), first)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def rows_to_experts(x, span, k):
+    """``x[span.order // k]``: [cap, D] rows in expert order from
+    [N, D]."""
+    return x[span.order // k]
+
+
+def _rows_to_experts_fwd(x, span, k):
+    return rows_to_experts(x, span, k), span
+
+
+def _rows_to_experts_bwd(k, span, g):
+    # rows behind the last live one hold whatever the products' backward
+    # left there
+    g = jnp.where(span.live[:, None], g, 0)
+    return rows_from_experts(g, span, k), None
 
 
 rows_to_experts.defvjp(_rows_to_experts_fwd, _rows_to_experts_bwd)
 
 
-# Rows of the buffer against assignments, above which the way back to
-# token order adds the buffer's rows into their tokens instead of
-# picking a row for every assignment. Measured on v5e silicon (PR 33;
-# 16,384 tokens of 2,048, bfloat16, ms): ten choices a token and a
-# buffer of 20,480 rows, picking 163,840 rows 5.52, adding 20,480 rows
-# 2.98; four choices and a buffer of 16,384, picking 65,536 rows 2.05,
-# adding 16,384 rows 2.61: an added row costs what 4.5 to 5 picked
-# ones do.
-PICKED_ROWS_AN_ADDED_ROW = 5
+# The way back to token order and the plan, measured on v5e silicon (PR
+# 40; rows of 2,048 bfloat16; ms): one call alone | with its transpose
+# (``cap`` picks of [N, D]) | one layer's value and gradients under
+# ``jax.checkpoint`` (two plans, three calls, one transpose), at the
+# three cells' shapes, tokens x k and the buffer's rows:
+#
+#                                   32,768 x 4,       16,384 x 4,       16,384 x 10,
+#                                   32,768            16,384            20,480
+#   pick every assignment (PR 33)   8.46 10.04 49.3   2.04 2.42 27.9    5.54 5.99
+#   add the buffer's rows (PR 33)   5.51  7.09        2.78 3.16         3.19 3.65 36.7
+#   sorted add of the picked rows   4.53  6.11        2.28 2.65         2.55 3.01
+#   runs, shifted reads, a select   4.92  6.50 38.7   1.88 2.26 24.0    3.48 3.93 31.6
+#   runs, products, a select        3.34  4.92 36.3   0.76 1.13 23.7    0.94 1.39 27.8
+#   runs, products, an empty place  2.72  4.30 34.9   1.14 1.52 23.4    1.31 1.77 27.5
+#
+# The last stands: a run's rows are summed by a 0/1 product a block of
+# places, and a token without a run picks a place that sums nothing
+# (alone it loses at the two smaller shapes, where 128 more places push
+# the second pick's operand out of the 64 and 80 MiB the compiler
+# copies to VMEM before it picks; in the layer it wins at all three).
+# A pick of a 4 KB row costs 0.012 us from such an operand and 0.037 to
+# 0.048 from one of 128 MiB; a row read at a shift of one to k - 1 rows
+# is a copy of the array a shift (rows are packed two a sublane); a
+# scattered or added row costs four picks. The plan: the stable sort,
+# its inverse by a scatter and the sizes by a scatter-add took 1.97,
+# 1.04 and 2.46 ms; the sort, the sizes by comparison and ``span_of``
+# 0.74, 0.69 and 0.74. A sort of 131,072 pairs is 0.40 ms and one of
+# 32,768 pairs 0.39, a cumulative sum 0.2 at any length, a scatter of
+# 131,072 scalars 0.67 and their scatter-add into nine bins 1.2: a
+# partition by counting (cumulative sums over [tokens, held] and over
+# the tokens, then the inversion) read 1.15 to 1.45 where this plan
+# reads 0.69 to 0.74, so the sort stays.
+
+@functools.partial(jax.jit, static_argnums=2)
+def _run_sums(p, token, k):
+    """[places, D], whole blocks of ``RUN_BLOCK``: each row of ``p``
+    plus the rows behind it of the same token (``token`` ascending, at
+    most k alike), in float32, rounded once; nothing where the token is
+    negative. A product with a 0/1 matrix a block; the block's last
+    k - 1 rows, whose runs may cross into the next block, once more
+    with that block's first k - 1 beside them."""
+    block, h, d = RUN_BLOCK, k - 1, p.shape[-1]
+    if h > block:
+        raise ValueError(f"runs of {k} rows cross more than one block of "
+                         f"{block}")
+    t, rows = token.reshape(-1, block), p.reshape(-1, block, d)
+    product = functools.partial(
+        jnp.einsum, "bij,bjd->bid", preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST)
+    here = ((t[:, :, None] == t[:, None, :]) & (t[:, :, None] >= 0)
+            & (jnp.arange(block)[:, None] <= jnp.arange(block))
+            ).astype(p.dtype)
+    sums = product(here, rows).astype(p.dtype)
+    if h and t.shape[0] > 1:
+        ahead = jnp.pad(t[1:, :h], ((0, 1), (0, 0)), constant_values=-1)
+        crossing = (t[:, -h:, None] == ahead[:, None, :]).astype(p.dtype)
+        sums = sums.at[:, -h:].set((
+            product(here[:, -h:, -h:], rows[:, -h:]) + product(
+                crossing, jnp.pad(rows[1:, :h], ((0, 1), (0, 0), (0, 0))))
+        ).astype(p.dtype))
+    return sums.reshape(-1, d)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def rows_from_experts(rows, order, inv, held, k):
-    """[N, D] from [cap, D] rows in expert order: each token's held
-    choices summed in float32; a row that belongs to no held expert
-    (whatever the buffer holds there) is left out. Where the buffer is
-    short against the assignments (``PICKED_ROWS_AN_ADDED_ROW``) its
-    rows are added into their tokens; else every assignment picks its
-    row."""
-    cap, n = rows.shape[0], held.shape[0]
-    if n * k > PICKED_ROWS_AN_ADDED_ROW * cap:
-        token = jnp.where(held.reshape(-1)[order], order // k, n)
-        return jnp.zeros((n, rows.shape[-1]), jnp.float32).at[token].add(
-            rows.astype(jnp.float32), mode="drop").astype(rows.dtype)
-    picked = rows[jnp.minimum(inv, cap - 1)]                 # [N*k, D]
-    picked = jnp.where(held.reshape(-1, 1), picked, 0)
-    return jnp.sum(picked.reshape(-1, k, rows.shape[-1]),
-                   axis=1, dtype=jnp.float32).astype(rows.dtype)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def rows_from_experts(rows, span, k):
+    """[N, D] from [cap, D] rows in expert order, zeros where not
+    ``span.live``: each token's held choices summed in float32. The
+    live rows are picked into token order (``cap`` picks), a token's
+    rows are summed where its run starts, and every token picks that
+    sum, or a place that sums nothing (N picks)."""
+    return _run_sums(rows[span.row], span.token, k)[span.first]
 
 
-def _rows_from_experts_fwd(rows, order, inv, held, k):
-    return rows_from_experts(rows, order, inv, held, k), (order, inv, held)
+def _rows_from_experts_fwd(rows, span, k):
+    from horovod_tpu.common import basics
+    n = span.first.shape[0]
+    basics.note_traced(
+        "hvd_moe_rows_moved",
+        "the differentiated expert layer traced last: assignments "
+        "(tokens x k) a layer, rows of its first tier's buffer, and rows "
+        "one call of the way back to token order picks",
+        {"assignments": n * k, "buffer_rows": rows.shape[0],
+         "picked_back": span.row.shape[0] + n})
+    return rows_from_experts(rows, span, k), span
 
 
-def _rows_from_experts_bwd(k, res, g):
-    order, inv, held = res
-    live = held.reshape(-1)[order]
-    rows = jnp.where(live[:, None], rows_to_experts(g, order, inv, held, k),
-                     0)
-    return (rows, None, None, None)
+def _rows_from_experts_bwd(k, span, g):
+    rows = jnp.where(span.live[:, None], rows_to_experts(g, span, k), 0)
+    return rows, None
 
 
 rows_from_experts.defvjp(_rows_from_experts_fwd, _rows_from_experts_bwd)
@@ -346,23 +449,20 @@ class ExpertLayer(nn.Module):
             local = chosen - cfg.expert_offset
             held = (local >= 0) & (local < held_n)             # [N, k]
             group = jnp.where(held, local, held_n).reshape(-1)
-            order = jnp.argsort(group, stable=True).astype(jnp.int32)
-            inv = jnp.zeros_like(order).at[order].set(
-                jnp.arange(n * k, dtype=jnp.int32))
-            sizes = jnp.zeros((held_n + 1,), jnp.int32).at[group].add(1)
+            order, sizes = expert_order(group, held_n)
             held_total = jnp.sum(sizes[:held_n])
             gate_rows = jnp.where(held, gates, 0.0).reshape(-1)
 
         w_gate, w_up, w_down = HeldExperts(cfg, name="experts")()
 
-        def span(first, inv, held, gs, live):
+        def span(first, gs, live):
             """The held experts' part of the assignments ``first`` (a
-            run of ``order``): ``inv`` and ``held`` say where in the
-            run an assignment's row lies and whether it is there, ``gs``
-            how many of its rows each expert has, ``live`` which rows
-            belong to a held expert at all."""
+            run of ``order``): ``gs`` says how many of its rows each
+            expert has, ``live`` which rows belong to a held expert at
+            all."""
             with jax.named_scope("moe.dispatch"):
-                rows = rows_to_experts(xf, first, inv, held, k)
+                plan = span_of(first, live, n, k)
+                rows = rows_to_experts(xf, plan, k)
             with jax.named_scope("moe.experts"):
                 hidden = nn.silu(jax.lax.ragged_dot(rows, w_gate, gs)) \
                     * jax.lax.ragged_dot(rows, w_up, gs)
@@ -370,15 +470,15 @@ class ExpertLayer(nn.Module):
             with jax.named_scope("moe.combine"):
                 # rows behind the last group are whatever the
                 # buffer held: never let them meet a gradient
-                out = jnp.where(live, out, 0) \
+                out = jnp.where(live[:, None], out, 0) \
                     * gate_rows[first][:, None].astype(out.dtype)
-                return rows_from_experts(out, first, inv, held, k)
+                return rows_from_experts(out, plan, k)
 
         def routed(cap):
             """The held experts' part through a row buffer of ``cap``."""
             def run(_):
-                return span(order[:cap], inv, held, sizes[:held_n],
-                            jnp.arange(cap)[:, None] < held_total)
+                return span(order[:cap], sizes[:held_n],
+                            jnp.arange(cap) < held_total)
             return run
 
         def walked(cap):
@@ -392,17 +492,14 @@ class ExpertLayer(nn.Module):
             padded = jnp.pad(order, (0, slabs * cap - n * k))
             ends = jnp.cumsum(sizes[:held_n])
             starts = ends - sizes[:held_n]
-            place = inv.reshape(held.shape)
 
             @jax.checkpoint
             def slab(y, lo):
                 first = jax.lax.dynamic_slice(padded, (lo,), (cap,))
-                inside = held & (place >= lo) & (place < lo + cap)
                 gs = jnp.clip(ends, lo, lo + cap) \
                     - jnp.clip(starts, lo, lo + cap)
-                live = (lo + jnp.arange(cap))[:, None] < held_total
-                return y + span(first, jnp.clip(inv - lo, 0, cap - 1),
-                                inside, gs, live).astype(jnp.float32), None
+                live = lo + jnp.arange(cap) < held_total
+                return y + span(first, gs, live).astype(jnp.float32), None
 
             def run(_):
                 y, _ = jax.lax.scan(
@@ -433,8 +530,8 @@ class ExpertLayer(nn.Module):
                 y = y + shared
         # held assignments whose row lies behind the buffer that ran: the
         # last tier holds every row, so none
-        dropped = jnp.sum(held.reshape(-1)
-                          & (inv >= jnp.asarray(caps, jnp.int32)[tier]))
+        dropped = jnp.maximum(
+            held_total - jnp.asarray(caps, jnp.int32)[tier], 0)
         counts = jnp.concatenate([sizes, dropped[None]])
         return y.reshape(x.shape), counts
 

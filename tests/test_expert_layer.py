@@ -3,9 +3,13 @@
 and without a shared expert, against a dense masked sum written here,
 the constant in its normaliser, its row buffer's tiers, the tier that
 walks the rows a slab at a time against the one-buffer result with
-every assignment forced onto held experts, the eight shares of a layer
-adding up to the uncut layer, and the two older models' parameter trees
-as they were."""
+every assignment forced onto held experts, the buffer's index plan and
+the way back to token order against the pick of every assignment they
+replaced, what the differentiated layer traces (no array of tokens x k
+rows by width, no scatter of tokens x k scalars) and says of it in the
+registry, the eight shares of a layer adding up to the uncut layer, and
+the two older models' parameter trees as they were. (PR 40's tests: 3 s
+cold.)"""
 
 import dataclasses
 import hashlib
@@ -131,17 +135,16 @@ def test_the_layer_is_the_dense_masked_sum_under_either_scoring(scoring):
 
 @pytest.mark.parametrize("forced", [False, True],
                          ids=["the_sound_tier", "the_walked_tier"])
-def test_a_short_buffer_adds_its_rows_into_their_tokens(forced):
-    """Two of 32 experts held: the buffer is an eighth of the 192
-    assignments, under a fifth, so the way back to token order is an
-    add of the buffer's rows and not a pick for every assignment; with
-    every token forced onto the held pair the walked tier does the same
-    a slab at a time."""
+def test_a_buffer_an_eighth_of_the_assignments_finds_its_tokens(forced):
+    """Two of 32 experts held: the buffer is 24 rows, an eighth of the
+    192 assignments, and the way back to token order picks those 24 and
+    one row a token; with every token forced onto the held pair the
+    walked tier does the same a slab at a time."""
     cfg = config("softmax", n_routed_experts=32, experts_held=2,
                  num_experts_per_tok=K)
     layer, p, x = layer_and_params(cfg)
     cap = round(glm_moe.row_tiers(2, 32)[0] * 48 * K)
-    assert 48 * K > glm_moe.PICKED_ROWS_AN_ADDED_ROW * cap
+    assert 8 * cap == 48 * K
     if forced:
         x = jnp.abs(x) + 0.1
         kernel = np.asarray(p["router"]["kernel"]) * 0.1
@@ -164,6 +167,166 @@ def test_a_short_buffer_adds_its_rows_into_their_tokens(forced):
     assert int(counts[glm_moe.DROPPED]) == 0
     assert int(counts[:2].sum()) == (48 * 2 if forced else counts[:2].sum())
     assert (int(counts[:2].sum()) > cap) == forced
+
+
+def routed_choices(scoring):
+    """[48, K] choices of the layer's own router under ``scoring`` with
+    six of sixteen experts held, then bent: token 5 chooses four held
+    experts and nothing else, and nobody chooses the last held one."""
+    cfg = config(scoring, experts_held=6)
+    layer, p, x = layer_and_params(cfg)
+    _, sown = jax.jit(lambda p, x: layer.apply(
+        {"params": p}, x, mutable=["intermediates"]))(p, x)
+    chosen = np.array(sown["intermediates"]["chosen"][0])
+    last = OFFSET + 5
+    for row in chosen:
+        spare = iter(sorted(set(range(OFFSET)) - set(row)))
+        row[row == last] = next(spare)
+    chosen[5] = OFFSET + np.arange(K)
+    return cfg, chosen
+
+
+@pytest.mark.parametrize("scoring", ["sigmoid", "softmax"])
+def test_the_plan_is_the_stable_sorts_on_the_held_rows(scoring):
+    """``expert_order`` and ``span_of`` against NumPy's stable argsort
+    and its inverse, on the first tier's buffer and on a slab of 32
+    rows from row 16 on: the rows' assignments, the sizes, the live
+    rows in token order, each token's run."""
+    cfg, chosen = routed_choices(scoring)
+    n, held_n = chosen.shape[0], cfg.experts_held
+    local = chosen - OFFSET
+    held = (local >= 0) & (local < held_n)
+    group = np.where(held, local, held_n).reshape(-1)
+    order = np.argsort(group, kind="stable")
+    total = int(held.sum())
+    got_order, sizes = jax.jit(glm_moe.expert_order, static_argnums=1)(
+        jnp.asarray(group, jnp.int32), held_n)
+    np.testing.assert_array_equal(got_order, order)
+    np.testing.assert_array_equal(sizes,
+                                  np.bincount(group, minlength=held_n + 1))
+    assert sizes[held_n - 1] == 0 and held[5].all() and total >= 48
+    for lo, cap in ((0, round(glm_moe.row_tiers(held_n, EXPERTS)[0]
+                              * n * K)), (16, 32)):
+        rows = np.arange(lo, lo + cap)
+        live = rows < total
+        span = jax.jit(glm_moe.span_of, static_argnums=(2, 3))(
+            jnp.asarray(order[lo:lo + cap], jnp.int32), jnp.asarray(live),
+            n, K)
+        inside = int(live.sum())
+        # the buffer's assignments ascending are its tokens ascending
+        by_token = np.argsort(order[lo:lo + cap][live], kind="stable")
+        np.testing.assert_array_equal(span.row[:inside], by_token)
+        tokens = order[lo:lo + cap][live][by_token] // K
+        np.testing.assert_array_equal(span.token[:inside], tokens)
+        assert (np.asarray(span.token[inside:cap]) == n).all()
+        assert (np.asarray(span.token[cap:]) == -1).all() \
+            and span.token.shape[0] % glm_moe.RUN_BLOCK == 0
+        has = np.isin(np.arange(n), tokens)
+        np.testing.assert_array_equal(
+            span.first,
+            np.where(has, np.searchsorted(tokens, np.arange(n)), cap))
+    assert not has[:5].all() and inside == cap    # a slab inside
+
+
+@pytest.mark.parametrize("scoring", ["sigmoid", "softmax"])
+def test_the_way_back_is_the_pick_of_every_assignment(scoring):
+    """``rows_from_experts`` and its transpose against what they
+    replace (every assignment picks its row, the absent are masked, a
+    token's K are summed in float32), bfloat16 rows, bit for bit; a
+    cotangent's rows behind the last live one hold NaN and meet
+    nothing."""
+    cfg, chosen = routed_choices(scoring)
+    n, held_n = chosen.shape[0], cfg.experts_held
+    local = chosen - OFFSET
+    held = (local >= 0) & (local < held_n)
+    group = np.where(held, local, held_n).reshape(-1)
+    order = np.argsort(group, kind="stable")
+    inv = np.argsort(order)
+    cap, total = 160, int(held.sum())     # two blocks of the run sums
+    live = np.arange(cap) < total
+    rows = jnp.where(live[:, None], jax.random.normal(
+        jax.random.key(7), (cap, D), jnp.bfloat16), 0)
+    grad = jax.random.normal(jax.random.key(8), (n, D), jnp.bfloat16)
+
+    @jax.jit
+    def both(rows, grad):
+        span = glm_moe.span_of(jnp.asarray(order[:cap], jnp.int32),
+                               jnp.asarray(live), n, K)
+        back, pull = jax.vjp(
+            lambda r: glm_moe.rows_from_experts(r, span, K), rows)
+        there, push = jax.vjp(
+            lambda x: glm_moe.rows_to_experts(x, span, K), grad)
+        return (back, pull(grad)[0], there,
+                push(jnp.where(live[:, None], rows, jnp.nan))[0])
+
+    back, pulled, there, pushed = both(rows, grad)
+    picked = np.where(held.reshape(-1, 1), np.asarray(
+        rows.astype(jnp.float32))[np.minimum(inv, cap - 1)], 0)
+    want = jnp.asarray(picked.reshape(n, K, D).sum(1)).astype(jnp.bfloat16)
+    np.testing.assert_array_equal(back, want)
+    np.testing.assert_array_equal(pushed, want)
+    np.testing.assert_array_equal(there, grad[order[:cap] // K])
+    np.testing.assert_array_equal(
+        pulled, jnp.where(live[:, None], grad[order[:cap] // K], 0))
+    assert cap > glm_moe.RUN_BLOCK and cap % glm_moe.RUN_BLOCK
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(sub)
+
+
+@pytest.mark.parametrize("scoring", ["sigmoid", "softmax", "no_shared"])
+def test_the_differentiated_layer_handles_no_array_of_every_assignment(
+        scoring):
+    """The traced value and gradients of the layer, both tiers: no
+    array of tokens x k rows by the width, no scatter of tokens x k
+    updates, and the one sort of tokens x k keys is the plan's."""
+    cfg = config(scoring)
+    layer, p, x = layer_and_params(cfg)
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(
+        lambda p, x: jnp.sum(layer.apply({"params": p}, x)[0]),
+        argnums=(0, 1)))(p, x)
+    every = 48 * K
+    assert round(glm_moe.row_tiers(HELD, EXPERTS)[0] * every) < every
+    sorts = 0
+    for eqn in _equations(jaxpr.jaxpr):
+        for var in eqn.outvars:
+            shape = getattr(var.aval, "shape", ())
+            assert not (len(shape) == 2 and shape[0] >= every
+                        and shape[1] == D), (eqn.primitive, shape)
+        if eqn.primitive.name.startswith("scatter"):
+            assert eqn.invars[2].aval.shape[0] < every, eqn
+        if eqn.primitive.name == "sort" \
+                and eqn.invars[0].aval.shape[0] >= every:
+            sorts += 1
+    assert sorts == 1
+
+
+def test_a_differentiated_layer_writes_its_rows_into_the_registry(
+        monkeypatch):
+    import horovod_tpu.jax as hvd
+    from horovod_tpu import metrics
+    monkeypatch.setenv("HOROVOD_TPU_METRICS", "1")
+    hvd.init()
+    try:
+        layer, p, x = layer_and_params(config("sigmoid"))
+        loss = lambda p, x: jnp.sum(layer.apply({"params": p}, x)[0])
+        loss(p, x)
+        assert not [name for name in metrics()["local"]
+                    if name.startswith("hvd_moe_rows_moved")]
+        jax.grad(loss)(p, x)
+        local = metrics()["local"]
+        # the buffer's 96 rows filled up to a block of 128 places
+        for kind, want in (("assignments", 48 * K), ("buffer_rows", 96),
+                           ("picked_back", 128 + 48)):
+            assert local[f'hvd_moe_rows_moved{{kind="{kind}"}}']["v"] \
+                == want
+    finally:
+        hvd.shutdown()
 
 
 def test_a_scoring_the_layer_does_not_know_is_refused():
