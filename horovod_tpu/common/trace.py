@@ -360,6 +360,12 @@ DEVICE_SCOPES = {
     "gdn.conv": "its causal depthwise convolution and SiLU",
     "gdn.rule": "L2 norms, gates, running sums and the rule's two kernels",
     "gdn.gate": "the gated RMSNorm of the rule's output",
+    "kda.proj": "Kimi delta attention's q, k, v and output projections",
+    "kda.conv": "its causal depthwise convolution and SiLU",
+    "kda.gate": "the decay gate's projection and bounded sigmoid, beta "
+                "and the output gate's projection",
+    "kda.rule": "L2 norms and the per-channel rule's two kernels",
+    "kda.norm": "the RMSNorm of the rule's output a head, times its gate",
     "gated_attn": "gated grouped-head attention around its flash call",
     "shortconv.proj": "the gated short convolution's two projections",
     "shortconv.conv": "its two gates and the three causal depthwise taps",

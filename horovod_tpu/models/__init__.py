@@ -15,6 +15,7 @@ from horovod_tpu.models.glm_moe import GlmMoeConfig, GlmMoeLM
 from horovod_tpu.models.phi4flash import Phi4FlashConfig, Phi4FlashLM
 from horovod_tpu.models.qwen3next import Qwen3NextConfig, Qwen3NextLM
 from horovod_tpu.models.lfm2 import Lfm2MoeConfig, Lfm2MoeLM
+from horovod_tpu.models.ling3flash import Ling3FlashConfig, Ling3FlashLM
 from horovod_tpu.models.mnist import MnistConvNet
 from horovod_tpu.models.vit import ViT, ViTConfig, ViT_S16, ViT_B16
 
@@ -22,6 +23,7 @@ __all__ = [
     "ResNet", "ResNet18", "ResNet34", "ResNet50", "ResNet101",
     "TransformerConfig", "TransformerLM", "GlmMoeConfig", "GlmMoeLM",
     "Phi4FlashConfig", "Phi4FlashLM", "Qwen3NextConfig", "Qwen3NextLM",
-    "Lfm2MoeConfig", "Lfm2MoeLM", "MnistConvNet",
+    "Lfm2MoeConfig", "Lfm2MoeLM", "Ling3FlashConfig", "Ling3FlashLM",
+    "MnistConvNet",
     "ViT", "ViTConfig", "ViT_S16", "ViT_B16",
 ]

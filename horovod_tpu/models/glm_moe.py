@@ -46,7 +46,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, NamedTuple
+from typing import Any, NamedTuple, Optional
 
 import flax.linen as nn
 import jax
@@ -62,11 +62,15 @@ class GlmMoeConfig:
     first_k_dense: int = 1
     hidden_size: int = 2048
     num_heads: int = 20
-    q_lora_rank: int = 768
+    q_lora_rank: Optional[int] = 768     # None: q = W_q x, no latent
     kv_lora_rank: int = 512
     qk_nope_head_dim: int = 192
     qk_rope_head_dim: int = 64
     v_head_dim: int = 256
+    # What else ``LatentAttention`` asks: an RMSNorm over the head on q
+    # and on the assembled k, and a sigmoid gate a head on the output.
+    qk_head_norm: bool = False
+    head_output_gate: bool = False
     intermediate_size: int = 10240
     moe_intermediate_size: int = 1536
     n_routed_experts: int = 64       # the router's width
@@ -78,6 +82,9 @@ class GlmMoeConfig:
     scoring: str = "sigmoid"
     shared_expert_gate: bool = False
     topk_weight_eps: float = 0.0
+    n_group: int = 1                 # the choice is not limited to groups
+    topk_group: int = 1
+    row_tier_headroom: float = 2.0   # ``ROW_TIER_HEADROOM``
     # The share of the experts this chip holds: ids
     # [expert_offset, expert_offset + experts_held).
     experts_held: int = 64
@@ -107,14 +114,15 @@ ABSENT, DROPPED = -2, -1
 ROW_TIER_HEADROOM = 2.0
 
 
-def row_tiers(experts_held: int, n_routed_experts: int) -> tuple:
+def row_tiers(experts_held: int, n_routed_experts: int,
+              headroom: float = ROW_TIER_HEADROOM) -> tuple:
     """Static sizes of the expert layer's row buffer, as shares of
-    tokens x k, ascending: ``ROW_TIER_HEADROOM`` times the share of the
-    experts held (a quarter where an eighth is held, an eighth for a
-    sixteenth), then 1.0, every assignment, so that nothing is ever
-    dropped. A layer that holds half the experts or more has the one
-    tier."""
-    first = ROW_TIER_HEADROOM * experts_held / n_routed_experts
+    tokens x k, ascending: ``headroom`` times the share of the experts
+    held (at the default a quarter where an eighth is held, an eighth
+    for a sixteenth), then 1.0, every assignment, so that nothing is
+    ever dropped. A layer that holds half the experts or more has the
+    one tier."""
+    first = headroom * experts_held / n_routed_experts
     return (first, 1.0) if first < 1.0 else (1.0,)
 
 
@@ -129,28 +137,65 @@ def _dense(cfg, features, name: str, axis=-1):
 
 
 class LatentAttention(nn.Module):
-    cfg: GlmMoeConfig
+    """Latent attention (DeepSeek-V2's MLA), nothing absorbed: keys and
+    values come through a normed latent of ``kv_lora_rank``, a rotary
+    part of ``qk_rope_head_dim`` that all heads share on the key side,
+    causal softmax attention at the score head's ``(nope + rope)^-1/2``
+    through ``best_attention`` (the value head may be narrower than the
+    score head).
+
+    One body for every model with such a layer; the configuration says
+    what differs. It is read for ``hidden_size``, ``num_heads``,
+    ``kv_lora_rank``, ``qk_nope_head_dim``, ``qk_rope_head_dim``,
+    ``v_head_dim``, ``rope_theta``, ``rms_norm_eps``, ``dtype`` and
+
+    * ``q_lora_rank``: the width of the queries' normed latent (``q_a``,
+      ``q_norm``, ``q_b``); ``None`` is ``q = W_q x`` (one kernel ``q``).
+      GLM-4.7-Flash 768, Ling-3.0-flash ``None``;
+    * ``qk_head_norm``: an RMSNorm over the head on q and on the
+      assembled k ([nope | shared rotary part]), before the rotary turns
+      their rotary parts (``q_head_norm``, ``k_head_norm``, one weight
+      vector for all heads each). Ling-3.0-flash only;
+    * ``head_output_gate``: the output of head ``h`` times
+      ``sigmoid(x W_g)_h``, ``W_g`` the layer's own ``d -> heads``
+      (``gate``). Ling-3.0-flash only."""
+
+    cfg: Any
 
     @nn.compact
     def __call__(self, x, positions):
         cfg = self.cfg
         h, nope, v_dim = cfg.num_heads, cfg.qk_nope_head_dim, cfg.v_head_dim
+        rope = cfg.qk_rope_head_dim
         with jax.named_scope("mla"):
-            c_q = _norm(cfg, "q_norm")(_dense(cfg, cfg.q_lora_rank, "q_a")(x))
-            q = _dense(cfg, (h, cfg.qk_head_dim), "q_b")(c_q)
-            kv = _dense(cfg, cfg.kv_lora_rank + cfg.qk_rope_head_dim,
-                        "kv_a")(x)
+            if cfg.q_lora_rank:
+                c_q = _norm(cfg, "q_norm")(
+                    _dense(cfg, cfg.q_lora_rank, "q_a")(x))
+                q = _dense(cfg, (h, nope + rope), "q_b")(c_q)
+            else:
+                q = _dense(cfg, (h, nope + rope), "q")(x)
+            kv = _dense(cfg, cfg.kv_lora_rank + rope, "kv_a")(x)
             c_kv = _norm(cfg, "kv_norm")(kv[..., :cfg.kv_lora_rank])
             k_rope = kv[..., None, cfg.kv_lora_rank:]          # [B,S,1,R]
             kv = _dense(cfg, (h, nope + v_dim), "kv_b")(c_kv)
+            if cfg.qk_head_norm:
+                k = jnp.concatenate(
+                    [kv[..., :nope],
+                     jnp.broadcast_to(k_rope, k_rope.shape[:2] + (h, rope))],
+                    -1)
+                q = _norm(cfg, "q_head_norm")(q)
+                k = _norm(cfg, "k_head_norm")(k)
+                k_nope, k_rope = k[..., :nope], k[..., nope:]
             q_rope = apply_rope(q[..., nope:], positions, cfg.rope_theta)
             k_rope = apply_rope(k_rope, positions, cfg.rope_theta)
             q = jnp.concatenate([q[..., :nope], q_rope], -1)
             k = jnp.concatenate(
-                [kv[..., :nope],
-                 jnp.broadcast_to(k_rope, k_rope.shape[:2]
-                                  + (h, cfg.qk_rope_head_dim))], -1)
+                [k_nope if cfg.qk_head_norm else kv[..., :nope],
+                 jnp.broadcast_to(k_rope, k_rope.shape[:2] + (h, rope))], -1)
             out = best_attention(q, k, kv[..., nope:], True)
+            if cfg.head_output_gate:
+                gate = _dense(cfg, h, "gate")(x).astype(jnp.float32)
+                out = out * jax.nn.sigmoid(gate)[..., None].astype(out.dtype)
             return _dense(cfg, cfg.hidden_size, "o", axis=(-2, -1))(out)
 
 
@@ -338,6 +383,22 @@ def _rows_from_experts_bwd(k, span, g):
 rows_from_experts.defvjp(_rows_from_experts_fwd, _rows_from_experts_bwd)
 
 
+def _within_best_groups(biased, n_group: int, topk_group: int):
+    """``biased`` [N, E] for a choice limited to groups (DeepSeek-V3's):
+    the experts in ``n_group`` groups of neighbours, a group's score the
+    sum of its two largest entries, every entry outside the best
+    ``topk_group`` groups at minus infinity. ``n_group`` 1 gives
+    ``biased`` itself."""
+    if n_group == 1:
+        return biased
+    n, e = biased.shape
+    grouped = biased.reshape(n, n_group, e // n_group)
+    group_score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
+    _, best = jax.lax.top_k(group_score, topk_group)           # [N, g]
+    kept = jnp.any(best[:, :, None] == jnp.arange(n_group), axis=1)
+    return jnp.where(kept[:, :, None], grouped, -jnp.inf).reshape(n, e)
+
+
 class Router(nn.Module):
     """The router's parameters: the float32 kernel over all the experts
     and, where the scores are sigmoids, the correction bias that only
@@ -396,13 +457,28 @@ class ExpertLayer(nn.Module):
       ``score + bias``, a correction bias the layer owns) or
       ``"softmax"`` (scores are a softmax over all the experts, no
       bias);
+    * ``n_group``, ``topk_group``: under sigmoid scores, the choice is
+      limited to the best ``topk_group`` of ``n_group`` groups of
+      neighbouring experts, a group scored by the sum of its two
+      largest ``score + bias`` (``_within_best_groups``); ``n_group`` 1
+      is a choice over all the experts;
+    * ``row_tier_headroom``: the first tier of the row buffer over the
+      share of the experts held (``row_tiers``; 2 but for
+      Ling-3.0-flash, whose sixty-fourth of the experts is too small a
+      share for twice its expectation to hold a step's assignments);
     * ``routed_scaling_factor``: what the normalised weights of a
       token's k choices are multiplied by;
     * ``topk_weight_eps``: what is added to the sum of a token's k
       chosen scores before they are divided by it (0: the bare sum);
     * ``shared_expert_gate``: whether the shared expert's output is
       multiplied by ``sigmoid(x w_s)``, ``w_s`` the layer's own
-      ``d -> 1``."""
+      ``d -> 1``.
+
+    What each model sets (the defaults are GLM-4.7-Flash's): GLM
+    sigmoid, scale 1.8, a shared expert without a gate; Qwen3-Next
+    softmax, scale 1, a gated shared expert; LFM2 sigmoid, scale 1, eps
+    1e-6, no shared expert; Ling-3.0-flash sigmoid, scale 2.5, 8 groups
+    of which 4, a shared expert without a gate."""
 
     cfg: Any
 
@@ -432,8 +508,9 @@ class ExpertLayer(nn.Module):
                 _, chosen = jax.lax.top_k(scores, k)           # [N, k]
             else:
                 scores = jax.nn.sigmoid(logits)
-                _, chosen = jax.lax.top_k(
-                    scores + jax.lax.stop_gradient(bias), k)
+                _, chosen = jax.lax.top_k(_within_best_groups(
+                    scores + jax.lax.stop_gradient(bias), cfg.n_group,
+                    cfg.topk_group), k)
             # kept only where a caller asks for ``intermediates``
             self.sow("intermediates", "chosen", chosen)
             picked = jnp.take_along_axis(scores, chosen, axis=-1)
@@ -509,7 +586,7 @@ class ExpertLayer(nn.Module):
             return run
 
         caps = [max(1, int(round(t * n * k)))
-                for t in row_tiers(held_n, e)]
+                for t in row_tiers(held_n, e, cfg.row_tier_headroom)]
         if len(caps) == 1:
             tier = 0
             y = routed(caps[0])(None)

@@ -82,6 +82,9 @@ class Lfm2MoeConfig:
     routed_scaling_factor: float = 1.0
     shared_expert_gate: bool = False
     topk_weight_eps: float = 1e-6
+    n_group: int = 1
+    topk_group: int = 1
+    row_tier_headroom: float = 2.0
     # The share of the experts this chip holds: ids
     # [expert_offset, expert_offset + experts_held).
     experts_held: int = 64

@@ -27,6 +27,7 @@ from horovod_tpu.common.basics import active_runtime
 from horovod_tpu.compat import jaxshim
 from horovod_tpu.models.glm_moe import ABSENT, DROPPED, GlmMoeLM
 from horovod_tpu.models.lfm2 import Lfm2MoeLM
+from horovod_tpu.models.ling3flash import Ling3FlashLM
 from horovod_tpu.models.phi4flash import Phi4FlashLM
 from horovod_tpu.models.qwen3next import Qwen3NextLM
 from horovod_tpu.models.resnet import ResNet50
@@ -291,6 +292,26 @@ def lfm2_train_step(model: Lfm2MoeLM, tx, mesh):
     recomputed with its kernels' outputs kept (``lfm2.RematBlock``),
     the counts for :class:`MoeLoadFeed`."""
     return _counted_train_step(lfm2_loss_fn(model), tx, mesh)
+
+
+def ling3flash_loss_fn(model: Ling3FlashLM):
+    """``(params, tokens) -> (loss, counts)``: next-token cross-entropy
+    through the chunked head on the untied ``lm_head``; ``counts`` are
+    the layers' loads ([layers, experts_held + 2], a dense layer's as
+    zeros)."""
+    def loss_fn(p, t):
+        hidden, counts = model.apply({"params": p}, t)
+        return lm_loss_from_hidden(hidden, p["lm_head"]["kernel"], t), counts
+    return loss_fn
+
+
+def ling3flash_train_step(model: Ling3FlashLM, tx, mesh):
+    """The Kimi-delta-attention sparse decoder's step,
+    ``glm_moe_train_step``'s shape: ``(params, opt_state, tokens) ->
+    (params, opt_state, loss, counts)``, state donated, every block
+    recomputed with its kernels' outputs kept
+    (``ling3flash.RematBlock``), the counts for :class:`MoeLoadFeed`."""
+    return _counted_train_step(ling3flash_loss_fn(model), tx, mesh)
 
 
 class MoeLoadFeed:

@@ -13,6 +13,8 @@ support at all. These are first-class here:
                         Pallas kernels (forward and backward)
 - ``gated_delta``     — the gated delta rule of a Gated DeltaNet layer
                         (a matrix state a head), as Pallas kernels
+- ``kda``             — Kimi delta attention: the same rule with a
+                        decay for every key channel, as Pallas kernels
 - ``trainer``         — composes dp x tp x sp x ep into one jitted step
 """
 
@@ -33,6 +35,7 @@ from horovod_tpu.parallel.trainer import (
 )
 from horovod_tpu.parallel.ssm_scan import selective_scan
 from horovod_tpu.parallel.gated_delta import gated_delta_rule
+from horovod_tpu.parallel.kda import kimi_delta_attention
 
 
 def __getattr__(name):
@@ -50,5 +53,5 @@ __all__ = [
     "ulysses_attention", "make_ulysses_attention",
     "pipeline_stages", "make_pipeline_apply", "PipelinedLM",
     "Trainer", "TrainerConfig", "make_chunked_lm_loss",
-    "selective_scan", "gated_delta_rule",
+    "selective_scan", "gated_delta_rule", "kimi_delta_attention",
 ]

@@ -773,6 +773,19 @@ def _shapes_ok(seq_q, seq_k, block_q, block_k):
 # 2048x1024 (the forward) and 1024x2048 (the dq kernel) do not fit
 # VMEM. The q tile of 1024 that PR 31 gave a head of 64 holds at four
 # query heads a key-value head and half the sequence: no rung changed.
+#
+# Measured at a score head of 192 over a value head of 128 on v5e
+# silicon (PR 41: B1, 32 heads, S16384, the latent attention of
+# models/ling3flash.py, the kernels' first D != Dv shape; one call's
+# forward, and forward + backward less that forward, by the host's
+# clock, ms; 256x256 sub-tiles):
+#   512x1024   34.85 +  87.68 = 122.53   (the ladder's own pick; again
+#                                         with the tiles named: 122.41)
+#   512x512    48.43 +  98.29 = 146.72
+#   256x1024   47.64 + 102.37 = 150.01
+# 1024x1024 does not fit VMEM (the dk/dv kernel). The default pair holds
+# at 192 / 128: no rung changed; 12.6 TFLOP of needed scores in 122.5 ms
+# is 52% of the chip's peak.
 _BLOCK_Q_LADDER = (512, 256, 128)
 _BLOCK_K_LADDER = (1024, 512, 256, 128)
 _HEAD_DIM_BASE = 256  # the largest D the default ladder was measured at

@@ -147,10 +147,12 @@ def _mm_f32(x, y, dims=_NN):
                                precision=jax.lax.Precision.HIGHEST)
 
 
-def _unit_lower_inverse(a):
+def _unit_lower_inverse(a, order: Optional[int] = None):
     """``(I - a)^-1`` of a strictly lower triangular ``a`` [C, C]:
-    ``(I + a)(I + a^2)(I + a^4)...``, exact because ``a^C = 0``."""
-    size = a.shape[0]
+    ``(I + a)(I + a^2)(I + a^4)...``, exact because ``a^C = 0``
+    (``order``: a smaller power of two at which ``a`` vanishes already,
+    as a block-diagonal ``a`` of such blocks does)."""
+    size = a.shape[0] if order is None else order
     rows = jax.lax.broadcasted_iota(jnp.int32, a.shape, 0)
     cols = jax.lax.broadcasted_iota(jnp.int32, a.shape, 1)
     t = jnp.where(rows == cols, 1.0, 0.0).astype(_F32) + a

@@ -247,6 +247,52 @@ def test_the_gated_delta_rule_compiles_for_v5e(chip):
         assert name in compiled.as_text()
 
 
+def test_kimi_delta_attention_compiles_for_v5e(chip):
+    """``kda_fwd`` and ``kda_bwd`` at the cell's shape (one row of
+    16,384, 32 heads of 128, a float32 log-decay a key channel) and the
+    ladder's chunk and sub-block: the levels' masked products, the
+    diagonal level and the inverse at full precision, the running sums
+    taken inside the kernels."""
+    from horovod_tpu.parallel import kda
+    bt, seq, heads, d = 1, 16384, 32, 128
+    arr = lambda *shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dt, sharding=chip)
+    qkv = arr(bt, seq, heads, d)
+    args = (qkv, qkv, qkv, arr(bt, seq, heads, d, dt=jnp.float32),
+            arr(bt, seq, heads, dt=jnp.float32))
+
+    def loss(*x):
+        return jnp.sum(kda.kimi_delta_attention(*x, interpret=False)
+                       .astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=tuple(range(5)))) \
+        .lower(*args).compile()
+    assert _kernel_calls(compiled) == 2
+    for name in ("kda_fwd", "kda_bwd"):
+        assert name in compiled.as_text()
+    assert kda._lengths_for(seq) == (128, 16)
+
+
+def test_the_flash_kernels_compile_at_a_score_head_of_192_over_a_value_head_of_128(
+        chip):
+    """The latent attention of ``ling3flash-injit-1chip``: 32 heads, q
+    and k of 128 + 64, v and o of 128, S 16,384, the ladder's tiles for
+    a head of 192 (512x1024: 1024x1024 does not fit VMEM in the dk/dv
+    kernel there)."""
+    from horovod_tpu.parallel.flash_attention import flash_attention
+    arr = lambda d: jax.ShapeDtypeStruct((1, 16384, 32, d), jnp.bfloat16,
+                                         sharding=chip)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True, interpret=False)
+                       .astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        arr(192), arr(192), arr(128)).compile()
+    assert _kernel_calls(compiled) == 3
+    assert _top(192) == (512, 1024)
+
+
 def test_d256_keeps_the_default_pair_and_d512_is_halved():
     """The D=256 cases above compile the pair the chip measured fastest
     of the ladder there (PR 27), the D=512 case the halved one."""

@@ -7,9 +7,11 @@ every assignment forced onto held experts, the buffer's index plan and
 the way back to token order against the pick of every assignment they
 replaced, what the differentiated layer traces (no array of tokens x k
 rows by width, no scatter of tokens x k scalars) and says of it in the
-registry, the eight shares of a layer adding up to the uncut layer, and
-the two older models' parameter trees as they were. (PR 40's tests: 3 s
-cold.)"""
+registry, the eight shares of a layer adding up to the uncut layer, the
+choice limited to groups against a literal loop over tokens (a group
+whose two best lose is never chosen from; one group is the choice as it
+was, bit for bit), and the two older models' parameter trees as they
+were. (PR 40's tests: 3 s cold; PR 41's grouped cases: 4 s.)"""
 
 import dataclasses
 import hashlib
@@ -344,6 +346,29 @@ def test_the_tiers_follow_the_share_held(held, experts, want):
     assert glm_moe.row_tiers(held, experts) == want
 
 
+def test_the_first_tier_follows_the_configurations_headroom():
+    """``row_tier_headroom`` (2 in the three older configurations, 8 in
+    the one that holds a sixty-fourth): the buffer that ran is the
+    first tier where it holds the held assignments, and the result is
+    the dense sum at either size."""
+    assert glm_moe.row_tiers(8, 512) == (1 / 32, 1.0)
+    assert glm_moe.row_tiers(8, 512, 8.0) == (1 / 8, 1.0)
+    assert glm_moe.row_tiers(8, 64, 8.0) == (1.0,)
+    from horovod_tpu.models import ling3flash
+    assert ling3flash.Ling3FlashConfig().row_tier_headroom == 8.0
+    assert config("sigmoid").row_tier_headroom == 2.0 \
+        == config("softmax").row_tier_headroom \
+        == config("no_shared").row_tier_headroom
+    wide = config("sigmoid", experts_held=2, row_tier_headroom=4.0)
+    layer, p, x = layer_and_params(wide)
+    y, counts = jax.jit(layer.apply)({"params": p}, x)
+    np.testing.assert_allclose(y, dense(wide, p, x), **TOL)
+    narrow = dataclasses.replace(wide, row_tier_headroom=2.0)
+    y2, counts2 = jax.jit(glm_moe.ExpertLayer(narrow).apply)({"params": p}, x)
+    np.testing.assert_allclose(y2, y, **TOL)
+    np.testing.assert_array_equal(counts, counts2)
+
+
 @pytest.mark.parametrize("scoring", ["sigmoid", "softmax"])
 def test_the_walked_tier_is_the_one_buffer(scoring, monkeypatch):
     """Every token's four choices forced onto the four held experts: 192
@@ -374,7 +399,7 @@ def test_the_walked_tier_is_the_one_buffer(scoring, monkeypatch):
                                           has_aux=True))(p, x)
 
     (_, (walked, counts)), walked_grads = run()
-    monkeypatch.setattr(glm_moe, "row_tiers", lambda held, experts: (1.0,))
+    monkeypatch.setattr(glm_moe, "row_tiers", lambda *_: (1.0,))
     (_, (whole, counts_one)), whole_grads = run()
     assert int(counts[:HELD].sum()) == 48 * K == int(counts_one[:HELD].sum())
     assert int(counts[glm_moe.ABSENT]) == 0 == int(counts[glm_moe.DROPPED])
@@ -390,8 +415,7 @@ def test_a_ragged_last_slab_loses_nothing(monkeypatch):
     """Tiers of 0.3: 192 assignments in slabs of 58, the last one 18
     rows long and padded."""
     cfg = config("sigmoid")
-    monkeypatch.setattr(glm_moe, "row_tiers",
-                        lambda held, experts: (0.3, 1.0))
+    monkeypatch.setattr(glm_moe, "row_tiers", lambda *_: (0.3, 1.0))
     layer, p, x = layer_and_params(cfg)
     bias = np.zeros(EXPERTS, np.float32)
     bias[[OFFSET, OFFSET + 1, OFFSET + 3, 0]] = 10.0
@@ -455,6 +479,118 @@ def test_the_eight_shares_add_up_to_the_uncut_layer():
     np.testing.assert_allclose(total, want, **TOL)
     np.testing.assert_allclose(
         jax.jit(layer.apply)({"params": p}, x)[0], want, **TOL)
+
+
+# -- the choice limited to groups --------------------------------------------
+
+GROUPS, TOP_GROUPS = 4, 2
+
+
+def literal_grouped_choice(biased, n_group, topk_group, k):
+    """Token by token on the host: a group's score is the sum of its
+    two largest entries, the best ``topk_group`` groups stay, and the
+    ``k`` largest entries inside them are the choice."""
+    chosen = []
+    for row in np.asarray(biased):
+        groups = row.reshape(n_group, -1)
+        score = np.sort(groups, -1)[:, -2:].sum(-1)
+        best = np.argsort(-score, kind="stable")[:topk_group]
+        inside = np.full_like(groups, -np.inf)
+        inside[best] = groups[best]
+        chosen.append(np.argsort(-inside.reshape(-1), kind="stable")[:k])
+    return np.array(chosen)
+
+
+def sown_choice(cfg, p, x):
+    (_, _), state = jax.jit(lambda p, x: glm_moe.ExpertLayer(cfg).apply(
+        {"params": p}, x, mutable=["intermediates"]))(p, x)
+    return np.asarray(state["intermediates"]["chosen"][0])
+
+
+def biased_scores(p, x):
+    return jax.nn.sigmoid(x.reshape(-1, D) @ p["router"]["kernel"]) \
+        + p["router"]["bias"]
+
+
+@pytest.mark.parametrize("groups, top", [(4, 2), (4, 1), (2, 1), (8, 3)])
+def test_the_grouped_choice_is_the_literal_loop(groups, top):
+    """16 experts in ``groups`` groups of neighbours, the best ``top``
+    kept: the layer's choice is the loop's, token by token, it lies
+    inside ``top`` groups, and the layer is the dense masked sum over
+    that choice (values and counts)."""
+    cfg = config("sigmoid", n_group=groups, topk_group=top,
+                 num_experts_per_tok=2, routed_scaling_factor=2.5)
+    layer, p, x = layer_and_params(cfg)
+    p["router"]["bias"] = jax.random.normal(jax.random.key(3),
+                                            (EXPERTS,)) * 0.3
+    got = sown_choice(cfg, p, x)
+    want = literal_grouped_choice(biased_scores(p, x), groups, top, 2)
+    np.testing.assert_array_equal(np.sort(got, -1), np.sort(want, -1))
+    size = EXPERTS // groups
+    assert all(len(set(row // size)) <= top for row in got)
+    free = sown_choice(config("sigmoid", num_experts_per_tok=2), p, x)
+    assert (np.sort(free, -1) != np.sort(got, -1)).any()
+
+    def masked_dense(p, x):
+        xf = x.reshape(-1, D)
+        s = jax.nn.sigmoid(xf @ p["router"]["kernel"])
+        picked = s * jnp.sum(jax.nn.one_hot(want, EXPERTS), axis=1)
+        w = 2.5 * picked / jnp.sum(picked, -1, keepdims=True)
+        swiglu = lambda g, u, d_: (nn.silu(xf @ g) * (xf @ u)) @ d_
+        y = swiglu(*(p["shared"][n]["kernel"] for n in ("gate", "up", "down")))
+        for j in range(HELD):
+            y = y + w[:, OFFSET + j, None] * swiglu(
+                p["experts"]["gate"][j], p["experts"]["up"][j],
+                p["experts"]["down"][j])
+        return y.reshape(x.shape)
+
+    y, counts = jax.jit(layer.apply)({"params": p}, x)
+    np.testing.assert_allclose(y, jax.jit(masked_dense)(p, x), **TOL)
+    held = (want >= OFFSET) & (want < OFFSET + HELD)
+    assert int(counts[:HELD].sum()) == int(held.sum())
+    assert int(counts[glm_moe.DROPPED]) == 0
+
+
+def test_a_group_whose_two_best_lose_is_never_chosen_from():
+    """Expert 0 carries the largest ``score + bias`` of all, alone in
+    its group: the group's two best sum to less than two full groups',
+    so no token ever takes expert 0; a choice over all the experts
+    takes it every time."""
+    bias = np.zeros(EXPERTS, np.float32)
+    bias[0], bias[1:4] = 3.0, -3.0          # group 0: one giant, three dwarfs
+    bias[4:12] = 1.5                        # groups 1 and 2: all good
+    cfg = config("sigmoid", n_group=GROUPS, topk_group=TOP_GROUPS)
+    _, p, x = layer_and_params(cfg)
+    p["router"]["bias"] = jnp.asarray(bias)
+    scores = np.asarray(biased_scores(p, x))
+    assert (scores.argmax(-1) == 0).all()
+    got = sown_choice(cfg, p, x)
+    assert not (got < 4).any() and ((got >= 4) & (got < 12)).all()
+    assert (sown_choice(config("sigmoid"), p, x) == 0).any(axis=-1).all()
+
+
+@pytest.mark.parametrize("scoring", ["sigmoid", "no_shared"])
+def test_one_group_is_the_choice_as_it_was_bit_for_bit(scoring):
+    """``n_group`` 1 hands the scores on untouched (the same array, no
+    operation added to the step), so the choice is ``top_k(score +
+    bias)`` to the bit; so is a choice among all of several groups."""
+    cfg = config(scoring)
+    assert (cfg.n_group, cfg.topk_group) == (1, 1) \
+        == (qwen3next.Qwen3NextConfig().n_group,
+            qwen3next.Qwen3NextConfig().topk_group)
+    _, p, x = layer_and_params(cfg)
+    p["router"]["bias"] = jax.random.normal(jax.random.key(3),
+                                            (EXPERTS,)) * 0.3
+    biased = biased_scores(p, x)
+    assert glm_moe._within_best_groups(biased, 1, 1) is biased
+    want = np.asarray(jax.lax.top_k(biased, K)[1])
+    np.testing.assert_array_equal(sown_choice(cfg, p, x), want)
+    every = dataclasses.replace(cfg, n_group=4, topk_group=4)
+    np.testing.assert_array_equal(sown_choice(every, p, x), want)
+    lowered = lambda c: jax.jit(glm_moe.ExpertLayer(c).apply).lower(
+        {"params": p}, x).as_text()
+    assert lowered(cfg) == lowered(dataclasses.replace(
+        cfg, n_group=1, topk_group=3))
 
 
 LAYER_TREES = {
