@@ -53,6 +53,7 @@ import jax
 import jax.numpy as jnp
 
 from horovod_tpu.models.transformer import apply_rope, best_attention
+from horovod_tpu.parallel import qkv_prologue
 
 
 @dataclasses.dataclass(frozen=True)
@@ -631,11 +632,14 @@ class Block(nn.Module):
         return x + y, counts
 
 
-def _keep_kernel_outputs(prim, *_, **__) -> bool:
+def _keep_kernel_outputs(prim, *_, **params) -> bool:
     """Remat policy: a recomputed block keeps what its Pallas kernels
     wrote (flash attention's output and row statistics), so the
-    backward pass does not run the forward kernel again."""
-    return prim.name == "pallas_call"
+    backward pass does not run the forward kernel again. Not the delta
+    rules' prologue: q, k and v are 403 MB a layer at 16,384 tokens and
+    a millisecond or two to make again."""
+    return prim.name == "pallas_call" and not str(
+        params.get("name")).startswith(qkv_prologue.KERNEL_PREFIX)
 
 
 # Every block is recomputed in the backward pass: at the widths this

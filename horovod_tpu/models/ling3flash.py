@@ -15,8 +15,10 @@ attention otherwise.
 
 * **Kimi delta attention** (:class:`KimiDeltaAttention`;
   arXiv:2510.26692): ``[q, k, v] = x W_qkv`` through a causal depthwise
-  convolution of ``short_conv_kernel_size`` taps and SiLU; q and k
-  L2-normalised by head, q scaled by ``D^-1/2``; ``f = x W_f +
+  convolution of ``short_conv_kernel_size`` taps and SiLU, q and k
+  L2-normalised by head, q scaled by ``D^-1/2`` (``qwen3next
+  .QkvPrologue``: one kernel pass each way, float32 inside,
+  ``parallel.qkv_prologue``); ``f = x W_f +
   dt_bias`` a key channel, ``g = kda_lower_bound sigmoid(exp(A_log_h)
   f)`` in float32 (the bounded gate: ``g`` in [lower, 0], which is what
   lets the rule's kernels hold a sub-block of 16 positions in float32);
@@ -37,9 +39,9 @@ attention otherwise.
 The model may hold any subset of the published layers
 (``kept_layers``); each keeps its published index, which fixes its
 mixer and its feed-forward. Every block is recomputed in the backward
-pass with its kernels' outputs kept. The model returns the pre-head
-states and the layers' load counts; ``train_steps.ling3flash_loss_fn``
-turns them into the next-token cross-entropy on an untied head.
+pass with its kernels' outputs kept, the prologue's q, k and v made
+again. The model returns the pre-head states and the layers' load
+counts; ``train_steps.ling3flash_loss_fn`` turns them into the next-token cross-entropy on an untied head.
 """
 
 from __future__ import annotations
@@ -54,8 +56,7 @@ import jax.numpy as jnp
 from horovod_tpu.models.glm_moe import (
     ExpertLayer, LatentAttention, SwiGLU, _dense, _keep_kernel_outputs, _norm,
 )
-from horovod_tpu.models.phi4flash import CausalDepthwiseConv
-from horovod_tpu.models.qwen3next import _l2_normalised
+from horovod_tpu.models.qwen3next import QkvPrologue
 from horovod_tpu.parallel.kda import kimi_delta_attention
 
 
@@ -137,8 +138,8 @@ class KimiDeltaAttention(nn.Module):
         with jax.named_scope("kda.proj"):
             qkv = _dense(cfg, 3 * width, "in_proj_qkv")(x)
         with jax.named_scope("kda.conv"):
-            qkv = nn.silu(CausalDepthwiseConv(
-                cfg.short_conv_kernel_size, use_bias=False, name="conv")(qkv))
+            q, k, v = QkvPrologue(cfg.short_conv_kernel_size, 3 * width, d,
+                                  2 * h, h, name="conv")(qkv)
         a_log = self.param("A_log", nn.initializers.zeros, (h,), jnp.float32)
         dt_bias = self.param("dt_bias", nn.initializers.zeros, (width,),
                              jnp.float32)
@@ -153,14 +154,9 @@ class KimiDeltaAttention(nn.Module):
                 jnp.exp(a_log + cfg.a_log_init)[:, None] * f)
             beta = jax.nn.sigmoid(bz[..., :h])
         with jax.named_scope("kda.rule"):
-            # float32 up to the kernel's door: the norms divide by a sum
-            # of squares
-            q = _l2_normalised(qkv[..., :width].reshape(*lead, h, d)) \
-                * d ** -0.5
-            k = _l2_normalised(qkv[..., width:2 * width].reshape(*lead, h, d))
-            v = qkv[..., 2 * width:].reshape(*lead, h, d)
-            o = kimi_delta_attention(q.astype(cfg.dtype), k.astype(cfg.dtype),
-                                     v.astype(cfg.dtype), g, beta)
+            o = kimi_delta_attention(
+                q.reshape(*lead, h, d), k.reshape(*lead, h, d),
+                v.reshape(*lead, h, d), g, beta)
         with jax.named_scope("kda.norm"):
             y = nn.RMSNorm(epsilon=cfg.rms_norm_eps, dtype=jnp.float32,
                            param_dtype=jnp.float32, name="norm")(

@@ -15,9 +15,11 @@ DeltaNet otherwise.
 
 * **Gated DeltaNet** (:class:`GatedDeltaNet`; arXiv:2412.06464): ``[q,
   k, v, z] = x W_qkvz``, ``[b, a] = x W_ba``; a causal depthwise
-  convolution and SiLU on ``[q, k, v]``; ``beta = sigmoid(b)``, ``g =
-  -exp(A_log) softplus(a + dt_bias)`` in float32; q and k
-  L2-normalised by head, q scaled by ``Dk^-1/2``; the gated delta rule
+  convolution and SiLU on ``[q, k, v]``, q and k L2-normalised by head,
+  q scaled by ``Dk^-1/2`` (:class:`QkvPrologue`: one kernel pass each
+  way, float32 inside, ``parallel.qkv_prologue``); ``beta =
+  sigmoid(b)``, ``g = -exp(A_log) softplus(a + dt_bias)`` in float32;
+  the gated delta rule
   (``parallel.gated_delta``: a float32 matrix state a value head, each
   key head serving ``Hv / Hk`` value heads); ``y = RMS(o) w_n
   silu(z)`` by head; ``out = y W_o``.
@@ -35,8 +37,8 @@ DeltaNet otherwise.
 The model may hold any subset of the published layers
 (``kept_layers``); each keeps its published index, which fixes its
 kind. Every block is recomputed in the backward pass with its kernels'
-outputs kept. The model returns the pre-head states and the expert
-layers' load counts; ``train_steps.qwen3next_loss_fn`` turns them into
+outputs kept, the prologue's q, k and v made again. The model returns
+the pre-head states and the expert layers' load counts; ``train_steps.qwen3next_loss_fn`` turns them into
 the next-token cross-entropy on an untied head.
 """
 
@@ -50,9 +52,9 @@ import jax
 import jax.numpy as jnp
 
 from horovod_tpu.models.glm_moe import ExpertLayer, _keep_kernel_outputs
-from horovod_tpu.models.phi4flash import CausalDepthwiseConv
 from horovod_tpu.models.transformer import apply_rope
 from horovod_tpu.parallel.gated_delta import gated_delta_rule
+from horovod_tpu.parallel.qkv_prologue import qkv_prologue
 
 
 @dataclasses.dataclass(frozen=True)
@@ -152,8 +154,28 @@ def _norm(cfg: Qwen3NextConfig, name: str):
     return ZeroCentredRMSNorm(cfg.rms_norm_eps, cfg.dtype, name=name)
 
 
-def _l2_normalised(x, eps: float = 1e-6):
-    return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), -1, keepdims=True) + eps)
+class QkvPrologue(nn.Module):
+    """``(q, k, v)``, each [B, S, heads x head_dim] in ``x``'s type,
+    from the leading ``channels`` columns of the fused projection's
+    output: a causal depthwise convolution of ``taps`` taps (``kernel``
+    [taps, channels] float32, the leaf ``CausalDepthwiseConv`` holds)
+    and SiLU; the first ``normalised_heads`` heads (q's and k's)
+    L2-normalised, the first ``scaled_heads`` of them (q's) scaled by
+    ``head_dim ** -0.5``. One pass of ``parallel.qkv_prologue`` each
+    way; the Gated DeltaNet's and Kimi delta attention's both."""
+
+    taps: int
+    channels: int
+    head_dim: int
+    normalised_heads: int
+    scaled_heads: int
+
+    @nn.compact
+    def __call__(self, x):
+        kernel = self.param("kernel", nn.initializers.lecun_normal(),
+                            (self.taps, self.channels), jnp.float32)
+        return qkv_prologue(x, kernel, self.head_dim, self.normalised_heads,
+                            self.scaled_heads)
 
 
 class GatedDeltaNet(nn.Module):
@@ -170,26 +192,21 @@ class GatedDeltaNet(nn.Module):
             qkvz = _dense(cfg, 2 * keys + 2 * values, "in_proj_qkvz")(x)
             ba = _dense(cfg, 2 * hv, "in_proj_ba")(x).astype(jnp.float32)
         with jax.named_scope("gdn.conv"):
-            qkv = nn.silu(CausalDepthwiseConv(
-                cfg.linear_conv_kernel_dim, use_bias=False, name="conv")(
-                    qkvz[..., :2 * keys + values]))
+            q, k, v = QkvPrologue(
+                cfg.linear_conv_kernel_dim, 2 * keys + values, dk, 2 * hk, hk,
+                name="conv")(qkvz)
         a_log = self.param("A_log", nn.initializers.zeros, (hv,),
                            jnp.float32)
         dt_bias = self.param("dt_bias", nn.initializers.zeros, (hv,),
                              jnp.float32)
         with jax.named_scope("gdn.rule"):
-            # float32 up to the kernel's door: g is an exponent's
-            # argument, and the norms divide by a sum of squares
-            q = _l2_normalised(qkv[..., :keys].reshape(*lead, hk, dk)) \
-                * dk ** -0.5
-            k = _l2_normalised(
-                qkv[..., keys:2 * keys].reshape(*lead, hk, dk))
-            v = qkv[..., 2 * keys:].reshape(*lead, hv, dv)
+            # float32 up to the kernel's door: g is an exponent's argument
             beta = jax.nn.sigmoid(ba[..., :hv])
             g = -jnp.exp(a_log + cfg.a_log_init) * jax.nn.softplus(
                 ba[..., hv:] + dt_bias + cfg.dt_bias_init)
-            o = gated_delta_rule(q.astype(cfg.dtype), k.astype(cfg.dtype),
-                                 v.astype(cfg.dtype), g, beta)
+            o = gated_delta_rule(
+                q.reshape(*lead, hk, dk), k.reshape(*lead, hk, dk),
+                v.reshape(*lead, hv, dv), g, beta)
         with jax.named_scope("gdn.gate"):
             z = qkvz[..., 2 * keys + values:].reshape(*lead, hv, dv)
             y = nn.RMSNorm(epsilon=cfg.rms_norm_eps, dtype=jnp.float32,

@@ -15,6 +15,10 @@ support at all. These are first-class here:
                         (a matrix state a head), as Pallas kernels
 - ``kda``             — Kimi delta attention: the same rule with a
                         decay for every key channel, as Pallas kernels
+- ``qkv_prologue``    — the way from the fused q, k, v projection to
+                        both rules' kernels (causal taps, SiLU, the L2
+                        norm a head, the scale, the cast), one Pallas
+                        pass each way (``qkv_prologue.qkv_prologue``)
 - ``trainer``         — composes dp x tp x sp x ep into one jitted step
 """
 

@@ -273,6 +273,40 @@ def test_kimi_delta_attention_compiles_for_v5e(chip):
     assert kda._lengths_for(seq) == (128, 16)
 
 
+# (case id, q, k and v heads of 128, columns of x)
+_PROLOGUE_SHAPES = [("kimi_delta_attention", 32, 32, 32, 12288),
+                    ("gated_deltanet_in_qkvz", 16, 16, 32, 12288)]
+
+
+@pytest.mark.parametrize("hq,hk,hv,columns",
+                         [c[1:] for c in _PROLOGUE_SHAPES],
+                         ids=[c[0] for c in _PROLOGUE_SHAPES])
+def test_the_delta_rules_prologue_compiles_for_v5e(chip, hq, hk, hv,
+                                                   columns):
+    """``qkv_prologue_fwd`` and ``qkv_prologue_bwd`` at the two cells'
+    shapes (one row of 16,384, bfloat16; the Gated DeltaNet's 8,192
+    columns are the leading ones of ``qkvz``'s 12,288) and the ladder's
+    tile: a head's columns at a dynamic offset, the taps' reads off the
+    float32 tiling, the tile's blocks inside the kernels' VMEM."""
+    from horovod_tpu.parallel import qkv_prologue as qp
+    seq, d = 16384, 128
+    x = jax.ShapeDtypeStruct((1, seq, columns), jnp.bfloat16, sharding=chip)
+    w = jax.ShapeDtypeStruct((4, (hq + hk + hv) * d), jnp.float32,
+                             sharding=chip)
+
+    def loss(x, w):
+        return sum(jnp.sum(o.astype(jnp.float32)) for o in qp.qkv_prologue(
+            x, w, d, hq + hk, hq, interpret=False))
+
+    # the backward needs x and the kernel alone: the value keeps the
+    # forward kernel in the program
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))) \
+        .lower(x, w).compile()
+    assert _kernel_calls(compiled) == 2
+    for name in ("qkv_prologue_fwd", "qkv_prologue_bwd"):
+        assert name in compiled.as_text()
+
+
 def test_the_flash_kernels_compile_at_a_score_head_of_192_over_a_value_head_of_128(
         chip):
     """The latent attention of ``ling3flash-injit-1chip``: 32 heads, q
