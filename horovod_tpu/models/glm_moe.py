@@ -53,7 +53,7 @@ import jax
 import jax.numpy as jnp
 
 from horovod_tpu.models.transformer import apply_rope, best_attention
-from horovod_tpu.parallel import qkv_prologue
+from horovod_tpu.parallel import delta_epilogue, qkv_prologue
 
 
 @dataclasses.dataclass(frozen=True)
@@ -632,14 +632,19 @@ class Block(nn.Module):
         return x + y, counts
 
 
+# Kernels whose outputs a recomputed block makes again, by name.
+_REMADE_KERNELS = (qkv_prologue.KERNEL_PREFIX, delta_epilogue.KERNEL_PREFIX)
+
+
 def _keep_kernel_outputs(prim, *_, **params) -> bool:
     """Remat policy: a recomputed block keeps what its Pallas kernels
     wrote (flash attention's output and row statistics), so the
     backward pass does not run the forward kernel again. Not the delta
-    rules' prologue: q, k and v are 403 MB a layer at 16,384 tokens and
-    a millisecond or two to make again."""
+    rules' prologue nor their epilogue: q, k and v are 403 MB a layer
+    at 16,384 tokens and the gated norm's y 134, a millisecond or two
+    to make again."""
     return prim.name == "pallas_call" and not str(
-        params.get("name")).startswith(qkv_prologue.KERNEL_PREFIX)
+        params.get("name")).startswith(_REMADE_KERNELS)
 
 
 # Every block is recomputed in the backward pass: at the widths this

@@ -25,7 +25,8 @@ attention otherwise.
   ``[b, z] = x W_bz`` a head, ``beta = sigmoid(b)``; the rule with **a
   decay for every key channel** (``parallel.kda``: a float32 matrix
   state a head); ``y_h = sigmoid(z_h) RMS(o_h) w_n``, the norm a head's
-  own; ``out = y W_o``. No rotary.
+  own (``qwen3next.GatedHeadNorm``: one kernel pass each way, float32
+  inside, ``parallel.delta_epilogue``); ``out = y W_o``. No rotary.
 * **Latent attention**: ``glm_moe.LatentAttention``, told here that q
   has no latent of its own, that q and the assembled k are normed by
   head, and that each head's output has a sigmoid gate.
@@ -39,9 +40,10 @@ attention otherwise.
 The model may hold any subset of the published layers
 (``kept_layers``); each keeps its published index, which fixes its
 mixer and its feed-forward. Every block is recomputed in the backward
-pass with its kernels' outputs kept, the prologue's q, k and v made
-again. The model returns the pre-head states and the layers' load
-counts; ``train_steps.ling3flash_loss_fn`` turns them into the next-token cross-entropy on an untied head.
+pass with its kernels' outputs kept, the prologue's q, k and v and the
+gated norm's y made again. The model returns the pre-head states and
+the layers' load counts; ``train_steps.ling3flash_loss_fn`` turns them
+into the next-token cross-entropy on an untied head.
 """
 
 from __future__ import annotations
@@ -56,7 +58,7 @@ import jax.numpy as jnp
 from horovod_tpu.models.glm_moe import (
     ExpertLayer, LatentAttention, SwiGLU, _dense, _keep_kernel_outputs, _norm,
 )
-from horovod_tpu.models.qwen3next import QkvPrologue
+from horovod_tpu.models.qwen3next import GatedHeadNorm, QkvPrologue
 from horovod_tpu.parallel.kda import kimi_delta_attention
 
 
@@ -158,13 +160,10 @@ class KimiDeltaAttention(nn.Module):
                 q.reshape(*lead, h, d), k.reshape(*lead, h, d),
                 v.reshape(*lead, h, d), g, beta)
         with jax.named_scope("kda.norm"):
-            y = nn.RMSNorm(epsilon=cfg.rms_norm_eps, dtype=jnp.float32,
-                           param_dtype=jnp.float32, name="norm")(
-                               o.astype(jnp.float32)) \
-                * jax.nn.sigmoid(bz[..., h:])[..., None]
+            y = GatedHeadNorm(d, "sigmoid", cfg.rms_norm_eps, name="norm")(
+                o.reshape(*lead, width), bz[..., h:])
         with jax.named_scope("kda.proj"):
-            return _dense(cfg, cfg.hidden_size, "out_proj")(
-                y.astype(cfg.dtype).reshape(*lead, width))
+            return _dense(cfg, cfg.hidden_size, "out_proj")(y)
 
 
 class Block(nn.Module):

@@ -22,7 +22,8 @@ DeltaNet otherwise.
   the gated delta rule
   (``parallel.gated_delta``: a float32 matrix state a value head, each
   key head serving ``Hv / Hk`` value heads); ``y = RMS(o) w_n
-  silu(z)`` by head; ``out = y W_o``.
+  silu(z)`` by head (:class:`GatedHeadNorm`: one kernel pass each way,
+  float32 inside, ``parallel.delta_epilogue``); ``out = y W_o``.
 * **Gated attention** (:class:`GatedAttention`; arXiv:2505.06708):
   ``[q, gate] = x W_q`` split by head; a zero-centred RMSNorm over the
   head on q and k; rotary on the first ``partial_rotary_factor`` of the
@@ -37,9 +38,10 @@ DeltaNet otherwise.
 The model may hold any subset of the published layers
 (``kept_layers``); each keeps its published index, which fixes its
 kind. Every block is recomputed in the backward pass with its kernels'
-outputs kept, the prologue's q, k and v made again. The model returns
-the pre-head states and the expert layers' load counts; ``train_steps.qwen3next_loss_fn`` turns them into
-the next-token cross-entropy on an untied head.
+outputs kept, the prologue's q, k and v and the gated norm's y made
+again. The model returns the pre-head states and the expert layers'
+load counts; ``train_steps.qwen3next_loss_fn`` turns them into the
+next-token cross-entropy on an untied head.
 """
 
 from __future__ import annotations
@@ -53,6 +55,7 @@ import jax.numpy as jnp
 
 from horovod_tpu.models.glm_moe import ExpertLayer, _keep_kernel_outputs
 from horovod_tpu.models.transformer import apply_rope
+from horovod_tpu.parallel.delta_epilogue import delta_epilogue
 from horovod_tpu.parallel.gated_delta import gated_delta_rule
 from horovod_tpu.parallel.qkv_prologue import qkv_prologue
 
@@ -178,6 +181,28 @@ class QkvPrologue(nn.Module):
                             self.scaled_heads)
 
 
+class GatedHeadNorm(nn.Module):
+    """``y`` [B, S, heads x head_dim] in ``o``'s type for the output
+    projection: the rule's output ``o`` [B, S, heads x head_dim]
+    through an RMSNorm a head (``scale`` [head_dim] float32, the leaf
+    ``nn.RMSNorm`` holds) times ``activation`` of the gate: a head's
+    scalar ``gate`` [B, S, heads], or an element's in the ``heads x
+    head_dim`` columns of ``gate`` from ``gate_start`` on. One pass of
+    ``parallel.delta_epilogue`` each way; the Gated DeltaNet's and Kimi
+    delta attention's both."""
+
+    head_dim: int
+    activation: str
+    eps: float
+
+    @nn.compact
+    def __call__(self, o, gate, gate_start: int = 0):
+        scale = self.param("scale", nn.initializers.ones, (self.head_dim,),
+                           jnp.float32)
+        return delta_epilogue(o, scale, gate, self.head_dim, self.activation,
+                              gate_start, self.eps)
+
+
 class GatedDeltaNet(nn.Module):
     cfg: Qwen3NextConfig
 
@@ -208,14 +233,11 @@ class GatedDeltaNet(nn.Module):
                 q.reshape(*lead, hk, dk), k.reshape(*lead, hk, dk),
                 v.reshape(*lead, hv, dv), g, beta)
         with jax.named_scope("gdn.gate"):
-            z = qkvz[..., 2 * keys + values:].reshape(*lead, hv, dv)
-            y = nn.RMSNorm(epsilon=cfg.rms_norm_eps, dtype=jnp.float32,
-                           param_dtype=jnp.float32, name="norm")(
-                               o.astype(jnp.float32)) \
-                * nn.silu(z.astype(jnp.float32))
+            # z is qkvz's last columns, read where they lie
+            y = GatedHeadNorm(dv, "silu", cfg.rms_norm_eps, name="norm")(
+                o.reshape(*lead, values), qkvz, 2 * keys + values)
         with jax.named_scope("gdn.proj"):
-            return _dense(cfg, cfg.hidden_size, "out_proj")(
-                y.astype(cfg.dtype).reshape(*lead, values))
+            return _dense(cfg, cfg.hidden_size, "out_proj")(y)
 
 
 class GatedAttention(nn.Module):

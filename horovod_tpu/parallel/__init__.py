@@ -19,6 +19,10 @@ support at all. These are first-class here:
                         both rules' kernels (causal taps, SiLU, the L2
                         norm a head, the scale, the cast), one Pallas
                         pass each way (``qkv_prologue.qkv_prologue``)
+- ``delta_epilogue``  — the way from both rules' kernels to the output
+                        projection (the RMSNorm a head, its gate, the
+                        cast), one Pallas pass each way
+                        (``delta_epilogue.delta_epilogue``)
 - ``trainer``         — composes dp x tp x sp x ep into one jitted step
 """
 

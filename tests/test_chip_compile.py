@@ -307,6 +307,44 @@ def test_the_delta_rules_prologue_compiles_for_v5e(chip, hq, hk, hv,
         assert name in compiled.as_text()
 
 
+# (case id, the gate's shape, its activation, the column z starts at)
+_EPILOGUE_SHAPES = [
+    ("kimi_delta_attention", (1, 16384, 32), jnp.float32, "sigmoid", 0),
+    ("gated_deltanet_in_qkvz", (1, 16384, 12288), jnp.bfloat16, "silu",
+     8192)]
+
+
+@pytest.mark.parametrize("gate,gate_type,activation,start",
+                         [c[1:] for c in _EPILOGUE_SHAPES],
+                         ids=[c[0] for c in _EPILOGUE_SHAPES])
+def test_the_delta_rules_epilogue_compiles_for_v5e(chip, gate, gate_type,
+                                                   activation, start):
+    """``delta_epilogue_fwd`` and ``delta_epilogue_bwd`` at the two
+    cells' shapes (one row of 16,384, 32 heads of 128, bfloat16; Kimi
+    delta attention's gate a head's float32 scalar, the Gated
+    DeltaNet's the last 4,096 of ``qkvz``'s 12,288 columns) and the
+    ladder's tile: a head's columns at a dynamic offset, a head's gate
+    by a masked row sum of a tile 32 lanes wide, the tile's blocks
+    inside the kernels' VMEM."""
+    from horovod_tpu.parallel import delta_epilogue as de
+    o = jax.ShapeDtypeStruct((1, 16384, 4096), jnp.bfloat16, sharding=chip)
+    w = jax.ShapeDtypeStruct((128,), jnp.float32, sharding=chip)
+    z = jax.ShapeDtypeStruct(gate, gate_type, sharding=chip)
+
+    def loss(o, w, z):
+        return jnp.sum(de.delta_epilogue(
+            o, w, z, 128, activation, start, interpret=False)
+            .astype(jnp.float32))
+
+    # the backward needs o, the scale and the gate alone: the value
+    # keeps the forward kernel in the program
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))) \
+        .lower(o, w, z).compile()
+    assert _kernel_calls(compiled) == 2
+    for name in ("delta_epilogue_fwd", "delta_epilogue_bwd"):
+        assert name in compiled.as_text()
+
+
 def test_the_flash_kernels_compile_at_a_score_head_of_192_over_a_value_head_of_128(
         chip):
     """The latent attention of ``ling3flash-injit-1chip``: 32 heads, q
