@@ -252,6 +252,44 @@ def _frameworks_bytecode():
     proc.wait()
 
 
+# -- the process ends when pytest does --------------------------------------
+
+_PYTESTS_OWN_PROCESS = os.path.basename(sys.argv[0]) in ("pytest", "py.test") \
+    or sys.argv[0].endswith(os.path.join("pytest", "__main__.py"))
+_exit_status = None
+
+
+def pytest_sessionfinish(session, exitstatus):
+    global _exit_status
+    _exit_status = int(exitstatus)
+
+
+@pytest.hookimpl(trylast=True)
+def pytest_unconfigure(config):
+    """The last hook of a session: every fixture is torn down and the
+    summary is written. What is left to a process that pytest started
+    is the interpreter's shutdown, which no line of the summary counts
+    and nobody reads: after the driver's run of PR 43's tree it was
+    still going 32 s after pytest's last line, when `timeout` cut a run
+    whose every test had passed. It is the shutdown as a whole, not one
+    library's handler: after 90 tests (800,000 objects alive) the
+    process ends 3.8 s after this hook, 2.2 s of them before the first
+    ``atexit`` handler runs and 1.8 s in jax's ``clean_up``
+    (``clear_backends`` drops every traced and compiled program); with
+    that handler unregistered the rest takes 5.2 s. So the process
+    leaves here, with the exit status pytest chose and both streams
+    flushed. A program that calls ``pytest.main()`` itself gets its
+    answer back as ever."""
+    if _exit_status is None or not _PYTESTS_OWN_PROCESS:
+        return
+    reporter = config.pluginmanager.get_plugin("terminalreporter")
+    for stream in (getattr(reporter, "_tw", None), sys.stdout, sys.stderr,
+                   sys.__stdout__, sys.__stderr__):
+        if stream is not None:
+            stream.flush()
+    os._exit(_exit_status)
+
+
 # -- where the time went ----------------------------------------------------
 
 def pytest_terminal_summary(terminalreporter):

@@ -13,10 +13,20 @@ Such compiles write persistent-cache entries that cannot be read back
 without a chip, so the cache is off around this file. The suite's CPU
 compiles skip the optimiser (``tests/conftest.py``); what these tests
 assert on is the TPU compiler's own work, so this file keeps it whole.
+
+The TPU's compiler takes a core or two a program and the file's
+programs are independent of one another, so every test asks one fixture
+(``compiled``) for its executable by the name of its lowering and its
+arguments, and a small pool of threads compiles the programs of the
+tests that follow (``PROGRAMS``, in the tests' order) meanwhile: no
+program is built twice, and none waits in a row behind the others.
 """
 
-import functools
+import concurrent.futures
+import contextlib
+import math
 import os
+from unittest import mock
 
 import pytest
 
@@ -90,52 +100,6 @@ _CASES = [
 ]
 
 
-def _kernel_calls(compiled) -> int:
-    return compiled.as_text().count(
-        'custom_call_target="tpu_custom_call"')
-
-
-@pytest.fixture(scope="module")
-def flash_compiled(chip):
-    """``flash_compiled(which, BH, S, D, block_q, block_k, causal)``:
-    the forward (``"fwd"``) or the backward (``"bwd"``) compiled for
-    the chip, once for every case that names the same program (the two
-    cells' causal shapes are cases of the ladder's tests too)."""
-    @functools.cache
-    def compiled(which, bh, seq, d, block_q, block_k, causal):
-        qkv = jax.ShapeDtypeStruct((bh, seq, d), jnp.bfloat16,
-                                   sharding=chip)
-        stat = jax.ShapeDtypeStruct((bh, 1, seq), jnp.float32,
-                                    sharding=chip)
-        offsets = jax.ShapeDtypeStruct((2,), jnp.int32, sharding=chip)
-        tiles = dict(causal=causal, block_q=block_q, block_k=block_k,
-                     interpret=False)
-        if which == "fwd":
-            return _flash_bhsd.lower(
-                qkv, qkv, qkv, offsets, **tiles).compile()
-        return _flash_bwd_bhsd.lower(
-            qkv, qkv, qkv, qkv, stat, stat, offsets, **tiles).compile()
-    return compiled
-
-
-@pytest.mark.parametrize("bh,seq,d,block_q,block_k",
-                         [c[1:] for c in _CASES],
-                         ids=[c[0] for c in _CASES])
-def test_flash_forward_compiles_for_v5e(flash_compiled, bh, seq, d,
-                                        block_q, block_k):
-    compiled = flash_compiled("fwd", bh, seq, d, block_q, block_k, True)
-    assert _kernel_calls(compiled) == 1
-
-
-@pytest.mark.parametrize("bh,seq,d,block_q,block_k",
-                         [c[1:] for c in _CASES],
-                         ids=[c[0] for c in _CASES])
-def test_flash_backward_compiles_for_v5e(flash_compiled, bh, seq, d,
-                                         block_q, block_k):
-    compiled = flash_compiled("bwd", bh, seq, d, block_q, block_k, True)
-    assert _kernel_calls(compiled) == 2  # dq, and dk/dv
-
-
 # The cells' own flash calls: (cell, BH, S, D) at the tile and the
 # causal sub-tile `flash_attention` picks for them.
 _CELL_SHAPES = [
@@ -143,13 +107,180 @@ _CELL_SHAPES = [
     ("glm47flash-injit", 80, 4096, 256),    # B4 x 20 heads of 256
 ]
 
+# (case id, q, k and v heads of 128, columns of x)
+_PROLOGUE_SHAPES = [("kimi_delta_attention", 32, 32, 32, 12288),
+                    ("gated_deltanet_in_qkvz", 16, 16, 32, 12288)]
+
+# (case id, the gate's shape, its type, its activation, the column z
+#  starts at)
+_EPILOGUE_SHAPES = [
+    ("kimi_delta_attention", (1, 16384, 32), jnp.float32, "sigmoid", 0),
+    ("gated_deltanet_in_qkvz", (1, 16384, 12288), jnp.bfloat16, "silu",
+     8192)]
+
+
+def _kernel_calls(compiled) -> int:
+    return compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"')
+
+
+# -- the lowerings: ``(chip, mesh4, *arguments)`` -> ``jax.stages.Lowered`` --
+
+def _arr(chip, *shape, dt=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+
+
+def _grads_of_the_sum(f, n_args, value=False):
+    """The gradients of ``sum(f(*args))`` in every argument; with
+    ``value`` the sum too, where the backward alone would drop the
+    forward kernel from the program."""
+    def loss(*args):
+        out = f(*args)
+        if isinstance(out, tuple):
+            return sum(jnp.sum(o.astype(jnp.float32)) for o in out)
+        return jnp.sum(out.astype(jnp.float32))
+    return jax.jit((jax.value_and_grad if value else jax.grad)(
+        loss, argnums=tuple(range(n_args))))
+
+
+def _lower_flash(chip, mesh4, which, bh, seq, d, block_q, block_k, causal):
+    """The forward (``"fwd"``) or the backward (``"bwd"``) of the flash
+    kernels (the two cells' causal shapes are cases of the ladder's
+    tests too)."""
+    qkv = _arr(chip, bh, seq, d)
+    stat = _arr(chip, bh, 1, seq, dt=jnp.float32)
+    offsets = _arr(chip, 2, dt=jnp.int32)
+    tiles = dict(causal=causal, block_q=block_q, block_k=block_k,
+                 interpret=False)
+    if which == "fwd":
+        return _flash_bhsd.lower(qkv, qkv, qkv, offsets, **tiles)
+    return _flash_bwd_bhsd.lower(
+        qkv, qkv, qkv, qkv, stat, stat, offsets, **tiles)
+
+
+def _phi4flash_blocks(chip):
+    from horovod_tpu.parallel.flash_attention import _blocks_for
+    return _blocks_for(_arr(chip, 1, 16384, 20, 64),
+                       _arr(chip, 1, 16384, 10, 64), None, None)
+
+
+def _lower_phi4flash(chip, mesh4, which, window):
+    seq, d, dv = 16384, 64, 128
+    q, k, v = _arr(chip, 20, seq, d), _arr(chip, 10, seq, d), \
+        _arr(chip, 10, seq, dv)
+    do = _arr(chip, 20, seq, dv, dt=jnp.float32)
+    stat = _arr(chip, 20, 1, seq, dt=jnp.float32)
+    offsets = _arr(chip, 2, dt=jnp.int32)
+    block_q, block_k = _phi4flash_blocks(chip)
+    args = dict(causal=True, block_q=block_q, block_k=block_k,
+                interpret=False, window=window)
+    if which == "fwd":
+        return _flash_bhsd.lower(q, k, v, offsets, out_dtype=jnp.float32,
+                                 **args)
+    return _flash_bwd_bhsd.lower(q, k, v, do, stat, stat, offsets, **args)
+
+
+def _lower_selective_scan(chip, mesh4):
+    from horovod_tpu.parallel import ssm_scan as ss
+    bt, seq, channels, states = 1, 16384, 5120, 16
+    f32 = dict(dt=jnp.float32)
+    return _grads_of_the_sum(
+        lambda *x: ss.selective_scan(*x, interpret=False), 6).lower(
+            _arr(chip, bt, seq, channels),
+            _arr(chip, bt, seq, channels, **f32),
+            _arr(chip, channels, states, **f32),
+            _arr(chip, bt, seq, states, **f32),
+            _arr(chip, bt, seq, states, **f32), _arr(chip, channels, **f32))
+
+
+def _lower_gated_delta_rule(chip, mesh4):
+    from horovod_tpu.parallel import gated_delta as gd
+    bt, seq, key_heads, value_heads, d = 1, 16384, 16, 32, 128
+    gate = _arr(chip, bt, seq, value_heads, dt=jnp.float32)
+    return _grads_of_the_sum(
+        lambda *x: gd.gated_delta_rule(*x, interpret=False), 5).lower(
+            *2 * [_arr(chip, bt, seq, key_heads, d)],
+            _arr(chip, bt, seq, value_heads, d), gate, gate)
+
+
+def _lower_kimi_delta_attention(chip, mesh4):
+    from horovod_tpu.parallel import kda
+    bt, seq, heads, d = 1, 16384, 32, 128
+    qkv = _arr(chip, bt, seq, heads, d)
+    return _grads_of_the_sum(
+        lambda *x: kda.kimi_delta_attention(*x, interpret=False), 5).lower(
+            qkv, qkv, qkv, _arr(chip, bt, seq, heads, d, dt=jnp.float32),
+            _arr(chip, bt, seq, heads, dt=jnp.float32))
+
+
+def _lower_prologue(chip, mesh4, hq, hk, hv, columns):
+    from horovod_tpu.parallel import qkv_prologue as qp
+    seq, d = 16384, 128
+    # the backward needs x and the kernel alone: the value keeps the
+    # forward kernel in the program
+    return _grads_of_the_sum(
+        lambda x, w: qp.qkv_prologue(x, w, d, hq + hk, hq, interpret=False),
+        2, value=True).lower(
+            _arr(chip, 1, seq, columns),
+            _arr(chip, 4, (hq + hk + hv) * d, dt=jnp.float32))
+
+
+def _lower_epilogue(chip, mesh4, gate, gate_type, activation, start):
+    from horovod_tpu.parallel import delta_epilogue as de
+    # the backward needs o, the scale and the gate alone: the value
+    # keeps the forward kernel in the program
+    return _grads_of_the_sum(
+        lambda o, w, z: de.delta_epilogue(o, w, z, 128, activation, start,
+                                          interpret=False),
+        3, value=True).lower(
+            _arr(chip, 1, 16384, 4096), _arr(chip, 128, dt=jnp.float32),
+            _arr(chip, *gate, dt=gate_type))
+
+
+def _lower_latent_flash(chip, mesh4):
+    from horovod_tpu.parallel.flash_attention import flash_attention
+    arr = lambda d: _arr(chip, 1, 16384, 32, d)
+    return _grads_of_the_sum(
+        lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                        interpret=False), 3).lower(
+            arr(192), arr(192), arr(128))
+
+
+@pytest.mark.parametrize("bh,seq,d,block_q,block_k",
+                         [c[1:] for c in _CASES],
+                         ids=[c[0] for c in _CASES])
+def test_flash_forward_compiles_for_v5e(compiled, bh, seq, d, block_q,
+                                        block_k):
+    fwd = compiled("flash", "fwd", bh, seq, d, block_q, block_k, True)
+    assert _kernel_calls(fwd) == 1
+
+
+@pytest.mark.parametrize("bh,seq,d,block_q,block_k",
+                         [c[1:] for c in _CASES],
+                         ids=[c[0] for c in _CASES])
+def test_flash_backward_compiles_for_v5e(compiled, bh, seq, d, block_q,
+                                         block_k):
+    bwd = compiled("flash", "bwd", bh, seq, d, block_q, block_k, True)
+    assert _kernel_calls(bwd) == 2  # dq, and dk/dv
+
+
+def _are_the_kernels_of(executable, *names):
+    assert _kernel_calls(executable) == len(names)
+    for name in names:          # the benchmark's readers match by name
+        assert name in executable.as_text()
+
+
+def _are_the_flash_kernels(fwd, bwd):
+    _are_the_kernels_of(fwd, "flash_fwd")
+    _are_the_kernels_of(bwd, "flash_bwd_dq", "flash_bwd_dkv")
+
 
 @pytest.mark.parametrize("causal", [True, False],
                          ids=["causal", "noncausal"])
 @pytest.mark.parametrize("bh,seq,d", [c[1:] for c in _CELL_SHAPES],
                          ids=[c[0] for c in _CELL_SHAPES])
-def test_cells_kernels_compile_with_their_subtiles(flash_compiled, bh,
-                                                   seq, d, causal):
+def test_cells_kernels_compile_with_their_subtiles(compiled, bh, seq, d,
+                                                   causal):
     """Forward, dq and dk/dv at the two cells' shapes, the sub-tile
     loops on runtime offsets included: a VMEM or Mosaic refusal of the
     chosen sub-tile shows here, before the chip is asked."""
@@ -157,167 +288,72 @@ def test_cells_kernels_compile_with_their_subtiles(flash_compiled, bh,
     sub_q, sub_k = _subtile_for(d, block_q, block_k)
     assert block_q % sub_q == 0 and block_k % sub_k == 0
     assert (sub_q, sub_k) != (block_q, block_k)
-    fwd, bwd = (flash_compiled(which, bh, seq, d, block_q, block_k, causal)
-                for which in ("fwd", "bwd"))
-    assert (_kernel_calls(fwd), _kernel_calls(bwd)) == (1, 2)
-    for text, names in ((fwd.as_text(), ["flash_fwd"]),
-                        (bwd.as_text(), ["flash_bwd_dq", "flash_bwd_dkv"])):
-        for name in names:      # the benchmark's readers match by name
-            assert name in text
+    _are_the_flash_kernels(*(
+        compiled("flash", which, bh, seq, d, block_q, block_k, causal)
+        for which in ("fwd", "bwd")))
 
 
 # phi4flash-injit-1chip (PR 31): one row of 16,384, two flash calls a
 # layer of 20 query heads over 10 key-value heads, key head 64, value
 # head 128, inside the window of 512 and over the whole prefix.
 @pytest.mark.parametrize("window", [512, None], ids=["window", "full"])
-def test_phi4flash_kernels_compile_for_v5e(chip, window):
+def test_phi4flash_kernels_compile_for_v5e(compiled, chip, window):
     """Grouped heads, a value head of its own size, head size 64, the
     windowed grid and a float32 output (and so a float32 ``do``)
     through the TPU's compiler at the cell's shape and the tiles
     ``flash_attention`` picks there."""
-    from horovod_tpu.parallel.flash_attention import _blocks_for
-    seq, d, dv = 16384, 64, 128
-    arr = lambda *shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(
-        shape, dt, sharding=chip)
-    q, k, v, do = arr(20, seq, d), arr(10, seq, d), arr(10, seq, dv), \
-        arr(20, seq, dv, dt=jnp.float32)
-    stat = arr(20, 1, seq, dt=jnp.float32)
-    offsets = arr(2, dt=jnp.int32)
-    block_q, block_k = _blocks_for(
-        arr(1, seq, 20, d), arr(1, seq, 10, d), None, None)
-    assert (block_q, block_k) == (1024, 1024)
-    args = dict(causal=True, block_q=block_q, block_k=block_k,
-                interpret=False, window=window)
-    fwd = _flash_bhsd.lower(q, k, v, offsets, out_dtype=jnp.float32,
-                            **args).compile()
-    bwd = _flash_bwd_bhsd.lower(q, k, v, do, stat, stat, offsets,
-                                **args).compile()
-    assert (_kernel_calls(fwd), _kernel_calls(bwd)) == (1, 2)
-    for text, names in ((fwd.as_text(), ["flash_fwd"]),
-                        (bwd.as_text(), ["flash_bwd_dq", "flash_bwd_dkv"])):
-        for name in names:
-            assert name in text
+    assert _phi4flash_blocks(chip) == (1024, 1024)
+    _are_the_flash_kernels(*(compiled("phi4flash", which, window)
+                             for which in ("fwd", "bwd")))
 
 
-def test_the_selective_scan_compiles_for_v5e(chip):
+def test_the_selective_scan_compiles_for_v5e(compiled):
     """``ssm_scan_fwd`` and ``ssm_scan_bwd`` at the cell's shape (one
     row of 16,384, 5,120 channels, 16 states) and the ladder's chunk:
     scalars from SMEM, registers of 1,024 channels, the backward's
     chunk of states in VMEM."""
-    from horovod_tpu.parallel import ssm_scan as ss
-    bt, seq, channels, states = 1, 16384, 5120, 16
-    arr = lambda *shape, dt=jnp.float32: jax.ShapeDtypeStruct(
-        shape, dt, sharding=chip)
-    args = (arr(bt, seq, channels, dt=jnp.bfloat16), arr(bt, seq, channels),
-            arr(channels, states), arr(bt, seq, states),
-            arr(bt, seq, states), arr(channels))
-
-    def loss(*x):
-        return jnp.sum(ss.selective_scan(*x, interpret=False)
-                       .astype(jnp.float32))
-
-    compiled = jax.jit(jax.grad(loss, argnums=tuple(range(6)))) \
-        .lower(*args).compile()
-    assert _kernel_calls(compiled) == 2
-    for name in ("ssm_scan_fwd", "ssm_scan_bwd"):
-        assert name in compiled.as_text()
+    _are_the_kernels_of(compiled("selective_scan"),
+                        "ssm_scan_fwd", "ssm_scan_bwd")
 
 
-def test_the_gated_delta_rule_compiles_for_v5e(chip):
+def test_the_gated_delta_rule_compiles_for_v5e(compiled):
     """``gdn_fwd`` and ``gdn_bwd`` at the cell's shape (one row of
     16,384, 16 key heads serving 32 value heads of 128) and the ladder's
     chunk: two value heads a grid step, the triangular inverse's
     float32 products, a head's whole table of gates resident."""
-    from horovod_tpu.parallel import gated_delta as gd
-    bt, seq, key_heads, value_heads, d = 1, 16384, 16, 32, 128
-    arr = lambda *shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(
-        shape, dt, sharding=chip)
-    gate = arr(bt, seq, value_heads, dt=jnp.float32)
-    args = (arr(bt, seq, key_heads, d), arr(bt, seq, key_heads, d),
-            arr(bt, seq, value_heads, d), gate, gate)
-
-    def loss(*x):
-        return jnp.sum(gd.gated_delta_rule(*x, interpret=False)
-                       .astype(jnp.float32))
-
-    compiled = jax.jit(jax.grad(loss, argnums=tuple(range(5)))) \
-        .lower(*args).compile()
-    assert _kernel_calls(compiled) == 2
-    for name in ("gdn_fwd", "gdn_bwd"):
-        assert name in compiled.as_text()
+    _are_the_kernels_of(compiled("gated_delta_rule"), "gdn_fwd", "gdn_bwd")
 
 
-def test_kimi_delta_attention_compiles_for_v5e(chip):
+def test_kimi_delta_attention_compiles_for_v5e(compiled):
     """``kda_fwd`` and ``kda_bwd`` at the cell's shape (one row of
     16,384, 32 heads of 128, a float32 log-decay a key channel) and the
     ladder's chunk and sub-block: the levels' masked products, the
     diagonal level and the inverse at full precision, the running sums
     taken inside the kernels."""
     from horovod_tpu.parallel import kda
-    bt, seq, heads, d = 1, 16384, 32, 128
-    arr = lambda *shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(
-        shape, dt, sharding=chip)
-    qkv = arr(bt, seq, heads, d)
-    args = (qkv, qkv, qkv, arr(bt, seq, heads, d, dt=jnp.float32),
-            arr(bt, seq, heads, dt=jnp.float32))
-
-    def loss(*x):
-        return jnp.sum(kda.kimi_delta_attention(*x, interpret=False)
-                       .astype(jnp.float32))
-
-    compiled = jax.jit(jax.grad(loss, argnums=tuple(range(5)))) \
-        .lower(*args).compile()
-    assert _kernel_calls(compiled) == 2
-    for name in ("kda_fwd", "kda_bwd"):
-        assert name in compiled.as_text()
-    assert kda._lengths_for(seq) == (128, 16)
-
-
-# (case id, q, k and v heads of 128, columns of x)
-_PROLOGUE_SHAPES = [("kimi_delta_attention", 32, 32, 32, 12288),
-                    ("gated_deltanet_in_qkvz", 16, 16, 32, 12288)]
+    _are_the_kernels_of(compiled("kimi_delta_attention"),
+                        "kda_fwd", "kda_bwd")
+    assert kda._lengths_for(16384) == (128, 16)
 
 
 @pytest.mark.parametrize("hq,hk,hv,columns",
                          [c[1:] for c in _PROLOGUE_SHAPES],
                          ids=[c[0] for c in _PROLOGUE_SHAPES])
-def test_the_delta_rules_prologue_compiles_for_v5e(chip, hq, hk, hv,
+def test_the_delta_rules_prologue_compiles_for_v5e(compiled, hq, hk, hv,
                                                    columns):
     """``qkv_prologue_fwd`` and ``qkv_prologue_bwd`` at the two cells'
     shapes (one row of 16,384, bfloat16; the Gated DeltaNet's 8,192
     columns are the leading ones of ``qkvz``'s 12,288) and the ladder's
     tile: a head's columns at a dynamic offset, the taps' reads off the
     float32 tiling, the tile's blocks inside the kernels' VMEM."""
-    from horovod_tpu.parallel import qkv_prologue as qp
-    seq, d = 16384, 128
-    x = jax.ShapeDtypeStruct((1, seq, columns), jnp.bfloat16, sharding=chip)
-    w = jax.ShapeDtypeStruct((4, (hq + hk + hv) * d), jnp.float32,
-                             sharding=chip)
-
-    def loss(x, w):
-        return sum(jnp.sum(o.astype(jnp.float32)) for o in qp.qkv_prologue(
-            x, w, d, hq + hk, hq, interpret=False))
-
-    # the backward needs x and the kernel alone: the value keeps the
-    # forward kernel in the program
-    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))) \
-        .lower(x, w).compile()
-    assert _kernel_calls(compiled) == 2
-    for name in ("qkv_prologue_fwd", "qkv_prologue_bwd"):
-        assert name in compiled.as_text()
-
-
-# (case id, the gate's shape, its activation, the column z starts at)
-_EPILOGUE_SHAPES = [
-    ("kimi_delta_attention", (1, 16384, 32), jnp.float32, "sigmoid", 0),
-    ("gated_deltanet_in_qkvz", (1, 16384, 12288), jnp.bfloat16, "silu",
-     8192)]
+    _are_the_kernels_of(compiled("prologue", hq, hk, hv, columns),
+                        "qkv_prologue_fwd", "qkv_prologue_bwd")
 
 
 @pytest.mark.parametrize("gate,gate_type,activation,start",
                          [c[1:] for c in _EPILOGUE_SHAPES],
                          ids=[c[0] for c in _EPILOGUE_SHAPES])
-def test_the_delta_rules_epilogue_compiles_for_v5e(chip, gate, gate_type,
+def test_the_delta_rules_epilogue_compiles_for_v5e(compiled, gate, gate_type,
                                                    activation, start):
     """``delta_epilogue_fwd`` and ``delta_epilogue_bwd`` at the two
     cells' shapes (one row of 16,384, 32 heads of 128, bfloat16; Kimi
@@ -326,42 +362,18 @@ def test_the_delta_rules_epilogue_compiles_for_v5e(chip, gate, gate_type,
     ladder's tile: a head's columns at a dynamic offset, a head's gate
     by a masked row sum of a tile 32 lanes wide, the tile's blocks
     inside the kernels' VMEM."""
-    from horovod_tpu.parallel import delta_epilogue as de
-    o = jax.ShapeDtypeStruct((1, 16384, 4096), jnp.bfloat16, sharding=chip)
-    w = jax.ShapeDtypeStruct((128,), jnp.float32, sharding=chip)
-    z = jax.ShapeDtypeStruct(gate, gate_type, sharding=chip)
-
-    def loss(o, w, z):
-        return jnp.sum(de.delta_epilogue(
-            o, w, z, 128, activation, start, interpret=False)
-            .astype(jnp.float32))
-
-    # the backward needs o, the scale and the gate alone: the value
-    # keeps the forward kernel in the program
-    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))) \
-        .lower(o, w, z).compile()
-    assert _kernel_calls(compiled) == 2
-    for name in ("delta_epilogue_fwd", "delta_epilogue_bwd"):
-        assert name in compiled.as_text()
+    _are_the_kernels_of(
+        compiled("epilogue", gate, gate_type, activation, start),
+        "delta_epilogue_fwd", "delta_epilogue_bwd")
 
 
 def test_the_flash_kernels_compile_at_a_score_head_of_192_over_a_value_head_of_128(
-        chip):
+        compiled):
     """The latent attention of ``ling3flash-injit-1chip``: 32 heads, q
     and k of 128 + 64, v and o of 128, S 16,384, the ladder's tiles for
     a head of 192 (512x1024: 1024x1024 does not fit VMEM in the dk/dv
     kernel there)."""
-    from horovod_tpu.parallel.flash_attention import flash_attention
-    arr = lambda d: jax.ShapeDtypeStruct((1, 16384, 32, d), jnp.bfloat16,
-                                         sharding=chip)
-
-    def loss(q, k, v):
-        return jnp.sum(flash_attention(q, k, v, causal=True, interpret=False)
-                       .astype(jnp.float32))
-
-    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
-        arr(192), arr(192), arr(128)).compile()
-    assert _kernel_calls(compiled) == 3
+    assert _kernel_calls(compiled("latent_flash")) == 3
     assert _top(192) == (512, 1024)
 
 
@@ -386,10 +398,11 @@ def _flash(q, k, v, causal=True):
     return flash_attention(q, k, v, causal=causal, interpret=False)
 
 
-def _lm_step_compiled(mesh4, num_layers=2):
-    """``lm_train_step`` of a two-layer ``TransformerLM`` cut to half
-    the cell's width (d 1024: its MLP, head and embedding leaves are
-    16.8 MB each, its attention leaves 4.2 MB), compiled for the mesh."""
+def _lower_lm_step(chip, mesh4, num_layers, with_the_options):
+    """``lm_train_step`` of a ``TransformerLM`` cut to half the cell's
+    width (d 1024: its MLP, head and embedding leaves are 16.8 MB each,
+    its attention leaves 4.2 MB), for the mesh; with the compiler
+    options the step asks for itself, or as if it asked for none."""
     from horovod_tpu import spmd
     from horovod_tpu.models import train_steps
     from horovod_tpu.models.transformer import (
@@ -404,39 +417,40 @@ def _lm_step_compiled(mesh4, num_layers=2):
     rep = spmd.replicated_sharding(mesh4)
     tokens = jax.ShapeDtypeStruct((4, 256), jnp.int32,
                                   sharding=spmd.batch_sharding(mesh4))
-    step = train_steps.lm_train_step(model, tx, mesh4)
-    compiled = step.lower(_abstract(params, rep),
-                          _abstract(jax.eval_shape(tx.init, params), rep),
-                          tokens).compile()
-    n_bytes = sum(4 * p.size for p in jax.tree_util.tree_leaves(params))
-    return compiled, n_bytes
+    asks_for_none = mock.patch.object(
+        spmd, "overlap_compiler_options", lambda mesh, axis="data": None)
+    with contextlib.nullcontext() if with_the_options else asks_for_none:
+        return train_steps.lm_train_step(model, tx, mesh4).lower(
+            _abstract(params, rep),
+            _abstract(jax.eval_shape(tx.init, params), rep), tokens)
 
 
-@pytest.fixture(scope="module")
-def lm_step4(mesh4):
-    """The step with the options it asks for itself, compiled once for
-    every test that reads its executable (no program twice in a run)."""
-    return _lm_step_compiled(mesh4)
+def _parameter_bytes(executable):
+    """The float32 bytes of the first argument (the parameters) of a
+    step's executable."""
+    return sum(4 * math.prod(leaf.shape) for leaf in
+               jax.tree_util.tree_leaves(executable.args_info[0][0]))
 
 
-def test_lm_step_reduces_its_gradients_under_the_backward(mesh4, lm_step4):
+def test_lm_step_reduces_its_gradients_under_the_backward(mesh4, compiled):
     """With the options the step asks for itself, most of the
     gradients' bytes go in asynchronous pairs that have compute ops
     scheduled between start and done, and the flash kernels are still
     in the executable."""
     from horovod_tpu import spmd
     assert spmd.overlap_compiler_options(mesh4)
-    compiled, n_bytes = lm_step4
-    got = spmd.collective_schedule(compiled)
+    step = compiled("lm_step", 2, True)
+    n_bytes = _parameter_bytes(step)
+    got = spmd.collective_schedule(step)
     hidden = got["async"]["overlapped"]
     assert got["sync"]["bytes"] + got["async"]["bytes"] == n_bytes + 4
     assert hidden["count"] >= 4
     assert hidden["bytes"] > got["sync"]["bytes"]
     assert hidden["bytes"] == got["async"]["bytes"]
-    assert _kernel_calls(compiled) == 2 * 3     # fwd, dq, dk/dv a layer
+    assert _kernel_calls(step) == 2 * 3     # fwd, dq, dk/dv a layer
 
 
-def test_lm_steps_device_ops_have_their_scopes(lm_step4):
+def test_lm_steps_device_ops_have_their_scopes(compiled):
     """``spmd.device_scopes`` on the same executable: every flash call
     is under ``attn``, what the head loss's path names under
     ``lm_head_loss`` (the products of its one chunk here), the
@@ -446,9 +460,9 @@ def test_lm_steps_device_ops_have_their_scopes(lm_step4):
     import re
     from horovod_tpu import spmd
     from horovod_tpu.spmd import overlap
-    compiled, _ = lm_step4
-    text = compiled.as_text()
-    table = spmd.device_scopes(compiled)
+    step = compiled("lm_step", 2, True)
+    text = step.as_text()
+    table = spmd.device_scopes(step)
     assert table == spmd.device_scopes(text)
     comps = overlap._computations(text)
     lines = {m.group(1): (m.group(3), line)
@@ -468,7 +482,7 @@ def test_lm_steps_device_ops_have_their_scopes(lm_step4):
     # the logits, `dlogits @ W^T` and `h^T @ dlogits`
     assert len([n for n in head if multiplies(n)]) == 3
     assert not [n for n in head if n in table.backward]     # one pass
-    pairs = spmd.collective_schedule(compiled)["pairs"]
+    pairs = spmd.collective_schedule(step)["pairs"]
     assert len(pairs) >= 4
     for pair in pairs:
         assert table[pair["name"]] == "exchange"
@@ -481,18 +495,15 @@ def test_lm_steps_device_ops_have_their_scopes(lm_step4):
     assert {"loss", "optimizer", "mlp", "embed"} <= set(table.values())
 
 
-def test_lm_step_without_the_options_reduces_synchronously(
-        mesh4, monkeypatch):
+def test_lm_step_without_the_options_reduces_synchronously(compiled):
     """The same step without the options: every all-reduce is
     synchronous. If a libtpu changes that default, this fails and the
     options can go. (One layer shows it: the schedule alone is read.)"""
     from horovod_tpu import spmd
-    monkeypatch.setattr(spmd, "overlap_compiler_options",
-                        lambda mesh, axis="data": None)
-    compiled, n_bytes = _lm_step_compiled(mesh4, num_layers=1)
-    got = spmd.collective_schedule(compiled)
+    step = compiled("lm_step", 1, False)
+    got = spmd.collective_schedule(step)
     assert got["async"]["count"] == 0
-    assert got["sync"]["bytes"] == n_bytes + 4
+    assert got["sync"]["bytes"] == _parameter_bytes(step) + 4
 
 
 def _resnet_step(mesh4):
@@ -545,15 +556,82 @@ def _glm_moe_step(mesh4):
     return train_steps.glm_moe_train_step(model, tx, mesh4), args
 
 
+def _lower_other_step(chip, mesh4, build):
+    step, args = build(mesh4)
+    return step.lower(*args)
+
+
 @pytest.mark.parametrize("build", [_resnet_step, _glm_moe_step],
                          ids=["resnet", "glm_moe"])
-def test_the_other_steps_compile_with_the_options(mesh4, build):
+def test_the_other_steps_compile_with_the_options(compiled, build):
     """No cell runs these two steps over four chips; they take the
     same options there, and the compiler accepts them: the gradients
     are reduced, every one, in one kind or the other."""
     from horovod_tpu import spmd
-    step, args = build(mesh4)
-    compiled = step.lower(*args).compile()
-    got = spmd.collective_schedule(compiled)
-    n_bytes = sum(4 * p.size for p in jax.tree_util.tree_leaves(args[0]))
-    assert got["sync"]["bytes"] + got["async"]["bytes"] >= n_bytes
+    step = compiled("other_step", build)
+    got = spmd.collective_schedule(step)
+    assert got["sync"]["bytes"] + got["async"]["bytes"] \
+        >= _parameter_bytes(step)
+
+
+# -- the one way to an executable -------------------------------------------
+
+LOWERINGS = {
+    "flash": _lower_flash, "phi4flash": _lower_phi4flash,
+    "selective_scan": _lower_selective_scan,
+    "gated_delta_rule": _lower_gated_delta_rule,
+    "kimi_delta_attention": _lower_kimi_delta_attention,
+    "prologue": _lower_prologue, "epilogue": _lower_epilogue,
+    "latent_flash": _lower_latent_flash, "lm_step": _lower_lm_step,
+    "other_step": _lower_other_step,
+}
+
+# What the tests above ask for, in their order and each once: the pool
+# works ahead through this list.
+PROGRAMS = list(dict.fromkeys([
+    *(("flash", which, *case[1:], True)
+      for which in ("fwd", "bwd") for case in _CASES),
+    *(("flash", which, *cell[1:], *_top(cell[3]), causal)
+      for causal in (True, False) for cell in _CELL_SHAPES
+      for which in ("fwd", "bwd")),
+    *(("phi4flash", which, window)
+      for window in (512, None) for which in ("fwd", "bwd")),
+    ("selective_scan",), ("gated_delta_rule",), ("kimi_delta_attention",),
+    *(("prologue", *case[1:]) for case in _PROLOGUE_SHAPES),
+    *(("epilogue", *case[1:]) for case in _EPILOGUE_SHAPES),
+    ("latent_flash",), ("lm_step", 2, True), ("lm_step", 1, False),
+    ("other_step", _resnet_step), ("other_step", _glm_moe_step),
+]))
+AHEAD = 8       # programs handed to the pool before they are asked for
+
+
+@pytest.fixture(scope="module")
+def compiled(chip, mesh4):
+    """``compiled(lowering, *arguments)``: the executable of
+    ``LOWERINGS[lowering](chip, mesh4, *arguments)`` for the described
+    chip(s), built once whoever asks. Lowering is Python and stays on
+    this thread; ``Lowered.compile()`` releases the interpreter, so a
+    pool of threads compiles the asked program and the next ``AHEAD``
+    of ``PROGRAMS`` while the tests before them assert."""
+    workers = max(2, min(4, len(os.sched_getaffinity(0)) // 2))
+    futures = {}
+    with concurrent.futures.ThreadPoolExecutor(workers) as pool:
+        def start(program):
+            try:
+                lowered = LOWERINGS[program[0]](chip, mesh4, *program[1:])
+            except Exception as refused:    # its own test's to report
+                future = concurrent.futures.Future()
+                future.set_exception(refused)
+                return future
+            return pool.submit(lowered.compile)
+
+        def ask(*program):
+            at = PROGRAMS.index(program) if program in PROGRAMS \
+                else len(PROGRAMS)
+            for ahead in (program, *PROGRAMS[at:at + AHEAD]):
+                if ahead not in futures:
+                    futures[ahead] = start(ahead)
+            return futures[program].result()
+        yield ask
+        for future in futures.values():
+            future.cancel()

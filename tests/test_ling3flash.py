@@ -517,8 +517,12 @@ def test_the_step_trains_on_the_counted_path(model, params):
         tx = hvd.DistributedOptimizer(optax.sgd(0.01, momentum=0.9),
                                       axis="data")
         step = train_steps.ling3flash_train_step(model, tx, mesh)
-        p = jax.tree_util.tree_map(jnp.array, params)
-        o, t, losses = tx.init(p), tokens(), []
+        # on the mesh, where the step leaves its state: one program for
+        # every step, not one for the first and one for the rest
+        rep = spmd.replicated_sharding(mesh)
+        p = jax.device_put(jax.tree_util.tree_map(jnp.array, params), rep)
+        o = jax.device_put(tx.init(p), rep)
+        t, losses = jax.device_put(tokens(), spmd.batch_sharding(mesh)), []
         for _ in range(3):
             p, o, loss, counts = step(p, o, t)
             losses.append(float(loss))

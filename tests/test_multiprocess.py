@@ -3,6 +3,7 @@ negotiate through the TCP controller and move data through the socket
 backend — the TPU build's version of the reference's ``mpirun -np 2
 pytest`` legs (reference: .travis.yml:109-122, test/common.py:25-57)."""
 
+import functools
 import os
 import signal
 import socket
@@ -178,52 +179,95 @@ def run_scenarios(scenarios, size: int, timeout: float = 170.0):
     return ScenarioResults(list(scenarios), outputs)
 
 
+# One world for the scenarios that can share one, not one a test: they
+# run in turn in the same interpreters, which import what they need
+# once (torch 2 s a rank, TensorFlow 5.5 s with bytecode and 10 s
+# without, Keras on top of it, jax 2 s; an interpreter itself and this
+# package 0.6 to 1 s). The first test to ask starts the world; each
+# test reads its own scenario's result. A scenario that needs its own
+# environment, exit codes or timeout, or whose failure is its point
+# (the mismatch errors, a rank's death, the planted inversion), keeps
+# a world of its own (``run_scenario``).
+
+PLAIN = {2: ["allreduce", "allreduce_fused", "allreduce_multi_dtype",
+             "allgather", "broadcast", "alltoall", "reducescatter",
+             "barrier", "rank_subset_order", "topology", "jax_adapter",
+             "scalar_broadcast"],
+         3: ["allgather", "broadcast"],
+         4: ["allreduce"]}
+
+
+@pytest.fixture(scope="module")
+def plain_world():
+    """``plain_world(size)``: the world of that size with every
+    scenario that needs nothing of its own."""
+    return functools.cache(lambda size: run_scenarios(PLAIN[size], size))
+
+
+@pytest.fixture(scope="module")
+def torch_world():
+    return run_scenarios(
+        ["torch_optimizer", "torch_allreduce_grad", "torch_adam_state",
+         "torch_opt_state_asymmetric"], 2)
+
+
+@pytest.fixture(scope="module")
+def tensorflow_world():
+    # tf_broadcast_hook turns eager execution off for its process, and
+    # so goes last
+    return run_scenarios(
+        ["keras_optimizer", "tf_tape", "tf_allreduce_grad",
+         "tf_sparse_as_dense", "tfkeras_facade", "tf_broadcast_hook"],
+        2)
+
+
 @pytest.mark.parametrize("size", [2, 4])
-def test_allreduce(size):
-    run_scenario("allreduce", size)
+def test_allreduce(size, plain_world):
+    plain_world(size).check("allreduce")
 
 
-def test_allreduce_fused():
-    run_scenario("allreduce_fused", 2)
+def test_allreduce_fused(plain_world):
+    plain_world(2).check("allreduce_fused")
 
 
-def test_allreduce_multi_dtype():
-    run_scenario("allreduce_multi_dtype", 2)
+def test_allreduce_multi_dtype(plain_world):
+    plain_world(2).check("allreduce_multi_dtype")
 
 
 @pytest.mark.parametrize("size", [2, 3])
-def test_allgather(size):
-    run_scenario("allgather", size)
+def test_allgather(size, plain_world):
+    plain_world(size).check("allgather")
 
 
-def test_broadcast():
-    run_scenario("broadcast", 2)
+def test_broadcast(plain_world):
+    plain_world(2).check("broadcast")
 
 
-def test_broadcast_nonzero_root_three_ranks():
+def test_broadcast_nonzero_root_three_ranks(plain_world):
     """size > 2 with every root: the root's payload must not be echoed
     back to it by the coordinator fan-out."""
-    run_scenario("broadcast", 3)
+    plain_world(3).check("broadcast")
 
 
-def test_alltoall():
-    run_scenario("alltoall", 2)
+def test_alltoall(plain_world):
+    plain_world(2).check("alltoall")
 
 
-def test_reducescatter():
-    run_scenario("reducescatter", 2)
+def test_reducescatter(plain_world):
+    plain_world(2).check("reducescatter")
 
 
-def test_barrier():
-    run_scenario("barrier", 2)
+def test_barrier(plain_world):
+    plain_world(2).check("barrier")
 
 
 def test_wide_world_smoke():
     """12 ranks on one host: the coordinator's fan-in (native poll
     gather), the shm plane, and FUSED batches all hold up beyond the
     2-4 rank worlds the rest of the suite uses."""
-    run_scenario("allreduce", 12, timeout=180.0)
-    run_scenario("allreduce_fused", 12, timeout=180.0)
+    world = run_scenarios(["allreduce", "allreduce_fused"], 12)
+    world.check("allreduce")
+    world.check("allreduce_fused")
 
 
 @pytest.mark.time_limit(630)
@@ -358,12 +402,12 @@ def test_root_rank_mismatch_error():
     run_scenario("root_rank_mismatch_error", 2)
 
 
-def test_out_of_order_submission():
-    run_scenario("rank_subset_order", 2)
+def test_out_of_order_submission(plain_world):
+    plain_world(2).check("rank_subset_order")
 
 
-def test_topology():
-    run_scenario("topology", 2)
+def test_topology(plain_world):
+    plain_world(2).check("topology")
 
 
 def test_stall_shutdown():
@@ -373,36 +417,12 @@ def test_stall_shutdown():
                    "HOROVOD_STALL_SHUTDOWN_TIME_SECONDS": "2"})
 
 
-# One world a framework, not one a test: the two-rank scenarios of a
-# framework run in turn in the same two interpreters, which import it
-# once (torch 2 s a rank, TensorFlow 5.5 s with bytecode and 10 s
-# without, Keras on top of it). The first test to ask starts the world;
-# each test reads its own scenario's result. Scenarios that need an
-# environment or a size of their own keep a world of their own.
-
-@pytest.fixture(scope="module")
-def torch_world():
-    return run_scenarios(
-        ["torch_optimizer", "torch_allreduce_grad", "torch_adam_state",
-         "torch_opt_state_asymmetric"], 2)
-
-
-@pytest.fixture(scope="module")
-def tensorflow_world():
-    # tf_broadcast_hook turns eager execution off for its process, and
-    # so goes last
-    return run_scenarios(
-        ["keras_optimizer", "tf_tape", "tf_allreduce_grad",
-         "tf_sparse_as_dense", "tfkeras_facade", "tf_broadcast_hook"],
-        2)
-
-
 def test_torch_distributed_optimizer(torch_world):
     torch_world.check("torch_optimizer")
 
 
-def test_jax_adapter_host_path():
-    run_scenario("jax_adapter", 2)
+def test_jax_adapter_host_path(plain_world):
+    plain_world(2).check("jax_adapter")
 
 
 def test_torch_allreduce_grad(torch_world):
@@ -462,8 +482,8 @@ def test_tfkeras_facade(tensorflow_world):
     tensorflow_world.check("tfkeras_facade")
 
 
-def test_scalar_broadcast():
-    run_scenario("scalar_broadcast", 2)
+def test_scalar_broadcast(plain_world):
+    plain_world(2).check("scalar_broadcast")
 
 
 @pytest.mark.parametrize("plane", ["shm", "socket"])

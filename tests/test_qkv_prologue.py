@@ -24,21 +24,17 @@ import pytest
 
 from .chip_bench import _paths  # noqa: F401  (makes chipbench importable)
 from .compiled import out_and_vjp
+from .former_mixers import (
+    MIXERS, a_mixer_is_its_former_self_in_bfloat16,
+    a_recomputed_blocks_backward, l2_normalised)
 from chipbench import harness, weights
 
-from horovod_tpu.models import glm_moe, ling3flash, qwen3next
 from horovod_tpu.models.phi4flash import CausalDepthwiseConv
 from horovod_tpu.parallel import qkv_prologue as qp
-from horovod_tpu.parallel.gated_delta import gated_delta_rule
-from horovod_tpu.parallel.kda import kimi_delta_attention
 
 pytestmark = [pytest.mark.fast, pytest.mark.time_limit(120)]
 
 TAPS, EPS = 4, 1e-6
-
-
-def l2_normalised(x, eps=EPS):
-    return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), -1, keepdims=True) + eps)
 
 
 def the_chain_it_replaced(x, kernel, dim, hq, hk, hv):
@@ -167,139 +163,13 @@ def test_the_traced_call_leaves_its_tile_in_the_gauge(monkeypatch):
 
 # -- the two mixers against their former selves -------------------------------
 
-class FormerKimiDeltaAttention(nn.Module):
-    """``ling3flash.KimiDeltaAttention`` as it was before the prologue
-    (PR 41), leaf for leaf."""
-
-    cfg: ling3flash.Ling3FlashConfig
-
-    @nn.compact
-    def __call__(self, x):
-        cfg = self.cfg
-        h, d = cfg.num_heads, cfg.kda_head_dim
-        width = h * d
-        lead = x.shape[:2]
-        qkv = glm_moe._dense(cfg, 3 * width, "in_proj_qkv")(x)
-        qkv = nn.silu(CausalDepthwiseConv(
-            cfg.short_conv_kernel_size, use_bias=False, name="conv")(qkv))
-        a_log = self.param("A_log", nn.initializers.zeros, (h,), jnp.float32)
-        dt_bias = self.param("dt_bias", nn.initializers.zeros, (width,),
-                             jnp.float32)
-        f = nn.Dense(width, use_bias=False, dtype=jnp.float32,
-                     name="in_proj_f")(x)
-        bz = nn.Dense(2 * h, use_bias=False, dtype=jnp.float32,
-                      name="in_proj_bz")(x)
-        f = (f + dt_bias + cfg.dt_bias_init).reshape(*lead, h, d)
-        g = cfg.kda_lower_bound * jax.nn.sigmoid(
-            jnp.exp(a_log + cfg.a_log_init)[:, None] * f)
-        beta = jax.nn.sigmoid(bz[..., :h])
-        q = l2_normalised(qkv[..., :width].reshape(*lead, h, d)) * d ** -0.5
-        k = l2_normalised(qkv[..., width:2 * width].reshape(*lead, h, d))
-        v = qkv[..., 2 * width:].reshape(*lead, h, d)
-        o = kimi_delta_attention(q.astype(cfg.dtype), k.astype(cfg.dtype),
-                                 v.astype(cfg.dtype), g, beta)
-        y = nn.RMSNorm(epsilon=cfg.rms_norm_eps, dtype=jnp.float32,
-                       param_dtype=jnp.float32, name="norm")(
-                           o.astype(jnp.float32)) \
-            * jax.nn.sigmoid(bz[..., h:])[..., None]
-        return glm_moe._dense(cfg, cfg.hidden_size, "out_proj")(
-            y.astype(cfg.dtype).reshape(*lead, width))
-
-
-class FormerGatedDeltaNet(nn.Module):
-    """``qwen3next.GatedDeltaNet`` as it was before the prologue (PR
-    33), leaf for leaf."""
-
-    cfg: qwen3next.Qwen3NextConfig
-
-    @nn.compact
-    def __call__(self, x):
-        cfg = self.cfg
-        hk, hv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
-        dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
-        keys, values = hk * dk, hv * dv
-        lead = x.shape[:2]
-        qkvz = qwen3next._dense(cfg, 2 * keys + 2 * values, "in_proj_qkvz")(x)
-        ba = qwen3next._dense(cfg, 2 * hv, "in_proj_ba")(x) \
-            .astype(jnp.float32)
-        qkv = nn.silu(CausalDepthwiseConv(
-            cfg.linear_conv_kernel_dim, use_bias=False, name="conv")(
-                qkvz[..., :2 * keys + values]))
-        a_log = self.param("A_log", nn.initializers.zeros, (hv,),
-                           jnp.float32)
-        dt_bias = self.param("dt_bias", nn.initializers.zeros, (hv,),
-                             jnp.float32)
-        q = l2_normalised(qkv[..., :keys].reshape(*lead, hk, dk)) * dk ** -0.5
-        k = l2_normalised(qkv[..., keys:2 * keys].reshape(*lead, hk, dk))
-        v = qkv[..., 2 * keys:].reshape(*lead, hv, dv)
-        beta = jax.nn.sigmoid(ba[..., :hv])
-        g = -jnp.exp(a_log + cfg.a_log_init) * jax.nn.softplus(
-            ba[..., hv:] + dt_bias + cfg.dt_bias_init)
-        o = gated_delta_rule(q.astype(cfg.dtype), k.astype(cfg.dtype),
-                             v.astype(cfg.dtype), g, beta)
-        z = qkvz[..., 2 * keys + values:].reshape(*lead, hv, dv)
-        y = nn.RMSNorm(epsilon=cfg.rms_norm_eps, dtype=jnp.float32,
-                       param_dtype=jnp.float32, name="norm")(
-                           o.astype(jnp.float32)) \
-            * nn.silu(z.astype(jnp.float32))
-        return qwen3next._dense(cfg, cfg.hidden_size, "out_proj")(
-            y.astype(cfg.dtype).reshape(*lead, values))
-
-
-SEQ, HIDDEN = 40, 32
-MIXERS = {
-    "kimi_delta_attention": (
-        ling3flash.KimiDeltaAttention, FormerKimiDeltaAttention,
-        ling3flash.Ling3FlashConfig(
-            hidden_size=HIDDEN, num_heads=2, kda_head_dim=16,
-            dt_bias_init=-2.0)),
-    "gated_deltanet": (
-        qwen3next.GatedDeltaNet, FormerGatedDeltaNet,
-        qwen3next.Qwen3NextConfig(
-            hidden_size=HIDDEN, linear_num_key_heads=2,
-            linear_num_value_heads=4, linear_key_head_dim=16,
-            linear_value_head_dim=16, a_log_init=0.5, dt_bias_init=-2.0)),
-}
-
-
 @pytest.mark.parametrize("name", list(MIXERS))
 def test_a_mixer_is_its_former_self_in_bfloat16(name):
-    """Output and every leaf's gradient to bfloat16's tolerance, from
-    the same leaves: the tree a checkpoint addresses is unchanged, the
-    initial values too (a leaf's draw follows its path)."""
-    now, former, cfg = MIXERS[name]
-    x = jax.random.normal(jax.random.key(2), (2, SEQ, HIDDEN)) \
-        .astype(jnp.bfloat16)
-    cot = jax.random.normal(jax.random.key(3), (2, SEQ, HIDDEN)) \
-        .astype(jnp.bfloat16)
-    p = now(cfg).init(jax.random.key(1), x)["params"]
-    p_former = former(cfg).init(jax.random.key(1), x)["params"]
-    assert jax.tree_util.tree_structure(p) \
-        == jax.tree_util.tree_structure(p_former)
-    for a, b in zip(jax.tree_util.tree_leaves(p),
-                    jax.tree_util.tree_leaves(p_former)):
-        assert a.shape == b.shape and a.dtype == b.dtype
-        np.testing.assert_array_equal(a, b)
+    """``tests/former_mixers.py``: against the mixer as it was before
+    the prologue (PR 41 and PR 33), leaf for leaf."""
+    p = a_mixer_is_its_former_self_in_bfloat16(name, "prologue")
     assert p["conv"]["kernel"].dtype == jnp.float32
     assert p["conv"]["kernel"].shape[0] == TAPS
-    p = jax.tree_util.tree_map(        # leaves that do something
-        lambda a: a + 0.3 * jax.random.normal(jax.random.key(a.size),
-                                              a.shape), p)
-    got, got_grads = out_and_vjp(
-        lambda p, x: now(cfg).apply({"params": p}, x), cot, p, x)
-    want, want_grads = out_and_vjp(
-        lambda p, x: former(cfg).apply({"params": p}, x), cot, p, x)
-    f32 = lambda a: np.asarray(a.astype(jnp.float32))
-    close = lambda g, w, what: np.testing.assert_allclose(
-        f32(g), f32(w), rtol=2 ** -5,
-        atol=2 ** -6 * float(np.abs(f32(w)).max()), err_msg=what)
-    close(got, want, "out")
-    flat = lambda t: {jax.tree_util.keystr(k): v for k, v in
-                      jax.tree_util.tree_flatten_with_path(t)[0]}
-    got_grads, want_grads = flat(got_grads), flat(want_grads)
-    for path, w in want_grads.items():
-        assert np.abs(f32(w)).max() > 0, path
-        close(got_grads[path], w, path)
 
 
 # The whole models' trees at the rehearsal sizes, by the hash the
@@ -329,28 +199,13 @@ def test_the_two_models_parameter_trees_are_what_they_were(cell):
 
 # -- what a recomputed block keeps ------------------------------------------
 
-def test_a_recomputed_block_makes_the_prologues_outputs_again(capsys):
+def test_a_recomputed_block_makes_the_prologues_outputs_again():
     """``_keep_kernel_outputs`` keeps the rule's output and entering
     states and not the prologue's q, k, v (403 MB a layer at the cell's
     size): the backward of a recomputed block runs the prologue's
     forward kernel again and the rule's forward kernel not."""
-    from jax.ad_checkpoint import print_saved_residuals
-    cfg = MIXERS["kimi_delta_attention"][2]
-    block = ling3flash.RematBlock(cfg, 0)
-    x = jnp.ones((1, SEQ, HIDDEN), jnp.bfloat16)
-    positions = jnp.zeros((1, SEQ), jnp.int32)
-    p = block.init(jax.random.key(0), x, positions)
-
-    def loss(p, x):
-        return jnp.sum(block.apply(p, x, positions)[0].astype(jnp.float32))
-
-    text = str(jax.make_jaxpr(jax.grad(loss))(p, x))
-    calls = lambda name: text.count(f"name={name}\n") \
-        + text.count(f"name={name} ")
+    calls, kept = a_recomputed_blocks_backward("kimi_delta_attention")
     assert calls("qkv_prologue_fwd") == 2 and calls("qkv_prologue_bwd") == 1
     assert calls("kda_fwd") == 1 and calls("kda_bwd") == 1
-    print_saved_residuals(loss, p, x)
-    kept = [line for line in capsys.readouterr().out.splitlines()
-            if "from the argument" not in line]
     assert len(kept) == 2 and all("kimi_delta_attention" in k for k in kept)
     assert not any("qkv_prologue" in k for k in kept)
