@@ -1,10 +1,13 @@
 """The cell ``lfm2moe-injit-1chip`` (family ``lfm2_moe_lm``): its check
 passes at the rehearsal's size, fails with bfloat16 parameters and comes
 out not correct with its step broken; its file holds the published
-widths and the cut; its counts are the hand-computed ones; it is in the
-nine lists it joins and its five new ones; its new readers have nothing
-to report at a rehearsal and count a hand-made trace at this shape, k
-and v by key-value head. (Cold on this sandbox: 45 s.)"""
+widths and the cut; its counts are the hand-computed ones; it is in
+every list it joined (PR 37's scope metrics among them since PR 45) and
+in its five new ones, whoever else is; its new readers have nothing to
+report at a rehearsal and count a hand-made trace at this shape, k and
+v by key-value head. What is asserted of the manifest is asserted of
+the root's and of the one the next PR would leave (``conftest.py``).
+(Cold on this sandbox: 45 s.)"""
 
 import argparse
 import json
@@ -33,7 +36,10 @@ NEW_READERS = tuple(SCOPE_READERS) + FLASH_READERS
 JOINED = ("tokens_per_s_chip", "step_p90_ms", "mfu.lm",
           "device_idle_share.lm", "hbm_need_gb.lm", "moe_grouped_time_share",
           "moe_grouped_roofline", "moe_load_max_over_mean",
-          "moe_dropped_share")
+          "moe_dropped_share",
+          "head_loss_ms_per_step", "unscoped_ms_per_step",
+          "attn_outside_kernels_ms_per_step", "mlp_ms_per_step",
+          "moe_route_ms_per_step", "moe_dispatch_combine_ms_per_step")
 
 pytestmark = pytest.mark.time_limit(170)
 
@@ -129,7 +135,7 @@ def test_the_file_states_the_published_widths_and_the_cut():
     assert CONFIG["check"]["set_from"] and CONFIG["rehearse"]["check"]
 
 
-def test_every_number_of_the_catalogs_row_is_in_the_file():
+def test_every_number_of_the_catalogs_row_is_in_the_file(manifest):
     """The row's ``config`` as the catalog of public architectures has
     it: every key under the same name, the value its own unless the key
     is in ``reduced``."""
@@ -153,24 +159,27 @@ def test_every_number_of_the_catalogs_row_is_in_the_file():
             assert CONFIG[key] != value
         else:
             assert CONFIG[key] == value, key
-    entry = {c["name"]: c for c in M["configs"]}[NAME]
+    entry = {c["name"]: c for c in manifest["configs"]}[NAME]
     assert sorted(entry["reduced"]) == sorted(CONFIG["reduced"])
     assert entry["source"] == CONFIG["source"]
     assert entry["file"] == f"benchmarks/chip/configs/{NAME}.json"
 
 
-def test_the_cell_is_in_the_lists_it_joins_and_in_no_other():
-    cell = {w["name"]: w for w in M["workloads"]}[CELL]
+def test_the_cell_is_in_every_list_it_joins(manifest):
+    """In each of ``JOINED`` and ``NEW_READERS``, once; what else lists
+    the cell, and which cells stand beside or behind it, is the
+    manifest's (``test_manifest.py`` holds every list to the rules, and
+    ``test_rehearse.py`` every listed reader to reading its cell)."""
+    cell = {w["name"]: w for w in manifest["workloads"]}[CELL]
     assert (cell["config"], cell["traffic"], cell["chips"]) \
         == (NAME, "injit-1chip", 1)
-    listed = {m["name"] for m in M["end_to_end"] + M["per_layer"]
-              if CELL in m.get("workloads", ())}
-    assert listed == set(JOINED) | set(NEW_READERS)
-    assert len(JOINED) == 9 and len(NEW_READERS) == 5
+    listed = _paths.listed_for(manifest, CELL)
+    assert set(JOINED) | set(NEW_READERS) <= set(listed)
+    assert len(listed) == len(set(listed))
     # a metric without a list would have to be reported here too
     assert all("workloads" in m or m["name"] == "setup_s"
                or m["moves"] == "setup_s"
-               for m in M["end_to_end"] + M["per_layer"])
+               for m in manifest["end_to_end"] + manifest["per_layer"])
 
 
 # -- counts, by hand ---------------------------------------------------------
@@ -250,13 +259,13 @@ def ctx_of(peak, trace, registry=None):
 
 
 @pytest.mark.parametrize("name", NEW_READERS)
-def test_a_new_reader_has_nothing_to_report_at_a_rehearsal(name):
+def test_a_new_reader_has_nothing_to_report_at_a_rehearsal(name, manifest):
     reader = harness.load_module("layer_metrics", name)
     assert reader.read(ctx_of(None, None)) is None
-    entry = {x["name"]: x for x in M["per_layer"]}[name]
+    entry = {x["name"]: x for x in manifest["per_layer"]}[name]
     assert (reader.LAYER, reader.UNIT, reader.MOVES) \
         == (entry["layer"], entry["unit"], entry["moves"])
-    assert entry["workloads"] == [CELL]
+    assert CELL in entry["workloads"]
     assert entry["source"] == "device_trace"
     assert (entry["unit"] == "%") == (name in FLASH_READERS)
 

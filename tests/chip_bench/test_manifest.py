@@ -1,11 +1,15 @@
 """BENCHMARK.json is well formed by the contract's own rules, and every
-name in it leads to a file of its own. The entries kept for a later PR
-(``kept/eager-cells.json``) are held to the same rules, entry by entry,
-so that they can be added as they are. Each rule is a function of a
-manifest, so that a test can hold a manifest of its own making to all
-of them (:func:`hold_to_every_rule`)."""
+name in it leads to a file of its own. The entries once kept for a
+later PR (``kept/eager-cells.json``) are held to the same rules, entry
+by entry. Each rule is a function of a manifest and of nothing else (a
+manifest knows its checkout: ``_paths.root_of``), so that a test can
+hold a manifest of its own making to all of them
+(:func:`hold_to_every_rule`); every test here holds two, the root's and
+the one the next PR would leave (``conftest.py``), and none holds
+either to a count, a place in a list or a closed set of names."""
 
 import ast
+import copy
 import json
 import os
 import re
@@ -14,8 +18,10 @@ import pytest
 
 from . import _paths
 
-M = _paths.manifest()
-KEPT = _paths.manifest_with_kept()
+# Names to parametrise by, read while the tests are collected; the
+# manifests the tests hold come from the ``manifests`` fixture.
+NAMED = {"root": _paths.manifest_with_kept()}
+NAMED["grown"] = _paths.grown(NAMED["root"])
 NAME = re.compile(r"[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}\Z")
 UNIT = re.compile(r"[A-Za-z0-9_/%.\-]{1,16}\Z")
 PATH = re.compile(r"[A-Za-z0-9_.\-/]{1,200}\Z")
@@ -74,18 +80,19 @@ def rule_command(m):
     assert 1 <= len(m["command"]) <= 32
     for word in m["command"][1:]:
         assert not word.startswith("/") and ".." not in word.split("/")
-        if os.path.exists(os.path.join(_paths.ROOT, word)):
+        if os.path.exists(os.path.join(_paths.root_of(m), word)):
             assert any(word.startswith(p + "/") for p in m["paths"])
 
 
 def rule_path(m, path):
+    root = _paths.root_of(m)
     assert PATH.match(path)
-    assert os.path.isdir(os.path.join(_paths.ROOT, path))
-    for base, _, files in os.walk(os.path.join(_paths.ROOT, path)):
+    assert os.path.isdir(os.path.join(root, path))
+    for base, _, files in os.walk(os.path.join(root, path)):
         if "__pycache__" in base:
             continue
         for f in files:
-            rel = os.path.relpath(os.path.join(base, f), _paths.ROOT)
+            rel = os.path.relpath(os.path.join(base, f), root)
             assert PATH.match(rel), rel
 
 
@@ -99,13 +106,13 @@ def rule_config(m, name):
     assert any(c["file"].startswith(p + "/") for p in m["paths"])
     assert sum(1 for o in configs.values() if o["file"] == c["file"]) == 1
     assert any(w["config"] == name for w in m["workloads"])
-    with open(os.path.join(_paths.ROOT, c["file"])) as f:
+    with open(os.path.join(_paths.root_of(m), c["file"])) as f:
         data = json.load(f)
     assert sorted(data["reduced"]) == sorted(c["reduced"])
     for key in c["reduced"]:
         assert NAME.match(key) and not WIDTHS.search(key), key
     assert os.path.exists(os.path.join(
-        _paths.BENCH, "families", data["family"] + ".py"))
+        _paths.bench_of(m), "families", data["family"] + ".py"))
     assert set(data["check"]["limits"]) == {
         "loss_gap", "grad_norm_gap", "update_norm_gap"}
     assert "rehearse" in data and "assumed" in data
@@ -120,7 +127,7 @@ def rule_cell(m, name):
     assert 1 <= len(w["why"]) <= 200 and "\n" not in w["why"]
     assert sum(1 for o in cells.values() if (o["config"], o["traffic"])
                == (w["config"], w["traffic"])) == 1
-    with open(os.path.join(_paths.BENCH, "traffic",
+    with open(os.path.join(_paths.bench_of(m), "traffic",
                            w["traffic"] + ".json")) as f:
         traffic = json.load(f)
     assert traffic["chips"] == w["chips"]
@@ -154,13 +161,14 @@ def rule_end_to_end(m, name):
     assert x["source"] in ("host_clock", "device_trace")
     assert 0.01 <= x["bound"] <= 0.1
     assert all(c in by_name(m, "workloads") for c in cells_of(m, x))
-    assert os.path.exists(os.path.join(_paths.BENCH, "end_to_end",
+    assert os.path.exists(os.path.join(_paths.bench_of(m), "end_to_end",
                                        name + ".py"))
 
 
-def reader_constants(name):
+def reader_constants(name, m=None):
     """LAYER, UNIT and MOVES of a reader file, read without running it."""
-    with open(os.path.join(_paths.BENCH, "layer_metrics", name + ".py")) as f:
+    with open(os.path.join(_paths.bench_of(m), "layer_metrics",
+                           name + ".py")) as f:
         tree = ast.parse(f.read())
     return {t.id: ast.literal_eval(node.value) for node in tree.body
             if isinstance(node, ast.Assign) for t in node.targets
@@ -174,7 +182,7 @@ def rule_per_layer(m, name):
     assert NAME.match(name) and UNIT.match(x["unit"])
     assert x["better"] in ("lower", "higher") and x["source"] in SOURCES
     assert 1 <= len(x["layer"]) <= 200 and "\n" not in x["layer"]
-    consts = reader_constants(name)
+    consts = reader_constants(name, m)
     assert (consts["LAYER"], consts["UNIT"], consts["MOVES"]) == (
         x["layer"], x["unit"], x["moves"])
     moved = by_name(m, "end_to_end")[x["moves"]]
@@ -206,37 +214,55 @@ def hold_to_every_rule(m):
         rule_path(m, path)
 
 
-# -- the manifest as it is, and with the kept entries merged in -------------
+# -- the manifest as it is and as the next PR would leave it, each with the
+# -- once-kept entries merged in ---------------------------------------------
 
+def names_of(key):
+    return [(which, name) for which, m in NAMED.items()
+            for name in sorted(by_name(m, key))]
+
+
+@pytest.fixture(scope="session")
+def kept(manifests):
+    """``{"root", "grown"}`` with the once-kept entries merged in (no
+    rule changes what it is given)."""
+    return {which: _paths.manifest_with_kept(copy.deepcopy(m))
+            for which, m in manifests.items()}
+
+
+@pytest.mark.parametrize("which", list(NAMED))
 @pytest.mark.parametrize("rule", WHOLE, ids=lambda r: r.__name__)
-def test_the_manifest_as_a_whole(rule):
-    rule(M)
+def test_the_manifest_as_a_whole(rule, which, manifests):
+    rule(manifests[which])
 
 
-def test_the_merged_manifest_has_each_name_once():
-    rule_names_are_unique(KEPT)
+@pytest.mark.parametrize("which", list(NAMED))
+def test_the_merged_manifest_has_each_name_once(which, kept):
+    rule_names_are_unique(kept[which])
+    assert kept[which] == NAMED[which]      # what the names were read from
 
 
-@pytest.mark.parametrize("path", M["paths"])
-def test_paths_are_directories_of_the_benchmarks_own(path):
-    rule_path(M, path)
+@pytest.mark.parametrize("which", list(NAMED))
+@pytest.mark.parametrize("path", NAMED["root"]["paths"])
+def test_paths_are_directories_of_the_benchmarks_own(path, which, manifests):
+    rule_path(manifests[which], path)
 
 
-@pytest.mark.parametrize("name", sorted(by_name(M, "configs")))
-def test_config_entry_and_file(name):
-    rule_config(KEPT, name)
+@pytest.mark.parametrize("which, name", names_of("configs"))
+def test_config_entry_and_file(which, name, kept):
+    rule_config(kept[which], name)
 
 
-@pytest.mark.parametrize("name", sorted(by_name(KEPT, "workloads")))
-def test_cell_entry_and_traffic_file(name):
-    rule_cell(KEPT, name)
+@pytest.mark.parametrize("which, name", names_of("workloads"))
+def test_cell_entry_and_traffic_file(which, name, kept):
+    rule_cell(kept[which], name)
 
 
-@pytest.mark.parametrize("name", sorted(by_name(KEPT, "end_to_end")))
-def test_end_to_end_metric(name):
-    rule_end_to_end(KEPT, name)
+@pytest.mark.parametrize("which, name", names_of("end_to_end"))
+def test_end_to_end_metric(which, name, kept):
+    rule_end_to_end(kept[which], name)
 
 
-@pytest.mark.parametrize("name", sorted(by_name(KEPT, "per_layer")))
-def test_per_layer_metric_and_its_reader_file(name):
-    rule_per_layer(KEPT, name)
+@pytest.mark.parametrize("which, name", names_of("per_layer"))
+def test_per_layer_metric_and_its_reader_file(which, name, kept):
+    rule_per_layer(kept[which], name)

@@ -179,14 +179,17 @@ def ctx_of(peak, trace, registry):
 
 
 @pytest.mark.parametrize("name", NEW_READERS)
-def test_a_new_reader_has_nothing_to_report_at_a_rehearsal(name):
+def test_a_new_reader_has_nothing_to_report_at_a_rehearsal(name, manifest):
+    """(Of the root's manifest and of the one the next PR would leave,
+    ``conftest.py``: another cell that runs the kernels may be listed
+    beside this one.)"""
     reader = harness.load_module("layer_metrics", name)
     assert reader.read(ctx_of(None, None, {CHUNK_GAUGE: 128})) is None
     assert reader.read(ctx_of(None, None, {})) is None
-    entry = {x["name"]: x for x in M["per_layer"]}[name]
+    entry = {x["name"]: x for x in manifest["per_layer"]}[name]
     assert (reader.LAYER, reader.UNIT, reader.MOVES) \
         == (entry["layer"], entry["unit"], entry["moves"])
-    assert entry["workloads"] == [CELL]
+    assert CELL in entry["workloads"]
 
 
 def test_the_readers_match_kernels_by_name():

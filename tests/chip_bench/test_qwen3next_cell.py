@@ -1,9 +1,12 @@
 """The cell ``qwen3next-injit-1chip`` (family ``qwen3next_lm``): its
 check passes at the rehearsal's size and fails with bfloat16
 parameters, its file holds the published widths and the cut, its counts
-are the hand-computed ones (the rule's recurrence and the readers it
-joins among them), and its readers have nothing to report at a
-rehearsal."""
+are the hand-computed ones (the rule's recurrence, at a second shape
+too, and the readers it joins among them), it is in every list it
+joined (PR 37's scope metrics and the two ``gdn_*`` ones among them
+since PR 45) whoever else is, and its readers have nothing to report at
+a rehearsal. What is asserted of the manifest is asserted of the
+root's and of the one the next PR would leave (``conftest.py``)."""
 
 import inspect
 import json
@@ -22,10 +25,14 @@ with open(os.path.join(_paths.BENCH, "configs",
     CONFIG = json.load(f)
 SZ = FAMILY.sizes(CONFIG, CONFIG["assumed"]["per_chip_batch"])
 NEW_READERS = ("gdn_time_share", "gdn_roofline")
+SCOPE_READERS = ("gdn_outside_kernels_ms_per_step", "gdn_conv_ms_per_step")
 JOINED = ("tokens_per_s_chip", "step_p90_ms", "mfu.lm",
           "device_idle_share.lm", "hbm_need_gb.lm", "moe_grouped_time_share",
           "moe_grouped_roofline", "moe_load_max_over_mean",
-          "moe_dropped_share", "mla_flash_time_share", "mla_flash_roofline")
+          "moe_dropped_share", "mla_flash_time_share", "mla_flash_roofline",
+          "head_loss_ms_per_step", "unscoped_ms_per_step",
+          "attn_outside_kernels_ms_per_step", "mlp_ms_per_step",
+          "moe_route_ms_per_step", "moe_dispatch_combine_ms_per_step")
 CHUNK_GAUGE = 'hvd_gdn_chunks{kind="chunk_length"}'
 
 pytestmark = pytest.mark.time_limit(170)
@@ -94,7 +101,7 @@ def test_the_file_states_the_published_widths_and_the_cut():
     assert any("multi-token" in d for d in CONFIG["departures"])
 
 
-def test_every_number_of_the_catalogs_row_is_in_the_file():
+def test_every_number_of_the_catalogs_row_is_in_the_file(manifest):
     """The row's ``config`` as the catalog of public architectures has
     it: every key under the same name, the value its own unless the key
     is in ``reduced``."""
@@ -121,19 +128,25 @@ def test_every_number_of_the_catalogs_row_is_in_the_file():
             assert CONFIG[key] != value
         else:
             assert CONFIG[key] == value, key
-    entry = {c["name"]: c for c in M["configs"]}[
+    entry = {c["name"]: c for c in manifest["configs"]}[
         "qwen3-next-80b-a3b-ep16-l4"]
     assert sorted(entry["reduced"]) == sorted(CONFIG["reduced"])
     assert entry["source"] == CONFIG["source"]
 
 
-def test_the_cell_is_in_the_lists_it_joins_and_in_no_other():
-    cell = {w["name"]: w for w in M["workloads"]}[CELL]
+def test_the_cell_is_in_every_list_it_joins(manifest):
+    """In each of ``JOINED``, ``NEW_READERS`` and ``SCOPE_READERS``,
+    once; what else lists the cell, and which cells stand beside or
+    behind it, is the manifest's (``test_manifest.py`` holds every list
+    to the rules, and ``test_rehearse.py`` every listed reader to
+    reading its cell)."""
+    cell = {w["name"]: w for w in manifest["workloads"]}[CELL]
     assert (cell["config"], cell["traffic"], cell["chips"]) \
         == ("qwen3-next-80b-a3b-ep16-l4", "injit-1chip", 1)
-    listed = {m["name"] for m in M["end_to_end"] + M["per_layer"]
-              if CELL in m.get("workloads", ())}
-    assert listed == set(JOINED) | set(NEW_READERS)
+    listed = _paths.listed_for(manifest, CELL)
+    assert set(JOINED) | set(NEW_READERS) | set(SCOPE_READERS) \
+        <= set(listed)
+    assert len(listed) == len(set(listed))
 
 
 # -- counts, by hand ---------------------------------------------------------
@@ -164,20 +177,46 @@ def test_the_configuration_holds_625_7_million_parameters():
     assert whole == pytest.approx(79.67e9, rel=1e-3)
 
 
-def test_the_rule_is_counted_by_its_recurrence():
+# batch, positions, key heads, value heads, key head, value head, layers;
+# one forward's operations, what the kernels are handed a layer (bytes)
+RULE_SHAPES = {
+    # the cell's own: 16 key heads under 32 value heads, both of 128
+    "cell": ((1, S, HK, HV, DH, DH, 3), 60.1e9, 1_086_324_736),
+    # 30 heads whose key is 96 wide and whose value 192 (neither a
+    # multiple of the 128 lanes, nor of a width with the other), two
+    # rows of 8,192: entries 2 x 8192 x 30 x 96 x 192 = 9,059,696,640;
+    # q, k 94,371,840 bytes each, v 188,743,680, a gate 1,966,080
+    "96_by_192": ((2, 8192, 30, 30, 96, 192, 3), 63.4e9, 1_521_745_920),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(RULE_SHAPES))
+def test_the_rule_is_counted_by_its_recurrence(shape):
     """Seven operations a state entry and position: the decay, ``S^T
     k``, the rank-one update, ``S^T q``; a step is four forwards'
-    worth; the bytes are the kernels' operands."""
-    entry_steps = S * HV * DH * DH
-    assert gdn_flops.rule_forward_ops(1, S, HV, DH, DH) == 7 * entry_steps \
-        == pytest.approx(60.1e9, rel=1e-3)
-    assert gdn_flops.rule_ops_per_step(1, S, HV, DH, DH, 3) \
-        == 3 * 4 * 7 * entry_steps == pytest.approx(721.6e9, rel=1e-3)
-    qk, v, gate = S * HK * DH * 2, S * HV * DH * 2, S * HV * 4
-    handed = (2 * qk + 2 * v + 2 * gate) + (4 * qk + 3 * v + 4 * gate)
-    assert handed == 1_086_324_736
-    assert gdn_flops.rule_bytes_per_step(1, S, HK, HV, DH, DH, 3) \
-        == 3 * handed
+    worth; the bytes are the kernels' operands. From the shapes alone,
+    so at a second shape as at the cell's."""
+    (b, s, hk, hv, dk, dv, layers), forward, handed = RULE_SHAPES[shape]
+    entry_steps = b * s * hv * dk * dv
+    assert gdn_flops.rule_forward_ops(b, s, hv, dk, dv) == 7 * entry_steps \
+        == pytest.approx(forward, rel=1e-3)
+    assert gdn_flops.rule_ops_per_step(b, s, hv, dk, dv, layers) \
+        == layers * 4 * 7 * entry_steps
+    qk, v, gate = b * s * hk * dk * 2, b * s * hv * dv * 2, b * s * hv * 4
+    assert (2 * qk + 2 * v + 2 * gate) + (4 * qk + 3 * v + 4 * gate) \
+        == handed
+    assert gdn_flops.rule_bytes_per_step(b, s, hk, hv, dk, dv, layers) \
+        == layers * handed
+    if shape == "cell":
+        assert layers * 4 * 7 * entry_steps \
+            == pytest.approx(721.6e9, rel=1e-3)
+    else:
+        assert 7 * entry_steps == 63_417_876_480
+        assert layers * 4 * 7 * entry_steps == 761_014_517_760
+        assert layers * handed == 4_565_237_760
+        # memory-bound here too: the bytes take longer than the products
+        assert layers * handed / 819e9 \
+            > layers * 4 * 7 * entry_steps / 197e12
     # nothing the kernels choose for themselves is in the count: the
     # reader has no chunk length to give it, and the states a kernel
     # keeps between its two passes are left out
@@ -220,15 +259,15 @@ def ctx_of(peak, trace, registry):
             "family": FAMILY, "steps": 7, "notes": []}
 
 
-@pytest.mark.parametrize("name", NEW_READERS)
-def test_a_new_reader_has_nothing_to_report_at_a_rehearsal(name):
+@pytest.mark.parametrize("name", NEW_READERS + SCOPE_READERS)
+def test_a_new_reader_has_nothing_to_report_at_a_rehearsal(name, manifest):
     reader = harness.load_module("layer_metrics", name)
     assert reader.read(ctx_of(None, None, {CHUNK_GAUGE: 64})) is None
     assert reader.read(ctx_of(None, None, {})) is None
-    entry = {x["name"]: x for x in M["per_layer"]}[name]
+    entry = {x["name"]: x for x in manifest["per_layer"]}[name]
     assert (reader.LAYER, reader.UNIT, reader.MOVES) \
         == (entry["layer"], entry["unit"], entry["moves"])
-    assert entry["workloads"] == [CELL]
+    assert CELL in entry["workloads"]
 
 
 def test_the_readers_match_kernels_by_name_and_count_this_shape():

@@ -1,7 +1,10 @@
 """Every cell through the real command, at the rehearsal's tiny sizes
-on the CPU; and the command refusing to measure where it cannot."""
+on the CPU; every reader a manifest lists for a cell reading that
+cell's rehearsal without raising; and the command refusing to measure
+where it cannot."""
 
 import json
+import math
 import os
 import shutil
 import subprocess
@@ -26,29 +29,30 @@ def last_json(stdout: str) -> dict:
     return json.loads(stdout.strip().splitlines()[-1])
 
 
-# One process a cell: the eager cell's rehearsal and the four-chip
-# cell's are the traced ones, so that the per-layer readers run through
-# the command as well. The eager cell is kept for a later PR (PERF.md,
-# Open questions), so it runs from a root whose manifest has its entries
-# merged in: by name, so that the PR which moves it into BENCHMARK.json
-# finds it here once. The cell of four chips is one process over four
-# forced CPU devices (``run.py`` asks for them where the environment
-# has not).
-TRACED = ("resnet50-eager-1rank", "lm-injit-4chip")
-
-
+# One process a cell, and every one traced (a traced rehearsal costs what
+# a plain one does: nothing is profiled on the CPU, and the step is the
+# same program from the same cache), so that every reader the manifest
+# lists for a cell reads that cell's rehearsal through the command, with
+# the program's registry on: one that raises fails the run, and the
+# count the run prints is the manifest's. (``test_kept.py`` keeps a plain
+# rehearsal through the command.) The eager cell came in with PR 26; a
+# cell that only ``kept/`` has would run from a root whose manifest has
+# its entries merged in by name. The cell of four chips is one process
+# over four forced CPU devices (``run.py`` asks for them where the
+# environment has not).
 @pytest.mark.parametrize("cell", [w["name"] for w in KEPT["workloads"]])
 def test_cell_rehearses_through_the_command(cell, tmp_path):
-    traced = cell in TRACED
     root = _paths.ROOT
     if cell not in [w["name"] for w in M["workloads"]]:
         root = tmp_path
         _paths.checkout_with(KEPT, root)
     out = run(["--workload", cell, "--seed", str(2**31 + 12345),
-               "--seconds", "1", "--trace", str(int(traced)), "--rehearse"],
-              cwd=root)
+               "--seconds", "1", "--trace", "1", "--rehearse"], cwd=root)
     check_rehearsal(out, cell, root=root)
-    assert ("rehearsal: per-layer readers" in out.stdout) == traced
+    listed = [x for x in KEPT["per_layer"]
+              if cell in x.get("workloads", [cell])]
+    assert f"rehearsal: per-layer readers {len(listed)} listed for the " \
+        f"cell" in out.stdout
 
 
 def check_rehearsal(out, cell, root=_paths.ROOT):
@@ -65,6 +69,54 @@ def check_rehearsal(out, cell, root=_paths.ROOT):
     assert '"window"' not in out.stdout.split(
         "compilations and cache traffic by part: ")[1].splitlines()[0]
     assert not os.path.exists(os.path.join(root, ".bench_run", cell))
+
+
+# What a closed set of lists in a cell's own test once guarded (a cell
+# listed under a reader that misreads it) is held here for every list of
+# every manifest: each (metric, cell) pair loads the metric's reader file
+# and reads what the harness hands a reader behind the cell's rehearsal.
+def pairs_of(m):
+    cells = [w["name"] for w in m["workloads"]]
+    return [(x["name"], cell) for x in m["per_layer"]
+            for cell in x.get("workloads", cells)]
+
+
+ROOT_PAIRS = pairs_of(M)
+PAIRS = [("root", *pair) for pair in ROOT_PAIRS] + [
+    ("grown", *pair) for pair in pairs_of(_paths.grown(M))
+    if pair not in set(ROOT_PAIRS)]
+
+
+@pytest.mark.parametrize("which, metric, cell", PAIRS)
+def test_a_listed_reader_reads_its_cells_rehearsal(which, metric, cell,
+                                                   manifests, monkeypatch):
+    """In process and without a step run: the sizes are the rehearsal's
+    own (the files' ``rehearse`` blocks laid over them, as the command
+    lays them), the numbers beside them made up, and there is neither a
+    trace nor a peak nor a registry, as where tracing is off. The
+    reader gives a number or ``None``. (The rehearsals above do the
+    same through the command for the root's pairs, with the program's
+    registry on; here a pair that fails says which.) Of the grown
+    manifest the pairs the root's has not: the new cell's, the new
+    metric's, the grown list's."""
+    from chipbench import harness
+    m = manifests[which]
+    assert (metric, cell) in pairs_of(m)
+    monkeypatch.setattr(harness, "ROOT", _paths.root_of(m))
+    monkeypatch.setattr(harness, "HERE", _paths.bench_of(m))
+    spec = harness.resolve_cell(m, cell, rehearse=True)
+    family = harness.load_module("families", spec["config"]["family"])
+    ranks, chips = spec["traffic"]["ranks"], spec["traffic"]["chips"]
+    ctx = {"spec": spec, "family": family, "peak": None, "trace": None,
+           "sz": family.sizes(spec["config"],
+                              spec["config"]["assumed"]["per_chip_batch"]),
+           "rate": 1234.5, "steps": 6, "group": 2,
+           "step_ms": [41.0, 40.0, 43.0], "window_s": 0.248,
+           "phases": {p: 0.5 for p in harness.PHASES}, "setup_s": 3.0,
+           "need_bytes": 123_456_789, "registry": {}, "size": ranks,
+           "chips": 1 if ranks > 1 else chips, "notes": []}
+    value = harness.load_module("layer_metrics", metric).read(ctx)
+    assert value is None or math.isfinite(value), (metric, cell, value)
 
 
 @pytest.mark.slow

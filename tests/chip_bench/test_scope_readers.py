@@ -3,40 +3,43 @@
 time is counted once, a ``while`` keeps what its body's ops leave, a
 table that knows too little of the window gives no value, and without
 a trace or a table (a rehearsal, an older commit) every reader gives
-``None``. The ten metric files are held to their entries."""
+``None``. The ten metric files are held to their entries, in the
+root's manifest and in the one the next PR would leave: the scopes a
+reader asks for are pinned, the cells it is listed for are the least
+it is listed for."""
 
 import json
 
 import pytest
 
-from . import _paths
+from . import _paths    # noqa: F401  (puts chipbench on the path)
 from chipbench import harness, scope_readers
 
 pytestmark = pytest.mark.time_limit(60)
 
-M = _paths.manifest()
 MS = 1e6
-LM_CELLS = ["lm-injit-1chip", "lm-injit-4chip", "glm47flash-injit-1chip",
-            "phi4flash-injit-1chip"]
-# metric -> (scopes, less, cells it is listed for)
+SPARSE_CELLS = ["glm47flash-injit-1chip", "qwen3next-injit-1chip",
+                "lfm2moe-injit-1chip", "ling3flash-injit-1chip"]
+LM_CELLS = ["lm-injit-1chip", "lm-injit-4chip", "phi4flash-injit-1chip"] \
+    + SPARSE_CELLS
+# metric -> (scopes, less, the cells it is listed for at least)
 METRICS = {
     "head_loss_ms_per_step": (("lm_head_loss",), None, LM_CELLS),
     "unscoped_ms_per_step": (("",), None, LM_CELLS),
     "attn_outside_kernels_ms_per_step": (
-        ("attn", "mla", "diff_attn", "diff_attn.", "gated_attn"), "flash_",
-        LM_CELLS),
+        ("attn", "mla", "diff_attn", "diff_attn.", "gated_attn",
+         "normed_attn"), "flash_", LM_CELLS),
     "mlp_ms_per_step": (("mlp", "moe.shared"), None, LM_CELLS),
-    "moe_route_ms_per_step": (("moe.route",), None,
-                              ["glm47flash-injit-1chip"]),
+    "moe_route_ms_per_step": (("moe.route",), None, SPARSE_CELLS),
     "moe_dispatch_combine_ms_per_step": (
-        ("moe.dispatch", "moe.combine"), None, ["glm47flash-injit-1chip"]),
+        ("moe.dispatch", "moe.combine"), None, SPARSE_CELLS),
     "ssm_outside_kernels_ms_per_step": (("ssm.",), "ssm_scan_",
                                         ["phi4flash-injit-1chip"]),
     "injit_exchange_ms_per_step": (("exchange",), None, ["lm-injit-4chip"]),
-    # their cell's lists are pinned by a test of its own
-    # (test_qwen3next_cell.py): files here, entries in a later PR
-    "gdn_outside_kernels_ms_per_step": (("gdn.",), "gdn_", None),
-    "gdn_conv_ms_per_step": (("gdn.conv",), None, None),
+    "gdn_outside_kernels_ms_per_step": (("gdn.",), "gdn_",
+                                        ["qwen3next-injit-1chip"]),
+    "gdn_conv_ms_per_step": (("gdn.conv",), None,
+                             ["qwen3next-injit-1chip"]),
 }
 
 TABLE = {"while.4": "lm_head_loss", "fusion.20": "lm_head_loss",
@@ -147,7 +150,7 @@ def test_no_trace_or_no_table_gives_none(monkeypatch):
 
 @pytest.mark.parametrize("name", sorted(METRICS))
 def test_a_scope_metric_reads_its_scopes_and_stands_by_its_entry(
-        name, noted, monkeypatch):
+        name, noted, monkeypatch, manifest):
     scopes, less, cells = METRICS[name]
     reader = harness.load_module("layer_metrics", name)
     assert (reader.LAYER, reader.UNIT, reader.MOVES) == (
@@ -157,14 +160,13 @@ def test_a_scope_metric_reads_its_scopes_and_stands_by_its_entry(
         scope_readers, "scope_ms_per_step",
         lambda ctx, scopes, less=None: asked.append((scopes, less)) or 1.5)
     assert reader.read({}) == 1.5 and asked == [(scopes, less)]
-    entry = {x["name"]: x for x in M["per_layer"]}.get(name)
-    if cells is None:
-        assert entry is None
-    else:
-        assert entry == {
-            "name": name, "unit": "ms", "better": "lower",
-            "source": "device_trace", "layer": "User's jitted step",
-            "moves": "tokens_per_s_chip", "workloads": cells}
+    entry = {x["name"]: x for x in manifest["per_layer"]}[name]
+    listed = entry.pop("workloads")
+    assert entry == {
+        "name": name, "unit": "ms", "better": "lower",
+        "source": "device_trace", "layer": "User's jitted step",
+        "moves": "tokens_per_s_chip"}
+    assert set(cells) <= set(listed) and len(listed) == len(set(listed))
     assert reader.read.__module__ and reader.__doc__
     monkeypatch.undo()
     assert reader.read({"steps": 2, "notes": [], "trace": None}) is None

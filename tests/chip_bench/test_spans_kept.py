@@ -1,9 +1,13 @@
 """The readers of the program's own spans: the entries kept in
-``kept/eager-spans.json`` are well formed as ``test_manifest.py`` holds
-the others to be, the eager cell rehearses traced from a manifest copy
-with both kept files added and the new readers give values, and
-``program_spans.idle_under`` splits a hand-made trace as it should."""
+``kept/eager-spans.json`` (six of them in the manifest too since PR 45;
+``pack_unpack_ms_per_step`` has nothing to read at one rank) are well
+formed as ``test_manifest.py`` holds the others to be, the eager cell
+rehearses traced from a manifest with both kept files merged in and
+the span readers give values that a manifest without them does not,
+and ``program_spans.idle_under`` splits a hand-made trace as it
+should."""
 
+import copy
 import re
 
 import pytest
@@ -22,31 +26,40 @@ REHEARSED = ["enqueue_ms_per_step", "queue_wait_ms_per_step",
              "sync_wait_ms_per_step"]
 
 
-def manifest_with_both() -> dict:
-    return _paths.merge_kept(_paths.manifest_with_kept(), SPANS_KEPT)
+def manifest_with_both(m=None) -> dict:
+    return _paths.merge_kept(_paths.manifest_with_kept(m), SPANS_KEPT)
+
+
+def without_the_spans(m: dict) -> dict:
+    """``m`` as it was before the seven came in: their entries out."""
+    m["per_layer"] = [x for x in m["per_layer"] if x["name"] not in NEW]
+    return m
 
 
 @pytest.mark.parametrize("name", sorted(NEW))
-def test_kept_span_metric_and_its_reader_file(name):
-    m = NEW[name]
+def test_kept_span_metric_and_its_reader_file(name, manifest):
+    m = NEW[name]           # the kept file's own entry, not a manifest's
     assert set(m) == {"name", "unit", "better", "source", "layer",
                       "moves", "workloads"}
     assert NAME.match(name) and UNIT.match(m["unit"])
     assert m["better"] == "lower" and m["source"] in SOURCES
     assert m["workloads"] == [CELL]
-    consts = reader_constants(name)
+    consts = reader_constants(name, manifest)
     assert (consts["LAYER"], consts["UNIT"], consts["MOVES"]) == (
         m["layer"], m["unit"], m["moves"])
-    both = manifest_with_both()
+    both = manifest_with_both(copy.deepcopy(manifest))
     assert m["moves"] in [e["name"] for e in both["end_to_end"]]
-    assert m["layer"] in {x["layer"] for x in
-                          _paths.manifest_with_kept()["per_layer"]}
+    assert m["layer"] in {x["layer"] for x in _paths.manifest_with_kept(
+        without_the_spans(manifest))["per_layer"]}
     names = [x["name"] for x in both["end_to_end"] + both["per_layer"]]
     assert names.count(name) == 1
+    mine = {x["name"]: x for x in both["per_layer"]}[name]
+    assert CELL in mine["workloads"]
+    assert _paths.but_workloads(mine) == _paths.but_workloads(m)
 
 
-def test_state_broadcast_s_is_listed_for_every_cell():
-    (entry,) = [m for m in _paths.manifest()["per_layer"]
+def test_state_broadcast_s_is_listed_for_every_cell(manifest):
+    (entry,) = [m for m in manifest["per_layer"]
                 if m["name"] == "state_broadcast_s"]
     assert entry == {"name": "state_broadcast_s", "unit": "s",
                      "better": "lower", "source": "program_span",
@@ -57,8 +70,9 @@ def test_state_broadcast_s_is_listed_for_every_cell():
 def test_eager_cell_rehearses_traced_with_the_span_readers(tmp_path):
     """Through the command, from a root whose manifest has both kept
     files' entries: the span readers give values there (the count the
-    rehearsal prints), and give none from a root without the program's
-    spans to read (the same count with the registry's family gone)."""
+    rehearsal prints), and a root whose manifest is without
+    ``eager-spans.json``'s seven (taken out, since the manifest has had
+    six of them from PR 45 on) gets five values fewer."""
     both = manifest_with_both()
     _paths.checkout_with(both, tmp_path)
     out = run(["--workload", CELL, "--seed", str(2**31 + 24), "--seconds",
@@ -71,7 +85,8 @@ def test_eager_cell_rehearses_traced_with_the_span_readers(tmp_path):
         out.stdout).groups())
     assert listed == len([m for m in both["per_layer"]
                           if CELL in m.get("workloads", [CELL])])
-    old = _paths.manifest_with_kept()
+    old = without_the_spans(_paths.manifest_with_kept())
+    assert len(both["per_layer"]) - len(old["per_layer"]) == len(NEW)
     _paths.checkout_with(old, tmp_path / "old")
     before = run(["--workload", CELL, "--seed", str(2**31 + 24),
                   "--seconds", "1", "--trace", "1", "--rehearse"],
