@@ -371,6 +371,8 @@ DEVICE_SCOPES = {
     "shortconv.conv": "its two gates and the three causal depthwise taps",
     "normed_attn": "grouped-head attention with normalised q and k "
                    "around its flash call",
+    "post_norm": "a block's RMSNorms on the outputs of its mixer and of "
+                 "its feed-forward, with the residual adds they feed",
     "ssm.proj": "Mamba's four projections",
     "ssm.conv": "its causal depthwise convolution and SiLU",
     "ssm.scan": "the relayouts and the selective scan's two kernels",

@@ -47,6 +47,7 @@ next-token cross-entropy on an untied head.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Optional, Tuple
 
 import flax.linen as nn
@@ -56,7 +57,9 @@ import jax.numpy as jnp
 from horovod_tpu.models.glm_moe import ExpertLayer, _keep_kernel_outputs
 from horovod_tpu.models.transformer import apply_rope
 from horovod_tpu.parallel.delta_epilogue import delta_epilogue
-from horovod_tpu.parallel.gated_delta import gated_delta_rule
+from horovod_tpu.parallel.gated_delta import (
+    LANES, gated_delta_rule, laid_columns, lay_heads, take_heads,
+)
 from horovod_tpu.parallel.qkv_prologue import qkv_prologue
 
 
@@ -79,6 +82,9 @@ class Qwen3NextConfig:
     linear_key_head_dim: int = 128
     linear_value_head_dim: int = 128
     linear_conv_kernel_dim: int = 4
+    # beta = 2 sigmoid(b): the state's transition I - beta k k^T may
+    # have a negative eigenvalue (Olmo-Hybrid's layers do)
+    linear_allow_neg_eigval: bool = False
     # Added to the learnt A_log and dt_bias. 0 for trained weights
     # (their leaves hold it); a job that starts from leaves drawn around
     # zero gives the family's starting point here, the log of a decay
@@ -132,6 +138,13 @@ def best_grouped_attention(q, k, v):
     return fa._dense_reference(q, k, v, True, 0, 0)
 
 
+def head_lanes() -> int:
+    """The lanes a run of a delta rule's columns is laid out to between
+    its kernels: the vector register's where they are compiled, 1 (as
+    the heads come) under the interpreter, which takes any block."""
+    return LANES if jax.default_backend() == "tpu" else 1
+
+
 def _dense(cfg: Qwen3NextConfig, features, name: str, axis=-1):
     return nn.DenseGeneral(features, axis=axis, use_bias=False,
                            dtype=cfg.dtype, name=name)
@@ -165,20 +178,26 @@ class QkvPrologue(nn.Module):
     and SiLU; the first ``normalised_heads`` heads (q's and k's)
     L2-normalised, the first ``scaled_heads`` of them (q's) scaled by
     ``head_dim ** -0.5``. One pass of ``parallel.qkv_prologue`` each
-    way; the Gated DeltaNet's and Kimi delta attention's both."""
+    way; the Gated DeltaNet's and Kimi delta attention's both. With
+    ``lay`` (a run of columns, the lanes it is laid out to) ``x`` comes
+    laid out (``lay_heads``), the kernel is laid out here, and q, k and
+    v leave so."""
 
     taps: int
     channels: int
     head_dim: int
     normalised_heads: int
     scaled_heads: int
+    lay: Tuple[int, int] = (1, 1)
 
     @nn.compact
     def __call__(self, x):
         kernel = self.param("kernel", nn.initializers.lecun_normal(),
                             (self.taps, self.channels), jnp.float32)
-        return qkv_prologue(x, kernel, self.head_dim, self.normalised_heads,
-                            self.scaled_heads)
+        return qkv_prologue(x, lay_heads(kernel, *self.lay),
+                            laid_columns(self.head_dim, *self.lay),
+                            self.normalised_heads, self.scaled_heads,
+                            key_dim=self.head_dim)
 
 
 class GatedHeadNorm(nn.Module):
@@ -189,22 +208,38 @@ class GatedHeadNorm(nn.Module):
     scalar ``gate`` [B, S, heads], or an element's in the ``heads x
     head_dim`` columns of ``gate`` from ``gate_start`` on. One pass of
     ``parallel.delta_epilogue`` each way; the Gated DeltaNet's and Kimi
-    delta attention's both."""
+    delta attention's both. With ``lay`` (a run of columns, the lanes
+    it is laid out to) ``o`` and an element's gate come laid out
+    (``lay_heads``), the scale is laid out here, and y leaves so."""
 
     head_dim: int
     activation: str
     eps: float
+    lay: Tuple[int, int] = (1, 1)
 
     @nn.compact
     def __call__(self, o, gate, gate_start: int = 0):
         scale = self.param("scale", nn.initializers.ones, (self.head_dim,),
                            jnp.float32)
-        return delta_epilogue(o, scale, gate, self.head_dim, self.activation,
-                              gate_start, self.eps)
+        return delta_epilogue(o, lay_heads(scale, *self.lay), gate,
+                              laid_columns(self.head_dim, *self.lay),
+                              self.activation, gate_start, self.eps,
+                              filled=self.head_dim)
 
 
 class GatedDeltaNet(nn.Module):
-    cfg: Qwen3NextConfig
+    """``cfg`` says what differs between the models that have the layer
+    (``Qwen3NextConfig``, ``olmo_hybrid.OlmoHybridConfig``): the heads
+    and their widths, and whether ``beta`` reaches 2
+    (``linear_allow_neg_eigval``). **Heads off the lane tile** (key
+    heads of 96 over value heads of 192) travel laid out from the
+    projection to the output projection: every ``gcd(Dk, Dv)`` columns
+    of ``qkvz`` behind zeros up to whole lanes (``head_lanes``;
+    ``parallel.gated_delta.lay_heads``: one copy in, one out, each way),
+    so that the three kernels between walk whole tiles; at heads of 128
+    nothing is laid out and nothing copied."""
+
+    cfg: Any
 
     @nn.compact
     def __call__(self, x):
@@ -212,14 +247,18 @@ class GatedDeltaNet(nn.Module):
         hk, hv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
         dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
         keys, values = hk * dk, hv * dv
+        lay = (math.gcd(dk, dv), head_lanes())
+        wide = lambda n: laid_columns(n, *lay)
+        beta_max = 2.0 if cfg.linear_allow_neg_eigval else 1.0
         lead = x.shape[:2]
         with jax.named_scope("gdn.proj"):
             qkvz = _dense(cfg, 2 * keys + 2 * values, "in_proj_qkvz")(x)
             ba = _dense(cfg, 2 * hv, "in_proj_ba")(x).astype(jnp.float32)
         with jax.named_scope("gdn.conv"):
+            qkvz = lay_heads(qkvz, *lay)
             q, k, v = QkvPrologue(
                 cfg.linear_conv_kernel_dim, 2 * keys + values, dk, 2 * hk, hk,
-                name="conv")(qkvz)
+                lay, name="conv")(qkvz)
         a_log = self.param("A_log", nn.initializers.zeros, (hv,),
                            jnp.float32)
         dt_bias = self.param("dt_bias", nn.initializers.zeros, (hv,),
@@ -227,15 +266,21 @@ class GatedDeltaNet(nn.Module):
         with jax.named_scope("gdn.rule"):
             # float32 up to the kernel's door: g is an exponent's argument
             beta = jax.nn.sigmoid(ba[..., :hv])
+            if cfg.linear_allow_neg_eigval:
+                beta = 2.0 * beta
             g = -jnp.exp(a_log + cfg.a_log_init) * jax.nn.softplus(
                 ba[..., hv:] + dt_bias + cfg.dt_bias_init)
             o = gated_delta_rule(
-                q.reshape(*lead, hk, dk), k.reshape(*lead, hk, dk),
-                v.reshape(*lead, hv, dv), g, beta)
+                q.reshape(*lead, hk, wide(dk)), k.reshape(*lead, hk, wide(dk)),
+                v.reshape(*lead, hv, wide(dv)), g, beta, beta_max=beta_max,
+                filled=(dk, dv))
         with jax.named_scope("gdn.gate"):
             # z is qkvz's last columns, read where they lie
-            y = GatedHeadNorm(dv, "silu", cfg.rms_norm_eps, name="norm")(
-                o.reshape(*lead, values), qkvz, 2 * keys + values)
+            y = GatedHeadNorm(dv, "silu", cfg.rms_norm_eps, lay,
+                              name="norm")(
+                o.reshape(*lead, wide(values)), qkvz,
+                wide(2 * keys + values))
+            y = take_heads(y, *lay)
         with jax.named_scope("gdn.proj"):
             return _dense(cfg, cfg.hidden_size, "out_proj")(y)
 

@@ -28,6 +28,7 @@ from horovod_tpu.compat import jaxshim
 from horovod_tpu.models.glm_moe import ABSENT, DROPPED, GlmMoeLM
 from horovod_tpu.models.lfm2 import Lfm2MoeLM
 from horovod_tpu.models.ling3flash import Ling3FlashLM
+from horovod_tpu.models.olmo_hybrid import OlmoHybridLM
 from horovod_tpu.models.phi4flash import Phi4FlashLM
 from horovod_tpu.models.qwen3next import Qwen3NextLM
 from horovod_tpu.models.resnet import ResNet50
@@ -312,6 +313,23 @@ def ling3flash_train_step(model: Ling3FlashLM, tx, mesh):
     recomputed with its kernels' outputs kept
     (``ling3flash.RematBlock``), the counts for :class:`MoeLoadFeed`."""
     return _counted_train_step(ling3flash_loss_fn(model), tx, mesh)
+
+
+def olmo_hybrid_loss_fn(model: OlmoHybridLM):
+    """``(params, tokens) -> loss``: next-token cross-entropy through
+    the chunked head on the untied ``lm_head``."""
+    def loss_fn(p, t):
+        hidden = model.apply({"params": p}, t)
+        return lm_loss_from_hidden(hidden, p["lm_head"]["kernel"], t)
+    return loss_fn
+
+
+def olmo_hybrid_train_step(model: OlmoHybridLM, tx, mesh):
+    """The dense hybrid decoder's step, ``lm_train_step``'s shape:
+    ``(params, opt_state, tokens) -> (params, opt_state, loss)``, state
+    donated, every block recomputed with its kernels' outputs kept
+    (``olmo_hybrid.RematBlock``)."""
+    return _loss_train_step(olmo_hybrid_loss_fn(model), tx, mesh)
 
 
 class MoeLoadFeed:

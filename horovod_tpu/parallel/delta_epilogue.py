@@ -27,7 +27,9 @@ and an element's ``dz`` rounded once.
 
 Kernel shape: the prologue's (``parallel.qkv_prologue``). Grid
 ``(batch, tile of rows)``; a step holds a tile's rows of **every**
-column and walks the heads, a head's ``D`` columns at a time, a few
+column and walks the heads, a head's ``D`` columns at a time (whole
+lane tiles: 128 in both models that brought the kernels; heads off the
+tile come laid out, as ``delta_epilogue`` says), a few
 heads a pass so that their chains of loads and stores overlap. No
 halo and no staging buffer: every row is its own, and a chunk of rows
 goes from the block to registers and back (``_ROW_LADDER`` has the
@@ -178,8 +180,8 @@ def _gate(z_ref, at_rows, head, cols, per_head: bool):
     return _row_sum(jnp.where(_lane_is(head, z.shape), z, 0.0))
 
 
-def _fwd_kernel(o_ref, w_ref, z_ref, y_ref, *, dim, group, eps, chunk,
-                activation, per_head):
+def _fwd_kernel(o_ref, w_ref, z_ref, y_ref, *, dim, filled, group, eps,
+                chunk, activation, per_head):
     rows = o_ref.shape[1]
     act, _ = _ACTIVATIONS[activation]
     w = w_ref[...]
@@ -192,7 +194,7 @@ def _fwd_kernel(o_ref, w_ref, z_ref, y_ref, *, dim, group, eps, chunk,
                 at_rows = slice(at, at + chunk)
                 x = o_ref[0, at_rows, cols].astype(_F32)
                 z = _gate(z_ref, at_rows, head, cols, per_head)
-                r = jax.lax.rsqrt(_row_sum(x * x) / dim + eps)
+                r = jax.lax.rsqrt(_row_sum(x * x) / filled + eps)
                 y_ref[0, at_rows, cols] = (
                     x * (r * w) * act(z, jax.nn.sigmoid(z))).astype(
                         y_ref.dtype)
@@ -202,7 +204,7 @@ def _fwd_kernel(o_ref, w_ref, z_ref, y_ref, *, dim, group, eps, chunk,
 
 
 def _bwd_kernel(o_ref, w_ref, z_ref, dy_ref, do_ref, dz_ref, dw_ref, acc_ref,
-                *, dim, group, eps, chunk, activation, per_head, seq):
+                *, dim, filled, group, eps, chunk, activation, per_head, seq):
     from jax.experimental import pallas as pl
     rows = o_ref.shape[1]
     act, dact = _ACTIVATIONS[activation]
@@ -231,17 +233,17 @@ def _bwd_kernel(o_ref, w_ref, z_ref, dy_ref, do_ref, dz_ref, dw_ref, acc_ref,
                 z = _gate(z_ref, at_rows, head, cols, per_head)
                 s = jax.nn.sigmoid(z)
                 a, da = act(z, s), dact(z, s)
-                r = jax.lax.rsqrt(_row_sum(x * x) / dim + eps)
+                r = jax.lax.rsqrt(_row_sum(x * x) / filled + eps)
                 xr, gw = x * r, g * w
                 u = gw * xr                     # dy n, less the gate
                 if per_head:
                     m = _row_sum(u)
-                    dx = (r * a) * (gw - xr * (m / dim))
+                    dx = (r * a) * (gw - xr * (m / filled))
                     dz_ref[0, at_rows, :] = jnp.where(
                         _lane_is(head, (chunk, dz_ref.shape[2])),
                         (m * da).astype(dz_ref.dtype), dz_ref[0, at_rows, :])
                 else:
-                    dx = r * (gw * a - xr * (_row_sum(u * a) / dim))
+                    dx = r * (gw * a - xr * (_row_sum(u * a) / filled))
                     dz_ref[0, at_rows, cols] = (u * da).astype(dz_ref.dtype)
                 do_ref[0, at_rows, cols] = dx.astype(do_ref.dtype)
                 dw = g * xr * a
@@ -274,19 +276,19 @@ def _specs(o, z, dim, start, rows):
     return tiled, whole, gate, per_head
 
 
-_STATIC = ("dim", "activation", "start", "eps", "rows", "chunk", "group",
-           "interpret")
+_STATIC = ("dim", "filled", "activation", "start", "eps", "rows", "chunk",
+           "group", "interpret")
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
-def _epilogue_fwd(o, w, z, dim, activation, start, eps, rows, chunk, group,
-                  interpret):
+def _epilogue_fwd(o, w, z, dim, filled, activation, start, eps, rows, chunk,
+                  group, interpret):
     from jax.experimental import pallas as pl
     bt, seq, width = o.shape
     tiled, whole, gate, per_head = _specs(o, z, dim, start, rows)
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, dim=dim, group=group, eps=eps,
-                          chunk=chunk, activation=activation,
+        functools.partial(_fwd_kernel, dim=dim, filled=filled, group=group,
+                          eps=eps, chunk=chunk, activation=activation,
                           per_head=per_head),
         grid=(bt, -(-seq // rows)),
         in_specs=[tiled, whole, gate],
@@ -305,16 +307,16 @@ def _epilogue_fwd(o, w, z, dim, activation, start, eps, rows, chunk, group,
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
-def _epilogue_bwd(o, w, z, dy, dim, activation, start, eps, rows, chunk,
-                  group, interpret):
+def _epilogue_bwd(o, w, z, dy, dim, filled, activation, start, eps, rows,
+                  chunk, group, interpret):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     bt, seq, width = o.shape
     tiled, whole, gate, per_head = _specs(o, z, dim, start, rows)
     dz = jax.ShapeDtypeStruct((bt, seq, gate.block_shape[2]), z.dtype)
     return pl.pallas_call(
-        functools.partial(_bwd_kernel, dim=dim, group=group, eps=eps,
-                          chunk=chunk, activation=activation,
+        functools.partial(_bwd_kernel, dim=dim, filled=filled, group=group,
+                          eps=eps, chunk=chunk, activation=activation,
                           per_head=per_head, seq=seq),
         grid=(bt, -(-seq // rows)),
         in_specs=[tiled, whole, gate, tiled],
@@ -335,7 +337,7 @@ def _epilogue_bwd(o, w, z, dy, dim, activation, start, eps, rows, chunk,
     )(o, w, z, dy)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=tuple(range(3, 11)))
+@functools.partial(jax.custom_vjp, nondiff_argnums=tuple(range(3, 12)))
 def _epilogue(o, w, z, *static):
     return _epilogue_fwd(o, w, z, *static)
 
@@ -348,7 +350,7 @@ def _vjp_bwd(*args):
     *static, (o, w, z), dy = args
     do, dz, dw = _epilogue_bwd(o, w, z, dy.astype(o.dtype), *static)
     if dz.shape != z.shape:     # z's columns of a wider array
-        start = static[2]
+        start = static[3]
         dz = jnp.pad(dz, ((0, 0), (0, 0),
                           (start, z.shape[2] - start - dz.shape[2])))
     return do, dw, dz
@@ -361,14 +363,22 @@ def delta_epilogue(o, scale, gate, head_dim: int, activation: str,
                    gate_start: int = 0, eps: float = 1e-6,
                    rows: Optional[int] = None, chunk: Optional[int] = None,
                    group: Optional[int] = None,
-                   interpret: Optional[bool] = None):
+                   interpret: Optional[bool] = None,
+                   filled: Optional[int] = None):
     """``y`` [B, S, H x head_dim] in ``o``'s type of the module
     docstring's chain: ``o`` [B, S, H x head_dim] normalised a head
     (RMS), times ``scale`` [head_dim] float32, times ``activation``
     (``"sigmoid"`` or ``"silu"``) of the gate. ``gate`` [B, S, H] is a
     head's scalar; any other is an element's, in the ``H x head_dim``
-    columns from ``gate_start`` (a multiple of that width) on.
-    ``rows``, ``chunk`` and ``group`` None take the ladder's
+    columns from ``gate_start`` (a multiple of that width) on. **A
+    head is whole lane tiles** (``head_dim`` a multiple of 128 where
+    the kernels are compiled; the interpreter takes any): heads of
+    another width come laid out, ``o``, ``scale`` and an element's gate
+    alike (``gated_delta.lay_heads``: a head of 192 as two runs of 96
+    channels behind 32 zeros each, 256 columns), and say in ``filled``
+    how many of a head's channels are no padding, the mean's divisor; a
+    zero channel under a zero scale stays zero, in ``y`` and on the way
+    back. ``rows``, ``chunk`` and ``group`` None take the ladder's
     (``_ROW_LADDER``); a length that is no multiple of ``rows`` ends in
     a tile whose rows past it are never written. Differentiable in
     ``o``, ``scale`` and ``gate``."""
@@ -385,11 +395,13 @@ def delta_epilogue(o, scale, gate, head_dim: int, activation: str,
             f"o{o.shape} scale{scale.shape} gate{gate.shape} from column "
             f"{gate_start} under {activation!r}, heads of {head_dim}: want "
             f"[B,S,HxD], [D], and [B,S,H] or [B,S,>=HxD] from a multiple "
-            f"of HxD on, under one of {sorted(_ACTIVATIONS)}")
+            f"of HxD on, under one of {sorted(_ACTIVATIONS)}; D in whole "
+            f"lane tiles on the chip (lay narrower heads out)")
     rows, chunk, group = _tile_for(o.shape[1], heads, rows, chunk, group)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     _note_call(rows, width, heads, per_head)
     return _epilogue(o, scale.astype(_F32).reshape(1, head_dim), gate,
-                     int(head_dim), activation, int(gate_start), float(eps),
+                     int(head_dim), int(filled or head_dim), activation,
+                     int(gate_start), float(eps),
                      rows, chunk, group, bool(interpret))

@@ -3,7 +3,9 @@
 (arXiv:2406.06484), as TPU kernels, forward and backward.
 
 A value head carries a float32 state ``S`` [Dk, Dv], zero at the start
-of a sequence (``g <= 0`` the log of the decay, ``beta`` in (0, 1))::
+of a sequence (``g <= 0`` the log of the decay, ``beta`` in (0, 1), or
+in (0, 2) where the state's transition ``I - beta k k^T`` may have a
+negative eigenvalue)::
 
     S   <- exp(g_t) S
     u_t  = beta_t (v_t - S^T k_t)
@@ -39,7 +41,26 @@ Kernel shape:
 - **the triangular inverse is all MXU**: ``A`` is strictly lower
   triangular, so ``A^C = 0`` and ``(I - A)^-1 = (I + A)(I + A^2)(I +
   A^4)...`` exactly, ``log2(C) - 1`` squarings and as many products, in
-  float32 at full precision (``_unit_lower_inverse``).
+  float32 at full precision (``_unit_lower_inverse``). **Exact is not
+  stable**: where the keys of a chunk resemble one another and the
+  decay is slow the powers grow like binomial coefficients and the
+  inverse is what is left of their cancellation; with ``beta`` under 1
+  and the keys a seeded model has the doublings hold (the cell
+  ``qwen3next-injit-1chip`` runs them), with ``beta`` up to 2 they do
+  not (a chunk of 128, keys of 96 at a mean cosine of 0.5, ``beta`` in
+  (1, 2), no decay: an error of 1e21 of the largest entry in float32
+  on the CPU, not finite at 0.8), so a call that says ``beta_max``
+  above 1 takes the inverse by forward substitution over blocks of
+  ``_SOLVE_BLOCK`` rows (``_unit_lower_inverse_by_blocks``), chosen
+  statically: the program of a call that does not is what it was.
+- **a head is whole lane tiles in the kernels**: a block is a head's
+  columns, so a head of 96 or 192 channels is laid out behind zeros up
+  to 128 or 256 (``lay_heads``; a zero key channel leaves every product
+  as it is and its row of the state at zero, a zero value channel is a
+  column of the state and of ``o`` that stays zero). The rule lays out
+  what it is handed, or takes operands that a layer laid out before
+  its prologue (``filled`` then says what is no padding). The gauge
+  ``hvd_gdn_layout`` has both widths.
 - ``gdn_fwd`` also writes the state that **entered** each chunk
   (``[B, Hv, chunks, Dk, Dv]`` float32: 64 KB a head and chunk, 537 MB
   a layer at the model's shape and a chunk of 64, 268 MB at 128; a
@@ -106,6 +127,37 @@ import jax.numpy as jnp
 # is rounded to the operands' type for ``W`` and ``U``).
 _CHUNK_LADDER = ((None, 128),)
 
+# The lanes of a vector register: a block of a head's columns is whole
+# multiples of it, so a head of another width is laid out behind zeros.
+LANES = 128
+
+# Rows a block of the inverse's forward substitution where ``beta``
+# passes 1 (``_unit_lower_inverse_by_blocks``). Measured at key heads
+# of 96 over value heads of 192 (PR 46; ``olmohybrid-injit-1chip``'s
+# shape: B1, S8192, 30 key heads each serving one value head, bfloat16
+# q, k, v, ``beta`` in (0.1, 1.9), one layer; v5e silicon, the host's
+# clock, ms; the error is of the inverse alone against float64, float32
+# on the CPU, a chunk of 128, keys of 96 at the mean cosine given,
+# ``beta`` in (1, 2), no decay):
+#   layout, chunk, inverse             fwd      fwd+bwd  error at cosine 0.5 / 0.8 / 0.95
+#   the rule lays out, 128, blocks 8    8.883   18.714   1e-6 / 5e-6 / 1e-5
+#   the rule lays out, 128, blocks 16   7.740   16.432   7e-5 / 3e-3 / 2e-2
+#   the rule lays out, 128, doublings   7.082   15.236   1e21 / nan / nan
+#   laid out already, 128, blocks 8     9.035   18.614
+#   the rule lays out, 64, blocks 8    10.641   22.909
+# (``lay_heads`` has what laying out costs.) Either way the
+# kernels hold a head as 128 and 256 lanes and run 1.78 times the
+# recurrence's products (``hvd_gdn_layout``; the benchmark's
+# ``gdn_layout_fill`` reads 56.25%): the MXU's tiles are 128 wide, so a
+# block whose last dimension is a whole head of 96 (``[B, H, S, D]``
+# operands: the compiler takes them, PR 46, compiled for a described
+# v5e and not run) would execute the same products behind a transpose
+# each way. Blocks of 8 cost 12% of the kernels (1.1% of the cell's
+# step) over blocks of 16 and are three orders closer where the keys
+# resemble one another; on seeded weights both read the same (the
+# check's gaps). The doublings are the fastest and are lost.
+_SOLVE_BLOCK = 8
+
 
 def _chunk_for(seq: int) -> int:
     """The ladder's chunk for a sequence of ``seq``, halved while the
@@ -126,6 +178,18 @@ def _note_chunks(seq: int, chunk: int) -> None:
         "the gated delta rule traced last: chunks a sequence and "
         "positions a chunk",
         {"chunks": -(-seq // chunk), "chunk_length": chunk})
+
+
+def _note_layout(dk: int, dv: int, laid_dk: int, laid_dv: int) -> None:
+    """``hvd_gdn_layout{kind=...}`` of the call being traced
+    (docs/metrics.md)."""
+    from horovod_tpu.common import basics
+    basics.note_traced(
+        "hvd_gdn_layout",
+        "the gated delta rule traced last: a key and a value head's "
+        "width as handed over and as the kernels hold them",
+        {"key_dim": dk, "value_dim": dv, "laid_key_dim": laid_dk,
+         "laid_value_dim": laid_dv})
 
 
 # -- inside a chunk ---------------------------------------------------------
@@ -164,6 +228,47 @@ def _unit_lower_inverse(a, order: Optional[int] = None):
     return t
 
 
+def _unit_lower_inverse_by_blocks(a, block: int):
+    """``(I - a)^-1`` of a strictly lower triangular ``a`` [C, C] by
+    forward substitution over blocks of ``block`` rows: with ``D`` the
+    diagonal blocks of ``a``, ``(I - a) = (I - D)(I - M)``, ``M = (I -
+    D)^-1 (a - D)``; ``(I - D)^-1`` by doublings (every block in one
+    product of full width: ``_unit_lower_inverse``), then
+    ``Y_i = E_i + M_i Y_<i`` a block of rows at a time and ``Y (I -
+    D)^-1``.
+
+    **Doublings over the whole chunk are not stable here.** The series
+    ``(I + a)(I + a^2)(I + a^4)...`` of an ``a`` whose entries share a
+    sign forms powers that grow like binomial coefficients (``a^32`` of
+    a 128 x 128 chunk reaches 1e14 and more where the keys of a chunk
+    resemble one another and the decay is slow) and an inverse of
+    order 1 out of their cancellation. Under one decay a head and
+    ``beta`` under 1 the decay cuts every power off on a seeded model's
+    keys (with ``beta`` up to 2 it does not: the module's docstring);
+    with a decay a channel the slow channels keep
+    ``M_ij`` near ``k_i . k_j`` across the chunk. On v5e silicon (PR
+    41) the second layer's rule at a chunk of 128 came out at 2e37
+    from position 8,832 on and not finite behind it, at 64 and 32
+    sound. Forward substitution is stable whatever ``a`` holds; inside
+    a block of 16 the powers stay below 1e4."""
+    size = a.shape[0]
+    if size <= block:
+        return _unit_lower_inverse(a)
+    rows = jax.lax.broadcasted_iota(jnp.int32, a.shape, 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, a.shape, 1)
+    shift = block.bit_length() - 1
+    diagonal = jnp.where((rows >> shift) == (cols >> shift), a, 0.0)
+    d_inv = _unit_lower_inverse(diagonal, order=block)
+    m = _mm_f32(d_inv, a - diagonal)
+    eye = jnp.where(rows == cols, 1.0, 0.0).astype(_F32)
+    done = [eye[:block]]
+    for at in range(block, size, block):
+        below = jnp.zeros((size - at, size), _F32)
+        done.append(eye[at:at + block] + _mm_f32(
+            m[at:at + block], jnp.concatenate(done + [below], axis=0)))
+    return _mm_f32(jnp.concatenate(done, axis=0), d_inv)
+
+
 def _masks(chunk: int):
     rows = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     cols = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
@@ -187,18 +292,20 @@ def _last(row):
                    keepdims=True)
 
 
-def _chunk(q, k, v, g_row, b_row, state, kk, qk, mm):
+def _chunk(q, k, v, g_row, b_row, state, kk, qk, mm, solve):
     """A value head's chunk from the state that entered it: everything
     the forward writes and the backward propagates through. q, k
     [C, Dk]; v [C, Dv]; g_row, b_row [1, C] float32 (``G`` and
     ``beta``); state [Dk, Dv] float32; kk, qk [C, C] the key head's raw
-    products."""
+    products; ``solve`` the rows a block of the inverse's forward
+    substitution, None for doublings over the whole chunk."""
     eye, lower, lower_eq = _masks(q.shape[0])
     g_col, b_col = _col(g_row, eye), _col(b_row, eye)
     decay = jnp.where(lower_eq, jnp.exp(jnp.minimum(g_col - g_row, 0.0)),
                       0.0)                              # exp(G_i - G_j)
     a = jnp.where(lower, -b_col * kk * decay, 0.0)
-    t = _unit_lower_inverse(a)
+    t = _unit_lower_inverse(a) if solve is None \
+        else _unit_lower_inverse_by_blocks(a, solve)
     e_col = jnp.exp(g_col)
     kb = (b_col * e_col) * k.astype(_F32)
     vb = b_col * v.astype(_F32)
@@ -214,7 +321,7 @@ def _chunk(q, k, v, g_row, b_row, state, kk, qk, mm):
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, sent_ref, s_scr,
-                *, rep: int, dv: int):
+                *, rep: int, dv: int, solve):
     from jax.experimental import pallas as pl
     c = pl.program_id(2)
 
@@ -231,7 +338,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, sent_ref, s_scr,
         state = s_scr[r]
         sent_ref[0, r, 0] = state
         x = _chunk(q, k, v, g_ref[0, r, pl.ds(c, 1), :],
-                   b_ref[0, r, pl.ds(c, 1), :], state, kk, qk, mm)
+                   b_ref[0, r, pl.ds(c, 1), :], state, kk, qk, mm, solve)
         out = _mm(q.astype(_F32) * x["e_col"], state, _NN, mm) \
             + _mm(x["p"], x["v_new"], _NN, mm)
         o_ref[0, :, r * dv:(r + 1) * dv] = out.astype(o_ref.dtype)
@@ -241,7 +348,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, sent_ref, s_scr,
 
 def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, sent_ref, do_ref,
                 dq_ref, dk_ref, dv_ref, dg_ref, db_ref, ds_scr,
-                *, rep: int, dv: int, n_chunks: int):
+                *, rep: int, dv: int, n_chunks: int, solve):
     from jax.experimental import pallas as pl
     step = pl.program_id(2)
     c = n_chunks - 1 - step
@@ -266,7 +373,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, sent_ref, do_ref,
         d_out = do_ref[0, :, r * dv:(r + 1) * dv]
         d_state = ds_scr[r]
         x = _chunk(q, k, v, g_ref[0, r, pl.ds(c, 1), :],
-                   b_ref[0, r, pl.ds(c, 1), :], state, kk, qk, mm)
+                   b_ref[0, r, pl.ds(c, 1), :], state, kk, qk, mm, solve)
         eye, t, decay = x["eye"], x["t"], x["decay"]
         b_col, e_col, e_last = x["b_col"], x["e_col"], x["e_last"]
         kd = kf * e_last
@@ -349,8 +456,9 @@ def chunk_flops(chunk: int, dk: int, dv: int, rep: int) -> int:
     return shared + rep * head
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "heads", "interpret"))
-def _gdn_fwd(q, k, v, g, beta, chunk: int, heads, interpret: bool):
+@functools.partial(jax.jit, static_argnames=("chunk", "heads", "solve",
+                                             "interpret"))
+def _gdn_fwd(q, k, v, g, beta, chunk: int, heads, solve, interpret: bool):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     hk, hv = heads
@@ -360,7 +468,7 @@ def _gdn_fwd(q, k, v, g, beta, chunk: int, heads, interpret: bool):
     n_chunks = padded // chunk
     s = _specs(chunk, dk, dv, rep, n_chunks, reverse=False)
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, rep=rep, dv=dv),
+        functools.partial(_fwd_kernel, rep=rep, dv=dv, solve=solve),
         grid=(bt, hk, n_chunks),
         in_specs=[s["qk"], s["qk"], s["v"], s["gates"], s["gates"]],
         out_specs=(s["v"], s["sent"]),
@@ -380,8 +488,9 @@ def _gdn_fwd(q, k, v, g, beta, chunk: int, heads, interpret: bool):
     )(q, k, v, g, beta)
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "heads", "interpret"))
-def _gdn_bwd(q, k, v, g, beta, sent, d_out, chunk: int, heads,
+@functools.partial(jax.jit, static_argnames=("chunk", "heads", "solve",
+                                             "interpret"))
+def _gdn_bwd(q, k, v, g, beta, sent, d_out, chunk: int, heads, solve,
              interpret: bool):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -392,7 +501,8 @@ def _gdn_bwd(q, k, v, g, beta, sent, d_out, chunk: int, heads,
     n_chunks = padded // chunk
     s = _specs(chunk, dk, dv, rep, n_chunks, reverse=True)
     return pl.pallas_call(
-        functools.partial(_bwd_kernel, rep=rep, dv=dv, n_chunks=n_chunks),
+        functools.partial(_bwd_kernel, rep=rep, dv=dv, n_chunks=n_chunks,
+                          solve=solve),
         grid=(bt, hk, n_chunks),
         in_specs=[s["qk"], s["qk"], s["v"], s["gates"], s["gates"],
                   s["sent"], s["v"]],
@@ -416,74 +526,141 @@ def _gdn_bwd(q, k, v, g, beta, sent, d_out, chunk: int, heads,
     )(q, k, v, g, beta, sent, d_out)
 
 
-def _laid_out(q, k, v, g, beta, chunk):
+def _laid(dim: int, lane: int = LANES) -> int:
+    """A head's width as the kernels hold it: the next multiple of
+    ``lane``."""
+    return -(-dim // lane) * lane
+
+
+def laid_columns(n: int, dim: int, lane: int = LANES) -> int:
+    """The width of ``n`` columns laid out in runs of ``dim``
+    (``lay_heads``)."""
+    return n // dim * _laid(dim, lane)
+
+
+def lay_heads(x, dim: int, lane: int = LANES):
+    """``x`` [..., n x ``dim``] with every run of ``dim`` columns behind
+    zeros up to the next multiple of ``lane``, [..., n x laid]; ``x``
+    itself where ``dim`` is whole lanes. How heads off the lane tile
+    travel between the delta rules' kernels (the prologue, the rule,
+    the epilogue): laid out once behind the projection and taken back
+    once before the output projection. A pad, which XLA runs as a
+    relayout at a quarter of the memory's pace (``qkvz``'s [8192,
+    17280] bfloat16 to 23,040 columns: 3.05 ms on v5e, 0.81 read and
+    written once; the slice back 2.92, ``y``'s [8192, 7680] to 5,760
+    0.70); **a product with a 0/1 matrix a block of four runs reads the
+    same** (3.04, 3.06 and 0.84: PR 46 wrote it, measured it and took
+    it out), so what is left is a kernel that reads the runs where they
+    lie (PERF.md section 7)."""
+    laid = _laid(dim, lane)
+    if laid == dim:
+        return x
+    heads = x.reshape(*x.shape[:-1], -1, dim)
+    heads = jnp.pad(heads, ((0, 0),) * (heads.ndim - 1) + ((0, laid - dim),))
+    return heads.reshape(*x.shape[:-1], -1)
+
+
+def take_heads(x, dim: int, lane: int = LANES):
+    """``lay_heads``'s way back: [..., n x ``dim``] of [..., n x laid]."""
+    laid = _laid(dim, lane)
+    if laid == dim:
+        return x
+    return x.reshape(*x.shape[:-1], -1, laid)[..., :dim].reshape(
+        *x.shape[:-1], -1)
+
+
+def _laid_out(q, k, v, g, beta, chunk, lane):
     """The kernels' operands from the module's: time padded to whole
     chunks (a padded step has beta 0 and g 0: the state passes through
-    it unchanged), heads folded into the columns, ``G`` (the running
-    sum of ``g`` inside each chunk) and ``beta`` as [B, Hv, chunks, C]
-    float32."""
-    bt, seq, hk, dk = q.shape
-    hv = v.shape[2]
+    it unchanged), a head's channels behind zeros up to a multiple of
+    ``lane`` (a zero key channel leaves every product as it is and its
+    row of the state at zero; a zero value channel is a column of the
+    state and of ``o`` that stays zero), heads folded into the columns,
+    ``G`` (the running sum of ``g`` inside each chunk) and ``beta`` as
+    [B, Hv, chunks, C] float32."""
+    bt, seq, hv = v.shape[:3]
     n_chunks = -(-seq // chunk)
-    pad = n_chunks * chunk - seq
-
-    def timed(x):
-        x = x.reshape(bt, seq, -1)
-        return jnp.pad(x, ((0, 0), (0, pad), (0, 0))) if pad else x
 
     def gate(x):
-        x = timed(x.astype(_F32)).transpose(0, 2, 1)
-        return x.reshape(bt, hv, n_chunks, chunk)
+        x = jnp.pad(x.astype(_F32), ((0, 0), (0, n_chunks * chunk - seq),
+                                     (0, 0)))
+        return x.transpose(0, 2, 1).reshape(bt, hv, n_chunks, chunk)
 
-    return (timed(q), timed(k), timed(v),
+    return (*(_laid_heads(x, chunk, lane) for x in (q, k, v)),
             jnp.cumsum(gate(g), axis=-1), gate(beta))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def _rule(q, k, v, g, beta, chunk, interpret):
-    return _rule_fwd(q, k, v, g, beta, chunk, interpret)[0]
+def _laid_heads(x, chunk, lane):
+    """[B, S', H x D'] of the module's [B, S, H, D]: whole chunks of
+    steps, whole multiples of ``lane`` channels a head, zeros behind
+    both."""
+    bt, seq = x.shape[:2]
+    x = lay_heads(x.reshape(bt, seq, -1), x.shape[3], lane)
+    return jnp.pad(x, ((0, 0), (0, -seq % chunk), (0, 0)))
 
 
-def _rule_fwd(q, k, v, g, beta, chunk, interpret):
-    seq = q.shape[1]
+def _taken_back(x, like, lane):
+    """The module's [B, S, H, D] of a kernel's [B, S', H x D']: the
+    padded steps and the zero channels cut off."""
+    return take_heads(x[:, :like.shape[1]], like.shape[3], lane).reshape(
+        like.shape)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _rule(q, k, v, g, beta, chunk, solve, lane, interpret):
+    return _rule_fwd(q, k, v, g, beta, chunk, solve, lane, interpret)[0]
+
+
+def _rule_fwd(q, k, v, g, beta, chunk, solve, lane, interpret):
     heads = (q.shape[2], v.shape[2])
-    out, sent = _gdn_fwd(*_laid_out(q, k, v, g, beta, chunk), chunk=chunk,
-                         heads=heads, interpret=interpret)
-    return out[:, :seq].reshape(v.shape), (q, k, v, g, beta, sent)
+    out, sent = _gdn_fwd(*_laid_out(q, k, v, g, beta, chunk, lane),
+                         chunk=chunk, heads=heads, solve=solve,
+                         interpret=interpret)
+    return _taken_back(out, v, lane), (q, k, v, g, beta, sent)
 
 
-def _rule_bwd(chunk, interpret, res, d_out):
+def _rule_bwd(chunk, solve, lane, interpret, res, d_out):
     q, k, v, g, beta, sent = res
     bt, seq, hv = v.shape[:3]
-    ops = _laid_out(q, k, v, g, beta, chunk)
+    ops = _laid_out(q, k, v, g, beta, chunk, lane)
     padded = ops[0].shape[1]
-    d_out = jnp.pad(d_out.astype(v.dtype).reshape(bt, seq, -1),
-                    ((0, 0), (0, padded - seq), (0, 0)))
+    d_out = _laid_heads(d_out.astype(v.dtype), chunk, lane)
     dq, dk, dv, d_gsum, d_beta = _gdn_bwd(
-        *ops, sent, d_out, chunk=chunk, heads=(q.shape[2], hv),
+        *ops, sent, d_out, chunk=chunk, heads=(q.shape[2], hv), solve=solve,
         interpret=interpret)
     # G is the running sum of g inside a chunk: g_j reaches every G_i
     # with i >= j
     d_g = jnp.flip(jnp.cumsum(jnp.flip(d_gsum, -1), axis=-1), -1)
     gate = lambda x, like: x.reshape(bt, hv, padded).transpose(0, 2, 1)[
         :, :seq].astype(like.dtype)
-    return (dq[:, :seq].reshape(q.shape), dk[:, :seq].reshape(k.shape),
-            dv[:, :seq].reshape(v.shape), gate(d_g, g), gate(d_beta, beta))
+    return (*(_taken_back(d, like, lane)
+              for d, like in ((dq, q), (dk, k), (dv, v))),
+            gate(d_g, g), gate(d_beta, beta))
 
 
 _rule.defvjp(_rule_fwd, _rule_bwd)
 
 
 def gated_delta_rule(q, k, v, g, beta, chunk: Optional[int] = None,
-                     interpret: Optional[bool] = None):
+                     interpret: Optional[bool] = None,
+                     beta_max: float = 1.0, lane: Optional[int] = None,
+                     filled: Optional[tuple] = None):
     """``o`` [B, S, Hv, Dv] in ``v.dtype`` of the recurrence in the
     module docstring. q, k: [B, S, Hk, Dk] (already normalised and
     scaled: the rule takes them as they come); v: [B, S, Hv, Dv] with
     ``Hv`` a multiple of ``Hk`` (value head ``h`` reads key head ``h //
-    (Hv / Hk)``); g, beta: [B, S, Hv], ``g <= 0``. ``chunk`` None takes
-    the ladder's (``_CHUNK_LADDER``), a power of two; a length that is
-    no multiple of it is padded with steps that leave the state as it
-    is. Differentiable in all five operands."""
+    (Hv / Hk)``); g, beta: [B, S, Hv], ``g <= 0``, ``beta`` in (0,
+    ``beta_max``). ``chunk`` None takes the ladder's
+    (``_CHUNK_LADDER``), a power of two; a length that is no multiple of
+    it is padded with steps that leave the state as it is. ``beta_max``
+    above 1 (a state transition with negative eigenvalues) takes the
+    chunk's inverse by blocks of ``_SOLVE_BLOCK`` rows, 1 and under by
+    doublings. ``lane`` None lays a head out to a multiple of
+    ``LANES`` where the kernels are compiled and as it comes under the
+    interpreter, which takes any block; operands that come laid out
+    already (``lay_heads``) say in ``filled`` how many of a key and of a
+    value head's channels are no padding, for the gauge
+    ``hvd_gdn_layout``. Differentiable in all five operands."""
     if k.shape != q.shape or v.shape[:2] != q.shape[:2] \
             or v.shape[2] % q.shape[2] or g.shape != v.shape[:3] \
             or beta.shape != g.shape:
@@ -495,8 +672,12 @@ def gated_delta_rule(q, k, v, g, beta, chunk: Optional[int] = None,
         raise ValueError(f"chunk {chunk} is no power of two")
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    lane = (1 if interpret else LANES) if lane is None else int(lane)
+    solve = min(chunk, _SOLVE_BLOCK) if beta_max > 1.0 else None
     _note_chunks(q.shape[1], chunk)
-    return _rule(q, k, v, g, beta, chunk, bool(interpret))
+    _note_layout(*(filled or (q.shape[3], v.shape[3])),
+                 _laid(q.shape[3], lane), _laid(v.shape[3], lane))
+    return _rule(q, k, v, g, beta, chunk, solve, lane, bool(interpret))
 
 
 def gated_delta_rule_reference(q, k, v, g, beta):

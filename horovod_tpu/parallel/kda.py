@@ -93,7 +93,7 @@ import jax.numpy as jnp
 
 from horovod_tpu.parallel.gated_delta import (
     _F32, _NN, _NT, _TN, _col, _compiler_params, _masks, _mm, _mm_f32, _row,
-    _unit_lower_inverse,
+    _unit_lower_inverse_by_blocks,
 )
 
 # Chunk and sub-block length by sequence length, as (longest sequence,
@@ -192,45 +192,6 @@ def _levels(g_sum, sub: int):
         levels.append((decay, decay, mask, False))
         group *= 2
     return levels
-
-
-def _unit_lower_inverse_by_blocks(a, block: int):
-    """``(I - a)^-1`` of a strictly lower triangular ``a`` [C, C] by
-    forward substitution over blocks of ``block`` rows: with ``D`` the
-    diagonal blocks of ``a``, ``(I - a) = (I - D)(I - M)``, ``M = (I -
-    D)^-1 (a - D)``; ``(I - D)^-1`` by doublings (every block in one
-    product of full width: ``gated_delta._unit_lower_inverse``), then
-    ``Y_i = E_i + M_i Y_<i`` a block of rows at a time and ``Y (I -
-    D)^-1``.
-
-    **Doublings over the whole chunk are not stable here.** The series
-    ``(I + a)(I + a^2)(I + a^4)...`` of an ``a`` whose entries share a
-    sign forms powers that grow like binomial coefficients (``a^32`` of
-    a 128 x 128 chunk reaches 1e14 and more where the keys of a chunk
-    resemble one another and the decay is slow) and an inverse of
-    order 1 out of their cancellation. Under one decay a head the decay
-    cuts every power off; with a decay a channel the slow channels keep
-    ``M_ij`` near ``k_i . k_j`` across the chunk. On v5e silicon (PR
-    41) the second layer's rule at a chunk of 128 came out at 2e37
-    from position 8,832 on and not finite behind it, at 64 and 32
-    sound. Forward substitution is stable whatever ``a`` holds; inside
-    a block of 16 the powers stay below 1e4."""
-    size = a.shape[0]
-    if size <= block:
-        return _unit_lower_inverse(a)
-    rows = jax.lax.broadcasted_iota(jnp.int32, a.shape, 0)
-    cols = jax.lax.broadcasted_iota(jnp.int32, a.shape, 1)
-    shift = block.bit_length() - 1
-    diagonal = jnp.where((rows >> shift) == (cols >> shift), a, 0.0)
-    d_inv = _unit_lower_inverse(diagonal, order=block)
-    m = _mm_f32(d_inv, a - diagonal)
-    eye = jnp.where(rows == cols, 1.0, 0.0).astype(_F32)
-    done = [eye[:block]]
-    for at in range(block, size, block):
-        below = jnp.zeros((size - at, size), _F32)
-        done.append(eye[at:at + block] + _mm_f32(
-            m[at:at + block], jnp.concatenate(done + [below], axis=0)))
-    return _mm_f32(jnp.concatenate(done, axis=0), d_inv)
 
 
 def _level_mm(x, y, dims, exact: bool, mm):

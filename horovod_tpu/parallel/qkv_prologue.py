@@ -23,7 +23,9 @@ Kernel shape: grid ``(batch, tile of rows)``, the tiles of a sequence
 innermost; a step holds a tile's rows of **every** column (a block of
 ``x``'s leading ``C`` columns: ``x`` may be wider, as the Gated
 DeltaNet's ``qkvz`` is, and no slice is materialised) and walks the
-heads, a head's 128 columns at a time: the head's rows go to a float32
+heads, a head's columns at a time (whole lane tiles: 128 in both
+models that brought the kernels; heads off the tile come laid out, as
+``qkv_prologue`` says): the head's rows go to a float32
 staging buffer behind the ``taps - 1`` rows before the tile, the taps
 read it at ``taps`` offsets, and SiLU, the norm, the scale and the cast
 run on row chunks that stay in registers. A pass of the loop takes a
@@ -157,9 +159,9 @@ def _note_call(rows: int, columns: int, normalised: int, taps: int) -> None:
          "normalised_heads": normalised, "taps": taps})
 
 
-def _segments(heads: int, normalised: int, scaled: int, dim: int):
+def _segments(heads: int, normalised: int, scaled: int, key_dim: int):
     """q's, k's and v's (first head, heads, normalised, scale)."""
-    return ((0, scaled, True, dim ** -0.5),
+    return ((0, scaled, True, key_dim ** -0.5),
             (scaled, normalised - scaled, True, 1.0),
             (normalised, heads - normalised, False, 1.0))
 
@@ -321,18 +323,18 @@ def _out_shapes(x, segments, dim):
                  for s in segments)
 
 
-_STATIC = ("dim", "normalised", "scaled", "eps", "rows", "chunk", "group",
-           "interpret")
+_STATIC = ("dim", "key_dim", "normalised", "scaled", "eps", "rows", "chunk",
+           "group", "interpret")
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
-def _prologue_fwd(x, w, dim, normalised, scaled, eps, rows, chunk, group,
-                  interpret):
+def _prologue_fwd(x, w, dim, key_dim, normalised, scaled, eps, rows, chunk,
+                  group, interpret):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     bt, seq = x.shape[:2]
     taps, width = w.shape
-    segments = _segments(width // dim, normalised, scaled, dim)
+    segments = _segments(width // dim, normalised, scaled, key_dim)
     outs = _out_shapes(x, segments, dim)
     tiled = lambda cols: pl.BlockSpec((1, rows, cols), lambda b, t: (b, t, 0))
     return pl.pallas_call(
@@ -355,14 +357,14 @@ def _prologue_fwd(x, w, dim, normalised, scaled, eps, rows, chunk, group,
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
-def _prologue_bwd(x, w, dq, dk, dv, dim, normalised, scaled, eps, rows, chunk,
-                  group, interpret):
+def _prologue_bwd(x, w, dq, dk, dv, dim, key_dim, normalised, scaled, eps,
+                  rows, chunk, group, interpret):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     bt, seq = x.shape[:2]
     taps, width = w.shape
     n_tiles = -(-seq // rows)
-    segments = _segments(width // dim, normalised, scaled, dim)
+    segments = _segments(width // dim, normalised, scaled, key_dim)
     at = lambda t: n_tiles - 1 - t
     tiled = lambda cols: pl.BlockSpec(
         (1, rows, cols), lambda b, t: (b, at(t), 0))
@@ -395,7 +397,7 @@ def _prologue_bwd(x, w, dq, dk, dv, dim, normalised, scaled, eps, rows, chunk,
     )(x, x, w, dq, dk, dv)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=tuple(range(2, 10)))
+@functools.partial(jax.custom_vjp, nondiff_argnums=tuple(range(2, 11)))
 def _prologue(x, w, *static):
     return _prologue_fwd(x, w, *static)
 
@@ -418,17 +420,25 @@ def qkv_prologue(x, kernel, head_dim: int, normalised_heads: int,
                  scaled_heads: int, eps: float = 1e-6,
                  rows: Optional[int] = None, chunk: Optional[int] = None,
                  group: Optional[int] = None,
-                 interpret: Optional[bool] = None):
+                 interpret: Optional[bool] = None,
+                 key_dim: Optional[int] = None):
     """``(q, k, v)``, each [B, S, heads x head_dim] in ``x``'s type, of
     the module docstring's chain over the leading ``C`` columns of ``x``
     [B, S, >= C], ``kernel`` [taps, C] float32 the causal depthwise
     convolution's. The columns are heads of ``head_dim``: the first
     ``scaled_heads`` are q's (L2-normalised and scaled by ``head_dim **
     -0.5``), up to ``normalised_heads`` k's (L2-normalised), the rest
-    v's. ``rows``, ``chunk`` and ``group`` None take the ladder's
-    (``_ROW_LADDER``); a length that is no multiple of ``rows`` ends in
-    a tile whose rows past it are never written. Differentiable in
-    ``x`` and ``kernel``."""
+    v's. **A head is whole lane tiles** (``head_dim`` a multiple of 128
+    where the kernels are compiled; the interpreter takes any): heads
+    of another width come laid out, ``x``'s columns and the kernel's
+    both (``gated_delta.lay_heads``: 96 channels behind 32 zeros, a
+    value head of 192 as two such runs), and say in ``key_dim`` how
+    many of a key head's channels are no padding, whose ``-0.5`` power
+    q's scale is; a zero channel under zero taps stays zero, in the
+    sums and on the way back. ``rows``, ``chunk`` and ``group`` None
+    take the ladder's (``_ROW_LADDER``); a length that is no multiple
+    of ``rows`` ends in a tile whose rows past it are never written.
+    Differentiable in ``x`` and ``kernel``."""
     taps, width = kernel.shape
     if x.ndim != 3 or x.shape[2] < width or width % head_dim \
             or not 0 < taps <= _LEAD + 1 \
@@ -436,14 +446,16 @@ def qkv_prologue(x, kernel, head_dim: int, normalised_heads: int,
         raise ValueError(
             f"x{x.shape} kernel{kernel.shape} heads of {head_dim}, "
             f"{normalised_heads} normalised, {scaled_heads} scaled: want "
-            f"[B,S,>=C], [taps<={_LEAD + 1},C], C in whole heads, scaled "
-            f"<= normalised <= heads")
+            f"[B,S,>=C], [taps<={_LEAD + 1},C], C in whole heads (of whole "
+            f"lane tiles on the chip: lay narrower ones out), scaled <= "
+            f"normalised <= heads")
+    key_dim = int(head_dim if key_dim is None else key_dim)
     rows, chunk, group = _tile_for(
         x.shape[1], _segments(width // head_dim, normalised_heads,
-                              scaled_heads, head_dim), rows, chunk, group)
+                              scaled_heads, key_dim), rows, chunk, group)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     _note_call(rows, width, normalised_heads, taps)
-    return _prologue(x, kernel.astype(_F32), int(head_dim),
+    return _prologue(x, kernel.astype(_F32), int(head_dim), key_dim,
                      int(normalised_heads), int(scaled_heads), float(eps),
                      rows, chunk, group, bool(interpret))

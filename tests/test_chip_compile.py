@@ -203,6 +203,41 @@ def _lower_gated_delta_rule(chip, mesh4):
             _arr(chip, bt, seq, value_heads, d), gate, gate)
 
 
+def _lower_delta_layer_off_the_lane_tile(chip, mesh4):
+    """Olmo-Hybrid's linear layer between its projections at the cell's
+    shape (one row of 8,192, 30 key heads of 96 over 30 value heads of
+    192): ``qkvz`` laid out 96 columns in 128 lanes, the prologue, the
+    rule handed its heads as they come (it lays them out itself) with
+    ``beta`` to 2, the epilogue, ``y`` taken back."""
+    from horovod_tpu.parallel import delta_epilogue as de
+    from horovod_tpu.parallel import gated_delta as gd
+    from horovod_tpu.parallel import qkv_prologue as qp
+    bt, seq, heads, dk, dv = 1, 8192, 30, 96, 192
+    keys, values = heads * dk, heads * dv
+
+    def layer(qkvz, taps, scale, g, beta):
+        laid = gd.lay_heads(qkvz, dk)
+        q, k, v = (gd.take_heads(t, dk) for t in qp.qkv_prologue(
+            laid, gd.lay_heads(taps, dk), gd.laid_columns(dk, dk),
+            2 * heads, heads, key_dim=dk, interpret=False))
+        o = gd.gated_delta_rule(
+            q.reshape(bt, seq, heads, dk), k.reshape(bt, seq, heads, dk),
+            v.reshape(bt, seq, heads, dv), g, beta, beta_max=2.0,
+            interpret=False)
+        y = de.delta_epilogue(
+            gd.lay_heads(o.reshape(bt, seq, values), dk),
+            gd.lay_heads(scale, dk), laid, gd.laid_columns(dv, dk), "silu",
+            gd.laid_columns(2 * keys + values, dk), filled=dv,
+            interpret=False)
+        return gd.take_heads(y, dk)
+
+    gate = _arr(chip, bt, seq, heads, dt=jnp.float32)
+    return _grads_of_the_sum(layer, 5, value=True).lower(
+        _arr(chip, bt, seq, 2 * keys + 2 * values),
+        _arr(chip, 4, 2 * keys + values, dt=jnp.float32),
+        _arr(chip, dv, dt=jnp.float32), gate, gate)
+
+
 def _lower_kimi_delta_attention(chip, mesh4):
     from horovod_tpu.parallel import kda
     bt, seq, heads, d = 1, 16384, 32, 128
@@ -322,6 +357,19 @@ def test_the_gated_delta_rule_compiles_for_v5e(compiled):
     chunk: two value heads a grid step, the triangular inverse's
     float32 products, a head's whole table of gates resident."""
     _are_the_kernels_of(compiled("gated_delta_rule"), "gdn_fwd", "gdn_bwd")
+
+
+def test_the_delta_rule_compiles_at_heads_of_96_over_192(compiled):
+    """``olmohybrid-injit-1chip``'s linear layer between its
+    projections: the prologue's, the rule's and the epilogue's two
+    kernels each, under the names the benchmark's readers match, at
+    heads that are no whole lane tiles (96 columns laid out in 128
+    lanes, a value head of 192 in 256) and with the chunk's inverse by
+    blocks (``beta`` to 2)."""
+    _are_the_kernels_of(
+        compiled("delta_layer_off_the_lane_tile"),
+        "qkv_prologue_fwd", "qkv_prologue_bwd", "gdn_fwd", "gdn_bwd",
+        "delta_epilogue_fwd", "delta_epilogue_bwd")
 
 
 def test_kimi_delta_attention_compiles_for_v5e(compiled):
@@ -580,6 +628,7 @@ LOWERINGS = {
     "flash": _lower_flash, "phi4flash": _lower_phi4flash,
     "selective_scan": _lower_selective_scan,
     "gated_delta_rule": _lower_gated_delta_rule,
+    "delta_layer_off_the_lane_tile": _lower_delta_layer_off_the_lane_tile,
     "kimi_delta_attention": _lower_kimi_delta_attention,
     "prologue": _lower_prologue, "epilogue": _lower_epilogue,
     "latent_flash": _lower_latent_flash, "lm_step": _lower_lm_step,
@@ -596,7 +645,8 @@ PROGRAMS = list(dict.fromkeys([
       for which in ("fwd", "bwd")),
     *(("phi4flash", which, window)
       for window in (512, None) for which in ("fwd", "bwd")),
-    ("selective_scan",), ("gated_delta_rule",), ("kimi_delta_attention",),
+    ("selective_scan",), ("gated_delta_rule",),
+    ("delta_layer_off_the_lane_tile",), ("kimi_delta_attention",),
     *(("prologue", *case[1:]) for case in _PROLOGUE_SHAPES),
     *(("epilogue", *case[1:]) for case in _EPILOGUE_SHAPES),
     ("latent_flash",), ("lm_step", 2, True), ("lm_step", 1, False),

@@ -27,6 +27,7 @@ from chipbench import harness, weights
 
 from horovod_tpu.models import glm_moe
 from horovod_tpu.parallel import delta_epilogue as de
+from horovod_tpu.parallel.gated_delta import lay_heads, take_heads
 
 pytestmark = [pytest.mark.fast, pytest.mark.time_limit(120)]
 
@@ -105,6 +106,62 @@ CASES = [
     ("sigmoid_of_an_elements_gate", 1, 48, 16, 16, 4, 16, False, "sigmoid",
      0, 0, jnp.float32, True),
 ]
+
+
+# (case, batch, sequence, rows a tile, heads, a head's width (two runs
+#  of half of it), the lanes a run is laid out to, columns of the gate's
+#  array before z's in runs, type)
+LAID_OUT = [
+    ("a_head_of_24_as_two_runs_of_12", 2, 40, 16, 4, 24, 16, 0,
+     jnp.float32),
+    ("in_a_wider_array_in_bfloat16_the_models_rows", 1, 48, 32, 2, 48, 128,
+     8, jnp.bfloat16),
+]
+
+
+@pytest.mark.parametrize("case", LAID_OUT, ids=[c[0] for c in LAID_OUT])
+def test_heads_off_the_lane_tile_come_and_leave_laid_out(case):
+    """Olmo-Hybrid's value head, two runs of a width that is no whole
+    lanes: ``o``, the scale and an element's gate laid out a run at a
+    time, ``filled`` the mean's divisor. Taken back, ``y``, ``do``,
+    ``dz`` and ``dscale`` are the chain's on the columns as they came;
+    the lanes between stay zero."""
+    _, batch, seq, rows, heads, dim, lane, before, dtype = case
+    run = dim // 2
+    start = before * heads * dim        # whole widths of z before it
+    o, scale, gate, cot = operands(13, batch, seq, heads, dim, False, start,
+                                   0, dtype, hard=True)
+    lay = lambda a: lay_heads(a, run, lane)
+    take = lambda a: take_heads(a, run, lane)
+    laid = 2 * (-(-run // lane) * lane)
+
+    def laid_out(o, w, z):
+        y = de.delta_epilogue(lay(o), lay(w), lay(z), laid, "silu",
+                              lay(z[..., :start]).shape[2], EPS, rows=rows,
+                              interpret=True, filled=dim)
+        return y, take(y)
+
+    (got_laid, got), (got_do, got_dw, got_dz) = out_and_vjp(
+        laid_out, (jnp.zeros_like(lay(cot)), cot), o, scale, gate)
+    want, (want_do, want_dw, want_dz) = out_and_vjp(
+        lambda o, w, z: the_chain_it_replaced(o, w, z, dim, "silu", start),
+        cot, o, scale, gate)
+    f32 = lambda a: np.asarray(a.astype(jnp.float32))
+    ulp = 2e-5 if dtype == jnp.float32 else 2 ** -7
+    close = lambda g, w, what, rtol=ulp, floor=1e-6: \
+        np.testing.assert_allclose(
+            f32(g), f32(w), rtol=rtol,
+            atol=floor * float(np.abs(f32(w)).max()), err_msg=what)
+    assert got.shape == o.shape and got.dtype == dtype
+    close(got, want, "y")
+    assert not np.any(f32(got_laid).reshape(
+        batch, seq, -1, laid // 2)[..., run:])
+    close(got_do, want_do, "do", floor=1e-5 if dtype == jnp.float32 else ulp)
+    assert got_dz.shape == gate.shape
+    close(got_dz, want_dz, "dz",
+          floor=1e-5 if dtype == jnp.float32 else ulp)
+    assert got_dw.shape == (dim,)
+    close(got_dw, want_dw, "dscale", rtol=2e-4, floor=1e-5)
 
 
 @pytest.mark.parametrize("case", CASES, ids=[c[0] for c in CASES])

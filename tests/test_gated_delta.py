@@ -2,9 +2,10 @@
 (``horovod_tpu/parallel/gated_delta.py``) in interpreter mode against
 the literal recurrence: forward and every gradient (q, k, v, g, beta),
 at lengths that are and are not a multiple of the chunk, with a key
-head serving one value head and two; and the chunked equations the
-kernels compute, in plain ``jax.numpy``, against the same
-recurrence."""
+head serving one value head and two, at heads off the lane tile in the
+ratio 1 : 2 laid out behind zeros, with ``beta`` in (0, 2) and the
+chunk's inverse by blocks; and the chunked equations the kernels
+compute, in plain ``jax.numpy``, against the same recurrence."""
 
 import jax
 import jax.numpy as jnp
@@ -19,9 +20,9 @@ pytestmark = [pytest.mark.fast, pytest.mark.time_limit(120)]
 
 
 def operands(seed, batch, seq, key_heads, value_heads, dk, dv,
-             dtype=jnp.float32):
+             dtype=jnp.float32, beta_max=1.0):
     """q, k as the layer hands them over (L2-normalised, q scaled), v,
-    a decay's logarithm around -0.1 and beta in (0, 1)."""
+    a decay's logarithm around -0.1 and beta in (0, ``beta_max``)."""
     ks = jax.random.split(jax.random.key(seed), 5)
     unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
     q = unit(jax.random.normal(ks[0], (batch, seq, key_heads, dk))) \
@@ -29,7 +30,7 @@ def operands(seed, batch, seq, key_heads, value_heads, dk, dv,
     k = unit(jax.random.normal(ks[1], (batch, seq, key_heads, dk)))
     v = jax.random.normal(ks[2], (batch, seq, value_heads, dv))
     g = -0.1 * jnp.exp(jax.random.normal(ks[3], (batch, seq, value_heads)))
-    beta = jax.nn.sigmoid(
+    beta = beta_max * jax.nn.sigmoid(
         jax.random.normal(ks[4], (batch, seq, value_heads)))
     return q.astype(dtype), k.astype(dtype), v.astype(dtype), g, beta
 
@@ -76,20 +77,26 @@ def test_the_chunked_equations_are_the_recurrence():
             rtol=2e-5, atol=2e-6)
 
 
-# (case, sequence, chunk, key heads, value heads, Dk, Dv)
-CASES = [("whole_chunks", 32, 16, 2, 4, 16, 8),
-         ("a_ragged_tail", 40, 16, 2, 4, 16, 8),
-         ("one_value_head_a_key_head", 24, 8, 2, 2, 8, 16),
-         ("shorter_than_a_chunk", 11, 16, 1, 2, 8, 8)]
+# (case, sequence, chunk, key heads, value heads, Dk, Dv, beta's upper
+#  end, the lanes a head is laid out to: None as it comes)
+CASES = [("whole_chunks", 32, 16, 2, 4, 16, 8, 1.0, None),
+         ("a_ragged_tail", 40, 16, 2, 4, 16, 8, 1.0, None),
+         ("one_value_head_a_key_head", 24, 8, 2, 2, 8, 16, 1.0, None),
+         ("shorter_than_a_chunk", 11, 16, 1, 2, 8, 8, 1.0, None),
+         # Olmo-Hybrid's ratio: a key head of 12 over one value head of
+         # 24, laid out to 16 and 32 lanes, beta up to 2
+         ("heads_off_the_lane_tile_beta_to_two", 40, 16, 2, 2, 12, 24, 2.0,
+          16)]
 
 
 @pytest.mark.parametrize("case", CASES, ids=[c[0] for c in CASES])
 def test_the_kernels_are_the_recurrence_forward_and_backward(case):
-    _, seq, chunk, hk, hv, dk, dv = case
-    args = operands(2, 2, seq, hk, hv, dk, dv)
+    _, seq, chunk, hk, hv, dk, dv, beta_max, lane = case
+    args = operands(2, 2, seq, hk, hv, dk, dv, beta_max=beta_max)
     weight = jax.random.normal(jax.random.key(9), (2, seq, hv, dv))
     got, got_grads = out_and_vjp(
-        lambda *a: gd.gated_delta_rule(*a, chunk=chunk, interpret=True),
+        lambda *a: gd.gated_delta_rule(*a, chunk=chunk, interpret=True,
+                                       beta_max=beta_max, lane=lane),
         weight, *args)
     want, want_grads = out_and_vjp(gd.gated_delta_rule_reference, weight,
                                    *args)
@@ -120,6 +127,85 @@ def test_the_unit_lower_inverse_is_exact_for_a_nilpotent_matrix():
         jax.jit(gd._unit_lower_inverse)(a),
         np.linalg.inv(np.eye(32) - np.asarray(a, np.float64)),
         rtol=2e-5, atol=2e-5)
+
+
+def resembling_keys(seed, chunk, dim, cosine):
+    """``chunk`` unit keys whose mean cosine to one another is
+    ``cosine``."""
+    kb, kn = jax.random.split(jax.random.key(seed))
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+    return unit(cosine ** 0.5 * unit(jax.random.normal(kb, (dim,)))
+                + (1 - cosine) ** 0.5 * unit(
+                    jax.random.normal(kn, (chunk, dim))))
+
+
+@pytest.mark.parametrize("cosine", [0.5, 0.95])
+def test_the_inverse_by_blocks_holds_where_doublings_lose_it(cosine):
+    """The chunk the kernels run at 8,192 positions (128), keys of 96
+    that resemble one another, ``beta`` in (1, 2), a decay near zero:
+    the entries of ``A`` share a sign and reach 2, the doublings'
+    powers grow past float32 and the inverse is lost in their
+    cancellation; forward substitution over blocks of ``_SOLVE_BLOCK``
+    rows stays within 1e-4 of the float64 inverse."""
+    chunk = gd._CHUNK_LADDER[-1][1]
+    k = resembling_keys(6, chunk, 96, cosine)
+    beta = jnp.linspace(1.0, 2.0, chunk)[:, None]
+    run = jnp.cumsum(jnp.full((chunk,), -0.01))
+    a = jnp.tril(-beta * (k @ k.T) * jnp.exp(run[:, None] - run[None]), -1)
+    exact = np.linalg.inv(np.eye(chunk) - np.asarray(a, np.float64))
+    by_blocks = jax.jit(lambda a: gd._unit_lower_inverse_by_blocks(
+        a, gd._SOLVE_BLOCK))(a)
+    np.testing.assert_allclose(by_blocks, exact, rtol=0,
+                               atol=1e-4 * np.abs(exact).max())
+    doubled = np.asarray(jax.jit(gd._unit_lower_inverse)(a))
+    assert not np.allclose(doubled, exact, rtol=0,
+                           atol=0.1 * np.abs(exact).max())
+
+
+def test_beta_above_one_takes_the_inverse_by_blocks_on_resembling_keys():
+    """The rule itself on such keys over four chunks of 32: within 1e-4
+    of the recurrence with ``beta_max`` 2 (blocks)."""
+    q, _, v, g, beta = operands(7, 1, 128, 1, 1, 96, 16, beta_max=2.0)
+    k = resembling_keys(8, 128, 96, 0.8)[None, :, None]
+    args = (q, k, v, 0.1 * g, beta)
+    want = jax.jit(gd.gated_delta_rule_reference)(*args)
+    got = jax.jit(lambda *a: gd.gated_delta_rule(
+        *a, chunk=32, interpret=True, beta_max=2.0))(*args)
+    np.testing.assert_allclose(got, want, rtol=0,
+                               atol=1e-4 * float(jnp.abs(want).max()))
+
+
+def test_heads_are_laid_out_behind_zeros_and_taken_back():
+    x = jax.random.normal(jax.random.key(3), (2, 5, 4 * 12))
+    laid = gd.lay_heads(x, 12, lane=16)
+    assert laid.shape == (2, 5, 4 * 16) == (2, 5, gd.laid_columns(48, 12, 16))
+    np.testing.assert_array_equal(
+        laid.reshape(2, 5, 4, 16)[..., :12], x.reshape(2, 5, 4, 12))
+    assert not np.any(laid.reshape(2, 5, 4, 16)[..., 12:])
+    np.testing.assert_array_equal(gd.take_heads(laid, 12, lane=16), x)
+    assert gd.lay_heads(x, 16, lane=16) is x        # whole lanes: as it is
+    assert gd.take_heads(x, 16, lane=16) is x
+    assert gd.laid_columns(2880, 96) == 3840 and gd.laid_columns(256, 128) \
+        == 256
+
+
+def test_the_traced_call_leaves_its_layout_in_the_gauge(monkeypatch):
+    from horovod_tpu.common import basics
+    noted = {}
+    monkeypatch.setattr(basics, "note_traced", lambda name, what, kinds:
+                        noted.update({name: kinds}))
+    args = operands(5, 1, 16, 2, 2, 12, 24)
+    gd.gated_delta_rule(*args, chunk=8, lane=16)
+    assert noted["hvd_gdn_layout"] == {
+        "key_dim": 12, "value_dim": 24, "laid_key_dim": 16,
+        "laid_value_dim": 32}
+    # operands that come laid out already say what is no padding
+    laid = [gd.lay_heads(x, 12, lane=16) for x in args[:3]]
+    gd.gated_delta_rule(*laid, *args[3:], chunk=8, filled=(12, 24))
+    assert noted["hvd_gdn_layout"] == {
+        "key_dim": 12, "value_dim": 24, "laid_key_dim": 16,
+        "laid_value_dim": 32}
+    assert noted["hvd_gdn_chunks"] == {"chunks": 2, "chunk_length": 8}
 
 
 def test_the_ladder_gives_a_power_of_two_that_holds_a_short_sequence():

@@ -31,6 +31,7 @@ from chipbench import harness, weights
 
 from horovod_tpu.models.phi4flash import CausalDepthwiseConv
 from horovod_tpu.parallel import qkv_prologue as qp
+from horovod_tpu.parallel.gated_delta import lay_heads, take_heads
 
 pytestmark = [pytest.mark.fast, pytest.mark.time_limit(120)]
 
@@ -129,6 +130,63 @@ def test_the_kernels_are_the_chain_they_replaced(case):
         atol=(1e-5 if dtype == jnp.float32 else 2 ** -8)
         * float(np.abs(want_dw).max()), err_msg="dkernel")
     assert np.abs(f32(want[0])[:, 8]).max() == 0    # the zero rows' q
+
+
+# (case, batch, sequence, rows a tile, key heads of ``dim`` over as many
+#  value heads of twice that, the lanes a run of ``dim`` is laid out to,
+#  columns of x past the prologue's, type)
+LAID_OUT = [
+    ("a_key_head_of_12_over_a_value_head_of_24", 2, 40, 16, 12, 2, 16, 0,
+     jnp.float32),
+    ("in_a_wider_array_in_bfloat16", 1, 48, 32, 24, 2, 128, 96,
+     jnp.bfloat16),
+]
+
+
+@pytest.mark.parametrize("case", LAID_OUT, ids=[c[0] for c in LAID_OUT])
+def test_heads_off_the_lane_tile_come_and_leave_laid_out(case):
+    """Olmo-Hybrid's ratio, a key head of ``dim`` over one value head of
+    ``2 dim`` with ``dim`` no whole lanes: ``x`` and the kernel laid out
+    a run of ``dim`` columns at a time, ``key_dim`` for q's scale. Taken
+    back, q, k, v, ``dx`` and ``dkernel`` are the chain's on the columns
+    as they came; the lanes between stay zero."""
+    _, batch, seq, rows, dim, heads, lane, extra, dtype = case
+    hv = 2 * heads                      # a value head is two runs of dim
+    x, kernel, cot = operands(7, batch, seq, dim, heads, heads, hv, extra,
+                              dtype)
+    laid = -(-dim // lane) * lane
+    lay = lambda a: lay_heads(a, dim, lane)
+    take = lambda a: take_heads(a, dim, lane)
+
+    def laid_out(x, w):
+        out = qp.qkv_prologue(lay(x), lay(w), laid, 2 * heads, heads,
+                              rows=rows, interpret=True, key_dim=dim)
+        return out, tuple(take(o) for o in out)
+
+    (got_laid, got), (got_dx, got_dw) = out_and_vjp(
+        laid_out, (tuple(jnp.zeros_like(lay(c)) for c in cot), cot), x,
+        kernel)
+    want, (want_dx, want_dw) = out_and_vjp(
+        lambda x, w: the_chain_it_replaced(x, w, dim, heads, heads, hv),
+        cot, x, kernel)
+    f32 = lambda a: np.asarray(a.astype(jnp.float32))
+    ulp = 2e-5 if dtype == jnp.float32 else 2 ** -7
+    for name, g, w, l in zip("qkv", got, want, got_laid):
+        assert g.shape == w.shape and g.dtype == dtype, name
+        np.testing.assert_allclose(f32(g), f32(w), rtol=ulp, atol=1e-6,
+                                   err_msg=name)
+        assert not np.any(f32(l).reshape(*l.shape[:2], -1, laid)[..., dim:])
+    assert got_dx.shape == x.shape and got_dw.shape == kernel.shape
+    scale = float(np.abs(f32(want_dx)).max())
+    np.testing.assert_allclose(
+        f32(got_dx), f32(want_dx), err_msg="dx",
+        rtol=2e-4 if dtype == jnp.float32 else 2 ** -5,
+        atol=(1e-5 if dtype == jnp.float32 else 2 ** -7) * scale)
+    np.testing.assert_allclose(
+        got_dw, want_dw, err_msg="dkernel",
+        rtol=2e-4 if dtype == jnp.float32 else 2 ** -6,
+        atol=(1e-5 if dtype == jnp.float32 else 2 ** -8)
+        * float(np.abs(want_dw).max()))
 
 
 def test_what_the_kernels_cannot_tile_is_refused():
