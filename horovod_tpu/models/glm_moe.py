@@ -17,8 +17,10 @@ expert layer after them.
 * **Expert layer** (:class:`ExpertLayer`, the one expert layer of
   every sparse model here; the configuration it is given says what
   differs between them): a float32 router over *all*
-  ``n_routed_experts``, here sigmoid scores and the top k of ``score +
-  bias``, weights normalised over the k and scaled. The layer is told
+  ``n_routed_experts`` (``route``: logits at full precision, the
+  choice by k rounds of max), here
+  sigmoid scores and the top k of ``score + bias``, weights normalised
+  over the k and scaled. The layer is told
   which experts it holds (``experts_held`` from ``expert_offset``: one
   chip's share under expert parallelism), routes over all of them and
   computes its own experts' part; what the absent experts would add is
@@ -384,20 +386,173 @@ def _rows_from_experts_bwd(k, span, g):
 rows_from_experts.defvjp(_rows_from_experts_fwd, _rows_from_experts_bwd)
 
 
+# -- the router --------------------------------------------------------------
+# Exact work with fewer operations (PR 47): the choice is k rounds of
+# max and not the sort ``jax.lax.top_k`` is on the chip, and a chosen
+# score is what its round's reduction carried, not a gather whose
+# transpose is a scatter. Measured on v5e silicon (PR 47; one call's
+# value and gradients at the cells' shapes, tokens x d x experts, k;
+# ms): the gather's form | this one | this one with bfloat16 rows
+# against the float32 kernel's three bfloat16 parts, three single-pass
+# products in place of the product at ``Precision.HIGHEST``:
+#
+#   16,384 x 2,048 x 512, 10 (softmax)            8.58  4.13  4.45
+#   16,384 x 2,560 x 512, 8 of 4 of 8 groups      9.56  4.52  4.84
+#   32,768 x 2,048 x 64, 4                        4.60  1.99  2.06
+#   16,384 x 2,048 x 64, 4                        2.82  1.51  1.48
+#
+# The product stays at ``HIGHEST``: over rows cast up from bfloat16 the
+# compiler leaves out the passes over the zero parts by itself (in the
+# step 0.53 ms forward and 0.55 for dW, 34 GFLOP each: three passes at
+# the chip's peak are 0.52; dx, two true float32 operands, 1.25), and
+# the three-part form pays a write and a read of [N, 3 E] float32.
+# In ``qwen3next-injit-1chip``'s step the gathers were 13.4 ms, the
+# sorts 10.5, the scatter-adds 5.6 and the products 11.5 of ``moe.route``'s
+# 43.9; eighty rounds of max are 1.8.
+
+
+@jax.custom_vjp
+def _logits(x, w):
+    """``x @ w`` in float32 at full precision. Its own rule, so that
+    the two backward products read the cotangent from memory: fused
+    into them, the comparison of places that makes it ran once a tile
+    of each product (``dW`` 1.33 ms where 0.55, ``dx`` 1.57 where 1.25,
+    at 16,384 x 2,048 x 512 on the chip)."""
+    return jnp.dot(x.astype(jnp.float32), w,
+                   precision=jax.lax.Precision.HIGHEST)
+
+
+def _logits_fwd(x, w):
+    return _logits(x, w), (x, w)
+
+
+def _logits_bwd(res, g):
+    x, w = res
+    g = jax.lax.optimization_barrier(g)
+    product = functools.partial(jnp.dot, precision=jax.lax.Precision.HIGHEST)
+    return (product(g, w.T).astype(x.dtype),
+            product(x.astype(jnp.float32).T, g).astype(w.dtype))
+
+
+_logits.defvjp(_logits_fwd, _logits_bwd)
+
+
+def _largest_below(choice, carried, value, index):
+    """One round of max over the last axis: the largest entry of
+    ``choice`` behind ``(value, index)`` in ``jax.lax.top_k``'s order
+    (larger first, of equals the one that comes first), its place, and
+    ``carried`` there; every entry where ``value`` is None. One
+    reduction that reads ``choice`` once: nothing is masked in
+    memory."""
+    place = jax.lax.broadcasted_iota(jnp.int32, choice.shape, choice.ndim - 1)
+    if value is not None:
+        behind = (choice < value[..., None]) | (
+            (choice == value[..., None]) & (place > index[..., None]))
+        choice = jnp.where(behind, choice, -jnp.inf)
+
+    def better(a, b):
+        first = (a[0] > b[0]) | ((a[0] == b[0]) & (a[1] < b[1]))
+        return tuple(jnp.where(first, p, q) for p, q in zip(a, b))
+
+    operands = (choice, place) + ((carried,) if carried is not None else ())
+    init = (-jnp.inf, jnp.iinfo(jnp.int32).max, 0.0)[:len(operands)]
+    out = jax.lax.reduce(
+        operands, tuple(jnp.asarray(i, o.dtype) for i, o in
+                        zip(init, operands)), better, (choice.ndim - 1,))
+    return out[0], out[1], out[2] if carried is not None else out[0]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def choose(scores, choice, k):
+    """``(chosen, picked)`` [N, k]: the places of the k largest entries
+    of each row of ``choice`` [N, E] in ``jax.lax.top_k``'s order, by k
+    rounds of max, and ``scores`` there (``choice`` None: the choice is
+    over ``scores``, and a round's maximum is the chosen score).
+    ``picked``'s transpose is a comparison of places, elementwise over
+    [N, E]."""
+    over, carried = (scores, None) if choice is None else (choice, scores)
+    value = index = None
+    chosen, picked = [], []
+    for _ in range(k):
+        value, index, score = _largest_below(over, carried, value, index)
+        chosen.append(index)
+        picked.append(score)
+    return jnp.stack(chosen, axis=-1), jnp.stack(picked, axis=-1)
+
+
+def _choose_fwd(scores, choice, k):
+    chosen, picked = choose(scores, choice, k)
+    return (chosen, picked), (chosen, jnp.arange(scores.shape[-1],
+                                                 dtype=chosen.dtype))
+
+
+def _choose_bwd(k, res, g):
+    chosen, place = res
+    column = lambda a, j: jax.lax.slice_in_dim(a, j, j + 1, axis=1)
+    dscores = sum(jnp.where(place == column(chosen, j), column(g[1], j), 0)
+                  for j in range(k))
+    return dscores, None
+
+
+choose.defvjp(_choose_fwd, _choose_bwd)
+
+
 def _within_best_groups(biased, n_group: int, topk_group: int):
     """``biased`` [N, E] for a choice limited to groups (DeepSeek-V3's):
     the experts in ``n_group`` groups of neighbours, a group's score the
-    sum of its two largest entries, every entry outside the best
-    ``topk_group`` groups at minus infinity. ``n_group`` 1 gives
-    ``biased`` itself."""
+    sum of its two largest entries (two rounds of max), every entry
+    outside the best ``topk_group`` groups (those that fewer than
+    ``topk_group`` groups come before, in ``jax.lax.top_k``'s order) at
+    minus infinity. ``n_group`` 1 gives ``biased`` itself."""
     if n_group == 1:
         return biased
     n, e = biased.shape
     grouped = biased.reshape(n, n_group, e // n_group)
-    group_score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
-    _, best = jax.lax.top_k(group_score, topk_group)           # [N, g]
-    kept = jnp.any(best[:, :, None] == jnp.arange(n_group), axis=1)
+    first, at, _ = _largest_below(grouped, None, None, None)
+    score = first + _largest_below(grouped, None, first, at)[0]
+    group = jnp.arange(n_group)
+    before = (score[:, None, :] > score[:, :, None]) | (
+        (score[:, None, :] == score[:, :, None])
+        & (group[None, :] < group[:, None]))
+    kept = jnp.sum(before, axis=-1) < topk_group               # [N, g]
     return jnp.where(kept[:, :, None], grouped, -jnp.inf).reshape(n, e)
+
+
+@functools.partial(jax.jit, static_argnums=3)
+def route(xf, w_r, bias, cfg):
+    """``(chosen, gates)`` [N, k] of rows ``xf`` [N, d] under the
+    router's float32 kernel ``w_r`` [d, E] and correction bias (None
+    under a softmax): float32 logits at full precision, the scores, the
+    k experts of each row and their normalised, scaled weights.
+    Jitted, so that a step traces it once and not once a layer and
+    pass."""
+    logits = _logits(xf, w_r)
+    if cfg.scoring == "softmax":
+        scores, choice = jax.nn.softmax(logits, axis=-1), None
+    else:
+        scores = jax.nn.sigmoid(logits)
+        # (nothing is differentiated through a round of max)
+        choice = _within_best_groups(jax.lax.stop_gradient(scores + bias),
+                                     cfg.n_group, cfg.topk_group)
+    chosen, picked = choose(scores, choice, cfg.num_experts_per_tok)
+    # (the scaled scores first and no add of a zero: the lowered step
+    # of a model without the constant is what it was)
+    gates = cfg.routed_scaling_factor * picked
+    total = jnp.sum(picked, axis=-1, keepdims=True)
+    if cfg.topk_weight_eps:
+        total = total + cfg.topk_weight_eps
+    return chosen, gates / total
+
+
+def _note_route(cfg) -> None:
+    from horovod_tpu.common import basics
+    basics.note_traced(
+        "hvd_moe_route",
+        "the expert layer's router traced last: rounds of max a call (k, "
+        "and two for the groups' scores), and the sorts and gathers left "
+        "in it",
+        {"max_rounds": cfg.num_experts_per_tok + 2 * (cfg.n_group > 1),
+         "sorts": 0, "gathers": 0})
 
 
 class Router(nn.Module):
@@ -444,6 +599,14 @@ class ExpertLayer(nn.Module):
     the shared expert, where the model has one; ``counts`` int32
     [experts_held + 2]: assignments to each held expert, to absent
     experts, and dropped (always 0).
+
+    The router (``route``, scope ``moe.route``) makes float32 logits at
+    full precision: a rounded score moves the choice, and the chip's
+    default would run a float32 matmul in one bfloat16 pass. The k
+    experts are ``jax.lax.top_k``'s to the place and the order under
+    ties, found by k rounds of max (the chip sorts every row for a
+    ``top_k``), and a chosen score is what its round carried: no
+    gather, and its transpose a comparison, not a scatter.
 
     One body for every sparse model; the configuration says what
     differs. It is read for ``hidden_size``, ``moe_intermediate_size``,
@@ -500,28 +663,13 @@ class ExpertLayer(nn.Module):
 
         with jax.named_scope("moe.route"):
             # float32 at full precision: a rounded score moves the
-            # choice (the chip's default runs an f32 matmul in bf16).
+            # choice (the chip's default runs an f32 matmul in bf16);
+            # the choice without a sort, the weights without a gather
             w_r, bias = Router(cfg, name="router")()
-            logits = jnp.dot(xf.astype(jnp.float32), w_r,
-                             precision=jax.lax.Precision.HIGHEST)
-            if cfg.scoring == "softmax":
-                scores = jax.nn.softmax(logits, axis=-1)
-                _, chosen = jax.lax.top_k(scores, k)           # [N, k]
-            else:
-                scores = jax.nn.sigmoid(logits)
-                _, chosen = jax.lax.top_k(_within_best_groups(
-                    scores + jax.lax.stop_gradient(bias), cfg.n_group,
-                    cfg.topk_group), k)
+            chosen, gates = route(xf, w_r, bias, cfg)
+            _note_route(cfg)
             # kept only where a caller asks for ``intermediates``
             self.sow("intermediates", "chosen", chosen)
-            picked = jnp.take_along_axis(scores, chosen, axis=-1)
-            # (the scaled scores first and no add of a zero: the lowered
-            # step of a model without the constant is what it was)
-            gates = cfg.routed_scaling_factor * picked
-            total = jnp.sum(picked, axis=-1, keepdims=True)
-            if cfg.topk_weight_eps:
-                total = total + cfg.topk_weight_eps
-            gates = gates / total
 
         with jax.named_scope("moe.dispatch"):
             local = chosen - cfg.expert_offset

@@ -107,6 +107,14 @@ _CELL_SHAPES = [
     ("glm47flash-injit", 80, 4096, 256),    # B4 x 20 heads of 256
 ]
 
+# The four sparse cells' routers: (cell, d, experts, k, groups, the
+# best, scoring)
+_ROUTERS = [("qwen3next-injit", 2048, 512, 10, 1, 1, "softmax"),
+            ("ling3flash-injit", 2560, 512, 8, 8, 4, "sigmoid"),
+            ("lfm2moe-injit", 2048, 64, 4, 1, 1, "sigmoid"),
+            ("glm47flash-injit", 2048, 64, 4, 1, 1, "sigmoid")]
+_ROUTER_ROWS = 2048
+
 # (case id, q, k and v heads of 128, columns of x)
 _PROLOGUE_SHAPES = [("kimi_delta_attention", 32, 32, 32, 12288),
                     ("gated_deltanet_in_qkvz", 16, 16, 32, 12288)]
@@ -281,6 +289,36 @@ def _lower_latent_flash(chip, mesh4):
             arr(192), arr(192), arr(128))
 
 
+def _router_config(cell):
+    from horovod_tpu.models import glm_moe, lfm2, ling3flash, qwen3next
+    return {"qwen3next-injit": qwen3next.Qwen3NextConfig,
+            "ling3flash-injit": ling3flash.Ling3FlashConfig,
+            "lfm2moe-injit": lfm2.Lfm2MoeConfig,
+            "glm47flash-injit": glm_moe.GlmMoeConfig}[cell]()
+
+
+def _lower_router(chip, mesh4, d, e, k, n_group, topk_group, scoring):
+    """The value and gradients of the expert layer's router alone
+    (``glm_moe.route``) over 2,048 bfloat16 rows at a cell's published
+    widths (two cells that differ in the weights' scale and constant
+    alone share a program)."""
+    import dataclasses
+    from horovod_tpu.models import glm_moe
+    cfg = dataclasses.replace(
+        glm_moe.GlmMoeConfig(), hidden_size=d, n_routed_experts=e,
+        num_experts_per_tok=k, n_group=n_group, topk_group=topk_group,
+        scoring=scoring)
+    bias = None if scoring == "softmax" else _arr(chip, e, dt=jnp.float32)
+
+    def loss(x, w, bias, weight):
+        chosen, gates = glm_moe.route(x, w, bias, cfg)
+        return jnp.sum(gates * weight), chosen
+    return jax.jit(jax.value_and_grad(
+        loss, argnums=(0, 1), has_aux=True)).lower(
+            _arr(chip, _ROUTER_ROWS, d), _arr(chip, d, e, dt=jnp.float32),
+            bias, _arr(chip, _ROUTER_ROWS, k, dt=jnp.float32))
+
+
 @pytest.mark.parametrize("bh,seq,d,block_q,block_k",
                          [c[1:] for c in _CASES],
                          ids=[c[0] for c in _CASES])
@@ -423,6 +461,29 @@ def test_the_flash_kernels_compile_at_a_score_head_of_192_over_a_value_head_of_1
     kernel there)."""
     assert _kernel_calls(compiled("latent_flash")) == 3
     assert _top(192) == (512, 1024)
+
+
+@pytest.mark.parametrize("cell,d,e,k,n_group,topk_group,scoring", _ROUTERS,
+                         ids=[c[0] for c in _ROUTERS])
+def test_the_router_compiles_without_a_sort_a_gather_or_a_scatter(
+        compiled, cell, d, e, k, n_group, topk_group, scoring):
+    """``glm_moe.route`` at each sparse cell's router, value and
+    gradients: the optimised program holds no ``sort`` (what
+    ``jax.lax.top_k`` is on this chip), no ``gather`` and no
+    ``scatter``, and its three products (the logits, ``dx``, ``dW``)
+    are float32 at full precision."""
+    import re
+    cfg = _router_config(cell)
+    assert (cfg.hidden_size, cfg.n_routed_experts, cfg.num_experts_per_tok,
+            cfg.n_group, cfg.topk_group, cfg.scoring) \
+        == (d, e, k, n_group, topk_group, scoring)
+    text = compiled("router", d, e, k, n_group, topk_group,
+                    scoring).as_text()
+    for op in ("sort", "gather", "scatter"):
+        assert not re.search(rf"\b{op}\(", text), op
+    products = re.findall(r"= f32\[[\d,]+\]\S* convolution\(.*", text)
+    assert len(products) == 3
+    assert all("operand_precision={highest,highest}" in p for p in products)
 
 
 def test_d256_keeps_the_default_pair_and_d512_is_halved():
@@ -631,7 +692,8 @@ LOWERINGS = {
     "delta_layer_off_the_lane_tile": _lower_delta_layer_off_the_lane_tile,
     "kimi_delta_attention": _lower_kimi_delta_attention,
     "prologue": _lower_prologue, "epilogue": _lower_epilogue,
-    "latent_flash": _lower_latent_flash, "lm_step": _lower_lm_step,
+    "latent_flash": _lower_latent_flash, "router": _lower_router,
+    "lm_step": _lower_lm_step,
     "other_step": _lower_other_step,
 }
 
@@ -649,7 +711,8 @@ PROGRAMS = list(dict.fromkeys([
     ("delta_layer_off_the_lane_tile",), ("kimi_delta_attention",),
     *(("prologue", *case[1:]) for case in _PROLOGUE_SHAPES),
     *(("epilogue", *case[1:]) for case in _EPILOGUE_SHAPES),
-    ("latent_flash",), ("lm_step", 2, True), ("lm_step", 1, False),
+    ("latent_flash",), *(("router", *case[1:]) for case in _ROUTERS),
+    ("lm_step", 2, True), ("lm_step", 1, False),
     ("other_step", _resnet_step), ("other_step", _glm_moe_step),
 ]))
 AHEAD = 8       # programs handed to the pool before they are asked for

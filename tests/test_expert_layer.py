@@ -10,8 +10,12 @@ rows by width, no scatter of tokens x k scalars) and says of it in the
 registry, the eight shares of a layer adding up to the uncut layer, the
 choice limited to groups against a literal loop over tokens (a group
 whose two best lose is never chosen from; one group is the choice as it
-was, bit for bit), and the two older models' parameter trees as they
-were. (PR 40's tests: 3 s cold; PR 41's grouped cases: 4 s.)"""
+was, bit for bit), the router (the choice by rounds of max against
+``jax.lax.top_k`` under ties, the chosen scores and their gradients
+against the gather's, the product at full precision whatever the rows'
+type) and what it says of itself in the registry, and the two older
+models' parameter trees as they were. (PR 40's tests: 3 s cold; PR
+41's grouped cases: 4 s; PR 47's router cases: 4 s.)"""
 
 import dataclasses
 import hashlib
@@ -331,6 +335,29 @@ def test_a_differentiated_layer_writes_its_rows_into_the_registry(
         hvd.shutdown()
 
 
+def test_a_traced_layer_writes_its_router_into_the_registry(monkeypatch):
+    """``hvd_moe_route`` holds the layer traced last: its rounds of max
+    (k, and two more where the choice is limited to groups), and no
+    sort and no gather."""
+    import horovod_tpu.jax as hvd
+    from horovod_tpu import metrics
+    monkeypatch.setenv("HOROVOD_TPU_METRICS", "1")
+    hvd.init()
+    try:
+        _, p, x = layer_and_params(config("sigmoid"))
+        for cfg, rounds in (
+                (config("sigmoid"), K),
+                (config("sigmoid", n_group=4, topk_group=2,
+                        num_experts_per_tok=2), 4)):
+            jax.eval_shape(glm_moe.ExpertLayer(cfg).apply, {"params": p}, x)
+            local = metrics()["local"]
+            for kind, want in (("max_rounds", rounds), ("sorts", 0),
+                               ("gathers", 0)):
+                assert local[f'hvd_moe_route{{kind="{kind}"}}']["v"] == want
+    finally:
+        hvd.shutdown()
+
+
 def test_a_scoring_the_layer_does_not_know_is_refused():
     cfg = dataclasses.replace(config("softmax"), scoring="tanh")
     with pytest.raises(ValueError, match="sigmoid or softmax"):
@@ -591,6 +618,107 @@ def test_one_group_is_the_choice_as_it_was_bit_for_bit(scoring):
         {"params": p}, x).as_text()
     assert lowered(cfg) == lowered(dataclasses.replace(
         cfg, n_group=1, topk_group=3))
+
+
+# -- the router --------------------------------------------------------------
+
+# (experts, k, groups, of which the best): the four cells' routers
+ROUTERS = [(512, 10, 1, 1), (512, 8, 8, 4), (64, 4, 1, 1)]
+ROUTER_IDS = ["qwen3next", "ling3flash", "lfm2moe_and_glm47flash"]
+
+
+def tied_scores(e, rows=256):
+    """[rows, e] scores in quarters of one to two: every row full of
+    ties."""
+    return 1 + jnp.round(4 * jax.random.uniform(jax.random.key(e),
+                                                (rows, e))) / 4
+
+
+def sorted_choice(choice, n_group, topk_group, k):
+    """The choice as it was made before PR 47: the sort that
+    ``jax.lax.top_k`` is, twice more where the choice is limited to
+    groups."""
+    if n_group > 1:
+        n, e = choice.shape
+        grouped = choice.reshape(n, n_group, e // n_group)
+        _, best = jax.lax.top_k(
+            jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1), topk_group)
+        kept = jnp.any(best[:, :, None] == jnp.arange(n_group), axis=1)
+        choice = jnp.where(kept[:, :, None], grouped, -jnp.inf).reshape(n, e)
+    return jax.lax.top_k(choice, k)[1]
+
+
+@pytest.mark.parametrize("e, k, n_group, topk_group", ROUTERS, ids=ROUTER_IDS)
+def test_the_rounds_of_max_choose_what_the_sort_chose_under_ties(
+        e, k, n_group, topk_group):
+    """``choose`` over scores full of ties: ``jax.lax.top_k``'s places
+    in ``jax.lax.top_k``'s order (of equals the one that comes first),
+    and the grouped choice is still the literal loop's."""
+    choice = tied_scores(e)
+    assert len(np.unique(choice)) <= 5
+
+    @jax.jit
+    def both(choice):
+        within = glm_moe._within_best_groups(choice, n_group, topk_group)
+        return (glm_moe.choose(choice, within, k)[0],
+                sorted_choice(choice, n_group, topk_group, k))
+    got, want = both(choice)
+    np.testing.assert_array_equal(got, want)
+    assert got.dtype == jnp.int32
+    np.testing.assert_array_equal(
+        got[:64], literal_grouped_choice(choice[:64], n_group, topk_group, k))
+
+
+@pytest.mark.parametrize("e, k, n_group, topk_group", ROUTERS, ids=ROUTER_IDS)
+def test_the_chosen_scores_and_their_gradients_are_the_gathers(
+        e, k, n_group, topk_group):
+    """``picked`` (what a round's reduction carried: the maximum itself
+    where the choice is over the scores, the score beside the biased
+    one otherwise) and its transpose (a comparison of places) against
+    ``take_along_axis`` and its scatter-add, to the bit."""
+    scores = tied_scores(e) / 4
+    bias = None if n_group == 1 and e == 512 else \
+        jnp.round(8 * jax.random.normal(jax.random.key(1), (e,))) / 32
+    weight = jax.random.normal(jax.random.key(2), (scores.shape[0], k))
+
+    def gates(picked):
+        return jnp.sum(weight * picked / jnp.sum(picked, -1, keepdims=True))
+
+    def by_rounds(scores):
+        choice = None if bias is None else glm_moe._within_best_groups(
+            jax.lax.stop_gradient(scores + bias), n_group, topk_group)
+        return gates(glm_moe.choose(scores, choice, k)[1])
+
+    def by_the_gather(scores):
+        chosen = sorted_choice(scores if bias is None else scores + bias,
+                               n_group, topk_group, k)
+        return gates(jnp.take_along_axis(scores, chosen, axis=-1))
+
+    got, want = jax.jit(lambda s: (jax.value_and_grad(by_rounds)(s),
+                                   jax.value_and_grad(by_the_gather)(s))
+                        )(scores)
+    np.testing.assert_array_equal(got[0], want[0])
+    np.testing.assert_array_equal(got[1], want[1])
+    assert int((np.asarray(got[1]) != 0).sum()) == scores.shape[0] * k
+
+
+@pytest.mark.parametrize("rows", ["float32", "bfloat16"])
+def test_the_product_is_at_full_precision_whatever_the_rows(rows):
+    """The router lowers to one product, float32 operands at
+    ``Precision.HIGHEST`` (bfloat16 rows cast up: the three-pass form
+    against the kernel's bfloat16 parts read no gain on the chip and
+    went out again), and to no sort, gather or scatter."""
+    import re
+    cfg = config("softmax")
+    arg = lambda *shape, dt=jnp.float32: jax.ShapeDtypeStruct(shape, dt)
+    text = glm_moe.route.lower(arg(48, D, dt=jnp.dtype(rows)),
+                               arg(D, EXPERTS), None, cfg).as_text()
+    dots = re.findall(r"stablehlo\.dot_general.*", text)
+    assert len(dots) == 1
+    assert "precision = [HIGHEST, HIGHEST]" in dots[0]
+    assert f"(tensor<48x{D}xf32>, tensor<{D}x{EXPERTS}xf32>)" in dots[0]
+    assert "sort" not in text and "gather" not in text \
+        and "scatter" not in text
 
 
 LAYER_TREES = {
