@@ -63,12 +63,18 @@ Kernel shape:
   ``hvd_gdn_layout`` has both widths.
 - ``gdn_fwd`` also writes the state that **entered** each chunk
   (``[B, Hv, chunks, Dk, Dv]`` float32: 64 KB a head and chunk, 537 MB
-  a layer at the model's shape and a chunk of 64, 268 MB at 128; a
-  recomputed block keeps it, as it keeps every kernel's output).
-  ``gdn_bwd`` walks the chunks from the last to the first with ``dS``
-  in scratch: it recomputes a chunk's ``T``, ``W``, ``U`` and ``V'``
-  from the entering state and propagates through every product above,
-  ``T`` included (``dA = T^T dT T^T``).
+  a layer at the model's shape and a chunk of 64, 268 MB at 128) and
+  **the chunk's inverse** ``T`` (``[B, Hv, chunks, C, C]`` float32, as
+  computed: 4 C bytes a position and head whatever the chunk, 268 MB a
+  layer at the model's shape); a recomputed block keeps both, as it
+  keeps every kernel's output. ``gdn_bwd`` walks the chunks from the
+  last to the first with ``dS`` in scratch: it makes a chunk's ``W``,
+  ``U`` and ``V'`` again from the entering state and the forward's
+  ``T``, **takes no inverse** (it was most of the kernel: 72 of its 104
+  MXU passes at a chunk of 128, a float32 product six), and propagates
+  through every product above, ``T`` included (``dA = T^T dT T^T``, in
+  float32 at full precision, which is why ``T`` travels as float32).
+  The gauge ``hvd_gdn_chunks`` has what a chunk hands over.
 - MXU operands take q's type (bfloat16 in the model: exact for q, k and
   v, a rounding for ``T``, ``S`` and the gated operands), accumulation
   is float32; the state, ``G``, ``beta``, every exponential and the
@@ -98,6 +104,7 @@ import jax.numpy as jnp
 #    64     13.571   31.525      537 MB          2.1
 #   128     11.316   25.179      268 MB          5.4
 #   256     33.473   72.180      134 MB          19.1
+#   128     11.231   17.191      268 + 268 MB (T)  5.4   (PR 48)
 # (128 and 64 again in a second call: 11.301 and 25.165, 13.556 and
 # 31.514.) A longer chunk executes more (``chunk_flops`` over the
 # recurrence's 7 Dk Dv a position: the last column) and is faster all
@@ -106,6 +113,13 @@ import jax.numpy as jnp
 # the inverse's fourteen products of 256^3 a head and chunk are the
 # kernel. In the cell's step (traced) the kernels read 9.77 ms forward
 # and 12.97 backward a layer at 128, 12.2 and 17.1 at 64.
+# **PR 48's line: the backward takes the forward's ``T`` and no
+# inverse.** The same call on one machine, its parent beside it, each
+# twice: 11.247 and 25.045, 11.246 and 25.053 before; 11.237 and 17.186
+# the second time: the backward alone 13.80 -> 5.96 ms, the forward the
+# same with 268 MB more to write; the output and all five gradients
+# the parent's to the bit. In the cell's step the kernels read 9.72 ms
+# forward and 5.13 backward a layer since.
 # The inverse, same shape and clock (PR 33, a later call; the doublings
 # twice, first and last): forward substitution by row blocks as ``(I -
 # A) = (I - D)(I - M)``, ``D`` the diagonal blocks (inverted by
@@ -145,6 +159,10 @@ LANES = 128
 #   the rule lays out, 128, doublings   7.082   15.236   1e21 / nan / nan
 #   laid out already, 128, blocks 8     9.035   18.614
 #   the rule lays out, 64, blocks 8    10.641   22.909
+#   the rule lays out, 128, blocks 8    8.897   13.332   (PR 48: the
+#     backward takes the forward's ``T``; its parent in the same call
+#     8.907 and 18.726, each again 8.895 and 13.339, 8.892 and 18.711;
+#     entering states 126 MB a layer and ``T`` 126 MB)
 # (``lay_heads`` has what laying out costs.) Either way the
 # kernels hold a head as 128 and 256 lanes and run 1.78 times the
 # recurrence's products (``hvd_gdn_layout``; the benchmark's
@@ -169,15 +187,25 @@ def _chunk_for(seq: int) -> int:
     return chunk
 
 
-def _note_chunks(seq: int, chunk: int) -> None:
+def kept_bytes(chunk: int, dk: int, dv: int) -> int:
+    """The bytes a value head's chunk hands from the forward kernel to
+    the backward: the entering state and ``T``, float32 (the Kimi rule's
+    too: ``parallel.kda``)."""
+    return 4 * (dk * dv + chunk * chunk)
+
+
+def _note_chunks(seq: int, chunk: int, dk: int, dv: int) -> None:
     """``hvd_gdn_chunks{kind=...}`` of the call being traced
-    (docs/metrics.md)."""
+    (docs/metrics.md); ``dk`` and ``dv`` a head's widths as the kernels
+    hold them."""
     from horovod_tpu.common import basics
     basics.note_traced(
         "hvd_gdn_chunks",
-        "the gated delta rule traced last: chunks a sequence and "
-        "positions a chunk",
-        {"chunks": -(-seq // chunk), "chunk_length": chunk})
+        "the gated delta rule traced last: chunks a sequence, positions "
+        "a chunk, and the bytes a value head's chunk hands from the "
+        "forward kernel to the backward",
+        {"chunks": -(-seq // chunk), "chunk_length": chunk,
+         "kept_bytes_per_chunk": kept_bytes(chunk, dk, dv)})
 
 
 def _note_layout(dk: int, dv: int, laid_dk: int, laid_dv: int) -> None:
@@ -292,20 +320,23 @@ def _last(row):
                    keepdims=True)
 
 
-def _chunk(q, k, v, g_row, b_row, state, kk, qk, mm, solve):
+def _chunk(q, k, v, g_row, b_row, state, kk, qk, mm, solve=None, t=None):
     """A value head's chunk from the state that entered it: everything
     the forward writes and the backward propagates through. q, k
     [C, Dk]; v [C, Dv]; g_row, b_row [1, C] float32 (``G`` and
     ``beta``); state [Dk, Dv] float32; kk, qk [C, C] the key head's raw
     products; ``solve`` the rows a block of the inverse's forward
-    substitution, None for doublings over the whole chunk."""
+    substitution, None for doublings over the whole chunk; ``t`` the
+    chunk's inverse where the caller has it (the backward kernel, from
+    the forward): no inverse is taken then."""
     eye, lower, lower_eq = _masks(q.shape[0])
     g_col, b_col = _col(g_row, eye), _col(b_row, eye)
     decay = jnp.where(lower_eq, jnp.exp(jnp.minimum(g_col - g_row, 0.0)),
                       0.0)                              # exp(G_i - G_j)
-    a = jnp.where(lower, -b_col * kk * decay, 0.0)
-    t = _unit_lower_inverse(a) if solve is None \
-        else _unit_lower_inverse_by_blocks(a, solve)
+    if t is None:
+        a = jnp.where(lower, -b_col * kk * decay, 0.0)
+        t = _unit_lower_inverse(a) if solve is None \
+            else _unit_lower_inverse_by_blocks(a, solve)
     e_col = jnp.exp(g_col)
     kb = (b_col * e_col) * k.astype(_F32)
     vb = b_col * v.astype(_F32)
@@ -320,8 +351,8 @@ def _chunk(q, k, v, g_row, b_row, state, kk, qk, mm, solve):
                 g_last=g_last, e_last=e_last)
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, sent_ref, s_scr,
-                *, rep: int, dv: int, solve):
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, sent_ref, t_ref,
+                s_scr, *, rep: int, dv: int, solve):
     from jax.experimental import pallas as pl
     c = pl.program_id(2)
 
@@ -339,6 +370,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, sent_ref, s_scr,
         sent_ref[0, r, 0] = state
         x = _chunk(q, k, v, g_ref[0, r, pl.ds(c, 1), :],
                    b_ref[0, r, pl.ds(c, 1), :], state, kk, qk, mm, solve)
+        t_ref[0, r, 0] = x["t"]
         out = _mm(q.astype(_F32) * x["e_col"], state, _NN, mm) \
             + _mm(x["p"], x["v_new"], _NN, mm)
         o_ref[0, :, r * dv:(r + 1) * dv] = out.astype(o_ref.dtype)
@@ -346,9 +378,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, sent_ref, s_scr,
             k.astype(_F32) * x["e_last"], x["v_new"], _TN, mm)
 
 
-def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, sent_ref, do_ref,
+def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, sent_ref, t_ref, do_ref,
                 dq_ref, dk_ref, dv_ref, dg_ref, db_ref, ds_scr,
-                *, rep: int, dv: int, n_chunks: int, solve):
+                *, rep: int, dv: int, n_chunks: int):
     from jax.experimental import pallas as pl
     step = pl.program_id(2)
     c = n_chunks - 1 - step
@@ -373,7 +405,8 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, sent_ref, do_ref,
         d_out = do_ref[0, :, r * dv:(r + 1) * dv]
         d_state = ds_scr[r]
         x = _chunk(q, k, v, g_ref[0, r, pl.ds(c, 1), :],
-                   b_ref[0, r, pl.ds(c, 1), :], state, kk, qk, mm, solve)
+                   b_ref[0, r, pl.ds(c, 1), :], state, kk, qk, mm,
+                   t=t_ref[0, r, 0])
         eye, t, decay = x["eye"], x["t"], x["decay"]
         b_col, e_col, e_last = x["b_col"], x["e_col"], x["e_last"]
         kd = kf * e_last
@@ -441,7 +474,9 @@ def _specs(chunk, dk, dv, rep, n_chunks, reverse: bool):
         gates=pl.BlockSpec((1, rep, n_chunks, chunk),
                            lambda b, h, c: (b, h, 0, 0)),
         sent=pl.BlockSpec((1, rep, 1, dk, dv),
-                          lambda b, h, c: (b, h, at(c), 0, 0)))
+                          lambda b, h, c: (b, h, at(c), 0, 0)),
+        t=pl.BlockSpec((1, rep, 1, chunk, chunk),
+                       lambda b, h, c: (b, h, at(c), 0, 0)))
 
 
 def chunk_flops(chunk: int, dk: int, dv: int, rep: int) -> int:
@@ -453,6 +488,16 @@ def chunk_flops(chunk: int, dk: int, dv: int, rep: int) -> int:
     head = inverse + 2 * chunk * chunk * (dk + dv) \
         + 2 * 2 * chunk * dk * dv + 2 * chunk * chunk * dv \
         + 2 * chunk * dk * dv
+    return shared + rep * head
+
+
+def _bwd_chunk_flops(chunk: int, dk: int, dv: int, rep: int) -> int:
+    """Multiply-adds x 2 of the backward kernel's products for one key
+    head's chunk: ``W``, ``U`` and ``V'`` again, every product's two
+    cotangents and ``dA``'s two of the chunk's width; no inverse."""
+    shared = 2 * 2 * chunk * chunk * dk
+    head = 2 * (7 * chunk * chunk * dk + 5 * chunk * chunk * dv
+                + 7 * chunk * dk * dv + 2 * chunk ** 3)
     return shared + rep * head
 
 
@@ -471,10 +516,11 @@ def _gdn_fwd(q, k, v, g, beta, chunk: int, heads, solve, interpret: bool):
         functools.partial(_fwd_kernel, rep=rep, dv=dv, solve=solve),
         grid=(bt, hk, n_chunks),
         in_specs=[s["qk"], s["qk"], s["v"], s["gates"], s["gates"]],
-        out_specs=(s["v"], s["sent"]),
+        out_specs=(s["v"], s["sent"], s["t"]),
         out_shape=(
             jax.ShapeDtypeStruct(v.shape, v.dtype),
-            jax.ShapeDtypeStruct((bt, hv, n_chunks, dk, dv), _F32)),
+            jax.ShapeDtypeStruct((bt, hv, n_chunks, dk, dv), _F32),
+            jax.ShapeDtypeStruct((bt, hv, n_chunks, chunk, chunk), _F32)),
         scratch_shapes=[pltpu.VMEM((rep, dk, dv), _F32)],
         compiler_params=_compiler_params(),
         interpret=interpret,
@@ -484,13 +530,12 @@ def _gdn_fwd(q, k, v, g, beta, chunk: int, heads, solve, interpret: bool):
             transcendentals=bt * hv * n_chunks * chunk * (chunk + 2),
             bytes_accessed=(q.size + k.size) * q.dtype.itemsize
             + 2 * v.size * v.dtype.itemsize + 4 * (g.size + beta.size)
-            + 4 * bt * hv * n_chunks * dk * dv),
+            + bt * hv * n_chunks * kept_bytes(chunk, dk, dv)),
     )(q, k, v, g, beta)
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "heads", "solve",
-                                             "interpret"))
-def _gdn_bwd(q, k, v, g, beta, sent, d_out, chunk: int, heads, solve,
+@functools.partial(jax.jit, static_argnames=("chunk", "heads", "interpret"))
+def _gdn_bwd(q, k, v, g, beta, sent, t, d_out, chunk: int, heads,
              interpret: bool):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -501,11 +546,10 @@ def _gdn_bwd(q, k, v, g, beta, sent, d_out, chunk: int, heads, solve,
     n_chunks = padded // chunk
     s = _specs(chunk, dk, dv, rep, n_chunks, reverse=True)
     return pl.pallas_call(
-        functools.partial(_bwd_kernel, rep=rep, dv=dv, n_chunks=n_chunks,
-                          solve=solve),
+        functools.partial(_bwd_kernel, rep=rep, dv=dv, n_chunks=n_chunks),
         grid=(bt, hk, n_chunks),
         in_specs=[s["qk"], s["qk"], s["v"], s["gates"], s["gates"],
-                  s["sent"], s["v"]],
+                  s["sent"], s["t"], s["v"]],
         out_specs=(s["qk"], s["qk"], s["v"], s["gates"], s["gates"]),
         out_shape=(
             jax.ShapeDtypeStruct(q.shape, q.dtype),
@@ -518,12 +562,12 @@ def _gdn_bwd(q, k, v, g, beta, sent, d_out, chunk: int, heads, solve,
         interpret=interpret,
         name="gdn_bwd",
         cost_estimate=pl.CostEstimate(
-            flops=3 * bt * hk * n_chunks * chunk_flops(chunk, dk, dv, rep),
+            flops=bt * hk * n_chunks * _bwd_chunk_flops(chunk, dk, dv, rep),
             transcendentals=bt * hv * n_chunks * chunk * (chunk + 2),
             bytes_accessed=2 * (q.size + k.size) * q.dtype.itemsize
             + 3 * v.size * v.dtype.itemsize + 8 * (g.size + beta.size)
-            + 4 * sent.size),
-    )(q, k, v, g, beta, sent, d_out)
+            + 4 * (sent.size + t.size)),
+    )(q, k, v, g, beta, sent, t, d_out)
 
 
 def _laid(dim: int, lane: int = LANES) -> int:
@@ -613,20 +657,20 @@ def _rule(q, k, v, g, beta, chunk, solve, lane, interpret):
 
 def _rule_fwd(q, k, v, g, beta, chunk, solve, lane, interpret):
     heads = (q.shape[2], v.shape[2])
-    out, sent = _gdn_fwd(*_laid_out(q, k, v, g, beta, chunk, lane),
-                         chunk=chunk, heads=heads, solve=solve,
-                         interpret=interpret)
-    return _taken_back(out, v, lane), (q, k, v, g, beta, sent)
+    out, sent, t = _gdn_fwd(*_laid_out(q, k, v, g, beta, chunk, lane),
+                            chunk=chunk, heads=heads, solve=solve,
+                            interpret=interpret)
+    return _taken_back(out, v, lane), (q, k, v, g, beta, sent, t)
 
 
 def _rule_bwd(chunk, solve, lane, interpret, res, d_out):
-    q, k, v, g, beta, sent = res
+    q, k, v, g, beta, sent, t = res
     bt, seq, hv = v.shape[:3]
     ops = _laid_out(q, k, v, g, beta, chunk, lane)
     padded = ops[0].shape[1]
     d_out = _laid_heads(d_out.astype(v.dtype), chunk, lane)
     dq, dk, dv, d_gsum, d_beta = _gdn_bwd(
-        *ops, sent, d_out, chunk=chunk, heads=(q.shape[2], hv), solve=solve,
+        *ops, sent, t, d_out, chunk=chunk, heads=(q.shape[2], hv),
         interpret=interpret)
     # G is the running sum of g inside a chunk: g_j reaches every G_i
     # with i >= j
@@ -674,9 +718,9 @@ def gated_delta_rule(q, k, v, g, beta, chunk: Optional[int] = None,
         interpret = jax.default_backend() != "tpu"
     lane = (1 if interpret else LANES) if lane is None else int(lane)
     solve = min(chunk, _SOLVE_BLOCK) if beta_max > 1.0 else None
-    _note_chunks(q.shape[1], chunk)
-    _note_layout(*(filled or (q.shape[3], v.shape[3])),
-                 _laid(q.shape[3], lane), _laid(v.shape[3], lane))
+    laid = _laid(q.shape[3], lane), _laid(v.shape[3], lane)
+    _note_chunks(q.shape[1], chunk, *laid)
+    _note_layout(*(filled or (q.shape[3], v.shape[3])), *laid)
     return _rule(q, k, v, g, beta, chunk, solve, lane, bool(interpret))
 
 

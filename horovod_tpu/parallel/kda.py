@@ -69,10 +69,15 @@ a block is a chunk's rows of one head's columns. ``g`` comes as it is
 with a triangle of ones at full precision, as they give ``dg`` back
 from ``dG``. ``beta`` comes as ``[B, H, chunks, C]``. ``kda_fwd`` also
 writes the state that **entered** each chunk (``[B, H, chunks, Dv,
-Dk]`` float32, 64 KB a head and chunk at 128/128); ``kda_bwd`` walks
-the chunks from the last to the first with ``dS`` in scratch,
-recomputes a chunk's tables from the entering state and propagates
-through every product above.
+Dk]`` float32, 64 KB a head and chunk at 128/128) and **the chunk's
+inverse** ``T`` (``[B, H, chunks, C, C]`` float32, as computed: 64 KB
+at a chunk of 128); ``kda_bwd`` walks the chunks from the last to the
+first with ``dS`` in scratch, makes a chunk's running sums, levels,
+``W``, ``U`` and ``V'`` again from the entering state and the
+forward's ``T``, **takes no inverse**, and propagates through every
+product above (``dA = T^T dT T^T`` in float32 at full precision, which
+is why ``T`` travels as float32). The gauge ``hvd_kda_chunks`` has what
+a chunk hands over.
 
 MXU operands take q's type where they are bounded by 1 (bfloat16 in
 the model), accumulation is float32; the state, ``G``, ``beta``, every
@@ -93,7 +98,7 @@ import jax.numpy as jnp
 
 from horovod_tpu.parallel.gated_delta import (
     _F32, _NN, _NT, _TN, _col, _compiler_params, _masks, _mm, _mm_f32, _row,
-    _unit_lower_inverse_by_blocks,
+    _unit_lower_inverse_by_blocks, kept_bytes,
 )
 
 # Chunk and sub-block length by sequence length, as (longest sequence,
@@ -111,6 +116,7 @@ from horovod_tpu.parallel.gated_delta import (
 #   128     8    18.238   43.924      268 MB          5
 #   128    32    17.484   41.619      268 MB          3
 #   256    16    31.665   77.329      134 MB          5
+#   128    16    17.652   32.255      268 + 268 MB (T)  4   (PR 48)
 # (the scalar rule, ``gated_delta``, at 32 key heads over 32 value heads
 # and its chunk of 128, same call: 12.956 and 29.073. With the inverse
 # by doublings over the whole chunk, an earlier call: 128/16 16.821 and
@@ -125,7 +131,14 @@ from horovod_tpu.parallel.gated_delta import (
 # factor reaches exp(155) under the published bound of -5, past
 # float32. 8 and 16 read alike; 16 is the largest the bound allows. In
 # the cell's step (traced) the kernels read 15.1 ms forward and 22.4
-# backward a layer.
+# backward a layer. **PR 48's line: the backward takes the forward's
+# ``T`` and no inverse.** The same call on one machine, its parent
+# beside it, each twice: 17.647 and 42.074, 17.642 and 42.054 before
+# (other seeded operands than PR 41's); 17.651 and 32.259 the second
+# time: the backward alone 24.43 -> 14.60 ms, the forward the same
+# with 268 MB more to write; the output and all five gradients the
+# parent's to the bit. In the cell's step the kernels read 15.1 ms
+# forward and 12.6 backward a layer since.
 _CHUNK_LADDER = ((None, 128, 16),)
 
 # Rows a block of the triangular inverse's forward substitution.
@@ -148,16 +161,19 @@ def _lengths_for(seq: int):
     return chunk, min(sub, chunk)
 
 
-def _note_chunks(seq: int, chunk: int, sub: int) -> None:
+def _note_chunks(seq: int, chunk: int, sub: int, dk: int, dv: int) -> None:
     """``hvd_kda_chunks{kind=...}`` of the call being traced
     (docs/metrics.md)."""
     from horovod_tpu.common import basics
     basics.note_traced(
         "hvd_kda_chunks",
         "the Kimi delta attention rule traced last: chunks a sequence, "
-        "positions a chunk and positions a diagonal sub-block",
+        "positions a chunk, positions a diagonal sub-block, and the "
+        "bytes a head's chunk hands from the forward kernel to the "
+        "backward",
         {"chunks": -(-seq // chunk), "chunk_length": chunk,
-         "sub_block_length": sub})
+         "sub_block_length": sub,
+         "kept_bytes_per_chunk": kept_bytes(chunk, dk, dv)})
 
 
 # -- inside a chunk ---------------------------------------------------------
@@ -198,11 +214,13 @@ def _level_mm(x, y, dims, exact: bool, mm):
     return _mm_f32(x, y, dims) if exact else _mm(x, y, dims, mm)
 
 
-def _chunk(q, k, v, g, b_row, state_t, sub: int, mm):
+def _chunk(q, k, v, g, b_row, state_t, sub: int, mm, t=None):
     """A head's chunk from the state that entered it: everything the
     forward writes and the backward propagates through. q, k [C, Dk];
     v [C, Dv]; g [C, Dk] float32 (a position's own log-decay); b_row
-    [1, C] float32; state_t [Dv, Dk] float32."""
+    [1, C] float32; state_t [Dv, Dk] float32; ``t`` the chunk's inverse
+    where the caller has it (the backward kernel, from the forward): no
+    inverse is taken then."""
     size = q.shape[0]
     eye, lower, lower_eq = _masks(size)
     ones = jnp.where(lower_eq, 1.0, 0.0).astype(_F32)
@@ -217,7 +235,8 @@ def _chunk(q, k, v, g, b_row, state_t, sub: int, mm):
                          kf * col_f, _NT, exact, mm)
         p = p + jnp.where(mask & lower_eq, both[:size], 0.0)
         m = m + jnp.where(mask & lower, both[size:], 0.0)
-    t = _unit_lower_inverse_by_blocks(-b_col * m, _SOLVE_BLOCK)
+    if t is None:
+        t = _unit_lower_inverse_by_blocks(-b_col * m, _SOLVE_BLOCK)
     gam = jnp.exp(g_sum)
     kb = (b_col * gam) * kf
     vb = b_col * vf
@@ -232,8 +251,8 @@ def _chunk(q, k, v, g, b_row, state_t, sub: int, mm):
                 e_last=e_last)
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, sent_ref, s_scr,
-                *, sub: int):
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, sent_ref, t_ref,
+                s_scr, *, sub: int):
     from jax.experimental import pallas as pl
     c = pl.program_id(2)
 
@@ -247,6 +266,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, sent_ref, s_scr,
     sent_ref[0, 0, 0] = state_t
     x = _chunk(q, k_ref[0], v_ref[0], g_ref[0], b_ref[0, 0, pl.ds(c, 1), :],
                state_t, sub, mm)
+    t_ref[0, 0, 0] = x["t"]
     out = _mm(x["qf"] * x["gam"], state_t, _NT, mm) \
         + _mm(x["p"], x["v_new"], _NN, mm)
     o_ref[0] = out.astype(o_ref.dtype)
@@ -254,7 +274,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, sent_ref, s_scr,
         x["v_new"], x["kf"] * x["e_last"], _TN, mm)
 
 
-def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, sent_ref, do_ref,
+def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, sent_ref, t_ref, do_ref,
                 dq_ref, dk_ref, dv_ref, dg_ref, db_ref, ds_scr,
                 *, sub: int, n_chunks: int):
     from jax.experimental import pallas as pl
@@ -272,7 +292,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, sent_ref, do_ref,
     d_out = do_ref[0]
     d_state_t = ds_scr[...]
     x = _chunk(q, k_ref[0], v_ref[0], g_ref[0], b_ref[0, 0, pl.ds(c, 1), :],
-               state_t, sub, mm)
+               state_t, sub, mm, t=t_ref[0, 0, 0])
     qf, kf, vf = x["qf"], x["kf"], x["vf"]
     t, b_col, gam, e_last = x["t"], x["b_col"], x["gam"], x["e_last"]
     rowsum = lambda a: jnp.sum(a, axis=1, keepdims=True)
@@ -337,7 +357,9 @@ def _specs(chunk, dk, dv, n_chunks, reverse: bool):
         beta=pl.BlockSpec((1, 1, n_chunks, chunk),
                           lambda b, h, c: (b, h, 0, 0)),
         sent=pl.BlockSpec((1, 1, 1, dv, dk),
-                          lambda b, h, c: (b, h, at(c), 0, 0)))
+                          lambda b, h, c: (b, h, at(c), 0, 0)),
+        t=pl.BlockSpec((1, 1, 1, chunk, chunk),
+                       lambda b, h, c: (b, h, at(c), 0, 0)))
 
 
 def chunk_flops(chunk: int, sub: int, dk: int, dv: int) -> int:
@@ -357,6 +379,17 @@ def chunk_flops(chunk: int, sub: int, dk: int, dv: int) -> int:
         + 2 * chunk * dk * dv
 
 
+def _bwd_chunk_flops(chunk: int, sub: int, dk: int, dv: int) -> int:
+    """Multiply-adds x 2 of the backward kernel's products for one head's
+    chunk: the running sum, the levels, ``W``, ``U`` and ``V'`` again,
+    every product's two cotangents (a level's are twice its own
+    product) and ``dA``'s two of the chunk's width; no inverse."""
+    levels = (chunk // sub).bit_length()
+    return 2 * ((5 + 6 * levels) * chunk * chunk * dk
+                + 5 * chunk * chunk * dv + 7 * chunk * dk * dv
+                + 2 * chunk ** 3)
+
+
 @functools.partial(jax.jit,
                    static_argnames=("chunk", "sub", "heads", "interpret"))
 def _kda_fwd(q, k, v, g, beta, chunk: int, sub: int, heads: int,
@@ -371,10 +404,11 @@ def _kda_fwd(q, k, v, g, beta, chunk: int, sub: int, heads: int,
         functools.partial(_fwd_kernel, sub=sub),
         grid=(bt, heads, n_chunks),
         in_specs=[s["qk"], s["qk"], s["v"], s["qk"], s["beta"]],
-        out_specs=(s["v"], s["sent"]),
+        out_specs=(s["v"], s["sent"], s["t"]),
         out_shape=(
             jax.ShapeDtypeStruct(v.shape, v.dtype),
-            jax.ShapeDtypeStruct((bt, heads, n_chunks, dv, dk), _F32)),
+            jax.ShapeDtypeStruct((bt, heads, n_chunks, dv, dk), _F32),
+            jax.ShapeDtypeStruct((bt, heads, n_chunks, chunk, chunk), _F32)),
         scratch_shapes=[pltpu.VMEM((dv, dk), _F32)],
         compiler_params=_compiler_params(),
         interpret=interpret,
@@ -385,13 +419,13 @@ def _kda_fwd(q, k, v, g, beta, chunk: int, sub: int, heads: int,
             * (3 + (chunk // sub).bit_length()),
             bytes_accessed=(q.size + k.size) * q.dtype.itemsize
             + 2 * v.size * v.dtype.itemsize + 4 * (g.size + beta.size)
-            + 4 * bt * heads * n_chunks * dk * dv),
+            + bt * heads * n_chunks * kept_bytes(chunk, dk, dv)),
     )(q, k, v, g, beta)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("chunk", "sub", "heads", "interpret"))
-def _kda_bwd(q, k, v, g, beta, sent, d_out, chunk: int, sub: int,
+def _kda_bwd(q, k, v, g, beta, sent, t, d_out, chunk: int, sub: int,
              heads: int, interpret: bool):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -403,7 +437,7 @@ def _kda_bwd(q, k, v, g, beta, sent, d_out, chunk: int, sub: int,
         functools.partial(_bwd_kernel, sub=sub, n_chunks=n_chunks),
         grid=(bt, heads, n_chunks),
         in_specs=[s["qk"], s["qk"], s["v"], s["qk"], s["beta"], s["sent"],
-                  s["v"]],
+                  s["t"], s["v"]],
         out_specs=(s["qk"], s["qk"], s["v"], s["qk"], s["beta"]),
         out_shape=(
             jax.ShapeDtypeStruct(q.shape, q.dtype),
@@ -416,13 +450,14 @@ def _kda_bwd(q, k, v, g, beta, sent, d_out, chunk: int, sub: int,
         interpret=interpret,
         name="kda_bwd",
         cost_estimate=pl.CostEstimate(
-            flops=3 * bt * heads * n_chunks * chunk_flops(chunk, sub, dk, dv),
+            flops=bt * heads * n_chunks
+            * _bwd_chunk_flops(chunk, sub, dk, dv),
             transcendentals=bt * heads * n_chunks * chunk * dk
             * (3 + (chunk // sub).bit_length()),
             bytes_accessed=2 * (q.size + k.size) * q.dtype.itemsize
             + 3 * v.size * v.dtype.itemsize + 8 * (g.size + beta.size)
-            + 4 * sent.size),
-    )(q, k, v, g, beta, sent, d_out)
+            + 4 * (sent.size + t.size)),
+    )(q, k, v, g, beta, sent, t, d_out)
 
 
 def _laid_out(q, k, v, g, beta, chunk):
@@ -450,20 +485,20 @@ def _rule(q, k, v, g, beta, chunk, sub, interpret):
 
 def _rule_fwd(q, k, v, g, beta, chunk, sub, interpret):
     seq = q.shape[1]
-    out, sent = _kda_fwd(*_laid_out(q, k, v, g, beta, chunk), chunk=chunk,
-                         sub=sub, heads=q.shape[2], interpret=interpret)
-    return out[:, :seq].reshape(v.shape), (q, k, v, g, beta, sent)
+    out, sent, t = _kda_fwd(*_laid_out(q, k, v, g, beta, chunk), chunk=chunk,
+                            sub=sub, heads=q.shape[2], interpret=interpret)
+    return out[:, :seq].reshape(v.shape), (q, k, v, g, beta, sent, t)
 
 
 def _rule_bwd(chunk, sub, interpret, res, d_out):
-    q, k, v, g, beta, sent = res
+    q, k, v, g, beta, sent, t = res
     bt, seq, heads = v.shape[:3]
     ops = _laid_out(q, k, v, g, beta, chunk)
     padded = ops[0].shape[1]
     d_out = jnp.pad(d_out.astype(v.dtype).reshape(bt, seq, -1),
                     ((0, 0), (0, padded - seq), (0, 0)))
     dq, dk, dv, dg, d_beta = _kda_bwd(
-        *ops, sent, d_out, chunk=chunk, sub=sub, heads=heads,
+        *ops, sent, t, d_out, chunk=chunk, sub=sub, heads=heads,
         interpret=interpret)
     d_beta = d_beta.reshape(bt, heads, padded).transpose(0, 2, 1)
     return (dq[:, :seq].reshape(q.shape), dk[:, :seq].reshape(k.shape),
@@ -500,7 +535,7 @@ def kimi_delta_attention(q, k, v, g, beta, chunk: Optional[int] = None,
                          f"two, the sub-block at most the chunk")
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    _note_chunks(q.shape[1], chunk, sub)
+    _note_chunks(q.shape[1], chunk, sub, q.shape[3], v.shape[3])
     return _rule(q, k, v, g, beta, chunk, sub, bool(interpret))
 
 

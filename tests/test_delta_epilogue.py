@@ -12,6 +12,8 @@ Then the two mixers against their former selves, the two models'
 parameter trees, and what a recomputed block keeps. (Cold on this
 sandbox: 40 s.)"""
 
+import re
+
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
@@ -291,16 +293,18 @@ def test_the_gated_norms_leaf_is_where_it_was(cell):
 # -- what a recomputed block keeps ------------------------------------------
 
 # the rule's kernels' prefix and the arrays kept beside the arguments
-BLOCKS = {"kimi_delta_attention": ("kda", 2), "gated_deltanet": ("gdn", 2)}
+# (``o``, the entering states and the chunks' inverses)
+BLOCKS = {"kimi_delta_attention": ("kda", 3), "gated_deltanet": ("gdn", 3)}
 
 
 @pytest.mark.parametrize("name", list(BLOCKS))
 def test_a_recomputed_block_makes_the_epilogues_output_again(name):
-    """``_keep_kernel_outputs`` keeps the rule's output ``o`` and its
-    entering states and not the epilogue's ``y`` (134 MB a layer at the
-    cell's size): the backward of a recomputed block runs the
-    epilogue's forward kernel again, from the ``o`` it kept, and the
-    rule's forward kernel not."""
+    """``_keep_kernel_outputs`` keeps the rule's output ``o``, its
+    entering states and its chunks' inverses ``T`` (the backward kernel
+    takes no inverse: it has the forward's) and not the epilogue's ``y``
+    (134 MB a layer at the cell's size): the backward of a recomputed
+    block runs the epilogue's forward kernel again, from the ``o`` it
+    kept, and the rule's forward kernel not."""
     rule, kept_arrays = BLOCKS[name]
     calls, kept = a_recomputed_blocks_backward(name)
     assert calls("delta_epilogue_fwd") == 2
@@ -309,3 +313,7 @@ def test_a_recomputed_block_makes_the_epilogues_output_again(name):
     assert calls(f"{rule}_fwd") == 1 and calls(f"{rule}_bwd") == 1
     assert len(kept) == kept_arrays, kept
     assert not any("epilogue" in k or "prologue" in k for k in kept)
+    # the one chunk of 64 that holds the block's 40 positions: ``T`` is
+    # [B, H, chunks, C, C] float32 out of the forward kernel's call
+    inverses = [k for k in kept if re.match(r"f32\[1,\d+,1,64,64\] ", k)]
+    assert len(inverses) == 1 and f"_{rule}_fwd" in inverses[0], kept

@@ -108,6 +108,71 @@ def test_the_kernels_are_the_recurrence_forward_and_backward(case):
             err_msg=f"d{name}")
 
 
+def kernel_products(call, name, *args):
+    """The ``dot_general``s in the body of the Pallas kernel ``name``
+    that ``call(*args)`` traces, as ``(all, at Precision.HIGHEST)``."""
+    found = []
+
+    def walk(jaxpr, inside):
+        for eqn in jaxpr.eqns:
+            mine = inside or (eqn.primitive.name == "pallas_call"
+                              and name in str(eqn.params["name"]))
+            if inside and eqn.primitive.name == "dot_general":
+                # None, or a pair: one precision an operand
+                found.append(set(eqn.params["precision"] or ())
+                             == {jax.lax.Precision.HIGHEST})
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub, mine)
+
+    walk(jax.make_jaxpr(call)(*args).jaxpr, False)
+    return len(found), sum(found)
+
+
+@pytest.mark.parametrize("case", CASES, ids=[c[0] for c in CASES])
+def test_the_forward_hands_the_backward_each_chunks_inverse(case):
+    """``gdn_fwd``'s third output is ``T = (I - A)^-1`` of every value
+    head's chunk, by the doublings or, with ``beta`` above 1, by blocks:
+    against the same function of the same ``A`` made outside the kernel
+    from the operands as the kernels get them. The backward kernel reads
+    it and takes no inverse: its only products at full precision are
+    ``dA = T^T dT T^T``'s two a value head, where the forward's are the
+    inverse's."""
+    _, seq, chunk, hk, hv, dk, dv, beta_max, lane = case
+    rep = hv // hk
+    solve = min(chunk, gd._SOLVE_BLOCK) if beta_max > 1.0 else None
+    args = operands(2, 2, seq, hk, hv, dk, dv, beta_max=beta_max)
+    ops = gd._laid_out(*args, chunk, lane or 1)
+    static = dict(chunk=chunk, heads=(hk, hv), interpret=True)
+    out, sent, t = gd._gdn_fwd(*ops, solve=solve, **static)
+    n_chunks = -(-seq // chunk)
+    assert t.shape == (2, hv, n_chunks, chunk, chunk) \
+        and t.dtype == jnp.float32 and sent.shape[:3] == t.shape[:3]
+
+    @jax.jit
+    def outside(k, g_sum, beta):
+        k = k.reshape(2, n_chunks, chunk, hk, -1).transpose(0, 3, 1, 2, 4)
+        kk = jnp.repeat(jnp.einsum("bhcid,bhcjd->bhcij", k, k), rep, axis=1)
+        decay = jnp.exp(jnp.minimum(g_sum[..., :, None] - g_sum[..., None, :],
+                                    0.0))
+        a = jnp.where(jnp.tril(jnp.ones((chunk, chunk), bool), -1),
+                      -beta[..., :, None] * kk * decay, 0.0)
+        inverse = gd._unit_lower_inverse if solve is None else (
+            lambda a: gd._unit_lower_inverse_by_blocks(a, solve))
+        return jax.lax.map(inverse, a.reshape(-1, chunk, chunk)).reshape(
+            a.shape)
+
+    with jax.default_matmul_precision("highest"):
+        want = outside(ops[1], ops[3], ops[4])
+    np.testing.assert_allclose(t, want, rtol=1e-5, atol=1e-6)
+    fwd = kernel_products(
+        lambda *o: gd._gdn_fwd(*o, solve=solve, **static), "gdn_fwd", *ops)
+    bwd = kernel_products(
+        lambda *o: gd._gdn_bwd(*o, **static), "gdn_bwd", *ops, sent, t, out)
+    assert bwd[1] == 2 * rep and fwd[1] > bwd[1], (fwd, bwd)
+    assert gd.kept_bytes(chunk, *sent.shape[3:]) \
+        == 4 * (sent[0, 0, 0].size + t[0, 0, 0].size)
+
+
 def test_bfloat16_operands_keep_the_state_and_the_gates_in_float32():
     """The model's call: q, k, v in bfloat16. The result is within a
     bfloat16's rounding of the float32 recurrence on the same rounded
@@ -205,7 +270,11 @@ def test_the_traced_call_leaves_its_layout_in_the_gauge(monkeypatch):
     assert noted["hvd_gdn_layout"] == {
         "key_dim": 12, "value_dim": 24, "laid_key_dim": 16,
         "laid_value_dim": 32}
-    assert noted["hvd_gdn_chunks"] == {"chunks": 2, "chunk_length": 8}
+    # a head's chunk keeps its entering state [16, 32] and its inverse
+    # [8, 8], float32, for the backward
+    assert noted["hvd_gdn_chunks"] == {
+        "chunks": 2, "chunk_length": 8,
+        "kept_bytes_per_chunk": 4 * (16 * 32 + 8 * 8)}
 
 
 def test_the_ladder_gives_a_power_of_two_that_holds_a_short_sequence():

@@ -18,6 +18,7 @@ from horovod_tpu.parallel import gated_delta as gd
 from horovod_tpu.parallel import kda
 
 from .compiled import out_and_vjp
+from .test_gated_delta import kernel_products
 
 pytestmark = [pytest.mark.fast, pytest.mark.time_limit(120)]
 
@@ -63,6 +64,50 @@ def test_the_kernels_are_the_recurrence_forward_and_backward(case):
         np.testing.assert_allclose(
             g, w, rtol=1e-4, atol=2e-5 * float(jnp.abs(w).max()),
             err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("case", CASES[:2], ids=[c[0] for c in CASES[:2]])
+def test_the_forward_hands_the_backward_each_chunks_inverse(case):
+    """``kda_fwd``'s third output is ``T = (I + diag(beta) M)^-1`` of
+    every head's chunk: against ``_unit_lower_inverse_by_blocks`` of the
+    same ``A`` made outside the kernel, ``M`` by the definition (a sum
+    over channels of ``k_i k_j exp(G_i - G_j)``). The backward kernel
+    reads it and takes no inverse: its products at full precision are
+    ``G``'s running sum, the diagonal level's three, ``dA = T^T dT
+    T^T``'s two and ``dg``'s, where the forward's are the running sum,
+    the diagonal level's one and the inverse's."""
+    _, seq, chunk, sub, heads, dk, dv, lower = case
+    args = operands(2, 2, seq, heads, dk, dv, lower)
+    ops = kda._laid_out(*args, chunk)
+    static = dict(chunk=chunk, sub=sub, heads=heads, interpret=True)
+    out, sent, t = kda._kda_fwd(*ops, **static)
+    n_chunks = -(-seq // chunk)
+    assert t.shape == (2, heads, n_chunks, chunk, chunk) \
+        and t.dtype == jnp.float32 and sent.shape[:3] == t.shape[:3]
+
+    @jax.jit
+    def outside(k, g, beta):
+        chunks = lambda x: x.reshape(2, n_chunks, chunk, heads, dk).transpose(
+            0, 3, 1, 2, 4)
+        k, g_sum = chunks(k), jnp.cumsum(chunks(g), axis=3)
+        m = jnp.sum(k[..., :, None, :] * k[..., None, :, :] * jnp.exp(
+            jnp.minimum(g_sum[..., :, None, :] - g_sum[..., None, :, :],
+                        0.0)), axis=-1)
+        a = jnp.where(jnp.tril(jnp.ones((chunk, chunk), bool), -1),
+                      -beta[..., :, None] * m, 0.0)
+        return jax.lax.map(
+            lambda a: kda._unit_lower_inverse_by_blocks(a, kda._SOLVE_BLOCK),
+            a.reshape(-1, chunk, chunk)).reshape(a.shape)
+
+    np.testing.assert_allclose(t, outside(ops[1], ops[3], ops[4]),
+                               rtol=1e-5, atol=1e-6)
+    fwd = kernel_products(lambda *o: kda._kda_fwd(*o, **static), "kda_fwd",
+                          *ops)
+    bwd = kernel_products(lambda *o: kda._kda_bwd(*o, **static), "kda_bwd",
+                          *ops, sent, t, out)
+    assert bwd[1] == 7 and fwd[1] > 2, (fwd, bwd)
+    assert gd.kept_bytes(chunk, dk, dv) \
+        == 4 * (sent[0, 0, 0].size + t[0, 0, 0].size)
 
 
 def test_a_gate_at_its_bound_for_whole_sub_blocks_stays_finite():
@@ -220,8 +265,11 @@ def test_the_traced_call_leaves_its_chunks_in_the_gauge():
     basics.note_traced = lambda name, what, kinds: noted.update(
         {name: kinds})
     try:
-        kda._note_chunks(100, 32, 16)
+        kda._note_chunks(100, 32, 16, 16, 8)
     finally:
         basics.note_traced = real
+    # a head's chunk keeps its entering state [8, 16] and its inverse
+    # [32, 32], float32, for the backward
     assert noted == {"hvd_kda_chunks": {
-        "chunks": 4, "chunk_length": 32, "sub_block_length": 16}}
+        "chunks": 4, "chunk_length": 32, "sub_block_length": 16,
+        "kept_bytes_per_chunk": 4 * (16 * 8 + 32 * 32)}}

@@ -258,12 +258,13 @@ def test_the_two_models_parameter_trees_are_what_they_were(cell):
 # -- what a recomputed block keeps ------------------------------------------
 
 def test_a_recomputed_block_makes_the_prologues_outputs_again():
-    """``_keep_kernel_outputs`` keeps the rule's output and entering
-    states and not the prologue's q, k, v (403 MB a layer at the cell's
-    size): the backward of a recomputed block runs the prologue's
-    forward kernel again and the rule's forward kernel not."""
+    """``_keep_kernel_outputs`` keeps the rule's output, entering
+    states and chunks' inverses and not the prologue's q, k, v (403 MB
+    a layer at the cell's size): the backward of a recomputed block
+    runs the prologue's forward kernel again and the rule's forward
+    kernel not."""
     calls, kept = a_recomputed_blocks_backward("kimi_delta_attention")
     assert calls("qkv_prologue_fwd") == 2 and calls("qkv_prologue_bwd") == 1
     assert calls("kda_fwd") == 1 and calls("kda_bwd") == 1
-    assert len(kept) == 2 and all("kimi_delta_attention" in k for k in kept)
+    assert len(kept) == 3 and all("kimi_delta_attention" in k for k in kept)
     assert not any("qkv_prologue" in k for k in kept)
