@@ -371,6 +371,10 @@ DEVICE_SCOPES = {
     "shortconv.conv": "its two gates and the three causal depthwise taps",
     "normed_attn": "grouped-head attention with normalised q and k "
                    "around its flash call",
+    "swa_attn": "grouped-head attention inside a sliding window, with "
+                "its rotary, around its flash call",
+    "nope_attn": "grouped-head attention over the whole prefix without "
+                 "any positional signal, around its flash call",
     "post_norm": "a block's RMSNorms on the outputs of its mixer and of "
                  "its feed-forward, with the residual adds they feed",
     "ssm.proj": "Mamba's four projections",

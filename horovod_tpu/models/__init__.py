@@ -17,6 +17,7 @@ from horovod_tpu.models.qwen3next import Qwen3NextConfig, Qwen3NextLM
 from horovod_tpu.models.lfm2 import Lfm2MoeConfig, Lfm2MoeLM
 from horovod_tpu.models.ling3flash import Ling3FlashConfig, Ling3FlashLM
 from horovod_tpu.models.olmo_hybrid import OlmoHybridConfig, OlmoHybridLM
+from horovod_tpu.models.smallthinker import SmallThinkerConfig, SmallThinkerLM
 from horovod_tpu.models.mnist import MnistConvNet
 from horovod_tpu.models.vit import ViT, ViTConfig, ViT_S16, ViT_B16
 
@@ -26,6 +27,7 @@ __all__ = [
     "Phi4FlashConfig", "Phi4FlashLM", "Qwen3NextConfig", "Qwen3NextLM",
     "Lfm2MoeConfig", "Lfm2MoeLM", "Ling3FlashConfig", "Ling3FlashLM",
     "OlmoHybridConfig", "OlmoHybridLM",
+    "SmallThinkerConfig", "SmallThinkerLM",
     "MnistConvNet",
     "ViT", "ViTConfig", "ViT_S16", "ViT_B16",
 ]

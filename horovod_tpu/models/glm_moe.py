@@ -87,6 +87,7 @@ class GlmMoeConfig:
     topk_weight_eps: float = 0.0
     n_group: int = 1                 # the choice is not limited to groups
     topk_group: int = 1
+    expert_activation: str = "silu"
     row_tier_headroom: float = 2.0   # ``ROW_TIER_HEADROOM``
     # The share of the experts this chip holds: ids
     # [expert_offset, expert_offset + experts_held).
@@ -555,6 +556,20 @@ def _note_route(cfg) -> None:
          "sorts": 0, "gathers": 0})
 
 
+class Routing(NamedTuple):
+    """What an expert layer's router decided of its rows
+    (``ExpertLayer.route``): all the layer needs of it."""
+    order: jax.Array        # [N k] the assignments sorted by held expert
+    sizes: jax.Array        # [held + 1] assignments a held expert, absent
+    held_total: jax.Array   # assignments to held experts
+    gate_rows: jax.Array    # [N k] an assignment's weight, 0 where absent
+    plan: Optional[Span]    # the first tier's buffer, where made ahead
+
+
+# What gates an expert's ``up`` rows: ``W_down (act(W_gate u) * W_up u)``.
+EXPERT_ACTIVATIONS = {"silu": nn.silu, "relu": nn.relu}
+
+
 class Router(nn.Module):
     """The router's parameters: the float32 kernel over all the experts
     and, where the scores are sigmoids, the correction bias that only
@@ -636,36 +651,71 @@ class ExpertLayer(nn.Module):
       chosen scores before they are divided by it (0: the bare sum);
     * ``shared_expert_gate``: whether the shared expert's output is
       multiplied by ``sigmoid(x w_s)``, ``w_s`` the layer's own
-      ``d -> 1``.
+      ``d -> 1``;
+    * ``expert_activation``: what gates a routed expert's ``up`` rows,
+      ``"silu"`` or ``"relu"`` (``EXPERT_ACTIVATIONS``; the shared
+      expert is a SwiGLU whatever it says).
+
+    **The router may read other rows than the experts do**
+    (``router_input``): SmallThinker takes its logits from the block's
+    input and feeds the experts the post-attention normalised states,
+    and its block calls ``route(x, plan_ahead=True)`` before attention
+    and hands the :class:`Routing` in, so that the choice, the gates,
+    the order by expert and the first tier's buffer plan stand in the
+    traced program before the flash kernels. The router's gradient then
+    flows into those rows. With neither set the layer lowers to what it
+    did before it had them.
 
     What each model sets (the defaults are GLM-4.7-Flash's): GLM
     sigmoid, scale 1.8, a shared expert without a gate; Qwen3-Next
     softmax, scale 1, a gated shared expert; LFM2 sigmoid, scale 1, eps
     1e-6, no shared expert; Ling-3.0-flash sigmoid, scale 2.5, 8 groups
-    of which 4, a shared expert without a gate."""
+    of which 4, a shared expert without a gate; SmallThinker softmax,
+    scale 1, no shared expert, ReLU, the router on the block's input."""
 
     cfg: Any
 
-    @nn.compact
-    def __call__(self, x):
+    def setup(self):
         cfg = self.cfg
-        e, k, held_n = (cfg.n_routed_experts, cfg.num_experts_per_tok,
-                        cfg.experts_held)
+        e, held_n = cfg.n_routed_experts, cfg.experts_held
         if not 0 <= cfg.expert_offset <= e - held_n:
             raise ValueError(
                 f"experts [{cfg.expert_offset}, {cfg.expert_offset + held_n}"
                 f") are not among the router's {e}")
         if cfg.scoring not in ("sigmoid", "softmax"):
             raise ValueError(f"scoring {cfg.scoring!r}: sigmoid or softmax")
-        d = cfg.hidden_size
-        xf = x.reshape(-1, d)
-        n = xf.shape[0]
+        if cfg.expert_activation not in EXPERT_ACTIVATIONS:
+            raise ValueError(f"expert_activation {cfg.expert_activation!r}: "
+                             f"one of {sorted(EXPERT_ACTIVATIONS)}")
+        self.router = Router(cfg)
+        self.experts = HeldExperts(cfg)
+        if cfg.shared_intermediate_size:
+            self.shared = SwiGLU(cfg, cfg.shared_intermediate_size)
+            if cfg.shared_expert_gate:
+                self.shared_gate = _dense(cfg, 1, None)
 
+    def _caps(self, n: int) -> list:
+        """The row buffer's static sizes for ``n`` tokens, a tier each."""
+        cfg = self.cfg
+        return [max(1, int(round(t * n * cfg.num_experts_per_tok)))
+                for t in row_tiers(cfg.experts_held, cfg.n_routed_experts,
+                                   cfg.row_tier_headroom)]
+
+    def route(self, rows, plan_ahead: bool = False) -> Routing:
+        """What the router decides of ``rows`` [..., d]: the choice, the
+        gates and the order of the assignments by held expert; with
+        ``plan_ahead`` the first tier's buffer plan too, so that a block
+        whose router reads its input (SmallThinker) has all of it in
+        the traced program before its attention call."""
+        cfg = self.cfg
+        k, held_n = cfg.num_experts_per_tok, cfg.experts_held
+        xf = rows.reshape(-1, cfg.hidden_size)
+        n = xf.shape[0]
         with jax.named_scope("moe.route"):
             # float32 at full precision: a rounded score moves the
             # choice (the chip's default runs an f32 matmul in bf16);
             # the choice without a sort, the weights without a gather
-            w_r, bias = Router(cfg, name="router")()
+            w_r, bias = self.router()
             chosen, gates = route(xf, w_r, bias, cfg)
             _note_route(cfg)
             # kept only where a caller asks for ``intermediates``
@@ -678,19 +728,41 @@ class ExpertLayer(nn.Module):
             order, sizes = expert_order(group, held_n)
             held_total = jnp.sum(sizes[:held_n])
             gate_rows = jnp.where(held, gates, 0.0).reshape(-1)
+            plan = None
+            if plan_ahead:
+                cap = self._caps(n)[0]
+                plan = span_of(order[:cap], jnp.arange(cap) < held_total,
+                               n, k)
+        return Routing(order, sizes, held_total, gate_rows, plan)
 
-        w_gate, w_up, w_down = HeldExperts(cfg, name="experts")()
+    def __call__(self, x, router_input=None):
+        """``router_input``: the rows the router reads where they are
+        not ``x`` ([..., d], as many as ``x`` has), or the
+        :class:`Routing` that ``route`` made of them ahead; None routes
+        on ``x`` itself."""
+        cfg = self.cfg
+        k, held_n = cfg.num_experts_per_tok, cfg.experts_held
+        d = cfg.hidden_size
+        xf = x.reshape(-1, d)
+        n = xf.shape[0]
+        routing = router_input if isinstance(router_input, Routing) \
+            else self.route(xf if router_input is None else router_input)
+        order, sizes, held_total, gate_rows, planned = routing
+        activation = EXPERT_ACTIVATIONS[cfg.expert_activation]
 
-        def span(first, gs, live):
+        w_gate, w_up, w_down = self.experts()
+
+        def span(first, gs, live, plan=None):
             """The held experts' part of the assignments ``first`` (a
             run of ``order``): ``gs`` says how many of its rows each
             expert has, ``live`` which rows belong to a held expert at
-            all."""
+            all; ``plan`` where the buffer's was made ahead."""
             with jax.named_scope("moe.dispatch"):
-                plan = span_of(first, live, n, k)
+                if plan is None:
+                    plan = span_of(first, live, n, k)
                 rows = rows_to_experts(xf, plan, k)
             with jax.named_scope("moe.experts"):
-                hidden = nn.silu(jax.lax.ragged_dot(rows, w_gate, gs)) \
+                hidden = activation(jax.lax.ragged_dot(rows, w_gate, gs)) \
                     * jax.lax.ragged_dot(rows, w_up, gs)
                 out = jax.lax.ragged_dot(hidden, w_down, gs)
             with jax.named_scope("moe.combine"):
@@ -700,11 +772,11 @@ class ExpertLayer(nn.Module):
                     * gate_rows[first][:, None].astype(out.dtype)
                 return rows_from_experts(out, plan, k)
 
-        def routed(cap):
+        def routed(cap, plan=None):
             """The held experts' part through a row buffer of ``cap``."""
             def run(_):
                 return span(order[:cap], sizes[:held_n],
-                            jnp.arange(cap) < held_total)
+                            jnp.arange(cap) < held_total, plan)
             return run
 
         def walked(cap):
@@ -734,24 +806,23 @@ class ExpertLayer(nn.Module):
                 return y.astype(cfg.dtype)
             return run
 
-        caps = [max(1, int(round(t * n * k)))
-                for t in row_tiers(held_n, e, cfg.row_tier_headroom)]
+        caps = self._caps(n)
         if len(caps) == 1:
             tier = 0
-            y = routed(caps[0])(None)
+            y = routed(caps[0], planned)(None)
         else:
             tier = jnp.sum(held_total > jnp.asarray(caps[:-1], jnp.int32))
             y = jax.lax.switch(
-                tier, [routed(c) for c in caps[:-1]] + [walked(caps[-2])],
-                None)
+                tier, [routed(c, None if i else planned)
+                       for i, c in enumerate(caps[:-1])]
+                + [walked(caps[-2])], None)
 
         if cfg.shared_intermediate_size:
             with jax.named_scope("moe.shared"):
-                shared = SwiGLU(cfg, cfg.shared_intermediate_size,
-                                name="shared")(xf)
+                shared = self.shared(xf)
                 if cfg.shared_expert_gate:
-                    shared = shared * jax.nn.sigmoid(_dense(
-                        cfg, 1, "shared_gate")(xf).astype(jnp.float32)) \
+                    shared = shared * jax.nn.sigmoid(
+                        self.shared_gate(xf).astype(jnp.float32)) \
                         .astype(shared.dtype)
                 y = y + shared
         # held assignments whose row lies behind the buffer that ran: the

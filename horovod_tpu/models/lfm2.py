@@ -84,6 +84,7 @@ class Lfm2MoeConfig:
     topk_weight_eps: float = 1e-6
     n_group: int = 1
     topk_group: int = 1
+    expert_activation: str = "silu"
     row_tier_headroom: float = 2.0
     # The share of the experts this chip holds: ids
     # [expert_offset, expert_offset + experts_held).

@@ -102,6 +102,7 @@ class Ling3FlashConfig:
     topk_weight_eps: float = 0.0
     n_group: int = 8
     topk_group: int = 4
+    expert_activation: str = "silu"
     # The first tier of the row buffer over the share held. A chip of
     # 64 holds a sixty-fourth of the experts, and a seeded router's
     # spread over so small a share is wide (a layer's held experts drew

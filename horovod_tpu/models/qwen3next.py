@@ -104,6 +104,7 @@ class Qwen3NextConfig:
     topk_weight_eps: float = 0.0
     n_group: int = 1
     topk_group: int = 1
+    expert_activation: str = "silu"
     row_tier_headroom: float = 2.0
     # The share of the experts this chip holds: ids
     # [expert_offset, expert_offset + experts_held).
@@ -129,13 +130,14 @@ def layer_kind(index: int, full_attention_interval: int) -> str:
         else "delta"
 
 
-def best_grouped_attention(q, k, v):
+def best_grouped_attention(q, k, v, window=None):
     """The flash kernels on the TPU, the same mathematics dense
-    elsewhere. q: [B,S,H,D]; k, v: [B,S,Hkv,D]."""
+    elsewhere. q: [B,S,H,D]; k, v: [B,S,Hkv,D]; ``window`` None is the
+    causal mask alone."""
     from horovod_tpu.parallel import flash_attention as fa
     if jax.default_backend() == "tpu":
-        return fa.flash_attention(q, k, v, causal=True)
-    return fa._dense_reference(q, k, v, True, 0, 0)
+        return fa.flash_attention(q, k, v, causal=True, window=window)
+    return fa._dense_reference(q, k, v, True, 0, 0, window)
 
 
 def head_lanes() -> int:

@@ -32,6 +32,7 @@ from horovod_tpu.models.olmo_hybrid import OlmoHybridLM
 from horovod_tpu.models.phi4flash import Phi4FlashLM
 from horovod_tpu.models.qwen3next import Qwen3NextLM
 from horovod_tpu.models.resnet import ResNet50
+from horovod_tpu.models.smallthinker import SmallThinkerLM
 from horovod_tpu.models.transformer import (
     TransformerConfig, TransformerLM, lm_loss_from_hidden,
 )
@@ -330,6 +331,25 @@ def olmo_hybrid_train_step(model: OlmoHybridLM, tx, mesh):
     donated, every block recomputed with its kernels' outputs kept
     (``olmo_hybrid.RematBlock``)."""
     return _loss_train_step(olmo_hybrid_loss_fn(model), tx, mesh)
+
+
+def smallthinker_loss_fn(model: SmallThinkerLM):
+    """``(params, tokens) -> (loss, counts)``: next-token cross-entropy
+    through the chunked head on the untied ``lm_head``; ``counts`` are
+    the layers' loads ([layers, experts_held + 2])."""
+    def loss_fn(p, t):
+        hidden, counts = model.apply({"params": p}, t)
+        return lm_loss_from_hidden(hidden, p["lm_head"]["kernel"], t), counts
+    return loss_fn
+
+
+def smallthinker_train_step(model: SmallThinkerLM, tx, mesh):
+    """The local-global sparse decoder's step, ``glm_moe_train_step``'s
+    shape: ``(params, opt_state, tokens) -> (params, opt_state, loss,
+    counts)``, state donated, every block recomputed with its kernels'
+    outputs kept (``smallthinker.RematBlock``), the counts for
+    :class:`MoeLoadFeed`."""
+    return _counted_train_step(smallthinker_loss_fn(model), tx, mesh)
 
 
 class MoeLoadFeed:

@@ -786,17 +786,44 @@ def _shapes_ok(seq_q, seq_k, block_q, block_k):
 # 1024x1024 does not fit VMEM (the dk/dv kernel). The default pair holds
 # at 192 / 128: no rung changed; 12.6 TFLOP of needed scores in 122.5 ms
 # is 52% of the chip's peak.
+#
+# Measured at D=128 under a window with whole tiles between its two
+# diagonals on v5e silicon (PR 49: B2, 28 query heads over 4 key-value
+# heads, S16384, a window of 4096, the windowed layers of
+# models/smallthinker.py; one call's forward, and forward + backward
+# less that forward, by the host's clock, ms; 256x256 sub-tiles):
+#   1024x1024  15.81 + 40.70 =  56.51
+#   512x1024   17.25 + 43.97 =  61.21   (the causal ladder's pick)
+#   1024x512   27.19 + 47.10 =  74.29
+#   512x2048   23.40 + 53.85 =  77.25
+#   512x512    27.17 + 50.13 =  77.30
+#   256x1024   22.18 + 55.47 =  77.65
+#   256x2048   24.95 + 57.78 =  82.73
+#   2048x512   29.84 + 61.19 =  91.03
+#   256x512    31.95 + 64.92 =  96.87
+# 1024x2048 does not fit VMEM (the forward). The same heads' causal
+# call at 512x1024 reads 32.89 + 92.82 = 125.72 (512x512 165.75,
+# 1024x512 156.26). A band eight key tiles of 512 deep is paced by its
+# arithmetic (40.05 TFLOP of needed scores a step run at 66% of the
+# MXU's peak in the cell), and still wants the longer q tile: 7.7% less
+# at 1024 rows, where a group of seven's accumulators fit at a head of
+# 128. So a windowed call takes a q tile of 1024 up to D=128
+# (_HEAD_DIM_WINDOW). The same heads' causal call reads 29.93 + 86.31 =
+# 116.24 at 1024x1024 (7.5% under its 512x1024; 256x1024 146.81), but
+# the causal ladder at D=128 is three other cells' too, at equal heads
+# and shorter rows, where 1024x1024 was never read: it stays.
 _BLOCK_Q_LADDER = (512, 256, 128)
 _BLOCK_K_LADDER = (1024, 512, 256, 128)
 _HEAD_DIM_BASE = 256  # the largest D the default ladder was measured at
 _HEAD_DIM_SMALL = 64  # up to here a q tile of 1024 fits, and is faster
+_HEAD_DIM_WINDOW = 128  # the same for a call with a window
 
 
-def _ladders_for(head_dim: int):
+def _ladders_for(head_dim: int, window=None):
     """(q_ladder, k_ladder) scaled to ``head_dim``: the measured
-    512x1024 defaults up to D=256 (a q tile of 1024 on top up to D=64),
-    then each doubling of D halves the leading tiles (floor 128) so
-    per-program VMEM stays level."""
+    512x1024 defaults up to D=256 (a q tile of 1024 on top up to D=64,
+    and under a ``window`` up to D=128), then each doubling of D halves
+    the leading tiles (floor 128) so per-program VMEM stays level."""
     q_top, k_top = _BLOCK_Q_LADDER[0], _BLOCK_K_LADDER[0]
     d = max(1, int(head_dim))
     while d > _HEAD_DIM_BASE and (q_top > 128 or k_top > 128):
@@ -805,7 +832,7 @@ def _ladders_for(head_dim: int):
         d //= 2
     q_ladder = tuple(b for b in _BLOCK_Q_LADDER if b <= q_top)
     k_ladder = tuple(b for b in _BLOCK_K_LADDER if b <= k_top)
-    if head_dim <= _HEAD_DIM_SMALL:
+    if head_dim <= (_HEAD_DIM_SMALL if window is None else _HEAD_DIM_WINDOW):
         q_ladder = (2 * q_ladder[0],) + q_ladder
     return q_ladder, k_ladder
 
@@ -918,10 +945,11 @@ def _check_call(q, k, v, causal, window):
                          f"least the row's own key")
 
 
-def _blocks_for(q, k, block_q, block_k):
+def _blocks_for(q, k, block_q, block_k, window=None):
     """The (q, kv) tile of a call: explicit, or the largest rung of the
-    head size's ladder that divides the sequence."""
-    q_ladder, k_ladder = _ladders_for(q.shape[-1])
+    head size's ladder (a windowed call's, under a ``window``) that
+    divides the sequence."""
+    q_ladder, k_ladder = _ladders_for(q.shape[-1], window)
     return (_auto_block(q.shape[1], q_ladder, block_q),
             _auto_block(k.shape[1], k_ladder, block_k))
 
@@ -940,7 +968,7 @@ def flash_attention_stats(q, k, v, causal: bool = True,
     step)."""
     _check_call(q, k, v, causal, window)
     seq_q, seq_k = q.shape[1], k.shape[1]
-    block_q, block_k = _blocks_for(q, k, block_q, block_k)
+    block_q, block_k = _blocks_for(q, k, block_q, block_k, window)
     if not _shapes_ok(seq_q, seq_k, block_q, block_k):
         raise ValueError(
             f"sequence lengths ({seq_q}, {seq_k}) must be divisible by "
@@ -985,7 +1013,7 @@ def flash_attention_bwd(q, k, v, o, m, l, do, causal: bool = True,
     _check_call(q, k, v, causal, window)
     b, seq_q, h, d = q.shape
     seq_k = k.shape[1]
-    block_q, block_k = _blocks_for(q, k, block_q, block_k)
+    block_q, block_k = _blocks_for(q, k, block_q, block_k, window)
     if not _shapes_ok(seq_q, seq_k, block_q, block_k):
         raise ValueError(
             f"sequence lengths ({seq_q}, {seq_k}) must be divisible by "
@@ -1076,7 +1104,7 @@ def flash_attention(q, k, v, causal: bool = True,
     pallas kernel (verified on v5e silicon)."""
     _check_call(q, k, v, causal, window)
     seq_q, seq_k = q.shape[1], k.shape[1]
-    bq, bk = _blocks_for(q, k, block_q, block_k)
+    bq, bk = _blocks_for(q, k, block_q, block_k, window)
     if not _shapes_ok(seq_q, seq_k, bq, bk):
         if not causal:
             raise ValueError("non-causal path requires block-divisible "

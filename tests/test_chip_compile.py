@@ -289,6 +289,15 @@ def _lower_latent_flash(chip, mesh4):
             arr(192), arr(192), arr(128))
 
 
+def _lower_smallthinker_flash(chip, mesh4, window):
+    from horovod_tpu.parallel.flash_attention import flash_attention
+    arr = lambda heads: _arr(chip, 1, 16384, heads, 128)
+    return _grads_of_the_sum(
+        lambda q, k, v: flash_attention(q, k, v, causal=True, window=window,
+                                        interpret=False), 3).lower(
+            arr(28), arr(4), arr(4))
+
+
 def _router_config(cell):
     from horovod_tpu.models import glm_moe, lfm2, ling3flash, qwen3next
     return {"qwen3next-injit": qwen3next.Qwen3NextConfig,
@@ -461,6 +470,20 @@ def test_the_flash_kernels_compile_at_a_score_head_of_192_over_a_value_head_of_1
     kernel there)."""
     assert _kernel_calls(compiled("latent_flash")) == 3
     assert _top(192) == (512, 1024)
+
+
+def test_the_flash_kernels_compile_at_a_window_of_4096_and_seven_heads_a_group(
+        compiled):
+    """A windowed layer of ``smallthinker-injit-1chip``: 28 query heads
+    over 4 key-value heads of 128, S 16,384, a window of 4,096 at a
+    windowed call's 1024x1024 tiles (the causal ladder's pair at this
+    head is 512x1024): whole tiles between the window's two diagonals
+    (``_over_tile``'s unmasked branch under a window, which phi4flash's
+    window of 512 never takes) and a group of seven's accumulators in
+    the dk/dv kernel's VMEM."""
+    assert _kernel_calls(compiled("smallthinker_flash", 4096)) == 3
+    assert _top(128) == (512, 1024)
+    assert _ladders_for(128, 4096)[0][0] == 1024 == _ladders_for(64)[0][0]
 
 
 @pytest.mark.parametrize("cell,d,e,k,n_group,topk_group,scoring", _ROUTERS,
@@ -692,7 +715,9 @@ LOWERINGS = {
     "delta_layer_off_the_lane_tile": _lower_delta_layer_off_the_lane_tile,
     "kimi_delta_attention": _lower_kimi_delta_attention,
     "prologue": _lower_prologue, "epilogue": _lower_epilogue,
-    "latent_flash": _lower_latent_flash, "router": _lower_router,
+    "latent_flash": _lower_latent_flash,
+    "smallthinker_flash": _lower_smallthinker_flash,
+    "router": _lower_router,
     "lm_step": _lower_lm_step,
     "other_step": _lower_other_step,
 }
@@ -711,7 +736,8 @@ PROGRAMS = list(dict.fromkeys([
     ("delta_layer_off_the_lane_tile",), ("kimi_delta_attention",),
     *(("prologue", *case[1:]) for case in _PROLOGUE_SHAPES),
     *(("epilogue", *case[1:]) for case in _EPILOGUE_SHAPES),
-    ("latent_flash",), *(("router", *case[1:]) for case in _ROUTERS),
+    ("latent_flash",), ("smallthinker_flash", 4096),
+    *(("router", *case[1:]) for case in _ROUTERS),
     ("lm_step", 2, True), ("lm_step", 1, False),
     ("other_step", _resnet_step), ("other_step", _glm_moe_step),
 ]))

@@ -762,3 +762,88 @@ def test_the_older_models_parameter_trees_are_what_they_were(scoring):
         lambda a: tuple(a.shape),
         family.program_shapes(family.build_model(sz), sz))), sort_keys=True)
     assert hashlib.sha256(tree.encode()).hexdigest()[:16] == want
+
+
+# -- a router that reads other rows than the experts (PR 49) ----------------
+
+def _value_and_grads(fn, *args):
+    weight = jax.random.normal(jax.random.key(4), args[-1].shape)
+    return jax.jit(jax.value_and_grad(
+        lambda *a: jnp.sum(fn(*a)[0] * weight),
+        argnums=tuple(range(len(args)))))(*args)
+
+
+@pytest.mark.parametrize("scoring", ["softmax", "no_shared"])
+def test_a_router_that_reads_the_layers_own_rows_is_the_layer_as_it_was(
+        scoring):
+    """``router_input`` equal to the rows, as rows or as the
+    ``Routing`` made of them ahead with the first tier's plan, and the
+    default activation: value and every gradient to the bit of the
+    call with neither (whose lowered step at the four cells' rehearsal
+    sizes is the parent's, byte for byte: CHANGES.md, PR 49)."""
+    cfg = config(scoring)
+    assert cfg.expert_activation == "silu"
+    layer, p, x = layer_and_params(cfg)
+    plain = _value_and_grads(
+        lambda p, x: layer.apply({"params": p}, x), p, x)
+    same_rows = _value_and_grads(
+        lambda p, x: layer.apply({"params": p}, x, x), p, x)
+
+    def ahead(p, x):
+        def routed_first(module, x):
+            routing = module.route(x, plan_ahead=True)
+            assert routing.plan is not None
+            return module(x, routing)
+        return nn.apply(routed_first, layer)({"params": p}, x)
+
+    for other in (same_rows, _value_and_grads(ahead, p, x)):
+        for a, b in zip(jax.tree_util.tree_leaves(plain),
+                        jax.tree_util.tree_leaves(other)):
+            np.testing.assert_array_equal(a, b)
+
+
+def test_the_router_reads_its_own_rows_and_relu_gates_the_experts():
+    """SmallThinker's layer: the choice and the weights from ``r``, the
+    experts ``W_down (relu(W_gate u) * (W_up u))`` on ``u``; the
+    router's gradient flows into ``r`` and the experts' into ``u``, and
+    the plan made ahead changes nothing."""
+    cfg = dataclasses.replace(config("no_shared"), scoring="softmax",
+                              topk_weight_eps=0.0, expert_activation="relu")
+    layer, p, u = layer_and_params(cfg)
+    r = jax.random.normal(jax.random.key(9), u.shape)
+
+    def by_hand(p, r, u):
+        rf, uf = r.reshape(-1, D), u.reshape(-1, D)
+        top, chosen = jax.lax.top_k(rf @ p["router"]["kernel"], K)
+        w = jnp.sum(jax.nn.one_hot(chosen, EXPERTS)
+                    * jax.nn.softmax(top, -1)[..., None], axis=1)
+        e = p["experts"]
+        return sum(w[:, OFFSET + j, None] * (
+            (jax.nn.relu(uf @ e["gate"][j]) * (uf @ e["up"][j]))
+            @ e["down"][j]) for j in range(HELD)).reshape(u.shape), None
+
+    def ahead(p, r, u):
+        return nn.apply(
+            lambda module, r, u: module(u, module.route(r, plan_ahead=True)),
+            layer)({"params": p}, r, u)
+
+    want = _value_and_grads(by_hand, p, r, u)
+    got = _value_and_grads(
+        lambda p, r, u: layer.apply({"params": p}, u, r), p, r, u)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(_value_and_grads(ahead, p, r,
+                                                               u))):
+        np.testing.assert_array_equal(a, b)
+    d_r, d_u = got[1][1], got[1][2]
+    assert float(jnp.abs(d_r).max()) > 0 and float(jnp.abs(d_u).max()) > 0
+    # SiLU in ReLU's place is another layer
+    silu = layer.clone(cfg=dataclasses.replace(cfg, expert_activation="silu"))
+    other = jax.jit(lambda p, r, u: silu.apply({"params": p}, u, r)[0])(
+        p, r, u)
+    assert float(jnp.abs(other - jax.jit(by_hand)(p, r, u)[0]).max()) > 1e-3
+    with pytest.raises(ValueError, match="expert_activation"):
+        jax.eval_shape(glm_moe.ExpertLayer(dataclasses.replace(
+            cfg, expert_activation="gelu")).apply, {"params": p}, u)

@@ -44,6 +44,13 @@ _CASES = [
     ("phi4flash-full", 2, 128, 128, 4, 2, 16, 32, None, 64, 128, 0, 0),
     ("grouped-not-causal", 1, 128, 128, 4, 2, 16, 32, "full", 64, 64,
      0, 0),
+    # the windowed layers of smallthinker, small: whole tiles between the
+    # window's two diagonals (``_over_tile``'s unmasked branch under a
+    # window) and seven query heads a key-value head
+    ("smallthinker-window-7-over-1", 1, 256, 256, 7, 1, 16, 16, 128, 32,
+     32, 0, 0),
+    ("smallthinker-window-14-over-2", 1, 256, 256, 14, 2, 16, 16, 160, 32,
+     64, 0, 0),
 ]
 
 
@@ -93,6 +100,39 @@ def test_default_call_builds_what_it_built():
     assert fa._kv_row(1)(5) == 5 and fa._kv_row(2)(5) == 2
     assert all(len(b) == 4 for d in (-512, 0, 512, 1024, 37)
                for b in fa._tile_blocks(d, 512, 1024, 256, 256))
+
+
+def test_a_window_of_4096_has_whole_tiles_between_its_diagonals():
+    """At smallthinker's shape (S 16384, window 4096, a head of 128: a
+    windowed call's 1024x1024 tiles, where the causal call keeps
+    512x1024) a q tile walks 6 of its 16 kv tiles and a kv tile 6 of
+    the 16 q tiles; two positions on the tiles' grid are crossed by a
+    diagonal and have a body of their own, and the three between them
+    are whole tiles that run unmasked."""
+    q = jnp.zeros((1, 16384, 28, 128))
+    assert fa._blocks_for(q, q[:, :, :4], None, None) == (512, 1024)
+    assert fa._blocks_for(q, q[:, :, :4], None, None, 4096) == (1024, 1024)
+    assert fa._blocks_for(q, q[:, :, :4], 512, None, 4096) == (512, 1024)
+    # a head of 256 keeps the causal tile under a window too
+    wide = jnp.zeros((1, 16384, 2, 256))
+    assert fa._blocks_for(wide, wide, None, None, 4096) == (512, 1024)
+    offs = jnp.zeros((2,), jnp.int32)
+    steps, tile, fetch = fa._streamed_tiles(4096, 1024, 1024, 16, True)
+    assert steps == 6
+    assert [int(tile(10, s, offs)) for s in range(6)] == [6, 7, 8, 9, 10, 11]
+    assert [int(fetch(10, s, offs)) for s in range(6)] == [6, 7, 8, 9, 10, 10]
+    steps, tile, fetch = fa._streamed_tiles(4096, 1024, 1024, 16, False)
+    assert steps == 6
+    assert [int(tile(3, s, offs)) for s in range(6)] == [3, 4, 5, 6, 7, 8]
+    assert [int(fetch(3, s, offs)) for s in range(6)] == [3, 4, 5, 6, 7, 7]
+    assert fa._window_positions(1024, 1024, 4096) == (0, 4096)
+    whole = [d for d in range(0, 4096 + 1024, 1024)
+             if fa._tile_blocks(d, 1024, 1024, 256, 256, 4096)
+             == [(0, 1024, 1024, 1024)]]
+    assert whole == [1024, 2048, 3072]
+    # phi4flash's window fits no tile between its diagonals
+    assert not any(fa._tile_blocks(d, 1024, 1024, 256, 256, 512)
+                   == [(0, 1024, 1024, 1024)] for d in range(0, 2048, 1024))
 
 
 def test_windowed_grid_walks_only_the_band():
@@ -166,11 +206,16 @@ _COUNT_CASES = [
     ("shard-behind", 256, 256, 128, 128, (64, 64), 160, 512, 256),
     ("shard-out-of-reach", 256, 256, 128, 128, (64, 64), 64, 1024, 256),
 ]
+# counted against the mask, too long for the interpreter's kernels
+_WIDE_COUNT_CASES = [
+    ("smallthinker-tiles", 8192, 8192, 1024, 1024, (256, 256), 4096, 0, 0),
+]
 _COUNT_ARGS = "seq_q,seq_k,block_q,block_k,sub,window,q_off,k_off"
 
 
-@pytest.mark.parametrize(_COUNT_ARGS, [c[1:] for c in _COUNT_CASES],
-                         ids=[c[0] for c in _COUNT_CASES])
+@pytest.mark.parametrize(
+    _COUNT_ARGS, [c[1:] for c in _COUNT_CASES + _WIDE_COUNT_CASES],
+    ids=[c[0] for c in _COUNT_CASES + _WIDE_COUNT_CASES])
 def test_window_counts_match_the_mask(seq_q, seq_k, block_q, block_k, sub,
                                       window, q_off, k_off):
     assert fa.causal_subtile_counts(
