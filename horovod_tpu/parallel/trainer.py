@@ -81,8 +81,10 @@ class Trainer:
             else:
                 batch_spec = P(config.data_axis)
         self.batch_sharding = jaxshim.named_sharding(mesh, batch_spec)
+        self._replicated = jaxshim.named_sharding(mesh, P())
         self._step = None
         self._param_shardings = None
+        self._state_shardings = None
 
     # ------------------------------------------------------------------
     def init(self, rng, sample_batch):
@@ -113,8 +115,15 @@ class Trainer:
             self.tx, params, self._param_shardings, self.mesh)
         opt_state = jax.jit(self.tx.init,
                             out_shardings=opt_shardings)(params)
+        # The counter is committed to the mesh like the rest of the
+        # state, and the step returns the state under the shardings it
+        # took (`step_fn`): its second call finds the first's executable.
+        self._state_shardings = {
+            "params": self._param_shardings, "opt_state": opt_shardings,
+            "step": self._replicated}
         return {"params": params, "opt_state": opt_state,
-                "step": jnp.zeros((), jnp.int32)}
+                "step": jax.device_put(jnp.zeros((), jnp.int32),
+                                       self._replicated)}
 
     # ------------------------------------------------------------------
     def step_fn(self):
@@ -134,7 +143,8 @@ class Trainer:
                     "step": state["step"] + 1}, loss
 
         donate = (0,) if self.config.donate_state else ()
-        self._step = jax.jit(step, donate_argnums=donate)
+        self._step = jax.jit(step, donate_argnums=donate,
+                             out_shardings=(self._state_shardings, None))
         return self._step
 
     def train_step(self, state, batch):

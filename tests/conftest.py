@@ -102,6 +102,8 @@ _MP_MODULES = {
 
 
 def pytest_configure(config):
+    global _config
+    _config = config
     # Build the native core ONCE up front (the zero-copy data plane
     # rides it): with a compiler present a broken build must fail the
     # tier LOUDLY — a silent skip would unhook every native test (and
@@ -133,6 +135,11 @@ def pytest_configure(config):
         "runs, big example smokes) excluded from the budgeted tier-1 "
         "sweep (-m 'not slow'); the full matrix (plain `pytest "
         "tests/`) still runs them")
+    config.addinivalue_line(
+        "markers", "interpreter_of_its_own: a file of in-process tests "
+        "that needs nothing of the session's process; in a session with "
+        "other files its tests run beside them, in an interpreter of "
+        "their own (tests/ahead.py)")
     config.addinivalue_line(
         "markers", "time_limit(seconds): this test's own limit in place "
         f"of the default {TEST_LIMIT_S:g} s, for a test whose spawned "
@@ -240,7 +247,8 @@ def _frameworks_bytecode():
             if (spec := importlib.util.find_spec(name)) and spec.origin
             and not os.path.exists(importlib.util.cache_from_source(
                 spec.origin))]
-    if not cold:
+    from tests import ahead
+    if not cold or ahead.REPORTS_TO in os.environ:  # the session's to do
         yield
         return
     proc = subprocess.Popen(
@@ -250,6 +258,108 @@ def _frameworks_bytecode():
     yield
     proc.kill()
     proc.wait()
+
+
+# -- a file's whole programs compile beside its first tests ------------------
+
+@pytest.fixture(scope="module", autouse=True)
+def _programs_from_the_files_start(request):
+    """A file's ``programs`` (a module fixture that lowers its whole
+    programs and hands them to ``tests/compiled.py``'s pool) is made at
+    the file's start if a selected test of the file takes it: the pool
+    then compiles beside the tests that stand before that one."""
+    if any(item.module is request.module and "programs" in item.fixturenames
+           for item in request.session.items):
+        request.getfixturevalue("programs")
+
+
+# -- interpreters of their own run beside the in-process tests ---------------
+
+# A file of in-process tests that needs nothing of this process, and
+# that nothing here needs, says so (``pytestmark``:
+# ``interpreter_of_its_own``): a kernel's cases in interpreter mode, a
+# model against its references, meshes of virtual devices, the analyzer
+# over the tree. Each is Python that traces for most of its seconds, on
+# one core of eight. In a session with other files to run, such a
+# file's selected tests run in an interpreter of their own from the
+# session's start (``tests/ahead.py``), and each is reported here, in
+# its turn, by the reports that interpreter wrote for it. Alone
+# (``pytest tests/test_kda.py``), under ``-s`` or under ``--pdb`` the
+# file runs here as ever.
+_file_runs: dict = {}       # a test file's path -> its ``ahead.FileRun``
+_config = None
+
+
+def _file_of(item) -> str:
+    return item.nodeid.split("::", 1)[0]
+
+
+@pytest.hookimpl(wrapper=True, tryfirst=True)
+def pytest_runtestloop(session):
+    """What a selected test would only wait for starts now, two at a
+    time, in the files' order (``tests/ahead.py``): the files that run
+    in an interpreter of their own, the examples' smoke runs and the
+    plain worlds of ranks (a file that has such runs has a
+    ``start_ahead(its selected tests)``)."""
+    from tests import ahead
+    option = session.config.option
+    if ahead.REPORTS_TO in os.environ or option.collectonly:
+        return (yield)      # another session's file, or no run at all
+    by_file = defaultdict(list)
+    for item in session.items:
+        by_file[_file_of(item)].append(item)
+    own = len(by_file) > 1 and option.capture != "no" and not option.usepdb
+    for path, items in by_file.items():
+        if own and items[0].get_closest_marker("interpreter_of_its_own"):
+            run = _file_runs[path] = ahead.FileRun(
+                session.config, path, [item.nodeid for item in items])
+            ahead.start(("file", path), run.run)
+        elif hasattr(items[0].module, "start_ahead"):
+            items[0].module.start_ahead(items)
+    try:
+        return (yield)
+    finally:
+        ahead.stop()
+        for run in _file_runs.values():
+            run.forget()
+
+
+@pytest.hookimpl(tryfirst=True)
+def pytest_runtest_protocol(item, nextitem):
+    """A test of a file that runs in an interpreter of its own: the
+    reports that interpreter wrote for it, in this test's turn; its
+    seconds here are those this process waited for them. Any other
+    test outside ``tests/chip_bench`` first waits until nothing started
+    ahead still runs: what is started ahead has the minutes of
+    ``tests/chip_bench`` (in-process, no clock in its assertions) to run
+    beside, and a world whose assertions read the clock runs alone, as
+    ever."""
+    from tests import ahead
+    run = _file_runs.get(_file_of(item))
+    if run is None:
+        if not item.nodeid.startswith("tests/chip_bench/"):
+            ahead.wait()
+        return None
+    item.ihook.pytest_runtest_logstart(nodeid=item.nodeid,
+                                       location=item.location)
+    t0 = time.monotonic()
+    reports = run.reports(item)
+    for report in reports:
+        report.duration = 0.0
+    reports[-1].duration = time.monotonic() - t0
+    for report in reports:
+        item.ihook.pytest_runtest_logreport(report=report)
+    item.ihook.pytest_runtest_logfinish(nodeid=item.nodeid,
+                                        location=item.location)
+    return True
+
+
+def pytest_runtest_logreport(report):
+    """In an interpreter that runs a file for another session: every
+    report goes where that session reads it."""
+    from tests import ahead
+    if ahead.REPORTS_TO in os.environ:
+        ahead.write_report(_config, report)
 
 
 # -- the process ends when pytest does --------------------------------------
@@ -311,6 +421,12 @@ def pytest_terminal_summary(terminalreporter):
     terminalreporter.section("seconds by file (set-up + call + teardown)")
     for name, s in largest_first(by_file):
         terminalreporter.write_line(f"{s:9.2f} s  {name}")
+    if _file_runs:
+        terminalreporter.section(
+            "seconds by file, in an interpreter of its own beside these")
+        for name, s in largest_first({path: run.seconds or 0.0 for path, run
+                                      in _file_runs.items()}):
+            terminalreporter.write_line(f"{s:9.2f} s  {name}")
     terminalreporter.section("the twenty longest tests")
     for name, s in largest_first(by_test)[:20]:
         terminalreporter.write_line(f"{s:9.2f} s  {name}")

@@ -17,12 +17,12 @@ assert on is the TPU compiler's own work, so this file keeps it whole.
 The TPU's compiler takes a core or two a program and the file's
 programs are independent of one another, so every test asks one fixture
 (``compiled``) for its executable by the name of its lowering and its
-arguments, and a small pool of threads compiles the programs of the
-tests that follow (``PROGRAMS``, in the tests' order) meanwhile: no
-program is built twice, and none waits in a row behind the others.
+arguments, and the suite's pool of threads (``tests/compiled.py``)
+compiles the programs of the tests that follow (``PROGRAMS``, in the
+tests' order) meanwhile: no program is built twice, and none waits in a
+row behind the others.
 """
 
-import concurrent.futures
 import contextlib
 import math
 import os
@@ -39,7 +39,10 @@ from horovod_tpu.parallel.flash_attention import (  # noqa: E402
     _flash_bhsd, _flash_bwd_bhsd, _ladders_for, _subtile_for,
 )
 
-pytestmark = [pytest.mark.fast, pytest.mark.usefixtures("whole_compiler")]
+from .compiled import ahead_of  # noqa: E402
+
+pytestmark = [pytest.mark.fast, pytest.mark.usefixtures("whole_compiler"),
+              pytest.mark.interpreter_of_its_own]
 
 
 def test_the_compiler_is_whole_here():
@@ -749,28 +752,12 @@ def compiled(chip, mesh4):
     """``compiled(lowering, *arguments)``: the executable of
     ``LOWERINGS[lowering](chip, mesh4, *arguments)`` for the described
     chip(s), built once whoever asks. Lowering is Python and stays on
-    this thread; ``Lowered.compile()`` releases the interpreter, so a
-    pool of threads compiles the asked program and the next ``AHEAD``
-    of ``PROGRAMS`` while the tests before them assert."""
-    workers = max(2, min(4, len(os.sched_getaffinity(0)) // 2))
-    futures = {}
-    with concurrent.futures.ThreadPoolExecutor(workers) as pool:
-        def start(program):
-            try:
-                lowered = LOWERINGS[program[0]](chip, mesh4, *program[1:])
-            except Exception as refused:    # its own test's to report
-                future = concurrent.futures.Future()
-                future.set_exception(refused)
-                return future
-            return pool.submit(lowered.compile)
-
-        def ask(*program):
-            at = PROGRAMS.index(program) if program in PROGRAMS \
-                else len(PROGRAMS)
-            for ahead in (program, *PROGRAMS[at:at + AHEAD]):
-                if ahead not in futures:
-                    futures[ahead] = start(ahead)
-            return futures[program].result()
-        yield ask
-        for future in futures.values():
-            future.cancel()
+    this thread; ``Lowered.compile()`` releases the interpreter, so the
+    suite's pool of threads (``tests/compiled.py``) compiles the asked
+    program and the next ``AHEAD`` of ``PROGRAMS`` while the tests
+    before them assert."""
+    programs = ahead_of(
+        lambda program: {"compiled": LOWERINGS[program[0]](
+            chip, mesh4, *program[1:])}, PROGRAMS, ahead=AHEAD)
+    yield lambda *program: programs(program)["compiled"]
+    programs.cancel()

@@ -31,7 +31,8 @@ from horovod_tpu.models import glm_moe
 from horovod_tpu.parallel import delta_epilogue as de
 from horovod_tpu.parallel.gated_delta import lay_heads, take_heads
 
-pytestmark = [pytest.mark.fast, pytest.mark.time_limit(120)]
+pytestmark = [pytest.mark.fast, pytest.mark.time_limit(120),
+              pytest.mark.interpreter_of_its_own]
 
 EPS = 1e-6
 ACTIVATIONS = {"sigmoid": jax.nn.sigmoid, "silu": nn.silu}

@@ -18,6 +18,7 @@ models' parameter trees as they were. (PR 40's tests: 3 s cold; PR
 41's grouped cases: 4 s; PR 47's router cases: 4 s.)"""
 
 import dataclasses
+import functools
 import hashlib
 import json
 
@@ -29,7 +30,8 @@ import pytest
 
 from horovod_tpu.models import glm_moe, lfm2, qwen3next
 
-pytestmark = [pytest.mark.fast, pytest.mark.time_limit(120)]
+pytestmark = [pytest.mark.fast, pytest.mark.time_limit(120),
+              pytest.mark.interpreter_of_its_own]
 
 D, WIDTH, EXPERTS, HELD, OFFSET, K = 32, 16, 16, 4, 8, 4
 TOL = dict(rtol=3e-5, atol=3e-6)
@@ -92,10 +94,21 @@ def dense_masked_sum(cfg, p, x):
     return y.reshape(x.shape)
 
 
-def layer_and_params(cfg):
-    layer = glm_moe.ExpertLayer(cfg)
+@functools.cache
+def _initialised(cfg):
+    """A configuration's leaves as initialised, made once for the
+    cases that share it (``init`` traces the whole layer)."""
     x = jax.random.normal(jax.random.key(1), (2, 24, D))
-    return layer, jax.jit(layer.init)(jax.random.key(2), x)["params"], x
+    return jax.jit(glm_moe.ExpertLayer(cfg).init)(
+        jax.random.key(2), x)["params"], x
+
+
+def layer_and_params(cfg):
+    """The layer, a tree of its own of the leaves as initialised (a
+    case may put leaves of its own into it), and the rows."""
+    p, x = _initialised(cfg)
+    return glm_moe.ExpertLayer(cfg), jax.tree_util.tree_map(
+        lambda leaf: leaf, p), x
 
 
 def dense(cfg, p, x):
@@ -175,7 +188,8 @@ def test_a_buffer_an_eighth_of_the_assignments_finds_its_tokens(forced):
     assert (int(counts[:2].sum()) > cap) == forced
 
 
-def routed_choices(scoring):
+@functools.cache
+def _routed_choices(scoring):
     """[48, K] choices of the layer's own router under ``scoring`` with
     six of sixteen experts held, then bent: token 5 chooses four held
     experts and nothing else, and nobody chooses the last held one."""
@@ -190,6 +204,11 @@ def routed_choices(scoring):
         row[row == last] = next(spare)
     chosen[5] = OFFSET + np.arange(K)
     return cfg, chosen
+
+
+def routed_choices(scoring):
+    cfg, chosen = _routed_choices(scoring)
+    return cfg, chosen.copy()
 
 
 @pytest.mark.parametrize("scoring", ["sigmoid", "softmax"])

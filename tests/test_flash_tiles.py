@@ -15,6 +15,8 @@ from horovod_tpu.parallel.flash_attention import (
 
 from .compiled import out_and_vjp
 
+pytestmark = pytest.mark.interpreter_of_its_own
+
 
 def _blocks_for(seq_q, seq_k, head_dim):
     ql, kl = _ladders_for(head_dim)

@@ -18,7 +18,7 @@ from horovod_tpu.parallel import flash_attention as fa  # noqa: E402
 from .compiled import out_and_vjp  # noqa: E402
 from .test_flash_tiles import _counted  # noqa: E402
 
-pytestmark = pytest.mark.fast
+pytestmark = [pytest.mark.fast, pytest.mark.interpreter_of_its_own]
 
 
 def _case(seed, b, sq, sk, h, hkv, d, dv):

@@ -16,7 +16,8 @@ from horovod_tpu.parallel import gated_delta as gd
 
 from .compiled import out_and_vjp
 
-pytestmark = [pytest.mark.fast, pytest.mark.time_limit(120)]
+pytestmark = [pytest.mark.fast, pytest.mark.time_limit(120),
+              pytest.mark.interpreter_of_its_own]
 
 
 def operands(seed, batch, seq, key_heads, value_heads, dk, dv,

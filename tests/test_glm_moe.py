@@ -13,10 +13,12 @@ import numpy as np
 import pytest
 
 from .chip_bench import _paths  # noqa: F401  (makes chipbench importable)
-from .compiled import weights_under
+from .compiled import beside, weights_under
 from chipbench import check, harness, weights
 
 from horovod_tpu.models import glm_moe, train_steps
+
+pytestmark = pytest.mark.interpreter_of_its_own
 
 FAMILY = harness.load_module("families", "glm_moe_lm")
 CONFIG = {
@@ -94,6 +96,16 @@ def tokens():
     return FAMILY.make_batch(SZ, 2)(jax.random.key(5))[0]
 
 
+@pytest.fixture(scope="module")
+def programs(model, params):
+    """The program's loss, counts and gradients, lowered at the file's
+    start and compiled beside the tests of its parts
+    (``tests/compiled.py``)."""
+    return beside(loss_and_grads=jax.jit(jax.value_and_grad(
+        train_steps.glm_moe_loss_fn(model), has_aux=True)).lower(
+            params, tokens()))
+
+
 def test_the_multi_token_module_reads_the_next_token_and_predicts_two_ahead(
         model, params, x):
     t = tokens()
@@ -110,13 +122,13 @@ def test_the_multi_token_module_reads_the_next_token_and_predicts_two_ahead(
         REF["head_loss"](head, want[:, :-2], t[:, 2:]), rtol=1e-5)
 
 
-def test_the_whole_loss_and_its_gradients_are_the_references(model, params):
+def test_the_whole_loss_and_its_gradients_are_the_references(programs,
+                                                             params):
     """The reference's chain hands the embedding on as the second of a
     pair, so that the multi-token module's use of it reaches the
     embedding's gradient."""
     t = tokens()
-    (loss, counts), grads = jax.jit(jax.value_and_grad(
-        train_steps.glm_moe_loss_fn(model), has_aux=True))(params, t)
+    (loss, counts), grads = programs["loss_and_grads"](params, t)
     want_loss, _, want = check.StagedGradient(
         FAMILY.reference_stages(SZ))(params, {}, (t,))
     np.testing.assert_allclose(loss, want_loss, rtol=1e-6)

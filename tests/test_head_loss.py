@@ -13,7 +13,7 @@ jnp = jax.numpy
 from horovod_tpu.compat import jaxshim  # noqa: E402
 from horovod_tpu.models.transformer import lm_loss_from_hidden  # noqa: E402
 
-pytestmark = pytest.mark.fast
+pytestmark = [pytest.mark.fast, pytest.mark.interpreter_of_its_own]
 
 BATCH, WIDTH, VOCAB, CHUNK = 2, 16, 50, 8
 

@@ -20,7 +20,8 @@ from horovod_tpu.parallel import kda
 from .compiled import out_and_vjp
 from .test_gated_delta import kernel_products
 
-pytestmark = [pytest.mark.fast, pytest.mark.time_limit(120)]
+pytestmark = [pytest.mark.fast, pytest.mark.time_limit(120),
+              pytest.mark.interpreter_of_its_own]
 
 
 def operands(seed, batch, seq, heads, dk, dv, lower=-1.0, shift=0.0,

@@ -15,19 +15,19 @@ import os
 import jax
 import jax.numpy as jnp
 import numpy as np
-import optax
 import pytest
 
 from .chip_bench import _paths  # noqa: F401  (makes chipbench importable)
-from .compiled import out_and_vjp, weights_under
+from .compiled import (beside, out_and_vjp, step_on_a_mesh_of_one,
+                       weights_under)
 from chipbench import check, harness, weights
 
 import horovod_tpu.jax as hvd
-from horovod_tpu import spmd
 from horovod_tpu.models import lfm2, train_steps
 from horovod_tpu.parallel import flash_attention as fa
 
-pytestmark = [pytest.mark.fast, pytest.mark.time_limit(120)]
+pytestmark = [pytest.mark.fast, pytest.mark.time_limit(120),
+              pytest.mark.interpreter_of_its_own]
 
 FAMILY = harness.load_module("families", "lfm2_moe_lm")
 D, HEADS, KV, HD, MLP, WIDTH, EXPERTS, HELD, OFFSET, K, VOCAB, SEQ = \
@@ -70,14 +70,29 @@ def params():
         if path[-1].key == "scale" else leaf, p)
 
 
-@pytest.fixture(scope="module")
-def loss_and_grads(model):
-    return jax.jit(jax.value_and_grad(
-        train_steps.lfm2_loss_fn(model), has_aux=True))
-
-
 def tokens():
     return FAMILY.make_batch(SZ, 2)(jax.random.key(5))[0]
+
+
+@pytest.fixture(scope="module")
+def programs(model, params):
+    """The file's whole-model programs, lowered at its start and
+    compiled beside one another and beside the tests before the first
+    that asks (``tests/compiled.py``): the program's loss and
+    gradients, the plain reference's, and the step on the counted path
+    with the state it is to train (``step_state``). The step is a
+    program of its own, not the first plus an update: ``shard_map``
+    over the mesh, the distributed optimizer's exchange, the state
+    donated."""
+    t = tokens()
+    step, state = step_on_a_mesh_of_one(
+        train_steps.lfm2_train_step, model, params, t)
+    return beside(
+        loss_and_grads=jax.jit(jax.value_and_grad(
+            train_steps.lfm2_loss_fn(model), has_aux=True)).lower(
+                params, t),
+        plain=jax.jit(jax.value_and_grad(plain_loss)).lower(params, t),
+        step=step, step_state=state)
 
 
 def flat(tree):
@@ -324,13 +339,13 @@ def test_attention_runs_through_the_flash_kernels_at_the_cells_heads(
 
 
 def test_the_whole_loss_and_its_gradients_are_the_plain_references(
-        loss_and_grads, params):
+        programs, params):
     """Against the reference written in this file, and against the chip
     benchmark's, stage by stage as ``check.py`` calls it: loss, counts
     and every leaf's gradient. The expert bias gets none."""
     t = tokens()
-    (loss, counts), grads = loss_and_grads(params, t)
-    want_loss, want = jax.jit(jax.value_and_grad(plain_loss))(params, t)
+    (loss, counts), grads = programs["loss_and_grads"](params, t)
+    want_loss, want = programs["plain"](params, t)
     with jax.default_matmul_precision("highest"):
         theirs_loss, _, theirs = check.StagedGradient(
             FAMILY.reference_stages(SZ))(params, {}, (t,))
@@ -352,21 +367,13 @@ def test_the_whole_loss_and_its_gradients_are_the_plain_references(
         assert got[f"layer_{i}/moe/router/kernel"].any()
 
 
-def test_the_step_trains_on_the_counted_path(model, params):
+def test_the_step_trains_on_the_counted_path(programs):
     """``lfm2_train_step``: ``_counted_train_step`` over a mesh of one,
     the state donated, the loss falling, the counts for the feed."""
+    step, (p, o, t) = programs["step"], programs["step_state"]
     hvd.init()
     try:
-        mesh = spmd.create_mesh({"data": 1}, devices=jax.devices()[:1])
-        tx = hvd.DistributedOptimizer(optax.sgd(0.01, momentum=0.9),
-                                      axis="data")
-        step = train_steps.lfm2_train_step(model, tx, mesh)
-        # on the mesh, where the step leaves its state: one program for
-        # every step, not one for the first and one for the rest
-        rep = spmd.replicated_sharding(mesh)
-        p = jax.device_put(jax.tree_util.tree_map(jnp.array, params), rep)
-        o = jax.device_put(tx.init(p), rep)
-        t, losses = jax.device_put(tokens(), spmd.batch_sharding(mesh)), []
+        losses = []
         for _ in range(3):
             p, o, loss, counts = step(p, o, t)
             losses.append(float(loss))

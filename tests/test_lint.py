@@ -33,7 +33,7 @@ import pytest
 from tools.hvdlint import Finding, core, lint_paths
 from tools.hvdlint.core import Project
 
-pytestmark = pytest.mark.lint
+pytestmark = [pytest.mark.lint, pytest.mark.interpreter_of_its_own]
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 

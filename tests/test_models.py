@@ -13,6 +13,8 @@ from horovod_tpu.models import (
 )
 from horovod_tpu.models.transformer import causal_attention, lm_loss
 
+pytestmark = pytest.mark.interpreter_of_its_own
+
 
 def test_mnist_convnet_forward():
     model = MnistConvNet()

@@ -15,6 +15,8 @@ import time
 
 import pytest
 
+from tests import ahead
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
@@ -197,28 +199,53 @@ PLAIN = {2: ["allreduce", "allreduce_fused", "allreduce_multi_dtype",
          4: ["allreduce"]}
 
 
+# tf_broadcast_hook turns eager execution off for its process, and so
+# goes last
+WORLDS = {
+    **{("plain", size): (scenarios, size)
+       for size, scenarios in PLAIN.items()},
+    "torch": (["torch_optimizer", "torch_allreduce_grad", "torch_adam_state",
+               "torch_opt_state_asymmetric"], 2),
+    "tensorflow": (["keras_optimizer", "tf_tape", "tf_allreduce_grad",
+                    "tf_sparse_as_dense", "tfkeras_facade",
+                    "tf_broadcast_hook"], 2)}
+
+
+def start_ahead(items):
+    """The session's start (``tests/conftest.py``): the shared worlds
+    that the selected tests take begin beside the in-process tests
+    (``tests/ahead.py``; their scenarios assert on what they compute,
+    none on the clock). A plain world's size is its test's ``size``, or
+    two; a world nobody started is run by the first test that asks."""
+    for item in items:
+        for fixture in set(item.fixturenames) & {
+                "plain_world", "torch_world", "tensorflow_world"}:
+            name = fixture[:-len("_world")]
+            if name == "plain":
+                params = getattr(item, "callspec", None)
+                name = (name, params.params.get("size", 2) if params else 2)
+            ahead.start(("world", name), run_scenarios, *WORLDS[name])
+
+
+def _world(name):
+    return ahead.take(("world", name), run_scenarios, *WORLDS[name])
+
+
 @pytest.fixture(scope="module")
 def plain_world():
     """``plain_world(size)``: the world of that size with every
     scenario that needs nothing of its own."""
-    return functools.cache(lambda size: run_scenarios(PLAIN[size], size))
+    return functools.cache(lambda size: _world(("plain", size)))
 
 
 @pytest.fixture(scope="module")
 def torch_world():
-    return run_scenarios(
-        ["torch_optimizer", "torch_allreduce_grad", "torch_adam_state",
-         "torch_opt_state_asymmetric"], 2)
+    return _world("torch")
 
 
 @pytest.fixture(scope="module")
 def tensorflow_world():
-    # tf_broadcast_hook turns eager execution off for its process, and
-    # so goes last
-    return run_scenarios(
-        ["keras_optimizer", "tf_tape", "tf_allreduce_grad",
-         "tf_sparse_as_dense", "tfkeras_facade", "tf_broadcast_hook"],
-        2)
+    return _world("tensorflow")
 
 
 @pytest.mark.parametrize("size", [2, 4])

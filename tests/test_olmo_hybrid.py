@@ -14,18 +14,18 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
-import optax
 import pytest
 
 import horovod_tpu.jax as hvd
-from horovod_tpu import spmd
 
 from .chip_bench import _paths  # noqa: F401  (makes chipbench importable)
+from .compiled import beside, step_on_a_mesh_of_one
 from chipbench import check, harness, weights
 
 from horovod_tpu.models import olmo_hybrid, qwen3next, train_steps
 
-pytestmark = [pytest.mark.fast, pytest.mark.time_limit(170)]
+pytestmark = [pytest.mark.fast, pytest.mark.time_limit(170),
+              pytest.mark.interpreter_of_its_own]
 
 FAMILY = harness.load_module("families", "olmo_hybrid_lm")
 KEPT = [2, 3]
@@ -66,11 +66,17 @@ def reference(params, tokens):
 
 
 @pytest.fixture(scope="module")
-def laid_out(params, tokens):
-    """The float32 program with its delta rule's heads laid out to
-    ``LANES`` (the interpreter would take them as they come): its loss
-    and gradients on the seeded weights, what the rule was told, and
-    the compiled function for further parameters."""
+def programs(params, tokens):
+    """The file's two whole-model programs, lowered at its start and
+    compiled beside one another and beside the reference's stages
+    (``tests/compiled.py``): the float32 program's loss and gradients
+    with its delta rule's heads laid out to ``LANES`` (the interpreter
+    would take them as they come; ``told``: what the rule was told as
+    it was traced), and the step on the counted path in bfloat16 with
+    the state it is to train (``step_state``). The step is a program
+    of its own, not the first plus an update: bfloat16 activations,
+    ``shard_map`` over the mesh, the distributed optimizer's exchange,
+    the state donated."""
     told = []
     rule = qwen3next.gated_delta_rule
 
@@ -84,8 +90,21 @@ def laid_out(params, tokens):
     with pytest.MonkeyPatch.context() as patch:
         patch.setattr(qwen3next, "head_lanes", lambda: LANES)
         patch.setattr(qwen3next, "gated_delta_rule", telling)
-        loss, grads = fn(params, tokens)
-    return float(loss), grads, told, fn
+        laid_out = fn.lower(params, tokens)
+    step, state = step_on_a_mesh_of_one(
+        train_steps.olmo_hybrid_train_step, FAMILY.build_model(SZ), params,
+        tokens)
+    return beside(laid_out=laid_out, told=told, step=step, step_state=state)
+
+
+@pytest.fixture(scope="module")
+def laid_out(programs, params, tokens):
+    """The laid-out program's loss and gradients on the seeded weights,
+    what the rule was told, and the compiled function for further
+    parameters."""
+    fn = programs["laid_out"]
+    loss, grads = fn(params, tokens)
+    return float(loss), grads, programs["told"], fn
 
 
 def flat(tree):
@@ -170,7 +189,7 @@ def test_full_attention_carries_no_positional_signal(params):
                                rtol=3e-5, atol=3e-6)
 
 
-def test_the_step_trains_on_the_counted_path_in_bfloat16(params, tokens,
+def test_the_step_trains_on_the_counted_path_in_bfloat16(programs, params,
                                                          reference):
     """``olmo_hybrid_train_step``: ``_loss_train_step`` over a mesh of
     one, bfloat16 activations, the state donated, the loss falling; its
@@ -178,19 +197,10 @@ def test_the_step_trains_on_the_counted_path_in_bfloat16(params, tokens,
     at zero) within bfloat16's reach of the float32 reference, leaf by
     leaf."""
     want_loss, want = reference
+    step, (p, o, t) = programs["step"], programs["step_state"]
     hvd.init()
     try:
-        mesh = spmd.create_mesh({"data": 1}, devices=jax.devices()[:1])
-        tx = hvd.DistributedOptimizer(optax.sgd(0.01, momentum=0.9),
-                                      axis="data")
-        step = train_steps.olmo_hybrid_train_step(
-            FAMILY.build_model(SZ), tx, mesh)
-        # on the mesh, where the step leaves its state: one program for
-        # every step, not one for the first and one for the rest
-        rep = spmd.replicated_sharding(mesh)
-        p = jax.device_put(jax.tree_util.tree_map(jnp.array, params), rep)
-        o = jax.device_put(tx.init(p), rep)
-        t, losses = jax.device_put(tokens, spmd.batch_sharding(mesh)), []
+        losses = []
         for i in range(3):
             p, o, loss = step(p, o, t)
             losses.append(float(loss))

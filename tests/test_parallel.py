@@ -20,6 +20,8 @@ from horovod_tpu.parallel import (
     ring_attention, transformer_tp_rules,
 )
 
+pytestmark = pytest.mark.interpreter_of_its_own
+
 
 def test_ring_attention_matches_reference():
     """Sequence sharded over 4 devices must reproduce single-device
@@ -96,6 +98,26 @@ def test_trainer_dp_tp_step_runs_and_improves():
         state, loss = trainer.train_step(state, batch)
         losses.append(float(loss))
     assert losses[-1] < losses[0]
+
+
+def test_a_trainers_step_compiles_once():
+    """Two calls of the step are one executable: ``init`` commits the
+    whole state to the mesh, the counter too, and the step hands the
+    state back under the shardings it took."""
+    import optax
+    mesh = spmd.create_mesh({"data": 4, "model": 2})
+    trainer = Trainer(TransformerLM(_tiny_cfg()), mesh, optax.adam(1e-2),
+                      TrainerConfig(data_axis="data", model_axis="model"))
+    batch = {"tokens": np.tile(np.arange(16, dtype=np.int32)[None], (8, 1))}
+    state = trainer.init(jax.random.key(0), batch)
+    took = jax.tree_util.tree_map(lambda a: (a.committed, a.sharding), state)
+    for _ in range(2):
+        state, _ = trainer.train_step(state, batch)
+    assert trainer.step_fn()._cache_size() == 1
+    assert all(committed for committed, _ in jax.tree_util.tree_leaves(
+        took, is_leaf=lambda x: isinstance(x, tuple)))
+    assert jax.tree_util.tree_map(
+        lambda a: (a.committed, a.sharding), state) == took
 
 
 def test_trainer_dp_tp_sp_with_ring_attention():

@@ -18,13 +18,13 @@ import optax
 import pytest
 
 from .chip_bench import _paths  # noqa: F401  (makes chipbench importable)
-from .compiled import momentum_step
+from .compiled import beside, momentum_step
 from chipbench import check, harness, weights
 
 from horovod_tpu.models import phi4flash, train_steps
 from horovod_tpu.parallel import flash_attention as fa
 
-pytestmark = pytest.mark.fast
+pytestmark = [pytest.mark.fast, pytest.mark.interpreter_of_its_own]
 
 FAMILY = harness.load_module("families", "phi4flash_lm")
 CONFIG = {
@@ -60,9 +60,22 @@ def reference():
 
 
 @pytest.fixture(scope="module")
-def loss_and_grads(model):
-    """The program's loss and gradients, compiled once for the file."""
-    return jax.jit(jax.value_and_grad(train_steps.phi4flash_loss_fn(model)))
+def programs(model, params):
+    """The file's two whole-model programs, lowered at its start and
+    compiled beside one another and beside the tests of the blocks
+    (``tests/compiled.py``): the program's loss and gradients, and the
+    gradients of the loss with the embedding untied by hand."""
+    table = params["embed"]["embedding"]
+    return beside(
+        loss_and_grads=jax.jit(jax.value_and_grad(
+            train_steps.phi4flash_loss_fn(model))).lower(params, tokens()),
+        untied=jax.jit(jax.grad(untied_loss(model, params),
+                                argnums=(0, 1))).lower(table, table))
+
+
+@pytest.fixture(scope="module")
+def loss_and_grads(programs):
+    return programs["loss_and_grads"]
 
 
 @pytest.fixture(scope="module")
@@ -218,10 +231,9 @@ def test_three_steps_follow_the_references(loss_and_grads, params,
         check.diff_norms({"params": want_p, "aux": {}}, start), rtol=2e-3)
 
 
-def test_the_tied_embeddings_gradient_is_the_sum_of_both_uses(
-        model, params, loss_and_grads):
-    """Untie by hand: the lookup reads one copy of the table and the
-    head another; the tied gradient is the two copies' sum."""
+def untied_loss(model, params):
+    """The loss with the lookup reading one copy of the table and the
+    head another."""
     t = tokens()
 
     def untied(lookup, head):
@@ -230,9 +242,16 @@ def test_the_tied_embeddings_gradient_is_the_sum_of_both_uses(
         from horovod_tpu.models.transformer import lm_loss_from_hidden
         return lm_loss_from_hidden(hidden, head.T, t)
 
+    return untied
+
+
+def test_the_tied_embeddings_gradient_is_the_sum_of_both_uses(
+        params, programs, loss_and_grads):
+    """Untie by hand: the lookup reads one copy of the table and the
+    head another; the tied gradient is the two copies' sum."""
     table = params["embed"]["embedding"]
-    g_lookup, g_head = jax.jit(jax.grad(untied, argnums=(0, 1)))(table, table)
-    tied = loss_and_grads(params, t)[1]["embed"]["embedding"]
+    g_lookup, g_head = programs["untied"](table, table)
+    tied = loss_and_grads(params, tokens())[1]["embed"]["embedding"]
     assert float(jnp.abs(g_lookup).max()) > 0 < float(jnp.abs(g_head).max())
     np.testing.assert_allclose(tied, g_lookup + g_head, rtol=1e-4, atol=3e-6)
 

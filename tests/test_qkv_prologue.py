@@ -33,7 +33,8 @@ from horovod_tpu.models.phi4flash import CausalDepthwiseConv
 from horovod_tpu.parallel import qkv_prologue as qp
 from horovod_tpu.parallel.gated_delta import lay_heads, take_heads
 
-pytestmark = [pytest.mark.fast, pytest.mark.time_limit(120)]
+pytestmark = [pytest.mark.fast, pytest.mark.time_limit(120),
+              pytest.mark.interpreter_of_its_own]
 
 TAPS, EPS = 4, 1e-6
 

@@ -16,13 +16,14 @@ import optax
 import pytest
 
 from .chip_bench import _paths  # noqa: F401  (makes chipbench importable)
-from .compiled import momentum_step, weights_under
+from .compiled import beside, momentum_step, weights_under
 from chipbench import check, harness, weights
 
 from horovod_tpu.models import glm_moe, qwen3next, train_steps
 from horovod_tpu.parallel import flash_attention as fa
 
-pytestmark = [pytest.mark.fast, pytest.mark.time_limit(170)]
+pytestmark = [pytest.mark.fast, pytest.mark.time_limit(170),
+              pytest.mark.interpreter_of_its_own]
 
 FAMILY = harness.load_module("families", "qwen3next_lm")
 CONFIG = {
@@ -68,11 +69,18 @@ def reference():
 
 
 @pytest.fixture(scope="module")
-def loss_and_grads(model):
-    """The program's loss, counts and gradients, compiled once for the
-    file."""
-    return jax.jit(jax.value_and_grad(
-        train_steps.qwen3next_loss_fn(model), has_aux=True))
+def programs(model, params):
+    """The program's loss, counts and gradients, lowered at the file's
+    start and compiled beside the tests of its parts
+    (``tests/compiled.py``: 11 s of XLA)."""
+    return beside(loss_and_grads=jax.jit(jax.value_and_grad(
+        train_steps.qwen3next_loss_fn(model), has_aux=True)).lower(
+            params, tokens()))
+
+
+@pytest.fixture(scope="module")
+def loss_and_grads(programs):
+    return programs["loss_and_grads"]
 
 
 @pytest.fixture(scope="module")

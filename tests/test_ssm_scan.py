@@ -13,7 +13,7 @@ from horovod_tpu.parallel import ssm_scan as ss  # noqa: E402
 
 from .compiled import out_and_vjp  # noqa: E402
 
-pytestmark = pytest.mark.fast
+pytestmark = [pytest.mark.fast, pytest.mark.interpreter_of_its_own]
 
 
 def _case(seed, bt, seq, channels, states, dtype=jnp.float32):

@@ -2,8 +2,10 @@
 optimiser, one bytecode cache serves every interpreter of a run, every
 test has a time limit, the pytest process keeps SIGTERM's disposition,
 a run prints how much of its clock it used and where the time went,
-and the process ends when pytest does, with the exit code pytest
-chose."""
+the process ends when pytest does, with the exit code pytest chose,
+whole programs compile beside one another on the session's one pool
+(``tests/compiled.py``), and an example's run is started ahead or made
+by the test that asks (``tests/ahead.py``)."""
 
 import os
 import re
@@ -41,16 +43,23 @@ def test_a_spawned_interpreter_shares_the_runs_bytecode_cache():
     assert out.stdout.split() == [sys.pycache_prefix, "False"]
 
 
-def _run_under_the_conftest(path, body, command=("-m", "pytest")):
-    """``body`` as a test file of its own, run under this suite's
-    conftest in a process of its own, read as the driver reads it (both
-    streams through one pipe); the result (``returncode``, ``stdout``,
-    ``after_its_last_line``: the seconds from the last line it wrote to
-    its end) and the seconds of the whole."""
+def _run_under_the_conftest(path, body, command=("-m", "pytest"),
+                            beside=None):
+    """``body`` as a test file of its own (and ``beside``: bodies by
+    name, further files beside it, in that order after it), run under
+    this suite's conftest in a process of its own, read as the driver
+    reads it (both streams through one pipe); the result
+    (``returncode``, ``stdout``, ``after_its_last_line``: the seconds
+    from the last line it wrote to its end) and the seconds of the
+    whole."""
     path.write_text(textwrap.dedent(body))
+    others = [path.with_name(name) for name in beside or ()]
+    for other in others:
+        other.write_text(textwrap.dedent(beside[other.name]))
     t0 = time.monotonic()
     proc = subprocess.Popen(
         [sys.executable, *command, "-p", "tests.conftest", str(path),
+         *map(str, others),
          "-q", "-p", "no:cacheprovider", "-p", "no:xdist", "-p",
          "no:randomly"],
         cwd=REPO, text=True, stdout=subprocess.PIPE,
@@ -225,3 +234,136 @@ def test_a_sigterm_ends_the_run_at_once_inside_a_world_too(tmp_path):
     """)
     assert out.returncode == -signal.SIGTERM, out.stdout
     assert seconds < 30, seconds
+
+
+def test_the_pool_compiles_two_programs_beside_one_another():
+    """``compiled.beside``: each program is lowered where it is asked
+    for (the main thread: tracing is Python), both are in the hands of
+    the session's one pool at once (each ``compile`` waits for the
+    other to have begun), and both executables come back."""
+    import threading
+
+    import jax
+    import jax.numpy as jnp
+
+    from tests import compiled
+
+    lowered_on, compiled_on = [], []
+    both_began = threading.Barrier(2, timeout=60)
+
+    class Noting:
+        def __init__(self, f, *args):
+            lowered_on.append(threading.current_thread())
+            self.lowered = jax.jit(f).lower(*args)
+
+        def compile(self):
+            compiled_on.append(threading.current_thread())
+            both_began.wait()
+            return self.lowered.compile()
+
+    x = jnp.arange(4.0)
+    programs = compiled.beside(twice=Noting(lambda x: 2 * x, x),
+                               squared=Noting(lambda x: x * x, x))
+    assert programs["twice"](x).tolist() == [0, 2, 4, 6]
+    assert programs["squared"](x).tolist() == [0, 1, 4, 9]
+    assert lowered_on == [threading.main_thread()] * 2
+    pool = compiled.pool()
+    assert pool is compiled.pool() and 2 <= pool._max_workers <= 4
+    assert len(set(compiled_on)) == 2 \
+        and set(compiled_on) <= set(pool._threads)
+    assert compiled.start(Noting(lambda x: -x, x).lowered).result()(x) \
+        .tolist() == [-0.0, -1, -2, -3]
+    assert len(pool._threads) <= pool._max_workers
+
+
+def test_an_example_nobody_started_is_run_by_its_own_test(monkeypatch):
+    """``pytest tests/test_examples.py`` starts its examples ahead; a
+    test that finds its example not started (picked by hand, or from a
+    program that imports the file) runs it there and then, and one that
+    was started is not run again."""
+    from tests import ahead, test_examples
+    ran = []
+
+    def run_example(name):
+        ran.append(name)
+        return ["loss 2.0 -> 1.0, said " + name], None
+
+    monkeypatch.setattr(test_examples, "run_example", run_example)
+    monkeypatch.setattr(ahead, "_started", {})  # a session that began none
+    test_examples.test_mxnet_mnist()
+    assert ran == ["mxnet_mnist"]
+    assert test_examples.printed("jax_mnist").endswith("said jax_mnist")
+    assert ran == ["mxnet_mnist", "jax_mnist"]
+    # the session's start: the selected tests' examples, in the table's
+    # order, each once
+    test_examples.start_ahead([
+        types.SimpleNamespace(originalname=name) for name in (
+            "test_zero_fsdp", "test_jax_mnist",
+            "test_every_example_is_covered")])
+    assert list(ahead._started) == [("example", "jax_mnist"),
+                                    ("example", "zero_fsdp")]
+    test_examples.test_jax_mnist()
+    test_examples.test_jax_mnist()
+    assert test_examples.printed("zero_fsdp").endswith("said zero_fsdp")
+    assert sorted(ran[2:]) == ["jax_mnist", "zero_fsdp"]
+
+
+def test_a_file_runs_in_an_interpreter_of_its_own_beside_the_others(
+        tmp_path):
+    """A file marked ``interpreter_of_its_own`` in a session with
+    another file: its tests run in another process, each is reported in
+    its turn as that process reported it (a failure with what it said),
+    and a test the process ended without reporting fails with what the
+    process said; the file beside it runs in the session's process,
+    after what was started ahead. Alone the file runs in the session's
+    process."""
+    own = """
+        import os
+        import pytest
+
+        pytestmark = pytest.mark.interpreter_of_its_own
+
+        def test_passes():
+            with open(os.path.join(os.path.dirname(__file__), "ran"),
+                      "a") as f:
+                f.write(f"own {os.getpid()}\\n")
+
+        def test_fails():
+            assert 1 + 1 == 3, "said by the test that failed"
+
+        @pytest.mark.parametrize("n", [1, 2])
+        def test_cases(n):
+            assert n
+
+        def test_ends_the_interpreter():
+            os._exit(7)
+
+        def test_never_runs():
+            pass
+    """
+    here = """
+        import os
+
+        def test_here():
+            with open(os.path.join(os.path.dirname(__file__), "ran"),
+                      "a") as f:
+                f.write(f"here {os.getpid()}\\n")
+    """
+    out, _ = _run_under_the_conftest(tmp_path / "test_own.py", own,
+                                     beside={"test_here.py": here})
+    lines = out.stdout.splitlines()
+    assert out.returncode == 1, out.stdout
+    assert "".join(line.split()[0] for line in lines
+                   if _DOTS.match(line)) == ".F..FF.", out.stdout
+    assert "said by the test that failed" in out.stdout
+    assert "ended (7) without this test's report" in out.stdout
+    assert "it said:\n  | .F.." in out.stdout
+    assert "3 failed, 4 passed" in lines[-1], lines[-1]
+    assert any("in an interpreter of its own" in line for line in lines)
+    (own_said, own_pid), (here_said, here_pid) = (
+        line.split() for line in (tmp_path / "ran").read_text().splitlines())
+    assert (own_said, here_said) == ("own", "here") and own_pid != here_pid
+    # alone, the file runs where it is asked for
+    (tmp_path / "ran").unlink()
+    alone, _ = _run_under_the_conftest(tmp_path / "test_own.py", own)
+    assert "ended (7)" not in alone.stdout and alone.returncode == 7
